@@ -1052,7 +1052,7 @@ def validate_overlap_row(row) -> list:
     Bars: every pair's loss trajectory bitwise equal across the knob flip
     (overlap must never change arithmetic); measured overlapped step time
     within ``OVERLAP_TOL_PCT`` of serial everywhere and <= serial outright
-    on hosts that can actually overlap (TPU, or multi-core CPU); MFU
+    on a TPU; MFU
     non-decreasing within the same tolerance; and the shardflow-priced
     speedup strictly > 1 — the deterministic witness that the per-op-class
     overlap factors re-price the placement."""
@@ -1084,12 +1084,13 @@ def validate_overlap_row(row) -> list:
     tol = OVERLAP_TOL_PCT / 100.0
     sp = row.get("speedup")
     if isinstance(sp, (int, float)) and not isinstance(sp, bool):
-        can_overlap = (
-            row.get("platform") == "tpu" or int(row.get("host_cores", 1)) > 1
-        )
-        if can_overlap and sp < 1.0:
+        # The strict bar is for the chip alone. A timing of virtual CPU
+        # devices sharing the host's cores says nothing about overlap: on
+        # an idle 8-core host the same command gave 1.136 and then 0.926
+        # (PR 24), so a ">= 1.0 on a multi-core CPU" bar was a coin flip.
+        if row.get("platform") == "tpu" and sp < 1.0:
             problems.append(
-                f"headline speedup {sp} < 1.0 on a host that can overlap "
+                f"headline speedup {sp} < 1.0 on a TPU "
                 "(overlapped step time exceeds serial)"
             )
         elif sp < 1.0 - tol:

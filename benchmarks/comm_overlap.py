@@ -159,7 +159,7 @@ def bench_ring(reps, steps):
     from jax.sharding import Mesh, PartitionSpec as P
 
     from saturn_tpu.ops.ring import ring_attention
-    from saturn_tpu.ops.shmap_compat import shard_map
+    from jax import shard_map
 
     B, H, T, D, S = 4, 8, 1024, 64, 8
     mesh = Mesh(np.array(jax.devices()[:S]).reshape(1, S), ("data", "seq"))

@@ -70,11 +70,6 @@ def main():
         import jax
 
         jax.config.update("jax_platforms", "cpu")
-        # compile cache is opt-in: cross-context entries execute wrong code
-        # (tests/conftest.py has the post-mortem)
-        if os.environ.get("SATURN_TPU_COMPILE_CACHE"):
-            jax.config.update("jax_compilation_cache_dir", "/tmp/jax_test_cache")
-            jax.config.update("jax_persistent_cache_min_compile_time_secs", 1.0)
 
     import saturn_tpu
     from saturn_tpu import HParams, Task, library

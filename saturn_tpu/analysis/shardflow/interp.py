@@ -51,6 +51,7 @@ _ELEMENTWISE = frozenset({
     "shift_right_arithmetic", "eq", "ne", "lt", "le", "gt", "ge",
     "select_n", "clamp", "nextafter", "is_finite", "stop_gradient",
     "copy", "real", "imag", "square", "logistic", "rng_uniform",
+    "add_any",
 })
 
 _REDUCERS = frozenset({
@@ -532,13 +533,13 @@ class Interpreter:
             _replicated(v.aval) for v in eqn.outvars
         ]
 
-    # psum inside a shard_map body traces as the ``psum2`` primitive on
-    # the jax versions this repo supports — same wire traffic as psum.
-    def _h_psum2(self, eqn, in_specs, index, multiplier, scan_depth):
+    # psum inside a shard_map body traces as ``psum_invariant`` — same wire
+    # traffic as psum.
+    def _h_psum_invariant(self, eqn, in_specs, index, multiplier, scan_depth):
         return self._h_psum(eqn, in_specs, index, multiplier, scan_depth)
 
-    # shard_map's replication-tracking bookkeeping: no bytes move.
-    def _h_pbroadcast(self, eqn, in_specs, index, multiplier, scan_depth):
+    # shard_map's varying-axes bookkeeping: no bytes move.
+    def _h_pvary(self, eqn, in_specs, index, multiplier, scan_depth):
         return list(in_specs[: len(eqn.outvars)]) or [
             _replicated(v.aval) for v in eqn.outvars
         ]
@@ -596,7 +597,7 @@ class Interpreter:
         self._interpret(jaxpr, env, multiplier, scan_depth)
         return [self._read(env, v) for v in jaxpr.outvars]
 
-    def _h_pjit(self, eqn, in_specs, index, multiplier, scan_depth):
+    def _h_jit(self, eqn, in_specs, index, multiplier, scan_depth):
         return self._recurse(eqn.params["jaxpr"], in_specs, multiplier,
                              scan_depth)
 
@@ -676,28 +677,21 @@ class Interpreter:
         """shard_map body: avals inside are already per-shard; explicit
         collectives in the body are counted directly."""
         p = eqn.params
-        inner = p.get("jaxpr")
-        in_names = p.get("in_names", ())
-        body_in: List[Spec] = []
+        inner = p["jaxpr"]
         jaxpr = getattr(inner, "jaxpr", inner)
-        for i, v in enumerate(jaxpr.invars):
-            rank = len(getattr(v.aval, "shape", ()))
-            names = in_names[i] if i < len(in_names) else {}
-            spec = [tuple(names.get(d, ())) for d in range(rank)]
-            body_in.append(tuple(spec))
+        body_in = [
+            _from_pspec(spec, len(getattr(v.aval, "shape", ())))
+            for v, spec in zip(jaxpr.invars, p["in_specs"])
+        ]
         self._shmap_depth += 1
         try:
             self._recurse(inner, body_in, multiplier, scan_depth)
         finally:
             self._shmap_depth -= 1
-        out_names = p.get("out_names", ())
-        outs: List[Spec] = []
-        for i, v in enumerate(eqn.outvars):
-            rank = len(getattr(v.aval, "shape", ()))
-            names = out_names[i] if i < len(out_names) else {}
-            outs.append(tuple(tuple(names.get(d, ()))
-                              for d in range(rank)))
-        return outs
+        return [
+            _from_pspec(spec, len(getattr(v.aval, "shape", ())))
+            for v, spec in zip(eqn.outvars, p["out_specs"])
+        ]
 
 
 def interpret(traced: Dict[str, Any],

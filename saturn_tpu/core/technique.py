@@ -26,6 +26,15 @@ import abc
 from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 
+class InfeasibleConfig(ValueError):
+    """This (config, sub-mesh) pair cannot exist for this task — a batch the
+    data axis does not divide, a stage count the block does not divide.
+
+    A verdict, not a fault: ``SPMDTechnique.search`` skips the config without
+    counting it among the errors that ``search()`` reports. Any other
+    exception from a config is counted there."""
+
+
 class BaseTechnique(abc.ABC):
     """Abstract parallelism technique ("UDP" in the reference's terms)."""
 

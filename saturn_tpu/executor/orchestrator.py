@@ -556,7 +556,8 @@ def _orchestrate_loop(
         analysis.verify_or_raise(plan, topology=topo, tasks=task_list,
                                  source="fresh-solve")
         logger.info("initial plan: makespan %.1fs, %d tasks", plan.makespan, len(task_list))
-        metrics.event("solve", makespan_s=plan.makespan, n_tasks=len(task_list))
+        metrics.event("solve", makespan_s=plan.makespan,
+                      n_tasks=len(task_list), plan=plan.to_json())
         if journal is not None:
             journal.append("plan_commit", interval=0,
                            makespan=plan.makespan, plan=plan.to_json())
@@ -736,7 +737,8 @@ def _orchestrate_loop(
                     )
                     logger.info("re-solve: makespan %.1fs", plan.makespan)
                     metrics.event("solve", makespan_s=plan.makespan,
-                                  n_tasks=len(remaining))
+                                  n_tasks=len(remaining),
+                                  plan=plan.to_json())
                 elif future is not None:
                     # Join the overlapped solve BEFORE the failure handling
                     # below mutates Task/Strategy state the solver thread
@@ -751,7 +753,8 @@ def _orchestrate_loop(
                     # idle for one interval and vanish at the next re-solve.
                     logger.info("re-solve: makespan %.1fs", plan.makespan)
                     metrics.event("solve", makespan_s=plan.makespan,
-                                  n_tasks=len(remaining))
+                                  n_tasks=len(remaining),
+                                  plan=plan.to_json())
                     if journal is not None:
                         journal.append("plan_commit",
                                        interval=interval_index + 1,

@@ -183,11 +183,13 @@ def _dw_kernel(src_ref, x_ref, lab_ref, lse_ref, g_ref, dw_ref, acc_scr,
 
 
 # ------------------------------------------------------------- vjp plumbing
-# ``blocks`` is the static tuple (bn_fwd, bv_fwd, bn_dw, bv_dw): fwd/dx tile
-# tokens wide and vocab narrow (the f32 score block is the VMEM hog under the
-# compiler's ~16 MiB scoped-vmem limit; W re-streams once per token row),
+# ``blocks`` is the static tuple (bn_fwd, bv_fwd, bn_dw, bv_dw, bn_dx): fwd/dx
+# tile tokens wide and vocab narrow (the f32 score block is the VMEM hog under
+# the compiler's ~16 MiB scoped-vmem limit; W re-streams once per token row),
 # while dW tiles vocab wide and tokens narrow (its accumulator spans the
-# vocab block; x re-streams once per vocab row).
+# vocab block; x re-streams once per vocab row). dx has a token block of its
+# own because it alone holds an f32 (bn, D) accumulator beside the
+# double-buffered (bn, D) output and (bv, D) weight streams.
 def _run_fwd(x, w_p, lab, block_n, block_v, n_vocab, interpret, stash):
     N, D = x.shape
     Vp = w_p.shape[0]
@@ -223,6 +225,7 @@ def _run_fwd(x, w_p, lab, block_n, block_v, n_vocab, interpret, stash):
             pltpu.VMEM((block_n, 1), jnp.float32),         # running denom
             pltpu.VMEM((block_n, 1), jnp.float32),         # label logit
         ],
+        name="saturn_ce_fwd",
         interpret=interpret,
     )(x, w_p, lab)
     if stash:
@@ -255,7 +258,7 @@ def _prep_w(w, x_dtype, Vp):
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
 def _fused_ce(x, w, lab, blocks, n_vocab, interpret, stash):
-    bn, bv, _, _ = blocks
+    bn, bv = blocks[:2]
     w_p = _prep_w(w, x.dtype, _padded_vocab(n_vocab, blocks))
     _, loss, _ = _run_fwd(x, w_p, lab, bn, bv, n_vocab, interpret,
                           stash=False)
@@ -263,7 +266,7 @@ def _fused_ce(x, w, lab, blocks, n_vocab, interpret, stash):
 
 
 def _fused_ce_fwd(x, w, lab, blocks, n_vocab, interpret, stash):
-    bn, bv, _, _ = blocks
+    bn, bv = blocks[:2]
     w_p = _prep_w(w, x.dtype, _padded_vocab(n_vocab, blocks))
     logits, loss, lse = _run_fwd(
         x, w_p, lab, bn, bv, n_vocab, interpret, stash=stash
@@ -272,7 +275,7 @@ def _fused_ce_fwd(x, w, lab, blocks, n_vocab, interpret, stash):
 
 
 def _fused_ce_bwd(blocks, n_vocab, interpret, stash, res, g):
-    block_n, block_v, bn_dw, bv_dw = blocks
+    _, block_v, bn_dw, bv_dw, block_n = blocks
     x, w_p, lab, logits, lse = res
     N, D = x.shape
     Vp = w_p.shape[0]
@@ -303,6 +306,7 @@ def _fused_ce_bwd(blocks, n_vocab, interpret, stash, res, g):
         out_specs=pl.BlockSpec((block_n, D), lambda nb, vb: (nb, 0)),
         out_shape=jax.ShapeDtypeStruct((N, D), x.dtype),
         scratch_shapes=[pltpu.VMEM((block_n, D), jnp.float32)],
+        name="saturn_ce_dx",
         interpret=interpret,
     )(dx_src, w_p, lab, lse, g)
 
@@ -327,6 +331,7 @@ def _fused_ce_bwd(blocks, n_vocab, interpret, stash, res, g):
         out_specs=pl.BlockSpec((bv_dw, D), lambda vb, nb: (vb, 0)),
         out_shape=jax.ShapeDtypeStruct((Vp, D), jnp.float32),
         scratch_shapes=[pltpu.VMEM((bv_dw, D), jnp.float32)],
+        name="saturn_ce_dw",
         interpret=interpret,
     )(dw_src, x, lab, lse, g)
 
@@ -345,6 +350,11 @@ def _auto_bv_dw(d_model: int) -> int:
     a non-power-of-two 128-multiple (e.g. 640) makes lcm(bv, bv_dw) inflate
     the vocab pad by up to ~4% dead columns in every kernel."""
     cap = min(1024, (1 << 20) // max(d_model, 1024))
+    if d_model > 2048:
+        # recompute mode streams a (bv_dw, D) weight block and a (bn_dw, D)
+        # x block beside the double-buffered f32 output: 256 @ D=4096 was
+        # refused by the v5e compiler, 128 fits
+        cap //= 2
     return max(128, 1 << (cap.bit_length() - 1))
 
 
@@ -445,12 +455,17 @@ def fused_linear_cross_entropy(
 
     # fwd/dx: wide token blocks, narrow vocab blocks; dW: the transpose.
     # Sized so every kernel's VMEM residency (score block, accumulators,
-    # double-buffered streams) stays under the ~16 MiB scoped-vmem limit up
-    # to d_model 4096 (gptj-6b). The round-5 chip run measured the stash-mode
-    # fwd at bn=2048/bv=512/D=768 at 17.18 MiB (double-buffered x + stash
-    # streams + f32 score block + exp temp) — 1.18 MiB over. One bf16 byte-
-    # pair of token-block per D column (bn*D*2B <= 2 MiB) is the budget that
-    # fits every kernel with ~35% headroom.
+    # double-buffered streams) stays under the 16 MiB scoped-vmem limit. The
+    # round-5 chip run measured the stash-mode fwd at bn=2048/bv=512/D=768 at
+    # 17.18 MiB (double-buffered x + stash streams + f32 score block + exp
+    # temp) — 1.18 MiB over. One bf16 byte-pair of token-block per D column
+    # (bn*D*2B <= 2 MiB) fits the fwd and dW kernels up to d_model 4096
+    # (gptj-6b). The dx kernel does not fit there at that block: compiled for
+    # a v5e at (N, D, V) = (2048, 4096, 50400) it wanted 16.50 MiB — 8 MiB of
+    # double-buffered (512, 4096) weight stream, 4 MiB of output stream and a
+    # 4 MiB f32 accumulator at bn=256 — so above D = 2048 dx halves its token
+    # block, and quarters it in recompute mode, below
+    # (tests/test_tpu_compile.py holds both modes to the limit).
     bn_cap = max((1 << 20) // max(D, 1), 128)  # 1024 @ D<=1024, 256 @ 4096
     bn = block_n or _pick_block(
         N, tuple(b for b in (2048, 1024, 512, 256, 128, 64, 32, 16, 8)
@@ -490,11 +505,14 @@ def fused_linear_cross_entropy(
 
     if stash is None:
         stash = N * Vp * 2 <= STASH_BYTES_MAX
+    # recompute mode also streams the (bn, D) x block into dx
+    shrink = 1 if D <= 2048 or block_n is not None else (2 if stash else 4)
+    bn_dx = bn // shrink if bn % (16 * shrink) == 0 else bn
+    blocks = (bn, bv, bn_dw, bv_dw, bn_dx)
 
     # f32 primal: a no-op for the zoo's f32 params; the compute-dtype cast
     # and vocab pad live inside _fused_ce so dW's dtype matches its primal
     per_tok = _fused_ce(
-        x2, w.astype(jnp.float32), lab, (bn, bv, bn_dw, bv_dw), V, interp,
-        stash,
+        x2, w.astype(jnp.float32), lab, blocks, V, interp, stash,
     )[:, 0]
     return reduce(per_tok, lab[:, 0] != ignore_index)

@@ -27,7 +27,7 @@ import numpy as np
 from jax import lax
 from jax.sharding import PartitionSpec as P
 
-from saturn_tpu.ops.shmap_compat import shard_map
+from jax import shard_map
 
 # Version tag for profile-cache fingerprints: bump when the overlapped
 # lowering changes shape (a serial profile must never price an overlapped

@@ -155,6 +155,7 @@ def _fwd(q, k, v, *, block_q, block_k, scale, causal, h, kv):
             pltpu.VMEM((block_q, 1), jnp.float32),   # running denom
             pltpu.VMEM((block_q, D), jnp.float32),   # output accumulator
         ],
+        name="saturn_flash_fwd",
         interpret=_use_interpret(),
     )(q, k, v)
     return o, lse
@@ -266,6 +267,7 @@ def _bwd(block_q, block_k, scale, causal, h, kv, res, do):
         out_specs=pl.BlockSpec((1, block_q, D), lambda bh, i, j: (bh, i, 0)),
         out_shape=jax.ShapeDtypeStruct((BH, T, D), q.dtype),
         scratch_shapes=[pltpu.VMEM((block_q, D), jnp.float32)],
+        name="saturn_flash_dq",
         interpret=_use_interpret(),
     )(q, k, v, do, lse, delta)
 
@@ -303,6 +305,7 @@ def _bwd(block_q, block_k, scale, causal, h, kv, res, do):
             pltpu.VMEM((block_k, D), jnp.float32),
             pltpu.VMEM((block_k, D), jnp.float32),
         ],
+        name="saturn_flash_dkv",
         interpret=_use_interpret(),
     )(q, k, v, do, lse, delta)
     return dq, dk, dv

@@ -39,7 +39,7 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import PartitionSpec as P
 
-from saturn_tpu.ops.shmap_compat import shard_map
+from jax import shard_map
 
 #: Version tag for the *set* of pipeline schedules this module implements.
 #: Folded into the profile-cache fingerprint so entries profiled before a

@@ -31,7 +31,7 @@ import optax
 from jax import lax
 from jax.sharding import PartitionSpec as P
 
-from saturn_tpu.ops.shmap_compat import shard_map
+from jax import shard_map
 
 
 def ring_attention(
@@ -63,9 +63,19 @@ def ring_attention(
     scale = 1.0 / math.sqrt(D)
     qpos = idx * Tc + jnp.arange(Tc)
 
-    o0 = jnp.zeros((B, H, Tc, D), jnp.float32)
-    l0 = jnp.zeros((B, H, Tc), jnp.float32)
-    m0 = jnp.full((B, H, Tc), -jnp.inf, jnp.float32)
+    # The accumulators become device-varying inside the scan (they fold q and
+    # the axis index), and shard_map's type check wants the initial carry to
+    # say so already.
+    varying = tuple(sorted(set(jax.typeof(q).vma) | {axis_name}))
+
+    def init(shape, fill):
+        return lax.pcast(
+            jnp.full(shape, fill, jnp.float32), varying, to="varying"
+        )
+
+    o0 = init((B, H, Tc, D), 0.0)
+    l0 = init((B, H, Tc), 0.0)
+    m0 = init((B, H, Tc), -jnp.inf)
     # Rotate kv blocks one hop per step: after s steps this device holds the
     # block originally on shard (idx - s) mod S.
     perm = [(i, (i + 1) % axis_size) for i in range(axis_size)]

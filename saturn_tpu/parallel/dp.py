@@ -35,5 +35,5 @@ class DataParallel(SPMDTechnique):
         # best-guess-first grid ordering idea as ``FSDP.py:72-78``; crossed
         # with flash attention on TPU so the solver picks from measurement.
         return self._with_attention_variants(
-            task, [{"remat": False}, {"remat": True}]
+            task, [{"remat": False}, {"remat": True}], n_devices
         )

@@ -26,6 +26,7 @@ from jax.sharding import PartitionSpec as P
 from saturn_tpu.parallel import sharding as shr
 from saturn_tpu.parallel.spmd_base import SPMDTechnique
 from saturn_tpu.core.strategy import Techniques
+from saturn_tpu.core.technique import InfeasibleConfig
 
 _EXPERT_PARAM = re.compile(r"(^|/)(we_in|we_out|be_in|be_out)$")
 
@@ -58,7 +59,7 @@ class ExpertParallel(SPMDTechnique):
     def mesh_spec(self, n_devices, task, config) -> Tuple[Tuple[str, ...], Tuple[int, ...]]:
         ep = config.get("ep", min(n_devices, 2))
         if n_devices % ep != 0:
-            raise ValueError(f"{n_devices} devices not divisible by ep={ep}")
+            raise InfeasibleConfig(f"{n_devices} devices not divisible by ep={ep}")
         return ("data", "expert"), (n_devices // ep, ep)
 
     def _n_experts(self, task) -> int:

@@ -66,7 +66,13 @@ class FSDP(SPMDTechnique):
             {"remat": False, "offload": False},
             {"remat": True, "offload": False},
         ]
-        if host_offload_supported():
+        if host_offload_supported() and n_devices == 1:
+            # One-chip blocks only: sharded over a multi-chip block the
+            # pinned-host state needs a lane-sliced async update, which the
+            # v5e compiler refuses ("Lane slice updating is not supported in
+            # async dynamic update slice yet" — compiled for a described
+            # 2x2, PR 24). Offering the points there would make every
+            # multi-chip sweep on a TPU report errors.
             grid += [
                 {"remat": True, "offload": True},
                 {"remat": False, "offload": True},
@@ -80,7 +86,7 @@ class FSDP(SPMDTechnique):
                 {"remat": False, "offload": False, "overlap": True},
                 {"remat": True, "offload": False, "overlap": True},
             ]
-        return self._with_attention_variants(task, grid)
+        return self._with_attention_variants(task, grid, n_devices)
 
     def _overlap_ok(self, task, n_devices: int) -> bool:
         """The explicit zero3 program needs the model's pipeline

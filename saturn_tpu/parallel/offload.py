@@ -45,43 +45,20 @@ from saturn_tpu.parallel.spmd_base import SPMDTechnique
 from saturn_tpu.core.strategy import Techniques
 
 
-def _device_space():
-    """The device-memory destination for ``jax.device_put``, if any.
-
-    ``jax.memory.Space`` came and went across 0.4.x; on versions without it
-    the memory-kind transfer spells ``TransferToMemoryKind("device")``. When
-    neither exists, return None — callers skip the transfer, which is exactly
-    right wherever ``host_offload_supported()`` is also False (the tree
-    already lives in device memory).
-    """
-    mem = getattr(jax, "memory", None)
-    if mem is not None:
-        return mem.Space.Device
-    try:
-        from jax.sharding import TransferToMemoryKind
-    except ImportError:
-        try:
-            from jax._src.sharding_impls import TransferToMemoryKind
-        except ImportError:
-            return None
-    return TransferToMemoryKind("device")
-
-
-_DEVICE_SPACE = _device_space()
 _REAL_OFFLOAD: Optional[bool] = None
 
 
 def _to_device(tree):
-    # Identity wherever real host offload is off (CPU meshes, missing memory
-    # -space API): the tree already lives in device memory, and the CPU SPMD
-    # partitioner rejects the placement annotation outright (RET_CHECK
-    # "Side-effect HLO must have sharding").
+    # Identity wherever real host offload is off (CPU meshes): the tree
+    # already lives in device memory, and the CPU SPMD partitioner rejects
+    # the placement annotation outright (RET_CHECK "Side-effect HLO must
+    # have sharding").
     global _REAL_OFFLOAD
     if _REAL_OFFLOAD is None:
-        _REAL_OFFLOAD = _DEVICE_SPACE is not None and host_offload_supported()
+        _REAL_OFFLOAD = host_offload_supported()
     if not _REAL_OFFLOAD:
         return tree
-    return jax.device_put(tree, _DEVICE_SPACE)
+    return jax.device_put(tree, jax.memory.Space.Device)
 
 
 class HostOffload(SPMDTechnique):

@@ -38,6 +38,7 @@ from saturn_tpu.ops.pipeline import (
 )
 from saturn_tpu.parallel.spmd_base import SPMDTechnique
 from saturn_tpu.core.strategy import Techniques
+from saturn_tpu.core.technique import InfeasibleConfig
 
 
 def _layer_costs(spec, n_layers: int) -> Optional[list]:
@@ -61,7 +62,7 @@ class Pipeline(SPMDTechnique):
     def mesh_spec(self, n_devices, task, config) -> Tuple[Tuple[str, ...], Tuple[int, ...]]:
         s = config.get("stages", 2)
         if n_devices % s != 0:
-            raise ValueError(f"{n_devices} devices not divisible by {s} stages")
+            raise InfeasibleConfig(f"{n_devices} devices not divisible by {s} stages")
         if config.get("layout") == "stage_major":
             # Cross-slice stage placement: with slice-major device ordering
             # (``core/mesh.py``) the LEADING mesh axis is the one whose
@@ -195,7 +196,7 @@ class Pipeline(SPMDTechnique):
         spans = config.get("spans")
         n_layers = getattr(spec.config, "n_layers", 1)
         if spans is None and n_layers % s != 0:
-            raise ValueError(
+            raise InfeasibleConfig(
                 f"{n_layers} layers not divisible by {s} stages — pass "
                 "config['spans'] (candidate_configs computes balanced ones)"
             )

@@ -29,6 +29,7 @@ from saturn_tpu.ops.ring import ring_loss_and_grads
 from saturn_tpu.parallel import sharding as shr
 from saturn_tpu.parallel.spmd_base import SPMDTechnique
 from saturn_tpu.core.strategy import Techniques
+from saturn_tpu.core.technique import InfeasibleConfig
 
 
 class RingSequenceParallel(SPMDTechnique):
@@ -38,7 +39,7 @@ class RingSequenceParallel(SPMDTechnique):
     def mesh_spec(self, n_devices, task, config) -> Tuple[Tuple[str, ...], Tuple[int, ...]]:
         sp = config.get("sp", 2)  # same default as _model_overrides
         if n_devices % sp != 0:
-            raise ValueError(f"{n_devices} devices not divisible by sp={sp}")
+            raise InfeasibleConfig(f"{n_devices} devices not divisible by sp={sp}")
         # 'seq' minor: ring neighbors are adjacent devices on the ICI ring.
         return ("data", "seq"), (n_devices // sp, sp)
 

@@ -18,6 +18,7 @@ import contextlib
 import logging
 import os
 import threading
+import time as _time
 import timeit as _timeit
 from dataclasses import dataclass, field
 from typing import Any, Dict, List, Optional, Sequence, Tuple
@@ -28,7 +29,7 @@ import optax
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from saturn_tpu.core.mesh import make_submesh
-from saturn_tpu.core.technique import BaseTechnique
+from saturn_tpu.core.technique import BaseTechnique, InfeasibleConfig
 from saturn_tpu.parallel import sharding as shr
 from saturn_tpu.utils import checkpoint as ckpt
 from saturn_tpu.utils.timing import (
@@ -296,8 +297,9 @@ class SPMDTechnique(BaseTechnique):
         # of re-paying (or re-raising) the trace every interval.
         self._flops_cache: Dict[Any, Optional[float]] = {}
         self._flops_lock = threading.Lock()
-        # Why each (task, size) search came back infeasible — consumed (and
-        # popped) by the trial runner's monotone pruning. Keyed per grid
+        # What each (task, size) search saw (config, memory-rejection and
+        # error counts) — consumed (and popped) by the trial runner for its
+        # monotone pruning and its error total. Keyed per grid
         # point because one instance serves concurrent trial threads.
         self._search_reports: Dict[Any, Dict[str, Any]] = {}
         # Host fraction measured for the best (task, size) config — consumed
@@ -307,8 +309,10 @@ class SPMDTechnique(BaseTechnique):
         self._reports_lock = threading.Lock()
 
     def search_report(self, task_name: str, size: int) -> Optional[Dict[str, Any]]:
-        """Pop the infeasibility report for the most recent ``search`` of
-        (task, size); None when the search was feasible or never ran."""
+        """Pop the report of the most recent ``search`` of (task, size): how
+        many configs there were, how many XLA's memory analysis rejected, how
+        many raised (``errors``, with ``first_error``), and whether memory
+        alone made the point infeasible. None when no search ran."""
         with self._reports_lock:
             return self._search_reports.pop((task_name, size), None)
 
@@ -489,10 +493,7 @@ class SPMDTechnique(BaseTechnique):
             if single:
                 fused_loss = fused
             else:
-                try:
-                    from jax import shard_map
-                except ImportError:  # jax < 0.5 keeps it in experimental
-                    from jax.experimental.shard_map import shard_map
+                from jax import shard_map
 
                 axes = tuple(mesh.axis_names)
                 bspec = batch_partition if batch_partition is not None else P(
@@ -506,9 +507,14 @@ class SPMDTechnique(BaseTechnique):
                     return s / jax.numpy.maximum(c, 1)
 
                 def fused_loss(params, batch):
+                    # check_vma=False: a pallas_call's outputs carry no
+                    # varying-axes type, which the check refuses (compiled
+                    # for a v5e 2x2; off-TPU the op is plain XLA and the
+                    # check never sees a kernel). Both outputs are psum'd
+                    # over every axis, so out_specs=P() holds by construction.
                     return shard_map(
                         _local, mesh=mesh, in_specs=(P(), bspec),
-                        out_specs=P(),
+                        out_specs=P(), check_vma=False,
                     )(params, batch)
 
             def loss_and_grads(params, batch):
@@ -546,7 +552,7 @@ class SPMDTechnique(BaseTechnique):
         schedule that would drop the aux term must fail loudly, not train a
         silently different objective."""
         if self._aux_incompatible(spec):
-            raise ValueError(
+            raise InfeasibleConfig(
                 f"{self.name}: model has an auxiliary loss (apply_with_aux_fn) "
                 f"that this technique's custom schedule would drop; use a "
                 f"dense technique (dp/fsdp/tp/ep) for aux-loss models"
@@ -583,6 +589,14 @@ class SPMDTechnique(BaseTechnique):
             }
 
         def train_step(state, batch):
+            if update_on_host:
+                # The state arrives in pinned host memory (the shardings'
+                # memory_kind), but a jit argument's type does not say so,
+                # and JAX types every value by memory space: mixing the
+                # host-typed grads below with untyped state is refused at
+                # trace time (on the v5e, PR 24: "memory_space of all inputs
+                # passed to `add` must be the same"). Say where it lives.
+                state = jax.device_put(state, jax.memory.Space.Host)
             loss, grads = loss_and_grads(state["params"], batch)
             if update_on_host:
                 from jax.experimental.compute_on import compute_on
@@ -615,16 +629,26 @@ class SPMDTechnique(BaseTechnique):
         return out
 
     def _with_attention_variants(
-        self, task: Any, grid: List[Dict[str, Any]]
+        self, task: Any, grid: List[Dict[str, Any]], n_devices: int
     ) -> List[Dict[str, Any]]:
-        """Cross an autotune grid with explicit {flash, dense} attention when
-        the Pallas kernel can lower for this task's model. Both variants are
-        pinned explicitly (the model default is 'auto', so an unpinned entry
-        would duplicate the flash one on TPU); flash first — it measured
-        fastest at every seq on the chip (BASELINE.md) — but the trial runner
-        keeps whichever measures faster for THIS task: the
-        empirically-selected-config premise of the whole system
-        (``PerformanceEvaluator.py:101-115``)."""
+        """Pin the attention implementation of every grid point when the
+        Pallas kernel can lower for this task's model (the model default is
+        'auto', which resolves to flash on TPU, so an unpinned entry is a
+        flash entry there).
+
+        On a one-chip block the grid is crossed with {flash, dense}, flash
+        first — it measured fastest at every seq on the chip (BASELINE.md) —
+        and the trial runner keeps whichever measures faster for THIS task:
+        the empirically-selected-config premise of the whole system
+        (``PerformanceEvaluator.py:101-115``).
+
+        On a multi-chip block every point is pinned dense: these techniques'
+        steps are GSPMD-partitioned ``jit`` programs, and a Mosaic kernel has
+        no partitioning rule — compiled for a v5e 2x2 the flash points are
+        refused ("Mosaic kernels cannot be automatically partitioned. Please
+        wrap the call in a shard_map"). Offering them would make every
+        multi-chip sweep on a TPU report errors and fall to dense anyway.
+        """
         from saturn_tpu.ops.flash import flash_supported
 
         try:
@@ -633,6 +657,8 @@ class SPMDTechnique(BaseTechnique):
             return grid
         if getattr(cfg, "attention", None) is None or not flash_supported(cfg):
             return grid
+        if n_devices > 1:
+            return [dict(c, attention="dense") for c in grid]
         out: List[Dict[str, Any]] = []
         for c in grid:
             out.append(dict(c, attention="flash"))
@@ -675,11 +701,10 @@ class SPMDTechnique(BaseTechnique):
     def _build_uncached(
         self, task: Any, devices: Sequence[Any], config: Dict[str, Any]
     ) -> _Bundle:
-        # Persistent XLA compilation cache (opt-in via
-        # SATURN_TPU_COMPILE_CACHE_DIR): every compile — trial-time AND the
+        # Persistent XLA compilation cache: every compile — trial-time AND the
         # execution engine's bundle builds — lands in one on-disk cache, so a
         # program compiled by a sweep is reused by later intervals and later
-        # processes. Idempotent no-op when unconfigured.
+        # processes. Decided once per process (see the function).
         from saturn_tpu.utils import profile_cache as _pcache
 
         _pcache.maybe_enable_persistent_compile_cache()
@@ -692,7 +717,7 @@ class SPMDTechnique(BaseTechnique):
         bspec = self.batch_spec(config)
         data_axis = tuple(bspec)[0] if len(tuple(bspec)) else None
         if data_axis is not None and ds.batch_size % mesh_axes.get(data_axis, 1) != 0:
-            raise ValueError(
+            raise InfeasibleConfig(
                 f"batch_size {ds.batch_size} not divisible by "
                 f"{data_axis}={mesh_axes.get(data_axis)}"
             )
@@ -884,36 +909,60 @@ class SPMDTechnique(BaseTechnique):
         best: Tuple[Optional[Dict[str, Any]], Optional[float]] = (None, None)
         best_hf = 0.0
         n_configs = n_memory = n_error = 0
+        first_error: Optional[str] = None
+        from saturn_tpu.utils import metrics as _metrics
+
+        def note(config, **fields):
+            # one event per grid point: which variant measured what, which
+            # did not fit, which raised — the winner alone hides the rest
+            _metrics.event("trial_config", task=task.name, size=len(devices),
+                           technique=self.name, config=dict(config), **fields)
+
         for config in self.candidate_configs(task, len(devices)):
             n_configs += 1
             try:
                 timed = self._try_config(task, devices, config)
-            except Exception as e:  # infeasible configs must not kill the sweep
-                log.info("%s trial %s failed: %r", self.name, config, e)
+            except InfeasibleConfig as e:
+                log.info("%s trial %s infeasible: %s", self.name, config, e)
+                note(config, infeasible=str(e))
+                continue
+            except Exception as e:  # a broken config must not kill the sweep
+                # ...but a config that RAISED is not a config that lost: on
+                # the chip a kernel variant that fails to lower would
+                # otherwise lose to its dense twin in silence. Warn, and
+                # count it into the report ``search()`` returns.
+                log.warning("%s trial %s for task %s failed: %r",
+                            self.name, config, task.name, e)
                 n_error += 1
+                if first_error is None:
+                    first_error = f"{self.name} {config}: {e!r}"
+                note(config, error=repr(e))
                 continue
             if timed is None:  # _try_config returns None only on the memory check
                 n_memory += 1
+                note(config, memory_rejected=True)
                 continue
             t, hf = timed
+            note(config, per_batch_s=t)
             if best[1] is None or t < best[1]:
                 best = (dict(config), t)
                 best_hf = hf
-        if best[1] is not None:
-            with self._reports_lock:
+        with self._reports_lock:
+            if best[1] is not None:
                 self._host_fracs[(task.name, len(devices))] = best_hf
-        if best[1] is None:
             # Memory is the binding constraint only when EVERY candidate was
             # rejected by XLA memory analysis — a mesh/divisibility error in
             # any config means smaller sizes might still work, so monotone
             # pruning must not engage.
-            with self._reports_lock:
-                self._search_reports[(task.name, len(devices))] = {
-                    "memory_infeasible": n_configs > 0 and n_memory == n_configs,
-                    "configs": n_configs,
-                    "memory_rejected": n_memory,
-                    "errors": n_error,
-                }
+            self._search_reports[(task.name, len(devices))] = {
+                "memory_infeasible": (
+                    best[1] is None and n_configs > 0 and n_memory == n_configs
+                ),
+                "configs": n_configs,
+                "memory_rejected": n_memory,
+                "errors": n_error,
+                "first_error": first_error,
+            }
         return best
 
     def _profile_window(self, config: Dict[str, Any]) -> int:
@@ -1049,6 +1098,7 @@ class SPMDTechnique(BaseTechnique):
         trajectory is bit-identical to running alone.
         """
         config = dict(task.selected_strategy.params or {})
+        ts_launch = _time.time()  # before any compile this interval needs
         bundle = self.build(task, devices, config)
         key = self._bundle_key(task, devices, config)
 
@@ -1145,6 +1195,7 @@ class SPMDTechnique(BaseTechnique):
         # interval-end fold (tiny buffers: one scalar / (K,) per unit).
         unit_losses: List[Any] = []
         t_all0 = _timeit.default_timer()
+        ts_start = _time.time()  # same clock as the metrics events' ``ts``
         t_steady = t_all0
         # Batch staging runs one unit ahead on the prefetch thread; the
         # loop body only dispatches device programs.
@@ -1315,28 +1366,48 @@ class SPMDTechnique(BaseTechnique):
             # Achieved TFLOP/s + MFU for this interval: shardflow's static
             # per-step FLOP count (cached per compiled program) over the
             # measured window wall time, normalized by the block's aggregate
-            # peak. Self-reports every run against the prior's 0.45 MFU
-            # target without a bench run; omitted when the step can't be
-            # traced (fields are additive, consumers treat them as optional).
+            # published peak (``utils/peaks``, keyed by device_kind — an
+            # accelerator that is not listed raises; the host CPU has no
+            # peak, so there only ``tflops`` is reported). Omitted when the
+            # step can't be traced (fields are additive, consumers treat
+            # them as optional).
             perf = {}
             if _metrics.enabled():
+                # the per-step trajectory (one scalar or (K,) per unit, all
+                # already computed: the readback above drained the queue)
+                perf["losses"] = [
+                    float(x) for u in unit_losses
+                    for x in np.asarray(_dist.host_array(u)).reshape(-1)
+                ]
                 step_flops = self._step_flops(task, devices, config)
                 if step_flops:
-                    from saturn_tpu.analysis.shardflow.prior import (
-                        hardware_model,
-                    )
-
                     achieved = step_flops * n / max(elapsed_all, 1e-9)
-                    peak = hardware_model()["peak_flops"]
                     perf["tflops"] = round(achieved / 1e12, 4)
-                    perf["mfu"] = round(
-                        achieved / (max(len(devices), 1) * peak), 6
-                    )
+                    if devices[0].platform != "cpu":
+                        from saturn_tpu.utils.peaks import peak_flops
+
+                        perf["mfu"] = round(
+                            achieved
+                            / (max(len(devices), 1) * peak_flops(devices[0])),
+                            6,
+                        )
+            # Where the state really lived (read off the arrays, not the plan),
+            # when this gang took its block (``ts_launch``, before compiles)
+            # and when its device work began (``ts_start``): what lets a
+            # reader of the events check a gang against its planned block and
+            # two gangs against each other.
+            on_devices = sorted({
+                d.id
+                for leaf in jax.tree_util.tree_leaves(state)
+                for d in leaf.sharding.device_set
+            })
             _metrics.event(
                 "task_interval", task=task.name, technique=self.name,
                 batches=n, loss=loss_val, samples_per_sec=round(sps, 2),
                 per_batch_s=per_batch, window=k, fused_windows=n_windows,
-                coscheduled=bool(shared), **perf,
+                coscheduled=bool(shared), devices=on_devices,
+                ts_launch=ts_launch, ts_start=ts_start, elapsed_s=elapsed_all,
+                **perf,
             )
             log.info("task %s [%s]: ran %d batches (K=%d, %d fused windows), "
                      "loss %.4f, %.1f samples/s",

@@ -83,7 +83,7 @@ class TensorParallel(SPMDTechnique):
                     {"tp": tp, "remat": True, "zero": True, "overlap": True}
                 )
             tp <<= 1
-        return self._with_attention_variants(task, grid)
+        return self._with_attention_variants(task, grid, n_devices)
 
     def _overlap_ok(self, task, n_devices: int) -> bool:
         """The zero3 program needs the model's pipeline decomposition and a

@@ -80,10 +80,9 @@ def search(
     parallel_trials: Optional[int] = None,
     profile_cache: Any = None,
     prune: bool = True,
-    compile_cache_dir: Optional[str] = None,
     trial_retries: int = 2,
     retry_backoff_s: float = 0.05,
-) -> Dict[str, int]:
+) -> Dict[str, Any]:
     """Fill ``task.strategies`` for every task in place.
 
     ``technique_names=None`` uses the whole library (registering the built-in
@@ -97,9 +96,7 @@ def search(
     ``profile_cache``: ``None`` uses the env-configured persistent cache
     (default on; ``SATURN_TPU_PROFILE_CACHE=0`` disables), ``False`` turns
     caching off for this sweep, a path string uses that directory.
-    ``prune`` toggles anchor-size cost-model pruning. ``compile_cache_dir``
-    additionally roots JAX's persistent compilation cache there for this
-    process (same effect as ``SATURN_TPU_COMPILE_CACHE_DIR``).
+    ``prune`` toggles anchor-size cost-model pruning.
 
     ``trial_retries``: extra attempts for a trial whose technique *raises*
     (transient fleet flake — a device hiccup mid-compile, an injected
@@ -111,13 +108,17 @@ def search(
     cached as permanently infeasible.
 
     Returns sweep stats ``{"trials_run", "cache_hits", "pruned",
-    "interpolated"}`` — the online admission controller uses ``trials_run``
-    to distinguish warm (zero-trial) from cold arrivals.
+    "interpolated", "dispatch", "fused_groups", "errors", "first_error"}`` —
+    the online admission controller uses ``trials_run`` to distinguish warm
+    (zero-trial) from cold arrivals. ``errors`` counts the candidate configs
+    (and whole trials, past their retry budget) that *raised* instead of
+    measuring or failing the memory check, and ``first_error`` is the repr
+    of the first of them (None when ``errors == 0``): the sweep completes
+    either way, but a kernel variant that could not lower is then a number
+    the caller can assert on, not a line at INFO.
     """
     if log:
         logging.basicConfig(level=logging.INFO)
-    if compile_cache_dir:
-        pcache.maybe_enable_persistent_compile_cache(compile_cache_dir)
     cache = pcache.resolve(profile_cache)
     with metrics.scoped(metrics_path), trace.profile_trace(trace_dir):
         return _search_inner(
@@ -228,7 +229,7 @@ class _EtaTracker:
 def _search_inner(
     tasks, technique_names, topology, parallel_trials=None, cache=None,
     prune=True, trial_retries=2, retry_backoff_s=0.05,
-) -> Dict[str, int]:
+) -> Dict[str, Any]:
     topo = topology if topology is not None else SliceTopology()
     if technique_names is None and not lib.registered_names():
         lib.register_default_library()
@@ -313,6 +314,16 @@ def _search_inner(
                     host_fraction=float(host_fraction or 0.0),
                     bubble_fraction=bubble,
                 )
+
+    # Configs (or whole trials) that RAISED, as opposed to measuring slower
+    # or not fitting: the sweep goes on, but the caller gets the count.
+    errors = {"n": 0, "first": None}
+
+    def note_errors(n: int, first: Optional[str]) -> None:
+        with update_lock:
+            errors["n"] += n
+            if errors["first"] is None:
+                errors["first"] = first
 
     def note_memory_floor(lane: _Lane, g: int) -> None:
         if getattr(lane.tech, "memory_monotone", False):
@@ -402,11 +413,12 @@ def _search_inner(
                     params, per_batch_time = None, None
                     break
                 if attempt >= max(0, trial_retries):
-                    logger.info(
+                    logger.warning(
                         "trial (%s, g=%d, %s) raised on attempt %d "
                         "(budget exhausted): %r",
                         task.name, g, name, attempt + 1, e,
                     )
+                    note_errors(1, f"{name} g={g}: {e!r}")
                     params, per_batch_time = None, None
                     break
                 # Exponential backoff with deterministic jitter — seeded per
@@ -431,11 +443,13 @@ def _search_inner(
                 time.sleep(delay)
                 attempt += 1
         dt = timeit.default_timer() - t0
+        report = None
+        reporter = getattr(tech, "search_report", None)
+        if callable(reporter):
+            report = reporter(task.name, g)
+        if report and report.get("errors"):
+            note_errors(int(report["errors"]), report.get("first_error"))
         if params is None or per_batch_time is None:
-            report = None
-            reporter = getattr(tech, "search_report", None)
-            if callable(reporter):
-                report = reporter(task.name, g)
             memory_bound = bool(report and report.get("memory_infeasible"))
             logger.info("trial (%s, g=%d, %s): infeasible%s", task.name, g, name,
                         " (memory)" if memory_bound else "")
@@ -664,6 +678,8 @@ def _search_inner(
         "interpolated": n_interp,
         "dispatch": dispatch,
         "fused_groups": fused_groups,
+        "errors": errors["n"],
+        "first_error": errors["first"],
     }
 
 

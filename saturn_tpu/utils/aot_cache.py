@@ -1,10 +1,8 @@
 """On-disk cache of AOT-compiled XLA executables (serialized, reloadable).
 
-JAX's persistent *compilation* cache (``profile_cache.
-maybe_enable_persistent_compile_cache``) is opt-in here because a cache
-shared across execution contexts with different feature detection can load
-mismatched entries (see ``tests/conftest.py``). This module is the narrower,
-always-safe alternative for the programs saturn_tpu itself builds: each
+Opt-in companion of JAX's persistent *compilation* cache
+(``profile_cache.maybe_enable_persistent_compile_cache``) for the programs
+saturn_tpu itself builds: each
 ``jit(...).lower(...)`` result is keyed by a content hash of its OWN HLO
 text plus the runtime identity (jax version, backend, device kinds/count,
 machine), and the compiled executable is serialized with
@@ -23,13 +21,14 @@ invalidate everything.
 
 Environment:
 
-- ``SATURN_TPU_AOT_CACHE=1`` forces the cache on, ``=0`` forces it off.
-  Unset, it is on for TPU backends and OFF on CPU: the conftest-documented
-  XLA:CPU hazard — AOT-loaded machine code from an execution context with
-  different CPU feature detection executes anyway ("machine type doesn't
-  match" is a warning, not an error) and silently wedges collective
-  programs — applies to serialized executables exactly as it does to the
-  persistent compilation cache, so CPU opts in per-context instead.
+- ``SATURN_TPU_AOT_CACHE=1`` turns the on-disk cache on; unset or ``=0`` it
+  is off on every backend. It was once on by default on a TPU backend only,
+  a default no test ever ran; JAX's own persistent compilation cache
+  (``profile_cache.maybe_enable_persistent_compile_cache``) is what a chip
+  run gets by default now. On XLA:CPU the hazard of a shared cache stands —
+  AOT-loaded machine code from an execution context with different CPU
+  feature detection executes anyway ("machine type doesn't match" is a
+  warning, not an error) and silently wedges collective programs.
 - ``SATURN_TPU_PROFILE_CACHE=0`` (the global profile-cache kill switch)
   disables it too, since it lives inside that directory.
 - ``SATURN_TPU_PROFILE_CACHE_DIR`` moves the root (the ``aot/`` subdir).
@@ -81,17 +80,8 @@ def enabled() -> bool:
     from saturn_tpu.utils import profile_cache as _pc
 
     raw = os.environ.get(_ENV_TOGGLE)
-    if raw is not None and raw.lower() in _pc._FALSEY:
+    if raw is None or raw.lower() in _pc._FALSEY:
         return False
-    if raw is None:
-        # default: TPU only — see the module docstring's CPU hazard note
-        try:
-            import jax
-
-            if jax.default_backend() not in ("tpu",):
-                return False
-        except Exception:
-            return False
     # riding inside the profile-cache directory means riding its kill switch
     return _pc.default_cache() is not None
 
@@ -185,13 +175,21 @@ def _path(key: str) -> str:
     return os.path.join(cache_dir(), f"{key}.jaxexec")
 
 
-def _load(key: str) -> Optional[Any]:
+def _load(key: str, devices: Any = None) -> Optional[Any]:
     try:
         with open(_path(key), "rb") as f:
             payload, in_tree, out_tree = pickle.load(f)
+        import jax
         from jax.experimental.serialize_executable import deserialize_and_load
 
-        return deserialize_and_load(payload, in_tree, out_tree)
+        # Load onto the block the program was lowered for. Left to its
+        # default, deserialize_and_load takes EVERY device of the backend and
+        # the executable then wants one shard per device. A caller that names
+        # no block lowered for the default device.
+        block = list(devices) if devices is not None else jax.devices()[:1]
+        return deserialize_and_load(
+            payload, in_tree, out_tree, execution_devices=block
+        )
     except FileNotFoundError:
         return None
     except Exception as e:
@@ -257,7 +255,7 @@ def load_or_compile(lowered: Any, devices: Any = None) -> Any:
         return lowered.compile()
     if key is None:
         return lowered.compile()
-    hit = _load(key)
+    hit = _load(key, devices)
     if hit is not None:
         _bump("hits")
         return hit

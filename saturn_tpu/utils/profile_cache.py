@@ -26,17 +26,19 @@ Environment:
 - ``SATURN_TPU_PROFILE_CACHE_DIR``: cache directory (default
   ``~/.cache/saturn_tpu/profiles``).
 - ``SATURN_TPU_PROFILE_CACHE=0``: disable the default cache entirely.
-- ``SATURN_TPU_COMPILE_CACHE_DIR``: additionally enable JAX's persistent
-  *compilation* cache rooted there, so the XLA executables built by trial
-  sweeps are reused by the execution engine's bundle build
-  (``parallel/spmd_base.py::_build_uncached``) and by later processes.
-  Off by default: on CPU test platforms a cache shared across execution
-  contexts with different feature detection can load mismatched entries
-  (see ``tests/conftest.py``).
+
+JAX's persistent *compilation* cache is placed from outside, not by an
+option of this package: where ``JAX_COMPILATION_CACHE_DIR`` is set JAX keeps
+its cache there and this package sets no directory; where it is unset the
+cache is on by default on a TPU backend, at the fixed path
+``<checkout>/.jax_compile_cache`` (the path is part of the cache key, so a
+directory that moves never hits), and off elsewhere — see
+:func:`maybe_enable_persistent_compile_cache`.
 """
 
 from __future__ import annotations
 
+import functools
 import hashlib
 import json
 import logging
@@ -53,7 +55,6 @@ SCHEMA_VERSION = 1
 
 _ENV_DIR = "SATURN_TPU_PROFILE_CACHE_DIR"
 _ENV_TOGGLE = "SATURN_TPU_PROFILE_CACHE"
-_ENV_COMPILE_DIR = "SATURN_TPU_COMPILE_CACHE_DIR"
 
 _FALSEY = ("0", "false", "off", "no")
 
@@ -466,40 +467,41 @@ def resolve(spec: Any = None) -> Optional[ProfileCache]:
 
 
 # -------------------------------------------------- JAX compilation cache
-_COMPILE_CACHE_STATE = {"decided": False}
+_ENV_JAX_COMPILE_DIR = "JAX_COMPILATION_CACHE_DIR"
 
 
-def maybe_enable_persistent_compile_cache(path: Optional[str] = None) -> bool:
-    """Point JAX's persistent compilation cache at ``path`` (or the env dir).
+def default_compile_cache_dir() -> str:
+    """``<checkout>/.jax_compile_cache``: beside the package, fixed."""
+    pkg = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    return os.path.join(os.path.dirname(pkg), ".jax_compile_cache")
 
-    Idempotent and cheap on the no-op path, so callers on the build hot path
-    (``SPMDTechnique._build_uncached``) can invoke it unconditionally. The
-    decision is made once per process: flipping the env var mid-run would
-    otherwise mix cache roots inside one JAX runtime.
+
+@functools.cache
+def maybe_enable_persistent_compile_cache() -> Optional[str]:
+    """Decide, once per process, where JAX's persistent compilation cache
+    lives, and return that directory (None = off).
+
+    The only place in the repo that touches ``jax_compilation_cache_dir``.
+    With ``JAX_COMPILATION_CACHE_DIR`` set, JAX has already read it and no
+    directory is set here. Unset, a TPU backend gets the fixed
+    :func:`default_compile_cache_dir`; any other backend gets no cache
+    (XLA:CPU loads entries written under different CPU feature detection
+    and runs them anyway — ``tests/conftest.py``). Either way the size and
+    time thresholds drop, because a sweep compiles many programs that the
+    defaults would not keep.
+
+    Cached after the first call, so the build hot path
+    (``SPMDTechnique._build_uncached``) calls it unconditionally.
     """
-    if _COMPILE_CACHE_STATE["decided"] and path is None:
-        return _COMPILE_CACHE_STATE.get("enabled", False)
-    explicit = path is not None
-    path = path or os.environ.get(_ENV_COMPILE_DIR)
-    if not explicit:
-        _COMPILE_CACHE_STATE["decided"] = True
-    if not path:
-        _COMPILE_CACHE_STATE["enabled"] = False
-        return False
-    try:
-        import jax
+    import jax
 
+    path = os.environ.get(_ENV_JAX_COMPILE_DIR) or None
+    if path is None and jax.default_backend() == "tpu":
+        path = default_compile_cache_dir()
         os.makedirs(path, exist_ok=True)
         jax.config.update("jax_compilation_cache_dir", path)
-        # Trials compile many small programs; default thresholds would skip
-        # most of them and the cache would never amortize the sweep.
+    if path is not None:
         jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.5)
         jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
-    except Exception:
-        log.warning("could not enable jax compilation cache at %s", path, exc_info=True)
-        _COMPILE_CACHE_STATE["enabled"] = False
-        return False
-    _COMPILE_CACHE_STATE["decided"] = True
-    _COMPILE_CACHE_STATE["enabled"] = True
-    log.info("jax persistent compilation cache enabled at %s", path)
-    return True
+        log.info("jax persistent compilation cache at %s", path)
+    return path

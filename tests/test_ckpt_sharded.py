@@ -298,9 +298,23 @@ class TestMfuTelemetry:
                if e["kind"] == "task_interval"]
         assert evs, "no task_interval events emitted"
         for e in evs:
-            assert "tflops" in e and "mfu" in e, e
             assert e["tflops"] > 0
-            assert 0 < e["mfu"] < 1.5  # vs the default cpu-prior peak
+            # the host CPU has no published peak: no MFU is made up for it
+            assert "mfu" not in e, e
+            assert e["devices"] == [devices8[0].id]
+            assert len(e["losses"]) == e["batches"]
+            assert e["losses"][-1] == pytest.approx(e["loss"])
+
+    def test_peaks_table_is_keyed_by_device_kind(self):
+        from saturn_tpu.utils.peaks import peak_flops
+
+        class Dev:
+            device_kind = "TPU v5 lite"
+
+        assert peak_flops(Dev()) == 197e12
+        Dev.device_kind = "TPU v9 imaginary"
+        with pytest.raises(KeyError, match="no published peak"):
+            peak_flops(Dev())
 
 
 class TestCkptCli:

@@ -221,7 +221,7 @@ class TestAttentionAutotune:
 
         monkeypatch.setattr(flash, "flash_supported", lambda cfg=None: True)
         for tech in (DataParallel(), FSDP()):
-            grid = tech.candidate_configs(tiny_task, 2)
+            grid = tech.candidate_configs(tiny_task, 1)
             # both variants pinned EXPLICITLY (the model default is 'auto',
             # so an unpinned entry would duplicate flash on TPU)
             assert any(c.get("attention") == "flash" for c in grid)
@@ -236,6 +236,10 @@ class TestAttentionAutotune:
                 i for i, c in enumerate(grid) if c.get("attention") == "dense"
             )
             assert flash_idx < dense_idx
+            # multi-chip blocks: the step is a GSPMD-partitioned program and
+            # a Mosaic kernel cannot be partitioned, so every point is dense
+            multi = tech.candidate_configs(tiny_task, 2)
+            assert multi and all(c["attention"] == "dense" for c in multi)
 
     def test_grid_dense_only_off_tpu(self, tiny_task):
         from saturn_tpu.parallel.dp import DataParallel

@@ -24,7 +24,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from saturn_tpu.ops.shmap_compat import shard_map
+from jax import shard_map
 from tests.test_pipeline import (
     _assert_bitwise_equal,
     _assert_close,

@@ -338,6 +338,123 @@ class TestPruning:
             library.deregister("onlymax")
 
 
+class TestCompileCachePlacement:
+    """One function places JAX's persistent compilation cache."""
+
+    @pytest.fixture()
+    def decide(self, monkeypatch, tmp_path):
+        import jax
+
+        updates = {}
+        monkeypatch.setattr(jax.config, "update",
+                            lambda k, v: updates.__setitem__(k, v))
+        monkeypatch.setattr(pcache, "default_compile_cache_dir",
+                            lambda: str(tmp_path / ".jax_compile_cache"))
+
+        def run(backend, env):
+            monkeypatch.setattr(jax, "default_backend", lambda: backend)
+            if env is None:
+                monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+            else:
+                monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", env)
+            updates.clear()
+            # the undecorated function: the decision itself, uncached
+            return pcache.maybe_enable_persistent_compile_cache.__wrapped__()
+
+        return run, updates
+
+    def test_env_set_means_no_directory_is_set_in_code(self, decide):
+        run, updates = decide
+        assert run("tpu", "/somewhere/outside") == "/somewhere/outside"
+        assert "jax_compilation_cache_dir" not in updates
+        assert updates["jax_persistent_cache_min_entry_size_bytes"] == 0
+
+    def test_tpu_default_is_the_fixed_checkout_path(self, decide, tmp_path):
+        run, updates = decide
+        want = str(tmp_path / ".jax_compile_cache")
+        assert run("tpu", None) == want
+        assert updates["jax_compilation_cache_dir"] == want
+        assert os.path.isdir(want)
+
+    def test_cpu_default_is_off(self, decide):
+        run, updates = decide
+        assert run("cpu", None) is None
+        assert updates == {}
+
+    def test_default_path_is_beside_the_package(self):
+        repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        assert pcache.default_compile_cache_dir() == os.path.join(
+            repo, ".jax_compile_cache")
+
+
+class TestTrialErrorsReported:
+    def test_raising_config_is_counted_and_sweep_completes(self):
+        """A candidate config that RAISES (on the chip: a kernel variant that
+        fails to lower) is counted into the dict ``search()`` returns — the
+        sweep still completes, and the technique's other config still wins."""
+        from saturn_tpu.parallel.dp import DataParallel
+
+        class OneBadConfig(DataParallel):
+            name = "onebad"
+
+            def candidate_configs(self, task, n_devices):
+                return [{"variant": "kernel"}, {"variant": "dense"}]
+
+            def _try_config(self, task, devices, config):
+                if config["variant"] == "kernel":
+                    raise RuntimeError("kernel failed to lower")
+                return 0.01, 0.0
+
+        library.register("onebad", OneBadConfig)
+        try:
+            t = FakeTask("a")
+            stats = evaluator.search(
+                [t], technique_names=["onebad"], topology=topo(1),
+                profile_cache=False, prune=False,
+            )
+        finally:
+            library.deregister("onebad")
+        assert stats["errors"] == 1
+        assert "kernel failed to lower" in stats["first_error"]
+        assert stats["trials_run"] == 1
+        assert t.strategies[1].feasible
+        assert t.strategies[1].params == {"variant": "dense"}
+
+    def test_clean_sweep_reports_zero_errors(self):
+        stats = evaluator.search(
+            [FakeTask("a")], technique_names=["counting"], topology=topo(2),
+            profile_cache=False, prune=False,
+        )
+        assert stats["errors"] == 0 and stats["first_error"] is None
+
+    def test_indivisible_batch_is_a_verdict_not_an_error(self):
+        """``InfeasibleConfig`` (here: nothing divides) marks the size
+        infeasible without counting as an error."""
+        from saturn_tpu.core.technique import InfeasibleConfig
+        from saturn_tpu.parallel.dp import DataParallel
+
+        class NeverFits(DataParallel):
+            name = "neverfits"
+
+            def candidate_configs(self, task, n_devices):
+                return [{}]
+
+            def _try_config(self, task, devices, config):
+                raise InfeasibleConfig("batch_size 6 not divisible by data=4")
+
+        library.register("neverfits", NeverFits)
+        try:
+            t = FakeTask("a")
+            stats = evaluator.search(
+                [t], technique_names=["neverfits"], topology=topo(1),
+                profile_cache=False, prune=False,
+            )
+        finally:
+            library.deregister("neverfits")
+        assert stats["errors"] == 0
+        assert not t.strategies[1].feasible
+
+
 class TestRealizedFeedbackUpgrade:
     def test_feedback_clears_interpolated_flag(self, tiny_task):
         s = Strategy(object(), 2, {"remat": False}, 5.0, per_batch_time=0.5,

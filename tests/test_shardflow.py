@@ -184,7 +184,7 @@ class TestShardMapMode:
             np.array(jax.devices()[:4]).reshape(4), ("data",))
 
     def test_only_explicit_collectives_counted(self, devices8):
-        from saturn_tpu.ops.shmap_compat import shard_map
+        from jax import shard_map
 
         mesh = self._mesh()
 
@@ -206,7 +206,7 @@ class TestShardMapMode:
         assert all(r.explicit for r in it.ledger.records)
 
     def test_flops_rescaled_to_global(self, devices8):
-        from saturn_tpu.ops.shmap_compat import shard_map
+        from jax import shard_map
 
         mesh = self._mesh()
 

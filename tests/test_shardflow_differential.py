@@ -86,7 +86,9 @@ _CANON = {
     "collective-permute": "ppermute",
 }
 _INSTR = re.compile(
-    r"^\s*(?:ROOT\s+)?%?[\w.-]+\s*=\s*(\([^=]*?\)|\S+)\s+"
+    # a long tuple shape carries /*index=N*/ markers between its elements
+    r"^\s*(?:ROOT\s+)?%?[\w.-]+\s*=\s*"
+    r"(\((?:[^=]|/\*index=\d+\*/)*?\)|\S+)\s+"
     r"(all-reduce|all-gather|reduce-scatter|all-to-all|collective-permute)"
     r"(-start)?\(",
     re.M,
@@ -351,3 +353,18 @@ except ImportError:
         rng = random.Random(20260805)
         for _ in range(1000):
             _check_one(rng)
+
+
+def test_hlo_collectives_reads_long_tuple_shapes():
+    """XLA marks every fifth element of a long tuple shape with
+    ``/*index=N*/``; the instruction must still be found and all of its
+    elements counted (the combined gradient all-reduce is such a tuple)."""
+    hlo = (
+        "  %all-reduce = (f32[2,64]{1,0}, f32[2,64,64]{2,1,0}, f32[2,64]{1,0}, "
+        "f32[2,64]{1,0}, f32[2,64]{1,0}, /*index=5*/f32[2,64]{1,0}, f32[]) "
+        "all-reduce(%a, %b), channel_id=1, replica_groups={{0,1,2,3}}\n"
+        "  %psum.1 = f32[] all-reduce(%x), channel_id=2\n"
+    )
+    got = hlo_collectives(hlo)
+    assert got == {"all_reduce": {
+        "count": 2, "bytes": 4 * (5 * 128 + 2 * 64 * 64 + 1) + 4}}

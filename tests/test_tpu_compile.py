@@ -1,0 +1,146 @@
+"""The main path's Pallas kernels, compiled for a described v5e.
+
+No chip is attached here: ``jax.experimental.topologies`` describes a
+v5e:2x2 and the TPU compiler that is installed lowers for it, so a kernel the
+chip's compiler would refuse (VMEM over the scoped limit, a misaligned slice)
+is refused in the CPU suite already. Nothing runs, so nothing here is a
+measurement.
+
+The topology is described inside a module-scoped fixture, never at import:
+only one process may load the TPU library at a time, and under several
+pytest-xdist workers a module that touched it while being imported would give
+the workers different tests to collect. All cases stay in this one file for
+the same reason (one worker holds the library).
+"""
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from saturn_tpu.ops import ce as ce_mod
+from saturn_tpu.ops import flash as flash_mod
+
+
+@pytest.fixture(scope="module")
+def topo():
+    import os
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+
+    try:
+        return topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2"
+        )
+    except Exception as e:  # no TPU compiler in this installation
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture()
+def real_lowering(monkeypatch):
+    """The kernels pick interpret mode from the default backend, which is the
+    CPU here; the compile is for the described chip, so steer them."""
+    monkeypatch.setattr(flash_mod, "_use_interpret", lambda: False)
+    monkeypatch.setattr(ce_mod, "_use_interpret", lambda: False)
+
+
+def _compile(fn, *shapes, kernels):
+    """Compile for the described chip; every named kernel must be there as a
+    ``tpu_custom_call`` (the names are the ``name=`` of the pallas_calls)."""
+    text = jax.jit(fn).lower(*shapes).compile().as_text()
+    calls = [line for line in text.splitlines() if "tpu_custom_call" in line]
+    for kernel in kernels:
+        assert any(kernel in line for line in calls), (kernel, len(calls))
+    return text
+
+
+# ------------------------------------------------------------------- flash
+def _flash_loss(q, k, v):
+    out = flash_mod.flash_attention(q, k, v)
+    return jnp.sum(out.astype(jnp.float32))
+
+
+@pytest.mark.parametrize("grad", [False, True], ids=["fwd", "grad"])
+@pytest.mark.parametrize(
+    "shape", [(8, 12, 512, 64), (8, 12, 1024, 64)], ids=["t512", "t1024"]
+)
+def test_flash_attention_compiles_for_v5e(one_chip, real_lowering, shape, grad):
+    sds = jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one_chip)
+    fn = jax.grad(_flash_loss, argnums=(0, 1, 2)) if grad else _flash_loss
+    kernels = ["saturn_flash_fwd"]
+    if grad:
+        kernels += ["saturn_flash_dq", "saturn_flash_dkv"]
+    _compile(fn, sds, sds, sds, kernels=kernels)
+
+
+# ---------------------------------------------------------------- fused CE
+CE_SHAPES = {
+    "gpt2-small": (4096, 768, 50257),
+    "gpt2-xl": (8192, 1600, 50257),
+    "gptj-6b": (2048, 4096, 50400),
+}
+
+
+def _ce_args(one_chip, n, d, v):
+    return (
+        jax.ShapeDtypeStruct((n, d), jnp.bfloat16, sharding=one_chip),
+        jax.ShapeDtypeStruct((v, d), jnp.float32, sharding=one_chip),
+        jax.ShapeDtypeStruct((n,), jnp.int32, sharding=one_chip),
+    )
+
+
+@pytest.mark.parametrize("name", ["gpt2-small", "gpt2-xl"])
+def test_fused_ce_forward_compiles_for_v5e(one_chip, real_lowering, name):
+    _compile(
+        ce_mod.fused_linear_cross_entropy, *_ce_args(one_chip, *CE_SHAPES[name]),
+        kernels=["saturn_ce_fwd"],
+    )
+
+
+@pytest.mark.parametrize("stash", [None, False], ids=["auto", "recompute"])
+@pytest.mark.parametrize("name", list(CE_SHAPES))
+def test_fused_ce_grad_compiles_for_v5e(one_chip, real_lowering, name, stash):
+    def loss(x, w, labels):
+        return ce_mod.fused_linear_cross_entropy(x, w, labels, stash=stash)
+
+    _compile(
+        jax.grad(loss, argnums=(0, 1)), *_ce_args(one_chip, *CE_SHAPES[name]),
+        kernels=["saturn_ce_fwd", "saturn_ce_dx", "saturn_ce_dw"],
+    )
+
+
+# ------------------------------------------- a whole step on the 2x2 mesh
+def test_dp_step_with_sharded_fused_ce_compiles_for_v5e_2x2(
+        topo, real_lowering, tmp_path):
+    """On a multi-chip block dp runs the fused CE head under shard_map (a
+    Mosaic kernel has no partitioning rule of its own). GPT-2-small width,
+    depth cut to 2 (the layer stack is scanned, so depth changes nothing the
+    compiler checks here)."""
+    from saturn_tpu import HParams, Task
+    from saturn_tpu.data.lm_dataset import make_lm_dataset
+    from saturn_tpu.models.gpt2 import build_gpt2
+    from saturn_tpu.models.loss import pretraining_loss
+    from saturn_tpu.parallel.dp import DataParallel
+
+    task = Task(
+        get_model=lambda **kw: build_gpt2(
+            "gpt2-small", seq_len=512, n_layers=2, **kw),
+        get_dataloader=lambda: make_lm_dataset(
+            context_length=512, batch_size=8, vocab_size=50257,
+            n_tokens=512 * 8 * 2),
+        loss_fn=pretraining_loss,
+        hparams=HParams(lr=3e-4, batch_count=2),
+        name="compile-dp-2x2",
+        save_dir=str(tmp_path),
+    )
+    bundle = DataParallel()._build_uncached(
+        task, list(topo.devices), {"remat": False, "attention": "dense"})
+    text = bundle.lowered.compile().as_text()
+    calls = [l for l in text.splitlines() if "tpu_custom_call" in l]
+    assert sum("saturn_ce_" in l for l in calls) == 3
