@@ -1,0 +1,10 @@
+"""``python -m pytest perf/tests`` runs on the CPU: hold JAX to it before any
+test imports it, and make the repo importable from any working directory."""
+
+import os
+import sys
+
+os.environ.setdefault("JAX_PLATFORMS", "cpu")
+REPO = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+if REPO not in sys.path:
+    sys.path.insert(0, REPO)
