@@ -37,7 +37,10 @@ def search(tasks, technique_names=None, log=False, topology=None, **kw):
 
     Reference: ``saturn/trial_runner/PerformanceEvaluator.py:33``.
     """
-    from saturn_tpu.trial_runner.evaluator import search as _search
+    from saturn_tpu.utils import metrics
+
+    with metrics.span("import", module="saturn_tpu.trial_runner"):
+        from saturn_tpu.trial_runner.evaluator import search as _search
 
     return _search(
         tasks, technique_names=technique_names, log=log, topology=topology, **kw
@@ -70,7 +73,14 @@ def orchestrate(
     and defaults — a signature-parity test enforces it) so callers get
     introspectable keywords instead of an opaque ``**kw`` passthrough.
     """
-    from saturn_tpu.executor.orchestrator import orchestrate as _orch
+    from saturn_tpu.utils import metrics
+
+    # The first call in a process imports the executor, the solver and
+    # SciPy's HiGHS behind it: seconds, before ``orchestrate`` proper (and
+    # its sink) exists. The span puts them into a profiler's trace
+    # (``saturn.import``) and into a sink the caller configured.
+    with metrics.span("import", module="saturn_tpu.executor"):
+        from saturn_tpu.executor.orchestrator import orchestrate as _orch
 
     return _orch(
         task_list,

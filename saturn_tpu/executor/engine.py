@@ -824,6 +824,15 @@ def execute(
         pbt = max(float(getattr(strat, "per_batch_time", 0.0) or 0.0), 0.0)
         return batches.get(t.name, 0) * pbt
 
+    # Entered around the threads' start and join below; made here so that
+    # every launcher thread can be handed it as its spans' parent.
+    interval_span = metrics.span("interval", planned_s=interval,
+                                 n_tasks=len(run_tasks))
+
+    def under_interval(fn, *args):
+        with metrics.under(interval_span):
+            fn(*args)
+
     # (thread, member task names, watchdog deadline in seconds). A group
     # thread's deadline covers the SUM of its members' profiled work — the
     # members run interleaved on this one thread.
@@ -833,14 +842,15 @@ def execute(
         if t.name in grouped:
             continue
         th = threading.Thread(
-            target=launcher, args=(t, i), daemon=True, name=f"launch-{t.name}"
+            target=under_interval, args=(launcher, t, i), daemon=True,
+            name=f"launch-{t.name}",
         )
         dl = guardian.window_deadline_s(_expected_s(t)) if use_watchdog else None
         watch.append((th, [t.name], dl))
     for g in co_groups:
         th = threading.Thread(
-            target=group_launcher,
-            args=(g, [tid_of[t.name] for t in g]),
+            target=under_interval,
+            args=(group_launcher, g, [tid_of[t.name] for t in g]),
             daemon=True,
             name="colaunch-" + "+".join(t.name for t in g),
         )
@@ -851,8 +861,8 @@ def execute(
         watch.append((th, [t.name for t in g], dl))
     for g in fused_groups:
         th = threading.Thread(
-            target=fused_launcher,
-            args=(g, [tid_of[t.name] for t in g]),
+            target=under_interval,
+            args=(fused_launcher, g, [tid_of[t.name] for t in g]),
             daemon=True,
             name="fuselaunch-" + "+".join(t.name for t in g),
         )
@@ -865,29 +875,30 @@ def execute(
         )
         watch.append((th, [t.name for t in g], dl))
 
-    t0 = timeit.default_timer()
-    for th, _, _ in watch:
-        th.start()
-    if use_watchdog:
-        _join_with_watchdog(watch, t0, hung, hung_lock, errors, events)
-    else:
+    # The ``interval`` event is this span: same fields, plus its start. The
+    # launcher threads are its children (``metrics.under``).
+    with interval_span:
+        t0 = timeit.default_timer()
         for th, _, _ in watch:
-            th.join()
-    for tm in timers:
-        tm.cancel()
-    elapsed = timeit.default_timer() - t0
-    metrics.event(
-        "interval",
-        elapsed_s=elapsed,
-        planned_s=interval,
-        n_tasks=len(run_tasks),
-        failed=sorted(
-            n for n, e in errors.items() if not isinstance(e, PreemptedError)
-        ),
-        preempted=sorted(
-            n for n, e in errors.items() if isinstance(e, PreemptedError)
-        ),
-    )
+            th.start()
+        if use_watchdog:
+            _join_with_watchdog(watch, t0, hung, hung_lock, errors, events)
+        else:
+            for th, _, _ in watch:
+                th.join()
+        for tm in timers:
+            tm.cancel()
+        elapsed = timeit.default_timer() - t0
+        interval_span.set(
+            elapsed_s=elapsed,
+            failed=sorted(
+                n for n, e in errors.items()
+                if not isinstance(e, PreemptedError)
+            ),
+            preempted=sorted(
+                n for n, e in errors.items() if isinstance(e, PreemptedError)
+            ),
+        )
     # Interval boundary: drain the buffered metrics writer — emission is off
     # the step critical path, but an interval's telemetry must land before
     # the next interval starts (live tail_events followers, crash windows).
@@ -929,18 +940,30 @@ def _execute_multihost(
     """
     import jax
 
-    from saturn_tpu.core import distributed
-
     # Co-schedule groups are ignored here on purpose: cross-host intervals
     # already serialize every task for deterministic program order, and
     # sequential execution of a group is trajectory-identical (just
     # unoverlapped). The single window-cap read per interval still applies.
     window_cap = _window_cap()
     my_proc = jax.process_index()
-    errors: Dict[str, BaseException] = {}
     ordered = sorted(
         run_tasks, key=lambda t: (plan.assignments[t.name].start, t.name)
     )
+    with metrics.span("interval", planned_s=interval,
+                      n_tasks=len(run_tasks)) as sp:
+        errors = _multihost_interval(
+            sp, ordered, batches, plan, topology, window_cap, my_proc,
+        )
+    metrics.flush()
+    return errors
+
+
+def _multihost_interval(sp, ordered, batches, plan, topology, window_cap,
+                        my_proc) -> Dict[str, BaseException]:
+    """The body of :func:`_execute_multihost`'s ``interval`` span."""
+    from saturn_tpu.core import distributed
+
+    errors: Dict[str, BaseException] = {}
     t0 = timeit.default_timer()
     for tid, task in enumerate(ordered):
         a = plan.assignments[task.name]
@@ -970,11 +993,7 @@ def _execute_multihost(
             # coordination service then aborts the rest of the cluster
             # (multi-host supports failure_policy='raise' only).
             logger.exception("task %s failed during interval", task.name)
-            metrics.event(
-                "interval", elapsed_s=timeit.default_timer() - t0,
-                planned_s=interval, n_tasks=len(run_tasks),
-                failed=[task.name],
-            )
+            sp.set(elapsed_s=timeit.default_timer() - t0, failed=[task.name])
             raise RuntimeError(
                 f"interval execution failed for task {task.name}"
             ) from e
@@ -987,10 +1006,5 @@ def _execute_multihost(
 
     _ckpt.flush()
     distributed.sync("interval-end")
-    elapsed = timeit.default_timer() - t0
-    metrics.event(
-        "interval", elapsed_s=elapsed, planned_s=interval,
-        n_tasks=len(run_tasks), failed=[],
-    )
-    metrics.flush()
+    sp.set(elapsed_s=timeit.default_timer() - t0, failed=[])
     return errors

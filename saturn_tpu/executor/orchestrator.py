@@ -100,8 +100,15 @@ def orchestrate(
     failed task, keep the rest running), or ``"retry"`` (keep the failed task
     in the batch for up to ``max_task_retries`` more attempts — it resumes
     from its last checkpoint at the next interval — then evict like
-    ``"drop"``). ``metrics_path`` appends JSONL events (``utils/metrics.py``);
-    ``trace_dir`` wraps the run in a jax.profiler trace.
+    ``"drop"``). ``metrics_path`` appends JSONL events (``utils/metrics.py``),
+    among them one ``metrics.span`` event per phase: ``orchestrate`` >
+    ``solver.resolve`` / ``forecast`` / ``interval`` > ``task_interval`` >
+    ``launch.*`` / ``readback`` / ``step_flops`` / ``ckpt.*``, and one
+    ``compile`` event per XLA compile with the span it fell into
+    (``docs/architecture.md``, "Metrics stream & spans"). ``trace_dir`` wraps the run — the
+    final checkpoint flush included — in a jax.profiler trace that holds the
+    device's ops under the same spans (``saturn.<name>`` on the host plane),
+    with the Python tracer off.
 
     Elasticity (``saturn_tpu.resilience``): passing ``health_monitor`` (a
     ``FleetHealthMonitor``) — or a ``fault_injector`` / setting
@@ -140,6 +147,34 @@ def orchestrate(
     """
     if log:
         logging.basicConfig(level=logging.INFO)
+    from saturn_tpu.core import distributed
+
+    if distributed.is_multihost() and not distributed.is_coordinator():
+        # One writer per metrics file: every rank appending the same JSONL
+        # on shared storage would duplicate each event N-fold (and NFS
+        # O_APPEND interleaving is not line-atomic).
+        metrics_path = None
+    # The sink, the profiler region and the root span enclose the whole call:
+    # the journal's replay before the loop, and after it the ``finally`` that
+    # joins the checkpoint writer threads (whose ``ckpt.write`` spans need a
+    # sink to land in) and closes the journal.
+    with metrics.scoped(metrics_path), trace.profile_trace(trace_dir), \
+            metrics.span("orchestrate", n_tasks=len(task_list)):
+        return _orchestrate(
+            task_list, interval, topology, threshold, solver_time_limit,
+            failure_policy, max_task_retries, fault_injector, health_monitor,
+            recovery_policy, replan_degrade_factor, resume_dir,
+            health_guardian, crash_barrier,
+        )
+
+
+def _orchestrate(
+    task_list, interval, topology, threshold, solver_time_limit,
+    failure_policy, max_task_retries, fault_injector, health_monitor,
+    recovery_policy, replan_degrade_factor, resume_dir, health_guardian,
+    crash_barrier,
+) -> dict:
+    """:func:`orchestrate` inside its sink, trace and root span."""
     if failure_policy not in ("raise", "drop", "retry"):
         raise ValueError(
             f"failure_policy must be 'raise', 'drop' or 'retry', got {failure_policy!r}"
@@ -279,7 +314,7 @@ def orchestrate(
     try:
         return _orchestrate_loop(
             task_list, topo, interval, threshold, tlimit, failure_policy,
-            max_task_retries, metrics_path, trace_dir,
+            max_task_retries,
             all_completed, all_failed, retries,
             health_monitor, fault_injector, replanner, journal,
             guardian,
@@ -500,9 +535,16 @@ def _handle_topology_change(
     return task_list, result.topology, result.plan
 
 
+def _resolve_under(above, *args, **kwargs):
+    """``anytime_resolve`` on the solver pool's thread, its ``solver.resolve``
+    span a child of the span that was open where it was submitted."""
+    with metrics.under(above):
+        return anytime.anytime_resolve(*args, **kwargs)
+
+
 def _orchestrate_loop(
     task_list, topo, interval, threshold, tlimit, failure_policy,
-    max_task_retries, metrics_path, trace_dir,
+    max_task_retries,
     all_completed, all_failed, retries,
     health=None, faults=None, replanner=None, journal=None,
     guardian=None,
@@ -511,458 +553,456 @@ def _orchestrate_loop(
     from saturn_tpu.resilience.faults import PreemptedError
 
     multihost = distributed.is_multihost()
-    if multihost and not distributed.is_coordinator():
-        # One writer per metrics file: every rank appending the same JSONL
-        # on shared storage would duplicate each event N-fold (and NFS
-        # O_APPEND interleaving is not line-atomic).
-        metrics_path = None
     if not task_list:
         # Nothing left to run — e.g. a resumed batch whose journal already
         # records every task terminal (restart after a crash-after-finish).
         logger.info("orchestration complete (%d completed, %d failed)",
                     len(all_completed), len(all_failed))
         return {"completed": all_completed, "failed": all_failed}
-    with metrics.scoped(metrics_path), trace.profile_trace(trace_dir):
-        if multihost:
-            # Profile sync BEFORE the first forecast: per-process wall-clock
-            # profiling yields slightly different per-batch times, and
-            # forecast budgets derived from divergent numbers mean divergent
-            # collective program counts (multi-controller deadlock). The
-            # coordinator's trial numbers win here; per-interval syncs below
-            # use each task's executing rank.
-            distributed.sync_task_state(task_list)
-        # Multi-host: ONLY the coordinator solves (a time-limited HiGHS run
-        # is not deterministic across processes); every rank executes the
-        # same broadcast plan. Single-host: unchanged.
-        if not multihost or distributed.is_coordinator():
-            # Initial blocking solve through the anytime tier ladder: a
-            # small batch degenerates to the exact MILP (single-partition
-            # tier 1); a big queue lands inside tlimit via the cheaper
-            # tiers instead of blowing the first interval.
-            plan = anytime.anytime_resolve(
-                task_list, topo, None, interval, deadline=tlimit,
-                source="orchestrator-initial",
-                fusion=_fusion_proposals(task_list),
-                fusion_fits=_memlens_fusion_gate(topo),
-            )
-        else:
-            plan = None
-        if multihost:
-            plan = milp.Plan.from_json(
-                distributed.broadcast_json(plan.to_json() if plan else None)
-            )
-        # Mandatory adoption gate (fresh-solve path): a malformed initial
-        # plan fails HERE, with structured diagnostics, not at gang launch.
-        analysis.verify_or_raise(plan, topology=topo, tasks=task_list,
-                                 source="fresh-solve")
-        logger.info("initial plan: makespan %.1fs, %d tasks", plan.makespan, len(task_list))
-        metrics.event("solve", makespan_s=plan.makespan,
-                      n_tasks=len(task_list), plan=plan.to_json())
-        if journal is not None:
-            journal.append("plan_commit", interval=0,
-                           makespan=plan.makespan, plan=plan.to_json())
+    if multihost:
+        # Profile sync BEFORE the first forecast: per-process wall-clock
+        # profiling yields slightly different per-batch times, and
+        # forecast budgets derived from divergent numbers mean divergent
+        # collective program counts (multi-controller deadlock). The
+        # coordinator's trial numbers win here; per-interval syncs below
+        # use each task's executing rank.
+        distributed.sync_task_state(task_list)
+    # Multi-host: ONLY the coordinator solves (a time-limited HiGHS run
+    # is not deterministic across processes); every rank executes the
+    # same broadcast plan. Single-host: unchanged.
+    if not multihost or distributed.is_coordinator():
+        # Initial blocking solve through the anytime tier ladder: a
+        # small batch degenerates to the exact MILP (single-partition
+        # tier 1); a big queue lands inside tlimit via the cheaper
+        # tiers instead of blowing the first interval.
+        plan = anytime.anytime_resolve(
+            task_list, topo, None, interval, deadline=tlimit,
+            source="orchestrator-initial",
+            fusion=_fusion_proposals(task_list),
+            fusion_fits=_memlens_fusion_gate(topo),
+        )
+    else:
+        plan = None
+    if multihost:
+        plan = milp.Plan.from_json(
+            distributed.broadcast_json(plan.to_json() if plan else None)
+        )
+    # Mandatory adoption gate (fresh-solve path): a malformed initial
+    # plan fails HERE, with structured diagnostics, not at gang launch.
+    analysis.verify_or_raise(plan, topology=topo, tasks=task_list,
+                             source="fresh-solve")
+    logger.info("initial plan: makespan %.1fs, %d tasks", plan.makespan, len(task_list))
+    metrics.event("solve", makespan_s=plan.makespan,
+                  n_tasks=len(task_list), plan=plan.to_json())
+    if journal is not None:
+        journal.append("plan_commit", interval=0,
+                       makespan=plan.makespan, plan=plan.to_json())
 
-        on_done = None
-        if journal is not None:
-            def on_done(name, n):  # buffered; durable at interval end
-                if n > 0:
-                    journal.append("task_progress", task=name,
-                                   batches=int(n))
+    on_done = None
+    if journal is not None:
+        def on_done(name, n):  # buffered; durable at interval end
+            if n > 0:
+                journal.append("task_progress", task=name,
+                               batches=int(n))
 
-        base_topo = topo  # health-monitor indices refer to the pre-fault fleet
-        interval_index = 0
-        # Tasks parked by the guardian's exponential backoff: out of the
-        # forecast/re-solve set entirely until their resume interval.
-        parked: List = []
-        with ThreadPoolExecutor(max_workers=1, thread_name_prefix="solver") as pool:
-            while task_list or parked:
-                if parked:
-                    back = [
-                        t for t in parked
-                        if guardian is None
-                        or not guardian.benched(t.name, interval_index)
+    base_topo = topo  # health-monitor indices refer to the pre-fault fleet
+    interval_index = 0
+    # Tasks parked by the guardian's exponential backoff: out of the
+    # forecast/re-solve set entirely until their resume interval.
+    parked: List = []
+    with ThreadPoolExecutor(max_workers=1, thread_name_prefix="solver") as pool:
+        while task_list or parked:
+            if parked:
+                back = [
+                    t for t in parked
+                    if guardian is None
+                    or not guardian.benched(t.name, interval_index)
+                ]
+                if back:
+                    names_back = {t.name for t in back}
+                    parked = [
+                        t for t in parked if t.name not in names_back
                     ]
-                    if back:
-                        names_back = {t.name for t in back}
-                        parked = [
-                            t for t in parked if t.name not in names_back
-                        ]
-                        task_list.extend(back)
-                        logger.info(
-                            "guardian: backoff expired for %s — re-admitted",
-                            sorted(names_back),
+                    task_list.extend(back)
+                    logger.info(
+                        "guardian: backoff expired for %s — re-admitted",
+                        sorted(names_back),
+                    )
+            if not task_list:
+                # Everyone is benched: burn an idle interval so the
+                # backoff clock advances.
+                interval_index += 1
+                continue
+            if health is not None:
+                # Pre-interval health poll (elastic hook point): apply
+                # scheduled interval-start faults, then consume at most
+                # one aggregated TopologyChange into a replan.
+                if faults is not None:
+                    faults.apply_due(interval_index, health)
+                change = health.poll()
+                if change is not None and change.kind in ("shrink", "grow"):
+                    if change.kind == "grow" and journal is not None:
+                        journal.log(
+                            "grow_event", interval=interval_index,
+                            gained=list(change.gained),
+                            cause=change.cause,
+                            n_parked=len(parked),
+                            capacity=base_topo.capacity,
                         )
-                if not task_list:
-                    # Everyone is benched: burn an idle interval so the
-                    # backoff clock advances.
-                    interval_index += 1
-                    continue
-                if health is not None:
-                    # Pre-interval health poll (elastic hook point): apply
-                    # scheduled interval-start faults, then consume at most
-                    # one aggregated TopologyChange into a replan.
-                    if faults is not None:
-                        faults.apply_due(interval_index, health)
-                    change = health.poll()
-                    if change is not None and change.kind in ("shrink", "grow"):
-                        if change.kind == "grow" and journal is not None:
+                    if change.kind == "grow" and parked:
+                        # Elastic scale-up: fresh capacity runs parked
+                        # work NOW — short-circuit remaining backoff
+                        # (streak ledgers untouched) and fold the parked
+                        # tasks into the replan set so the grow re-solve
+                        # covers live ∪ parked.
+                        if guardian is not None:
+                            guardian.unbench_all(cause="grow")
+                        names_back = sorted(t.name for t in parked)
+                        task_list.extend(parked)
+                        parked = []
+                        if journal is not None:
+                            # log, not append: durable alongside the
+                            # grow_event so a crash cannot drop the
+                            # drain attribution record.
                             journal.log(
-                                "grow_event", interval=interval_index,
-                                gained=list(change.gained),
-                                cause=change.cause,
-                                n_parked=len(parked),
-                                capacity=base_topo.capacity,
-                            )
-                        if change.kind == "grow" and parked:
-                            # Elastic scale-up: fresh capacity runs parked
-                            # work NOW — short-circuit remaining backoff
-                            # (streak ledgers untouched) and fold the parked
-                            # tasks into the replan set so the grow re-solve
-                            # covers live ∪ parked.
-                            if guardian is not None:
-                                guardian.unbench_all(cause="grow")
-                            names_back = sorted(t.name for t in parked)
-                            task_list.extend(parked)
-                            parked = []
-                            if journal is not None:
-                                # log, not append: durable alongside the
-                                # grow_event so a crash cannot drop the
-                                # drain attribution record.
-                                journal.log(
-                                    "backlog_drain",
-                                    interval=interval_index,
-                                    jobs=names_back, trigger="grow",
-                                )
-                            metrics.event(
-                                "backlog_drain", interval=interval_index,
+                                "backlog_drain",
+                                interval=interval_index,
                                 jobs=names_back, trigger="grow",
                             )
-                            logger.info(
-                                "grow: re-admitted parked %s ahead of "
-                                "backoff", names_back,
-                            )
-                        task_list, topo, plan = _handle_topology_change(
-                            task_list, base_topo, health, replanner, change,
-                            plan, tlimit, all_failed,
+                        metrics.event(
+                            "backlog_drain", interval=interval_index,
+                            jobs=names_back, trigger="grow",
                         )
-                        if not task_list:
-                            break
-                    elif change is not None:  # degrade: advisory, no replan
-                        metrics.event("topology_change", **change.to_fields())
-                        logger.warning(
-                            "degraded fleet: stragglers %s (policy %s keeps "
-                            "running)", change.stragglers, replanner.policy,
-                        )
-                run_tasks, batches, completed = engine.forecast(task_list, interval, plan)
-                remaining = [t for t in task_list if t not in completed]
-
-                future = None
-                if remaining and (not multihost or distributed.is_coordinator()):
-                    # overlap next-interval solve with this interval's execution
-                    # (``orchestrator.py:69-71``)
-                    future = pool.submit(
-                        anytime.anytime_resolve, remaining, topo, plan,
-                        interval, threshold, deadline=tlimit,
-                        coschedule_exclude=(
-                            guardian.detached_names() if guardian is not None
-                            else None
-                        ),
-                        source="orchestrator",
-                        fusion=_fusion_proposals(remaining),
-                        fusion_exclude=(
-                            guardian.detached_names() if guardian is not None
-                            else None
-                        ),
-                        fusion_fits=_memlens_fusion_gate(topo),
-                    )
-
-                # Snapshot the EXECUTED plan's assignments before the
-                # re-solve broadcast replaces `plan`: feedback source ranks
-                # must name the rank that actually ran each task, not where
-                # the next plan happens to move it.
-                executed_assignments = {
-                    t.name: plan.assignments.get(t.name) for t in run_tasks
-                }
-                errors: dict = {}
-                if run_tasks:
-                    errors = engine.execute(
-                        run_tasks, batches, interval, plan, topo,
-                        failure_policy="raise" if failure_policy == "raise" else "drop",
-                        health=health, faults=faults,
-                        interval_index=interval_index,
-                        on_task_done=on_done,
-                        guardian=guardian,
-                    )
-                    if guardian is not None:
-                        # Consecutive-fault streaks reset on a clean interval
-                        # (quarantine/detach state persists — corrections,
-                        # not penalties).
-                        for t in run_tasks:
-                            if t.name not in errors:
-                                guardian.note_success(t.name)
-                    if journal is not None:
-                        journal.barrier("mid-interval",
-                                        interval=interval_index)
-                elif remaining:
-                    # nothing scheduled inside this interval (all starts beyond
-                    # it): the slide in resolve() brings work forward next round.
-                    logger.info("idle interval: no task starts within %.1fs", interval)
-
-                if multihost and remaining:
-                    # Every rank must reach this broadcast; the coordinator
-                    # contributes its joined re-solve. A coordinator-side
-                    # solve failure must still be broadcast — as an error
-                    # sentinel every rank raises on — or the other ranks
-                    # block inside broadcast_json until the distributed
-                    # failure detector fires (opaque cluster hang; same
-                    # fail-fast rationale as engine._execute_multihost).
-                    new_plan = None
-                    if future is not None:
-                        try:
-                            new_plan = future.result().to_json()
-                        except Exception as e:
-                            new_plan = {
-                                "__solve_error__": f"{type(e).__name__}: {e}"
-                            }
-                    future = None
-                    payload = distributed.broadcast_json(new_plan)
-                    if isinstance(payload, dict) and "__solve_error__" in payload:
-                        raise RuntimeError(
-                            "re-solve failed on coordinator: "
-                            + payload["__solve_error__"]
-                        )
-                    plan = _gate_resolved_plan(
-                        milp.Plan.from_json(payload), plan, topo, remaining,
-                        interval, None, interval_index,
-                    )
-                    logger.info("re-solve: makespan %.1fs", plan.makespan)
-                    metrics.event("solve", makespan_s=plan.makespan,
-                                  n_tasks=len(remaining),
-                                  plan=plan.to_json())
-                elif future is not None:
-                    # Join the overlapped solve BEFORE the failure handling
-                    # below mutates Task/Strategy state the solver thread
-                    # reads (retry rollback rewrites strategy runtimes).
-                    plan = _gate_resolved_plan(
-                        future.result(), plan, topo, remaining, interval,
-                        journal, interval_index,
-                    )
-                    future = None
-                    # Evictions happen after the solve was submitted: the
-                    # plan may still cover dropped tasks; their slots simply
-                    # idle for one interval and vanish at the next re-solve.
-                    logger.info("re-solve: makespan %.1fs", plan.makespan)
-                    metrics.event("solve", makespan_s=plan.makespan,
-                                  n_tasks=len(remaining),
-                                  plan=plan.to_json())
-                    if journal is not None:
-                        journal.append("plan_commit",
-                                       interval=interval_index + 1,
-                                       makespan=plan.makespan,
-                                       plan=plan.to_json())
-
-                # Estimate feedback: fold each task's realized per-batch time
-                # into its executed strategy (EWMA) now that no solver thread
-                # is reading strategy state; the NEXT re-solve and forecast
-                # consume the corrected numbers. The reference only logged
-                # this error (``executor.py:126-129``).
-                local_updates = fold_realized_feedback(run_tasks)
-                all_updates = local_updates
-                if multihost and run_tasks:
-                    # All ranks must forecast from identical numbers. Each
-                    # task's numbers come from the rank that actually ran it
-                    # (the lowest process of its EXECUTED block) —
-                    # broadcasting the coordinator's view would throw away
-                    # realized-feedback corrections for tasks on other
-                    # hosts' blocks forever. The merged update map rides the
-                    # same broadcast so the coordinator (sole metrics
-                    # writer) records corrections made on other hosts.
-                    src = {}
-                    for t in run_tasks:
-                        a = executed_assignments.get(t.name)
-                        if a is not None:
-                            devs = topo.block_devices(a.block)
-                            src[t.name] = min(
-                                getattr(d, "process_index", 0) for d in devs
-                            )
-                    all_updates = distributed.sync_task_state(
-                        run_tasks, src, local_updates
-                    )
-                for name, (old, new) in sorted(all_updates.items()):
-                    metrics.event(
-                        "estimate_update", task=name,
-                        profiled_s=round(old, 6), updated_s=round(new, 6),
-                    )
-                    if abs(new - old) > 0.25 * max(old, 1e-9):
                         logger.info(
-                            "estimate correction for %s: %.3fs -> %.3fs "
-                            "per batch", name, old, new,
+                            "grow: re-admitted parked %s ahead of "
+                            "backoff", names_back,
                         )
+                    task_list, topo, plan = _handle_topology_change(
+                        task_list, base_topo, health, replanner, change,
+                        plan, tlimit, all_failed,
+                    )
+                    if not task_list:
+                        break
+                elif change is not None:  # degrade: advisory, no replan
+                    metrics.event("topology_change", **change.to_fields())
+                    logger.warning(
+                        "degraded fleet: stragglers %s (policy %s keeps "
+                        "running)", change.stragglers, replanner.policy,
+                    )
+            with metrics.span("forecast", n_tasks=len(task_list)):
+                run_tasks, batches, completed = engine.forecast(
+                    task_list, interval, plan)
+            remaining = [t for t in task_list if t not in completed]
 
-                preempted = {
-                    n: e for n, e in errors.items()
-                    if isinstance(e, PreemptedError)
-                }
-                if preempted:
-                    # Abort-and-requeue: preemption is the fleet's fault, not
-                    # the task's — roll back forecast's accounting and requeue
-                    # WITHOUT counting against max_task_retries; the next
-                    # loop-top health poll replans onto the surviving mesh
-                    # and the task resumes from its checkpoint there.
-                    errors = {
-                        n: e for n, e in errors.items() if n not in preempted
-                    }
-                    by_name = {t.name: t for t in run_tasks}
-                    for name, err in sorted(preempted.items()):
-                        t = by_name[name]
-                        release = getattr(t, "release_live_state", None)
-                        if release is not None:
-                            release()  # device state died with the chips
-                        engine.rollback_forecast(t, batches.get(name, 0))
-                        metrics.event("task_preempted", task=name,
-                                      error=repr(err))
-                        logger.warning(
-                            "task %s preempted — requeued for replan: %r",
-                            name, err,
-                        )
-                        if t not in remaining:
-                            remaining.append(t)  # was forecast-completed
-                    completed = [
-                        t for t in completed if t.name not in preempted
-                    ]
-
-                health_errs = (
-                    {n: e for n, e in errors.items() if guardian.owns(e)}
-                    if guardian is not None and errors else {}
+            future = None
+            if remaining and (not multihost or distributed.is_coordinator()):
+                # overlap next-interval solve with this interval's execution
+                # (``orchestrator.py:69-71``)
+                future = pool.submit(
+                    _resolve_under, metrics.current_span(),
+                    remaining, topo, plan,
+                    interval, threshold, deadline=tlimit,
+                    coschedule_exclude=(
+                        guardian.detached_names() if guardian is not None
+                        else None
+                    ),
+                    source="orchestrator",
+                    fusion=_fusion_proposals(remaining),
+                    fusion_exclude=(
+                        guardian.detached_names() if guardian is not None
+                        else None
+                    ),
+                    fusion_fits=_memlens_fusion_gate(topo),
                 )
-                if health_errs:
-                    # Guardian path: rollback to the last published
-                    # checkpoint + backoff/quarantine/detach/evict — a ledger
-                    # separate from both preemption and max_task_retries.
-                    errors = {
-                        n: e for n, e in errors.items()
-                        if n not in health_errs
-                    }
-                    by_name = {t.name: t for t in run_tasks}
-                    group_of = plan.coschedule_group_of()
-                    for name, err in sorted(health_errs.items()):
-                        t = by_name[name]
-                        release = getattr(t, "release_live_state", None)
-                        if release is not None:
-                            release()  # poisoned/hung device state is dead
-                        engine.rollback_forecast(t, batches.get(name, 0))
-                        decision = guardian.on_fault(
-                            t, err, interval_index,
-                            in_group=name in group_of,
+
+            # Snapshot the EXECUTED plan's assignments before the
+            # re-solve broadcast replaces `plan`: feedback source ranks
+            # must name the rank that actually ran each task, not where
+            # the next plan happens to move it.
+            executed_assignments = {
+                t.name: plan.assignments.get(t.name) for t in run_tasks
+            }
+            errors: dict = {}
+            if run_tasks:
+                errors = engine.execute(
+                    run_tasks, batches, interval, plan, topo,
+                    failure_policy="raise" if failure_policy == "raise" else "drop",
+                    health=health, faults=faults,
+                    interval_index=interval_index,
+                    on_task_done=on_done,
+                    guardian=guardian,
+                )
+                if guardian is not None:
+                    # Consecutive-fault streaks reset on a clean interval
+                    # (quarantine/detach state persists — corrections,
+                    # not penalties).
+                    for t in run_tasks:
+                        if t.name not in errors:
+                            guardian.note_success(t.name)
+                if journal is not None:
+                    journal.barrier("mid-interval",
+                                    interval=interval_index)
+            elif remaining:
+                # nothing scheduled inside this interval (all starts beyond
+                # it): the slide in resolve() brings work forward next round.
+                logger.info("idle interval: no task starts within %.1fs", interval)
+
+            if multihost and remaining:
+                # Every rank must reach this broadcast; the coordinator
+                # contributes its joined re-solve. A coordinator-side
+                # solve failure must still be broadcast — as an error
+                # sentinel every rank raises on — or the other ranks
+                # block inside broadcast_json until the distributed
+                # failure detector fires (opaque cluster hang; same
+                # fail-fast rationale as engine._execute_multihost).
+                new_plan = None
+                if future is not None:
+                    try:
+                        new_plan = future.result().to_json()
+                    except Exception as e:
+                        new_plan = {
+                            "__solve_error__": f"{type(e).__name__}: {e}"
+                        }
+                future = None
+                payload = distributed.broadcast_json(new_plan)
+                if isinstance(payload, dict) and "__solve_error__" in payload:
+                    raise RuntimeError(
+                        "re-solve failed on coordinator: "
+                        + payload["__solve_error__"]
+                    )
+                plan = _gate_resolved_plan(
+                    milp.Plan.from_json(payload), plan, topo, remaining,
+                    interval, None, interval_index,
+                )
+                logger.info("re-solve: makespan %.1fs", plan.makespan)
+                metrics.event("solve", makespan_s=plan.makespan,
+                              n_tasks=len(remaining),
+                              plan=plan.to_json())
+            elif future is not None:
+                # Join the overlapped solve BEFORE the failure handling
+                # below mutates Task/Strategy state the solver thread
+                # reads (retry rollback rewrites strategy runtimes).
+                plan = _gate_resolved_plan(
+                    future.result(), plan, topo, remaining, interval,
+                    journal, interval_index,
+                )
+                future = None
+                # Evictions happen after the solve was submitted: the
+                # plan may still cover dropped tasks; their slots simply
+                # idle for one interval and vanish at the next re-solve.
+                logger.info("re-solve: makespan %.1fs", plan.makespan)
+                metrics.event("solve", makespan_s=plan.makespan,
+                              n_tasks=len(remaining),
+                              plan=plan.to_json())
+                if journal is not None:
+                    journal.append("plan_commit",
+                                   interval=interval_index + 1,
+                                   makespan=plan.makespan,
+                                   plan=plan.to_json())
+
+            # Estimate feedback: fold each task's realized per-batch time
+            # into its executed strategy (EWMA) now that no solver thread
+            # is reading strategy state; the NEXT re-solve and forecast
+            # consume the corrected numbers. The reference only logged
+            # this error (``executor.py:126-129``).
+            local_updates = fold_realized_feedback(run_tasks)
+            all_updates = local_updates
+            if multihost and run_tasks:
+                # All ranks must forecast from identical numbers. Each
+                # task's numbers come from the rank that actually ran it
+                # (the lowest process of its EXECUTED block) —
+                # broadcasting the coordinator's view would throw away
+                # realized-feedback corrections for tasks on other
+                # hosts' blocks forever. The merged update map rides the
+                # same broadcast so the coordinator (sole metrics
+                # writer) records corrections made on other hosts.
+                src = {}
+                for t in run_tasks:
+                    a = executed_assignments.get(t.name)
+                    if a is not None:
+                        devs = topo.block_devices(a.block)
+                        src[t.name] = min(
+                            getattr(d, "process_index", 0) for d in devs
                         )
-                        if journal is not None:
-                            # Kill point: quarantine/detach records are
-                            # already durable (guardian journals them with
-                            # an immediate commit) — a kill here must replay
-                            # them on restart.
-                            journal.barrier("post-rollback", task=name,
-                                            interval=interval_index)
-                        if decision.action == "retry":
-                            parked.append(t)
-                            logger.warning(
-                                "task %s health fault (%s, attempt %d) — "
-                                "rolled back, parked for %d interval(s)",
-                                name, decision.cause, decision.attempt,
-                                decision.cooldown,
-                            )
-                        else:
-                            all_failed[name] = repr(err)
-                            if journal is not None:
-                                journal.append("task_failed", task=name,
-                                               error=repr(err))
-                            metrics.event("task_failed", task=name,
-                                          error=repr(err))
-                            logger.error(
-                                "evicting task %s after exhausted health "
-                                "retry budget: %r", name, err,
-                            )
-                            release_c = getattr(t, "release_compiled", None)
-                            if release_c is not None:
-                                release_c()
-                    remaining = [
-                        t for t in remaining if t.name not in health_errs
-                    ]
-                    completed = [
-                        t for t in completed if t.name not in health_errs
-                    ]
+                all_updates = distributed.sync_task_state(
+                    run_tasks, src, local_updates
+                )
+            for name, (old, new) in sorted(all_updates.items()):
+                metrics.event(
+                    "estimate_update", task=name,
+                    profiled_s=round(old, 6), updated_s=round(new, 6),
+                )
+                if abs(new - old) > 0.25 * max(old, 1e-9):
+                    logger.info(
+                        "estimate correction for %s: %.3fs -> %.3fs "
+                        "per batch", name, old, new,
+                    )
 
-                if errors:  # "drop": evict failed tasks; "retry": give them
-                    # max_task_retries more intervals first
-                    by_name = {t.name: t for t in run_tasks}
-                    retried: List = []
-                    for name, err in errors.items():
-                        t = by_name[name]
-                        release = getattr(t, "release_live_state", None)
-                        if release is not None:
-                            release()  # free HBM before the block is reused
-                        retries[name] = retries.get(name, 0) + 1
-                        if (
-                            failure_policy == "retry"
-                            and retries[name] <= max_task_retries
-                        ):
-                            # Roll back forecast's optimistic accounting: the
-                            # batches it pre-deducted never ran (the checkpoint
-                            # is the ground truth the retry resumes from).
-                            engine.rollback_forecast(t, batches.get(name, 0))
-                            retried.append(t)
-                            metrics.event("task_retry", task=name,
-                                          attempt=retries[name], error=repr(err))
-                            logger.warning(
-                                "task %s failed (attempt %d/%d) — retrying "
-                                "next interval from its last checkpoint: %r",
-                                name, retries[name], max_task_retries + 1, err,
-                            )
-                        else:
-                            all_failed[name] = repr(err)
-                            if journal is not None:
-                                journal.append("task_failed", task=name,
-                                               error=repr(err))
-                            metrics.event("task_failed", task=name, error=repr(err))
-                            logger.warning("evicting failed task %s: %r", name, err)
-                            # permanently dropped: also free its compiled
-                            # programs (a retried task keeps them — recompiling
-                            # an identical program is the cost the cache avoids)
-                            release_c = getattr(t, "release_compiled", None)
-                            if release_c is not None:
-                                release_c()
-                    keep = {t.name for t in retried}
-                    remaining = [
-                        t for t in remaining
-                        if t.name not in errors or t.name in keep
-                    ]
-                    for t in retried:
-                        if t not in remaining:
-                            remaining.append(t)  # was forecast-completed
-                    completed = [t for t in completed if t.name not in errors]
-
-                for t in completed:
-                    all_completed.append(t.name)
-                    if journal is not None:
-                        journal.append("task_completed", task=t.name)
-                    metrics.event("task_completed", task=t.name)
+            preempted = {
+                n: e for n, e in errors.items()
+                if isinstance(e, PreemptedError)
+            }
+            if preempted:
+                # Abort-and-requeue: preemption is the fleet's fault, not
+                # the task's — roll back forecast's accounting and requeue
+                # WITHOUT counting against max_task_retries; the next
+                # loop-top health poll replans onto the surviving mesh
+                # and the task resumes from its checkpoint there.
+                errors = {
+                    n: e for n, e in errors.items() if n not in preempted
+                }
+                by_name = {t.name: t for t in run_tasks}
+                for name, err in sorted(preempted.items()):
+                    t = by_name[name]
                     release = getattr(t, "release_live_state", None)
                     if release is not None:
-                        release()  # free HBM held by finished tasks
-                    release_c = getattr(t, "release_compiled", None)
-                    if release_c is not None:
-                        release_c()  # and their compiled programs
-                task_list = remaining
+                        release()  # device state died with the chips
+                    engine.rollback_forecast(t, batches.get(name, 0))
+                    metrics.event("task_preempted", task=name,
+                                  error=repr(err))
+                    logger.warning(
+                        "task %s preempted — requeued for replan: %r",
+                        name, err,
+                    )
+                    if t not in remaining:
+                        remaining.append(t)  # was forecast-completed
+                completed = [
+                    t for t in completed if t.name not in preempted
+                ]
+
+            health_errs = (
+                {n: e for n, e in errors.items() if guardian.owns(e)}
+                if guardian is not None and errors else {}
+            )
+            if health_errs:
+                # Guardian path: rollback to the last published
+                # checkpoint + backoff/quarantine/detach/evict — a ledger
+                # separate from both preemption and max_task_retries.
+                errors = {
+                    n: e for n, e in errors.items()
+                    if n not in health_errs
+                }
+                by_name = {t.name: t for t in run_tasks}
+                group_of = plan.coschedule_group_of()
+                for name, err in sorted(health_errs.items()):
+                    t = by_name[name]
+                    release = getattr(t, "release_live_state", None)
+                    if release is not None:
+                        release()  # poisoned/hung device state is dead
+                    engine.rollback_forecast(t, batches.get(name, 0))
+                    decision = guardian.on_fault(
+                        t, err, interval_index,
+                        in_group=name in group_of,
+                    )
+                    if journal is not None:
+                        # Kill point: quarantine/detach records are
+                        # already durable (guardian journals them with
+                        # an immediate commit) — a kill here must replay
+                        # them on restart.
+                        journal.barrier("post-rollback", task=name,
+                                        interval=interval_index)
+                    if decision.action == "retry":
+                        parked.append(t)
+                        logger.warning(
+                            "task %s health fault (%s, attempt %d) — "
+                            "rolled back, parked for %d interval(s)",
+                            name, decision.cause, decision.attempt,
+                            decision.cooldown,
+                        )
+                    else:
+                        all_failed[name] = repr(err)
+                        if journal is not None:
+                            journal.append("task_failed", task=name,
+                                           error=repr(err))
+                        metrics.event("task_failed", task=name,
+                                      error=repr(err))
+                        logger.error(
+                            "evicting task %s after exhausted health "
+                            "retry budget: %r", name, err,
+                        )
+                        release_c = getattr(t, "release_compiled", None)
+                        if release_c is not None:
+                            release_c()
+                remaining = [
+                    t for t in remaining if t.name not in health_errs
+                ]
+                completed = [
+                    t for t in completed if t.name not in health_errs
+                ]
+
+            if errors:  # "drop": evict failed tasks; "retry": give them
+                # max_task_retries more intervals first
+                by_name = {t.name: t for t in run_tasks}
+                retried: List = []
+                for name, err in errors.items():
+                    t = by_name[name]
+                    release = getattr(t, "release_live_state", None)
+                    if release is not None:
+                        release()  # free HBM before the block is reused
+                    retries[name] = retries.get(name, 0) + 1
+                    if (
+                        failure_policy == "retry"
+                        and retries[name] <= max_task_retries
+                    ):
+                        # Roll back forecast's optimistic accounting: the
+                        # batches it pre-deducted never ran (the checkpoint
+                        # is the ground truth the retry resumes from).
+                        engine.rollback_forecast(t, batches.get(name, 0))
+                        retried.append(t)
+                        metrics.event("task_retry", task=name,
+                                      attempt=retries[name], error=repr(err))
+                        logger.warning(
+                            "task %s failed (attempt %d/%d) — retrying "
+                            "next interval from its last checkpoint: %r",
+                            name, retries[name], max_task_retries + 1, err,
+                        )
+                    else:
+                        all_failed[name] = repr(err)
+                        if journal is not None:
+                            journal.append("task_failed", task=name,
+                                           error=repr(err))
+                        metrics.event("task_failed", task=name, error=repr(err))
+                        logger.warning("evicting failed task %s: %r", name, err)
+                        # permanently dropped: also free its compiled
+                        # programs (a retried task keeps them — recompiling
+                        # an identical program is the cost the cache avoids)
+                        release_c = getattr(t, "release_compiled", None)
+                        if release_c is not None:
+                            release_c()
+                keep = {t.name for t in retried}
+                remaining = [
+                    t for t in remaining
+                    if t.name not in errors or t.name in keep
+                ]
+                for t in retried:
+                    if t not in remaining:
+                        remaining.append(t)  # was forecast-completed
+                completed = [t for t in completed if t.name not in errors]
+
+            for t in completed:
+                all_completed.append(t.name)
                 if journal is not None:
-                    # Interval-end group commit: one fsync covers this
-                    # interval's progress, plan and completion records.
+                    journal.append("task_completed", task=t.name)
+                metrics.event("task_completed", task=t.name)
+                release = getattr(t, "release_live_state", None)
+                if release is not None:
+                    release()  # free HBM held by finished tasks
+                release_c = getattr(t, "release_compiled", None)
+                if release_c is not None:
+                    release_c()  # and their compiled programs
+            task_list = remaining
+            if journal is not None:
+                # Interval-end group commit: one fsync covers this
+                # interval's progress, plan and completion records.
+                with metrics.span("journal.commit", interval=interval_index):
                     journal.append("interval_commit",
                                    interval=interval_index)
                     journal.commit()
-                # Interval boundary for the buffered metrics writer too:
-                # telemetry rides the buffer during the hot loop and lands
-                # here, with the journal commit.
-                metrics.flush()
-                interval_index += 1
+            # Interval boundary for the buffered metrics writer too:
+            # telemetry rides the buffer during the hot loop and lands
+            # here, with the journal commit.
+            metrics.flush()
+            interval_index += 1
     logger.info("orchestration complete (%d completed, %d failed)",
                 len(all_completed), len(all_failed))
     return {"completed": all_completed, "failed": all_failed}

@@ -144,7 +144,7 @@ def _fold_fn(spike_factor: float, alpha: float, warmup: int) -> Callable:
     import jax
     import jax.numpy as jnp
 
-    def fold(carry, losses):
+    def saturn_sentinel_fold(carry, losses):  # the name a profile shows
         losses = losses.astype(jnp.float32)
 
         def step(c, x):
@@ -191,7 +191,7 @@ def _fold_fn(spike_factor: float, alpha: float, warmup: int) -> Callable:
             [ewma, steps, bad, first_off, first_kind, losses[-1]]
         )
 
-    return jax.jit(fold)
+    return jax.jit(saturn_sentinel_fold)
 
 
 def fold(carry: Any, losses: Any, cfg: SentinelConfig):

@@ -34,6 +34,7 @@ from __future__ import annotations
 import hashlib
 import json
 import logging
+import time as _time
 import timeit as _timeit
 from dataclasses import dataclass, field
 from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
@@ -49,6 +50,7 @@ from saturn_tpu.core.mesh import make_submesh
 from saturn_tpu.ops import stacking
 from saturn_tpu.parallel.spmd_base import choose_window
 from saturn_tpu.utils import checkpoint as ckpt
+from saturn_tpu.utils import metrics as _metrics
 
 log = logging.getLogger("saturn_tpu")
 
@@ -566,7 +568,22 @@ def run_fused_interval(
     )
     if n <= 0:
         return report
+    # The ``fused_interval`` event is this span (same fields, plus its start):
+    # from before the stacked program is built to after the members'
+    # snapshots, so the group's ``ckpt.*`` spans are its children.
+    with _metrics.span("fused_interval") as sp:
+        return _run_fused(
+            sp, cur, devices, tid, n, report, inner, config, window_size,
+            detach_requested,
+        )
 
+
+def _run_fused(
+    sp, cur, devices, tid, n, report, inner, config, window_size,
+    detach_requested,
+) -> FusedIntervalReport:
+    """The body of :func:`run_fused_interval`'s ``fused_interval`` span."""
+    ts_launch = _time.time()  # before any compile this interval needs
     fp = fusion_fingerprint(cur[0])
     prog = build_fused_program(cur, devices, inner=inner, config=config)
     live_key = _fused_live_key(fp, config, prog._devices())
@@ -627,8 +644,6 @@ def run_fused_interval(
                     "fused.unfuse", task=m.name, step=steps_done, tid=tid
                 )
                 ckpt.save(m.ckpt_path, member_host)
-                from saturn_tpu.utils import metrics as _metrics
-
                 _metrics.event(
                     "fused_unfuse", task=m.name, group=names0,
                     step=steps_done, n_remaining=len(cur) - 1,
@@ -731,7 +746,6 @@ def run_fused_interval(
     t_end = _timeit.default_timer()
     elapsed_all = t_end - t_all0
     from saturn_tpu.health import sentinel as _sentinel
-    from saturn_tpu.utils import metrics as _metrics
 
     scfg = _sentinel.get_config()
     # Per-member loss columns across segments (a detached member's column
@@ -784,11 +798,6 @@ def run_fused_interval(
                     m.name, first_off // max(k, 1), cause, step=first_off,
                     loss=loss_val, batch_indices=bad, bad_count=bad_count,
                 )
-                _metrics.event(
-                    "task_numeric_fault", task=m.name, cause=cause,
-                    window=first_off // max(k, 1), step=int(first_off),
-                    bad_count=int(bad_count), batches=list(bad), fused=True,
-                )
                 log.warning(
                     "fused member %s: sentinel tripped (%s) at interval "
                     "step %d — discarding the member's interval",
@@ -825,14 +834,17 @@ def run_fused_interval(
     report.samples_per_sec = (
         n * n_members0 * batch_size / max(elapsed_all, 1e-9)
     )
-    _metrics.event(
-        "fused_interval", members=names0, n_members=n_members0,
+    sp.set(
+        members=names0, n_members=n_members0,
         batches=n, window=k,
         per_step_s=per_step,
         samples_per_sec=round(report.samples_per_sec, 2),
         losses={nm: round(v, 6) for nm, v in final_losses.items()},
         detached=[m.name for m, _ in report.detached],
         faulted=sorted(faulted),
+        # ``task_interval``'s meaning: when the group took its block, and
+        # the seconds from its first dispatch to its last loss read back
+        ts_launch=ts_launch, elapsed_s=elapsed_all,
     )
     log.info(
         "fused group %s: ran %d lockstep batches (K=%d, %d members, "

@@ -32,6 +32,7 @@ from saturn_tpu.core.mesh import make_submesh
 from saturn_tpu.core.technique import BaseTechnique, InfeasibleConfig
 from saturn_tpu.parallel import sharding as shr
 from saturn_tpu.utils import checkpoint as ckpt
+from saturn_tpu.utils import metrics as _metrics
 from saturn_tpu.utils.timing import (
     device_hbm_bytes,
     hbm_bytes_required,
@@ -198,11 +199,11 @@ class _Bundle:
         if train is None or k < 1:
             raise ValueError(f"bundle cannot build a fused window (k={k})")
 
-        def multi_step(state, window):
+        def saturn_window(state, window):
             return jax.lax.scan(train, state, window)
 
         fused = jax.jit(
-            multi_step,
+            saturn_window,
             in_shardings=(self.state_shardings, self.stacked_sharding()),
             out_shardings=(self.state_shardings, NamedSharding(self.mesh, P())),
             donate_argnums=(0, 1),
@@ -745,13 +746,22 @@ class SPMDTechnique(BaseTechnique):
         state_shardings = jax.tree_util.tree_map_with_path(shard_of, state_shapes)
         batch_sharding = NamedSharding(mesh, bspec)
 
+        # Stable names for the two programs (the profile's module line, the
+        # ``compile`` event's span and a refactor then agree): the window
+        # program is ``saturn_window`` (``_Bundle.fused_compiled``).
+        def saturn_step(state, batch):
+            return train_step(state, batch)
+
+        def saturn_init():
+            return init_state()
+
         step = jax.jit(
-            train_step,
+            saturn_step,
             in_shardings=(state_shardings, batch_sharding),
             out_shardings=(state_shardings, NamedSharding(mesh, P())),
             donate_argnums=(0,),
         )
-        init = jax.jit(init_state, out_shardings=state_shardings)
+        init = jax.jit(saturn_init, out_shardings=state_shardings)
 
         batch_sds = jax.ShapeDtypeStruct(
             ds.example_batch().shape, ds.example_batch().dtype
@@ -843,23 +853,26 @@ class SPMDTechnique(BaseTechnique):
         static predicted bytes next to the compiled figure — so the
         SAT-M005 drift audit accrues for free on every sweep.
         """
-        limit = device_hbm_bytes(devices[0])
-        if limit <= 0:
-            # platform doesn't report limits (CPU tests); honor the same
-            # env capacity memlens reads, so CPU sweeps can model a chip
-            limit = _env_hbm_bytes()
-        need = hbm_bytes_required(compiled)
-        if task is not None and config is not None:
-            self._memlens_calibration(task, devices, config, need, k)
-        if limit <= 0:
-            return True
-        ok = need == 0 or need <= 0.92 * limit
-        if not ok:
-            log.info(
-                "%s: config needs %.2f GiB > %.2f GiB HBM — infeasible",
-                self.name, need / 2**30, limit / 2**30,
-            )
-        return ok
+        with _metrics.span("trial.memory_check", k=int(k)) as sp:
+            limit = device_hbm_bytes(devices[0])
+            if limit <= 0:
+                # platform doesn't report limits (CPU tests); honor the same
+                # env capacity memlens reads, so CPU sweeps can model a chip
+                limit = _env_hbm_bytes()
+            need = hbm_bytes_required(compiled)
+            sp.set(need_bytes=int(need), limit_bytes=int(limit))
+            if task is not None and config is not None:
+                with _metrics.span("trial.memlens", k=int(k)):
+                    self._memlens_calibration(task, devices, config, need, k)
+            if limit <= 0:
+                return True
+            ok = need == 0 or need <= 0.92 * limit
+            if not ok:
+                log.info(
+                    "%s: config needs %.2f GiB > %.2f GiB HBM — infeasible",
+                    self.name, need / 2**30, limit / 2**30,
+                )
+            return ok
 
     def _memlens_calibration(
         self, task: Any, devices: Sequence[Any], config: Dict[str, Any],
@@ -870,7 +883,6 @@ class SPMDTechnique(BaseTechnique):
         try:
             from saturn_tpu.analysis.memlens import liveness as _ml_liveness
             from saturn_tpu.analysis.memlens import passes as _ml_passes
-            from saturn_tpu.utils import metrics as _metrics
 
             traced = self.trace_step(task, list(devices), dict(config))
             profile = _ml_liveness.analyze(traced, window=k)
@@ -910,7 +922,6 @@ class SPMDTechnique(BaseTechnique):
         best_hf = 0.0
         n_configs = n_memory = n_error = 0
         first_error: Optional[str] = None
-        from saturn_tpu.utils import metrics as _metrics
 
         def note(config, **fields):
             # one event per grid point: which variant measured what, which
@@ -920,30 +931,42 @@ class SPMDTechnique(BaseTechnique):
 
         for config in self.candidate_configs(task, len(devices)):
             n_configs += 1
-            try:
-                timed = self._try_config(task, devices, config)
-            except InfeasibleConfig as e:
-                log.info("%s trial %s infeasible: %s", self.name, config, e)
-                note(config, infeasible=str(e))
-                continue
-            except Exception as e:  # a broken config must not kill the sweep
-                # ...but a config that RAISED is not a config that lost: on
-                # the chip a kernel variant that fails to lower would
-                # otherwise lose to its dense twin in silence. Warn, and
-                # count it into the report ``search()`` returns.
-                log.warning("%s trial %s for task %s failed: %r",
-                            self.name, config, task.name, e)
-                n_error += 1
-                if first_error is None:
-                    first_error = f"{self.name} {config}: {e!r}"
-                note(config, error=repr(e))
-                continue
-            if timed is None:  # _try_config returns None only on the memory check
-                n_memory += 1
-                note(config, memory_rejected=True)
-                continue
-            t, hf = timed
-            note(config, per_batch_s=t)
+            # one ``trial.config`` span per ``trial_config`` event: where the
+            # grid point's seconds went, by how it ended
+            with _metrics.span("trial.config", task=task.name,
+                               size=len(devices), technique=self.name,
+                               config=dict(config)) as sp:
+                try:
+                    timed = self._try_config(task, devices, config)
+                except InfeasibleConfig as e:
+                    log.info("%s trial %s infeasible: %s", self.name, config, e)
+                    note(config, infeasible=str(e))
+                    sp.set(outcome="infeasible")
+                    continue
+                except Exception as e:  # a broken config must not kill the sweep
+                    # ...but a config that RAISED is not a config that lost:
+                    # on the chip a kernel variant that fails to lower would
+                    # otherwise lose to its dense twin in silence. Warn, and
+                    # count it into the report ``search()`` returns.
+                    log.warning("%s trial %s for task %s failed: %r",
+                                self.name, config, task.name, e)
+                    n_error += 1
+                    if first_error is None:
+                        first_error = f"{self.name} {config}: {e!r}"
+                    note(config, error=repr(e))
+                    # the chip's compiler refusing the program for memory is
+                    # the memory check's verdict arriving as an exception
+                    sp.set(outcome="refused" if "RESOURCE_EXHAUSTED" in repr(e)
+                           else "error")
+                    continue
+                if timed is None:  # _try_config returns None only on the memory check
+                    n_memory += 1
+                    note(config, memory_rejected=True)
+                    sp.set(outcome="memory_rejected")
+                    continue
+                t, hf = timed
+                note(config, per_batch_s=t)
+                sp.set(outcome="timed")
             if best[1] is None or t < best[1]:
                 best = (dict(config), t)
                 best_hf = hf
@@ -985,7 +1008,7 @@ class SPMDTechnique(BaseTechnique):
         execute() time); staging is measured separately, outside the timed
         region.
         """
-        bundle = self.build(task, devices, config)
+        bundle = self._spanned_build("trial.build", task, devices, config)
         k = self._profile_window(config)
         if k > 1:
             # Profile the fused window program execute() dispatches at
@@ -994,7 +1017,7 @@ class SPMDTechnique(BaseTechnique):
             # keep donation honest and transfer out of the timed region —
             # at execute() time the prefetcher overlaps staging with
             # compute, so a trial that timed staging would overestimate.
-            fused = bundle.fused_compiled(k)
+            fused = self._spanned_compile("trial.compile", bundle, k)
             if not self._fits_compiled(fused, devices,
                                        task=task, config=config, k=k):
                 return None
@@ -1007,27 +1030,75 @@ class SPMDTechnique(BaseTechnique):
                 )
                 return jax.device_put(host, sharding)
 
-            state = bundle.init()
-            t = time_fused_window(
-                fused, state, stage, k, n_timed=2, n_warmup=1
-            )
-            t0 = _timeit.default_timer()
-            probe = stage(0)
-            jax.block_until_ready(probe)
-            t_host = (_timeit.default_timer() - t0) / k
-            del probe
+            with _metrics.span("trial.init"):
+                state = bundle.init()
+            # The stacks are staged here, not inside time_fused_window, so
+            # that ``trial.timing`` is the device program alone; the second
+            # ``trial.stage`` is the probe that prices staging.
+            with _metrics.span("trial.stage", k=k, n_stacks=3):
+                windows = [stage(j) for j in range(3)]
+                jax.block_until_ready(windows)
+            with _metrics.span("trial.timing", k=k, n_timed=2):
+                t = time_fused_window(
+                    fused, state, windows.__getitem__, k, n_timed=2, n_warmup=1
+                )
+            with _metrics.span("trial.stage", k=k, n_stacks=1):
+                t0 = _timeit.default_timer()
+                probe = stage(0)
+                jax.block_until_ready(probe)
+                t_host = (_timeit.default_timer() - t0) / k
+                del probe
             return t, _host_fraction(t_host, t)
-        if not self._fits_memory(bundle, devices, task=task, config=config):
+        compiled = self._spanned_compile("trial.compile", bundle, 1)
+        if not self._fits_compiled(compiled, devices,
+                                   task=task, config=config, k=1):
             return None
-        state = bundle.init()
-        t0 = _timeit.default_timer()
-        batch = jax.device_put(
-            task.get_dataset().batch(0), bundle.batch_sharding
-        )
-        jax.block_until_ready(batch)
-        t_host = _timeit.default_timer() - t0
-        t = time_train_step(bundle.compiled, state, batch, n_timed=3, n_warmup=2)
+        with _metrics.span("trial.init"):
+            state = bundle.init()
+        with _metrics.span("trial.stage", k=1):
+            t0 = _timeit.default_timer()
+            batch = jax.device_put(
+                task.get_dataset().batch(0), bundle.batch_sharding
+            )
+            jax.block_until_ready(batch)
+            t_host = _timeit.default_timer() - t0
+        with _metrics.span("trial.timing", k=1, n_timed=3):
+            t = time_train_step(compiled, state, batch, n_timed=3, n_warmup=2)
         return t, _host_fraction(t_host, t)
+
+    def _spanned_build(self, name: str, task, devices, config,
+                       parent=None) -> _Bundle:
+        """``self.build`` under a span that says whether the bundle cache
+        had the program (``trial.build`` in a trial, ``launch.build`` at an
+        interval's launch)."""
+        with _metrics.span(name, parent=parent, task=task.name) as sp:
+            if _metrics.enabled():
+                with self._bundles_lock:
+                    hit = self._bundle_key(task, devices, config) in self._bundles
+                sp.set(cache="hit" if hit else "miss")
+            return self.build(task, devices, config)
+
+    @staticmethod
+    def _spanned_compile(name: str, bundle: _Bundle, k: int, parent=None):
+        """The bundle's K-step window program (``k > 1``) or its 1-step
+        program, compiled at most once per bundle, under a span that says
+        whether this call was the one that compiled it (``was_warm``) and
+        whether the AOT cache gave the executable (``aot``)."""
+        from saturn_tpu.utils import aot_cache
+
+        def aot_hits() -> int:
+            stats = aot_cache.stats()
+            return stats["hits"] + stats["warm_hits"]
+
+        with _metrics.span(name, parent=parent, k=int(k)) as sp:
+            if _metrics.enabled():
+                sp.set(was_warm=bool(bundle.has_fused(k) if k > 1
+                                     else bundle._compiled is not None))
+                before = aot_hits()
+            out = bundle.fused_compiled(k) if k > 1 else bundle.compiled
+            if _metrics.enabled():
+                sp.set(aot="hit" if aot_hits() > before else "miss")
+            return out
 
     # --------------------------------------------------------------- execute
     def execute(
@@ -1099,7 +1170,13 @@ class SPMDTechnique(BaseTechnique):
         """
         config = dict(task.selected_strategy.params or {})
         ts_launch = _time.time()  # before any compile this interval needs
-        bundle = self.build(task, devices, config)
+        # The ``task_interval`` event's own id: it is emitted by hand below
+        # (this generator yields while the stretch is open), and the phases
+        # before the first step and after the last are its children. No span
+        # is open across a ``yield``, and none sits in the dispatch loop.
+        ti = _metrics.span("task_interval").open()
+        bundle = self._spanned_build("launch.build", task, devices, config,
+                                     parent=ti)
         key = self._bundle_key(task, devices, config)
 
         live = getattr(task, "_live_state", None)
@@ -1118,9 +1195,15 @@ class SPMDTechnique(BaseTechnique):
             # tree (and legacy single-file checkpoints take its compat path).
             from saturn_tpu.core import distributed as _dist
 
-            state = ckpt.restore_sharded(
-                task.ckpt_path, bundle.state_shapes, bundle.state_shardings
-            )
+            with _metrics.span("launch.restore", parent=ti,
+                               task=task.name) as sp:
+                state = ckpt.restore_sharded(
+                    task.ckpt_path, bundle.state_shapes, bundle.state_shardings
+                )
+                if _metrics.enabled():
+                    sp.set(bytes=int(sum(
+                        x.nbytes for x in jax.tree_util.tree_leaves(state)
+                    )))
             # Data cursor is derived from the trained-step count, so resume
             # is restart-safe (the reference replayed the iterator from the
             # in-memory cursor only, ``Task.py:130-140``).
@@ -1132,7 +1215,8 @@ class SPMDTechnique(BaseTechnique):
                 int(np.asarray(_dist.host_array(step_leaf)))
             )
         else:
-            state = bundle.init()
+            with _metrics.span("launch.init", parent=ti, task=task.name):
+                state = bundle.init()
 
         # The cached buffers get donated into the first step below, so they
         # must not be offered again if this interval crashes mid-run: drop
@@ -1171,9 +1255,13 @@ class SPMDTechnique(BaseTechnique):
         # AOT-compile every program this interval needs BEFORE the clock
         # starts — compile cost belongs to neither samples/sec nor the
         # realized-feedback window (docs/parity.md, round 10).
-        fused_fn = bundle.fused_compiled(k) if n_windows else None
+        fused_fn = (
+            self._spanned_compile("launch.compile", bundle, k, parent=ti)
+            if n_windows else None
+        )
         single_fn = (
-            bundle.compiled if any(not f for f, _ in units) else None
+            self._spanned_compile("launch.compile", bundle, 1, parent=ti)
+            if any(not f for f, _ in units) else None
         )
         stacked_sharding = bundle.stacked_sharding() if n_windows else None
 
@@ -1243,45 +1331,47 @@ class SPMDTechnique(BaseTechnique):
             prefetch.close()
         if loss is not None:
             from saturn_tpu.health import sentinel as _sentinel
-            from saturn_tpu.utils import metrics as _metrics
 
             scfg = _sentinel.get_config()
             poison = task.__dict__.pop("_health_poison", None)
             rep = None
-            if scfg.enabled:
-                import jax.numpy as jnp
+            # drain to the loss value: the sentinel's fold and the ONE host
+            # read-back of the interval
+            with _metrics.span("readback", parent=ti, task=task.name):
+                if scfg.enabled:
+                    import jax.numpy as jnp
 
-                # Sentinel path: fold the interval's full per-step loss
-                # vector through one jitted on-device scan and read back the
-                # fixed-shape report instead of the bare scalar — STILL one
-                # host readback per interval (the reliable queue drain, see
-                # utils/timing.py note), and the report's last slot is the
-                # same final loss the bare readback returned.
-                losses_vec = jnp.concatenate(
-                    [jnp.reshape(x, (-1,)) for x in unit_losses]
-                )
-                if poison is not None:
-                    ov = _sentinel.poison_overrides(
-                        poison, n, lambda j: task.dataset_index(start + j)
+                    # Sentinel path: fold the interval's full per-step loss
+                    # vector through one jitted on-device scan and read back the
+                    # fixed-shape report instead of the bare scalar — STILL one
+                    # host readback per interval (the reliable queue drain, see
+                    # utils/timing.py note), and the report's last slot is the
+                    # same final loss the bare readback returned.
+                    losses_vec = jnp.concatenate(
+                        [jnp.reshape(x, (-1,)) for x in unit_losses]
                     )
-                    if ov is not None:
-                        # Chaos injection corrupts the OBSERVED losses only
-                        # (a device-side scatter); train state is untouched,
-                        # so post-rollback trajectories stay fault-free.
-                        losses_vec = losses_vec.at[ov[0]].set(ov[1])
-                carry = getattr(task, "_sentinel_carry", None)
-                if carry is None:
-                    carry = _sentinel.carry_init()
-                rep = np.asarray(
-                    _dist.host_array(_sentinel.fold(carry, losses_vec, scfg))
-                )
-                loss_val = float(rep[_sentinel.REP_LAST_LOSS])
-            else:
-                # ONE host readback per interval — the reliable queue drain
-                # (see utils/timing.py note). A fused window's loss is the
-                # (K,) per-step trajectory; its last entry is the interval's
-                # final loss, identical to what the 1-step path would report.
-                loss_val = float(_dist.host_array(loss).reshape(-1)[-1])
+                    if poison is not None:
+                        ov = _sentinel.poison_overrides(
+                            poison, n, lambda j: task.dataset_index(start + j)
+                        )
+                        if ov is not None:
+                            # Chaos injection corrupts the OBSERVED losses only
+                            # (a device-side scatter); train state is untouched,
+                            # so post-rollback trajectories stay fault-free.
+                            losses_vec = losses_vec.at[ov[0]].set(ov[1])
+                    carry = getattr(task, "_sentinel_carry", None)
+                    if carry is None:
+                        carry = _sentinel.carry_init()
+                    rep = np.asarray(
+                        _dist.host_array(_sentinel.fold(carry, losses_vec, scfg))
+                    )
+                    loss_val = float(rep[_sentinel.REP_LAST_LOSS])
+                else:
+                    # ONE host readback per interval — the reliable queue drain
+                    # (see utils/timing.py note). A fused window's loss is the
+                    # (K,) per-step trajectory; its last entry is the interval's
+                    # final loss, identical to what the 1-step path would report.
+                    loss_val = float(_dist.host_array(loss).reshape(-1)[-1])
             fault = _sentinel.inspect(rep) if rep is not None else None
             if fault is not None:
                 cause, first_off, bad_count = fault
@@ -1307,11 +1397,6 @@ class SPMDTechnique(BaseTechnique):
                 bad_batches = tuple(sorted(
                     {task.dataset_index(start + j) for j in bad_offsets}
                 ))
-                _metrics.event(
-                    "task_numeric_fault", task=task.name, cause=cause,
-                    window=window, step=first_off, bad_count=bad_count,
-                    batches=list(bad_batches),
-                )
                 log.warning(
                     "task %s: sentinel tripped (%s) at interval step %d "
                     "(window %d, %d bad step(s)) — discarding interval",
@@ -1379,7 +1464,13 @@ class SPMDTechnique(BaseTechnique):
                     float(x) for u in unit_losses
                     for x in np.asarray(_dist.host_array(u)).reshape(-1)
                 ]
-                step_flops = self._step_flops(task, devices, config)
+                with self._flops_lock:
+                    cached = key in self._flops_cache
+                # what the package's own tflops / mfu costs the interval: the
+                # first one of a program re-traces the step (shardflow)
+                with _metrics.span("step_flops", parent=ti, task=task.name,
+                                   cached=cached):
+                    step_flops = self._step_flops(task, devices, config)
                 if step_flops:
                     achieved = step_flops * n / max(elapsed_all, 1e-9)
                     perf["tflops"] = round(achieved / 1e12, 4)
@@ -1407,7 +1498,7 @@ class SPMDTechnique(BaseTechnique):
                 per_batch_s=per_batch, window=k, fused_windows=n_windows,
                 coscheduled=bool(shared), devices=on_devices,
                 ts_launch=ts_launch, ts_start=ts_start, elapsed_s=elapsed_all,
-                **perf,
+                **ti.ids(), **perf,
             )
             log.info("task %s [%s]: ran %d batches (K=%d, %d fused windows), "
                      "loss %.4f, %.1f samples/s",
@@ -1418,5 +1509,6 @@ class SPMDTechnique(BaseTechnique):
         # overlaps the next interval (device->host copy happens here; see
         # utils/checkpoint.save_async) — interval boundaries don't stall the
         # gang on GB-scale npz writes.
-        ckpt.save_async(task.ckpt_path, state)
+        with _metrics.under(ti):  # ckpt.wait_pending / .snapshot / .write
+            ckpt.save_async(task.ckpt_path, state)
         task._live_state = (key, state)

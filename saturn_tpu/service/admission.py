@@ -398,9 +398,11 @@ class AdmissionController:
         try:
             from saturn_tpu.analysis.shardflow import prior as sf_prior
 
-            added = sf_prior.synthesize_strategies(
-                task, topology, technique_names=self.technique_names,
-            )
+            with metrics.span("prior.shardflow", task=rec.name) as sp:
+                added = sf_prior.synthesize_strategies(
+                    task, topology, technique_names=self.technique_names,
+                )
+                sp.set(n_points=len(added or ()))
         except Exception as e:
             logger.warning(
                 "admission: shardflow prior failed for %s (%r); falling "

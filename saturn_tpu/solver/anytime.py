@@ -799,8 +799,29 @@ def anytime_resolve(task_list: Sequence, topology: SliceTopology,
     incumbent); pass ``warm`` alone (with ``previous=None``) to seed the
     ladder without the compare-and-swap — the replanner's shape, where the
     old plan may reference dead devices and must never be kept.
+
+    The whole call is one ``solver.resolve`` span (``source``, ``n_tasks``,
+    ``deadline_s``, then the adopted plan's ``tier``, ``outcome`` and
+    ``makespan_s``), whoever the caller and whichever thread.
     """
     dl = resolve_deadline(deadline, interval)
+    with metrics.span("solver.resolve", source=source,
+                      n_tasks=len(task_list), deadline_s=round(dl, 6)) as sp:
+        plan = _resolve(
+            task_list, topology, previous, interval, threshold, dl, weights,
+            coschedule_exclude, warm, ordering_slack, source, seed, fusion,
+            fusion_exclude, fusion_fits,
+        )
+        report = getattr(plan, "anytime", None)
+        sp.set(makespan_s=round(plan.makespan, 6),
+               tier=getattr(report, "tier", None),
+               outcome=getattr(report, "outcome", None))
+        return plan
+
+
+def _resolve(task_list, topology, previous, interval, threshold, dl, weights,
+             coschedule_exclude, warm, ordering_slack, source, seed, fusion,
+             fusion_exclude, fusion_fits) -> Plan:
     warm_seed = warm if warm is not None else previous
     fresh, report = anytime_solve(
         task_list, topology, dl, previous=warm_seed,

@@ -88,8 +88,13 @@ def search(
     ``technique_names=None`` uses the whole library (registering the built-in
     default library if the user registered nothing — the reference required
     explicit registration, ``WikiText103.py:53-54``). ``metrics_path``
-    appends per-trial JSONL events; ``trace_dir`` wraps the sweep in a
-    jax.profiler trace. ``parallel_trials`` caps how many same-size trials
+    appends per-trial JSONL events, among them one ``metrics.span`` event per
+    phase of every trial (``search`` > ``trial`` > ``trial.config`` >
+    ``trial.build`` / ``.compile`` / ``.memory_check`` / ``.init`` /
+    ``.stage`` / ``.timing``, and one ``compile`` event per XLA compile);
+    ``trace_dir`` wraps the sweep in a jax.profiler trace that holds the
+    device's ops under the same spans (``saturn.<name>``), with the Python
+    tracer off. ``parallel_trials`` caps how many same-size trials
     run concurrently on disjoint blocks (default: 4 on accelerators, 1 on
     the CPU test platform where concurrency would skew timings).
 
@@ -120,7 +125,8 @@ def search(
     if log:
         logging.basicConfig(level=logging.INFO)
     cache = pcache.resolve(profile_cache)
-    with metrics.scoped(metrics_path), trace.profile_trace(trace_dir):
+    with metrics.scoped(metrics_path), trace.profile_trace(trace_dir), \
+            metrics.span("search", n_tasks=len(tasks)):
         return _search_inner(
             tasks, technique_names, topology, parallel_trials, cache, prune,
             trial_retries=trial_retries, retry_backoff_s=retry_backoff_s,
@@ -381,6 +387,12 @@ def _search_inner(
     workers = parallel_trials if parallel_trials is not None else _default_parallelism(topo)
 
     def run_trial(tid, lane: _Lane, g: int, block):
+        # the ``trial`` event is this span: same fields, plus its start
+        with metrics.span("trial", task=lane.task.name, size=g,
+                          technique=lane.name) as sp:
+            _run_trial(sp, tid, lane, g, block)
+
+    def _run_trial(sp, tid, lane: _Lane, g: int, block):
         devices = block.devices_of(topo.devices)
         task, name, tech = lane.task, lane.name, lane.tech
         if cache is not None and lane.keys.get(g):
@@ -404,11 +416,6 @@ def _search_inner(
                     logger.info(
                         "trial (%s, g=%d, %s): sharding lint refused: %s",
                         task.name, g, name, e,
-                    )
-                    metrics.event(
-                        "sharding_lint", task=task.name, size=g,
-                        technique=name,
-                        codes=[d.code for d in e.diagnostics],
                     )
                     params, per_batch_time = None, None
                     break
@@ -453,8 +460,7 @@ def _search_inner(
             memory_bound = bool(report and report.get("memory_infeasible"))
             logger.info("trial (%s, g=%d, %s): infeasible%s", task.name, g, name,
                         " (memory)" if memory_bound else "")
-            metrics.event("trial", task=task.name, size=g, technique=name,
-                          feasible=False, memory_infeasible=memory_bound)
+            sp.set(feasible=False, memory_infeasible=memory_bound)
             with update_lock:
                 lane.done[g] = (False, None, None, "trial")
             if memory_bound:
@@ -473,10 +479,9 @@ def _search_inner(
         hf_reporter = getattr(tech, "host_fraction_report", None)
         if callable(hf_reporter):
             hf = hf_reporter(task.name, g) or 0.0
-        metrics.event("trial", task=task.name, size=g, technique=name,
-                      feasible=True, per_batch_s=per_batch_time,
-                      est_total_s=total, params=params,
-                      host_fraction=round(float(hf), 4))
+        sp.set(feasible=True, per_batch_s=per_batch_time,
+               est_total_s=total, params=params,
+               host_fraction=round(float(hf), 4))
         logger.info(
             "trial (%s, g=%d, %s): %.4fs/batch, est total %.1fs (trial took %.1fs)",
             task.name, g, name, per_batch_time, total, dt,
@@ -531,8 +536,12 @@ def _search_inner(
             return False
         try:
             devices = topo.blocks(g)[0].devices_of(topo.devices)
-            return ml_passes.grid_point_infeasible(
-                lane.tech, lane.task, devices, memlens_cap)
+            with metrics.span("prior.memlens", task=lane.task.name, size=g,
+                              technique=lane.name, n_points=1) as sp:
+                verdict = ml_passes.grid_point_infeasible(
+                    lane.tech, lane.task, devices, memlens_cap)
+                sp.set(infeasible=bool(verdict))
+            return verdict
         except Exception:
             return False
 
@@ -564,10 +573,13 @@ def _search_inner(
         for b in blocks[:n_workers]:
             free.put(b)
 
+        above = metrics.current_span()  # ``search``: the trial threads' parent
+
         def with_block(lane):
             block = free.get()
             try:
-                run_trial(next_tid(), lane, g, block)
+                with metrics.under(above):
+                    run_trial(next_tid(), lane, g, block)
             finally:
                 free.put(block)
 

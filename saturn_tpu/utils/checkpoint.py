@@ -50,6 +50,7 @@ from typing import Any, Callable, Dict, List, Optional, Tuple
 import jax
 import numpy as np
 
+from saturn_tpu.utils import metrics
 from saturn_tpu.utils.treepath import path_str as _path_str
 
 log = logging.getLogger("saturn_tpu")
@@ -294,7 +295,8 @@ class _Snapshot:
     only part that touches devices — and only via local per-shard
     device→host copies (``shard.data``), never a gather."""
 
-    __slots__ = ("manifest", "local", "rank", "gen", "writes_manifest")
+    __slots__ = ("manifest", "local", "rank", "gen", "writes_manifest",
+                 "nbytes")
 
     def __init__(self, manifest: Dict[str, Any], local: Dict[str, np.ndarray],
                  rank: int, gen: str, writes_manifest: bool):
@@ -303,6 +305,8 @@ class _Snapshot:
         self.rank = rank
         self.gen = gen
         self.writes_manifest = writes_manifest
+        #: bytes this process copied to the host and will write
+        self.nbytes = int(sum(a.nbytes for a in local.values()))
 
 
 def _snapshot(path: str, tree: Any) -> _Snapshot:
@@ -440,8 +444,13 @@ def save(path: str, tree: Any) -> None:
     shard file; the tree's writer rank additionally commits the manifest.
     The manifest rename is the commit point — a crash at any earlier moment
     leaves the previously published checkpoint fully readable."""
-    snap = _snapshot(path, tree)
-    _commit_snapshot(path, snap)
+    base = os.path.basename(path)
+    with metrics.span("ckpt.snapshot", path=base) as sp:
+        snap = _snapshot(path, tree)
+        sp.set(bytes=snap.nbytes)
+    with metrics.span("ckpt.write", path=base, bytes=snap.nbytes,
+                      n_shards=len(snap.local)):
+        _commit_snapshot(path, snap)
 
 
 # --------------------------------------------------------------- async writes
@@ -502,13 +511,22 @@ def save_async(path: str, tree: Any) -> None:
     (``_writer_rank`` — lowest process addressing it) commits the manifest.
     The multi-host engine flushes + barriers at interval end so readers
     never race the write (``engine.py``)."""
-    _wait_pending(path)  # at most one in-flight write per path
-    snap = _snapshot(path, tree)
+    base = os.path.basename(path)
+    # Both stretches are on the caller's thread — the gang's: training is
+    # stalled for as long as they last (``ckpt_stall`` reads them).
+    with metrics.span("ckpt.wait_pending", path=base):
+        _wait_pending(path)  # at most one in-flight write per path
+    with metrics.span("ckpt.snapshot", path=base) as snapped:
+        snap = _snapshot(path, tree)
+        snapped.set(bytes=snap.nbytes)
     key = os.path.abspath(path)
 
     def write():
         try:
-            _commit_snapshot(path, snap)
+            # the writer thread's span is its snapshot's child
+            with metrics.span("ckpt.write", parent=snapped, path=base,
+                              bytes=snap.nbytes, n_shards=len(snap.local)):
+                _commit_snapshot(path, snap)
         except BaseException as e:  # re-raised at the next join point
             log.exception("async checkpoint write to %s failed", path)
             _record_async_failure(key, path, e)
@@ -527,8 +545,9 @@ def flush() -> None:
     """Join every outstanding async write; re-raise the first failure."""
     with _PENDING_LOCK:
         threads = list(_PENDING.values())
-    for t in threads:
-        t.join()
+    with metrics.span("ckpt.flush", n_pending=len(threads)):
+        for t in threads:
+            t.join()
     with _PENDING_LOCK:
         errs = dict(_FAILED)
         _FAILED.clear()

@@ -8,11 +8,16 @@ makespans, task failures) to one JSONL file a notebook or `jq` can consume.
 
 Disabled unless configured — ``search(metrics_path=...)`` /
 ``orchestrate(metrics_path=...)`` or :func:`configure` directly.
+
+Stretches of time are :func:`span` events on the same stream (one event per
+span, emitted at its end, carrying its start); ``docs/architecture.md``
+("Metrics stream & spans") has the record and the table of names.
 """
 
 from __future__ import annotations
 
 import contextlib
+import itertools
 import json
 import logging
 import os
@@ -120,6 +125,8 @@ _CONF_LOCK = tsan.lock("metrics.conf")
 def configure(path: Optional[str]) -> None:
     """Point the global metrics stream at ``path`` (None disables)."""
     global _WRITER
+    if path:
+        _listen_for_compiles()
     with _CONF_LOCK:
         if _WRITER is not None:
             _WRITER.close()
@@ -155,6 +162,183 @@ def flush() -> None:
     w = _WRITER
     if w is not None:
         w.flush()
+
+
+# ---------------------------------------------------------------------- spans
+_SPAN_IDS = itertools.count(1)  # next() on it is atomic: ids are process-unique
+_OPEN = threading.local()       # .stack: the spans open on this thread
+_ANNOTATION = None              # the profiler's annotation class, on first use
+_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+_CACHE_HIT_EVENT = "/jax/compilation_cache/cache_hits"
+_LISTENING = False
+
+
+def _stack() -> list:
+    try:
+        return _OPEN.stack
+    except AttributeError:
+        _OPEN.stack = []
+        return _OPEN.stack
+
+
+class span:
+    """``with metrics.span("launch.build", task=t.name) as sp:`` — one
+    stretch of the program's time, as ONE event at its end.
+
+    The event's ``kind`` is the span's name; besides the caller's fields
+    (``sp.set(outcome="refused")`` adds more inside the block) it carries
+    ``ts`` (end, stamped by the writer like any event), ``ts_start``
+    (``time.time()`` at entry: the events' clock), ``dur_s``
+    (``perf_counter``), ``id`` (unique in the process), ``parent`` (the id of
+    the ``parent=`` handed over, else of the span open on this thread at
+    entry, else None), ``root`` (the id of the outermost enclosing span —
+    ``search`` or ``orchestrate``: what every span of one call shares) and
+    ``thread``. An exception leaves the span emitted with
+    ``error=<type name>`` and propagates untouched, ``BaseException``s
+    (``SimulatedKill``) included.
+
+    The block is also annotated for the profiler (``saturn.<name>``): under
+    any profiler session the same stretch sits on the host plane of the
+    trace, on the trace's clock, beside the device's ops.
+
+    Threads: a new thread has no open span. Whoever starts one takes
+    :func:`current_span` first and the thread runs ``with
+    metrics.under(that):`` (or opens its first span with ``parent=that``).
+
+    Cost: with no sink configured nothing is stamped, allocated or emitted
+    (the :func:`enabled` contract); what is left is the annotation, a flag
+    check when no profiler runs. No span belongs inside a per-step loop: the
+    finest grain in the package is one per phase per task per interval.
+    """
+
+    __slots__ = ("name", "fields", "id", "parent", "root", "ts_start",
+                 "_handed", "_t0", "_ann")
+
+    def __init__(self, name: str, parent: Optional["span"] = None, **fields):
+        self.name = name
+        self.fields = fields
+        self.id: Optional[int] = None
+        self.parent: Optional[int] = None
+        self.root: Optional[int] = None
+        self._handed = parent
+
+    def open(self) -> "span":
+        """Stamp the start and take an id WITHOUT entering the block: for an
+        event that is emitted by hand because a generator yields while the
+        stretch is open (``task_interval``). ``ids()`` gives the event its
+        ``id`` / ``parent`` / ``root``; ``under(sp)`` makes the phases in
+        between its children. A no-op without a sink."""
+        if not enabled():
+            return self
+        above = self._handed
+        if above is None or above.id is None:
+            stack = _stack()
+            above = stack[-1] if stack else None
+        self.id = next(_SPAN_IDS)
+        if above is not None and above.id is not None:
+            self.parent, self.root = above.id, above.root
+        else:
+            self.root = self.id
+        self.ts_start = time.time()
+        self._t0 = time.perf_counter()
+        return self
+
+    def ids(self) -> dict:
+        if self.id is None:
+            return {}
+        return {"id": self.id, "parent": self.parent, "root": self.root}
+
+    def set(self, **fields) -> None:
+        self.fields.update(fields)
+
+    def __enter__(self) -> "span":
+        global _ANNOTATION
+        if _ANNOTATION is None:
+            import jax.profiler
+
+            _ANNOTATION = jax.profiler.TraceAnnotation
+        self._ann = _ANNOTATION("saturn." + self.name)
+        self._ann.__enter__()
+        if self.open().id is not None:
+            _stack().append(self)
+        return self
+
+    def __exit__(self, exc_type, exc, tb) -> bool:
+        try:
+            if self.id is not None:
+                dur = time.perf_counter() - self._t0
+                _stack().pop()  # blocks nest: the top of the stack is self
+                if exc_type is not None:
+                    self.fields["error"] = exc_type.__name__
+                event(self.name, ts_start=self.ts_start, dur_s=dur,
+                      thread=threading.current_thread().name,
+                      **self.ids(), **self.fields)
+        finally:
+            self._ann.__exit__(exc_type, exc, tb)
+        return False
+
+
+def current_span() -> Optional[span]:
+    """The innermost span open on this thread (its ``.id`` is what a child's
+    ``parent`` will read), or None — always None without a sink. Take it
+    before starting a thread and hand it to :func:`under` there."""
+    stack = getattr(_OPEN, "stack", None)
+    return stack[-1] if stack else None
+
+
+@contextlib.contextmanager
+def under(parent: Optional[span]):
+    """The receiving end of a thread hand-off: spans opened on THIS thread
+    inside the block, with no span of this thread's own above them, are
+    children of ``parent`` (a span taken with :func:`current_span` or
+    ``as sp`` on the thread that started this one). Emits nothing."""
+    if parent is None or parent.id is None:
+        yield
+        return
+    stack = _stack()
+    stack.append(parent)
+    try:
+        yield
+    finally:
+        stack.pop()
+
+
+def _on_cache_hit(name: str, **_) -> None:
+    if name == _CACHE_HIT_EVENT:
+        _OPEN.cache_hit = True  # read by the duration event that follows
+
+
+def _on_duration(name: str, secs: float, fun_name: Optional[str] = None,
+                 **_) -> None:
+    if name != _COMPILE_EVENT:
+        return
+    # JAX clocks the persistent compilation cache's retrievals under the
+    # same event; the hit it reported on this thread just before says so.
+    cached = bool(_OPEN.__dict__.pop("cache_hit", False))
+    if not enabled():
+        return
+    sp = current_span()  # the listener runs on the compiling thread
+    event("compile", seconds=secs, program=fun_name, cached=cached,
+          in_span=None if sp is None else {"name": sp.name, "id": sp.id},
+          thread=threading.current_thread().name)
+
+
+def _listen_for_compiles() -> None:
+    """One ``compile`` event per backend (XLA) compile while a sink is
+    configured: the jitted function's name (``program``), its ``seconds``,
+    whether the persistent compilation cache gave it (``cached``) and the
+    span open on the compiling thread (``in_span``: what places a compile
+    inside an interval). Registered once per process, at the first sink; a
+    no-op without one."""
+    global _LISTENING
+    with _CONF_LOCK:
+        if _LISTENING:
+            return
+        _LISTENING = True
+    import jax.monitoring
+
+    jax.monitoring.register_event_listener(_on_cache_hit)
+    jax.monitoring.register_event_duration_secs_listener(_on_duration)
 
 
 def read_events(path: str, kind: Optional[str] = None) -> list:
@@ -231,6 +415,7 @@ def scoped(path: Optional[str]):
     if not path:
         yield
         return
+    _listen_for_compiles()
     mine = MetricsWriter(path)
     with _CONF_LOCK:
         prev = _WRITER
