@@ -22,6 +22,13 @@ a crashed save can never tear the previously committed generation's files,
 and the manifest rename is the single atomic commit point (stale generations
 are garbage-collected only after it lands).
 
+A save is a **pipeline** (PR 27): the plan (manifest body and the ordered
+list of members this process fetches) comes from metadata alone; then the
+device→host copies run back to back while the writer thread is already
+putting the shards that have landed into the shard file. The caller is held
+until its last shard is on the host, the commit order is what it was, and
+``save`` is ``save_async`` joined.
+
 Saving by *path* rather than pickling tree structure is what makes
 interval-boundary **technique switching** work (the reference's central
 trick, ``executor.py:65`` kill-and-respawn + state-dict reload): any
@@ -39,13 +46,16 @@ import glob
 import hashlib
 import json
 import logging
+import math
 import os
+import queue
 import re
 import tempfile
 import threading
 import time
+import zipfile
 import zlib
-from typing import Any, Callable, Dict, List, Optional, Tuple
+from typing import Any, Callable, Dict, List, NamedTuple, Optional, Tuple
 
 import jax
 import numpy as np
@@ -163,8 +173,6 @@ def verify(path: str) -> bool:
     cover its full shape. Legacy ``.npz``: the zip central directory must
     parse and every member CRC must match. False for missing, truncated,
     partial or corrupt checkpoints — never raises."""
-    import zipfile
-
     try:
         if _is_manifest_file(path):
             m = _read_manifest(path)
@@ -256,13 +264,21 @@ def _my_rank() -> int:
     return distributed.process_index() if distributed.is_multihost() else 0
 
 
+def _widens(dtype: Any) -> bool:
+    # npz can't round-trip ml_dtypes (bfloat16/fp8); such a leaf is stored
+    # as float32 — restore() narrows back to the template's dtype.
+    return (np.dtype(dtype).kind == "V" or "bfloat16" in str(dtype)
+            or "float8" in str(dtype))
+
+
+def _stored_dtype(dtype: Any) -> np.dtype:
+    """The dtype a leaf of ``dtype`` has on disk — from metadata alone, so
+    the manifest is whole before a byte has left the device."""
+    return np.dtype(np.float32) if _widens(dtype) else np.dtype(dtype)
+
+
 def _stored(arr: np.ndarray) -> np.ndarray:
-    # npz can't round-trip ml_dtypes (bfloat16/fp8); widen to float32 —
-    # restore() narrows back to the template's dtype.
-    if (arr.dtype.kind == "V" or "bfloat16" in str(arr.dtype)
-            or "float8" in str(arr.dtype)):
-        return arr.astype(np.float32)
-    return arr
+    return arr.astype(np.float32) if _widens(arr.dtype) else arr
 
 
 def _norm_index(index: Tuple, shape: Tuple[int, ...]) -> Tuple[Tuple[int, int], ...]:
@@ -289,43 +305,44 @@ def _pspec_fingerprint(tree: Any) -> str:
     return hashlib.sha1(canon.encode("utf-8")).hexdigest()[:12]
 
 
-class _Snapshot:
-    """The synchronous half of a save: the global shard plan (manifest body)
-    plus this process's shard payloads, already on host. Building one is the
-    only part that touches devices — and only via local per-shard
-    device→host copies (``shard.data``), never a gather."""
+class _Plan(NamedTuple):
+    """The first stage of a save, from metadata alone (no device is touched
+    and no array is copied): the global shard plan — the manifest body —
+    and, in the order the shard file will hold them, the ``(member,
+    source)`` pairs this process must fetch. A source is a single-device
+    ``jax.Array`` (a shard this process owns; fetched by a pure local
+    device→host copy, never a gather) or a host ``ndarray`` (written by the
+    tree's writer rank)."""
 
-    __slots__ = ("manifest", "local", "rank", "gen", "writes_manifest",
-                 "nbytes")
-
-    def __init__(self, manifest: Dict[str, Any], local: Dict[str, np.ndarray],
-                 rank: int, gen: str, writes_manifest: bool):
-        self.manifest = manifest
-        self.local = local
-        self.rank = rank
-        self.gen = gen
-        self.writes_manifest = writes_manifest
-        #: bytes this process copied to the host and will write
-        self.nbytes = int(sum(a.nbytes for a in local.values()))
+    manifest: Dict[str, Any]
+    fetch: List[Tuple[str, Any]]
+    rank: int
+    gen: str
+    writes_manifest: bool
+    nbytes: int  #: bytes this process copies to the host and writes
 
 
-def _snapshot(path: str, tree: Any) -> _Snapshot:
+def _plan(path: str, tree: Any) -> _Plan:
     gen = f"{time.time_ns():x}"
     rank = _my_rank()
     wrank = _writer_rank(tree)
     base = os.path.basename(path)
     leaves: Dict[str, Any] = {}
-    local: Dict[str, np.ndarray] = {}
-    # which ranks own at least one shard — their files must exist on restore
+    fetch: List[Tuple[str, Any]] = []
+    nbytes = 0
     for tpath, leaf in jax.tree_util.tree_flatten_with_path(tree)[0]:
         key = _path_str(tpath)
         if key in leaves:
             raise ValueError(f"duplicate tree path key: {key!r}")
         sharding = getattr(leaf, "sharding", None)
-        shape = tuple(int(s) for s in getattr(leaf, "shape", np.shape(leaf)))
-        dtype = str(getattr(leaf, "dtype", np.asarray(leaf).dtype))
+        on_device = (sharding is not None
+                     and hasattr(sharding, "devices_indices_map"))
+        if not on_device:
+            leaf = np.asarray(leaf)  # python scalar -> 0-d; ndarray: no copy
+        shape = tuple(int(s) for s in leaf.shape)
+        stored_dtype = _stored_dtype(leaf.dtype)
         shards: List[Dict[str, Any]] = []
-        if sharding is not None and hasattr(sharding, "devices_indices_map"):
+        if on_device:
             # Global layout from metadata alone: devices_indices_map is
             # identical on every process, so each rank derives the same
             # plan with zero communication. Replicas dedupe to one owner
@@ -336,7 +353,6 @@ def _snapshot(path: str, tree: Any) -> _Snapshot:
             by_dev_id = {
                 s.device.id: s for s in getattr(leaf, "addressable_shards", [])
             }
-            stored_dtype = None
             for i, extent in enumerate(sorted(groups)):
                 owner = min(
                     groups[extent],
@@ -351,30 +367,25 @@ def _snapshot(path: str, tree: Any) -> _Snapshot:
                     "key": member,
                 })
                 if orank == rank:
-                    dshard = by_dev_id[getattr(owner, "id", 0)]
-                    arr = _stored(np.asarray(jax.device_get(dshard.data)))
-                    local[member] = arr
-                    stored_dtype = str(arr.dtype)
-            if stored_dtype is None:  # no local shard: derive, don't copy
-                widened = "bfloat16" in dtype or "float8" in dtype
-                stored_dtype = "float32" if widened else str(np.dtype(dtype))
+                    fetch.append((member, by_dev_id[getattr(owner, "id", 0)].data))
+                    nbytes += stored_dtype.itemsize * math.prod(
+                        b - a for a, b in extent)
         else:
             # Host (plain numpy / python scalar) leaf: one full-extent
             # shard, written by the tree's writer rank.
-            arr = _stored(np.asarray(leaf))
             member = f"{key}#s0"
             shards.append({
                 "index": [[0, d] for d in shape],
                 "file": f"{base}.g{gen}.r{wrank}.npz",
                 "key": member,
             })
-            stored_dtype = str(arr.dtype)
             if rank == wrank:
-                local[member] = arr
+                fetch.append((member, leaf))
+                nbytes += leaf.size * stored_dtype.itemsize
         leaves[key] = {
             "shape": list(shape),
-            "dtype": dtype,
-            "stored_dtype": stored_dtype,
+            "dtype": str(leaf.dtype),
+            "stored_dtype": str(stored_dtype),
             "shards": shards,
         }
     manifest = {
@@ -384,7 +395,7 @@ def _snapshot(path: str, tree: Any) -> _Snapshot:
         "pspec_fingerprint": _pspec_fingerprint(tree),
         "leaves": leaves,
     }
-    return _Snapshot(manifest, local, rank, gen, rank == wrank)
+    return _Plan(manifest, fetch, rank, gen, rank == wrank, nbytes)
 
 
 def _gc_stale_generations(path: str, keep_gen: str) -> None:
@@ -400,71 +411,114 @@ def _gc_stale_generations(path: str, keep_gen: str) -> None:
                 log.warning("could not GC stale checkpoint shard %s", f)
 
 
-def _commit_snapshot(path: str, snap: _Snapshot) -> None:
-    """The disk half of a save: stage + rename this rank's shard file, then
+# ------------------------------------------------------------- the pipeline
+# A save is three stages. (1) ``_plan``: the manifest and the list of
+# members to fetch, from metadata. (2) The caller's thread copies the shards
+# to the host one after another, in plan order, and hands each to the writer
+# as it lands. (3) The writer thread, started before the first shard is
+# waited for, puts each member into the shard file as it arrives, and after
+# the last one commits exactly as before: shard rename, then (manifest
+# writer only) manifest rename, publication, GC. End-of-interval
+# checkpoints are GB-scale (full train state incl. optimizer): a save that
+# is joined right away — the last interval of any job, every interval of a
+# multi-host one — costs the longer of copy and write, not their sum.
+#
+# The caller's thread is held exactly until its last local shard is on the
+# host (the engine may donate the buffers into the next interval's first
+# step), and never by the writer: the hand-off queue is unbounded. One
+# writer thread per path; restore() and a second save to the same path
+# wait for the in-flight write first. A failed write is recorded per path
+# and re-raised at the next join point (exists/restore/save_async/flush) —
+# a checkpoint that never hit disk must not be silently reported as saved.
+_PENDING: Dict[str, threading.Thread] = {}
+_FAILED: Dict[str, BaseException] = {}
+_PENDING_LOCK = threading.Lock()
+
+_LANDED_ALL = object()  # hand-off marks: every member is in the queue /
+_FETCH_FAILED = object()  # a fetch raised, commit nothing
+
+
+class _SaveAborted(Exception):
+    """Raised on the writer thread when the caller's thread gave up."""
+
+
+class _Stream:
+    """One save in flight: what the caller's thread and the writer thread
+    share. ``inbox`` carries ``(member, ndarray)`` in plan order and then
+    one of the two marks; ``snapshot_end`` (``perf_counter``) is stamped
+    before the mark is put; ``error`` is what the writer died of."""
+
+    __slots__ = ("inbox", "thread", "error", "starved_s", "snapshot_end")
+
+    def __init__(self):
+        self.inbox: "queue.SimpleQueue" = queue.SimpleQueue()
+        self.thread: Optional[threading.Thread] = None
+        self.error: Optional[BaseException] = None
+        self.starved_s = 0.0
+        self.snapshot_end: Optional[float] = None
+
+    def landed(self):
+        """Writer side: the members as they arrive, until the mark."""
+        while True:
+            t0 = time.perf_counter()
+            item = self.inbox.get()
+            self.starved_s += time.perf_counter() - t0
+            if item is _LANDED_ALL:
+                return
+            if item is _FETCH_FAILED:
+                raise _SaveAborted()
+            yield item
+
+    def end_snapshot(self, mark: object) -> None:
+        self.snapshot_end = time.perf_counter()
+        self.inbox.put(mark)
+
+
+def _commit_streamed(path: str, plan: _Plan, stream: _Stream) -> None:
+    """The disk half of a save, on the writer thread: stage this rank's
+    shard file member by member as the shards land, rename it, then
     (manifest writer only) stage + rename the manifest — the atomic commit
     point — and notify publication. Crash-barrier crossings bracket both
     renames; a kill at either leaves the previous generation untouched."""
     d = os.path.dirname(os.path.abspath(path))
     os.makedirs(d, exist_ok=True)
-    if snap.local:
+    if plan.fetch:
         fname = os.path.join(d, f"{os.path.basename(path)}"
-                                f".g{snap.gen}.r{snap.rank}.npz")
+                                f".g{plan.gen}.r{plan.rank}.npz")
         fd, tmp = tempfile.mkstemp(dir=d, suffix=".tmp")
         try:
-            with os.fdopen(fd, "wb") as f:
-                np.savez(f, **snap.local)
-            _barrier("mid-shard-write", path=fname, tmp=tmp, gen=snap.gen)
+            # what np.savez does per member, one member at a time: the
+            # file is the same .npz
+            with os.fdopen(fd, "wb") as f, \
+                    zipfile.ZipFile(f, "w", allowZip64=True) as zf:
+                for member, arr in stream.landed():
+                    with zf.open(member + ".npy", "w",
+                                 force_zip64=True) as fid:
+                        np.lib.format.write_array(fid, arr,
+                                                  allow_pickle=False)
+            _barrier("mid-shard-write", path=fname, tmp=tmp, gen=plan.gen)
             os.replace(tmp, fname)
         finally:
             if os.path.exists(tmp):
                 os.unlink(tmp)
-    if not snap.writes_manifest:
+    else:
+        for _ in stream.landed():  # no local shard: only the mark comes
+            pass
+    if not plan.writes_manifest:
         return
-    body = dict(snap.manifest)
+    body = dict(plan.manifest)
     body["checksum"] = _manifest_checksum(body)
     fd, tmp = tempfile.mkstemp(dir=d, suffix=".tmp")
     try:
         with os.fdopen(fd, "w", encoding="utf-8") as f:
             json.dump(body, f, separators=(",", ":"))
-        _barrier("pre-manifest-rename", path=path, tmp=tmp, gen=snap.gen)
+        _barrier("pre-manifest-rename", path=path, tmp=tmp, gen=plan.gen)
         os.replace(tmp, path)  # atomic: no torn checkpoints on crash
         _notify_published(path)
     finally:
         if os.path.exists(tmp):
             os.unlink(tmp)
-    _gc_stale_generations(path, snap.gen)
-
-
-def save(path: str, tree: Any) -> None:
-    """Atomically write a sharded pytree checkpoint rooted at ``path``.
-
-    Each process pulls only its locally-addressable shards to host (no
-    collective of any kind) and writes them to its own generation-tagged
-    shard file; the tree's writer rank additionally commits the manifest.
-    The manifest rename is the commit point — a crash at any earlier moment
-    leaves the previously published checkpoint fully readable."""
-    base = os.path.basename(path)
-    with metrics.span("ckpt.snapshot", path=base) as sp:
-        snap = _snapshot(path, tree)
-        sp.set(bytes=snap.nbytes)
-    with metrics.span("ckpt.write", path=base, bytes=snap.nbytes,
-                      n_shards=len(snap.local)):
-        _commit_snapshot(path, snap)
-
-
-# --------------------------------------------------------------- async writes
-# End-of-interval checkpoints are GB-scale (full train state incl. optimizer):
-# the device->host transfer must happen synchronously (the engine may donate
-# the buffers into the next interval's first step), but the DISK write can
-# overlap the next interval's compute. One writer thread per path; restore()
-# and a second save() to the same path wait for the in-flight write first.
-# A failed write is recorded per path and re-raised at the next join point
-# (exists/restore/save_async/flush) — a checkpoint that never hit disk must
-# not be silently reported as saved.
-_PENDING: Dict[str, threading.Thread] = {}
-_FAILED: Dict[str, BaseException] = {}
-_PENDING_LOCK = threading.Lock()
+    _gc_stale_generations(path, plan.gen)
 
 
 def _wait_pending(path: str) -> None:
@@ -495,50 +549,119 @@ def _record_async_failure(key: str, path: str, err: BaseException) -> None:
             _FAILED[key] = err
 
 
-def save_async(path: str, tree: Any) -> None:
-    """``save`` with the disk write off the critical path.
+def _fetch(source: Any) -> np.ndarray:
+    """One planned source as the host array the shard file stores: a device
+    shard's pure local device→host copy, waited for (a host leaf is there
+    already)."""
+    return _stored(np.asarray(source))
 
-    Blocks only for the local device->host shard transfer (``_snapshot``);
-    the shard + manifest writes and atomic renames happen in a background
-    thread. A crash mid-write leaves the previous checkpoint intact (same
-    commit discipline as ``save``). ``flush()`` joins all outstanding
-    writes; a failed write re-raises from the next join point on the same
-    path (or ``flush``).
 
-    Multi-host: every participating process snapshots its OWN shards (pure
-    local copies — the sharded format removed the old collective gather)
-    and writes its own shard file; only the tree's writer rank
-    (``_writer_rank`` — lowest process addressing it) commits the manifest.
-    The multi-host engine flushes + barriers at interval end so readers
-    never race the write (``engine.py``)."""
+def _start_save(path: str, tree: Any, park_failure: bool) -> _Stream:
+    """Run the pipeline up to the moment the last local shard is on the
+    host and handed to the writer; the writer thread is still running on
+    return. A fetch that raises stops the writer (nothing is committed, the
+    temp file is removed) and propagates from here. What the *writer* dies
+    of is left in ``stream.error`` and, with ``park_failure``, parked for
+    the path's next join point."""
     base = os.path.basename(path)
+    key = os.path.abspath(path)
+    # ``ckpt.write`` is its snapshot's sibling, not its child: the two
+    # overlap, and a span's self time is its duration minus its children.
+    above = metrics.current_span()
     # Both stretches are on the caller's thread — the gang's: training is
     # stalled for as long as they last (``ckpt_stall`` reads them).
     with metrics.span("ckpt.wait_pending", path=base):
         _wait_pending(path)  # at most one in-flight write per path
     with metrics.span("ckpt.snapshot", path=base) as snapped:
-        snap = _snapshot(path, tree)
-        snapped.set(bytes=snap.nbytes)
-    key = os.path.abspath(path)
+        plan = _plan(path, tree)
+        stream = _Stream()
 
-    def write():
+        def write():
+            try:
+                with metrics.span("ckpt.write", parent=above, path=base,
+                                  bytes=plan.nbytes,
+                                  n_shards=len(plan.fetch)) as wrote:
+                    t0 = time.perf_counter()
+                    try:
+                        _commit_streamed(path, plan, stream)
+                    finally:
+                        t1 = time.perf_counter()
+                        end = stream.snapshot_end  # None: still snapshotting
+                        under = t1 if end is None else min(t1, end)
+                        wrote.set(overlap_s=max(under - t0, 0.0),
+                                  starved_s=stream.starved_s)
+            except _SaveAborted:
+                pass  # the caller's thread raises what stopped it
+            except BaseException as e:  # re-raised at a join point
+                stream.error = e
+                if park_failure:
+                    log.exception("async checkpoint write to %s failed", path)
+                    _record_async_failure(key, path, e)
+            finally:
+                with _PENDING_LOCK:
+                    if _PENDING.get(key) is threading.current_thread():
+                        del _PENDING[key]
+
+        stream.thread = threading.Thread(target=write, name=f"ckpt-{base}",
+                                         daemon=True)
+        with _PENDING_LOCK:
+            _PENDING[key] = stream.thread
+        stream.thread.start()
+        n_streamed = 0
         try:
-            # the writer thread's span is its snapshot's child
-            with metrics.span("ckpt.write", parent=snapped, path=base,
-                              bytes=snap.nbytes, n_shards=len(snap.local)):
-                _commit_snapshot(path, snap)
-        except BaseException as e:  # re-raised at the next join point
-            log.exception("async checkpoint write to %s failed", path)
-            _record_async_failure(key, path, e)
-        finally:
-            with _PENDING_LOCK:
-                if _PENDING.get(key) is threading.current_thread():
-                    del _PENDING[key]
+            for member, source in plan.fetch:
+                if not stream.thread.is_alive():
+                    break  # the writer failed: nothing left to feed
+                stream.inbox.put((member, _fetch(source)))
+                n_streamed += 1
+        except BaseException:
+            stream.end_snapshot(_FETCH_FAILED)
+            stream.thread.join()
+            raise
+        stream.end_snapshot(_LANDED_ALL)
+        snapped.set(bytes=plan.nbytes, n_streamed=n_streamed)
+    return stream
 
-    t = threading.Thread(target=write, name=f"ckpt-{os.path.basename(path)}", daemon=True)
-    with _PENDING_LOCK:
-        _PENDING[key] = t
-    t.start()
+
+def save(path: str, tree: Any) -> None:
+    """Atomically write a sharded pytree checkpoint rooted at ``path``.
+
+    Each process pulls only its locally-addressable shards to host (no
+    collective of any kind) and writes them to its own generation-tagged
+    shard file; the tree's writer rank additionally commits the manifest.
+    The manifest rename is the commit point — a crash at any earlier moment
+    leaves the previously published checkpoint fully readable.
+
+    The same pipeline as ``save_async``, joined before it returns; what
+    the writer thread raised (a crash barrier's kill included) is raised
+    here."""
+    stream = _start_save(path, tree, park_failure=False)
+    with metrics.span("ckpt.flush", path=os.path.basename(path), n_pending=1):
+        stream.thread.join()
+    if stream.error is not None:
+        raise stream.error
+
+
+def save_async(path: str, tree: Any) -> None:
+    """``save`` with the end of the disk write off the critical path.
+
+    Blocks until the last local shard is on the host — the device→host
+    copies back to back, the writer thread already putting the shards that
+    have landed into the shard file — and no longer; the rest of the file,
+    the manifest and the atomic renames happen in the writer thread. A
+    crash mid-write leaves the previous checkpoint intact (same commit
+    discipline as ``save``). ``flush()`` joins all outstanding writes; a
+    failed write re-raises from the next join point on the same path (or
+    ``flush``). A host leaf is written from the caller's own array: it must
+    not change before the write is joined.
+
+    Multi-host: every participating process streams its OWN shards (pure
+    local copies — the sharded format removed the old collective gather)
+    into its own shard file; only the tree's writer rank
+    (``_writer_rank`` — lowest process addressing it) commits the manifest,
+    after its own shard file. The multi-host engine flushes + barriers at
+    interval end so readers never race the write (``engine.py``)."""
+    _start_save(path, tree, park_failure=True)
 
 
 def flush() -> None:

@@ -10,6 +10,7 @@ and what the consumers observe.
 
 import json
 import os
+import threading
 import zipfile
 
 import jax
@@ -207,6 +208,288 @@ class TestAsyncErrorRetention:
         with pytest.raises(RuntimeError, match="async checkpoint write"):
             ckpt.flush()
         ckpt.flush()  # error consumed: the next flush is clean
+
+
+# ------------------------------------------------- PR 27: the save pipeline
+LAYOUTS = ("replicated", "sharded", "host")
+A = np.arange(256 * 64, dtype=np.float32).reshape(256, 64)
+MODES = ("save", "save_async")
+
+
+def stream_tree(layout):
+    """Four leaves, one of them bf16 (stored widened): every one replicated
+    over four devices, the arrays split over them, or plain numpy. The
+    first member of the shard file (``params/a``) is larger than a file
+    object's buffer, so its bytes reach the file when it is written."""
+    host = {
+        "params": {
+            "a": A.copy(),
+            "b": np.linspace(-1.0, 1.0, 8).astype(np.float32),
+            "e": np.asarray(jnp.arange(16, dtype=jnp.bfloat16)),
+        },
+        "step": np.asarray(7, dtype=np.int32),
+    }
+    if layout == "host":
+        return host
+    mesh = mesh_of(4)
+    split = NamedSharding(mesh, P("dp") if layout == "sharded" else P())
+    whole = NamedSharding(mesh, P())
+    return {
+        "params": {k: jax.device_put(v, split)
+                   for k, v in host["params"].items()},
+        "step": jax.device_put(host["step"], whole),
+    }
+
+
+def n_members(layout):
+    return 3 * 4 + 1 if layout == "sharded" else 4
+
+
+def run_save(mode, path, tree):
+    getattr(ckpt, mode)(path, tree)
+    ckpt.flush()
+
+
+def litter(d):
+    return sorted(n for n in os.listdir(d) if n.endswith(".tmp"))
+
+
+def shard_files(d):
+    return sorted(n for n in os.listdir(d) if ckpt._SHARD_RE.search(n))
+
+
+def zip_directory(path):
+    with zipfile.ZipFile(path) as zf:
+        return [(i.filename, i.file_size, i.compress_size, i.CRC,
+                 i.compress_type, i.flag_bits, i.extract_version,
+                 i.header_offset) for i in zf.infolist()]
+
+
+class Recorder:
+    """Order of events in one save: ``("fetched", member)`` on the caller's
+    thread, ``("written", member)`` on the writer's. ``hold_last`` keeps the
+    fetch of the last member back until the first one is in the temp file
+    (an event with a generous timeout, never a sleep)."""
+
+    def __init__(self, monkeypatch, directory, hold_last=False,
+                 fail_at=None):
+        self.events = []
+        self.members = []  # the plan's, in its order
+        self.first_written = threading.Event()
+        self.seen_in_tmp = None
+        self.n_fetch = 0
+        real_fetch, real_write = ckpt._fetch, np.lib.format.write_array
+        real_plan = ckpt._plan
+
+        def plan(path, tree):
+            planned = real_plan(path, tree)
+            self.members[:] = [m for m, _ in planned.fetch]
+            return planned
+
+        def fetch(source):
+            k = self.n_fetch
+            self.n_fetch += 1
+            if fail_at is not None and k == fail_at:
+                raise OSError(f"no shard {k} for you")
+            if hold_last and k == len(self.members) - 1:
+                assert self.first_written.wait(60), "writer never wrote"
+                (tmp,) = litter(directory)
+                self.seen_in_tmp = os.path.getsize(
+                    os.path.join(directory, tmp))
+            self.events.append(("fetched", self.members[k]))
+            return real_fetch(source)
+
+        def write_array(fid, arr, **kw):
+            real_write(fid, arr, **kw)
+            fid.flush()
+            self.events.append(("written", arr.shape))
+            self.first_written.set()
+
+        monkeypatch.setattr(ckpt, "_plan", plan)
+        monkeypatch.setattr(ckpt, "_fetch", fetch)
+        monkeypatch.setattr(np.lib.format, "write_array", write_array)
+
+
+@pytest.fixture
+def no_pending():
+    """Every case leaves no writer behind and no parked failure."""
+    yield
+    for t in list(ckpt._PENDING.values()):
+        t.join(60)
+    assert not ckpt._PENDING
+    ckpt._FAILED.clear()
+
+
+@pytest.mark.usefixtures("devices8", "no_pending")
+class TestStreamedSave:
+    @pytest.mark.parametrize("layout", LAYOUTS)
+    @pytest.mark.parametrize("mode", MODES)
+    def test_every_reader_reads_it_and_savez_writes_the_same_file(
+            self, tmp_path, mode, layout):
+        tree = stream_tree(layout)
+        want = {
+            "params/a": A,
+            "params/b": np.linspace(-1.0, 1.0, 8).astype(np.float32),
+            "params/e": np.arange(16, dtype=np.float32),
+            "step": np.asarray(7, dtype=np.int32),
+        }
+        path = str(tmp_path / "t.npz")
+        run_save(mode, path, tree)
+
+        def read_all():
+            assert ckpt.verify(path)
+            got = ckpt.load_arrays(path)
+            assert set(got) == set(want)
+            for k in want:  # bf16 comes back widened, as before
+                assert got[k].dtype == want[k].dtype, k
+                assert got[k].tobytes() == want[k].tobytes(), k
+            template = stream_tree("host")
+            back = ckpt.restore(path, template)
+            assert str(back["params"]["e"].dtype) == "bfloat16"
+            assert back["params"]["a"].tobytes() == want["params/a"].tobytes()
+            placed = ckpt.restore_sharded(
+                path, template, NamedSharding(mesh_of(2), P()))
+            assert (np.asarray(placed["params"]["b"]).tobytes()
+                    == want["params/b"].tobytes())
+            assert int(placed["step"]) == 7
+            summ = ckpt.summarize(path)
+            assert summ["ok"] and summ["format"] == "sharded-manifest"
+            assert summ["leaves"] == 4 and summ["shards"] == n_members(layout)
+
+        read_all()
+        with open(path) as f:
+            assert json.load(f)["version"] == 1
+        # the same members through plain np.savez, under the same manifest:
+        # the zip's directory is the same, entry for entry, and every
+        # reader reads the same arrays — the format did not move
+        for name in shard_files(tmp_path):
+            full = str(tmp_path / name)
+            streamed = zip_directory(full)
+            with np.load(full) as z:
+                members = {k: z[k] for k in z.files}
+            with open(full, "wb") as f:
+                np.savez(f, **members)
+            assert zip_directory(full) == streamed
+        read_all()
+
+    @pytest.mark.parametrize("layout", LAYOUTS)
+    @pytest.mark.parametrize("mode", MODES)
+    def test_first_member_is_on_disk_before_the_last_is_fetched(
+            self, tmp_path, monkeypatch, mode, layout):
+        rec = Recorder(monkeypatch, str(tmp_path), hold_last=True)
+        path = str(tmp_path / "t.npz")
+        run_save(mode, path, stream_tree(layout))
+        members = rec.members
+        assert len(members) == n_members(layout)
+        fetched = [e for e in rec.events if e[0] == "fetched"]
+        assert [m for _, m in fetched] == members  # plan order
+        first_write = next(i for i, e in enumerate(rec.events)
+                           if e[0] == "written")
+        assert first_write < rec.events.index(("fetched", members[-1]))
+        # ... and its bytes were in the shard file's temp file by then
+        first = A.nbytes // (4 if layout == "sharded" else 1)
+        assert rec.seen_in_tmp is not None and rec.seen_in_tmp >= first
+        assert not litter(tmp_path) and ckpt.verify(path)
+
+    @pytest.mark.parametrize("layout", LAYOUTS)
+    def test_save_async_returns_with_every_shard_on_the_host(
+            self, tmp_path, monkeypatch, layout):
+        rec = Recorder(monkeypatch, str(tmp_path))
+        tree = stream_tree(layout)
+        path = str(tmp_path / "t.npz")
+        ckpt.save_async(path, tree)
+        # every member was fetched before the call returned ...
+        assert ([m for k, m in rec.events if k == "fetched"]
+                == rec.members)
+        # ... so the source may go (the engine donates it into the next
+        # step); a host leaf is the caller's to keep until the join
+        for leaf in jax.tree_util.tree_leaves(tree):
+            if hasattr(leaf, "delete"):
+                leaf.delete()
+        del tree
+        ckpt.flush()
+        got = ckpt.load_arrays(path)
+        assert got["params/a"].tobytes() == A.tobytes()
+        assert got["params/e"].tobytes() == np.arange(
+            16, dtype=np.float32).tobytes()
+        assert int(got["step"]) == 7
+
+    @pytest.mark.parametrize("layout", LAYOUTS)
+    @pytest.mark.parametrize("mode", MODES)
+    def test_fetch_that_raises_commits_nothing(
+            self, tmp_path, monkeypatch, mode, layout):
+        path = str(tmp_path / "t.npz")
+        ckpt.save(path, stream_tree(layout))
+        before = sorted(os.listdir(tmp_path))
+        prev = ckpt.load_arrays(path)["params/a"].tobytes()
+        Recorder(monkeypatch, str(tmp_path), fail_at=2)
+        bad = stream_tree(layout)
+        with pytest.raises(OSError, match="no shard 2"):  # on this thread
+            getattr(ckpt, mode)(path, bad)
+        assert not ckpt._PENDING  # the writer was stopped and joined
+        ckpt.flush()  # ... and parked nothing
+        assert sorted(os.listdir(tmp_path)) == before  # no tmp, no new gen
+        assert ckpt.verify(path)
+        assert ckpt.load_arrays(path)["params/a"].tobytes() == prev
+
+    @pytest.mark.parametrize("join", ["flush", "next_save_async", "save"])
+    def test_writer_that_dies_surfaces_and_hangs_nobody(self, tmp_path, join):
+        (tmp_path / "nodir").write_bytes(b"")  # the "directory" is a file
+        target = str(tmp_path / "nodir" / "t.npz")
+        raised = []
+
+        def caller():
+            try:
+                if join == "save":
+                    ckpt.save(target, stream_tree("sharded"))
+                else:
+                    ckpt.save_async(target, stream_tree("sharded"))
+                    if join == "flush":
+                        ckpt.flush()
+                    else:
+                        ckpt.save_async(target, stream_tree("sharded"))
+            except BaseException as e:
+                raised.append(e)
+
+        t = threading.Thread(target=caller, daemon=True)
+        t.start()
+        t.join(60)
+        assert not t.is_alive(), "the caller's thread hangs on a dead writer"
+        (err,) = raised
+        if join == "save":  # the writer's own error, on the caller's thread
+            assert isinstance(err, OSError)
+        else:
+            assert isinstance(err, RuntimeError)
+            assert "async checkpoint write" in str(err)
+            assert isinstance(err.__cause__, OSError)
+        ckpt.flush()  # consumed: the next join point is clean
+
+    @pytest.mark.parametrize("mode", MODES)
+    def test_write_span_is_its_snapshots_sibling_and_overlaps_it(
+            self, tmp_path, monkeypatch, mode):
+        Recorder(monkeypatch, str(tmp_path), hold_last=True)
+        ev = str(tmp_path / "ev.jsonl")
+        with metrics.scoped(ev):
+            with metrics.span("task_interval_like"):
+                getattr(ckpt, mode)(str(tmp_path / "t.npz"),
+                                    stream_tree("sharded"))
+            ckpt.flush()
+        by = {}
+        for e in metrics.read_events(ev):
+            by.setdefault(e["kind"], []).append(e)
+        (outer,), (snap,), (write,) = (
+            by["task_interval_like"], by["ckpt.snapshot"], by["ckpt.write"])
+        assert snap["parent"] == write["parent"] == outer["id"]
+        assert write["root"] == outer["id"]
+        assert write["thread"].startswith("ckpt-")
+        assert write["thread"] != snap["thread"]
+        assert write["ts_start"] < snap["ts"]  # began under its snapshot
+        assert snap["n_streamed"] == n_members("sharded") == write["n_shards"]
+        assert snap["bytes"] == write["bytes"] == A.nbytes + 8 * 4 + 16 * 4 + 4
+        assert 0 < write["overlap_s"] <= write["dur_s"] + 1e-6
+        assert 0 <= write["starved_s"] <= write["dur_s"] + 1e-6
+        # the join of a synchronous save is a ckpt.flush of its own
+        assert len(by["ckpt.flush"]) == (2 if mode == "save" else 1)
 
 
 @pytest.mark.crash

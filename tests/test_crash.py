@@ -244,6 +244,66 @@ class TestCheckpointCorruption:
         assert seen == [("t1", True)]
 
 
+class TestStreamedSaveBarriers:
+    """PR 27: the save is a pipeline (the writer thread fills the shard file
+    while the shards still land), and the commit is what it was: both
+    barriers, in order, once each, the publish hook after the manifest."""
+
+    @pytest.fixture(autouse=True)
+    def _clean(self):
+        from saturn_tpu.utils import checkpoint as ckpt
+
+        yield
+        ckpt.set_crash_barrier(None)
+        ckpt._FAILED.clear()
+
+    @pytest.mark.parametrize("mode", ["save", "save_async"])
+    def test_both_barriers_once_each_in_order(self, tmp_path, mode):
+        from saturn_tpu.utils import checkpoint as ckpt
+
+        path = str(tmp_path / "t.npz")
+        seen = []
+
+        def barrier(point, ctx):
+            # at either crossing the new generation is not yet published
+            seen.append((point, os.path.exists(ctx["tmp"]),
+                         os.path.exists(path)))
+
+        hook = lambda stem, p: seen.append(("published", True,
+                                            os.path.exists(p)))
+        ckpt.set_crash_barrier(barrier)
+        ckpt.add_publish_hook(hook)
+        try:
+            getattr(ckpt, mode)(path, {"a": np.arange(6.0), "b": np.ones(3)})
+            ckpt.flush()
+        finally:
+            ckpt.remove_publish_hook(hook)
+        assert seen == [("mid-shard-write", True, False),
+                        ("pre-manifest-rename", True, False),
+                        ("published", True, True)]
+        assert ckpt.verify(path)
+
+    @pytest.mark.parametrize("point",
+                             ["mid-shard-write", "pre-manifest-rename"])
+    def test_kill_in_the_async_writer_keeps_the_previous_generation(
+            self, tmp_path, point):
+        from saturn_tpu.utils import checkpoint as ckpt
+
+        path = str(tmp_path / "t.npz")
+        ckpt.save(path, {"a": np.arange(6.0)})
+        before = ckpt.load_arrays(path)["a"].tobytes()
+        inj = CrashInjector(point)
+        ckpt.set_crash_barrier(inj.barrier)
+        ckpt.save_async(path, {"a": np.arange(6.0) + 1})  # returns: parked
+        with pytest.raises(RuntimeError) as ei:
+            ckpt.flush()
+        assert isinstance(ei.value.__cause__, SimulatedKill)
+        ckpt.set_crash_barrier(None)
+        assert ckpt.verify(path)
+        assert ckpt.load_arrays(path)["a"].tobytes() == before
+        assert not [n for n in os.listdir(tmp_path) if n.endswith(".tmp")]
+
+
 # ---------------------------------------------------------- metrics satellite
 class TestMetricsTornTail:
     def test_read_events_skips_and_warns_on_torn_line(self, tmp_path, caplog):
