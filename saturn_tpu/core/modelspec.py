@@ -58,6 +58,20 @@ class ModelSpec:
     # objectives on top of this model's trunk.
     hidden_fn: Optional[Callable[[Any, Any], Any]] = None
 
+    @property
+    def stack_passes(self) -> int:
+        """How many times a token passes the block stack: the ``passes`` of
+        ``hints["pipeline"]`` (a looped model), 1 for every model that says
+        nothing."""
+        return int((self.hints.get("pipeline") or {}).get("passes", 1))
+
+    @property
+    def stack_layers(self) -> Optional[int]:
+        """Layers the block stack holds (each applied ``stack_passes``
+        times a token), if the model says."""
+        n = self.hints.get("n_layers", getattr(self.config, "n_layers", None))
+        return None if n is None else int(n)
+
     def abstract_init(self):
         import jax
 

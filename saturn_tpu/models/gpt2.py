@@ -96,6 +96,21 @@ class GPT2Config:
     # address the stash statically. 1 = plain scan (smallest compile);
     # measure before changing the default (benchmarks/profile_step.py).
     scan_unroll: int = 1
+    # Looped-LM structure knobs (Ouro-class: one stack of layers run several
+    # times on shared weights). Each at its default leaves every earlier
+    # preset's program unchanged op for op.
+    #   sandwich_norm: a norm on each branch's *output* as well
+    #     (x += post(attn(ln_1 x)); x += post(mlp(ln_2 x))).
+    #   rope_theta: the rotary base.
+    #   use_bias: False drops the bias of every projection.
+    #   tie_head: False gives the output head its own (V, D) ``lm_head``.
+    #   n_passes: how many times a token passes the whole stack; ``ln_f``
+    #     follows every pass and its output feeds the next one.
+    sandwich_norm: bool = False
+    rope_theta: float = 10000.0
+    use_bias: bool = True
+    tie_head: bool = True
+    n_passes: int = 1
     name: str = "gpt2-small"
 
     def __post_init__(self) -> None:
@@ -121,6 +136,15 @@ class GPT2Config:
         if self.mlp_act not in ("gelu", "swiglu"):
             raise ValueError(f"mlp_act must be 'gelu' or 'swiglu', "
                              f"got {self.mlp_act!r}")
+        if self.sandwich_norm and self.parallel_residual:
+            raise ValueError("sandwich_norm needs the sequential residual "
+                             "(each branch's output is normed before its add)")
+        if self.n_passes < 1:
+            raise ValueError(f"n_passes must be >= 1, got {self.n_passes}")
+        if self.n_passes > 1 and self.moe:
+            raise ValueError("a looped stack (n_passes > 1) with moe=True is "
+                             "not supported: the sown aux loss has one slot "
+                             "per layer, not per layer application")
         if self.n_kv_heads is not None and (
             self.n_kv_heads < 1 or self.n_heads % self.n_kv_heads != 0
         ):
@@ -185,6 +209,23 @@ PRESETS: Dict[str, Dict[str, Any]] = {
         vocab_size=256, seq_len=64, rotary=True, norm="rmsnorm",
         mlp_act="swiglu",
     ),
+    # Ouro (ByteDance/Ouro-2.6B): a looped LM -- 48 layers run 4 times on
+    # shared weights, ``ln_f`` after every pass. Sandwich RMSNorm, SwiGLU,
+    # rotary (base 1e6) on the whole 128-wide head, no bias anywhere, an
+    # untied head. The published exit gate (Linear(d, 1) per pass) is not
+    # built: at the published early_exit_threshold 1 no pass is skipped,
+    # and the next-token loss gives it no gradient.
+    "ouro-2.6b": dict(
+        d_model=2048, n_layers=48, n_heads=16, d_ff=5632, vocab_size=49152,
+        rotary=True, rope_theta=1e6, norm="rmsnorm", mlp_act="swiglu",
+        sandwich_norm=True, use_bias=False, tie_head=False, n_passes=4,
+    ),
+    "ouro-test-tiny": dict(
+        d_model=64, n_layers=2, n_heads=4, d_ff=176, vocab_size=256,
+        seq_len=64, rotary=True, rope_theta=1e6, norm="rmsnorm",
+        mlp_act="swiglu", sandwich_norm=True, use_bias=False,
+        tie_head=False, n_passes=4,
+    ),
     # Switch-style MoE family (extension beyond the reference; SURVEY.md §2.3
     # lists EP as absent there).
     "moe-test-tiny": dict(
@@ -196,7 +237,8 @@ PRESETS: Dict[str, Dict[str, Any]] = {
 }
 
 
-def rotary_sin_cos(positions: jax.Array, rotary_dim: int):
+def rotary_sin_cos(positions: jax.Array, rotary_dim: int,
+                   theta: float = 10000.0):
     """(sin, cos) tables, each (T, rotary_dim//2), fp32.
 
     Reference computed fixed sinusoids and rotated every-other dim
@@ -204,7 +246,7 @@ def rotary_sin_cos(positions: jax.Array, rotary_dim: int):
     fuses into the surrounding matmuls without the interleaving gathers.
     """
     inv_freq = 1.0 / (
-        10000.0 ** (jnp.arange(0, rotary_dim, 2, dtype=jnp.float32) / rotary_dim)
+        theta ** (jnp.arange(0, rotary_dim, 2, dtype=jnp.float32) / rotary_dim)
     )
     angles = positions.astype(jnp.float32)[:, None] * inv_freq[None, :]
     return jnp.sin(angles), jnp.cos(angles)
@@ -249,6 +291,9 @@ def _norm_cls(cfg: GPT2Config):
 class Block(nn.Module):
     """Pre-LN transformer block, scan-compatible signature.
 
+    ``sandwich_norm=True`` (sequential wiring only) norms each branch's
+    output too, before its residual add (``ln_1_post``, ``ln_2_post``).
+
     Two residual wirings (parity with ``GPTJ.py:392-424``): sequential GPT-2
     (ln_1 → attn, ln_2 → mlp) or, with ``parallel_residual=True``, GPT-J's
     parallel form (one ln, attn and mlp added together). ``rotary=True``
@@ -265,10 +310,14 @@ class Block(nn.Module):
         def make_norm(name):
             return _norm_cls(cfg)(dtype=dt, param_dtype=pdt, name=name)
 
+        def dense(features, name):
+            return nn.Dense(features, dtype=dt, param_dtype=pdt,
+                            use_bias=cfg.use_bias, name=name)
+
         # ---- attention ----
         h = make_norm("ln_1")(x)
         if cfg.n_kv_heads is None:
-            qkv = nn.Dense(3 * D, dtype=dt, param_dtype=pdt, name="qkv")(h)
+            qkv = dense(3 * D, "qkv")(h)
             q, k, v = jnp.split(qkv, 3, axis=-1)
             kv_heads = cfg.n_heads
         else:
@@ -276,8 +325,7 @@ class Block(nn.Module):
             # projection sized D + 2 * kv_dim.
             kv_heads = cfg.n_kv_heads
             kv_dim = kv_heads * cfg.head_dim
-            qkv = nn.Dense(D + 2 * kv_dim, dtype=dt, param_dtype=pdt,
-                           name="qkv")(h)
+            qkv = dense(D + 2 * kv_dim, "qkv")(h)
             q = qkv[..., :D]
             k = qkv[..., D:D + kv_dim]
             v = qkv[..., D + kv_dim:]
@@ -294,7 +342,8 @@ class Block(nn.Module):
                 offset = jax.lax.axis_index(cfg.seq_axis) * T
             else:
                 offset = 0
-            sin, cos = rotary_sin_cos(jnp.arange(T) + offset, rd)
+            sin, cos = rotary_sin_cos(jnp.arange(T) + offset, rd,
+                                      cfg.rope_theta)
             q = apply_rotary(q, sin, cos, rd)
             k = apply_rotary(k, sin, cos, rd)
         if kv_heads != cfg.n_heads and not (
@@ -336,7 +385,9 @@ class Block(nn.Module):
             probs = jax.nn.softmax(scores, axis=-1).astype(dt)
             attn = jnp.einsum("bhqk,bhkd->bhqd", probs, v)
         attn = attn.transpose(0, 2, 1, 3).reshape(B, T, D)
-        attn = nn.Dense(D, dtype=dt, param_dtype=pdt, name="attn_out")(attn)
+        attn = dense(D, "attn_out")(attn)
+        if cfg.sandwich_norm:
+            attn = make_norm("ln_1_post")(attn)
 
         # ---- mlp (dense or Switch-routed experts) ----
         def mlp(inp):
@@ -349,16 +400,13 @@ class Block(nn.Module):
                 # is local — a fused contiguous split would put all gate
                 # columns on shard 0 and force a full-activation reshard
                 # per layer.
-                gate = nn.Dense(cfg.ff_dim, dtype=dt, param_dtype=pdt,
-                                name="mlp_gate")(inp)
-                up = nn.Dense(cfg.ff_dim, dtype=dt, param_dtype=pdt,
-                              name="mlp_in")(inp)
+                gate = dense(cfg.ff_dim, "mlp_gate")(inp)
+                up = dense(cfg.ff_dim, "mlp_in")(inp)
                 m = nn.silu(gate) * up
             else:
-                m = nn.Dense(cfg.ff_dim, dtype=dt, param_dtype=pdt,
-                             name="mlp_in")(inp)
+                m = dense(cfg.ff_dim, "mlp_in")(inp)
                 m = nn.gelu(m, approximate=True)
-            return nn.Dense(D, dtype=dt, param_dtype=pdt, name="mlp_out")(m)
+            return dense(D, "mlp_out")(m)
 
         if cfg.parallel_residual:
             # GPT-J wiring: attn and MLP both read ln_1(x), one residual add
@@ -367,7 +415,10 @@ class Block(nn.Module):
         else:
             x = x + attn
             h2 = make_norm("ln_2")(x)
-            x = x + mlp(h2)
+            m = mlp(h2)
+            if cfg.sandwich_norm:
+                m = make_norm("ln_2_post")(m)
+            x = x + m
         return x, None
 
     def _attention_impl(self) -> str:
@@ -451,17 +502,43 @@ class GPT2(nn.Module):
             metadata_params={nn.PARTITION_NAME: "layers"},
             unroll=cfg.scan_unroll,
         )
-        x, _ = stack(cfg, name="blocks")(x, None)
+        def final_norm(**kw):
+            return _norm_cls(cfg)(dtype=cfg.dtype, param_dtype=cfg.param_dtype,
+                                  name="ln_f", **kw)
 
-        x = _norm_cls(cfg)(dtype=cfg.dtype, param_dtype=cfg.param_dtype,
-                           name="ln_f")(x)
+        if cfg.n_passes == 1:
+            x, _ = stack(cfg, name="blocks")(x, None)
+            x = final_norm()(x)
+        else:
+            # Looped stack: the same scanned layers (parameters broadcast to
+            # every pass), ``n_passes`` times, ``ln_f`` after every pass: one
+            # outer scan over the layer scan. The backward sums each layer's
+            # gradient over its uses; with remat the stash holds one block
+            # input per layer *application*. (The same module applied
+            # ``n_passes`` times in a Python loop is the same mathematics;
+            # compiled for a v5e at the published widths, depth 8, K = 8, it
+            # took 14.8 s against 9.5 s and planned 20.3 GiB against 17.3:
+            # PR 28.)
+            def one_pass(mdl, h, _):
+                h, _ = stack(cfg, name="blocks", parent=mdl)(h, None)
+                return final_norm(parent=mdl)(h), None
+
+            x, _ = nn.scan(
+                one_pass, variable_broadcast="params",
+                split_rngs={"params": False}, length=cfg.n_passes,
+            )(self, x, None)
+
+        head = wte if cfg.tie_head else self.param(
+            "lm_head", nn.initializers.normal(0.02),
+            (cfg.vocab_size, cfg.d_model), cfg.param_dtype,
+        )
         if return_hidden:
             # final hidden states for the fused head+loss path (ops/ce.py);
-            # the caller owns the tied-head matmul
+            # the caller owns the head matmul
             return x
-        # Tied output head (reference ties via lm_head over flattened weights,
-        # GPTJ.py:340-390); fp32 logits for a stable loss.
-        logits = jnp.einsum("btd,vd->btv", x, wte.astype(cfg.dtype))
+        # Tied output head by default (reference ties via lm_head over
+        # flattened weights, GPTJ.py:340-390); fp32 logits for a stable loss.
+        logits = jnp.einsum("btd,vd->btv", x, head.astype(cfg.dtype))
         return logits.astype(jnp.float32)
 
 
@@ -484,6 +561,13 @@ def build_gpt2(
     """
     cfg = resolve_attention(config_for(name, **overrides))
     module = GPT2(cfg)
+    head_key = "wte" if cfg.tie_head else "lm_head"
+    if pretrained is not None and (
+        cfg.n_passes > 1 or cfg.sandwich_norm or not cfg.tie_head
+    ):
+        raise NotImplementedError(
+            "pretrained ingest knows the GPT-2 / GPT-J state-dict names only"
+        )
 
     if pretrained is None:
         def init_fn(rng):
@@ -536,10 +620,14 @@ def build_gpt2(
         y, _ = Block(cfg).apply({"params": layer_params}, x, None)
         return y
 
-    def pipeline_head(other_params, x):
+    def final_norm(other_params, x):
         ln = _norm_cls(cfg)(dtype=cfg.dtype, param_dtype=cfg.param_dtype)
-        xn = ln.apply({"params": other_params["ln_f"]}, x)
-        logits = jnp.einsum("btd,vd->btv", xn, other_params["wte"].astype(cfg.dtype))
+        return ln.apply({"params": other_params["ln_f"]}, x)
+
+    def pipeline_head(other_params, x):
+        xn = final_norm(other_params, x)
+        logits = jnp.einsum("btd,vd->btv", xn,
+                            other_params[head_key].astype(cfg.dtype))
         return logits.astype(jnp.float32)
 
     def hidden_fn(params, tokens):
@@ -547,7 +635,7 @@ def build_gpt2(
 
     fused_loss_fn = fused_loss_parts_fn = None
     if cfg.causal and not cfg.moe and cfg.seq_axis is None:
-        # Fused head+loss (ops/ce.py): hidden states + the tied wte go
+        # Fused head+loss (ops/ce.py): hidden states + the head weights go
         # straight into the Pallas CE kernel — no (B,T,V) logits tensor.
         # Identical objective to pretraining_loss∘apply_fn (next-token CE,
         # mean over B*(T-1) real targets); the op itself falls back to a
@@ -561,7 +649,7 @@ def build_gpt2(
                 constant_values=-1,
             )
             return fused_linear_cross_entropy(
-                x, params["wte"], labels, reduction=reduction
+                x, params[head_key], labels, reduction=reduction
             )
 
         def fused_loss_fn(params, tokens):
@@ -597,6 +685,13 @@ def build_gpt2(
             "head": pipeline_head,
             "act_shape": lambda batch, seqlen: (batch, seqlen, cfg.d_model),
             "act_dtype": cfg.dtype,
+            # A looped stack: embed, then ``passes`` times the whole block
+            # stack with ``between`` (here ``ln_f``) applied between one
+            # pass and the next, then head (which norms the last pass). A
+            # technique that rebuilds the model from these pieces either
+            # honours both or refuses the model (``ModelSpec.stack_passes``).
+            "passes": cfg.n_passes,
+            "between": final_norm if cfg.n_passes > 1 else None,
         },
     }
     return ModelSpec(
@@ -622,3 +717,13 @@ def build_llama(name: str = "llama-1b", **overrides) -> ModelSpec:
     reference zoo never had; every technique works on it because the stack
     is the same scanned-block ModelSpec contract."""
     return build_gpt2(name, **overrides)
+
+
+def build_ouro(name: str = "ouro-2.6b", **overrides) -> ModelSpec:
+    """Ouro factory: a looped LM (``n_passes`` runs of one scanned stack on
+    shared weights, ``ln_f`` after each), sandwich RMSNorm, SwiGLU, rotary
+    base 1e6 on the whole head, no bias, untied ``lm_head``. Same
+    ``ModelSpec`` contract as :func:`build_gpt2`; ``hints["pipeline"]``
+    carries the pass count and the between-passes function."""
+    return build_gpt2(name, **overrides)
+

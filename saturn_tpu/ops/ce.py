@@ -343,19 +343,80 @@ _fused_ce.defvjp(_fused_ce_fwd, _fused_ce_bwd)
 
 
 # ------------------------------------------------------------------ public
-def _auto_bv_dw(d_model: int) -> int:
-    """dW vocab block: (bv_dw, D) f32 accumulator ≤ 4 MiB, rounded DOWN to a
-    power of two ≥ the 128-lane tile. A non-128-multiple (819 @ D=1280) both
-    breaks Mosaic tiling and, pre-fix, produced a Vp the fwd grid truncated;
-    a non-power-of-two 128-multiple (e.g. 640) makes lcm(bv, bv_dw) inflate
-    the vocab pad by up to ~4% dead columns in every kernel."""
-    cap = min(1024, (1 << 20) // max(d_model, 1024))
-    if d_model > 2048:
-        # recompute mode streams a (bv_dw, D) weight block and a (bn_dw, D)
-        # x block beside the double-buffered f32 output: 256 @ D=4096 was
-        # refused by the v5e compiler, 128 fits
-        cap //= 2
-    return max(128, 1 << (cap.bit_length() - 1))
+# What the v5e compiler puts in VMEM for one grid step of a backward kernel,
+# as far as shapes say it: every streamed block twice (double buffering) and
+# the f32 accumulator once. The f32 score block and its exp / ds temporaries
+# come on top and are not summed. Readings, each compiled for a described v5e
+# at bv = 512 (PR 28; "wants" is the compiler's own figure):
+#   dx  sum  13.0 MiB  D 2048 stash bn 512        admitted
+#       sum  12.25     D 4096 stash bn 128        admitted
+#       sum  12.0      D 1024 stash bn 1024       admitted
+#       sum  14.0      D 4096 recompute bn 128    refused, wants 16.34
+#       sum  14.0      D 1024 recompute bn 1024   refused, wants 19.17
+#       sum  16.0      D 2048 recompute bn 512    refused, wants 16.79
+#   dW  sum  16.0      D 1024 stash     (bn 512, bv 1024)  admitted
+#       sum  15.6      D 1600 recompute (512, 512)         admitted
+#       sum  16.5      D 1024 recompute (128, 1024)        refused, wants 16.50
+#       sum  17.0      D 2048 recompute (128, 512)         refused, wants 16.72
+# dW's sum is the compiler's figure to within a few percent, so it is held
+# to the limit itself; dx keeps 2.5 MiB back for what is not summed.
+_VMEM_LIMIT = 16 << 20
+_DX_VMEM_BUDGET = _VMEM_LIMIT - (5 << 19)
+
+
+def _dx_vmem(bn: int, bv: int, d: int, stash: bool) -> int:
+    """dx: the score source (bf16 logits block, or the x block it recomputes
+    from), the weight block and the output block streamed, (bn, D) f32
+    accumulated."""
+    src = bn * bv * 2 if stash else bn * d * 2
+    return 2 * (src + bv * d * 2 + bn * d * 2) + bn * d * 4
+
+
+def _dw_vmem(bn: int, bv: int, d: int, stash: bool) -> int:
+    """dW: the score source (bf16 logits block, or the weight block it
+    recomputes from), the x block and the f32 output block streamed, (bv, D)
+    f32 accumulated."""
+    src = bn * bv * 2 if stash else bv * d * 2
+    return 2 * (src + bn * d * 2 + bv * d * 4) + bv * d * 4
+
+
+def _auto_bv_dw(d_model: int, bn_dw: int = 512, stash: bool = True) -> int:
+    """dW vocab block: the largest power of two in 128..1024 whose kernel
+    fits (:func:`_dw_vmem`). A power of two >= the 128-lane tile because a
+    non-128-multiple (819 @ D=1280) breaks Mosaic tiling and a
+    non-power-of-two 128-multiple (e.g. 640) makes lcm(bv, bv_dw) inflate
+    the vocab pad by up to ~4% dead columns in every kernel. Recompute mode
+    streams the (bv_dw, D) weight block as well, so it gets half the block
+    stash mode gets from D = 1024 up."""
+    for bv in (1024, 512, 256):
+        if _dw_vmem(bn_dw, bv, d_model, stash) <= _VMEM_LIMIT:
+            return bv
+    return 128
+
+
+def _auto_blocks(n_tokens: int, d_model: int, n_vocab: int, bn: int,
+                 stash: bool, block_n: Optional[int] = None,
+                 block_v: Optional[int] = None):
+    """(bn, bv, bn_dw, bv_dw, bn_dx) for one backward strategy. fwd and dx
+    tile tokens wide (``bn``) and vocab narrow; dW the transpose; dx has a
+    token block of its own, halved until the kernel fits (an explicit
+    ``block_n`` / ``block_v`` is the caller's word and is kept)."""
+    if block_v is not None:
+        bv = bv_dw = block_v
+        bn_dw = block_n or bn
+    else:
+        bn_dw = min(512, bn)
+        if n_vocab >= 2048:
+            bv, bv_dw = 512, _auto_bv_dw(d_model, bn_dw, stash)
+        else:
+            bv = bv_dw = ((n_vocab + 127) // 128) * 128
+    if n_tokens % bn_dw != 0:  # possible only with an explicit non-power-of-2 bn
+        bn_dw = bn
+    bn_dx = bn
+    while (block_n is None and bn_dx % 32 == 0
+           and _dx_vmem(bn_dx, bv, d_model, stash) > _DX_VMEM_BUDGET):
+        bn_dx //= 2
+    return (bn, bv, bn_dw, bv_dw, bn_dx)
 
 
 def _pick_block(n: int, candidates) -> Optional[int]:
@@ -460,12 +521,12 @@ def fused_linear_cross_entropy(
     # 17.18 MiB (double-buffered x + stash streams + f32 score block + exp
     # temp) — 1.18 MiB over. One bf16 byte-pair of token-block per D column
     # (bn*D*2B <= 2 MiB) fits the fwd and dW kernels up to d_model 4096
-    # (gptj-6b). The dx kernel does not fit there at that block: compiled for
-    # a v5e at (N, D, V) = (2048, 4096, 50400) it wanted 16.50 MiB — 8 MiB of
-    # double-buffered (512, 4096) weight stream, 4 MiB of output stream and a
-    # 4 MiB f32 accumulator at bn=256 — so above D = 2048 dx halves its token
-    # block, and quarters it in recompute mode, below
-    # (tests/test_tpu_compile.py holds both modes to the limit).
+    # (gptj-6b). The backward kernels hold more (dx an f32 (bn, D) accumulator
+    # beside three streams, dW an f32 (bv, D) accumulator and output stream),
+    # so each sizes one block of its own from its VMEM sum: ``_dx_vmem`` /
+    # ``_dw_vmem`` above, with the readings they were set from
+    # (tests/test_tpu_compile.py holds both modes to the limit at every
+    # width the presets have).
     bn_cap = max((1 << 20) // max(D, 1), 128)  # 1024 @ D<=1024, 256 @ 4096
     bn = block_n or _pick_block(
         N, tuple(b for b in (2048, 1024, 512, 256, 128, 64, 32, 16, 8)
@@ -477,38 +538,21 @@ def fused_linear_cross_entropy(
         or (not interp and _use_interpret())
     ):
         return dense_fallback()
-    if block_v is not None:
-        bv = bv_dw = block_v
-        bn_dw = block_n or bn
-    elif V >= 2048:
-        bv = 512
-        bv_dw = _auto_bv_dw(D)
-        bn_dw = min(512, bn)
-    else:
-        bv = bv_dw = ((V + 127) // 128) * 128
-        bn_dw = min(512, bn)
-    if N % bn_dw != 0:  # possible only with an explicit non-power-of-2 bn
-        bn_dw = bn
+    x2 = x.reshape(N, D)
+    lab = labels.reshape(N, 1).astype(jnp.int32)
 
+    if stash is None:
+        at_stash = _auto_blocks(N, D, V, bn, True, block_n, block_v)
+        stash = N * _padded_vocab(V, at_stash) * 2 <= STASH_BYTES_MAX
+    blocks = _auto_blocks(N, D, V, bn, bool(stash), block_n, block_v)
     # Real TPU lowering needs lane-aligned vocab blocks (Mosaic tiles the
     # last dim in 128-lane units); _padded_vocab's LCM padding already makes
     # every grid tile Vp exactly, so misalignment — possible only with an
     # explicit non-128-multiple block_v — is the one way left to reach the
     # kernel with a shape the chip can't lower. Route it to dense. Interpret
     # mode (CPU numerics tests) has no such constraint.
-    if not interp and (bv % 128 != 0 or bv_dw % 128 != 0):
+    if not interp and (blocks[1] % 128 != 0 or blocks[3] % 128 != 0):
         return dense_fallback()
-    Vp = _padded_vocab(V, (bn, bv, bn_dw, bv_dw))
-
-    x2 = x.reshape(N, D)
-    lab = labels.reshape(N, 1).astype(jnp.int32)
-
-    if stash is None:
-        stash = N * Vp * 2 <= STASH_BYTES_MAX
-    # recompute mode also streams the (bn, D) x block into dx
-    shrink = 1 if D <= 2048 or block_n is not None else (2 if stash else 4)
-    bn_dx = bn // shrink if bn % (16 * shrink) == 0 else bn
-    blocks = (bn, bv, bn_dw, bv_dw, bn_dx)
 
     # f32 primal: a no-op for the zoo's f32 params; the compute-dtype cast
     # and vocab pad live inside _fused_ce so dW's dtype matches its primal

@@ -19,6 +19,7 @@ with a tolerance, never bitwise.
 from __future__ import annotations
 
 import re
+from functools import partial
 from typing import Any, Callable, Optional, Sequence, Tuple
 
 import jax
@@ -28,6 +29,8 @@ from jax import lax
 from jax.sharding import PartitionSpec as P
 
 from jax import shard_map
+
+from saturn_tpu.ops.pipeline import run_passes
 
 # Version tag for profile-cache fingerprints: bump when the overlapped
 # lowering changes shape (a serial profile must never price an overlapped
@@ -176,8 +179,15 @@ def zero3_loss_and_grads(
     prefetch: bool = True,
     remat: bool = False,
     min_size: int = 1024,
+    passes: int = 1,
+    between_fn: Optional[Callable[[Any, jax.Array], jax.Array]] = None,
 ):
     """(loss, grads) for one ZeRO-3 step with explicit, prefetchable gathers.
+
+    ``passes`` / ``between_fn``: a looped model (``hints["pipeline"]``'s
+    ``passes`` and ``between``) sends the activations through the whole
+    gathered stack ``passes`` times, ``between_fn(other, h)`` between one
+    pass and the next; each pass gathers every layer again.
 
     The block stack enters sharded per :func:`zero3_block_rules`; the scan
     over layers gathers each layer's shards with :func:`ring_all_gather`.
@@ -242,29 +252,35 @@ def zero3_loss_and_grads(
 
     blk = jax.checkpoint(block_fn) if remat else block_fn
 
+    def run_stack(stack, h):
+        if prefetch:
+            def body(carry, k):
+                hh, cur_full = carry
+                # Issue layer k+1's gather hops before layer k's
+                # compute: no data dependence, the DMA rides under it.
+                nxt = gather_layer(
+                    layer_shard(stack, jnp.minimum(k + 1, L - 1))
+                )
+                hh = blk(cur_full, hh)
+                return (hh, nxt), None
+
+            first = gather_layer(layer_shard(stack, 0))
+            (h_out, _), _ = lax.scan(body, (h, first), jnp.arange(L))
+        else:
+            def body(hh, k):
+                return blk(gather_layer(layer_shard(stack, k)), hh), None
+
+            h_out, _ = lax.scan(body, h, jnp.arange(L))
+        return h_out
+
     def local_fn(p, tok):
         def loss_of(pp):
             stack = pp[block_key]
             other = {k: v for k, v in pp.items() if k != block_key}
-            h = embed_fn(other, tok)
-            if prefetch:
-                def body(carry, k):
-                    hh, cur_full = carry
-                    # Issue layer k+1's gather hops before layer k's
-                    # compute: no data dependence, the DMA rides under it.
-                    nxt = gather_layer(
-                        layer_shard(stack, jnp.minimum(k + 1, L - 1))
-                    )
-                    hh = blk(cur_full, hh)
-                    return (hh, nxt), None
-
-                first = gather_layer(layer_shard(stack, 0))
-                (h_out, _), _ = lax.scan(body, (h, first), jnp.arange(L))
-            else:
-                def body(hh, k):
-                    return blk(gather_layer(layer_shard(stack, k)), hh), None
-
-                h_out, _ = lax.scan(body, h, jnp.arange(L))
+            h_out = run_passes(
+                partial(run_stack, stack), embed_fn(other, tok), passes,
+                between_fn and partial(between_fn, other),
+            )
             logits = head_fn(other, h_out)
             # LOCAL mean only: differentiating a psum'd scalar bakes the
             # psum transpose convention (identity vs psum — it changed

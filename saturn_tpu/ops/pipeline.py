@@ -718,3 +718,26 @@ def pipeline_hints(spec: Any) -> Dict[str, Any]:
             "(hints['pipeline'] with embed/block/head fns)"
         )
     return h
+
+
+def run_passes(
+    run_stack: Callable[[jax.Array], jax.Array],
+    h: jax.Array,
+    passes: int = 1,
+    between: Optional[Callable[[jax.Array], jax.Array]] = None,
+) -> jax.Array:
+    """``h`` through ``run_stack`` (the whole block stack, once) ``passes``
+    times, ``between`` applied between one pass and the next: the outer loop
+    of a looped model (``hints["pipeline"]``'s ``passes`` / ``between``) for
+    a technique that rebuilds the model from the hints. One pass is
+    ``run_stack(h)`` and nothing else; more are one ``lax.scan`` over the
+    passes, as in the model, so the stack is traced and compiled once."""
+    if passes == 1:
+        return run_stack(h)
+
+    def one_pass(x, t):
+        if between is not None:
+            x = lax.cond(t > 0, between, lambda v: v, x)
+        return run_stack(x), None
+
+    return lax.scan(one_pass, h, jnp.arange(passes))[0]

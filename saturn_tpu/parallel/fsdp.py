@@ -120,6 +120,8 @@ class FSDP(SPMDTechnique):
                 batch_axes=("data",),
                 prefetch=True,
                 remat=bool(config.get("remat", False)),
+                passes=spec.stack_passes,
+                between_fn=hints.get("between"),
             )
 
         return self.step_fns_from_loss_and_grads(

@@ -33,12 +33,13 @@ with default memory, so the streaming math stays covered everywhere.
 
 from __future__ import annotations
 
+from functools import partial
 from typing import Any, Dict, List, Optional, Tuple
 
 import jax
 from jax.sharding import PartitionSpec as P
 
-from saturn_tpu.ops.pipeline import pipeline_hints
+from saturn_tpu.ops.pipeline import pipeline_hints, run_passes
 from saturn_tpu.parallel import sharding as shr
 from saturn_tpu.parallel.fsdp import host_offload_supported
 from saturn_tpu.parallel.spmd_base import SPMDTechnique
@@ -122,6 +123,9 @@ class HostOffload(SPMDTechnique):
         hints = pipeline_hints(spec)
         bkey = spec.hints.get("block_param_key", "blocks")
         embed_fn, block_fn, head_fn = hints["embed"], hints["block"], hints["head"]
+        # a looped model: the layer loop gets the model's own outer loop,
+        # every pass streams the stack in again
+        passes, between_fn = spec.stack_passes, hints.get("between")
 
         def forward(params, tokens):
             other = {k: v for k, v in params.items() if k != bkey}
@@ -134,7 +138,10 @@ class HostOffload(SPMDTechnique):
 
             if config.get("remat", True):
                 body = jax.checkpoint(body, prevent_cse=False)
-            x, _ = jax.lax.scan(body, x, params[bkey])
+            x = run_passes(
+                lambda h: jax.lax.scan(body, h, params[bkey])[0], x, passes,
+                between_fn and partial(between_fn, other_dev),
+            )
             return head_fn(other_dev, x)
 
         return self.step_fns_from_forward(

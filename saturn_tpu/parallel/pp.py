@@ -185,6 +185,14 @@ class Pipeline(SPMDTechnique):
 
     def make_step_fns(self, spec, task, config, mesh, ds):
         self._require_no_aux(spec)  # staged forward would drop an aux loss
+        if spec.stack_passes != 1:
+            # A looped stack would need the last stage's output (after the
+            # between-passes norm) fed back to the first stage, which no
+            # schedule of ops/pipeline.py has (ROADMAP.md, Reach, M7).
+            raise InfeasibleConfig(
+                f"pp: the model passes its stack {spec.stack_passes} times; "
+                f"the stage schedules run each block once"
+            )
         s = config.get("stages", 2)
         m = config.get("microbatches", 2 * s)
         schedule = str(config.get("schedule", "gpipe"))

@@ -923,11 +923,14 @@ class SPMDTechnique(BaseTechnique):
         n_configs = n_memory = n_error = 0
         first_error: Optional[str] = None
 
+        stack = self._stack_fields(task)
+
         def note(config, **fields):
             # one event per grid point: which variant measured what, which
             # did not fit, which raised — the winner alone hides the rest
             _metrics.event("trial_config", task=task.name, size=len(devices),
-                           technique=self.name, config=dict(config), **fields)
+                           technique=self.name, config=dict(config),
+                           **stack, **fields)
 
         for config in self.candidate_configs(task, len(devices)):
             n_configs += 1
@@ -941,7 +944,7 @@ class SPMDTechnique(BaseTechnique):
                 except InfeasibleConfig as e:
                     log.info("%s trial %s infeasible: %s", self.name, config, e)
                     note(config, infeasible=str(e))
-                    sp.set(outcome="infeasible")
+                    sp.set(outcome="infeasible", reason=str(e))
                     continue
                 except Exception as e:  # a broken config must not kill the sweep
                     # ...but a config that RAISED is not a config that lost:
@@ -987,6 +990,17 @@ class SPMDTechnique(BaseTechnique):
                 "first_error": first_error,
             }
         return best
+
+    @staticmethod
+    def _stack_fields(task: Any) -> Dict[str, int]:
+        """``stack_layers`` / ``stack_passes`` of the task's model for the
+        ``trial_config`` and ``task_interval`` events (nothing where the
+        model, or a stand-in for a ``ModelSpec``, does not say)."""
+        spec = task.get_model()
+        layers = getattr(spec, "stack_layers", None)
+        if layers is None:
+            return {}
+        return {"stack_layers": layers, "stack_passes": spec.stack_passes}
 
     def _profile_window(self, config: Dict[str, Any]) -> int:
         """K the trial should profile: steady-state execute() runs full
@@ -1458,6 +1472,7 @@ class SPMDTechnique(BaseTechnique):
             # them as optional).
             perf = {}
             if _metrics.enabled():
+                perf.update(self._stack_fields(task))
                 # the per-step trajectory (one scalar or (K,) per unit, all
                 # already computed: the readback above drained the queue)
                 perf["losses"] = [

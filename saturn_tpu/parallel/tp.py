@@ -117,6 +117,8 @@ class TensorParallel(SPMDTechnique):
                 batch_axes=("data", "model"),
                 prefetch=True,
                 remat=bool(config.get("remat", False)),
+                passes=spec.stack_passes,
+                between_fn=hints.get("between"),
             )
 
         return self.step_fns_from_loss_and_grads(

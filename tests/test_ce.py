@@ -407,3 +407,42 @@ class TestModelFusedLoss:
         for a, b in zip(flat_got, flat_ref):
             np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                        rtol=1e-3, atol=4e-4)
+
+
+# ------------------------------------------------ backward blocks (PR 28)
+# (tokens, d_model, vocab, bn, stash) -> (bn, bv, bn_dw, bv_dw, bn_dx). The
+# first four rows are the blocks the committed cells ran with before the rule
+# was a VMEM sum (d 1024 stash, d 4096 both modes, d 768); the rest are what
+# the sum gives where the fixed blocks were refused by the v5e's compiler
+# (tests/test_tpu_compile.py compiles each).
+AUTO_BLOCKS = [
+    ((4096, 1024, 50257, 1024, True), (1024, 512, 512, 1024, 1024)),
+    ((8192, 4096, 50400, 256, False), (256, 512, 256, 128, 64)),
+    ((2048, 4096, 50400, 256, True), (256, 512, 256, 128, 128)),
+    ((4096, 768, 50257, 1024, True), (1024, 512, 512, 1024, 1024)),
+    ((8192, 2048, 49152, 512, False), (512, 512, 512, 256, 256)),
+    ((8192, 1024, 50257, 1024, False), (1024, 512, 512, 512, 512)),
+    ((8192, 1600, 50257, 512, False), (512, 512, 512, 512, 512)),
+]
+
+
+@pytest.mark.parametrize("args,want", AUTO_BLOCKS,
+                         ids=[f"d{a[1]}-n{a[0]}-{'stash' if a[4] else 'recompute'}"
+                              for a, _ in AUTO_BLOCKS])
+def test_backward_blocks_follow_the_vmem_sum(args, want):
+    from saturn_tpu.ops import ce
+
+    got = ce._auto_blocks(*args)
+    assert got == want
+    bn, bv, bn_dw, bv_dw, bn_dx = got
+    assert ce._dx_vmem(bn_dx, bv, args[1], args[4]) <= ce._DX_VMEM_BUDGET
+    assert ce._dw_vmem(bn_dw, bv_dw, args[1], args[4]) <= ce._VMEM_LIMIT
+    # the next larger dx block would not fit, or is the fwd's own block
+    assert bn_dx == bn or ce._dx_vmem(2 * bn_dx, bv, args[1], args[4]) > ce._DX_VMEM_BUDGET
+
+
+def test_explicit_blocks_are_kept():
+    from saturn_tpu.ops import ce
+
+    assert ce._auto_blocks(8192, 4096, 50400, 256, False, block_n=256)[4] == 256
+    assert ce._auto_blocks(512, 64, 256, 128, True, block_v=128)[1::2] == (128, 128)

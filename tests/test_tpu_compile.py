@@ -68,7 +68,8 @@ def _flash_loss(q, k, v):
 
 @pytest.mark.parametrize("grad", [False, True], ids=["fwd", "grad"])
 @pytest.mark.parametrize(
-    "shape", [(8, 12, 512, 64), (8, 12, 1024, 64)], ids=["t512", "t1024"]
+    "shape", [(8, 12, 512, 64), (8, 12, 1024, 64), (2, 16, 4096, 128)],
+    ids=["t512", "t1024", "ouro-t4096-h128"],
 )
 def test_flash_attention_compiles_for_v5e(one_chip, real_lowering, shape, grad):
     sds = jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one_chip)
@@ -84,6 +85,13 @@ CE_SHAPES = {
     "gpt2-small": (4096, 768, 50257),
     "gpt2-xl": (8192, 1600, 50257),
     "gptj-6b": (2048, 4096, 50400),
+    # d 2048, the one width the other rows do not hold: 8192 tokens are over
+    # the stash threshold, so auto is recompute mode, where dx at its full
+    # 512-token block wanted 16.79 MiB and dW at a 512-row block 19.11 MiB
+    # (PR 28); d 1024 at 8192 tokens is PERF.md Findings 1's case (dx 19.17,
+    # dW 18.00 MiB) and falls out of the same rule
+    "ouro-2.6b": (8192, 2048, 49152),
+    "gpt2-medium-8k": (8192, 1024, 50257),
 }
 
 
@@ -144,3 +152,36 @@ def test_dp_step_with_sharded_fused_ce_compiles_for_v5e_2x2(
     text = bundle.lowered.compile().as_text()
     calls = [l for l in text.splitlines() if "tpu_custom_call" in l]
     assert sum("saturn_ce_" in l for l in calls) == 3
+
+
+@pytest.mark.parametrize("name, config", [
+    ("fsdp", {"remat": True, "offload": False, "overlap": True}),
+    ("tp", {"tp": 2, "remat": True, "zero": True, "overlap": True}),
+    ("offload", {"stream": True, "remat": True}),
+])
+def test_looped_stack_outside_the_model_compiles_for_v5e_2x2(
+        topo, tmp_path, name, config):
+    """The techniques that rebuild the model from ``hints["pipeline"]`` give a
+    looped model its outer loop themselves (``ops/pipeline.py::run_passes``:
+    a scan over the passes around the layer scan, ring gathers and host
+    streaming inside). None of them has met a looped model on a chip (PR 28:
+    one chip, ``dp``); the chip's compiler at least takes the programs."""
+    from saturn_tpu import HParams, Task
+    from saturn_tpu.data.lm_dataset import make_lm_dataset
+    from saturn_tpu.models.gpt2 import build_ouro
+    from saturn_tpu.models.loss import pretraining_loss
+    from saturn_tpu.parallel import BUILTIN_TECHNIQUES
+
+    task = Task(
+        get_model=lambda **kw: build_ouro("ouro-test-tiny", **kw),
+        get_dataloader=lambda: make_lm_dataset(
+            context_length=64, batch_size=4, vocab_size=256, n_tokens=64 * 4 * 4),
+        loss_fn=pretraining_loss,
+        hparams=HParams(lr=1e-3, batch_count=2),
+        name=f"compile-looped-{name}",
+        save_dir=str(tmp_path),
+    )
+    tech = BUILTIN_TECHNIQUES[name]()
+    assert config in tech.candidate_configs(task, 4)
+    bundle = tech._build_uncached(task, list(topo.devices), dict(config))
+    assert "while" in bundle.lowered.compile().as_text()
