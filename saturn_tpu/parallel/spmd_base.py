@@ -31,6 +31,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from saturn_tpu.core.mesh import make_submesh
 from saturn_tpu.core.technique import BaseTechnique, InfeasibleConfig
 from saturn_tpu.parallel import sharding as shr
+from saturn_tpu.utils import aot_cache
 from saturn_tpu.utils import checkpoint as ckpt
 from saturn_tpu.utils import metrics as _metrics
 from saturn_tpu.utils.timing import (
@@ -160,8 +161,6 @@ class _Bundle:
         restart or re-admission of a previously-seen program deserializes
         instead of recompiling."""
         if self._compiled is None:
-            from saturn_tpu.utils import aot_cache
-
             self._compiled = aot_cache.load_or_compile(
                 self.lowered, self._block_devices()
             )
@@ -224,8 +223,6 @@ class _Bundle:
             )
             if diag is not None:
                 log.warning("%s", diag.message)
-        from saturn_tpu.utils import aot_cache
-
         compiled = aot_cache.load_or_compile(
             fused.lower(self.state_shapes, window_sds), self._block_devices()
         )
@@ -311,7 +308,9 @@ class SPMDTechnique(BaseTechnique):
 
     def search_report(self, task_name: str, size: int) -> Optional[Dict[str, Any]]:
         """Pop the report of the most recent ``search`` of (task, size): how
-        many configs there were, how many XLA's memory analysis rejected, how
+        many configs there were, how many XLA's memory analysis rejected or
+        the compiler refused for memory (of those, ``refusals_fresh`` by a
+        compile and ``refusals_replayed`` from ``aot_cache``'s records), how
         many raised (``errors``, with ``first_error``), and whether memory
         alone made the point infeasible. None when no search ran."""
         with self._reports_lock:
@@ -921,6 +920,7 @@ class SPMDTechnique(BaseTechnique):
         best: Tuple[Optional[Dict[str, Any]], Optional[float]] = (None, None)
         best_hf = 0.0
         n_configs = n_memory = n_error = 0
+        refusals = {"fresh": 0, "recorded": 0}
         first_error: Optional[str] = None
 
         stack = self._stack_fields(task)
@@ -946,6 +946,19 @@ class SPMDTechnique(BaseTechnique):
                     note(config, infeasible=str(e))
                     sp.set(outcome="infeasible", reason=str(e))
                     continue
+                except aot_cache.CompileRefused as e:
+                    # The chip's compiler refusing the program for memory
+                    # (just now, or on record from an earlier compile) is
+                    # the memory check's verdict, not a config that raised.
+                    log.info("%s trial %s for task %s refused by the "
+                             "compiler (%s): %s", self.name, config,
+                             task.name, e.refusal, e.first_line)
+                    n_memory += 1
+                    refusals[e.refusal] += 1
+                    note(config, memory_rejected=True, refusal=e.refusal,
+                         compiler=e.first_line)
+                    sp.set(outcome="refused", refusal=e.refusal)
+                    continue
                 except Exception as e:  # a broken config must not kill the sweep
                     # ...but a config that RAISED is not a config that lost:
                     # on the chip a kernel variant that fails to lower would
@@ -957,10 +970,7 @@ class SPMDTechnique(BaseTechnique):
                     if first_error is None:
                         first_error = f"{self.name} {config}: {e!r}"
                     note(config, error=repr(e))
-                    # the chip's compiler refusing the program for memory is
-                    # the memory check's verdict arriving as an exception
-                    sp.set(outcome="refused" if "RESOURCE_EXHAUSTED" in repr(e)
-                           else "error")
+                    sp.set(outcome="error")
                     continue
                 if timed is None:  # _try_config returns None only on the memory check
                     n_memory += 1
@@ -977,9 +987,9 @@ class SPMDTechnique(BaseTechnique):
             if best[1] is not None:
                 self._host_fracs[(task.name, len(devices))] = best_hf
             # Memory is the binding constraint only when EVERY candidate was
-            # rejected by XLA memory analysis — a mesh/divisibility error in
-            # any config means smaller sizes might still work, so monotone
-            # pruning must not engage.
+            # rejected by XLA memory analysis or refused by the compiler for
+            # memory — a mesh/divisibility error in any config means smaller
+            # sizes might still work, so monotone pruning must not engage.
             self._search_reports[(task.name, len(devices))] = {
                 "memory_infeasible": (
                     best[1] is None and n_configs > 0 and n_memory == n_configs
@@ -988,6 +998,8 @@ class SPMDTechnique(BaseTechnique):
                 "memory_rejected": n_memory,
                 "errors": n_error,
                 "first_error": first_error,
+                "refusals_fresh": refusals["fresh"],
+                "refusals_replayed": refusals["recorded"],
             }
         return best
 
@@ -1096,10 +1108,10 @@ class SPMDTechnique(BaseTechnique):
     def _spanned_compile(name: str, bundle: _Bundle, k: int, parent=None):
         """The bundle's K-step window program (``k > 1``) or its 1-step
         program, compiled at most once per bundle, under a span that says
-        whether this call was the one that compiled it (``was_warm``) and
-        whether the AOT cache gave the executable (``aot``)."""
-        from saturn_tpu.utils import aot_cache
-
+        whether this call was the one that compiled it (``was_warm``),
+        whether the AOT cache gave the executable (``aot``) and, where the
+        compiler refused the program for memory, whether it did so now or
+        on record (``refusal`` = ``fresh`` / ``recorded``)."""
         def aot_hits() -> int:
             stats = aot_cache.stats()
             return stats["hits"] + stats["warm_hits"]
@@ -1109,7 +1121,11 @@ class SPMDTechnique(BaseTechnique):
                 sp.set(was_warm=bool(bundle.has_fused(k) if k > 1
                                      else bundle._compiled is not None))
                 before = aot_hits()
-            out = bundle.fused_compiled(k) if k > 1 else bundle.compiled
+            try:
+                out = bundle.fused_compiled(k) if k > 1 else bundle.compiled
+            except aot_cache.CompileRefused as e:
+                sp.set(refusal=e.refusal)
+                raise
             if _metrics.enabled():
                 sp.set(aot="hit" if aot_hits() > before else "miss")
             return out

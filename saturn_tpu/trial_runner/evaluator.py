@@ -113,14 +113,19 @@ def search(
     cached as permanently infeasible.
 
     Returns sweep stats ``{"trials_run", "cache_hits", "pruned",
-    "interpolated", "dispatch", "fused_groups", "errors", "first_error"}`` —
+    "interpolated", "dispatch", "fused_groups", "errors", "first_error",
+    "refusals_fresh", "refusals_replayed"}`` —
     the online admission controller uses ``trials_run`` to distinguish warm
     (zero-trial) from cold arrivals. ``errors`` counts the candidate configs
     (and whole trials, past their retry budget) that *raised* instead of
     measuring or failing the memory check, and ``first_error`` is the repr
     of the first of them (None when ``errors == 0``): the sweep completes
     either way, but a kernel variant that could not lower is then a number
-    the caller can assert on, not a line at INFO.
+    the caller can assert on, not a line at INFO. A config the chip's
+    compiler refuses for memory is not among them: it failed the memory
+    check. ``refusals_fresh`` counts those the compiler refused in this
+    sweep, ``refusals_replayed`` those a record of an earlier refusal
+    answered without a compile (``utils/aot_cache``, "Refusal records").
     """
     if log:
         logging.basicConfig(level=logging.INFO)
@@ -324,6 +329,9 @@ def _search_inner(
     # Configs (or whole trials) that RAISED, as opposed to measuring slower
     # or not fitting: the sweep goes on, but the caller gets the count.
     errors = {"n": 0, "first": None}
+    # Configs the chip's compiler refused for memory: by a compile, or from
+    # the record of an earlier one (``utils/aot_cache``, "Refusal records").
+    refusals = {"refusals_fresh": 0, "refusals_replayed": 0}
 
     def note_errors(n: int, first: Optional[str]) -> None:
         with update_lock:
@@ -456,6 +464,10 @@ def _search_inner(
             report = reporter(task.name, g)
         if report and report.get("errors"):
             note_errors(int(report["errors"]), report.get("first_error"))
+        if report:
+            with update_lock:
+                for k in refusals:
+                    refusals[k] += int(report.get(k, 0))
         if params is None or per_batch_time is None:
             memory_bound = bool(report and report.get("memory_infeasible"))
             logger.info("trial (%s, g=%d, %s): infeasible%s", task.name, g, name,
@@ -692,6 +704,7 @@ def _search_inner(
         "fused_groups": fused_groups,
         "errors": errors["n"],
         "first_error": errors["first"],
+        **refusals,
     }
 
 

@@ -32,29 +32,61 @@ Environment:
 - ``SATURN_TPU_PROFILE_CACHE=0`` (the global profile-cache kill switch)
   disables it too, since it lives inside that directory.
 - ``SATURN_TPU_PROFILE_CACHE_DIR`` moves the root (the ``aot/`` subdir).
+
+**Refusal records** (PR 29). A program the chip's compiler refuses for
+memory (``RESOURCE_EXHAUSTED``: HBM over capacity, an allocation over
+memory, a Mosaic kernel over its scoped VMEM) is the one compile outcome
+JAX's persistent compilation cache does not keep, so every search paid for
+it again in full. :func:`load_or_compile` keeps it: one small JSON file
+``<compile cache directory>/saturn-refused/<key>.json`` with the compiler's
+message, and the next compile of the same program raises
+:class:`CompileRefused` with that message without calling the compiler.
+
+- *Where*: inside JAX's persistent compilation cache directory, and on
+  exactly when that cache is on
+  (``profile_cache.maybe_enable_persistent_compile_cache()`` returns the
+  directory; no switch of its own, independent of ``SATURN_TPU_AOT_CACHE``).
+  Deleting the compile cache deletes the refusals.
+- *Key*: the content hash of the program's own text, as for the executables,
+  **without** the block's device ids (the verdict does not depend on which
+  chips) and **with** the backend's ``platform_version`` (libtpu),
+  ``XLA_FLAGS`` and ``LIBTPU_INIT_ARGS``: a repaired kernel, a new block
+  size, a new JAX or libtpu all miss, so a refusal cannot outlive its cause.
+- *What is kept*: only what ``lowered.compile()`` itself raised. A
+  ``RESOURCE_EXHAUSTED`` from *running* a program (an init, a step) depends
+  on what else is on the chip and is never recorded; no other exception is.
+  An unreadable or malformed record is a miss, never an error.
 """
 
 from __future__ import annotations
 
 import hashlib
+import json
 import logging
 import os
 import pickle
 import platform
+import re
 import threading
-from typing import Any, Optional
+from typing import Any, List, NamedTuple, Optional
 
 log = logging.getLogger("saturn_tpu")
 
 _ENV_TOGGLE = "SATURN_TPU_AOT_CACHE"
 _SUBDIR = "aot"
+_REFUSED_SUBDIR = "saturn-refused"
+#: What the compiler's three memory refusals have in common (HBM over
+#: capacity, an allocation over memory, a kernel over its scoped VMEM).
+_REFUSAL_MARK = "RESOURCE_EXHAUSTED"
+_MESSAGE_CAP = 8192
 
 #: Bump when the payload layout changes meaning — old entries then miss.
 SCHEMA_VERSION = 1
 
 _stats_lock = threading.Lock()
 _stats = {"hits": 0, "misses": 0, "stores": 0, "errors": 0,
-          "prewarms": 0, "warm_hits": 0}
+          "prewarms": 0, "warm_hits": 0,
+          "refusals_fresh": 0, "refusals_replayed": 0}
 
 # In-process warm pool fed by the compile-ahead service
 # (``tenancy.compile_ahead``): executables compiled in the background
@@ -63,6 +95,31 @@ _stats = {"hits": 0, "misses": 0, "stores": 0, "errors": 0,
 # consulted even when the on-disk cache is disabled (CPU default).
 _warm_lock = threading.Lock()
 _warm: dict = {}
+
+
+class CompileRefused(RuntimeError):
+    """The chip's compiler refused the program for memory.
+
+    Carries the compiler's own message (so text that looks for
+    ``RESOURCE_EXHAUSTED`` still finds it). ``refusal`` says whether the
+    compiler said so just now (``"fresh"``) or a record of an earlier
+    compile of the same program did (``"recorded"``); ``program`` is the
+    module's name (``jit_saturn_window``).
+    """
+
+    def __init__(self, message: str, refusal: str,
+                 program: Optional[str] = None):
+        super().__init__(message)
+        self.refusal = refusal
+        self.program = program
+
+    @property
+    def first_line(self) -> str:
+        """The message's first line that says something, shortened."""
+        for line in str(self).splitlines():
+            if line.strip():
+                return line.strip()[:300]
+        return ""
 
 
 def stats() -> dict:
@@ -142,6 +199,45 @@ def _runtime_identity() -> str:
     )
 
 
+class _Program(NamedTuple):
+    """One pass over a lowered program's text: its content hash and name."""
+
+    digest: bytes
+    name: Optional[str]
+
+
+_MODULE_NAME = re.compile(r"\s*module\s+@([\w.$-]+)")
+
+
+def _program(lowered: Any) -> Optional[_Program]:
+    """The text of ``lowered`` hashed once (None = it has no text)."""
+    try:
+        text = lowered.as_text()
+    except Exception:
+        return None
+    m = _MODULE_NAME.match(text)
+    return _Program(hashlib.sha256(text.encode()).digest(),
+                    m.group(1) if m else None)
+
+
+def _keyed(prog: _Program, *parts: str) -> str:
+    """``prog``'s content hash under ``parts`` (what else the entry depends
+    on), as a file name."""
+    h = hashlib.sha256()
+    for part in parts:
+        h.update(part.encode())
+        h.update(b"\x00")
+    h.update(prog.digest)
+    return h.hexdigest()
+
+
+def _executable_key(prog: _Program, devices: Any) -> str:
+    if devices is None:
+        return _keyed(prog, _runtime_identity())
+    ids = ",".join(str(getattr(d, "id", i)) for i, d in enumerate(devices))
+    return _keyed(prog, _runtime_identity(), f"block:{ids}")
+
+
 def cache_key(lowered: Any, devices: Any = None) -> Optional[str]:
     """Content key for a ``jit(...).lower(...)`` result; None = uncacheable.
 
@@ -154,21 +250,90 @@ def cache_key(lowered: Any, devices: Any = None) -> Optional[str]:
     program pinned to a different block would silently run on the wrong
     chips.
     """
+    prog = _program(lowered)
+    return None if prog is None else _executable_key(prog, devices)
+
+
+# ------------------------------------------------------------ refusal records
+def _compiler_identity() -> List[str]:
+    """What can change the compiler's verdict on one program text and is not
+    in :func:`_runtime_identity`: the backend's own version (libtpu) and the
+    two variables that hand flags to XLA and to libtpu."""
+    import jax
+
     try:
-        text = lowered.as_text()
+        version = str(jax.devices()[0].client.platform_version)
     except Exception:
+        version = "?"
+    return [
+        f"platform_version:{version}",
+        "XLA_FLAGS:" + os.environ.get("XLA_FLAGS", ""),
+        "LIBTPU_INIT_ARGS:" + os.environ.get("LIBTPU_INIT_ARGS", ""),
+    ]
+
+
+def _refusal_path(prog: Optional[_Program]) -> Optional[str]:
+    """Where the refusal of ``prog`` is or would be recorded: inside JAX's
+    persistent compilation cache, so None wherever that cache is off."""
+    from saturn_tpu.utils import profile_cache as _pc
+
+    if prog is None:
         return None
-    h = hashlib.sha256()
-    h.update(_runtime_identity().encode())
-    h.update(b"\x00")
-    if devices is not None:
-        ids = ",".join(
-            str(getattr(d, "id", i)) for i, d in enumerate(devices)
-        )
-        h.update(f"block:{ids}".encode())
-        h.update(b"\x00")
-    h.update(text.encode())
-    return h.hexdigest()
+    root = _pc.maybe_enable_persistent_compile_cache()
+    if not root:
+        return None
+    key = _keyed(prog, _runtime_identity(), *_compiler_identity())
+    return os.path.join(root, _REFUSED_SUBDIR, f"{key}.json")
+
+
+def _read_refusal(path: str) -> Optional[str]:
+    """The recorded message, or None: no record, or one that cannot be
+    read as a refusal (a miss, never an error: the compiler is asked)."""
+    try:
+        with open(path, "r", encoding="utf-8") as f:
+            record = json.load(f)
+        message = record["message"]
+    except (OSError, ValueError, KeyError, TypeError):
+        return None
+    if not isinstance(message, str) or _REFUSAL_MARK not in message:
+        return None
+    return message
+
+
+def _write_refusal(path: str, prog: _Program, message: str) -> None:
+    import jax
+
+    record = {
+        "program": prog.name,
+        "message": message[:_MESSAGE_CAP],
+        "jax": jax.__version__,
+        "compiler": _compiler_identity(),
+    }
+    if not _write_atomic(path, json.dumps(record).encode()):
+        log.info("compile refusal of %s not recorded at %s", prog.name, path)
+
+
+def _compile(lowered: Any, prog: Optional[_Program]) -> Any:
+    """``lowered.compile()``, behind the refusal records."""
+    path = _refusal_path(prog)
+    if path is not None:
+        recorded = _read_refusal(path)
+        if recorded is not None:
+            _bump("refusals_replayed")
+            log.info("%s: the compiler refused this program before (%s); "
+                     "not compiled again", prog.name, path)
+            raise CompileRefused(recorded, "recorded", prog.name)
+    try:
+        return lowered.compile()
+    except Exception as e:
+        message = str(e)
+        if _REFUSAL_MARK not in message:
+            raise
+        _bump("refusals_fresh")
+        if path is not None:
+            _write_refusal(path, prog, message)
+        raise CompileRefused(
+            message, "fresh", prog.name if prog else None) from e
 
 
 def _path(key: str) -> str:
@@ -203,6 +368,24 @@ def _load(key: str, devices: Any = None) -> Optional[Any]:
         return None
 
 
+def _write_atomic(path: str, blob: bytes) -> bool:
+    """``blob`` whole under ``path`` or not there (temp file + rename: trial
+    threads run side by side, and so may processes); False = not written."""
+    tmp = f"{path}.tmp.{os.getpid()}.{threading.get_ident()}"
+    try:
+        os.makedirs(os.path.dirname(path), exist_ok=True)
+        with open(tmp, "wb") as f:
+            f.write(blob)
+        os.replace(tmp, path)
+    except OSError:
+        try:
+            os.unlink(tmp)
+        except OSError:
+            pass
+        return False
+    return True
+
+
 def _store(key: str, compiled: Any) -> bool:
     try:
         from jax.experimental.serialize_executable import serialize
@@ -213,18 +396,7 @@ def _store(key: str, compiled: Any) -> bool:
         _bump("errors")
         log.info("aot executable not serializable (%r) — caching skipped", e)
         return False
-    path = _path(key)
-    tmp = path + f".tmp.{os.getpid()}.{threading.get_ident()}"
-    try:
-        os.makedirs(cache_dir(), exist_ok=True)
-        with open(tmp, "wb") as f:
-            f.write(blob)
-        os.replace(tmp, path)
-    except OSError:
-        try:
-            os.unlink(tmp)
-        except OSError:
-            pass
+    if not _write_atomic(_path(key), blob):
         return False
     _bump("stores")
     return True
@@ -241,8 +413,14 @@ def load_or_compile(lowered: Any, devices: Any = None) -> Any:
     unchanged. One caveat: ``memory_analysis()`` may be unavailable on a
     deserialized executable; ``utils.timing.hbm_bytes_required`` already
     degrades that to "feasible, with a warning".
+
+    A compile the compiler refuses for memory raises :class:`CompileRefused`
+    and, where JAX's persistent compilation cache is on, is recorded beside
+    it; the next call for the same program raises from the record without
+    compiling (module docstring, "Refusal records").
     """
-    key = cache_key(lowered, devices)
+    prog = _program(lowered)
+    key = None if prog is None else _executable_key(prog, devices)
     if key is not None:
         # Compile-ahead warm pool first: same process, no load hazard,
         # works even where the disk cache is off (CPU default).
@@ -251,16 +429,14 @@ def load_or_compile(lowered: Any, devices: Any = None) -> Any:
         if warm is not None:
             _bump("warm_hits")
             return warm
-    if not enabled():
-        return lowered.compile()
-    if key is None:
-        return lowered.compile()
+    if not enabled() or key is None:
+        return _compile(lowered, prog)
     hit = _load(key, devices)
     if hit is not None:
         _bump("hits")
         return hit
     _bump("misses")
-    compiled = lowered.compile()
+    compiled = _compile(lowered, prog)
     _store(key, compiled)
     return compiled
 
