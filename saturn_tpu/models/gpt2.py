@@ -70,10 +70,10 @@ class GPT2Config:
     seq_overlap: bool = False
     # Single-program attention implementation: "dense" (XLA einsums), "flash"
     # (fused Pallas kernel, ops/flash.py), or "auto" (flash wherever the
-    # kernel can lower — measured on the v5e chip: 1.01x at seq 512, 1.42x at
-    # 1024, 1.97x at 2048, and dense OOMs first at long seq; BASELINE.md
-    # attention table). Ignored when seq_axis is set (sequence-parallel
-    # attention has its own kernels).
+    # kernel can lower — on one v5e chip at GPT-J widths 344.6 ms a batch
+    # against 378.9 ms for dense, PERF.md section 5, and dense is refused
+    # for memory first at long seq). Ignored when seq_axis is set
+    # (sequence-parallel attention has its own kernels).
     attention: str = "auto"
     # False = bidirectional (encoder / BERT-class) attention. Sequence-
     # parallel attention paths assume causal, so seq techniques are only
@@ -94,7 +94,8 @@ class GPT2Config:
     # showed the scan's dynamic-update-slice activation stashing dragging
     # the MLP matmul fusions to ~0.4-0.5 efficiency; unrolling lets XLA
     # address the stash statically. 1 = plain scan (smallest compile);
-    # measure before changing the default (benchmarks/profile_step.py).
+    # measure on the chip before changing the default (a traced run of a
+    # cell, perf/README.md).
     scan_unroll: int = 1
     # Looped-LM structure knobs (Ouro-class: one stack of layers run several
     # times on shared weights). Each at its default leaves every earlier

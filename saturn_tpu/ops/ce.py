@@ -5,8 +5,8 @@ config-#1 step (GPT-2-small b8x512 on v5e) was the logits pipeline: XLA
 materializes f32 logits (B,T,V) = 824 MiB for the loss, a bf16 stash for the
 backward, a separately-fused dlogits (softmax gradient) tensor, and three
 reduce/broadcast fusions over (B,T,V) — together ~10% of device time at zero
-FLOPs utilization, and the allocation that OOMs b8x2048 (BASELINE.md
-attention table). The reference hit the same wall differently: its 6B
+FLOPs utilization, and the allocation that runs a long sequence out of
+memory first. The reference hit the same wall differently: its 6B
 example shrank batch sizes until torch's unfused CE fit
 (``/root/reference/examples/wikitext103/WikiText103.py:62-71``).
 

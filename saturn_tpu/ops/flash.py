@@ -18,11 +18,12 @@ dK/dV kernel gridded over (kv block × query step) — recomputing
 P = exp(S - lse) from the forward's saved logsumexp.
 
 Used by the model zoo when ``GPT2Config.attention`` resolves to "flash" —
-which is the DEFAULT on TPU since the round-3 chip measurements
-(``benchmarks/attention_bench.py`` on v5e, GPT-2-small, fixed 4096 tokens:
-1.01x at seq 512, 1.42x at 1024, 1.97x at 2048, and dense OOMs first at
-b8×1024; BASELINE.md attention table). Numerics are validated against the
-dense reference in interpret mode on CPU (``tests/test_flash.py``).
+which is the DEFAULT on TPU: on one v5e chip at GPT-J widths (head 256,
+seq 2048 x batch 4) the search timed the flash point at 344.6 ms a batch
+against 378.9 ms for dense (PERF.md section 5, the GPT-J cell's search), and
+dense is the side the memory check refuses first as the sequence grows.
+Numerics are validated against the dense reference in interpret mode on CPU
+(``tests/test_flash.py``).
 """
 
 from __future__ import annotations
@@ -336,10 +337,9 @@ _flash_bh.defvjp(_flash_bh_fwd, _flash_bh_bwd)
 
 
 def _default_block(T: int) -> int:
-    """Largest power-of-two block ≤ 512 dividing T. 512 measured fastest on
-    v5e at seq 512 (block sweep, BASELINE.md attention table): bigger blocks
-    mean fewer grid programs and larger MXU matmuls; VMEM stays comfortable
-    (the f32 score block at 512² is 1 MiB)."""
+    """Largest power-of-two block ≤ 512 dividing T: bigger blocks mean fewer
+    grid programs and larger MXU matmuls; VMEM stays comfortable (the f32
+    score block at 512² is 1 MiB)."""
     for b in (512, 256, 128):
         if T % b == 0:
             return b

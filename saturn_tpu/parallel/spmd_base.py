@@ -637,8 +637,9 @@ class SPMDTechnique(BaseTechnique):
         flash entry there).
 
         On a one-chip block the grid is crossed with {flash, dense}, flash
-        first — it measured fastest at every seq on the chip (BASELINE.md) —
-        and the trial runner keeps whichever measures faster for THIS task:
+        first — on the chip it timed faster where both fit (344.6 against
+        378.9 ms a batch at GPT-J widths, PERF.md section 5) — and the trial
+        runner keeps whichever measures faster for THIS task:
         the empirically-selected-config premise of the whole system
         (``PerformanceEvaluator.py:101-115``).
 

@@ -10,8 +10,7 @@ quarantine/detach records went durable), and restarts the batch orchestrator
 against the same journal directory until the batch completes — exactly the
 operator's restart loop.
 
-What a campaign proves (asserted by ``tests/test_chaos.py`` and summarized
-by ``benchmarks/chaos_campaign.py``):
+What a campaign proves (asserted by ``tests/test_chaos.py``):
 
 - **zero lost jobs** — every task reaches ``completed`` across restarts;
 - **quarantine survives the kill** — the skip-list replayed from the

@@ -707,10 +707,10 @@ def solve(
 
     # Cheap native pass first (~0.1-0.2s at these sizes): its plan is a
     # guaranteed-feasible incumbent that (a) upper-bounds the MILP via a cut
-    # and (b) floors the result quality if HiGHS strikes out. Measured
-    # (benchmarks/solver_quality.py): at >= 8 tasks with rich option sets the
-    # exact solver rarely proves optimality inside a 30s budget and the
-    # native search often leads — combining them is never worse than either.
+    # and (b) floors the result quality if HiGHS strikes out. At >= 8 tasks
+    # with rich option sets the exact solver rarely proves optimality inside
+    # a 30s budget and the native search often leads — combining them is
+    # never worse than either.
     # Its cost (incl. a possible first-call g++ build) is deducted from the
     # caller's budget below so solve() never overruns time_limit.
     import time as _time

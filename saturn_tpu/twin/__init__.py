@@ -12,8 +12,9 @@ surface by advancing a simulated clock instead of running training steps.
 Modules:
 
 - ``clock``    — virtual time (``time.*`` patch) + deterministic event queue
-- ``arrivals`` — seeded Poisson + diurnal-burst arrival synthesis (shared
-  with ``benchmarks/online_arrivals.py`` so bench and twin cannot drift)
+- ``arrivals`` — seeded Poisson + diurnal-burst arrival synthesis (the
+  one generator: the live-service fidelity test in ``tests/test_twin.py``
+  draws its trace from it too)
 - ``fleet``    — virtual devices/slices and seeded per-slice failure
   schedules
 - ``oracle``   — static cost/memory model: prior-built strategies, no chips
@@ -22,8 +23,7 @@ Modules:
 - ``trace``    — journal → arrival trace loading + fidelity comparison
 - ``runner``   — the campaign loop mirroring ``SaturnService._run_loop``
 
-Entry points: ``python -m saturn_tpu.analysis twin`` (campaign CLI view)
-and ``benchmarks/twin_scale.py`` (the 100k-job scale + fidelity rows).
+Entry point: ``python -m saturn_tpu.analysis twin`` (campaign CLI view).
 """
 
 from saturn_tpu.twin.arrivals import Arrival, arrival_stream  # noqa: F401

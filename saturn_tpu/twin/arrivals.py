@@ -1,10 +1,10 @@
 """Seeded Poisson + diurnal-burst arrival synthesis.
 
-Extracted from ``benchmarks/online_arrivals.py`` so the twin and the bench
-draw the *same* trace from the same seed and can never drift. The generator
-consumes its RNG in exactly the order the bench's submit loop always did —
-one ``expovariate`` gap, then one ``randint`` priority, per arrival — so
-seed 7 still produces the historical gateway-bench trace draw for draw.
+One generator, so that the twin and a live service driven over the same
+trace (``tests/test_twin.py::TestRealServiceFidelity``) draw the *same*
+arrivals from the same seed and can never drift. The generator consumes its
+RNG in a fixed order — one ``expovariate`` gap, then one ``randint``
+priority, per arrival — so a seed reproduces its trace draw for draw.
 
 Traffic shape: a Poisson base rate modulated by periodic diurnal bursts —
 every ``burst_every`` arrivals, a window of ``burst_len`` arrivals comes in
@@ -19,7 +19,7 @@ import random
 from dataclasses import dataclass
 from typing import List, Mapping, Optional
 
-#: Diurnal-burst cycle defaults (historically the bench module constants).
+#: Diurnal-burst cycle defaults.
 BURST_EVERY = 50          # every 50 arrivals, a burst window opens...
 BURST_LEN = 20            # ...for 20 arrivals
 
@@ -52,8 +52,8 @@ def arrival_stream(n_jobs: int, *,
 
     ``tenant_mix`` maps tenant name → positive arrival weight: each
     arrival is tagged with a tenant drawn from the mix (a 10:1 weight
-    skew yields the noisy-neighbour traffic the fairness benchmarks
-    need). Tenant draws come from a *separate* RNG stream seeded as
+    skew yields the noisy-neighbour traffic the fairness tests need).
+    Tenant draws come from a *separate* RNG stream seeded as
     ``f"{seed}:tenant"`` so the primary gap/priority draw order — one
     ``expovariate`` plus one ``randint`` per arrival — is untouched:
     adding tenants to a historical seed reproduces the historical trace

@@ -123,8 +123,8 @@ class StaticOracle:
 
     ``flat_per_batch_s`` switches to trace-replay mode: every strategy gets
     that constant per-batch time with ``static_prior=False`` — mirroring
-    the gateway bench's pre-profiled tasks, so a replayed bench trace is
-    costed the way the real run was.
+    a live run's pre-profiled tasks, so a replayed trace is costed the way
+    the real run was.
     """
 
     def __init__(self, fleet, seed: int = 0, n_families: int = 16,

@@ -1,7 +1,7 @@
 """Trace loading + fidelity comparison: journals from real runs feed the twin.
 
 ``load_trace`` folds a durability journal (written by a real
-``SaturnService`` run — e.g. the gateway bench with ``durability_dir`` set)
+``SaturnService`` run with ``durability_dir`` set)
 into an arrival trace plus the run's *reference distributions*: admission
 verdict mix and, when a metrics file rode along, ``solver_tier`` shares.
 Multi-incarnation journals are handled by
@@ -12,8 +12,7 @@ still replays as one valid trace.
 ``fidelity_compare`` is the calibrated-instrument check: the twin replays
 the trace and its tier shares / verdict mix / makespan must agree with
 journaled reality within the documented band (see ``DEFAULT_BAND`` — the
-values asserted by ``tests/test_twin.py`` and reported by
-``benchmarks/twin_scale.py``).
+values asserted by ``tests/test_twin.py``).
 """
 
 from __future__ import annotations

@@ -498,17 +498,3 @@ class TestAnalysisSchemaInFingerprints:
 
         ident = aot_cache._runtime_identity()
         assert f"lint{analysis.SCHEMA_VERSION}" in ident
-
-
-class TestBenchGuardGate:
-    def test_bench_plan_verifies(self):
-        import importlib.util
-
-        spec = importlib.util.spec_from_file_location(
-            "bench_guard",
-            os.path.join(os.path.dirname(__file__), "..", "benchmarks",
-                         "bench_guard.py"),
-        )
-        mod = importlib.util.module_from_spec(spec)
-        spec.loader.exec_module(mod)
-        assert mod.bench_plan_errors({"value": 1.0}) == []

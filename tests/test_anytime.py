@@ -1,5 +1,5 @@
 """Anytime tier-ladder tests: deadline races, tier equivalence vs the exact
-MILP, verifier compliance on randomized instances, and the scaling smoke.
+MILP, and verifier compliance on randomized instances.
 
 Hardware-free (solver consumes only numbers), same layer as
 ``test_solver.py``; the randomized-instance sweep reuses the
@@ -8,11 +8,8 @@ many random instances, run every tier, and hold each output to the same
 ``plan_verifier`` gate the orchestrator enforces at adoption.
 """
 
-import json
 import os
 import random
-import subprocess
-import sys
 
 import pytest
 
@@ -21,8 +18,6 @@ from saturn_tpu.core.mesh import SliceTopology
 from saturn_tpu.core.strategy import Strategy
 from saturn_tpu.solver import anytime, milp
 from saturn_tpu.utils import metrics
-
-REPO = os.path.join(os.path.dirname(os.path.abspath(__file__)), "..")
 
 
 class FakeDev:
@@ -360,25 +355,3 @@ class TestSweepVerifier:
         # and be far sparser than the dense pairwise form
         n_edges = sum(len(v) for v in plan.dependencies.values())
         assert n_edges < len(plan.assignments) * 8
-
-
-@pytest.mark.solver
-@pytest.mark.perf
-class TestScalingSmoke:
-    """The quick-mode scaling bench end-to-end: 500 jobs through the real
-    gateway + service, zero deadline misses, schema-valid row."""
-
-    def test_quick_mode_row(self):
-        r = subprocess.run(
-            [sys.executable, os.path.join(REPO, "benchmarks",
-                                          "solver_scaling.py")],
-            capture_output=True, text=True, timeout=300,
-        )
-        assert r.returncode == 0, (r.stdout, r.stderr)
-        row = json.loads(r.stdout.strip().splitlines()[-1])
-        sys.path.insert(0, os.path.join(REPO, "benchmarks"))
-        import bench_guard
-        assert bench_guard.validate_solver_row(row) == []
-        assert row["deadline_misses"] == 0
-        assert row["quality_delta_pct"] <= 10.0
-        assert row["resolves"] >= 3

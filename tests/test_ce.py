@@ -388,8 +388,8 @@ class TestModelFusedLoss:
         # Gradients through shard_map with replicated params (the psum
         # transpose): must match the unsharded fused grads (round-3 advisor
         # low finding — value-only coverage). On CPU the kernel falls back
-        # to dense, so the TPU-pallas-under-shard_map case stays a chip-run
-        # checklist item (BASELINE.md).
+        # to dense, so the TPU-pallas-under-shard_map case is for the chip
+        # (``chip_smoke.py --chips 4`` runs fsdp on four chips against one).
         ref_val, ref_grads = jax.value_and_grad(spec.fused_loss_fn)(
             params, tokens
         )

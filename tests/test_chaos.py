@@ -1,9 +1,8 @@
-"""Chaos campaign harness (round 13): seeded schedules, row schema, and the
-ISSUE's acceptance sweep.
+"""Chaos campaign harness (round 13): seeded schedules and the ISSUE's
+acceptance sweep.
 
-The fast half is hardware-free: schedule determinism/coverage, the
-``compare_checkpoints`` bit-identity primitive, and the benchmark row schema
-guard. The slow half runs the real acceptance campaign — three seeded
+The fast half is hardware-free: schedule determinism/coverage and the
+``compare_checkpoints`` bit-identity primitive. The slow half runs the real acceptance campaign — three seeded
 mixed-fault sweeps (one per health-fault class each) over two tiny GPT-2
 jobs, the first seed killed at the ``post-rollback`` journal barrier — and
 asserts zero lost jobs, quarantine surviving the kill via journal replay,
@@ -11,7 +10,6 @@ and byte-identical final checkpoints against a fault-free reference run
 with the campaign's quarantine pre-applied.
 """
 
-import importlib.util
 import os
 
 import numpy as np
@@ -27,17 +25,6 @@ from saturn_tpu.resilience.chaos import (
 from saturn_tpu.resilience.faults import FaultKind
 
 pytestmark = pytest.mark.chaos
-
-REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-
-
-def _bench_guard():
-    spec = importlib.util.spec_from_file_location(
-        "bench_guard_chaos", os.path.join(REPO, "benchmarks", "bench_guard.py")
-    )
-    m = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(m)
-    return m
 
 
 # ----------------------------------------------------------------- schedule
@@ -115,53 +102,6 @@ class TestCompareCheckpoints:
         self._save(a, "junk", w=np.ones(2))
         self._save(b, "job", w=np.zeros(2))
         assert compare_checkpoints(a, b, names=["job"]) == []
-
-
-# ---------------------------------------------------------------- row schema
-class TestChaosRowSchema:
-    GOOD = {
-        "metric": "chaos_campaign",
-        "seeds": [11, 23, 47],
-        "fault_classes": ["numeric_nan", "loss_spike", "batch_poison",
-                          "dispatch_stall"],
-        "jobs": 6,
-        "jobs_lost": 0,
-        "restarts": 1,
-        "quarantined_batches": 3,
-        "makespan_inflation": 1.2,
-        "trajectory_bit_identical": True,
-        "sentinel_overhead_pct": 0.4,
-        "platform": "cpu",
-        "status": "ok",
-    }
-
-    def test_good_row_passes(self):
-        assert _bench_guard().validate_chaos_row(dict(self.GOOD)) == []
-
-    def test_missing_key_flagged(self):
-        row = dict(self.GOOD)
-        del row["jobs_lost"]
-        assert any("jobs_lost" in p for p in
-                   _bench_guard().validate_chaos_row(row))
-
-    def test_bool_in_count_field_flagged(self):
-        row = dict(self.GOOD, jobs_lost=False)
-        assert any("is bool" in p for p in
-                   _bench_guard().validate_chaos_row(row))
-
-    def test_too_few_seeds_or_classes_flagged(self):
-        m = _bench_guard()
-        assert any("fewer than 3 seeds" in p for p in
-                   m.validate_chaos_row(dict(self.GOOD, seeds=[1, 2])))
-        assert any(
-            "fewer than 4 fault classes" in p for p in
-            m.validate_chaos_row(
-                dict(self.GOOD, fault_classes=["numeric_nan"])
-            )
-        )
-
-    def test_non_dict_rejected(self):
-        assert _bench_guard().validate_chaos_row([1, 2]) != []
 
 
 # --------------------------------------------------------------- acceptance

@@ -653,66 +653,6 @@ class TestCLI:
         assert "saturn-tsan" in src and "static_pass" in src
 
 
-class TestBenchGuardRefusal:
-    def test_env_instrumented_run_refused(self, monkeypatch, capsys):
-        import importlib.util
-        import os
-
-        repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-        spec = importlib.util.spec_from_file_location(
-            "bench_guard", os.path.join(repo, "benchmarks", "bench_guard.py")
-        )
-        bg = importlib.util.module_from_spec(spec)
-        spec.loader.exec_module(bg)
-
-        monkeypatch.setenv("SATURN_TPU_TSAN", "1")
-        monkeypatch.setattr(bg, "latest_record", lambda: (1, {"value": 100.0}))
-        monkeypatch.setattr(
-            bg, "run_bench",
-            lambda: (_ for _ in ()).throw(AssertionError("must not run")),
-        )
-        rc = bg.main()
-        out = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
-        assert rc == 1 and out["status"] == "tsan_instrumented"
-
-    def test_stamped_row_refused(self, monkeypatch, capsys):
-        import importlib.util
-        import os
-
-        repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-        spec = importlib.util.spec_from_file_location(
-            "bench_guard2", os.path.join(repo, "benchmarks", "bench_guard.py")
-        )
-        bg = importlib.util.module_from_spec(spec)
-        spec.loader.exec_module(bg)
-
-        monkeypatch.delenv("SATURN_TPU_TSAN", raising=False)
-        monkeypatch.setattr(bg, "latest_record", lambda: (1, {"value": 100.0}))
-        monkeypatch.setattr(
-            bg, "run_bench", lambda: {"value": 120.0, "tsan": True},
-        )
-        rc = bg.main()
-        out = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
-        assert rc == 1 and out["status"] == "tsan_instrumented"
-
-    def test_tsan_reference_rows_never_baseline(self, monkeypatch, tmp_path):
-        import importlib.util
-        import os
-
-        repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-        spec = importlib.util.spec_from_file_location(
-            "bench_guard3", os.path.join(repo, "benchmarks", "bench_guard.py")
-        )
-        bg = importlib.util.module_from_spec(spec)
-        spec.loader.exec_module(bg)
-
-        (tmp_path / "BENCH_r1.json").write_text(json.dumps(
-            {"parsed": {"value": 500.0, "tsan": True}}
-        ))
-        monkeypatch.setattr(bg, "REPO", str(tmp_path))
-        assert bg.latest_record() is None
-
-
 class TestTracedPrimitives:
     def test_factories_return_plain_types_when_off(self):
         import queue as queue_mod

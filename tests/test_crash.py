@@ -50,8 +50,13 @@ class RecordingTech(BaseTechnique):
         self.per_batch = per_batch
         self.calls = []
         self.lock = threading.Lock()
+        # Closed by a test that must arm a kill before any batch runs: no
+        # execute starts while it is clear, however slow the test's thread.
+        self.gate = threading.Event()
+        self.gate.set()
 
     def execute(self, task, devices, tid, override_batch_count=None):
+        self.gate.wait()
         with self.lock:
             self.calls.append((task.name, override_batch_count or 1))
         time.sleep(self.per_batch * (override_batch_count or 1))
@@ -426,7 +431,15 @@ class TestOrchestrateResume:
 
 # --------------------------------------------------------------- acceptance
 class TestKillReplayAcceptance:
-    TOTALS = {"job-a": 90, "job-b": 90, "job-c": 60, "job-d": 60}
+    # Sized against the forecast's ceiling of interval / per_batch = 50
+    # batches a job an interval (a slower host runs fewer, never more), so
+    # that every kill below finds work left whatever the host's speed: at
+    # most 50 a job are durable after the first kill (its second interval
+    # is lost), so at least 60 remain and the second incarnation needs two
+    # intervals, of which the second cannot become durable past a kill at
+    # its second fsync; so the third still has an interval to run, whose
+    # end is the post-checkpoint crossing.
+    TOTALS = {"job-a": 140, "job-b": 140, "job-c": 110, "job-d": 110}
     PRIORITIES = {"job-a": 0.0, "job-b": 1.0, "job-c": 2.0, "job-d": 3.0}
 
     def _provider(self, tech):
@@ -447,11 +460,22 @@ class TestKillReplayAcceptance:
             crash_barrier=barrier,
         )
 
+    @staticmethod
+    def _kill(inj, svc, tech):
+        """Arm the injector, and only then let batches run: the crossings
+        that count all come after this call, on a loaded host too."""
+        inj.arm()
+        tech.gate.set()
+        run_to_kill(inj, svc)
+        assert svc.killed
+        tech.gate.clear()
+
     def test_kill_replay_no_lost_jobs_no_rerun_iterations(self, tmp_path):
         from saturn_tpu.service import ServiceClient
 
         wal = str(tmp_path / "wal")
         tech = RecordingTech(per_batch=0.004)
+        tech.gate.clear()
 
         # ---- incarnation 1: submit 4 mixed-priority jobs, kill mid-interval
         inj = CrashInjector("mid-interval", hit=2, armed=False)
@@ -465,29 +489,27 @@ class TestKillReplayAcceptance:
                 priority=self.PRIORITIES[name],
                 spec={"sizes": [2]},
             )
-        run_to_kill(inj, svc)
-        assert svc.killed
+        self._kill(inj, svc, tech)
 
         # ---- incarnation 2: recover, kill mid-fsync (tears the journal)
         inj2 = CrashInjector("mid-fsync", hit=2, armed=False)
         svc2 = self._service(wal, tech, inj2.barrier)
         svc2.start()
-        run_to_kill(inj2, svc2)
-        assert svc2.killed
+        self._kill(inj2, svc2, tech)
 
         # the torn tail is quarantined on the NEXT open, not fatal
-        # ---- incarnation 3: recover, kill post-checkpoint (hit 1: the
-        # remaining work may fit one interval)
+        # ---- incarnation 3: recover, kill post-checkpoint (hit 1: what is
+        # left may fit one interval)
         inj3 = CrashInjector("post-checkpoint", hit=1, armed=False)
         svc3 = self._service(wal, tech, inj3.barrier)
         assert svc3.journal.recovery_report["quarantined"], (
             "mid-fsync tear must leave a quarantined sidecar"
         )
         svc3.start()
-        run_to_kill(inj3, svc3)
-        assert svc3.killed
+        self._kill(inj3, svc3, tech)
 
         # ---- final incarnation: no injector, run everything to completion
+        tech.gate.set()
         svc4 = self._service(wal, tech)
         svc4.start()
         client4 = ServiceClient(svc4)

@@ -164,7 +164,8 @@ class TestHostOffload:
         """VERDICT r3 item 4 (CPU side): the offload streaming path at a
         REAL billion-class d_model (gptj-1b3's 2048, layer count cut to 2)
         builds and takes a step — keeps the >=1B configuration covered off
-        chip; benchmarks/billion_scale.py runs the full-depth chip row."""
+        chip (no cell of the benchmark runs the offload path yet: PERF.md,
+        Open question 6)."""
         import jax
 
         from saturn_tpu import HParams, Task
@@ -197,7 +198,7 @@ class TestHostOffload:
     def test_cross_technique_switch_from_offload(self, tiny_task, devices8):
         """Offload -> DP technique switch at an interval boundary (on the CPU
         test mesh state is device-resident — real pinned_host placement is
-        TPU-only and covered by the TPU bench/verify drives)."""
+        TPU-only: a chip drive's to show)."""
         from saturn_tpu.parallel.offload import HostOffload
 
         off, dp = HostOffload(), DataParallel()
@@ -227,8 +228,8 @@ class TestAttentionAutotune:
             assert any(c.get("attention") == "flash" for c in grid)
             assert any(c.get("attention") == "dense" for c in grid)
             assert all("attention" in c for c in grid)
-            # flash precedes its dense twin per base config (chip-measured
-            # fastest; BASELINE.md attention table)
+            # flash precedes its dense twin per base config (on the chip it
+            # timed faster where both fit; PERF.md section 5)
             flash_idx = min(
                 i for i, c in enumerate(grid) if c.get("attention") == "flash"
             )

@@ -155,12 +155,11 @@ def test_audit_point_fires_both_directions():
 # ------------------------------------------------- traced-technique behavior
 @pytest.fixture()
 def dp_traced(tiny_task, devices8):
-    from saturn_tpu import library as lib
+    # by name from the package's own table, not from the library's registry:
+    # other test files of the same worker deregister techniques
+    from saturn_tpu.parallel import BUILTIN_TECHNIQUES
 
-    if not lib.registered_names():
-        lib.register_default_library()
-    cls = lib.retrieve("dp")
-    tech = cls() if isinstance(cls, type) else cls
+    tech = BUILTIN_TECHNIQUES["dp"]()
     config = tech.candidate_configs(tiny_task, 4)[0]
     return tech, tech.trace_step(tiny_task, devices8[:4], config)
 
@@ -352,3 +351,101 @@ class TestPipelineStashResidency:
         analytic = (ml_passes.pipeline_stash_bytes("gpipe", S, M, unit)
                     - ml_passes.pipeline_stash_bytes("1f1b", S, M, unit))
         assert 0.5 * analytic <= gap <= 4.0 * analytic, (gap, analytic)
+
+
+# ------------------------------------ the static prune vs the compiled check
+PRUNE_SIZE = 4
+#: The second task: test-tiny's vocabulary and sequence at about thirty times
+#: the parameter bytes, so that one capacity stands far from both peaks.
+PRUNE_BIG = dict(d_model=256, n_layers=4)
+
+
+def _prune_task(save_dir, name, big):
+    from saturn_tpu import HParams, Task
+    from saturn_tpu.data.lm_dataset import make_lm_dataset
+    from saturn_tpu.models.gpt2 import build_gpt2
+    from saturn_tpu.models.loss import pretraining_loss
+
+    overrides = dict(PRUNE_BIG) if big else {}
+    return Task(
+        get_model=lambda **kw: build_gpt2("test-tiny", **{**overrides, **kw}),
+        get_dataloader=lambda: make_lm_dataset(
+            context_length=64, batch_size=8, vocab_size=256,
+            n_tokens=64 * 8 * 8),
+        loss_fn=pretraining_loss,
+        hparams=HParams(lr=1e-3, batch_count=8),
+        chip_range=[PRUNE_SIZE],
+        name=name,
+        save_dir=save_dir,
+    )
+
+
+@pytest.fixture(scope="module")
+def prune_setup(tmp_path_factory):
+    """(topology, the two tasks' static peaks, a capacity between them: the
+    geometric mean, a factor of about five from either peak and so far
+    outside the prune margin and the compiled check's headroom, whatever
+    the static model's calibration)."""
+    import math
+
+    from saturn_tpu.core.mesh import SliceTopology
+    from saturn_tpu.parallel import BUILTIN_TECHNIQUES
+
+    topo = SliceTopology(jax.devices())
+    tech = BUILTIN_TECHNIQUES["dp"]()
+    devices = topo.blocks(PRUNE_SIZE)[0].devices_of(topo.devices)
+    peaks = {}
+    for case in ("fits", "oom"):
+        task = _prune_task(str(tmp_path_factory.mktemp(f"peak-{case}")),
+                           f"peak-{case}", big=case == "oom")
+        config = tech.candidate_configs(task, PRUNE_SIZE)[0]
+        peaks[case] = ml_passes.predict_profile(
+            tech, task, devices, config).peak_bytes
+    return topo, peaks, int(math.sqrt(peaks["fits"] * peaks["oom"]))
+
+
+@pytest.mark.parametrize("case", ["fits", "oom"])
+def test_static_prune_never_contradicts_the_compiled_check(
+        case, prune_setup, tmp_path, monkeypatch):
+    """Under one capacity the search is asked about each task with the
+    static prune on and, where it pruned, again with it off, so that the
+    compiled memory check gives its own verdict on the same grid point. A
+    point memlens prunes before lowering must be one the compiled check
+    rejects, and a point the compiled check rejects must not be one memlens
+    placed under the headroom margin."""
+    import json
+
+    import saturn_tpu
+    from saturn_tpu import library
+
+    topo, peaks, capacity = prune_setup
+    monkeypatch.setenv(ml_passes.ENV_CAPACITY, str(capacity))
+    monkeypatch.setattr(library, "_REGISTRY", dict(library._REGISTRY))
+    library.register_default_library()
+
+    def sweep(prune):
+        monkeypatch.setenv("SATURN_TPU_MEMLENS_PRUNE", "1" if prune else "0")
+        tag = f"{case}-{'on' if prune else 'off'}"
+        path = str(tmp_path / f"{tag}.jsonl")
+        task = _prune_task(str(tmp_path / tag), tag, big=case == "oom")
+        saturn_tpu.search([task], technique_names=["dp"], topology=topo,
+                          profile_cache=False, metrics_path=path)
+        events = [json.loads(l) for l in open(path) if l.strip()]
+        pruned = [e for e in events if e.get("kind") == "trial_pruned"
+                  and e.get("reason") == "memlens_static"]
+        rejected = [e for e in events if e.get("kind") == "trial"
+                    and e.get("memory_infeasible")]
+        return task, pruned, rejected
+
+    task, pruned, rejected = sweep(prune=True)
+    if case == "fits":
+        # not pruned, so this one search also holds the compiled verdict
+        assert not pruned and not rejected
+        assert task.feasible_strategies()
+    else:
+        assert len(pruned) == 1 and not rejected  # refused, never lowered
+        assert not task.feasible_strategies()
+        task, pruned, rejected = sweep(prune=False)
+        assert not pruned and len(rejected) == 1  # the compiler agrees
+    blessed = peaks[case] <= ml_passes.HEADROOM_MARGIN * capacity
+    assert not (rejected and blessed), (peaks, capacity)

@@ -261,7 +261,7 @@ class TestInterleavedEncodeCache:
 
 class TestCorpusGen:
     """WikiText-scale corpus synthesis (data/corpus_gen.py) — small sizes
-    here; benchmarks/tokenizer_bench.py runs the 100MB+ flow."""
+    here."""
 
     def test_generates_requested_size_and_type_count(self, tmp_path):
         from saturn_tpu.data.corpus_gen import generate_corpus

@@ -362,12 +362,9 @@ class TestTracePasses:
 
 class TestTraceStepIntegration:
     def test_dp_trace_yields_gradient_all_reduce(self, tiny_task, devices8):
-        from saturn_tpu import library as lib
+        from saturn_tpu.parallel import BUILTIN_TECHNIQUES
 
-        if not lib.registered_names():
-            lib.register_default_library()
-        cls = lib.retrieve("dp")
-        tech = cls() if isinstance(cls, type) else cls
+        tech = BUILTIN_TECHNIQUES["dp"]()
         config = tech.candidate_configs(tiny_task, 4)[0]
         traced = tech.trace_step(tiny_task, devices8[:4], config)
         for key in ("jaxpr", "state_shapes", "state_specs", "batch_spec",
@@ -634,18 +631,3 @@ class TestCLI:
                             self._fake_audit(report))
         assert cli.main(["shardflow"]) == 1
         capsys.readouterr()
-
-
-class TestBenchGuard:
-    def test_bench_shardflow_errors_clean_on_tree(self):
-        import importlib.util
-        import os
-
-        import saturn_tpu
-
-        repo = os.path.dirname(os.path.dirname(saturn_tpu.__file__))
-        spec = importlib.util.spec_from_file_location(
-            "bench_guard", os.path.join(repo, "benchmarks", "bench_guard.py"))
-        mod = importlib.util.module_from_spec(spec)
-        spec.loader.exec_module(mod)
-        assert mod.bench_shardflow_errors() == []

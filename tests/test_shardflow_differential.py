@@ -143,12 +143,11 @@ def moe_task(tmp_path):
 
 
 def _technique(name):
-    from saturn_tpu import library as lib
+    # by name from the package's own table, not from the library's registry:
+    # other test files of the same worker deregister techniques
+    from saturn_tpu.parallel import BUILTIN_TECHNIQUES
 
-    if not lib.registered_names():
-        lib.register_default_library()
-    cls = lib.retrieve(name)
-    return cls() if isinstance(cls, type) else cls
+    return BUILTIN_TECHNIQUES[name]()
 
 
 def trace_and_compile(name, task, devices):

@@ -23,10 +23,8 @@ unpatched on purpose — so bit-identity is only guaranteed when every
 attempted tier finishes inside its budget on any host.
 """
 
-import importlib.util
 import json
 import os
-import sys
 import time
 import timeit
 import zlib
@@ -40,8 +38,6 @@ from saturn_tpu.twin.runner import CampaignConfig, run_campaign, run_what_if
 from saturn_tpu.twin.trace import DEFAULT_BAND, fidelity_compare, load_trace
 
 pytestmark = pytest.mark.twin
-
-REPO = os.path.join(os.path.dirname(os.path.abspath(__file__)), "..")
 
 
 @pytest.fixture(autouse=True)
@@ -75,13 +71,6 @@ def _campaign_bytes(out_dir):
         with open(os.path.join(out_dir, fn), "rb") as fh:
             out[fn] = fh.read()
     return out
-
-
-def _load(name, path):
-    spec = importlib.util.spec_from_file_location(name, path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
 
 
 # --------------------------------------------------------------------------
@@ -141,7 +130,7 @@ class TestVirtualClock:
 
 
 # --------------------------------------------------------------------------
-# arrivals (satellite: extracted generator, shared with the gateway bench)
+# arrivals (the one seeded generator: the twin's and the live run's below)
 # --------------------------------------------------------------------------
 class TestArrivals:
     def test_deterministic_across_calls(self):
@@ -170,17 +159,6 @@ class TestArrivals:
         with pytest.raises(ValueError):
             arrival_stream(1, base_rate_hz=1.0, burst_rate_hz=1.0,
                            burst_every=0)
-
-    def test_gateway_bench_imports_the_same_generator(self):
-        # The bench must consume the twin's generator, not a fork of it.
-        sys.path.insert(0, os.path.join(REPO, "benchmarks"))
-        try:
-            import online_arrivals
-        finally:
-            sys.path.pop(0)
-        assert online_arrivals.arrival_stream is arrival_stream
-        assert online_arrivals.BURST_EVERY == BURST_EVERY
-        assert online_arrivals.BURST_LEN == BURST_LEN
 
 
 # --------------------------------------------------------------------------
@@ -489,80 +467,105 @@ class TestTwinCLI:
 
 
 # --------------------------------------------------------------------------
-# bench guard: the twin_scale row schema + acceptance bars
-# --------------------------------------------------------------------------
-class TestTwinRowGuard:
-    GOOD = {
-        "metric": "twin_scale", "mode": "full", "n_jobs": 100_000,
-        "n_slices": 32, "chips": 256, "submitted": 100_000,
-        "scheduled": 100_000, "completed": 100_000, "failed": 0,
-        "evicted": 0, "shed": 0, "solves": 32, "deadline_misses": 0,
-        "tier_counts": {"1": 1, "2": 31}, "makespan_sim_s": 19200.0,
-        "wall_s": 131.1, "seed": 7,
-        "fidelity": {"within_band": True}, "status": "ok",
-    }
-
-    def _guard(self):
-        return _load("bench_guard_twin",
-                     os.path.join(REPO, "benchmarks", "bench_guard.py"))
-
-    def test_good_row_passes(self):
-        assert self._guard().validate_twin_row(dict(self.GOOD)) == []
-
-    def test_deadline_miss_fails(self):
-        row = dict(self.GOOD, deadline_misses=1)
-        assert any("deadline_misses" in p
-                   for p in self._guard().validate_twin_row(row))
-
-    def test_full_mode_scale_floor(self):
-        g = self._guard()
-        assert any("n_jobs" in p for p in g.validate_twin_row(
-            dict(self.GOOD, n_jobs=50_000, submitted=50_000,
-                 scheduled=50_000, completed=50_000)))
-        assert any("n_slices" in p for p in g.validate_twin_row(
-            dict(self.GOOD, n_slices=16)))
-        # Quick mode is exempt from the floor.
-        assert g.validate_twin_row(
-            dict(self.GOOD, mode="quick", n_jobs=2_000, submitted=2_000,
-                 scheduled=2_000, completed=2_000)) == []
-
-    def test_conservation_and_fidelity_bars(self):
-        g = self._guard()
-        assert any("limbo" in p for p in g.validate_twin_row(
-            dict(self.GOOD, completed=90_000)))
-        assert any("within_band" in p for p in g.validate_twin_row(
-            dict(self.GOOD, fidelity={"within_band": False})))
-        # An empty fidelity dict (phase skipped) is allowed.
-        assert g.validate_twin_row(dict(self.GOOD, fidelity={})) == []
-
-    def test_missing_keys_and_wrong_types(self):
-        g = self._guard()
-        row = dict(self.GOOD)
-        row.pop("tier_counts")
-        assert any("tier_counts" in p for p in g.validate_twin_row(row))
-        assert g.validate_twin_row([1, 2]) != []
-        assert any("bool" in p for p in g.validate_twin_row(
-            dict(self.GOOD, deadline_misses=False)))
-
-
-# --------------------------------------------------------------------------
 # the real-service fidelity regression (sockets + threads: slow tier)
 # --------------------------------------------------------------------------
+#: The live run's shape, which the twin's replay must mirror exactly: the
+#: same 8-chip mesh, 0.2 s interval (solve deadline = interval / 2), gateway
+#: window and pre-profiled flat per-batch cost. The window is small on
+#: purpose: the bursts overrun it, so the shed path is in the trace.
+LIVE_JOBS = 500
+LIVE_PER_BATCH_S = 0.004
+LIVE_WINDOW = 12
+LIVE_INTERVAL_S = 0.2
+LIVE_SEED = 7
+
+
+def _run_live_service(durability_dir, metrics_path):
+    """A live ``SaturnService`` behind its gateway, driven over the seeded
+    arrival trace (Poisson base rate, diurnal bursts); it leaves its journal
+    in ``durability_dir``. The client does not retry: a shed is counted, not
+    retried away. Returns (accepted, shed, makespan in seconds)."""
+    from saturn_tpu.core.mesh import SliceTopology
+    from saturn_tpu.service import (
+        GatewayClient, GatewayError, GatewayServer, SaturnService,
+    )
+    from saturn_tpu.service.gateway import protocol
+    from tests.test_crash import FakeTask, RecordingTech
+
+    # pre-profiled tasks (strategies filled): admission is the wire and the
+    # queue, not a profiling sweep
+    tech = RecordingTech(per_batch=LIVE_PER_BATCH_S)
+    svc = SaturnService(
+        topology=SliceTopology([object() for _ in range(8)]),
+        interval=LIVE_INTERVAL_S, poll_s=0.02, health_guardian=False,
+        task_provider=lambda p: FakeTask(
+            p["task"], p["remaining_batches"], [4, 8], tech,
+            pbt=LIVE_PER_BATCH_S),
+        metrics_path=metrics_path, durability_dir=durability_dir,
+    ).start()
+    gw = GatewayServer(svc, max_inflight=LIVE_WINDOW,
+                       max_inflight_per_session=16)
+    gw.start()
+    accepted, shed = [], 0
+    t0 = time.monotonic()
+    try:
+        with GatewayClient(*gw.address, session="live", seed=LIVE_SEED,
+                           timeout_s=30.0, max_attempts=1) as client:
+            for arr in arrival_stream(LIVE_JOBS, base_rate_hz=12.0,
+                                      burst_rate_hz=80.0, seed=LIVE_SEED):
+                time.sleep(arr.gap_s)
+                try:
+                    accepted.append(client.submit(
+                        name=f"online-{arr.index}", total_batches=2,
+                        priority=arr.priority, spec={"sizes": [4, 8]}))
+                except GatewayError as e:
+                    if e.code not in (protocol.GW_RETRY_AFTER,
+                                      protocol.GW_UNAVAILABLE):
+                        raise
+                    shed += 1
+            for jid in accepted:
+                assert client.wait(jid, timeout=300)["state"] == "DONE"
+        makespan = time.monotonic() - t0
+    finally:
+        gw.shutdown(timeout=10, reason="test-complete")
+        svc.stop(timeout=60)
+    return len(accepted), shed, makespan
+
+
 @pytest.mark.slow
 class TestRealServiceFidelity:
     def test_gateway_bench_journal_replays_within_band(self, tmp_path):
         """The full calibrated-instrument check: a real SaturnService run
         (sockets, threads, real engine stub) journals its arrivals; the twin
         replays that journal; tier shares / verdict mix / makespan agree
-        within ``DEFAULT_BAND``. This is exactly what
-        ``benchmarks/twin_scale.py``'s fidelity phase gates in CI."""
-        sys.path.insert(0, os.path.join(REPO, "benchmarks"))
-        try:
-            import twin_scale
+        within ``DEFAULT_BAND``."""
+        from saturn_tpu.twin.trace import tier_shares
 
-            row = twin_scale.run_fidelity_phase(str(tmp_path))
-        finally:
-            sys.path.pop(0)
-        assert row["metric"] == "twin_fidelity"
-        assert row["within_band"], row
-        assert row["deadline_misses"] == 0
+        durability_dir = str(tmp_path / "real-journal")
+        metrics_path = str(tmp_path / "real-metrics.jsonl")
+        accepted, shed, makespan = _run_live_service(
+            durability_dir, metrics_path)
+        assert accepted + shed == LIVE_JOBS and accepted > 0
+        real = {
+            "tier_shares": tier_shares(metrics_path),
+            "verdict_shares": load_trace(durability_dir).verdict_shares,
+            "makespan_s": makespan,
+        }
+        twin = run_campaign(
+            CampaignConfig(
+                trace_dir=durability_dir, n_slices=1, chips_per_slice=8,
+                interval_s=LIVE_INTERVAL_S,
+                solve_deadline_s=LIVE_INTERVAL_S / 2,
+                max_inflight=LIVE_WINDOW,
+                flat_per_batch_s=LIVE_PER_BATCH_S, metrics=False,
+                seed=LIVE_SEED,
+            ),
+            str(tmp_path / "twin-replay"),
+        )
+        cmp = fidelity_compare(
+            {k: twin[k] for k in ("tier_shares", "verdict_shares",
+                                  "makespan_s")},
+            real,
+        )
+        assert cmp["within_band"], (cmp, real)
+        assert twin["deadline_misses"] == 0

@@ -151,7 +151,7 @@ def main() -> int:
 
     if _have("ruff"):
         rc = _run([sys.executable, "-m", "ruff", "check", "saturn_tpu",
-                   "tests", "tools", "benchmarks"])
+                   "tests", "tools", "perf"])
         results["ruff"] = "ok" if rc == 0 else f"failed rc={rc}"
         failed |= rc != 0
     else:
