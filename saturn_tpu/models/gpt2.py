@@ -70,8 +70,8 @@ class GPT2Config:
     seq_overlap: bool = False
     # Single-program attention implementation: "dense" (XLA einsums), "flash"
     # (fused Pallas kernel, ops/flash.py), or "auto" (flash wherever the
-    # kernel can lower — on one v5e chip at GPT-J widths 344.6 ms a batch
-    # against 378.9 ms for dense, PERF.md section 5, and dense is refused
+    # kernel can lower — on one v5e chip at GPT-J widths 309.5 ms a batch
+    # against 343.8 ms for dense, PERF.md section 5, and dense is refused
     # for memory first at long seq). Ignored when seq_axis is set
     # (sequence-parallel attention has its own kernels).
     attention: str = "auto"

@@ -44,8 +44,10 @@ the identical objective through plain XLA ops
 
 from __future__ import annotations
 
+import contextlib
 import functools
-from typing import Any, Optional
+import threading
+from typing import Any, List, NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -189,7 +191,9 @@ def _dw_kernel(src_ref, x_ref, lab_ref, lse_ref, g_ref, dw_ref, acc_scr,
 # while dW tiles vocab wide and tokens narrow (its accumulator spans the
 # vocab block; x re-streams once per vocab row). dx has a token block of its
 # own because it alone holds an f32 (bn, D) accumulator beside the
-# double-buffered (bn, D) output and (bv, D) weight streams.
+# double-buffered (bn, D) output and (bv, D) weight streams. ``dx_vmem_limit``
+# is the scoped VMEM dx asks the compiler for where its block needs more than
+# the default (:func:`ce_plan`), else None.
 def _run_fwd(x, w_p, lab, block_n, block_v, n_vocab, interpret, stash):
     N, D = x.shape
     Vp = w_p.shape[0]
@@ -256,8 +260,8 @@ def _prep_w(w, x_dtype, Vp):
     return w_p
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
-def _fused_ce(x, w, lab, blocks, n_vocab, interpret, stash):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
+def _fused_ce(x, w, lab, blocks, n_vocab, interpret, stash, dx_vmem_limit):
     bn, bv = blocks[:2]
     w_p = _prep_w(w, x.dtype, _padded_vocab(n_vocab, blocks))
     _, loss, _ = _run_fwd(x, w_p, lab, bn, bv, n_vocab, interpret,
@@ -265,7 +269,7 @@ def _fused_ce(x, w, lab, blocks, n_vocab, interpret, stash):
     return loss
 
 
-def _fused_ce_fwd(x, w, lab, blocks, n_vocab, interpret, stash):
+def _fused_ce_fwd(x, w, lab, blocks, n_vocab, interpret, stash, dx_vmem_limit):
     bn, bv = blocks[:2]
     w_p = _prep_w(w, x.dtype, _padded_vocab(n_vocab, blocks))
     logits, loss, lse = _run_fwd(
@@ -274,7 +278,7 @@ def _fused_ce_fwd(x, w, lab, blocks, n_vocab, interpret, stash):
     return loss, (x, w_p, lab, logits, lse)
 
 
-def _fused_ce_bwd(blocks, n_vocab, interpret, stash, res, g):
+def _fused_ce_bwd(blocks, n_vocab, interpret, stash, dx_vmem_limit, res, g):
     _, block_v, bn_dw, bv_dw, block_n = blocks
     x, w_p, lab, logits, lse = res
     N, D = x.shape
@@ -290,6 +294,10 @@ def _fused_ce_bwd(blocks, n_vocab, interpret, stash, res, g):
         pl.BlockSpec((block_n, block_v), lambda nb, vb: (nb, vb))
         if stash else pl.BlockSpec((block_n, D), lambda nb, vb: (nb, 0))
     )
+    # under the default scoped limit the call is what it always was
+    dx_params = {} if dx_vmem_limit is None else {
+        "compiler_params": pltpu.CompilerParams(vmem_limit_bytes=dx_vmem_limit)
+    }
     dx = pl.pallas_call(
         functools.partial(
             _dx_kernel, block_n=block_n, block_v=block_v, n_vocab=n_vocab,
@@ -308,6 +316,7 @@ def _fused_ce_bwd(blocks, n_vocab, interpret, stash, res, g):
         scratch_shapes=[pltpu.VMEM((block_n, D), jnp.float32)],
         name="saturn_ce_dx",
         interpret=interpret,
+        **dx_params,
     )(dx_src, w_p, lab, lse, g)
 
     dw_src = logits if stash else w_p
@@ -343,33 +352,103 @@ _fused_ce.defvjp(_fused_ce_fwd, _fused_ce_bwd)
 
 
 # ------------------------------------------------------------------ public
-# What the v5e compiler puts in VMEM for one grid step of a backward kernel,
-# as far as shapes say it: every streamed block twice (double buffering) and
-# the f32 accumulator once. The f32 score block and its exp / ds temporaries
-# come on top and are not summed. Readings, each compiled for a described v5e
-# at bv = 512 (PR 28; "wants" is the compiler's own figure):
-#   dx  sum  13.0 MiB  D 2048 stash bn 512        admitted
-#       sum  12.25     D 4096 stash bn 128        admitted
-#       sum  12.0      D 1024 stash bn 1024       admitted
-#       sum  14.0      D 4096 recompute bn 128    refused, wants 16.34
-#       sum  14.0      D 1024 recompute bn 1024   refused, wants 19.17
-#       sum  16.0      D 2048 recompute bn 512    refused, wants 16.79
-#   dW  sum  16.0      D 1024 stash     (bn 512, bv 1024)  admitted
-#       sum  15.6      D 1600 recompute (512, 512)         admitted
-#       sum  16.5      D 1024 recompute (128, 1024)        refused, wants 16.50
-#       sum  17.0      D 2048 recompute (128, 512)         refused, wants 16.72
-# dW's sum is the compiler's figure to within a few percent, so it is held
-# to the limit itself; dx keeps 2.5 MiB back for what is not summed.
+# The chip the blocks are sized for: one TPU v5e core. 197 TFLOP/s in bf16
+# over 819 GB/s of HBM (Google Cloud documentation, "TPU v5e") is a ridge of
+# 240 operations per HBM byte; a core has 128 MiB of VMEM, of which Mosaic
+# gives one kernel 16 MiB unless the ``pallas_call`` asks for more
+# (``pltpu.CompilerParams(vmem_limit_bytes=...)``). dx asks (below); every
+# other kernel here is sized to the default.
 _VMEM_LIMIT = 16 << 20
-_DX_VMEM_BUDGET = _VMEM_LIMIT - (5 << 19)
+_VMEM_PHYSICAL = 128 << 20
+_VMEM_REQUEST_MAX = _VMEM_PHYSICAL // 2   # the most one kernel asks for
+_RIDGE_FLOP_PER_BYTE = 240
+
+# What the v5e compiler puts in VMEM for one grid step of a backward kernel,
+# as far as shapes say it: every streamed block twice (double buffering), the
+# f32 accumulator once and, for dx, the temporaries of one step. Readings,
+# each compiled for a described v5e at bv = 512 ("from" is the smallest
+# ``vmem_limit_bytes`` the compiler admits the kernel at, bisected to 64 KiB;
+# PR 31. PR 28's "wants" figures were the allocation the compiler stopped at,
+# 2-4 MiB under these):
+#   dx  sum 15.69 MiB  D 4096 recompute bn 64     from 15.17
+#       sum 19.38      D 4096 recompute bn 128    from 18.34
+#       sum 26.75      D 4096 recompute bn 256    from 25.64
+#       sum 41.50      D 4096 recompute bn 512    from 40.28
+#       sum 13.75      D 2048 recompute bn 256    from 13.15
+#       sum 21.50      D 2048 recompute bn 512    from 20.82
+#       sum 11.50      D 1024 recompute bn 512    from 10.95
+#       sum 20.00      D 1024 recompute bn 1024   from 19.21
+#       sum 17.75      D 1664 recompute bn 512    from 17.10
+#       sum 14.00      D 1600 recompute bn 512    from 10.58
+#       sum 12.38      D 4096 stash bn 128        from 12.28
+#       sum 16.75      D 4096 stash bn 256        from 16.69
+#       sum 25.50      D 4096 stash bn 512        from 25.50
+#       sum 13.00      D 1024 stash bn 1024       from 13.02
+#       sum 13.50      D 2048 stash bn 512        from 13.47
+#   dW  sum 16.0       D 1024 stash     (bn 512, bv 1024)  admitted (PR 28)
+#       sum 15.6       D 1600 recompute (512, 512)         admitted
+#       sum 16.5       D 1024 recompute (128, 1024)        refused
+#       sum 17.0       D 2048 recompute (128, 512)         refused
+# dx's sum is the compiler's figure to within 6 %, from above, at every width
+# that is a multiple of the 128-lane tile; at D 1600 the compiler lays the
+# (., D) blocks out another way and stays under even the streams' sum, so the
+# sum errs on the safe side there. dW's sum is within a few percent too (its
+# temporaries hide under its larger streams) and is held to the default limit.
+_DX_REQUEST_MARGIN = 1.125   # asked for over the sum, then rounded up to a MiB
 
 
 def _dx_vmem(bn: int, bv: int, d: int, stash: bool) -> int:
     """dx: the score source (bf16 logits block, or the x block it recomputes
     from), the weight block and the output block streamed, (bn, D) f32
-    accumulated."""
+    accumulated; then one step's temporaries: the f32 score block with its
+    exp / ds copies (half a block in stash mode, where the scores arrive in
+    bf16; a block and a half in recompute mode) and, in recompute mode at a
+    lane-aligned D, one more copy of each matmul operand block (the weight
+    block laid out for x.W^T, the token block)."""
     src = bn * bv * 2 if stash else bn * d * 2
-    return 2 * (src + bv * d * 2 + bn * d * 2) + bn * d * 4
+    streams = 2 * (src + bv * d * 2 + bn * d * 2) + bn * d * 4
+    if stash:
+        return streams + bn * bv * 2
+    operands = bv * d * 2 + bn * d * 2 if d % 128 == 0 else 0
+    return streams + operands + bn * bv * 6
+
+
+def _dx_compute_bound_block(stash: bool) -> int:
+    """The token block from which dx's own weight stream hides under its
+    matmuls: per (bv, D) weight block it streams 2 bv D bytes once and does
+    2 bn bv D operations a matmul pass (two passes in recompute mode), and
+    the block is the smallest power of two at which that is twice the chip's
+    ridge: 256 tokens in recompute mode, 512 in stash mode; 512 is the upper
+    end. Read on the chip, dx alone (``tools/ce_dx_blocks.py``; PR 31), ms at
+    bn 64 / 128 / 256 / 512 / 1024:
+      8192 x 4096 x 50400 recompute  70.50  37.41  35.35  35.06
+      8192 x 2048 x 49152 recompute                17.48  17.20
+      8192 x 1024 x 50257 recompute                 9.36   9.06   8.91
+      2048 x 4096 x 50400 stash              9.18   4.91   4.56
+    The smallest block within 2 % of the best of its row is the rule's in
+    every row (256, 256, 512 with what fits the default limit, 512)."""
+    passes = 1 if stash else 2
+    bn = 128
+    while passes * bn < 2 * _RIDGE_FLOP_PER_BYTE and bn < 512:
+        bn *= 2
+    return bn
+
+
+def _dx_vmem_limit(bn: int, bv: int, d: int, stash: bool) -> Optional[int]:
+    """What dx asks the compiler for: nothing while its sum is under the
+    default scoped limit, else the sum with a margin, in whole MiB."""
+    need = _dx_vmem(bn, bv, d, stash)
+    if need <= _VMEM_LIMIT:
+        return None
+    return -(-int(need * _DX_REQUEST_MARGIN) // (1 << 20)) << 20
+
+
+def _dx_block_under(bn: int, bv: int, d: int, stash: bool, limit: float) -> int:
+    """The largest halving of ``bn`` (16 at the least) whose sum is under
+    ``limit``."""
+    while bn % 32 == 0 and _dx_vmem(bn, bv, d, stash) > limit:
+        bn //= 2
+    return bn
 
 
 def _dw_vmem(bn: int, bv: int, d: int, stash: bool) -> int:
@@ -398,9 +477,13 @@ def _auto_blocks(n_tokens: int, d_model: int, n_vocab: int, bn: int,
                  stash: bool, block_n: Optional[int] = None,
                  block_v: Optional[int] = None):
     """(bn, bv, bn_dw, bv_dw, bn_dx) for one backward strategy. fwd and dx
-    tile tokens wide (``bn``) and vocab narrow; dW the transpose; dx has a
-    token block of its own, halved until the kernel fits (an explicit
-    ``block_n`` / ``block_v`` is the caller's word and is kept)."""
+    tile tokens wide (``bn``) and vocab narrow; dW the transpose. dx has a
+    token block of its own: the largest halving of ``bn`` that fits the
+    default scoped VMEM, raised to the block that keeps the kernel
+    compute-bound (:func:`_dx_compute_bound_block`) where ``n_tokens`` allows,
+    and halved again only where the request would pass the most a kernel
+    asks for (an explicit ``block_n`` / ``block_v`` is the caller's word and
+    is kept)."""
     if block_v is not None:
         bv = bv_dw = block_v
         bn_dw = block_n or bn
@@ -413,9 +496,13 @@ def _auto_blocks(n_tokens: int, d_model: int, n_vocab: int, bn: int,
     if n_tokens % bn_dw != 0:  # possible only with an explicit non-power-of-2 bn
         bn_dw = bn
     bn_dx = bn
-    while (block_n is None and bn_dx % 32 == 0
-           and _dx_vmem(bn_dx, bv, d_model, stash) > _DX_VMEM_BUDGET):
-        bn_dx //= 2
+    if block_n is None:
+        bn_dx = _dx_block_under(bn_dx, bv, d_model, stash, _VMEM_LIMIT)
+        target = _dx_compute_bound_block(stash)
+        while bn_dx < target and n_tokens % (2 * bn_dx) == 0:
+            bn_dx *= 2
+        bn_dx = _dx_block_under(bn_dx, bv, d_model, stash,
+                                _VMEM_REQUEST_MAX / _DX_REQUEST_MARGIN)
     return (bn, bv, bn_dw, bv_dw, bn_dx)
 
 
@@ -453,6 +540,93 @@ def dense_linear_cross_entropy(x, w, labels, *, ignore_index=-1):
 # HBM; above this, recompute mode drops ALL O(N·V) memory — the difference
 # between b8x2048 GPT-2 fitting on a v5e chip or not.
 STASH_BYTES_MAX = 512 * 1024 * 1024
+
+
+class CEPlan(NamedTuple):
+    """How one fused call runs: the five blocks, the backward's mode, each
+    backward kernel's VMEM sum and what dx asks the compiler for (None: the
+    default scoped limit). ``_asdict()`` is the ``ce_plan`` a ``trial_config``
+    event carries."""
+
+    bn: int
+    bv: int
+    bn_dw: int
+    bv_dw: int
+    bn_dx: int
+    mode: str                      # "stash" | "recompute"
+    dx_vmem: int
+    dw_vmem: int
+    dx_vmem_limit: Optional[int]
+
+    @property
+    def blocks(self):
+        return tuple(self[:5])
+
+
+def ce_plan(n_tokens: int, d_model: int, n_vocab: int, *,
+            stash: Optional[bool] = None, block_n: Optional[int] = None,
+            block_v: Optional[int] = None) -> Optional[CEPlan]:
+    """The plan :func:`fused_linear_cross_entropy` follows for these shapes
+    (pure: shapes in, plan out), or None where no token block tiles
+    ``n_tokens`` and the call computes through plain XLA ops.
+
+    fwd/dx tile tokens wide and vocab narrow, dW the transpose. One bf16
+    byte-pair of token block per D column (bn*D*2B <= 2 MiB) fits the fwd and
+    dW kernels under the default scoped VMEM up to d_model 4096 (gptj-6b; the
+    round-5 chip run measured the stash-mode fwd at bn=2048/bv=512/D=768 at
+    17.18 MiB). The backward kernels hold more and size one block each from
+    their own sums (``_dx_vmem`` / ``_dw_vmem`` above, with the readings they
+    were set from): dW's vocab block to the default limit, dx's token block
+    to what keeps it compute-bound, with the VMEM that takes asked for
+    (tests/test_tpu_compile.py compiles both modes at every width the
+    presets have)."""
+    bn_cap = max((1 << 20) // max(d_model, 1), 128)  # 1024 @ D<=1024, 256 @ 4096
+    bn = block_n or _pick_block(
+        n_tokens, tuple(b for b in (2048, 1024, 512, 256, 128, 64, 32, 16, 8)
+                        if b <= bn_cap)
+    )
+    if bn is None or n_tokens % bn != 0:  # an explicit block_n must tile N
+        return None
+    def blocks_at(stash_):
+        return _auto_blocks(n_tokens, d_model, n_vocab, bn, stash_,
+                            block_n, block_v)
+
+    if stash is None:
+        stash = (n_tokens * _padded_vocab(n_vocab, blocks_at(True)) * 2
+                 <= STASH_BYTES_MAX)
+    stash = bool(stash)
+    blocks = blocks_at(stash)
+    _, bv, bn_dw, bv_dw, bn_dx = blocks
+    return CEPlan(
+        *blocks, "stash" if stash else "recompute",
+        _dx_vmem(bn_dx, bv, d_model, stash),
+        _dw_vmem(bn_dw, bv_dw, d_model, stash),
+        _dx_vmem_limit(bn_dx, bv, d_model, stash),
+    )
+
+
+# Who traces a program asks here what its fused calls ran as: the plan of
+# every call traced by this thread inside the block, None for a call that
+# fell back to plain XLA ops (``parallel/spmd_base.py`` puts the first on the
+# grid point's ``trial_config`` event).
+_traced = threading.local()
+
+
+@contextlib.contextmanager
+def traced_plans():
+    outer = getattr(_traced, "plans", None)
+    plans: List[Optional[CEPlan]] = []
+    _traced.plans = plans
+    try:
+        yield plans
+    finally:
+        _traced.plans = outer
+
+
+def _note_plan(plan: Optional[CEPlan]) -> None:
+    plans = getattr(_traced, "plans", None)
+    if plans is not None:
+        plans.append(plan)
 
 
 def fused_linear_cross_entropy(
@@ -514,49 +688,27 @@ def fused_linear_cross_entropy(
         per_tok = _dense_per_token(x.reshape(N, D), w, lab1)
         return reduce(per_tok, lab1 != ignore_index)
 
-    # fwd/dx: wide token blocks, narrow vocab blocks; dW: the transpose.
-    # Sized so every kernel's VMEM residency (score block, accumulators,
-    # double-buffered streams) stays under the 16 MiB scoped-vmem limit. The
-    # round-5 chip run measured the stash-mode fwd at bn=2048/bv=512/D=768 at
-    # 17.18 MiB (double-buffered x + stash streams + f32 score block + exp
-    # temp) — 1.18 MiB over. One bf16 byte-pair of token-block per D column
-    # (bn*D*2B <= 2 MiB) fits the fwd and dW kernels up to d_model 4096
-    # (gptj-6b). The backward kernels hold more (dx an f32 (bn, D) accumulator
-    # beside three streams, dW an f32 (bv, D) accumulator and output stream),
-    # so each sizes one block of its own from its VMEM sum: ``_dx_vmem`` /
-    # ``_dw_vmem`` above, with the readings they were set from
-    # (tests/test_tpu_compile.py holds both modes to the limit at every
-    # width the presets have).
-    bn_cap = max((1 << 20) // max(D, 1), 128)  # 1024 @ D<=1024, 256 @ 4096
-    bn = block_n or _pick_block(
-        N, tuple(b for b in (2048, 1024, 512, 256, 128, 64, 32, 16, 8)
-                 if b <= bn_cap)
-    )
-    if (
-        bn is None
-        or N % bn != 0  # explicit block_n must tile N exactly
-        or (not interp and _use_interpret())
-    ):
-        return dense_fallback()
-    x2 = x.reshape(N, D)
-    lab = labels.reshape(N, 1).astype(jnp.int32)
-
-    if stash is None:
-        at_stash = _auto_blocks(N, D, V, bn, True, block_n, block_v)
-        stash = N * _padded_vocab(V, at_stash) * 2 <= STASH_BYTES_MAX
-    blocks = _auto_blocks(N, D, V, bn, bool(stash), block_n, block_v)
+    plan = ce_plan(N, D, V, stash=stash, block_n=block_n, block_v=block_v)
     # Real TPU lowering needs lane-aligned vocab blocks (Mosaic tiles the
     # last dim in 128-lane units); _padded_vocab's LCM padding already makes
     # every grid tile Vp exactly, so misalignment — possible only with an
     # explicit non-128-multiple block_v — is the one way left to reach the
     # kernel with a shape the chip can't lower. Route it to dense. Interpret
     # mode (CPU numerics tests) has no such constraint.
-    if not interp and (blocks[1] % 128 != 0 or blocks[3] % 128 != 0):
+    if plan is not None and not interp and (
+        _use_interpret() or plan.bv % 128 != 0 or plan.bv_dw % 128 != 0
+    ):
+        plan = None
+    _note_plan(plan)
+    if plan is None:
         return dense_fallback()
+    x2 = x.reshape(N, D)
+    lab = labels.reshape(N, 1).astype(jnp.int32)
 
     # f32 primal: a no-op for the zoo's f32 params; the compute-dtype cast
     # and vocab pad live inside _fused_ce so dW's dtype matches its primal
     per_tok = _fused_ce(
-        x2, w.astype(jnp.float32), lab, blocks, V, interp, stash,
+        x2, w.astype(jnp.float32), lab, plan.blocks, V, interp,
+        plan.mode == "stash", plan.dx_vmem_limit,
     )[:, 0]
     return reduce(per_tok, lab[:, 0] != ignore_index)

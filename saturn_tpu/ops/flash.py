@@ -19,8 +19,8 @@ P = exp(S - lse) from the forward's saved logsumexp.
 
 Used by the model zoo when ``GPT2Config.attention`` resolves to "flash" —
 which is the DEFAULT on TPU: on one v5e chip at GPT-J widths (head 256,
-seq 2048 x batch 4) the search timed the flash point at 344.6 ms a batch
-against 378.9 ms for dense (PERF.md section 5, the GPT-J cell's search), and
+seq 2048 x batch 4) the search timed the flash point at 309.5 ms a batch
+against 343.8 ms for dense (PERF.md section 5, the GPT-J cell's search), and
 dense is the side the memory check refuses first as the sequence grows.
 Numerics are validated against the dense reference in interpret mode on CPU
 (``tests/test_flash.py``).

@@ -142,6 +142,10 @@ class _Bundle:
     train_step: Any = None    # raw python step fn (fused scan re-traces it)
     batch_sds: Any = None     # ShapeDtypeStruct of one host batch
     retrace_key: Any = None   # stable (task, config, block) dispatch identity
+    # what each fused head+loss call of the step was traced as (ops/ce.py's
+    # plan; None for a call that fell back to plain XLA ops); empty where the
+    # step has no such call
+    ce_plans: Tuple[Any, ...] = ()
     _compiled: Any = None
     _fused: Dict[int, Any] = field(default_factory=dict)
     _fused_lock: Any = field(default_factory=threading.Lock)
@@ -637,8 +641,8 @@ class SPMDTechnique(BaseTechnique):
         flash entry there).
 
         On a one-chip block the grid is crossed with {flash, dense}, flash
-        first — on the chip it timed faster where both fit (344.6 against
-        378.9 ms a batch at GPT-J widths, PERF.md section 5) — and the trial
+        first — on the chip it timed faster where both fit (309.5 against
+        343.8 ms a batch at GPT-J widths, PERF.md section 5) — and the trial
         runner keeps whichever measures faster for THIS task:
         the empirically-selected-config premise of the whole system
         (``PerformanceEvaluator.py:101-115``).
@@ -766,7 +770,10 @@ class SPMDTechnique(BaseTechnique):
         batch_sds = jax.ShapeDtypeStruct(
             ds.example_batch().shape, ds.example_batch().dtype
         )
-        lowered = step.lower(state_shapes, batch_sds)
+        from saturn_tpu.ops import ce as _ce
+
+        with _ce.traced_plans() as ce_plans:
+            lowered = step.lower(state_shapes, batch_sds)
         return _Bundle(
             mesh=mesh,
             step=step,
@@ -777,6 +784,7 @@ class SPMDTechnique(BaseTechnique):
             lowered=lowered,
             train_step=train_step,
             batch_sds=batch_sds,
+            ce_plans=tuple(ce_plans),
         )
 
     # ------------------------------------------------------------- shardflow
@@ -931,7 +939,8 @@ class SPMDTechnique(BaseTechnique):
             # did not fit, which raised — the winner alone hides the rest
             _metrics.event("trial_config", task=task.name, size=len(devices),
                            technique=self.name, config=dict(config),
-                           **stack, **fields)
+                           **stack, **self._ce_plan_field(task, devices, config),
+                           **fields)
 
         for config in self.candidate_configs(task, len(devices)):
             n_configs += 1
@@ -1014,6 +1023,21 @@ class SPMDTechnique(BaseTechnique):
         if layers is None:
             return {}
         return {"stack_layers": layers, "stack_passes": spec.stack_passes}
+
+    def _ce_plan_field(self, task, devices, config) -> Dict[str, Any]:
+        """``ce_plan`` of a grid point whose loss is the fused one, for its
+        ``trial_config`` event: the blocks, the backward's mode, the backward
+        kernels' VMEM sums and what dx asked the compiler for, as the step
+        was traced (``ops/ce.py::ce_plan``); None where the op computed
+        through plain XLA ops (off-TPU, or no block tiles the tokens).
+        Nothing where the point's program was never built or holds no fused
+        call."""
+        with self._bundles_lock:
+            bundle = self._bundles.get(self._bundle_key(task, devices, config))
+        if bundle is None or not bundle.ce_plans:
+            return {}
+        plan = bundle.ce_plans[0]
+        return {"ce_plan": None if plan is None else plan._asdict()}
 
     def _profile_window(self, config: Dict[str, Any]) -> int:
         """K the trial should profile: steady-state execute() runs full

@@ -409,36 +409,97 @@ class TestModelFusedLoss:
                                        rtol=1e-3, atol=4e-4)
 
 
-# ------------------------------------------------ backward blocks (PR 28)
+# ------------------------------------------- backward blocks (PRs 28, 31)
 # (tokens, d_model, vocab, bn, stash) -> (bn, bv, bn_dw, bv_dw, bn_dx). The
-# first four rows are the blocks the committed cells ran with before the rule
-# was a VMEM sum (d 1024 stash, d 4096 both modes, d 768); the rest are what
-# the sum gives where the fixed blocks were refused by the v5e's compiler
-# (tests/test_tpu_compile.py compiles each).
+# first four rows are the shapes the committed cells ran before the rule was
+# a VMEM sum (d 1024 stash, d 4096 both modes, d 768); the next three are what
+# the sum gives where fixed blocks were refused by the v5e's compiler
+# (tests/test_tpu_compile.py compiles each). Since PR 31 dx's block is the one
+# that keeps it compute-bound, with the VMEM asked for: only the d 4096 rows
+# moved (64 -> 256 recompute, 128 -> 512 stash). The last two: stash mode
+# forced at the GPT-J cell's shape, and a token count that 256 does not divide.
 AUTO_BLOCKS = [
     ((4096, 1024, 50257, 1024, True), (1024, 512, 512, 1024, 1024)),
-    ((8192, 4096, 50400, 256, False), (256, 512, 256, 128, 64)),
-    ((2048, 4096, 50400, 256, True), (256, 512, 256, 128, 128)),
+    ((8192, 4096, 50400, 256, False), (256, 512, 256, 128, 256)),
+    ((2048, 4096, 50400, 256, True), (256, 512, 256, 128, 512)),
     ((4096, 768, 50257, 1024, True), (1024, 512, 512, 1024, 1024)),
     ((8192, 2048, 49152, 512, False), (512, 512, 512, 256, 256)),
     ((8192, 1024, 50257, 1024, False), (1024, 512, 512, 512, 512)),
     ((8192, 1600, 50257, 512, False), (512, 512, 512, 512, 512)),
+    ((8192, 4096, 50400, 256, True), (256, 512, 256, 128, 512)),
+    ((2176, 4096, 50400, 128, False), (128, 512, 128, 128, 128)),
 ]
+AUTO_BLOCK_IDS = [f"d{a[1]}-n{a[0]}-{'stash' if a[4] else 'recompute'}"
+                  for a, _ in AUTO_BLOCKS]
 
 
-@pytest.mark.parametrize("args,want", AUTO_BLOCKS,
-                         ids=[f"d{a[1]}-n{a[0]}-{'stash' if a[4] else 'recompute'}"
-                              for a, _ in AUTO_BLOCKS])
+@pytest.mark.parametrize("args,want", AUTO_BLOCKS, ids=AUTO_BLOCK_IDS)
 def test_backward_blocks_follow_the_vmem_sum(args, want):
     from saturn_tpu.ops import ce
 
     got = ce._auto_blocks(*args)
     assert got == want
     bn, bv, bn_dw, bv_dw, bn_dx = got
-    assert ce._dx_vmem(bn_dx, bv, args[1], args[4]) <= ce._DX_VMEM_BUDGET
-    assert ce._dw_vmem(bn_dw, bv_dw, args[1], args[4]) <= ce._VMEM_LIMIT
-    # the next larger dx block would not fit, or is the fwd's own block
-    assert bn_dx == bn or ce._dx_vmem(2 * bn_dx, bv, args[1], args[4]) > ce._DX_VMEM_BUDGET
+    d, stash = args[1], args[4]
+    assert ce._dw_vmem(bn_dw, bv_dw, d, stash) <= ce._VMEM_LIMIT
+    # dx asks for VMEM exactly where its sum is over the default limit, with
+    # room over the sum, and never for more than a kernel may ask
+    need, asked = ce._dx_vmem(bn_dx, bv, d, stash), ce._dx_vmem_limit(bn_dx, bv, d, stash)
+    if need <= ce._VMEM_LIMIT:
+        assert asked is None
+    else:
+        assert need < asked <= ce._VMEM_REQUEST_MAX and asked % (1 << 20) == 0
+
+
+# the chip's figures, the test's own copy (Google Cloud documentation, "TPU v5e")
+PEAK_FLOPS, HBM_BYTES_PER_S = 197e12, 819e9
+
+
+@pytest.mark.parametrize("args,want", AUTO_BLOCKS, ids=AUTO_BLOCK_IDS)
+def test_dx_block_hides_its_weight_stream(args, want):
+    """The property the rule is for: at the chosen ``bn_dx`` the kernel's own
+    weight stream (the padded head matrix once per token block) takes less
+    HBM time than its matmul passes take the MXU at peak — or the block is
+    the largest that ``n_tokens`` and the VMEM a kernel may ask for admit."""
+    from saturn_tpu.ops import ce
+
+    n, d, v, _, stash = args
+    blocks = ce._auto_blocks(*args)
+    bv, bn_dx = blocks[1], blocks[4]
+    vp = ce._padded_vocab(v, blocks)
+    stream_s = (n // bn_dx) * vp * d * 2 / HBM_BYTES_PER_S
+    matmul_s = (1 if stash else 2) * 2 * n * vp * d / PEAK_FLOPS
+    if stream_s < matmul_s:
+        return
+    larger = 2 * bn_dx
+    assert (n % larger != 0 or ce._dx_vmem_limit(larger, bv, d, stash)
+            > ce._VMEM_REQUEST_MAX), (stream_s, matmul_s)
+
+
+def test_dx_weight_stream_was_the_bound_at_the_old_block():
+    """The case PR 31 was written for, in numbers: at the GPT-J cell's shape a
+    64-token block streams the head matrix 128 times, 64.9 ms of HBM time
+    against 34.5 ms of matmul (at the padded vocab); at 256 tokens 16.2 ms."""
+    n, d, vp = 8192, 4096, 50688
+
+    def stream_ms(bn):
+        return (n // bn) * vp * d * 2 / HBM_BYTES_PER_S * 1e3
+
+    matmul_ms = 2 * 2 * n * vp * d / PEAK_FLOPS * 1e3
+    assert round(stream_ms(64), 1) == 64.9 and round(stream_ms(256), 1) == 16.2
+    assert round(matmul_ms, 1) == 34.5
+
+
+def test_dx_block_is_halved_where_the_request_would_pass_the_cap(monkeypatch):
+    """No width dW can run reaches the cap today (d 4096 at 512 tokens asks
+    for 47 of 64 MiB), so lower it: a block that would need more is halved,
+    and the smaller block still asks for what it needs."""
+    from saturn_tpu.ops import ce
+
+    monkeypatch.setattr(ce, "_VMEM_REQUEST_MAX", 24 << 20)
+    blocks = ce._auto_blocks(8192, 4096, 50400, 256, False)
+    assert blocks[4] == 128
+    assert (16 << 20) < ce._dx_vmem_limit(128, 512, 4096, False) <= (24 << 20)
 
 
 def test_explicit_blocks_are_kept():
@@ -446,3 +507,87 @@ def test_explicit_blocks_are_kept():
 
     assert ce._auto_blocks(8192, 4096, 50400, 256, False, block_n=256)[4] == 256
     assert ce._auto_blocks(512, 64, 256, 128, True, block_v=128)[1::2] == (128, 128)
+
+
+# (tokens, d_model, vocab) of the three cells' fused heads -> what the plan
+# says: GPT-J moves to the 256-token dx block and asks for VMEM; gpt2-medium
+# (stash mode, dx at the forward's block) and Ouro (recompute, 256 already)
+# are what they were and ask for nothing.
+CELL_PLANS = {
+    "gptj-6b-1chip": ((8192, 4096, 50400),
+                      (256, 512, 256, 128, 256), "recompute", True),
+    "gpt2-medium": ((4096, 1024, 50257),
+                    (1024, 512, 512, 1024, 1024), "stash", False),
+    "ouro-2.6b-1chip": ((8192, 2048, 49152),
+                        (512, 512, 512, 256, 256), "recompute", False),
+}
+
+
+@pytest.mark.parametrize("cell", list(CELL_PLANS))
+def test_ce_plan_of_the_benchmark_cells(cell):
+    from saturn_tpu.ops import ce
+
+    shape, blocks, mode, asks = CELL_PLANS[cell]
+    plan = ce.ce_plan(*shape)
+    assert plan.blocks == blocks and plan.mode == mode
+    assert (plan.dx_vmem_limit is not None) == asks
+    assert plan.dx_vmem == ce._dx_vmem(plan.bn_dx, plan.bv, shape[1], mode == "stash")
+    assert plan.dw_vmem <= ce._VMEM_LIMIT
+    assert set(plan._asdict()) == {
+        "bn", "bv", "bn_dw", "bv_dw", "bn_dx", "mode", "dx_vmem", "dw_vmem",
+        "dx_vmem_limit"}
+
+
+def test_ce_plan_is_none_where_no_block_tiles_the_tokens():
+    from saturn_tpu.ops import ce
+
+    assert ce.ce_plan(100, 64, 256) is None
+    assert ce.ce_plan(128, 64, 256, block_n=48) is None
+
+
+@pytest.mark.parametrize("interpret", [True, None], ids=["kernel", "dense-fallback"])
+def test_traced_plans_say_what_a_call_ran_as(interpret):
+    """``traced_plans`` collects the plan the op followed, None for a call
+    that computed through plain XLA ops (off-TPU without interpret mode)."""
+    from saturn_tpu.ops import ce
+
+    x, w, labels = _case(n=128, d=64, v=256)
+    with ce.traced_plans() as outer:
+        with ce.traced_plans() as plans:
+            jax.make_jaxpr(lambda x_: fused_linear_cross_entropy(
+                x_, w, labels, interpret=interpret))(x)
+        assert outer == []          # the inner block kept its own
+    want = ce.ce_plan(128, 64, 256) if interpret else None
+    assert plans == [want]
+    fused_linear_cross_entropy(x, w, labels, interpret=interpret)  # no block open
+
+
+# dx's token block only groups rows: every row's sum over the vocab blocks
+# runs in the same order, so the gradients are bitwise what they were.
+@pytest.mark.parametrize("stash", [False, True], ids=["recompute", "stash"])
+def test_dx_token_block_changes_no_bit(stash):
+    from saturn_tpu.ops import ce
+
+    n, d, v = 512, 64, 300          # 300: the padded, masked vocab block too
+    x, w, labels = _case(n=n, d=d, v=v, masked=16, dtype=jnp.bfloat16)
+    lab = labels.reshape(n, 1)
+
+    def run(bn_dx):
+        blocks = (256, 128, 128, 128, bn_dx)
+        loss, vjp = jax.vjp(
+            lambda x_, w_: ce._fused_ce(x_, w_, lab, blocks, v, True, stash, None),
+            x, w)
+        valid = (lab != -1).astype(jnp.float32)
+        return (loss,) + vjp(valid / valid.sum())
+
+    small, large = run(64), run(256)
+    for a, b in zip(small, large):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    ref_gx, ref_gw = jax.grad(
+        lambda x_, w_: dense_linear_cross_entropy(x_, w_, labels),
+        argnums=(0, 1))(x, w)
+    np.testing.assert_allclose(
+        np.asarray(large[1], np.float32), np.asarray(ref_gx, np.float32),
+        rtol=3e-2, atol=2e-4)
+    np.testing.assert_allclose(np.asarray(large[2]), np.asarray(ref_gw),
+                               rtol=3e-2, atol=3e-4)

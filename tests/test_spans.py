@@ -476,6 +476,17 @@ def test_existing_kinds_keep_their_fields(tiny_run):
     assert iv["elapsed_s"] == pytest.approx(iv["dur_s"], abs=0.05)
 
 
+def test_trial_config_says_how_the_fused_head_ran(tiny_run):
+    """A grid point whose loss is the fused head + cross-entropy carries
+    ``ce_plan``; off the chip the op computes through plain XLA ops, which the
+    event says as None (on a chip: the blocks, the mode, the backward kernels'
+    VMEM sums and what dx asked for; ``tests/test_tpu_compile.py`` reads one
+    from a step built for a described v5e)."""
+    configs = [e for e in tiny_run["search"] if e["kind"] == "trial_config"]
+    assert configs and all("ce_plan" in e and e["ce_plan"] is None
+                           for e in configs)
+
+
 def test_fused_interval_has_a_start_and_elapsed(tiny_run):
     (e,) = [e for e in tiny_run["fused"] if e["kind"] == "fused_interval"]
     assert {"members", "n_members", "batches", "window", "per_step_s",

@@ -13,6 +13,8 @@ the workers different tests to collect. All cases stay in this one file for
 the same reason (one worker holds the library).
 """
 
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -85,6 +87,10 @@ CE_SHAPES = {
     "gpt2-small": (4096, 768, 50257),
     "gpt2-xl": (8192, 1600, 50257),
     "gptj-6b": (2048, 4096, 50400),
+    # the benchmark cell's own shape (seq 2048 x batch 4): over the stash
+    # threshold, so auto is recompute mode; dx runs its compute-bound
+    # 256-token block there and asks the compiler for the VMEM (PR 31)
+    "gptj-6b-8k": (8192, 4096, 50400),
     # d 2048, the one width the other rows do not hold: 8192 tokens are over
     # the stash threshold, so auto is recompute mode, where dx at its full
     # 512-token block wanted 16.79 MiB and dW at a 512-row block 19.11 MiB
@@ -111,16 +117,39 @@ def test_fused_ce_forward_compiles_for_v5e(one_chip, real_lowering, name):
     )
 
 
+def _vmem_limit_of(lowered_text, kernel):
+    """The scoped VMEM the custom call of ``kernel`` asks for in a lowered
+    (not yet compiled) program's text (what ``vmem_limit_bytes`` becomes: the
+    call's ``scoped_memory_configs`` size); None where it asks for none."""
+    calls = [line for line in lowered_text.splitlines()
+             if "tpu_custom_call" in line and f'kernel_name = "{kernel}"' in line]
+    assert len(calls) == 1, (kernel, len(calls))
+    m = re.search(r"scoped_memory_configs.*?size\\22:\s*(\d+)", calls[0])
+    return int(m.group(1)) if m else None
+
+
 @pytest.mark.parametrize("stash", [None, False], ids=["auto", "recompute"])
 @pytest.mark.parametrize("name", list(CE_SHAPES))
 def test_fused_ce_grad_compiles_for_v5e(one_chip, real_lowering, name, stash):
+    n, d, v = CE_SHAPES[name]
+
     def loss(x, w, labels):
         return ce_mod.fused_linear_cross_entropy(x, w, labels, stash=stash)
 
-    _compile(
-        jax.grad(loss, argnums=(0, 1)), *_ce_args(one_chip, *CE_SHAPES[name]),
-        kernels=["saturn_ce_fwd", "saturn_ce_dx", "saturn_ce_dw"],
-    )
+    lowered = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
+        *_ce_args(one_chip, n, d, v))
+    # dx carries a limit exactly where the plan asks for one; the other two
+    # kernels never do
+    plan = ce_mod.ce_plan(n, d, v, stash=stash)
+    text = lowered.as_text()
+    assert _vmem_limit_of(text, "saturn_ce_dx") == plan.dx_vmem_limit
+    assert _vmem_limit_of(text, "saturn_ce_fwd") is None
+    assert _vmem_limit_of(text, "saturn_ce_dw") is None
+    assert (plan.dx_vmem_limit is not None) == (d == 4096), plan
+    compiled = lowered.compile().as_text()
+    for kernel in ("saturn_ce_fwd", "saturn_ce_dx", "saturn_ce_dw"):
+        assert any(kernel in line for line in compiled.splitlines()
+                   if "tpu_custom_call" in line), kernel
 
 
 # ------------------------------------------- a whole step on the 2x2 mesh
@@ -152,6 +181,11 @@ def test_dp_step_with_sharded_fused_ce_compiles_for_v5e_2x2(
     text = bundle.lowered.compile().as_text()
     calls = [l for l in text.splitlines() if "tpu_custom_call" in l]
     assert sum("saturn_ce_" in l for l in calls) == 3
+    # what the grid point's ``trial_config`` event carries as ``ce_plan``: the
+    # one fused call, traced on a shard of 8 x 512 / 4 tokens
+    (plan,) = bundle.ce_plans
+    assert plan == ce_mod.ce_plan(1024, 768, 50257)
+    assert plan.mode == "stash" and plan.dx_vmem_limit is None
 
 
 @pytest.mark.parametrize("name, config", [
