@@ -49,6 +49,25 @@ def _holds_in(metric: Dict[str, Any], cell: str) -> bool:
     return "workloads" not in metric or cell in metric["workloads"]
 
 
+def _per_layer_of(bench: Dict[str, Any], cell: str,
+                  end_to_end: List[Dict[str, Any]]) -> List[Dict[str, Any]]:
+    """The per-layer metrics of a cell: those that list it, and those with
+    no list whose ``moves`` names an end-to-end metric the cell reports (an
+    end-to-end metric may list its cells too: ``train_tokens_per_s`` leaves
+    the four-chip cell out, PR 32). A metric that lists a cell which does not
+    report what it should move is a fault of ``BENCHMARK.json``."""
+    reported = {m["name"] for m in end_to_end}
+    out = []
+    for m in bench["per_layer"]:
+        if "workloads" in m and cell in m["workloads"] and m["moves"] not in reported:
+            raise BenchmarkError(
+                f"per-layer metric {m['name']!r} lists {cell!r}, which does not "
+                f"report {m['moves']!r}, the metric it should move")
+        if _holds_in(m, cell) and m["moves"] in reported:
+            out.append(m)
+    return out
+
+
 def load_cell(workload: str, root: Optional[str] = None) -> Cell:
     root = os.path.abspath(root or REPO)
     bench = _load_json(os.path.join(root, "BENCHMARK.json"))
@@ -61,6 +80,7 @@ def load_cell(workload: str, root: Optional[str] = None) -> Cell:
     if cfg_entry is None:
         raise BenchmarkError(f"workload {workload!r} names no known config")
     bench_dir = os.path.join(root, bench["paths"][0])
+    end_to_end = [m for m in bench["end_to_end"] if _holds_in(m, workload)]
     return Cell(
         name=workload,
         chips=int(entry["chips"]),
@@ -69,8 +89,8 @@ def load_cell(workload: str, root: Optional[str] = None) -> Cell:
         config=_load_json(os.path.join(root, cfg_entry["file"])),
         traffic_name=entry["traffic"],
         traffic=_load_json(os.path.join(bench_dir, "traffic", entry["traffic"] + ".json")),
-        end_to_end=[m for m in bench["end_to_end"] if _holds_in(m, workload)],
-        per_layer=[m for m in bench["per_layer"] if _holds_in(m, workload)],
+        end_to_end=end_to_end,
+        per_layer=_per_layer_of(bench, workload, end_to_end),
         root=root,
         bench_dir=bench_dir,
     )
@@ -78,9 +98,15 @@ def load_cell(workload: str, root: Optional[str] = None) -> Cell:
 
 def load_reader(cell: Cell, metric: str) -> Callable[[Any], Optional[float]]:
     """``read(run) -> float | None`` of ``metrics/<metric>.py``: looked for
-    beside the BENCHMARK.json that was read, then beside this harness."""
-    for base in (cell.bench_dir, PERF_DIR):
-        path = os.path.join(base, "metrics", metric + ".py")
+    beside the BENCHMARK.json that was read, then beside this harness. A
+    metric named ``<reader>.<anything>`` with no file of its own is read by
+    ``metrics/<reader>.py``: a metric that lists its cells (a kernel's
+    roofline, which only the cells that run the kernel can report) gets a
+    new cell through a new *entry* ``<reader>.<cell>`` that lists it, with no
+    copied reader."""
+    names = [metric] + ([metric.split(".", 1)[0]] if "." in metric else [])
+    for name, base in ((n, b) for n in names for b in (cell.bench_dir, PERF_DIR)):
+        path = os.path.join(base, "metrics", name + ".py")
         if os.path.isfile(path):
             spec = importlib.util.spec_from_file_location(
                 "perf_metric_" + metric.replace(".", "_").replace("-", "_"), path)

@@ -17,11 +17,12 @@ import json
 import math
 import os
 import shutil
+import sys
 import tempfile
 import time
 from typing import Any, Callable, Dict, List, Optional, Sequence
 
-from perf.lib import bench, refcheck
+from perf.lib import bench, primed, refcheck
 from perf.lib.clock import CompileClock
 
 #: test-only: the platform a rehearsal may run on instead of a TPU. A run
@@ -173,6 +174,8 @@ class Run:
         self.trace: Optional[Dict[str, Any]] = None
         self.setup_s: Optional[float] = None
         self.notes: List[str] = []
+        #: every number the reference check compared, beside its limit
+        self.compared: Dict[str, Dict[str, Any]] = {}
 
     # -- what readers use
     def job(self, name: str) -> Job:
@@ -274,11 +277,9 @@ def timed_search(run: Run) -> None:
     if stats["cache_hits"]:
         raise NotCorrect(f"{stats['cache_hits']} profile-cache hit(s) in a new "
                          f"empty cache: the run read another run's profiles")
-    # A grid point the chip's compiler refuses for memory is a verdict of
-    # the memory check that arrives as an exception (PERF.md, Open
-    # questions); any other exception is a fault.
-    faults = [e for e in run.events("search", "trial_config")
-              if "error" in e and "RESOURCE_EXHAUSTED" not in str(e["error"])]
+    # A grid point the chip's compiler refuses for memory arrives as
+    # ``memory_rejected`` (PR 29); an ``error`` is a fault.
+    faults = [e for e in run.events("search", "trial_config") if "error" in e]
     if faults:
         raise NotCorrect(f"{len(faults)} grid point(s) raised; first: "
                          f"{str(faults[0]['error'])[:300]}")
@@ -292,6 +293,42 @@ def timed_search(run: Run) -> None:
                               "per_batch_s": float(s.per_batch_time)}
         say(f"search: {t.name} -> {s.executor.name} {s.params} at "
             f"{s.per_batch_time * 1e3:.2f} ms/batch")
+
+
+def warm_up(run: Run) -> None:
+    """What a mix of several intervals needs compiled before its window,
+    inside ``setup_s``. An interval of n steps runs n // K fused windows and
+    an n % K tail on the 1-step program, and one of fewer than K steps runs
+    one partial window of n (K = 8, the mix's ``round_steps_to``): the search
+    leaves only the K-step program of each task in the process, and how many
+    steps a forecast gives an interval follows from the clock, so which of
+    the others a run meets changes from run to run. So where the interval is
+    shorter than the window (``interval.window_fraction`` < 1) each task's
+    1-step program and its windows of 2..K-1 steps are built where the
+    engine will look for them, in the chosen technique's bundle of the task
+    (every program but a job's first is a persistent-cache hit in a primed
+    checkout, and costs its trace and lowering). A mix of one interval of
+    whole windows pays nothing."""
+    traffic = run.cell.traffic
+    k_full = int(traffic.get("round_steps_to", 1))
+    if float(traffic["interval"]["window_fraction"]) >= 1.0 or k_full < 2:
+        return
+    n = len(run.devices)
+    before, t0 = run.clock.snapshot(), time.perf_counter()
+    built = []
+    for t in run.tasks:
+        s = t.strategies[n]
+        bundle = s.executor.build(t, list(run.devices), dict(s.params))
+        bundle.compiled
+        built.append(f"{t.name} K=1")
+        for k in range(2, k_full):
+            bundle.fused_compiled(k)
+            built.append(f"{t.name} K={k}")
+    spent = run.clock.since(before)
+    say(f"warm-up: {len(built)} program(s) in {time.perf_counter() - t0:.2f}s "
+        f"(backend compiles {spent['backend_compiles']:.0f}, "
+        f"{spent['backend_compile_s']:.1f}s; cache hits {spent['cache_hits']:.0f}): "
+        + ", ".join(built))
 
 
 def timed_window(run: Run) -> None:
@@ -335,12 +372,34 @@ def timed_window(run: Run) -> None:
         f"inside it: backend compiles {spent['backend_compiles']:.0f} "
         f"({spent['backend_compile_s']:.2f}s), trace {spent['trace_s']:.2f}s, lower "
         f"{spent['lower_s']:.2f}s, cache retrieval {spent['cache_retrieval_s']:.2f}s")
+    compiled = sorted(str(e.get("program")) for e in run.events("window", "compile")
+                      if not e.get("cached"))
+    say(f"window: programs compiled inside it: {compiled or 'none'}")
     check_window(run)
 
 
+def saved_step(ckpt_path: str) -> int:
+    """The step count a checkpoint holds, read alone."""
+    import jax
+    import numpy as np
+    from jax.sharding import SingleDeviceSharding
+    from saturn_tpu.utils import checkpoint
+
+    only = checkpoint.restore_sharded(
+        ckpt_path, {"step": jax.ShapeDtypeStruct((), np.int32)},
+        SingleDeviceSharding(jax.devices()[0]))
+    return int(only["step"])
+
+
 def check_window(run: Run) -> None:
-    """Every job's checkpoint is at its ``batch_count``, every recorded loss
-    is finite, every gang's state lived on the devices of its planned block."""
+    """Every job's checkpoint is whole (``checkpoint.verify``: every byte of
+    every shard file against its CRC, every leaf's extents covered)
+    and at its ``batch_count``, every recorded loss is finite, every gang's
+    state lived on the devices of its planned block. What the leaves *hold*
+    is compared where there is something to compare it with: in the
+    reference check, on the checkpoint the same ``execute`` writes there
+    (``refcheck.read_back``); the window's own state has left the chips by
+    now (``orchestrate`` releases a completed job's)."""
     from saturn_tpu.core.mesh import Block
     from saturn_tpu.utils import checkpoint
 
@@ -355,7 +414,13 @@ def check_window(run: Run) -> None:
     tokens = 0
     for t in run.tasks:
         job = run.job(t.name)
-        step = int(checkpoint.load_arrays(t.ckpt_path)["step"])
+        t0 = time.perf_counter()
+        step = saved_step(t.ckpt_path)  # joins a write still in flight
+        if not checkpoint.verify(t.ckpt_path):
+            raise NotCorrect(f"{t.name}: the checkpoint at {t.ckpt_path} does "
+                             f"not verify (a shard file missing, torn or short)")
+        say(f"job {t.name}: checkpoint verified and its step read in "
+            f"{time.perf_counter() - t0:.1f}s")
         if step != job.batch_count:
             raise NotCorrect(f"{t.name}: checkpoint at step {step}, "
                              f"batch_count {job.batch_count}")
@@ -407,24 +472,32 @@ def reference_check(run: Run) -> bool:
                           batch=sequences, batch_count=steps)
         batches = [clone.batch_at(i) for i in range(steps)]
         ref_losses, ref_logits, ref_state = refcheck.reference_side(
-            ref, run.arch(job), weight_seed(cfg), batches, job.lr)
+            ref, run.arch(job), weight_seed(cfg), batches, job.lr,
+            devices=run.devices)
+        t1 = time.perf_counter()
         s = t.strategies[n]
         sys_logits = refcheck.system_logits(clone, dict(s.params), batches[0])
         numbers = {"logits_rel_rms": refcheck.logits_error(ref_logits, sys_logits)}
         del ref_logits, sys_logits
         gc.collect()
-        sys_losses, sys_state = refcheck.system_side(
+        t2 = time.perf_counter()
+        sys_losses, sys_state, read_back = refcheck.system_side(
             clone, s.executor, dict(s.params), run.devices, steps,
-            os.path.join(run.tmp, "refcheck.metrics.jsonl"))
+            os.path.join(run.tmp, "refcheck.metrics.jsonl"), run.seed)
         clone.clear_ckpt()
+        t3 = time.perf_counter()
+        numbers.update(read_back)
         numbers.update(refcheck.loss_errors(ref_losses, sys_losses))
         numbers.update(refcheck.state_errors(ref_state, sys_state, say))
         del ref_state, sys_state
+        say(f"reference check {who}: reference {t1 - t0:.1f}s, program's logits "
+            f"{t2 - t1:.1f}s, its steps and the state they left {t3 - t2:.1f}s, "
+            f"the comparison {time.perf_counter() - t3:.1f}s")
         say(f"reference check {who}: {sequences} sequence(s) x {steps} steps under "
             f"{s.executor.name} {s.params}; reference losses "
             f"{[round(x, 5) for x in ref_losses]}, program losses "
             f"{[round(x, 5) for x in sys_losses]} ({time.perf_counter() - t0:.1f}s)")
-        ok = refcheck.verdict(numbers, limits, say, who) and ok
+        ok = refcheck.verdict(numbers, limits, say, who, run.compared) and ok
         gc.collect()
     return ok
 
@@ -465,20 +538,42 @@ def label_gap(intervals: Sequence[Dict[str, Any]], start_s: float,
     return "between intervals: checkpoint snapshot, re-solve, forecast"
 
 
-def breakdown(run: Run) -> Optional[Dict[str, Any]]:
-    if run.trace is None:
-        return None
+def idle_by_label(run: Run, device: str) -> Dict[str, float]:
+    """Seconds of one chip's idle gaps by what the host was doing in them."""
     off = run.trace["wall_offset_s"]
-    by_label: Dict[str, float] = {}
     intervals = run.events("window", "task_interval")
-    first = next(iter(sorted(run.trace["devices"])), None)
-    for s, e in (run.trace["devices"][first]["gaps"] if first else []):
+    by_label: Dict[str, float] = {}
+    for s, e in run.trace["devices"][device]["gaps"]:
         label = label_gap(intervals, s / 1e9 + off, e / 1e9 + off)
         by_label[label] = by_label.get(label, 0.0) + (e - s) / 1e9
+    return by_label
+
+
+def breakdown(run: Run) -> Optional[Dict[str, Any]]:
+    """The heaviest device operations and the idle gaps by label, each the
+    mean over the chips used; on several chips the one that was busy least
+    (the chip that waited most) is said beside it."""
+    if run.trace is None or not run.trace["devices"]:
+        return None
+    chips = sorted(run.trace["devices"])
+    mean: Dict[str, float] = {}
+    for dev in chips:
+        for label, secs in idle_by_label(run, dev).items():
+            mean[label] = mean.get(label, 0.0) + secs / len(chips)
+    if len(chips) > 1:
+        worst = run.trace["worst"]
+        w = run.trace["devices"][worst]
+        top = sorted(w["by_name"].items(), key=lambda kv: -kv[1])[:3]
+        gaps = sorted(idle_by_label(run, worst).items(), key=lambda kv: -kv[1])[:3]
+        busy = ", ".join("%.3fs" % run.trace["devices"][d]["busy_s"] for d in chips)
+        say(f"per chip: busy {busy} (mean {run.trace['busy_s']:.3f}s); least busy "
+            f"{worst}: idle {100 * (1 - w['busy_s'] / run.trace['window_s']):.2f} % of "
+            f"the window, its heaviest ops {[(k, round(v, 3)) for k, v in top]}, its "
+            f"idle gaps {[(k[:40], round(v, 3)) for k, v in gaps]}")
     return {
         "device_ops": [[k, v] for k, v in run.trace["ops"][:10]],
         "idle_gaps": [[k, v] for k, v in
-                      sorted(by_label.items(), key=lambda kv: -kv[1])[:10]],
+                      sorted(mean.items(), key=lambda kv: -kv[1])[:10]],
     }
 
 
@@ -507,8 +602,7 @@ def result_line(run: Run, correct: bool, failed: int) -> Dict[str, Any]:
     if run.rehearsal:
         # a rehearsal proves control flow; it reports no number
         line["rehearsal"] = True
-        return line
-    if run.traced:
+    elif run.traced:
         if run.trace is not None:
             device["busy_s"] = run.trace["busy_s"]
             device["window_s"] = run.trace["window_s"]
@@ -518,12 +612,26 @@ def result_line(run: Run, correct: bool, failed: int) -> Dict[str, Any]:
             line["breakdown"] = bd
     else:
         line["metrics"] = read_metrics(run, run.cell.end_to_end)
+    # the numbers the reference check compared, each beside its limit: last
+    line["compared"] = run.compared
     return line
 
 
+def say_compared(run: Run) -> None:
+    """Every number compared beside its limit, as the last lines of standard
+    error (what is kept of a run that was not correct)."""
+    sys.stdout.flush()
+    for name, c in run.compared.items():
+        print(f"perf: compared {name} = {c['value']:.6g} (limit {c['limit']:.6g}) "
+              f"{'ok' if c['ok'] else 'NOT OK'}", file=sys.stderr, flush=True)
+
+
 def main_run(workload: str, seed: int, seconds: float, trace: bool,
-             t_process_start: float, prime: bool = False,
-             root: Optional[str] = None) -> int:
+             t_process_start: float, prime: Optional[str] = None,
+             root: Optional[str] = None, cache_dir: Optional[str] = None) -> int:
+    """One run; with ``prime`` (the marker's path) the priming child's: no
+    window, no result, and at its end the marker that names what it read
+    from and wrote to the compile cache in ``cache_dir``."""
     cell = bench.load_cell(workload, root)
     run = Run(cell, seed, seconds, trace, t_process_start)
     say(f"cell {cell.name}: config {cell.config_name}, traffic {cell.traffic_name}, "
@@ -534,6 +642,7 @@ def main_run(workload: str, seed: int, seconds: float, trace: bool,
         set_up(run)
         try:
             timed_search(run)
+            warm_up(run)
             if not prime:
                 timed_window(run)
                 if trace:
@@ -543,6 +652,8 @@ def main_run(workload: str, seed: int, seconds: float, trace: bool,
             correct, failed = False, len(run.jobs)
         correct = correct and reference_check(run)
         if prime:
+            if correct:
+                primed.write(prime, cache_dir, t_process_start)
             return 0 if correct else 1
         if run.setup_s is None:
             run.setup_s = time.time() - t_process_start
@@ -553,6 +664,7 @@ def main_run(workload: str, seed: int, seconds: float, trace: bool,
         line = result_line(run, correct, failed)
         for note in run.notes:
             say(f"note: {note}")
+        say_compared(run)
         print(json.dumps(line), flush=True)
         return 0
     finally:

@@ -7,6 +7,21 @@ body of a ``while`` included, which is why busy time is a *union* of
 intervals and never a sum). Host spans are the benchmark's own
 ``TraceAnnotation``s (names starting with ``perf.``) on the host plane.
 Times in the trace are nanoseconds from the start of the trace.
+
+Across chips every device plane is reduced alone and the planes are then
+averaged: ``busy_s``, ``ops``, ``collective_s`` and ``collective_exposed_s``
+are means over the chips, and ``worst`` names the plane with the least busy
+time (the chip that waited most) with its own numbers beside them.
+
+Collectives are the operations whose instruction is named ``all-gather``,
+``reduce-scatter``, ``all-reduce`` or ``collective-permute``. An asynchronous
+one is in flight from the start of its ``-start`` operation to the end of its
+``-done`` operation (paired by the ``-done``'s operand where the event holds
+the HLO line, else first started, first done, per kind); a synchronous one
+for its own event (the line ``Async XLA Ops`` holds the same flights, but
+in this profiler on the first chip's plane only, so it is not read).
+*Exposed* is the part of that time in which no other operation ran on the
+same chip: the wait the step pays for.
 """
 
 from __future__ import annotations
@@ -23,6 +38,7 @@ KERNEL_MARK = "saturn_"
 #: events that enclose others on the ops line: they would count a loop's
 #: whole body once more if they were summed by name
 _ENCLOSING = ("while", "conditional", "call")
+COLLECTIVES = ("all-gather", "reduce-scatter", "all-reduce", "collective-permute")
 
 
 def find_xplane(trace_dir: str) -> Optional[str]:
@@ -82,6 +98,61 @@ def _kernel_name(event_name: str) -> Optional[str]:
     return text[at:end].rstrip("_")
 
 
+def collective_kind(event_name: str) -> Optional[str]:
+    """``all-gather`` from ``%all-gather-start.3 = ...`` or
+    ``%all-gather.7.fusion``; None for any other operation."""
+    text = short_name(event_name)
+    return next((c for c in COLLECTIVES if c in text), None)
+
+
+def _done_operand(event_name: str) -> Optional[str]:
+    """``all-gather-start.3`` from ``%all-gather-done.3 = ...
+    all-gather-done(%all-gather-start.3)``; None where the event is named by
+    the instruction alone."""
+    _, _, call = event_name.partition("-done(")
+    at = call.find("%")
+    if at < 0:
+        return None
+    end = at + 1
+    while end < len(call) and (call[end].isalnum() or call[end] in "._-"):
+        end += 1
+    return call[at + 1:end] or None
+
+
+def collectives_in_flight(events: Iterable[Tuple[str, float, float]]
+                          ) -> List[Tuple[float, float]]:
+    """(start_ns, end_ns) of every collective among ``events`` (name,
+    start_ns, end_ns; one chip's ops line), ``-start`` and ``-done`` joined
+    into one stretch."""
+    out: List[Tuple[float, float]] = []
+    open_by_name: Dict[str, List[float]] = {}
+    open_by_kind: Dict[str, List[Tuple[str, float]]] = {}
+    for name, s, e in sorted(events, key=lambda ev: ev[1]):
+        kind = collective_kind(name)
+        if kind is None:
+            continue
+        short = short_name(name)
+        if "-start" in short:
+            open_by_name.setdefault(short, []).append(s)
+            open_by_kind.setdefault(kind, []).append((short, s))
+            continue
+        if "-done" in short:
+            started = None
+            operand = _done_operand(name)
+            if operand and open_by_name.get(operand):
+                started = open_by_name[operand].pop(0)
+                open_by_kind[kind].remove((operand, started))
+            elif operand is None and open_by_kind.get(kind):
+                first, started = open_by_kind[kind].pop(0)
+                open_by_name[first].remove(started)
+            out.append((s if started is None else started, e))
+            continue
+        out.append((s, e))
+    # a start whose done fell outside the trace: its own issue time is lost
+    # with it (microseconds), which no share can see
+    return out
+
+
 def reduce_trace(path: str) -> Dict[str, Any]:
     import jax
 
@@ -91,6 +162,7 @@ def reduce_trace(path: str) -> Dict[str, Any]:
     for plane in data.planes:
         if plane.name.startswith(DEVICE_PLANE_PREFIX):
             ops: List[Tuple[float, float]] = []
+            named: List[Tuple[str, float, float]] = []
             by_name: Dict[str, float] = {}
             kernels: Dict[str, List[Tuple[float, float]]] = {}
             lines = [ln for ln in plane.lines if ln.name == OPS_LINE]
@@ -100,6 +172,7 @@ def reduce_trace(path: str) -> Dict[str, Any]:
                     if d <= 0:
                         continue
                     ops.append((s, s + d))
+                    named.append((ev.name, s, s + d))
                     kernel = _kernel_name(ev.name)
                     if kernel is not None:
                         kernels.setdefault(kernel, []).append((s, d))
@@ -107,7 +180,7 @@ def reduce_trace(path: str) -> Dict[str, Any]:
                     if not name.startswith(_ENCLOSING):
                         by_name[name] = by_name.get(name, 0.0) + d / 1e9
             devices[plane.name] = {"ops": ops, "by_name": by_name,
-                                   "kernels": kernels}
+                                   "kernels": kernels, "named": named}
         elif plane.name == HOST_PLANE:
             for line in plane.lines:
                 for ev in line.events:
@@ -123,18 +196,37 @@ def reduce_trace(path: str) -> Dict[str, Any]:
         "window_ns": window, "window_s": (hi - lo) / 1e9, "spans": spans,
         "n_devices": len(devices), "devices": {},
     }
-    busy, merged = [], {}
+    merged: Dict[str, float] = {}
+
+    def clipped(intervals):
+        return [(max(s, lo), min(e, hi)) for s, e in intervals if e > lo and s < hi]
+
     for name, dev in sorted(devices.items()):
-        inside = [(max(s, lo), min(e, hi)) for s, e in dev["ops"] if e > lo and s < hi]
-        b = union_seconds(inside)
-        busy.append(b)
-        out["devices"][name] = {"busy_s": b, "n_ops": len(inside),
-                                "gaps": gaps(inside, lo, hi),
-                                "kernels": dev["kernels"]}
+        inside = clipped(dev["ops"])
+        flight = clipped(collectives_in_flight(dev["named"]))
+        # what else ran: every operation that is no collective and encloses
+        # no other (a ``while`` covers its whole body, collectives included)
+        other = clipped((s, e) for n, s, e in dev["named"]
+                        if collective_kind(n) is None
+                        and not short_name(n).startswith(_ENCLOSING))
+        # in flight while the chip was busy: a transfer that outlasts the
+        # chip's work is the chip's idle time, not its busy time's share
+        in_flight = (union_seconds(flight) + union_seconds(inside)
+                     - union_seconds(flight + inside))
+        out["devices"][name] = {
+            "busy_s": union_seconds(inside), "n_ops": len(inside),
+            "gaps": gaps(inside, lo, hi), "kernels": dev["kernels"],
+            "n_collectives": len(flight), "collective_s": in_flight,
+            "collective_exposed_s": union_seconds(flight + other) - union_seconds(other),
+            "by_name": dev["by_name"]}
         for op, secs in dev["by_name"].items():
             merged[op] = merged.get(op, 0.0) + secs
     n = max(len(devices), 1)
-    out["busy_s"] = sum(busy) / n
+    for key in ("busy_s", "collective_s", "collective_exposed_s"):
+        out[key] = sum(d[key] for d in out["devices"].values()) / n
+    out["n_collectives"] = sum(d["n_collectives"] for d in out["devices"].values())
     out["ops"] = sorted(((k, v / n) for k, v in merged.items()),
                         key=lambda kv: -kv[1])
+    out["worst"] = (min(out["devices"], key=lambda k: out["devices"][k]["busy_s"])
+                    if devices else None)
     return out

@@ -282,39 +282,111 @@ def adamw_step(params, grads, opt, lr: float):
     return jax.tree_util.tree_map(new, params, m, v), {"m": m, "v": v, "t": t}
 
 
+def param_shardings(a: Arch, devices) -> Dict[str, Any]:
+    """For a state larger than one chip: a ``NamedSharding`` for every leaf
+    of the parameter tree over a one-axis mesh of ``devices``, each leaf
+    split along its largest axis that the chip count divides (the stacked
+    layer axis is left whole: the layer loop indexes it) and replicated where
+    none does. Plain ``jit`` shardings and nothing else: the compiler
+    partitions the same float32 arithmetic, no technique of the program is
+    involved."""
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec
+
+    mesh = Mesh(np.asarray(list(devices)), ("chips",))
+    n = len(devices)
+    out = {}
+    for path, (shape, _) in _shapes(a).items():
+        axes = range(1 if path.startswith("blocks/") else 0, len(shape))
+        fit = [i for i in axes if shape[i] % n == 0 and shape[i] >= n]
+        spec = [None] * len(shape)
+        if fit:
+            spec[max(fit, key=lambda i: shape[i])] = "chips"
+        out[path] = NamedSharding(mesh, PartitionSpec(*spec))
+    return out
+
+
 @functools.lru_cache(maxsize=None)
-def _jitted(a: Arch, lr: float, mm: Optional[Callable]) -> Dict[str, Callable]:
+def _jitted(a: Arch, lr: float, mm: Optional[Callable],
+            devices: Optional[Tuple[Any, ...]] = None) -> Dict[str, Callable]:
     """The jitted pieces of ``train`` and ``logits_of``, made once for an
     architecture, a learning rate and a matmul (a process that reads many
-    seeds traces and compiles each once)."""
+    seeds traces and compiles each once). With ``devices`` (more than one
+    chip) weights and moments are sharded over them (``param_shardings``),
+    and a step takes its gradient one sequence at a time and averages: the
+    sequences are equally long, so the mean of their losses is the batch's
+    loss, and the float32 stash of a whole batch would not fit beside
+    16 B/param."""
+
+    def loss_and_grads(params, tokens):
+        def of(t):
+            return jax.value_and_grad(lambda p: loss_fn(a, p, t, mm))(params)
+
+        if not devices or tokens.shape[0] == 1:
+            return of(tokens)
+
+        def add(acc, one):
+            return jax.tree_util.tree_map(jnp.add, acc, of(one[None])), None
+
+        zero = (jnp.zeros((), jnp.float32),
+                jax.tree_util.tree_map(jnp.zeros_like, params))
+        total, _ = jax.lax.scan(add, zero, tokens)
+        return jax.tree_util.tree_map(lambda x: x / tokens.shape[0], total)
 
     def step(params, opt, tokens):
-        loss, grads = jax.value_and_grad(lambda p: loss_fn(a, p, tokens, mm))(params)
+        loss, grads = loss_and_grads(params, tokens)
         params, opt = adamw_step(params, grads, opt, lr)
         return params, opt, loss
+
+    by_path = param_shardings(a, devices) if devices else {}
+    p_sh = _nest(by_path) if devices else None
+
+    def make_params(k):
+        seeded = seeded_params(a, k)
+        return seeded if p_sh is None else jax.lax.with_sharding_constraint(seeded, p_sh)
 
     def moved(params, key):
         return jax.tree_util.tree_map(
             lambda p, p0: jnp.sqrt(jnp.sum(jnp.square(p - p0))),
-            params, seeded_params(a, key))
+            params, make_params(key))
 
-    return {"params": jax.jit(lambda k: seeded_params(a, k)),
-            "opt": jax.jit(adamw_init),
-            "step": jax.jit(step, donate_argnums=(0, 1)),
-            "moved": jax.jit(moved),
-            "logits": jax.jit(lambda k, t: forward(a, seeded_params(a, k), t, mm))}
+    def logits(k, t):
+        return forward(a, make_params(k), t, mm)
+
+    if not devices:
+        return {"params": jax.jit(make_params), "opt": jax.jit(adamw_init),
+                "step": jax.jit(step, donate_argnums=(0, 1)),
+                "moved": jax.jit(moved), "logits": jax.jit(logits)}
+    from jax.sharding import NamedSharding, PartitionSpec
+
+    whole = NamedSharding(by_path["wte"].mesh, PartitionSpec())  # on every chip
+    o_sh = {"m": p_sh, "v": p_sh, "t": whole}
+    return {"params": jax.jit(make_params, in_shardings=whole, out_shardings=p_sh),
+            "opt": jax.jit(adamw_init, in_shardings=(p_sh,), out_shardings=o_sh),
+            "step": jax.jit(step, in_shardings=(p_sh, o_sh, whole),
+                            out_shardings=(p_sh, o_sh, whole), donate_argnums=(0, 1)),
+            "moved": jax.jit(moved, in_shardings=(p_sh, whole), out_shardings=whole),
+            "logits": jax.jit(logits, in_shardings=(whole, whole),
+                              out_shardings=whole)}
+
+
+def _over(devices) -> Optional[Tuple[Any, ...]]:
+    """``devices`` as ``_jitted`` keys them: None for one chip or none."""
+    return tuple(devices) if devices is not None and len(devices) > 1 else None
 
 
 def train(a: Arch, seed: int, batches, lr: float,
-          mm: Optional[Callable] = None, keep_state: bool = False):
+          mm: Optional[Callable] = None, keep_state: bool = False,
+          devices=None):
     """``len(batches)`` AdamW steps from the seeded weights: one jitted step,
     called in a Python loop. Returns (the loss before each step, as floats;
     the final state). The state is None unless ``keep_state``; then it is
     host arrays by leaf path, in the program's layout:
     ``{"m": first moments, "params": weights, "moved": ||weights - seeded
     weights|| per leaf}`` -- what a checkpoint of the program is held
-    against."""
-    fns = _jitted(a, float(lr), mm)
+    against. ``devices`` (more than one chip): the state is sharded over
+    them, for a configuration whose 16 B/param overfill one chip."""
+    fns = _jitted(a, float(lr), mm, _over(devices))
     with jax.default_matmul_precision("highest"):
         key = seed_key(seed)
         params = fns["params"](key)
@@ -333,14 +405,17 @@ def train(a: Arch, seed: int, batches, lr: float,
             state = {"moved": {k: float(v) for k, v in
                                flat(fns["moved"](params, key)).items()}}
             for name, tree in (("m", m), ("params", params)):
-                host = jax.tree_util.tree_map(np.asarray, tree)
-                state[name] = flat(program_layout(a, host, xp=np))
+                # the program's layout on the device, then one copy to the host
+                state[name] = flat(jax.tree_util.tree_map(
+                    np.asarray, program_layout(a, tree)))
             del m
     del params
     return out, state
 
 
-def logits_of(a: Arch, seed: int, tokens, mm: Optional[Callable] = None):
+def logits_of(a: Arch, seed: int, tokens, mm: Optional[Callable] = None,
+              devices=None):
     """Float32 logits of the seeded weights on ``tokens``."""
     with jax.default_matmul_precision("highest"):
-        return _jitted(a, 0.0, mm)["logits"](seed_key(seed), jnp.asarray(tokens))
+        return _jitted(a, 0.0, mm, _over(devices))["logits"](
+            seed_key(seed), jnp.asarray(tokens))

@@ -10,10 +10,13 @@ a reader. See perf/README.md.
 
 The first run of a cell in a checkout finds no marker in the XLA compile
 cache directory (one per cell, checkout path and source tree) and first runs
-itself once as a child process (``--prime``)
-that does the cell's search and reference check and exits: it
+itself once as a child process (``--prime <marker>``)
+that does the cell's search, warm-up and reference check and exits: it
 compiles every program the cell uses into the cache, so that the timed search
-of *every* run, the first included, meets a warm XLA cache. This file imports
+of *every* run, the first included, meets a warm XLA cache, and writes the
+marker, which names the cache entries and refusal records it vouches for
+(``perf/lib/primed.py``): where one of them has gone since, the next run
+primes again. This file imports
 nothing of JAX before the child has ended (a chip belongs to one process).
 """
 
@@ -61,24 +64,23 @@ def checkout_fingerprint() -> str:
 
 
 def prime_once(args) -> None:
+    from perf.lib import primed  # imports nothing of JAX
+
     marker = os.path.join(compile_cache_dir(),
                           f"perf-primed.{args.workload}.{checkout_fingerprint()}")
-    if os.path.exists(marker) or os.environ.get("PERF_REHEARSAL_PLATFORM"):
+    if os.environ.get("PERF_REHEARSAL_PLATFORM") or primed.holds(
+            marker, compile_cache_dir()):
         return
-    print(f"perf: no {marker}: priming the XLA compile cache in a child "
-          f"process first", flush=True)
+    print(f"perf: no {marker} that holds: priming the XLA compile cache in a "
+          f"child process first", flush=True)
     child = subprocess.run(
         [sys.executable, os.path.abspath(__file__), "--workload", args.workload,
          "--seed", str(args.seed), "--seconds", str(args.seconds),
-         "--trace", "0", "--prime"]
+         "--trace", "0", "--prime", marker]
         + (["--bench-root", args.bench_root] if args.bench_root else []),
         stdout=sys.stderr)  # the child prints no result; keep stdout ours
-    if child.returncode != 0:
+    if child.returncode != 0 or not os.path.exists(marker):
         raise SystemExit(f"perf: the priming child failed ({child.returncode})")
-    os.makedirs(os.path.dirname(marker), exist_ok=True)
-    with open(marker, "w") as f:
-        f.write(f"primed in {time.time() - _T0:.1f}s\n")
-    print(f"perf: primed in {time.time() - _T0:.1f}s", flush=True)
 
 
 def main() -> int:
@@ -87,7 +89,7 @@ def main() -> int:
     p.add_argument("--seed", type=int, required=True)
     p.add_argument("--seconds", type=float, required=True)
     p.add_argument("--trace", type=int, choices=(0, 1), default=0)
-    p.add_argument("--prime", action="store_true", help=argparse.SUPPRESS)
+    p.add_argument("--prime", default=None, metavar="MARKER", help=argparse.SUPPRESS)
     p.add_argument("--bench-root", default=None, help=argparse.SUPPRESS)
     args = p.parse_args()
     if not os.path.isdir(os.path.join(REPO, "saturn_tpu")):
@@ -98,7 +100,7 @@ def main() -> int:
 
     return harness.main_run(args.workload, args.seed, args.seconds,
                             bool(args.trace), _T0, prime=args.prime,
-                            root=args.bench_root)
+                            root=args.bench_root, cache_dir=compile_cache_dir())
 
 
 if __name__ == "__main__":

@@ -130,9 +130,10 @@ def test_the_new_cell_loads_with_its_readers_and_its_published_widths():
     cell = bench.load_cell(CELL)
     assert cell.chips == 1 and cell.traffic_name == "steady-4k"
     names = [m["name"] for m in cell.per_layer]
-    for new in ("loop_passes", "loop_flash_roofline", "loop_ce_roofline", "loop_head_share"):
+    for new in ("loop_passes", "loop_head_share", "flash_roofline", "ce_roofline"):
         assert new in names and callable(bench.load_reader(cell, new))
-    assert "flash_roofline" not in names and "ce_roofline" not in names
+    # PR 32: the copied readers are gone, the cell is on the rooflines' lists
+    assert "loop_flash_roofline" not in names and "loop_ce_roofline" not in names
     for other in ("gptj-6b-1chip.steady", "gpt2-medium.steady"):
         assert not {"loop_passes", "loop_head_share"} & {
             m["name"] for m in bench.load_cell(other).per_layer}
@@ -147,36 +148,25 @@ def test_the_new_cell_loads_with_its_readers_and_its_published_widths():
     (job,) = run.jobs
     assert (job.seq, job.batch, job.batch_count % 8) == (4096, 2, 0)
     # nothing measured yet: every new reader returns nothing and does not raise
-    for new in ("loop_passes", "loop_flash_roofline", "loop_ce_roofline", "loop_head_share"):
+    for new in ("loop_passes", "flash_roofline", "ce_roofline", "loop_head_share"):
         assert bench.load_reader(cell, new)(run) is None
 
 
-def test_benchmark_json_grows_at_the_end_and_keeps_the_span_entries():
-    """A list of ``BENCHMARK.json`` grows only at its end (the driver reads an
-    entry put first or in the middle as a change to what was there), so this
-    PR's four metrics follow PR 26's eight. ``test_span_metrics.py`` looks for
-    those eight at ``per_layer[-8:]`` and so fails from the first PR on that
-    appends; only a ``benchmark`` PR may mend it. Until then its checks run
-    here, the eight found by name."""
-    from perf.tests.test_span_metrics import NEW as SPAN_METRICS
-
+def test_benchmark_json_keeps_the_looped_cells_own_metrics():
+    """``loop_passes`` and ``loop_head_share`` are the looped cell's own and
+    list it; the two copied roofline readers went in PR 32 (a ``benchmark``
+    PR), when ``flash_roofline`` and ``ce_roofline`` took the cell onto their
+    lists. The span entries' checks are ``test_span_metrics.py``'s again."""
     with open(os.path.join(bench.REPO, "BENCHMARK.json")) as f:
         b = json.load(f)
-    names = [m["name"] for m in b["per_layer"]]
-    first = names.index(SPAN_METRICS[0])
-    assert tuple(names[first:first + 8]) == SPAN_METRICS
-    assert names[first + 8:] == ["loop_passes", "loop_flash_roofline",
-                                 "loop_ce_roofline", "loop_head_share"]
-    assert [w["name"] for w in b["workloads"]][-1] == CELL
-    assert b["configs"][-1]["name"] == "ouro-2.6b-1chip"
-    layers = {m["layer"] for m in b["per_layer"][:first]}
-    ends = {m["name"] for m in b["end_to_end"]}
-    for m in b["per_layer"][first:]:
-        assert m["layer"] in layers and m["moves"] in ends
-        assert set(m) == {"name", "unit", "better", "source", "layer", "moves",
-                          "workloads"}
-        assert os.path.isfile(os.path.join(bench.PERF_DIR, "metrics",
-                                           m["name"] + ".py"))
+    by_name = {m["name"]: m for m in b["per_layer"]}
+    assert "loop_flash_roofline" not in by_name and "loop_ce_roofline" not in by_name
+    for name in ("loop_passes", "loop_head_share"):
+        assert by_name[name]["workloads"] == [CELL]
+    for name in ("flash_roofline", "ce_roofline"):
+        assert CELL in by_name[name]["workloads"]
+    assert not os.path.exists(os.path.join(bench.PERF_DIR, "metrics",
+                                           "loop_flash_roofline.py"))
 
 
 TINY_OURO = {
