@@ -67,10 +67,21 @@ class ModelSpec:
 
     @property
     def stack_layers(self) -> Optional[int]:
-        """Layers the block stack holds (each applied ``stack_passes``
-        times a token), if the model says."""
+        """Layers the block stack holds, of every kind (each applied
+        ``stack_passes`` times a token), if the model says."""
         n = self.hints.get("n_layers", getattr(self.config, "n_layers", None))
         return None if n is None else int(n)
+
+    @property
+    def stack_kinds(self) -> Optional[Dict[str, int]]:
+        """Layers of each kind in one scanned unit (a period) of a stack of
+        several block kinds, e.g. ``{"linear_attention": 3,
+        "full_attention": 1}``: ``hints["stack_kinds"]``. None for a stack
+        of one kind. ``stack_layers`` counts the layers of every kind; the
+        scan runs ``stack_layers / sum(stack_kinds.values())`` periods, and
+        ``hints["pipeline"]["block"]`` is one period."""
+        kinds = self.hints.get("stack_kinds")
+        return None if not kinds else {str(k): int(v) for k, v in kinds.items()}
 
     def abstract_init(self):
         import jax

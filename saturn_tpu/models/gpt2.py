@@ -22,7 +22,7 @@ from __future__ import annotations
 
 import math
 from dataclasses import dataclass, field, replace
-from typing import Any, Dict, Optional
+from typing import Any, Dict, Optional, Tuple
 
 import flax.linen as nn
 import jax
@@ -112,6 +112,36 @@ class GPT2Config:
     use_bias: bool = True
     tie_head: bool = True
     n_passes: int = 1
+    # Hybrid-stack structure knobs (Olmo-Hybrid-class: gated-delta-rule
+    # linear-attention layers between full-attention layers). Each at its
+    # default leaves every earlier preset's program unchanged op for op.
+    #   layer_types: the kinds of one *period* of the stack
+    #     ("linear_attention" | "full_attention"); the stack is
+    #     ``n_layers / len(layer_types)`` periods, one ``nn.scan`` over a
+    #     period block that applies its layers in order. None = one kind.
+    #   held_heads: how many of the ``n_heads`` published heads this program
+    #     holds (a tensor-parallel rank's share of every mixer: q/k/v/gate
+    #     columns and ``attn_out`` rows of the held heads only). Head widths
+    #     stay ``d_model / n_heads``. None = all.
+    #   pre_norm: False drops the norm *before* each branch (with
+    #     ``sandwich_norm`` the block is x += N(mixer(x)); x += N(mlp(x))).
+    #   qk_norm: an RMSNorm on the full layers' q and k, over all held heads'
+    #     lanes together, before the heads are split.
+    #   learned_positions: False with ``rotary=False`` gives no position
+    #     signal at all (the recurrent layers carry the order).
+    #   lin_*: a linear layer's key / value head widths, the taps of its
+    #     depthwise causal convolution, whether beta reaches 2 (a negative
+    #     eigenvalue of I - beta k k^T), and the chunk of ``ops/gdn.py``.
+    layer_types: Optional[Tuple[str, ...]] = None
+    held_heads: Optional[int] = None
+    pre_norm: bool = True
+    qk_norm: bool = False
+    learned_positions: bool = True
+    lin_key_dim: int = 96
+    lin_value_dim: int = 192
+    lin_conv: int = 4
+    lin_neg_eigval: bool = True
+    lin_chunk: int = 64
     name: str = "gpt2-small"
 
     def __post_init__(self) -> None:
@@ -153,6 +183,31 @@ class GPT2Config:
                 f"n_kv_heads must divide n_heads ({self.n_heads}), "
                 f"got {self.n_kv_heads}"
             )
+        if not self.pre_norm and not self.sandwich_norm:
+            raise ValueError("pre_norm=False needs sandwich_norm=True "
+                             "(a block with no norm at all is not offered)")
+        if self.held_heads is not None and (
+            not 1 <= self.held_heads <= self.n_heads or self.n_kv_heads is not None
+        ):
+            raise ValueError(
+                f"held_heads must be 1..n_heads ({self.n_heads}) with one k/v "
+                f"head per q head, got {self.held_heads}")
+        if self.layer_types is not None:
+            object.__setattr__(self, "layer_types", tuple(self.layer_types))
+            kinds = set(self.layer_types)
+            if not kinds or kinds - {"linear_attention", "full_attention"}:
+                raise ValueError(f"layer_types holds 'linear_attention' / "
+                                 f"'full_attention', got {self.layer_types!r}")
+            if self.n_layers % len(self.layer_types) != 0:
+                raise ValueError(
+                    f"n_layers ({self.n_layers}) must be whole periods of "
+                    f"{len(self.layer_types)} layers")
+            if "linear_attention" in kinds and (
+                not self.causal or self.seq_axis is not None or self.moe
+            ):
+                raise ValueError(
+                    "a linear-attention layer is causal, dense-MLP and "
+                    "single-program (its state crosses the whole sequence)")
 
     @property
     def head_dim(self) -> int:
@@ -161,6 +216,23 @@ class GPT2Config:
     @property
     def ff_dim(self) -> int:
         return self.d_ff if self.d_ff is not None else 4 * self.d_model
+
+    @property
+    def heads_held(self) -> int:
+        return self.n_heads if self.held_heads is None else self.held_heads
+
+    @property
+    def n_periods(self) -> int:
+        """Trip count of the layer scan: periods of ``layer_types``, or
+        layers where the stack has one kind."""
+        return self.n_layers // len(self.layer_types or (None,))
+
+    @property
+    def stack_kinds(self) -> Optional[Dict[str, int]]:
+        """Layers of each kind in one period; None for a one-kind stack."""
+        if self.layer_types is None:
+            return None
+        return {k: self.layer_types.count(k) for k in dict.fromkeys(self.layer_types)}
 
     def example_inputs(self, batch_size: int = 1):
         return jnp.zeros((batch_size, self.seq_len), dtype=jnp.int32)
@@ -226,6 +298,27 @@ PRESETS: Dict[str, Dict[str, Any]] = {
         seq_len=64, rotary=True, rope_theta=1e6, norm="rmsnorm",
         mlp_act="swiglu", sandwich_norm=True, use_bias=False,
         tie_head=False, n_passes=4,
+    ),
+    # Olmo-Hybrid (allenai/Olmo-Hybrid-7B): 8 periods of three gated-delta-
+    # rule layers (30 heads of 96 / 192, a 4-tap convolution in front, beta
+    # up to 2) and one full-attention layer (30 heads of 128, q/k RMSNorm,
+    # no rotary: ``rope_theta`` is null in the source). OLMo's reordered
+    # norm (x += N(f(x))), SwiGLU, no bias, an untied head.
+    "olmo-hybrid-7b": dict(
+        d_model=3840, n_layers=32, n_heads=30, d_ff=11008, vocab_size=100352,
+        norm="rmsnorm", mlp_act="swiglu", pre_norm=False, sandwich_norm=True,
+        use_bias=False, tie_head=False, learned_positions=False, qk_norm=True,
+        layer_types=("linear_attention",) * 3 + ("full_attention",),
+        lin_key_dim=96, lin_value_dim=192, lin_conv=4, lin_neg_eigval=True,
+    ),
+    "olmo-hybrid-test-tiny": dict(
+        d_model=64, n_layers=8, n_heads=4, d_ff=176, vocab_size=256,
+        seq_len=64, norm="rmsnorm", mlp_act="swiglu", pre_norm=False,
+        sandwich_norm=True, use_bias=False, tie_head=False,
+        learned_positions=False, qk_norm=True,
+        layer_types=("linear_attention",) * 3 + ("full_attention",),
+        lin_key_dim=12, lin_value_dim=24, lin_conv=4, lin_neg_eigval=True,
+        lin_chunk=16,
     ),
     # Switch-style MoE family (extension beyond the reference; SURVEY.md §2.3
     # lists EP as absent there).
@@ -298,9 +391,16 @@ class Block(nn.Module):
     Two residual wirings (parity with ``GPTJ.py:392-424``): sequential GPT-2
     (ln_1 → attn, ln_2 → mlp) or, with ``parallel_residual=True``, GPT-J's
     parallel form (one ln, attn and mlp added together). ``rotary=True``
-    rotates the first ``rotary_dim`` q/k dims by position."""
+    rotates the first ``rotary_dim`` q/k dims by position.
+
+    ``kind`` chooses the mixer: softmax attention, or the gated delta rule of
+    ``ops/gdn.py`` behind a short convolution (``_linear_mixer``). With
+    ``held_heads`` the mixer computes the held heads' part of its output:
+    the projections' columns and ``attn_out``'s rows of those heads, and
+    nothing that stands in for the heads held elsewhere."""
 
     cfg: GPT2Config
+    kind: str = "full_attention"
 
     @nn.compact
     def __call__(self, x, _unused):
@@ -315,12 +415,57 @@ class Block(nn.Module):
             return nn.Dense(features, dtype=dt, param_dtype=pdt,
                             use_bias=cfg.use_bias, name=name)
 
-        # ---- attention ----
-        h = make_norm("ln_1")(x)
+        h = make_norm("ln_1")(x) if cfg.pre_norm else x
+        if self.kind == "linear_attention":
+            attn = self._linear_mixer(h, dense)
+        else:
+            attn = self._softmax_mixer(h, dense, make_norm)
+        attn = dense(D, "attn_out")(attn)
+        if cfg.sandwich_norm:
+            attn = make_norm("ln_1_post")(attn)
+
+        # ---- mlp (dense or Switch-routed experts) ----
+        def mlp(inp):
+            if cfg.moe:
+                return self._moe_mlp(inp)
+            if cfg.mlp_act == "swiglu":
+                # Separate gate/up projections (NOT one fused 2F Dense): the
+                # TP column rule shards each kernel's output dim, so
+                # gate_i/up_i stay on the same model shard and silu(gate)*up
+                # is local — a fused contiguous split would put all gate
+                # columns on shard 0 and force a full-activation reshard
+                # per layer.
+                gate = dense(cfg.ff_dim, "mlp_gate")(inp)
+                up = dense(cfg.ff_dim, "mlp_in")(inp)
+                m = nn.silu(gate) * up
+            else:
+                m = dense(cfg.ff_dim, "mlp_in")(inp)
+                m = nn.gelu(m, approximate=True)
+            return dense(D, "mlp_out")(m)
+
+        if cfg.parallel_residual:
+            # GPT-J wiring: attn and MLP both read ln_1(x), one residual add
+            # (reference ``GPTJ.py:392-424``).
+            x = x + attn + mlp(h)
+        else:
+            x = x + attn
+            m = mlp(make_norm("ln_2")(x) if cfg.pre_norm else x)
+            if cfg.sandwich_norm:
+                m = make_norm("ln_2_post")(m)
+            x = x + m
+        return x, None
+
+    def _softmax_mixer(self, h, dense, make_norm):
+        """(B, T, D) -> the held heads' attention output (B, T, A), before
+        ``attn_out``; A = held heads x head_dim (D where all are held)."""
+        cfg = self.cfg
+        dt = cfg.dtype
+        B, T, D = h.shape
+        A = cfg.heads_held * cfg.head_dim
         if cfg.n_kv_heads is None:
-            qkv = dense(3 * D, "qkv")(h)
+            qkv = dense(3 * A, "qkv")(h)
             q, k, v = jnp.split(qkv, 3, axis=-1)
-            kv_heads = cfg.n_heads
+            kv_heads = cfg.heads_held
         else:
             # Grouped-query attention: k/v carry n_kv_heads; one fused
             # projection sized D + 2 * kv_dim.
@@ -330,11 +475,15 @@ class Block(nn.Module):
             q = qkv[..., :D]
             k = qkv[..., D:D + kv_dim]
             v = qkv[..., D + kv_dim:]
+        if cfg.qk_norm:
+            # over all held heads' lanes together (with a share of the heads
+            # the statistic is the held share's: ROADMAP.md, Reach)
+            q, k = make_norm("q_norm")(q), make_norm("k_norm")(k)
 
         def heads(t, n):
             return t.reshape(B, T, n, cfg.head_dim).transpose(0, 2, 1, 3)
 
-        q = heads(q, cfg.n_heads)
+        q = heads(q, cfg.heads_held)
         k, v = heads(k, kv_heads), heads(v, kv_heads)
         if cfg.rotary:
             rd = cfg.rotary_dim or cfg.head_dim
@@ -347,7 +496,7 @@ class Block(nn.Module):
                                       cfg.rope_theta)
             q = apply_rotary(q, sin, cos, rd)
             k = apply_rotary(k, sin, cos, rd)
-        if kv_heads != cfg.n_heads and not (
+        if kv_heads != cfg.heads_held and not (
             cfg.seq_axis is None and self._attention_impl() == "flash"
         ):
             # GQA on the non-flash paths: repeat k/v head groups up to
@@ -385,42 +534,77 @@ class Block(nn.Module):
                 scores = jnp.where(mask[None, None], scores, jnp.float32(-1e30))
             probs = jax.nn.softmax(scores, axis=-1).astype(dt)
             attn = jnp.einsum("bhqk,bhkd->bhqd", probs, v)
-        attn = attn.transpose(0, 2, 1, 3).reshape(B, T, D)
-        attn = dense(D, "attn_out")(attn)
-        if cfg.sandwich_norm:
-            attn = make_norm("ln_1_post")(attn)
+        return attn.transpose(0, 2, 1, 3).reshape(B, T, A)
 
-        # ---- mlp (dense or Switch-routed experts) ----
-        def mlp(inp):
-            if cfg.moe:
-                return self._moe_mlp(inp)
-            if cfg.mlp_act == "swiglu":
-                # Separate gate/up projections (NOT one fused 2F Dense): the
-                # TP column rule shards each kernel's output dim, so
-                # gate_i/up_i stay on the same model shard and silu(gate)*up
-                # is local — a fused contiguous split would put all gate
-                # columns on shard 0 and force a full-activation reshard
-                # per layer.
-                gate = dense(cfg.ff_dim, "mlp_gate")(inp)
-                up = dense(cfg.ff_dim, "mlp_in")(inp)
-                m = nn.silu(gate) * up
-            else:
-                m = dense(cfg.ff_dim, "mlp_in")(inp)
-                m = nn.gelu(m, approximate=True)
-            return dense(D, "mlp_out")(m)
+    def _linear_mixer(self, h, dense):
+        """(B, T, D) -> the held heads' gated-delta-rule output (B, T,
+        held x lin_value_dim), before ``attn_out``:
 
-        if cfg.parallel_residual:
-            # GPT-J wiring: attn and MLP both read ln_1(x), one residual add
-            # (reference ``GPTJ.py:392-424``).
-            x = x + attn + mlp(h)
-        else:
-            x = x + attn
-            h2 = make_norm("ln_2")(x)
-            m = mlp(h2)
-            if cfg.sandwich_norm:
-                m = make_norm("ln_2_post")(m)
-            x = x + m
-        return x, None
+            q~, k~, v~ = silu(conv(h Wq)), silu(conv(h Wk)), silu(conv(h Wv))
+            q = q~ / |q~| / sqrt(dk);  k = k~ / |k~|        (per head)
+            beta = (2 if lin_neg_eigval else 1) sigmoid(h Wb)
+            g = -exp(A_log) softplus(h Wa + dt_bias)        (log decay)
+            o = gated_delta_rule(q, k, v, g, beta)
+            out = RMSNorm_head(o) * silu(h Wgate)
+
+        The convolution is depthwise and causal (tap ``j`` of ``lin_conv``
+        multiplies the token ``lin_conv - 1 - j`` back). Gates, norms and the
+        rule's state are float32; the projections and the rule's products
+        take ``cfg.dtype`` operands. The rule runs as the Pallas kernel where
+        the attention implementation is "flash", as the plain chunked scan
+        where it is "dense": one grid point chooses both mixers."""
+        from saturn_tpu.ops.gdn import gated_delta_rule
+
+        cfg = self.cfg
+        dt, pdt = cfg.dtype, cfg.param_dtype
+        B, T, _ = h.shape
+        H, dk, dv, taps = cfg.heads_held, cfg.lin_key_dim, cfg.lin_value_dim, cfg.lin_conv
+        f32 = jnp.float32
+
+        def tap_init(key, shape, dtype):   # a Conv1d's own: +-1/sqrt(fan_in)
+            bound = 1.0 / math.sqrt(taps)
+            return jax.random.uniform(key, shape, dtype, -bound, bound)
+
+        def conv_silu(t, name):
+            w = self.param(name, tap_init, (taps, t.shape[-1]), pdt).astype(f32)
+            padded = jnp.pad(t.astype(f32), ((0, 0), (taps - 1, 0), (0, 0)))
+            return nn.silu(sum(w[j] * padded[:, j:j + T] for j in range(taps)))
+
+        def heads(t, width):
+            return t.reshape(B, T, H, width).transpose(0, 2, 1, 3)
+
+        def unit(t):
+            return t * jax.lax.rsqrt(jnp.sum(t * t, axis=-1, keepdims=True) + 1e-6)
+
+        q = heads(conv_silu(dense(H * dk, "lin_q")(h), "conv_q"), dk)
+        k = heads(conv_silu(dense(H * dk, "lin_k")(h), "conv_k"), dk)
+        v = heads(conv_silu(dense(H * dv, "lin_v")(h), "conv_v"), dv)
+        gate = dense(H * dv, "lin_gate")(h)
+        a = dense(H, "lin_a")(h).astype(f32)
+        b = dense(H, "lin_b")(h).astype(f32)
+        # Mamba-2's inits: decay rates in [1, 16), step sizes log-uniform in
+        # [1e-3, 1e-1] through the inverse softplus
+        a_log = self.param(
+            "A_log", lambda key, shape, dtype: jnp.log(
+                jax.random.uniform(key, shape, dtype, 1.0, 16.0)), (H,), pdt)
+
+        def dt_init(key, shape, dtype):
+            step = jnp.exp(jax.random.uniform(key, shape, dtype,
+                                              math.log(1e-3), math.log(1e-1)))
+            return step + jnp.log(-jnp.expm1(-step))
+
+        dt_bias = self.param("dt_bias", dt_init, (H,), pdt)
+        beta = (2.0 if cfg.lin_neg_eigval else 1.0) * jax.nn.sigmoid(b)
+        g = -jnp.exp(a_log.astype(f32)) * jax.nn.softplus(a + dt_bias.astype(f32))
+        o = gated_delta_rule(
+            (unit(q) / math.sqrt(dk)).astype(dt), unit(k).astype(dt), v.astype(dt),
+            g.transpose(0, 2, 1), beta.transpose(0, 2, 1),
+            impl="kernel" if self._attention_impl() == "flash" else "xla",
+            chunk=cfg.lin_chunk,
+        )
+        o = nn.RMSNorm(dtype=f32, param_dtype=pdt, name="o_norm")(o)   # float32 as it comes
+        o = o.transpose(0, 2, 1, 3).reshape(B, T, H * dv)
+        return (o * nn.silu(gate.astype(f32))).astype(dt)
 
     def _attention_impl(self) -> str:
         """'auto' resolution for configs built without ``build_gpt2`` — one
@@ -455,6 +639,31 @@ class Block(nn.Module):
         return y
 
 
+def _remat(block_cls, prevent_cse: bool = False):
+    """``prevent_cse=False`` is for a block that is a scan's whole body: the
+    loop boundary already keeps the backward's recomputation apart from the
+    forward. Several rematerialised blocks in one body need the barriers."""
+    return nn.remat(block_cls, prevent_cse=prevent_cse,
+                    policy=jax.checkpoint_policies.nothing_saveable)
+
+
+class PeriodBlock(nn.Module):
+    """One period of a stack of several block kinds (``cfg.layer_types``):
+    its layers in order, each a :class:`Block` of its kind under the name
+    ``l<i>``; the unit the layer scan repeats, with ``Block``'s signature.
+    Under remat each layer is rematerialised on its own, so the backward
+    holds one layer's activations at a time, as in a one-kind stack."""
+
+    cfg: GPT2Config
+
+    @nn.compact
+    def __call__(self, x, _unused):
+        block_cls = _remat(Block, prevent_cse=True) if self.cfg.remat else Block
+        for i, kind in enumerate(self.cfg.layer_types):
+            x, _ = block_cls(self.cfg, kind=kind, name=f"l{i}")(x, None)
+        return x, None
+
+
 class GPT2(nn.Module):
     """Decoder-only LM with a scanned block stack under param key 'blocks'."""
 
@@ -470,9 +679,11 @@ class GPT2(nn.Module):
             (cfg.vocab_size, cfg.d_model),
             cfg.param_dtype,
         )
-        if cfg.rotary:
+        if cfg.rotary or not cfg.learned_positions:
             # GPT-J: positions enter through rotary q/k rotation in each
             # block; there is no learned position table (``GPTJ.py:271-338``).
+            # (A hybrid stack may have neither: its recurrent layers order
+            # the tokens.)
             x = wte[tokens].astype(cfg.dtype)
         else:
             wpe = self.param(
@@ -490,16 +701,15 @@ class GPT2(nn.Module):
                 pos = wpe[:T]
             x = wte[tokens].astype(cfg.dtype) + pos.astype(cfg.dtype)
 
-        block_cls = Block
-        if cfg.remat:
-            block_cls = nn.remat(
-                Block, prevent_cse=False, policy=jax.checkpoint_policies.nothing_saveable
-            )
+        if cfg.layer_types is not None:
+            block_cls = PeriodBlock     # remat inside, layer by layer
+        else:
+            block_cls = _remat(Block) if cfg.remat else Block
         stack = nn.scan(
             block_cls,
             variable_axes={"params": 0, "aux_loss": 0},
             split_rngs={"params": True},
-            length=cfg.n_layers,
+            length=cfg.n_periods,
             metadata_params={nn.PARTITION_NAME: "layers"},
             unroll=cfg.scan_unroll,
         )
@@ -563,8 +773,10 @@ def build_gpt2(
     cfg = resolve_attention(config_for(name, **overrides))
     module = GPT2(cfg)
     head_key = "wte" if cfg.tie_head else "lm_head"
+    has_wpe = cfg.learned_positions and not cfg.rotary
     if pretrained is not None and (
         cfg.n_passes > 1 or cfg.sandwich_norm or not cfg.tie_head
+        or cfg.layer_types is not None or cfg.held_heads is not None
     ):
         raise NotImplementedError(
             "pretrained ingest knows the GPT-2 / GPT-J state-dict names only"
@@ -613,12 +825,15 @@ def build_gpt2(
     def pipeline_embed(other_params, tokens):
         T = tokens.shape[-1]
         x = other_params["wte"][tokens].astype(cfg.dtype)
-        if not cfg.rotary:
+        if has_wpe:
             x = x + other_params["wpe"][:T].astype(cfg.dtype)
         return x
 
+    # the unit of the scanned stack: a layer, or a period of several kinds
+    unit = PeriodBlock(cfg) if cfg.layer_types is not None else Block(cfg)
+
     def pipeline_block(layer_params, x):
-        y, _ = Block(cfg).apply({"params": layer_params}, x, None)
+        y, _ = unit.apply({"params": layer_params}, x, None)
         return y
 
     def final_norm(other_params, x):
@@ -675,11 +890,17 @@ def build_gpt2(
     hints = {
         "block_param_key": "blocks",  # where the scanned layer stack lives
         "n_layers": cfg.n_layers,
+        # layers of each kind in one scanned unit, where the stack has
+        # several kinds (``ModelSpec.stack_kinds``); the scan then runs
+        # ``n_layers / sum(kinds)`` periods and ``pipeline["block"]`` is one
+        # period
+        "stack_kinds": cfg.stack_kinds,
         "moe": {"n_experts": cfg.n_experts} if cfg.moe else None,
-        "embed_param_keys": ("wte",) if cfg.rotary else ("wte", "wpe"),
+        "embed_param_keys": ("wte", "wpe") if has_wpe else ("wte",),
         # factory accepts seq_axis/seq_axis_size; the sharded attention +
-        # boundary-label loss assume causal next-token training.
-        "seq_parallel": cfg.causal,
+        # boundary-label loss assume causal next-token training. A linear
+        # layer's state crosses the whole sequence: not sequence-parallel.
+        "seq_parallel": cfg.causal and "linear_attention" not in (cfg.layer_types or ()),
         "pipeline": {
             "embed": pipeline_embed,
             "block": pipeline_block,
@@ -717,6 +938,17 @@ def build_llama(name: str = "llama-1b", **overrides) -> ModelSpec:
     """Llama-class factory (RMSNorm + SwiGLU + GQA + rotary) — a family the
     reference zoo never had; every technique works on it because the stack
     is the same scanned-block ModelSpec contract."""
+    return build_gpt2(name, **overrides)
+
+
+def build_olmo_hybrid(name: str = "olmo-hybrid-7b", **overrides) -> ModelSpec:
+    """Olmo-Hybrid factory: periods of three gated-delta-rule layers
+    (``ops/gdn.py`` behind a 4-tap convolution) and one full-attention layer
+    with q/k norms, no position signal, post-norm blocks (x += N(f(x))),
+    SwiGLU, no bias, untied ``lm_head``. ``held_heads`` makes the program one
+    tensor-parallel rank's share of every mixer. Same ``ModelSpec`` contract
+    as :func:`build_gpt2`; the scanned unit, and so ``hints["pipeline"]``'s
+    ``block``, is one period."""
     return build_gpt2(name, **overrides)
 
 

@@ -185,6 +185,13 @@ class Pipeline(SPMDTechnique):
 
     def make_step_fns(self, spec, task, config, mesh, ds):
         self._require_no_aux(spec)  # staged forward would drop an aux loss
+        if spec.stack_kinds:
+            # stages are spans of ``config.n_layers`` equal layers; a period
+            # of several kinds is one scanned unit of unequal layers
+            # (ROADMAP.md, Reach: per-kind stage costs in balance_stages)
+            raise InfeasibleConfig(
+                f"pp: the model's stack holds several block kinds "
+                f"{spec.stack_kinds}; pp stages a stack of one kind")
         if spec.stack_passes != 1:
             # A looped stack would need the last stage's output (after the
             # between-passes norm) fed back to the first stage, which no

@@ -146,6 +146,10 @@ class _Bundle:
     # plan; None for a call that fell back to plain XLA ops); empty where the
     # step has no such call
     ce_plans: Tuple[Any, ...] = ()
+    # likewise each gated-delta-rule call (ops/gdn.py's plan: kernel or plain
+    # scan, chunk, grid, the kernel's VMEM sum); empty for a model with no
+    # linear-attention layer
+    gdn_plans: Tuple[Any, ...] = ()
     _compiled: Any = None
     _fused: Dict[int, Any] = field(default_factory=dict)
     _fused_lock: Any = field(default_factory=threading.Lock)
@@ -365,8 +369,15 @@ class SPMDTechnique(BaseTechnique):
         try:
             from saturn_tpu.analysis.shardflow.interp import interpret
 
-            traced = self.trace_step(task, devices, config)
-            flops = float(interpret(traced).flops) or None
+            if getattr(task.get_model(), "stack_kinds", None):
+                # shardflow counts dense products it can see; the chunked
+                # delta rule's are inside a kernel and a custom_vjp, so the
+                # count would be short by a mixer: no figure rather than a
+                # wrong one under ``tflops`` / ``mfu``
+                flops = None
+            else:
+                traced = self.trace_step(task, devices, config)
+                flops = float(interpret(traced).flops) or None
         except Exception:
             log.debug("shardflow flops trace failed for task %s", task.name,
                       exc_info=True)
@@ -771,8 +782,9 @@ class SPMDTechnique(BaseTechnique):
             ds.example_batch().shape, ds.example_batch().dtype
         )
         from saturn_tpu.ops import ce as _ce
+        from saturn_tpu.ops import gdn as _gdn
 
-        with _ce.traced_plans() as ce_plans:
+        with _ce.traced_plans() as ce_plans, _gdn.traced_plans() as gdn_plans:
             lowered = step.lower(state_shapes, batch_sds)
         return _Bundle(
             mesh=mesh,
@@ -785,6 +797,7 @@ class SPMDTechnique(BaseTechnique):
             train_step=train_step,
             batch_sds=batch_sds,
             ce_plans=tuple(ce_plans),
+            gdn_plans=tuple(gdn_plans),
         )
 
     # ------------------------------------------------------------- shardflow
@@ -939,7 +952,7 @@ class SPMDTechnique(BaseTechnique):
             # did not fit, which raised — the winner alone hides the rest
             _metrics.event("trial_config", task=task.name, size=len(devices),
                            technique=self.name, config=dict(config),
-                           **stack, **self._ce_plan_field(task, devices, config),
+                           **stack, **self._plan_fields(task, devices, config),
                            **fields)
 
         for config in self.candidate_configs(task, len(devices)):
@@ -1015,29 +1028,39 @@ class SPMDTechnique(BaseTechnique):
 
     @staticmethod
     def _stack_fields(task: Any) -> Dict[str, int]:
-        """``stack_layers`` / ``stack_passes`` of the task's model for the
+        """``stack_layers`` / ``stack_passes`` (and ``stack_kinds``, where the
+        stack has several) of the task's model for the
         ``trial_config`` and ``task_interval`` events (nothing where the
         model, or a stand-in for a ``ModelSpec``, does not say)."""
         spec = task.get_model()
         layers = getattr(spec, "stack_layers", None)
         if layers is None:
             return {}
-        return {"stack_layers": layers, "stack_passes": spec.stack_passes}
+        out = {"stack_layers": layers, "stack_passes": spec.stack_passes}
+        kinds = getattr(spec, "stack_kinds", None)
+        if kinds:   # a stack of several block kinds: layers of each a period
+            out["stack_kinds"] = kinds
+        return out
 
-    def _ce_plan_field(self, task, devices, config) -> Dict[str, Any]:
+    def _plan_fields(self, task, devices, config) -> Dict[str, Any]:
         """``ce_plan`` of a grid point whose loss is the fused one, for its
         ``trial_config`` event: the blocks, the backward's mode, the backward
         kernels' VMEM sums and what dx asked the compiler for, as the step
         was traced (``ops/ce.py::ce_plan``); None where the op computed
-        through plain XLA ops (off-TPU, or no block tiles the tokens).
-        Nothing where the point's program was never built or holds no fused
-        call."""
+        through plain XLA ops (off-TPU, or no block tiles the tokens). And
+        ``gdn_plan`` of a model with linear-attention layers: the first
+        layer's call of the gated delta rule (``ops/gdn.py::GDNPlan``:
+        kernel or plain scan, chunk, grid, the kernel's VMEM sum). Nothing where the point's program was
+        never built or holds no such call."""
         with self._bundles_lock:
             bundle = self._bundles.get(self._bundle_key(task, devices, config))
-        if bundle is None or not bundle.ce_plans:
-            return {}
-        plan = bundle.ce_plans[0]
-        return {"ce_plan": None if plan is None else plan._asdict()}
+        out: Dict[str, Any] = {}
+        if bundle is not None and bundle.ce_plans:
+            plan = bundle.ce_plans[0]
+            out["ce_plan"] = None if plan is None else plan._asdict()
+        if bundle is not None and bundle.gdn_plans:
+            out["gdn_plan"] = bundle.gdn_plans[0]._asdict()
+        return out
 
     def _profile_window(self, config: Dict[str, Any]) -> int:
         """K the trial should profile: steady-state execute() runs full

@@ -22,6 +22,7 @@ from jax.sharding import SingleDeviceSharding
 
 from saturn_tpu.ops import ce as ce_mod
 from saturn_tpu.ops import flash as flash_mod
+from saturn_tpu.ops import gdn as gdn_mod
 
 
 @pytest.fixture(scope="module")
@@ -50,6 +51,7 @@ def real_lowering(monkeypatch):
     CPU here; the compile is for the described chip, so steer them."""
     monkeypatch.setattr(flash_mod, "_use_interpret", lambda: False)
     monkeypatch.setattr(ce_mod, "_use_interpret", lambda: False)
+    monkeypatch.setattr(gdn_mod, "_use_interpret", lambda: False)
 
 
 def _compile(fn, *shapes, kernels):
@@ -70,8 +72,9 @@ def _flash_loss(q, k, v):
 
 @pytest.mark.parametrize("grad", [False, True], ids=["fwd", "grad"])
 @pytest.mark.parametrize(
-    "shape", [(8, 12, 512, 64), (8, 12, 1024, 64), (2, 16, 4096, 128)],
-    ids=["t512", "t1024", "ouro-t4096-h128"],
+    "shape", [(8, 12, 512, 64), (8, 12, 1024, 64), (2, 16, 4096, 128),
+              (1, 15, 8192, 128)],
+    ids=["t512", "t1024", "ouro-t4096-h128", "hybrid-15-heads-t8192-h128"],
 )
 def test_flash_attention_compiles_for_v5e(one_chip, real_lowering, shape, grad):
     sds = jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one_chip)
@@ -80,6 +83,28 @@ def test_flash_attention_compiles_for_v5e(one_chip, real_lowering, shape, grad):
     if grad:
         kernels += ["saturn_flash_dq", "saturn_flash_dkv"]
     _compile(fn, sds, sds, sds, kernels=kernels)
+
+
+# ------------------------------------------------------ gated delta rule
+@pytest.mark.parametrize("grad", [False, True], ids=["fwd", "grad"])
+def test_gated_delta_rule_kernel_compiles_for_v5e(one_chip, real_lowering, grad):
+    """``saturn_gdn_fwd`` / ``saturn_gdn_fwd_only`` at the hybrid cell's own
+    shape: 15 heads with keys of 96 and values of 192 lanes (neither a
+    multiple of 128), 8192 tokens, bf16 operands, a float32 state in VMEM."""
+    sds = lambda *shape, dtype=jnp.bfloat16: jax.ShapeDtypeStruct(
+        shape, dtype, sharding=one_chip)
+    args = (sds(1, 15, 8192, 96), sds(1, 15, 8192, 96), sds(1, 15, 8192, 192),
+            sds(1, 15, 8192, dtype=jnp.float32), sds(1, 15, 8192, dtype=jnp.float32))
+
+    def loss(*a):
+        return jnp.sum(gdn_mod.gated_delta_rule(*a, impl="kernel").astype(jnp.float32))
+
+    if grad:   # the differentiated forward keeps the chunks' states; the backward is XLA's
+        text = _compile(jax.grad(loss, argnums=(0, 1, 2, 3, 4)), *args,
+                        kernels=["saturn_gdn_fwd"])
+        assert "saturn_gdn_fwd_only" not in text
+    else:
+        _compile(loss, *args, kernels=["saturn_gdn_fwd_only"])
 
 
 # ---------------------------------------------------------------- fused CE
@@ -98,6 +123,10 @@ CE_SHAPES = {
     # dW 18.00 MiB) and falls out of the same rule
     "ouro-2.6b": (8192, 2048, 49152),
     "gpt2-medium-8k": (8192, 1024, 50257),
+    # d 3840 = 30 x 128 lanes and the held eighth of a vocabulary (12544 rows
+    # = 24.5 blocks of 512): the hybrid cell's head, stash mode (8192 x 12544
+    # bf16 scores are 0.2 GB); dx asks for its VMEM as at d 4096
+    "olmo-hybrid-8k": (8192, 3840, 12544),
 }
 
 
@@ -145,7 +174,7 @@ def test_fused_ce_grad_compiles_for_v5e(one_chip, real_lowering, name, stash):
     assert _vmem_limit_of(text, "saturn_ce_dx") == plan.dx_vmem_limit
     assert _vmem_limit_of(text, "saturn_ce_fwd") is None
     assert _vmem_limit_of(text, "saturn_ce_dw") is None
-    assert (plan.dx_vmem_limit is not None) == (d == 4096), plan
+    assert (plan.dx_vmem_limit is not None) == (d in (3840, 4096)), plan
     compiled = lowered.compile().as_text()
     for kernel in ("saturn_ce_fwd", "saturn_ce_dx", "saturn_ce_dw"):
         assert any(kernel in line for line in compiled.splitlines()
