@@ -26,6 +26,7 @@ from typing import Any, Dict, List, Optional, Sequence, Tuple
 import jax
 import numpy as np
 import optax
+from jax.extend.core import jaxpr_as_fun
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from saturn_tpu.core.mesh import make_submesh
@@ -130,17 +131,31 @@ def dispatch_signature() -> str:
 
 @dataclass
 class _Bundle:
-    """Everything needed to run one (task, devices, config) combination."""
+    """Everything needed to run one (task, devices, config) combination.
+
+    The bundle owns the ONE Python trace of its train step (``traced``,
+    made where the bundle is built): the 1-step program, every K-step window
+    program and every static analysis of this (task, config, block) replay
+    or read that closed jaxpr. The programs are lowered on first use, so a
+    search that profiles the K-step window never lowers the 1-step program.
+    """
 
     mesh: Any
-    step: Any                 # jitted train step: (state, batch) -> (state, loss)
+    step: Any                 # jitted replay: (state, batch) -> (state, loss)
     init: Any                 # jitted sharded init: () -> state
     state_shapes: Any         # ShapeDtypeStruct tree (for restore templates)
     state_shardings: Any
     batch_sharding: Any
-    lowered: Any              # jit(...).lower(...) result, for memory analysis
-    train_step: Any = None    # raw python step fn (fused scan re-traces it)
-    batch_sds: Any = None     # ShapeDtypeStruct of one host batch
+    # what ``SPMDTechnique.trace_step`` answers: the closed jaxpr of the raw
+    # train step at (state_shapes, batch_sds) with its sharding intent
+    traced: Dict[str, Any]
+    # the kept trace as a function: (state, batch) -> (state, loss), re-binding
+    # the jaxpr's equations (no Python trace of the model)
+    replay: Any
+    batch_sds: Any            # ShapeDtypeStruct of one host batch
+    # how often the model's Python step function has been called for this
+    # bundle (a one-element list the tracing site counts into): 1
+    trace_count: List[int]
     retrace_key: Any = None   # stable (task, config, block) dispatch identity
     # what each fused head+loss call of the step was traced as (ops/ce.py's
     # plan; None for a call that fell back to plain XLA ops); empty where the
@@ -150,7 +165,9 @@ class _Bundle:
     # scan, chunk, grid, the kernel's VMEM sum); empty for a model with no
     # linear-attention layer
     gdn_plans: Tuple[Any, ...] = ()
+    _lowered: Any = None
     _compiled: Any = None
+    _single_lock: Any = field(default_factory=threading.Lock)
     _fused: Dict[int, Any] = field(default_factory=dict)
     _fused_lock: Any = field(default_factory=threading.Lock)
 
@@ -159,6 +176,22 @@ class _Bundle:
         of every AOT-cache key (same program, different block = different
         executable)."""
         return list(self.mesh.devices.flat)
+
+    @property
+    def step_traces(self) -> int:
+        """Python traces of the train step this bundle has made."""
+        return self.trace_count[0]
+
+    @property
+    def lowered(self):
+        """``jit(...).lower(...)`` of the 1-step program, lowered on first
+        use from the kept trace (an interval's tail, ``_fits_memory``, the
+        K = 1 trial of an offloaded config, a warm-up)."""
+        with self._single_lock:
+            if self._lowered is None:
+                self._lowered = self.step.lower(self.state_shapes,
+                                                self.batch_sds)
+            return self._lowered
 
     @property
     def compiled(self):
@@ -189,11 +222,13 @@ class _Bundle:
     def fused_compiled(self, k: int):
         """AOT-compiled fused K-step program, compiled once per (bundle, K).
 
-        ``lax.scan`` of the raw train step over a stacked (K, batch, seq)
-        window inside one XLA program: one Python dispatch and one loss
-        readback amortize over K batches, and XLA pipelines the inter-step
-        boundary (no host round-trip between steps). State AND the window
-        stack are donated — the caller must stage a fresh stack per call.
+        ``lax.scan`` of the kept trace of the train step (``replay``: the
+        step's own equations as the scan body, no second Python trace) over a
+        stacked (K, batch, seq) window inside one XLA program: one Python
+        dispatch and one loss readback amortize over K batches, and XLA
+        pipelines the inter-step boundary (no host round-trip between steps).
+        State AND the window stack are donated — the caller must stage a fresh
+        stack per call.
         The per-step losses come back as a (K,) vector so the loss
         trajectory is observable exactly as the 1-step path reports it.
         """
@@ -202,9 +237,9 @@ class _Bundle:
             hit = self._fused.get(k)
         if hit is not None:
             return hit
-        train = self.train_step
-        if train is None or k < 1:
+        if k < 1:
             raise ValueError(f"bundle cannot build a fused window (k={k})")
+        train = self.replay
 
         def saturn_window(state, window):
             return jax.lax.scan(train, state, window)
@@ -298,9 +333,9 @@ class SPMDTechnique(BaseTechnique):
         self._bundles_lock = threading.Lock()
         # Static per-step FLOPs (shardflow's dense-dot ledger) per bundle
         # key — the numerator of the task_interval tflops/mfu report.
-        # Traced lazily at most once per compiled program; a failed trace
-        # caches None so telemetry degrades to omitting the fields instead
-        # of re-paying (or re-raising) the trace every interval.
+        # Counted lazily at most once per bundle, from the bundle's kept
+        # trace; a failed count caches None so telemetry degrades to omitting
+        # the fields instead of re-paying (or re-raising) it every interval.
         self._flops_cache: Dict[Any, Optional[float]] = {}
         self._flops_lock = threading.Lock()
         # What each (task, size) search saw (config, memory-rejection and
@@ -392,6 +427,19 @@ class SPMDTechnique(BaseTechnique):
             tuple(sorted((k, v) for k, v in config.items())),
             tuple(getattr(d, "id", i) for i, d in enumerate(devices)),
         )
+
+    def _cached_bundle(self, task, devices, config) -> Optional[_Bundle]:
+        """The bundle of (task, config, block) if the cache holds it (no LRU
+        touch, nothing built)."""
+        with self._bundles_lock:
+            return self._bundles.get(self._bundle_key(task, devices, config))
+
+    def _trace_source(self, task, devices, config) -> str:
+        """What a reader of ``trace_step`` is about to get, for its span's
+        ``trace`` field: ``shared`` where a bundle already keeps the trace,
+        ``own`` where the call has to build one first."""
+        cached = self._cached_bundle(task, devices, config) is not None
+        return "shared" if cached else "own"
 
     # ----------------------------------------------------------------- hooks
     def mesh_spec(
@@ -745,7 +793,7 @@ class SPMDTechnique(BaseTechnique):
 
         from saturn_tpu.analysis import jax_lint as _jlint
 
-        def shard_of(path, leaf):
+        def spec_of(path, leaf):
             spec_ = rules(shr._path_str(path), tuple(leaf.shape), mesh_axes)
             # Sharding lint (saturn-lint pass 2d): refuse a spec the mesh
             # cannot satisfy (unknown axis, rank overflow) HERE, on CPU,
@@ -754,18 +802,54 @@ class SPMDTechnique(BaseTechnique):
             # trial runner treats it like any infeasible configuration).
             _jlint.enforce_pspec(spec_, tuple(leaf.shape), mesh_axes,
                                  path=shr._path_str(path), rules=rules)
+            return spec_
+
+        def shard_of(spec_):
             if mem_kind is not None:
                 return NamedSharding(mesh, spec_, memory_kind=mem_kind)
             return NamedSharding(mesh, spec_)
 
-        state_shardings = jax.tree_util.tree_map_with_path(shard_of, state_shapes)
+        state_specs = jax.tree_util.tree_map_with_path(spec_of, state_shapes)
+        state_shardings = jax.tree_util.tree_map(
+            shard_of, state_specs, is_leaf=lambda x: isinstance(x, P)
+        )
         batch_sharding = NamedSharding(mesh, bspec)
+        batch_sds = jax.ShapeDtypeStruct(
+            ds.example_batch().shape, ds.example_batch().dtype
+        )
 
-        # Stable names for the two programs (the profile's module line, the
+        # THE trace of this (task, config, block): the raw step's equations
+        # as one closed jaxpr, abstract values only. Every program below and
+        # every analysis (``trace_step``) is made from it; ``trace_count``
+        # counts the calls of the model's Python step function, so a second
+        # tracing site would show in ``step_traces``.
+        from saturn_tpu.ops import ce as _ce
+        from saturn_tpu.ops import gdn as _gdn
+
+        trace_count = [0]
+
+        def counted_step(state, batch):
+            trace_count[0] += 1
+            return train_step(state, batch)
+
+        with _ce.traced_plans() as ce_plans, _gdn.traced_plans() as gdn_plans:
+            closed, out_shapes = jax.make_jaxpr(
+                counted_step, return_shape=True
+            )(state_shapes, batch_sds)
+        out_tree = jax.tree_util.tree_structure(out_shapes)
+        run_trace = jaxpr_as_fun(closed)
+
+        def replay(state, batch):
+            # every caller is a ``jit`` whose ``in_shardings`` hold the
+            # arguments to the trees the step was traced with
+            leaves = jax.tree_util.tree_leaves((state, batch))
+            return jax.tree_util.tree_unflatten(out_tree, run_trace(*leaves))
+
+        # Stable names for the programs (the profile's module line, the
         # ``compile`` event's span and a refactor then agree): the window
         # program is ``saturn_window`` (``_Bundle.fused_compiled``).
         def saturn_step(state, batch):
-            return train_step(state, batch)
+            return replay(state, batch)
 
         def saturn_init():
             return init_state()
@@ -778,14 +862,20 @@ class SPMDTechnique(BaseTechnique):
         )
         init = jax.jit(saturn_init, out_shardings=state_shardings)
 
-        batch_sds = jax.ShapeDtypeStruct(
-            ds.example_batch().shape, ds.example_batch().dtype
-        )
-        from saturn_tpu.ops import ce as _ce
-        from saturn_tpu.ops import gdn as _gdn
-
-        with _ce.traced_plans() as ce_plans, _gdn.traced_plans() as gdn_plans:
-            lowered = step.lower(state_shapes, batch_sds)
+        traced = {
+            "jaxpr": closed,
+            "state_shapes": state_shapes,
+            "state_specs": state_specs,
+            "batch_spec": bspec,
+            "batch_sds": batch_sds,
+            "mesh_axes": mesh_axes,
+            "technique": self.name,
+            "size": len(devices),
+            "config": dict(config),
+            # memlens: pinned-host configs keep resident params/opt-state
+            # in host memory, so the liveness pass excludes them from HBM
+            "param_memory_kind": mem_kind,
+        }
         return _Bundle(
             mesh=mesh,
             step=step,
@@ -793,9 +883,10 @@ class SPMDTechnique(BaseTechnique):
             state_shapes=state_shapes,
             state_shardings=state_shardings,
             batch_sharding=batch_sharding,
-            lowered=lowered,
-            train_step=train_step,
+            traced=traced,
+            replay=replay,
             batch_sds=batch_sds,
+            trace_count=trace_count,
             ce_plans=tuple(ce_plans),
             gdn_plans=tuple(gdn_plans),
         )
@@ -804,50 +895,18 @@ class SPMDTechnique(BaseTechnique):
     def trace_step(
         self, task: Any, devices: Sequence[Any], config: Dict[str, Any]
     ) -> Dict[str, Any]:
-        """Build hook for saturn-shardflow (``analysis/shardflow/``): trace
-        this technique's train step to a closed jaxpr together with its
-        sharding intent, **without compiling** — abstract values only, so
-        the static analyzer can propagate PartitionSpecs through every
-        equation on CPU before any chip time is spent.
+        """Build hook for saturn-shardflow (``analysis/shardflow/``) and
+        memlens: this technique's train step as a closed jaxpr together with
+        its sharding intent, **without compiling** — abstract values only, so
+        a static analyzer can propagate PartitionSpecs through every equation
+        on CPU before any chip time is spent.
 
-        Mirrors ``_build_uncached`` up to (but excluding) ``jit``/``lower``:
-        same mesh, same step functions, same rule-derived specs — if the two
-        ever diverge the differential test (``tests/test_shardflow_
-        differential.py``) catches it against the compiled program.
+        Answered from the bundle of this (task, config, block), built through
+        ``build`` where none is cached: the jaxpr is the one trace the
+        bundle's programs replay, so the analysers read the equations the
+        chip runs and nothing is traced for them a second time.
         """
-        spec = task.get_model(**self._model_overrides(config))
-        axis_names, axis_sizes = self.mesh_spec(len(devices), task, config)
-        mesh = make_submesh(devices, axis_names, axis_sizes)
-        mesh_axes = dict(zip(mesh.axis_names, mesh.devices.shape))
-
-        ds = task.get_dataset()
-        init_state, train_step = self.make_step_fns(spec, task, config, mesh, ds)
-        state_shapes = jax.eval_shape(init_state)
-        rules = self.param_rules(task, config)
-        state_specs = jax.tree_util.tree_map_with_path(
-            lambda path, leaf: rules(
-                shr._path_str(path), tuple(leaf.shape), mesh_axes
-            ),
-            state_shapes,
-        )
-        batch_sds = jax.ShapeDtypeStruct(
-            ds.example_batch().shape, ds.example_batch().dtype
-        )
-        closed = jax.make_jaxpr(train_step)(state_shapes, batch_sds)
-        return {
-            "jaxpr": closed,
-            "state_shapes": state_shapes,
-            "state_specs": state_specs,
-            "batch_spec": self.batch_spec(config),
-            "batch_sds": batch_sds,
-            "mesh_axes": mesh_axes,
-            "technique": self.name,
-            "size": len(devices),
-            "config": dict(config),
-            # memlens: pinned-host configs keep resident params/opt-state
-            # in host memory, so the liveness pass excludes them from HBM
-            "param_memory_kind": self.param_memory_kind(config),
-        }
+        return dict(self.build(task, devices, config).traced)
 
     # ------------------------------------------------------------ feasibility
     def _fits_memory(
@@ -883,7 +942,8 @@ class SPMDTechnique(BaseTechnique):
             need = hbm_bytes_required(compiled)
             sp.set(need_bytes=int(need), limit_bytes=int(limit))
             if task is not None and config is not None:
-                with _metrics.span("trial.memlens", k=int(k)):
+                with _metrics.span("trial.memlens", k=int(k), trace=(
+                        self._trace_source(task, devices, config))):
                     self._memlens_calibration(task, devices, config, need, k)
             if limit <= 0:
                 return True
@@ -1050,15 +1110,18 @@ class SPMDTechnique(BaseTechnique):
         through plain XLA ops (off-TPU, or no block tiles the tokens). And
         ``gdn_plan`` of a model with linear-attention layers: the first
         layer's call of the gated delta rule (``ops/gdn.py::GDNPlan``:
-        kernel or plain scan, chunk, grid, the kernel's VMEM sum). Nothing where the point's program was
-        never built or holds no such call."""
-        with self._bundles_lock:
-            bundle = self._bundles.get(self._bundle_key(task, devices, config))
-        out: Dict[str, Any] = {}
-        if bundle is not None and bundle.ce_plans:
+        kernel or plain scan, chunk, grid, the kernel's VMEM sum). Beside them
+        ``step_traces``: how often the model's Python step function was
+        called for this grid point (its bundle's one trace: 1). Nothing where
+        the point's bundle was never built."""
+        bundle = self._cached_bundle(task, devices, config)
+        if bundle is None:
+            return {}
+        out: Dict[str, Any] = {"step_traces": bundle.step_traces}
+        if bundle.ce_plans:
             plan = bundle.ce_plans[0]
             out["ce_plan"] = None if plan is None else plan._asdict()
-        if bundle is not None and bundle.gdn_plans:
+        if bundle.gdn_plans:
             out["gdn_plan"] = bundle.gdn_plans[0]._asdict()
         return out
 
@@ -1143,14 +1206,16 @@ class SPMDTechnique(BaseTechnique):
     def _spanned_build(self, name: str, task, devices, config,
                        parent=None) -> _Bundle:
         """``self.build`` under a span that says whether the bundle cache
-        had the program (``trial.build`` in a trial, ``launch.build`` at an
-        interval's launch)."""
+        had the bundle and how many Python traces of the train step the
+        bundle has made, this one included (``traces``: 1; ``trial.build`` in
+        a trial, ``launch.build`` at an interval's launch)."""
         with _metrics.span(name, parent=parent, task=task.name) as sp:
             if _metrics.enabled():
-                with self._bundles_lock:
-                    hit = self._bundle_key(task, devices, config) in self._bundles
+                hit = self._cached_bundle(task, devices, config) is not None
                 sp.set(cache="hit" if hit else "miss")
-            return self.build(task, devices, config)
+            bundle = self.build(task, devices, config)
+            sp.set(traces=bundle.step_traces)
+            return bundle
 
     @staticmethod
     def _spanned_compile(name: str, bundle: _Bundle, k: int, parent=None):
@@ -1159,7 +1224,9 @@ class SPMDTechnique(BaseTechnique):
         whether this call was the one that compiled it (``was_warm``),
         whether the AOT cache gave the executable (``aot``) and, where the
         compiler refused the program for memory, whether it did so now or
-        on record (``refusal`` = ``fresh`` / ``recorded``)."""
+        on record (``refusal`` = ``fresh`` / ``recorded``). ``trace`` is
+        ``shared``: the program replays the bundle's kept trace (``own`` would
+        say that making it called the model's Python step function again)."""
         def aot_hits() -> int:
             stats = aot_cache.stats()
             return stats["hits"] + stats["warm_hits"]
@@ -1169,11 +1236,15 @@ class SPMDTechnique(BaseTechnique):
                 sp.set(was_warm=bool(bundle.has_fused(k) if k > 1
                                      else bundle._compiled is not None))
                 before = aot_hits()
+            traces = bundle.step_traces
             try:
                 out = bundle.fused_compiled(k) if k > 1 else bundle.compiled
             except aot_cache.CompileRefused as e:
                 sp.set(refusal=e.refusal)
                 raise
+            finally:
+                sp.set(trace="shared" if bundle.step_traces == traces
+                       else "own")
             if _metrics.enabled():
                 sp.set(aot="hit" if aot_hits() > before else "miss")
             return out
@@ -1546,9 +1617,11 @@ class SPMDTechnique(BaseTechnique):
                 with self._flops_lock:
                     cached = key in self._flops_cache
                 # what the package's own tflops / mfu costs the interval: the
-                # first one of a program re-traces the step (shardflow)
+                # first one of a program runs shardflow over the bundle's
+                # kept trace
                 with _metrics.span("step_flops", parent=ti, task=task.name,
-                                   cached=cached):
+                                   cached=cached, trace=self._trace_source(
+                                       task, devices, config)):
                     step_flops = self._step_flops(task, devices, config)
                 if step_flops:
                     achieved = step_flops * n / max(elapsed_all, 1e-9)

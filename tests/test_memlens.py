@@ -244,6 +244,11 @@ def test_prediction_feeds_fits_compiled_calibration(dp_traced, tiny_task,
     events = [json.loads(l) for l in open(path) if l.strip()]
     cal = [e for e in events if e.get("kind") == "memlens_calibration"]
     assert len(cal) == 1
+    # PR 34: the audit reads the trace of the bundle ``dp_traced`` built
+    # (``trace_step`` answers from the bundle cache) and traces nothing itself
+    (audit,) = [e for e in events if e.get("kind") == "trial.memlens"]
+    assert audit["trace"] == "shared"
+    assert tech.build(tiny_task, devices8[:4], config).step_traces == 1
     assert cal[0]["technique"] == "dp" and cal[0]["k"] == 1
     assert cal[0]["predicted_bytes"] > 0
     assert cal[0]["compiled_bytes"] >= 0

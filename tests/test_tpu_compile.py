@@ -207,7 +207,12 @@ def test_dp_step_with_sharded_fused_ce_compiles_for_v5e_2x2(
     )
     bundle = DataParallel()._build_uncached(
         task, list(topo.devices), {"remat": False, "attention": "dense"})
+    # PR 34: the build keeps the step's one trace and lowers nothing; the
+    # 1-step program is lowered here, on first use, from that trace (the
+    # kernels' ``pallas_call``s and the ``shard_map`` around them bound again)
+    assert bundle.step_traces == 1 and bundle._lowered is None
     text = bundle.lowered.compile().as_text()
+    assert bundle.step_traces == 1
     calls = [l for l in text.splitlines() if "tpu_custom_call" in l]
     assert sum("saturn_ce_" in l for l in calls) == 3
     # what the grid point's ``trial_config`` event carries as ``ce_plan``: the
@@ -247,4 +252,6 @@ def test_looped_stack_outside_the_model_compiles_for_v5e_2x2(
     tech = BUILTIN_TECHNIQUES[name]()
     assert config in tech.candidate_configs(task, 4)
     bundle = tech._build_uncached(task, list(topo.devices), dict(config))
+    assert bundle._lowered is None   # lowered on first use (PR 34)
     assert "while" in bundle.lowered.compile().as_text()
+    assert bundle.step_traces == 1
