@@ -57,6 +57,13 @@ class ModelSpec:
     # forward) — lets wrappers (models/bert.py) build their own fused
     # objectives on top of this model's trunk.
     hidden_fn: Optional[Callable[[Any, Any], Any]] = None
+    # Optional: ``fused_loss_fn``'s loss with the step's counters beside it,
+    # ``(params, inputs) -> (loss, {name: scalar})`` (a routed-expert layer's
+    # pairs, fullest expert, second path). A technique that takes the fused
+    # loss on one device differentiates this instead (``has_aux``); the
+    # counters then ride the step's loss output to the ``task_interval``
+    # event and are read back with the losses.
+    fused_loss_stats_fn: Optional[Callable[[Any, Any], Any]] = None
 
     @property
     def stack_passes(self) -> int:
@@ -82,6 +89,15 @@ class ModelSpec:
         ``hints["pipeline"]["block"]`` is one period."""
         kinds = self.hints.get("stack_kinds")
         return None if not kinds else {str(k): int(v) for k, v in kinds.items()}
+
+    @property
+    def stack_lead(self) -> Optional[Dict[str, int]]:
+        """Layers before the scanned periods, by kind, e.g.
+        ``{"full_attention_dense": 1}``: ``hints["stack_lead"]``; they are in
+        ``stack_layers``, not in a period, and run inside
+        ``hints["pipeline"]["embed"]``. None where the stack has none."""
+        lead = self.hints.get("stack_lead")
+        return None if not lead else {str(k): int(v) for k, v in lead.items()}
 
     def abstract_init(self):
         import jax

@@ -142,6 +142,48 @@ class GPT2Config:
     lin_conv: int = 4
     lin_neg_eigval: bool = True
     lin_chunk: int = 64
+    # Routed-expert / mixed-window structure knobs (Laguna-class: a leading
+    # dense layer, then periods of sliding-window and full-attention layers
+    # whose feed-forward is a shared expert beside top-k routed experts).
+    # Each at its default leaves every earlier preset's program unchanged op
+    # for op.
+    #   head_width: a head's lanes where ``d_model / n_heads`` is not it (q
+    #     is then wider than the stream: ``heads x head_width`` lanes).
+    #   kind_heads: q heads of a layer kind where the kinds differ,
+    #     (("full_attention", 48), ("sliding_attention", 64)); the k/v heads
+    #     are ``n_kv_heads`` for every kind.
+    #   window: a "sliding_attention" layer's reach, the token itself counted
+    #     (query i reads keys i - window + 1 .. i).
+    #   window_rope_theta: a sliding layer rotates all its lanes at this
+    #     base; a full layer keeps ``rotary_dim`` / ``rope_theta`` and, with
+    #     ``yarn`` = (factor, original positions, beta_fast, beta_slow,
+    #     attention factor), YaRN's interpolated frequencies.
+    #   attn_gate: a sigmoid gate a head on the attention output, from the
+    #     block's normed input (``attn_gate``, d_model -> heads).
+    #   lead_layers: layers before the scanned periods, outside the scan
+    #     (param key ``lead``): full attention and the dense MLP ``d_ff``.
+    #     ``n_layers`` counts them.
+    #   routed_experts: experts the router scores (0 = no routed layer);
+    #     ``held_experts`` of them are computed here (a chip's share, the
+    #     first ones: the router keeps all its outputs and its ``top_k`` a
+    #     token, the layer computes the part of the result the held experts
+    #     give, nothing stands in for the rest); ``expert_ff`` /
+    #     ``shared_ff`` the experts' and the shared expert's SwiGLU widths;
+    #     ``routed_scale`` multiplies the normalised weights. The row buffer
+    #     and its tile are ``ops/moe.py``'s (``BUFFER``, ``ROW_TILE``).
+    head_width: Optional[int] = None
+    kind_heads: Optional[Tuple[Tuple[str, int], ...]] = None
+    window: Optional[int] = None
+    window_rope_theta: float = 10000.0
+    yarn: Optional[Tuple[float, int, float, float, float]] = None
+    attn_gate: bool = False
+    lead_layers: int = 0
+    routed_experts: int = 0
+    held_experts: Optional[int] = None
+    top_k: int = 8
+    expert_ff: int = 512
+    shared_ff: int = 0
+    routed_scale: float = 1.0
     name: str = "gpt2-small"
 
     def __post_init__(self) -> None:
@@ -195,13 +237,22 @@ class GPT2Config:
         if self.layer_types is not None:
             object.__setattr__(self, "layer_types", tuple(self.layer_types))
             kinds = set(self.layer_types)
-            if not kinds or kinds - {"linear_attention", "full_attention"}:
-                raise ValueError(f"layer_types holds 'linear_attention' / "
-                                 f"'full_attention', got {self.layer_types!r}")
-            if self.n_layers % len(self.layer_types) != 0:
+            if not kinds or kinds - {"linear_attention", "full_attention",
+                                     "sliding_attention"}:
                 raise ValueError(
-                    f"n_layers ({self.n_layers}) must be whole periods of "
+                    f"layer_types holds 'linear_attention' / 'full_attention' "
+                    f"/ 'sliding_attention', got {self.layer_types!r}")
+            if (self.n_layers - self.lead_layers) % len(self.layer_types) != 0:
+                raise ValueError(
+                    f"n_layers ({self.n_layers}) less the {self.lead_layers} "
+                    f"leading must be whole periods of "
                     f"{len(self.layer_types)} layers")
+            if "sliding_attention" in kinds and (
+                not self.window or not self.causal or self.seq_axis is not None
+            ):
+                raise ValueError(
+                    "a sliding-attention layer needs window >= 1 and is causal "
+                    "and single-program")
             if "linear_attention" in kinds and (
                 not self.causal or self.seq_axis is not None or self.moe
             ):
@@ -209,9 +260,41 @@ class GPT2Config:
                     "a linear-attention layer is causal, dense-MLP and "
                     "single-program (its state crosses the whole sequence)")
 
+        if self.kind_heads is not None:
+            object.__setattr__(self, "kind_heads", tuple(
+                (str(k), int(h)) for k, h in self.kind_heads))
+            if self.held_heads is not None or self.n_kv_heads is None or any(
+                    h % self.n_kv_heads for _, h in self.kind_heads):
+                raise ValueError(
+                    "kind_heads needs whole heads over n_kv_heads k/v heads "
+                    f"that divide each count, got {self.kind_heads!r}")
+        if self.lead_layers and self.layer_types is None:
+            raise ValueError("lead_layers precede a stack of layer_types")
+        if self.yarn is not None:
+            object.__setattr__(self, "yarn", tuple(self.yarn))
+        if self.routed_experts:
+            held = self.experts_held
+            if (self.layer_types is None or self.moe or self.seq_axis is not None
+                    or self.mlp_act != "swiglu"
+                    or not 1 <= self.top_k <= self.routed_experts
+                    or held < 1 or self.routed_experts % held):
+                raise ValueError(
+                    "a routed layer is SwiGLU, in a stack of layer_types, "
+                    "single-program, with held_experts a whole share of "
+                    f"routed_experts: got top_k {self.top_k}, "
+                    f"{self.held_experts} of {self.routed_experts} experts")
+
     @property
     def head_dim(self) -> int:
-        return self.d_model // self.n_heads
+        return self.head_width or self.d_model // self.n_heads
+
+    @property
+    def experts_held(self) -> int:
+        return self.routed_experts if self.held_experts is None else self.held_experts
+
+    def heads_of(self, kind: str) -> int:
+        """q heads of a layer of ``kind`` (held, where a share is held)."""
+        return dict(self.kind_heads or ()).get(kind, self.heads_held)
 
     @property
     def ff_dim(self) -> int:
@@ -225,7 +308,7 @@ class GPT2Config:
     def n_periods(self) -> int:
         """Trip count of the layer scan: periods of ``layer_types``, or
         layers where the stack has one kind."""
-        return self.n_layers // len(self.layer_types or (None,))
+        return (self.n_layers - self.lead_layers) // len(self.layer_types or (None,))
 
     @property
     def stack_kinds(self) -> Optional[Dict[str, int]]:
@@ -233,6 +316,12 @@ class GPT2Config:
         if self.layer_types is None:
             return None
         return {k: self.layer_types.count(k) for k in dict.fromkeys(self.layer_types)}
+
+    @property
+    def stack_lead(self) -> Optional[Dict[str, int]]:
+        """Layers before the scanned periods, by kind (full attention with
+        the dense MLP); None where the stack has none."""
+        return {"full_attention_dense": self.lead_layers} if self.lead_layers else None
 
     def example_inputs(self, batch_size: int = 1):
         return jnp.zeros((batch_size, self.seq_len), dtype=jnp.int32)
@@ -320,6 +409,42 @@ PRESETS: Dict[str, Dict[str, Any]] = {
         lin_key_dim=12, lin_value_dim=24, lin_conv=4, lin_neg_eigval=True,
         lin_chunk=16,
     ),
+    # Laguna (poolside/Laguna-XS.2, 33.4B-A3B): a leading dense layer (full
+    # attention, SwiGLU 8192), then periods of three sliding-window layers
+    # (64 q heads, window 512, plain rotary on all 128 lanes) and one
+    # full-attention layer (48 q heads, YaRN rotary on the first 64 lanes),
+    # all over 8 k/v heads of 128 with a sigmoid gate a head; every layer of
+    # a period feeds a shared expert beside 256 routed ones (top-8, sigmoid
+    # scores normalised and scaled 2.5), each a SwiGLU of 512. RMSNorm
+    # before each branch, no bias, an untied head. The published order
+    # starts each period at its full layer (layers 0, 4, 8, ..): with layer 0
+    # taken out as the leading dense layer, the scanned period is layers
+    # 1-4: sliding, sliding, sliding, full. A stack is 1 + 4 p layers: 37
+    # here, the published 40 less the three sliding layers that follow the
+    # last full one (a trailing part of a period is not built: ROADMAP.md,
+    # Reach).
+    "laguna-xs2": dict(
+        d_model=2048, n_layers=37, n_heads=48, n_kv_heads=8, head_width=128,
+        d_ff=8192, vocab_size=100352, rotary=True, rotary_dim=64,
+        rope_theta=500000.0, yarn=(64.0, 4096, 64.0, 1.0, 1.4158883083359672),
+        norm="rmsnorm", mlp_act="swiglu",
+        use_bias=False, tie_head=False, attn_gate=True, lead_layers=1,
+        layer_types=("sliding_attention",) * 3 + ("full_attention",),
+        kind_heads=(("full_attention", 48), ("sliding_attention", 64)),
+        window=512, routed_experts=256, top_k=8, expert_ff=512, shared_ff=512,
+        routed_scale=2.5,
+    ),
+    "laguna-test-tiny": dict(
+        d_model=64, n_layers=5, n_heads=6, n_kv_heads=2, head_width=16,
+        d_ff=128, vocab_size=256, seq_len=64, rotary=True, rotary_dim=8,
+        rope_theta=500000.0, yarn=(64.0, 16, 8.0, 1.0, 1.4158883083359672),
+        norm="rmsnorm", mlp_act="swiglu",
+        use_bias=False, tie_head=False, attn_gate=True, lead_layers=1,
+        layer_types=("sliding_attention",) * 3 + ("full_attention",),
+        kind_heads=(("full_attention", 6), ("sliding_attention", 8)),
+        window=24, routed_experts=16, held_experts=4, top_k=4, expert_ff=32,
+        shared_ff=32, routed_scale=2.5,
+    ),
     # Switch-style MoE family (extension beyond the reference; SURVEY.md §2.3
     # lists EP as absent there).
     "moe-test-tiny": dict(
@@ -344,6 +469,47 @@ def rotary_sin_cos(positions: jax.Array, rotary_dim: int,
     )
     angles = positions.astype(jnp.float32)[:, None] * inv_freq[None, :]
     return jnp.sin(angles), jnp.cos(angles)
+
+
+def yarn_inv_freq(rotary_dim: int, theta: float, factor: float,
+                  original_positions: int, beta_fast: float, beta_slow: float):
+    """YaRN's frequencies, (rotary_dim // 2,) float32: each plain frequency
+    ``theta^(-2j / rotary_dim)`` interpolated towards itself / ``factor`` by a
+    linear ramp over the dimensions between the one that turns ``beta_fast``
+    times in ``original_positions`` positions (and faster: kept) and the one
+    that turns ``beta_slow`` times (and slower: divided by ``factor``)."""
+    plain = 1.0 / (theta ** (jnp.arange(0, rotary_dim, 2, dtype=jnp.float32)
+                             / rotary_dim))
+
+    def turns_at(turns):   # the (real-valued) dimension that turns so often
+        return rotary_dim * math.log(original_positions / (turns * 2 * math.pi)) \
+            / (2 * math.log(theta))
+
+    low = max(math.floor(turns_at(beta_fast)), 0)
+    high = min(math.ceil(turns_at(beta_slow)), rotary_dim - 1)
+    if low == high:
+        high += 0.001
+    ramp = jnp.clip((jnp.arange(rotary_dim // 2, dtype=jnp.float32) - low)
+                    / (high - low), 0.0, 1.0)
+    return plain / factor * ramp + plain * (1.0 - ramp)
+
+
+def rotary_tables(cfg: "GPT2Config", kind: str, positions: jax.Array):
+    """(sin, cos, rotary_dim) of a softmax layer of ``kind``: a sliding layer
+    rotates all its lanes at ``window_rope_theta``; any other the first
+    ``rotary_dim`` at ``rope_theta``, with YaRN's frequencies and its
+    attention factor on sin and cos where ``cfg.yarn`` says."""
+    if kind == "sliding_attention":
+        rd = cfg.head_dim
+        return (*rotary_sin_cos(positions, rd, cfg.window_rope_theta), rd)
+    rd = cfg.rotary_dim or cfg.head_dim
+    if cfg.yarn is None:
+        return (*rotary_sin_cos(positions, rd, cfg.rope_theta), rd)
+    factor, original, beta_fast, beta_slow, attention_factor = cfg.yarn
+    angles = positions.astype(jnp.float32)[:, None] * yarn_inv_freq(
+        rd, cfg.rope_theta, factor, int(original), beta_fast, beta_slow)[None, :]
+    return (jnp.sin(angles) * attention_factor,
+            jnp.cos(angles) * attention_factor, rd)
 
 
 def apply_rotary(t: jax.Array, sin: jax.Array, cos: jax.Array, rotary_dim: int):
@@ -401,6 +567,9 @@ class Block(nn.Module):
 
     cfg: GPT2Config
     kind: str = "full_attention"
+    #: "routed": the feed-forward is a shared expert beside the held routed
+    #: experts (``_routed_mlp``); "dense": the MLP of ``d_ff``
+    ff: str = "dense"
 
     @nn.compact
     def __call__(self, x, _unused):
@@ -428,6 +597,8 @@ class Block(nn.Module):
         def mlp(inp):
             if cfg.moe:
                 return self._moe_mlp(inp)
+            if self.ff == "routed":
+                return self._routed_mlp(inp, dense)
             if cfg.mlp_act == "swiglu":
                 # Separate gate/up projections (NOT one fused 2F Dense): the
                 # TP column rule shards each kernel's output dim, so
@@ -461,20 +632,23 @@ class Block(nn.Module):
         cfg = self.cfg
         dt = cfg.dtype
         B, T, D = h.shape
-        A = cfg.heads_held * cfg.head_dim
+        n_q = cfg.heads_of(self.kind)
+        A = n_q * cfg.head_dim
+        window = cfg.window if self.kind == "sliding_attention" else None
         if cfg.n_kv_heads is None:
             qkv = dense(3 * A, "qkv")(h)
             q, k, v = jnp.split(qkv, 3, axis=-1)
-            kv_heads = cfg.heads_held
+            kv_heads = n_q
         else:
             # Grouped-query attention: k/v carry n_kv_heads; one fused
-            # projection sized D + 2 * kv_dim.
+            # projection sized A + 2 * kv_dim (A = the q heads' lanes: D
+            # wherever heads x head_dim is the stream's width).
             kv_heads = cfg.n_kv_heads
             kv_dim = kv_heads * cfg.head_dim
-            qkv = dense(D + 2 * kv_dim, "qkv")(h)
-            q = qkv[..., :D]
-            k = qkv[..., D:D + kv_dim]
-            v = qkv[..., D + kv_dim:]
+            qkv = dense(A + 2 * kv_dim, "qkv")(h)
+            q = qkv[..., :A]
+            k = qkv[..., A:A + kv_dim]
+            v = qkv[..., A + kv_dim:]
         if cfg.qk_norm:
             # over all held heads' lanes together (with a share of the heads
             # the statistic is the held share's: ROADMAP.md, Reach)
@@ -483,20 +657,18 @@ class Block(nn.Module):
         def heads(t, n):
             return t.reshape(B, T, n, cfg.head_dim).transpose(0, 2, 1, 3)
 
-        q = heads(q, cfg.heads_held)
+        q = heads(q, n_q)
         k, v = heads(k, kv_heads), heads(v, kv_heads)
         if cfg.rotary:
-            rd = cfg.rotary_dim or cfg.head_dim
             if cfg.seq_axis is not None:
                 # Global positions for a sequence-sharded chunk.
                 offset = jax.lax.axis_index(cfg.seq_axis) * T
             else:
                 offset = 0
-            sin, cos = rotary_sin_cos(jnp.arange(T) + offset, rd,
-                                      cfg.rope_theta)
+            sin, cos, rd = rotary_tables(cfg, self.kind, jnp.arange(T) + offset)
             q = apply_rotary(q, sin, cos, rd)
             k = apply_rotary(k, sin, cos, rd)
-        if kv_heads != cfg.heads_held and not (
+        if kv_heads != n_q and not (
             cfg.seq_axis is None and self._attention_impl() == "flash"
         ):
             # GQA on the non-flash paths: repeat k/v head groups up to
@@ -504,7 +676,7 @@ class Block(nn.Module):
             # params stay at kv_heads — the repeat is activation-only. The
             # flash kernel handles grouped k/v natively (ops/flash.py), so
             # the expanded activations never exist there.
-            rep = cfg.n_heads // kv_heads
+            rep = (n_q if cfg.kind_heads else cfg.n_heads) // kv_heads
             k = jnp.repeat(k, rep, axis=1)
             v = jnp.repeat(v, rep, axis=1)
         if cfg.seq_axis is not None:
@@ -524,16 +696,23 @@ class Block(nn.Module):
         elif self._attention_impl() == "flash":
             from saturn_tpu.ops.flash import flash_attention
 
-            attn = flash_attention(q, k, v, causal=cfg.causal)
+            attn = flash_attention(q, k, v, causal=cfg.causal, window=window)
         else:
             # fp32 softmax accumulation for stability; matmuls stay bf16-in.
             scores = jnp.einsum("bhqd,bhkd->bhqk", q, k).astype(jnp.float32)
             scores = scores / math.sqrt(cfg.head_dim)
             if cfg.causal:
                 mask = jnp.tril(jnp.ones((T, T), dtype=bool))
+                if window is not None:   # keys i - window + 1 .. i
+                    mask = mask & ~jnp.tril(jnp.ones((T, T), dtype=bool), -window)
                 scores = jnp.where(mask[None, None], scores, jnp.float32(-1e30))
             probs = jax.nn.softmax(scores, axis=-1).astype(dt)
             attn = jnp.einsum("bhqk,bhkd->bhqd", probs, v)
+        if cfg.attn_gate:
+            # one scalar a head from the block's normed input, float32
+            gate = jax.nn.sigmoid(dense(n_q, "attn_gate")(h).astype(jnp.float32))
+            attn = (attn.astype(jnp.float32)
+                    * gate.transpose(0, 2, 1)[..., None]).astype(dt)
         return attn.transpose(0, 2, 1, 3).reshape(B, T, A)
 
     def _linear_mixer(self, h, dense):
@@ -611,6 +790,42 @@ class Block(nn.Module):
         rule, shared with the factory path (:func:`resolve_attention`)."""
         return resolve_attention(self.cfg).attention
 
+    def _routed_mlp(self, inp, dense):
+        """A shared expert beside the held share of ``routed_experts`` routed
+        ones (``ops/moe.py::routed_experts``): every expert a SwiGLU of
+        ``expert_ff`` (``shared_ff``). The router scores all the experts in
+        float32 and keeps its ``top_k`` a token; the tables hold
+        ``held_experts`` experts (a leading expert axis: dim 1 under the
+        layer scan) in ``param_dtype`` and are rounded to ``dtype`` inside
+        the op. The kernels run where the attention implementation is
+        "flash", the plain twin where it is "dense". The layer's counters go
+        to the ``moe_stats`` collection."""
+        from saturn_tpu.ops.moe import routed_experts, routed_plan
+
+        cfg = self.cfg
+        B, T, D = inp.shape
+        E, held, F = cfg.routed_experts, cfg.experts_held, cfg.expert_ff
+        pdt = cfg.param_dtype
+        init = nn.initializers.normal(0.02)
+        router = self.param("router", init, (D, E), pdt)
+        w_gate = self.param("we_gate", init, (held, D, F), pdt)
+        w_up = self.param("we_up", init, (held, D, F), pdt)
+        w_down = self.param("we_down", init, (held, F, D), pdt)
+        plan = routed_plan(
+            B * T, E, held, cfg.top_k,
+            impl="kernel" if self._attention_impl() == "flash" else "xla")
+        y, stats = routed_experts(
+            inp.reshape(B * T, D), router, w_gate, w_up, w_down, plan=plan,
+            scale=cfg.routed_scale, dtype=cfg.dtype)
+        for name, value in stats.items():
+            self.sow("moe_stats", name, value)
+        y = y.reshape(B, T, D)
+        if cfg.shared_ff:
+            m = nn.silu(dense(cfg.shared_ff, "shared_gate")(inp)) \
+                * dense(cfg.shared_ff, "shared_in")(inp)
+            y = y + dense(D, "shared_out")(m)
+        return y
+
     def _moe_mlp(self, inp):
         """Expert MLP with explicit (E, ...) weight tables — the leading
         expert axis is what the EP executor shards over the ``expert`` mesh
@@ -639,12 +854,18 @@ class Block(nn.Module):
         return y
 
 
-def _remat(block_cls, prevent_cse: bool = False):
+def _remat(block_cls, prevent_cse: bool = False, routed: bool = False):
     """``prevent_cse=False`` is for a block that is a scan's whole body: the
     loop boundary already keeps the backward's recomputation apart from the
-    forward. Several rematerialised blocks in one body need the barriers."""
-    return nn.remat(block_cls, prevent_cse=prevent_cse,
-                    policy=jax.checkpoint_policies.nothing_saveable)
+    forward. Several rematerialised blocks in one body need the barriers.
+    A ``routed`` block keeps its row buffer's integer tables (a megabyte;
+    ``ops/moe.py::routed_layout``): its backward does not sort again."""
+    policy = jax.checkpoint_policies.nothing_saveable
+    if routed:
+        from saturn_tpu.ops.moe import LAYOUT_NAME
+
+        policy = jax.checkpoint_policies.save_only_these_names(LAYOUT_NAME)
+    return nn.remat(block_cls, prevent_cse=prevent_cse, policy=policy)
 
 
 class PeriodBlock(nn.Module):
@@ -658,10 +879,28 @@ class PeriodBlock(nn.Module):
 
     @nn.compact
     def __call__(self, x, _unused):
-        block_cls = _remat(Block, prevent_cse=True) if self.cfg.remat else Block
+        ff = "routed" if self.cfg.routed_experts else "dense"
+        block_cls = Block
+        if self.cfg.remat:
+            block_cls = _remat(Block, prevent_cse=True, routed=ff == "routed")
         for i, kind in enumerate(self.cfg.layer_types):
-            x, _ = block_cls(self.cfg, kind=kind, name=f"l{i}")(x, None)
+            x, _ = block_cls(self.cfg, kind=kind, ff=ff, name=f"l{i}")(x, None)
         return x, None
+
+
+class LeadBlocks(nn.Module):
+    """The ``cfg.lead_layers`` layers before the scanned periods, each a
+    full-attention :class:`Block` with the dense MLP under the name
+    ``l<i>``; rematerialised one by one under remat, like a period's."""
+
+    cfg: GPT2Config
+
+    @nn.compact
+    def __call__(self, x):
+        block_cls = _remat(Block, prevent_cse=True) if self.cfg.remat else Block
+        for i in range(self.cfg.lead_layers):
+            x, _ = block_cls(self.cfg, kind="full_attention", name=f"l{i}")(x, None)
+        return x
 
 
 class GPT2(nn.Module):
@@ -701,13 +940,16 @@ class GPT2(nn.Module):
                 pos = wpe[:T]
             x = wte[tokens].astype(cfg.dtype) + pos.astype(cfg.dtype)
 
+        if cfg.lead_layers:
+            # the layers before the periods: outside the scan, counted once
+            x = LeadBlocks(cfg, name="lead")(x)
         if cfg.layer_types is not None:
             block_cls = PeriodBlock     # remat inside, layer by layer
         else:
             block_cls = _remat(Block) if cfg.remat else Block
         stack = nn.scan(
             block_cls,
-            variable_axes={"params": 0, "aux_loss": 0},
+            variable_axes={"params": 0, "aux_loss": 0, "moe_stats": 0},
             split_rngs={"params": True},
             length=cfg.n_periods,
             metadata_params={nn.PARTITION_NAME: "layers"},
@@ -777,6 +1019,7 @@ def build_gpt2(
     if pretrained is not None and (
         cfg.n_passes > 1 or cfg.sandwich_norm or not cfg.tie_head
         or cfg.layer_types is not None or cfg.held_heads is not None
+        or cfg.routed_experts
     ):
         raise NotImplementedError(
             "pretrained ingest knows the GPT-2 / GPT-J state-dict names only"
@@ -827,6 +1070,10 @@ def build_gpt2(
         x = other_params["wte"][tokens].astype(cfg.dtype)
         if has_wpe:
             x = x + other_params["wpe"][:T].astype(cfg.dtype)
+        if cfg.lead_layers:
+            # everything before the scanned stack: a technique that rebuilds
+            # the model from these pieces runs the leading layers here
+            x = LeadBlocks(cfg).apply({"params": other_params["lead"]}, x)
         return x
 
     # the unit of the scanned stack: a layer, or a period of several kinds
@@ -849,7 +1096,31 @@ def build_gpt2(
     def hidden_fn(params, tokens):
         return module.apply({"params": params}, tokens, return_hidden=True)
 
-    fused_loss_fn = fused_loss_parts_fn = None
+    def routed_stats(collected):
+        """The routed layers' counters of one step, over layers: the pairs
+        computed a layer, the fullest held expert's rows and the mean
+        (``ops/moe.py::routed_experts``), and whether any layer needed the
+        exact second path."""
+        per = {name: jnp.concatenate([jnp.reshape(v, (-1,)) for v in jax.tree.leaves(
+            {k: c[name] for k, c in sorted(collected.items())})]).astype(jnp.float32)
+            for name in ("pairs_held", "rows_max", "second_path")}
+        return {"moe_pairs_held": jnp.mean(per["pairs_held"]),
+                "moe_rows_max": jnp.max(per["rows_max"]),
+                "moe_rows_mean": jnp.mean(per["pairs_held"]) / cfg.experts_held,
+                "moe_second_path": jnp.max(per["second_path"])}
+
+    fused_loss_fn = fused_loss_parts_fn = fused_loss_stats_fn = routing_fn = None
+    if cfg.routed_experts:
+        def routing_fn(params, tokens):
+            """The experts every routed layer's router chose, (layers, B * T,
+            top_k) int32 in the stack's order: for a comparison of routing."""
+            _, mut = module.apply({"params": params}, tokens, return_hidden=True,
+                                  mutable=["moe_stats"])
+            blocks = mut["moe_stats"]["blocks"]
+            return jnp.concatenate(
+                [jnp.stack([blocks[k]["chosen"][0][p] for k in sorted(blocks)])
+                 for p in range(cfg.n_periods)])
+
     if cfg.causal and not cfg.moe and cfg.seq_axis is None:
         # Fused head+loss (ops/ce.py): hidden states + the head weights go
         # straight into the Pallas CE kernel — no (B,T,V) logits tensor.
@@ -857,9 +1128,11 @@ def build_gpt2(
         # mean over B*(T-1) real targets); the op itself falls back to a
         # dense computation off-TPU, so this is always safe to call.
         def _fused(params, tokens, reduction):
+            return _fused_of(hidden_fn(params, tokens), params, tokens, reduction)
+
+        def _fused_of(x, params, tokens, reduction):
             from saturn_tpu.ops.ce import fused_linear_cross_entropy
 
-            x = hidden_fn(params, tokens)
             labels = jnp.pad(
                 tokens[:, 1:].astype(jnp.int32), ((0, 0), (0, 1)),
                 constant_values=-1,
@@ -875,6 +1148,15 @@ def build_gpt2(
             # (loss_sum, valid_count) for sharded callers (the dp shard_map
             # wrapper psums both parts before dividing)
             return _fused(params, tokens, "sum_count")
+
+        if cfg.routed_experts:
+            def fused_loss_stats_fn(params, tokens):
+                # the same loss with the routed layers' counters of the step
+                # beside it (an auxiliary output: no gradient, no sync)
+                x, mut = module.apply({"params": params}, tokens,
+                                      return_hidden=True, mutable=["moe_stats"])
+                stats = routed_stats(mut["moe_stats"]["blocks"])
+                return _fused_of(x, params, tokens, "mean"), jax.lax.stop_gradient(stats)
 
     apply_with_aux_fn = None
     if cfg.moe:
@@ -895,7 +1177,14 @@ def build_gpt2(
         # ``n_layers / sum(kinds)`` periods and ``pipeline["block"]`` is one
         # period
         "stack_kinds": cfg.stack_kinds,
+        # layers before the scanned periods (``ModelSpec.stack_lead``); they
+        # run inside ``pipeline["embed"]``, with their weights under "lead"
+        "stack_lead": cfg.stack_lead,
         "moe": {"n_experts": cfg.n_experts} if cfg.moe else None,
+        # a routed layer that computes a held share of its experts
+        "routed": {"experts": cfg.routed_experts, "held": cfg.experts_held,
+                   "top_k": cfg.top_k, "routing_fn": routing_fn}
+        if cfg.routed_experts else None,
         "embed_param_keys": ("wte", "wpe") if has_wpe else ("wte",),
         # factory accepts seq_axis/seq_axis_size; the sharded attention +
         # boundary-label loss assume causal next-token training. A linear
@@ -926,6 +1215,7 @@ def build_gpt2(
         fused_loss_parts_fn=fused_loss_parts_fn,
         fused_loss_objective="causal-lm" if fused_loss_fn else None,
         hidden_fn=hidden_fn,
+        fused_loss_stats_fn=fused_loss_stats_fn,
     )
 
 
@@ -949,6 +1239,18 @@ def build_olmo_hybrid(name: str = "olmo-hybrid-7b", **overrides) -> ModelSpec:
     tensor-parallel rank's share of every mixer. Same ``ModelSpec`` contract
     as :func:`build_gpt2`; the scanned unit, and so ``hints["pipeline"]``'s
     ``block``, is one period."""
+    return build_gpt2(name, **overrides)
+
+
+def build_laguna(name: str = "laguna-xs2", **overrides) -> ModelSpec:
+    """Laguna factory: a leading dense layer outside the scan (param key
+    ``lead``), then periods of three sliding-window layers and one
+    full-attention layer at their own q-head counts over shared k/v heads,
+    per-kind rotary (YaRN on the full layers), a gate a head, and a shared
+    expert beside top-k routed experts (``ops/moe.py::routed_experts``) of
+    which ``held_experts`` are computed here. Same ``ModelSpec`` contract as
+    :func:`build_gpt2`: the scanned unit, and ``hints["pipeline"]``'s
+    ``block``, is one period; its ``embed`` runs the leading layer."""
     return build_gpt2(name, **overrides)
 
 

@@ -15,11 +15,17 @@ all-to-alls over ICI.
 
 from __future__ import annotations
 
+import contextlib
+import functools
 import math
-from typing import Tuple
+from dataclasses import asdict, dataclass
+from typing import Any, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 
 def expert_capacity(n_tokens: int, n_experts: int, capacity_factor: float) -> int:
@@ -81,3 +87,397 @@ def switch_moe(
     mean_prob = probs.mean(axis=0)
     aux = E * jnp.sum(frac * mean_prob)
     return y.reshape(B, T, D), aux
+
+
+# ===================================================================== routed
+# A routed layer that drops nothing: top-k over all the experts, the (token,
+# expert) pairs sorted by expert, a grouped matrix product over the experts
+# *held* here, the combine back by the router's weights.
+#
+# Static shapes under run-time group sizes: the held pairs go to a row buffer
+# in which every held expert's rows start at a multiple of the row tile (each
+# group padded to whole tiles, an empty one to one tile of zeros), so a tile
+# of rows belongs to one expert and the grouped product is a tiled product
+# whose right-hand block is chosen per tile (``tile_expert``, a prefetched
+# scalar table). Both directions between token order and row order are
+# gathers: a row names its token (``tok``), a (token, slot) pair names its row
+# (``pos``), and the two maps are each other's transposes, so neither the
+# forward nor the backward scatters. A buffer smaller than the worst case
+# needs an exact second path for the steps that overflow it
+# (``_masked_experts``: every held expert over every token, under a mask).
+@dataclass(frozen=True)
+class RoutedPlan:
+    """How one routed layer runs (``moe_plan`` on the ``trial_config``
+    event)."""
+
+    impl: str        # "kernel" (saturn_gmm_*) | "xla" (jax.lax.ragged_dot)
+    tokens: int
+    experts: int     # the router's outputs
+    held: int        # experts whose tables are here
+    top_k: int
+    row_tile: int
+    rows: int        # the row buffer
+    worst_rows: int  # the buffer no step can overflow
+
+    @property
+    def second_path(self) -> bool:
+        return self.rows < self.worst_rows
+
+    def as_event(self) -> Dict[str, Any]:
+        return dict(asdict(self), second_path=self.second_path)
+
+
+_PLANS: list = []
+
+
+@contextlib.contextmanager
+def traced_plans():
+    """Collects the plan of every routed layer traced inside (as
+    ``ops/ce.py``'s)."""
+    global _PLANS
+    before, _PLANS = _PLANS, []
+    try:
+        yield _PLANS
+    finally:
+        _PLANS = before
+
+
+def _round_up(n: int, to: int) -> int:
+    return -(-n // to) * to
+
+
+#: the row buffer as a multiple of the mean held pairs: past it a step takes
+#: the exact second path. Twice the mean is the one value a chip has run
+#: (PERF.md, Findings PR 36).
+BUFFER = 2.0
+#: the name ``routed_layout``'s tables carry for a checkpoint policy
+LAYOUT_NAME = "saturn_moe_layout"
+#: rows of one tile of the grouped product (every expert's rows are padded to
+#: whole tiles): the MXU's 128, or 8 where an expert's mean rows are fewer
+ROW_TILE = 128
+
+
+def routed_plan(tokens: int, experts: int, held: int, top_k: int, *,
+                buffer: Optional[float] = None, row_tile: Optional[int] = None,
+                impl: str = "xla") -> RoutedPlan:
+    """``buffer`` (``BUFFER``) x the mean held pairs (tokens x top_k x held /
+    experts), plus a tile an expert for the padding, capped at the worst case
+    (every token's every choice held)."""
+    if impl not in ("kernel", "xla"):
+        raise ValueError(f"impl must be 'kernel' or 'xla', got {impl!r}")
+    buffer = BUFFER if buffer is None else buffer
+    if row_tile is None:
+        row_tile = ROW_TILE if tokens * top_k >= experts * ROW_TILE else 8
+    pad = held * row_tile
+    worst = _round_up(tokens * min(top_k, held), row_tile) + pad
+    mean = tokens * top_k * held / experts
+    rows = min(worst, _round_up(int(math.ceil(buffer * mean)), row_tile) + pad)
+    return RoutedPlan(impl, tokens, experts, held, top_k, row_tile, rows, worst)
+
+
+def _interpret() -> bool:
+    return jax.default_backend() != "tpu"
+
+
+# ------------------------------------------------------------------ kernels
+def _gmm_kernel(te_ref, na_ref, x_ref, w_ref, o_ref, *, transpose_rhs):
+    del te_ref
+    i = pl.program_id(0)
+
+    @pl.when(i < na_ref[0])
+    def _product():
+        dims = (((1,), (1,)), ((), ())) if transpose_rhs else (((1,), (0,)), ((), ()))
+        o_ref[...] = jax.lax.dot_general(
+            x_ref[...], w_ref[0], dims, preferred_element_type=jnp.float32
+        ).astype(o_ref.dtype)
+
+    @pl.when(i >= na_ref[0])
+    def _past_the_rows():
+        o_ref[...] = jnp.zeros_like(o_ref)
+
+
+def _gmm_call(x, w, tile_expert, n_active, *, row_tile, transpose_rhs, name):
+    """(R, A) x (held, P, Q) -> (R, Q), or (R, P) against the transposes: a
+    row tile times its expert's whole matrix (2 MiB at 2048 x 512 in bf16)."""
+    R, A = x.shape
+    _, Pw, Qw = w.shape
+    out = Pw if transpose_rhs else Qw
+    last = lambda na: jnp.maximum(na[0] - 1, 0)   # noqa: E731
+    return pl.pallas_call(
+        functools.partial(_gmm_kernel, transpose_rhs=transpose_rhs),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(R // row_tile,),
+            in_specs=[
+                # past the active tiles the blocks stay where they were: no
+                # fetch for rows no pair fills
+                pl.BlockSpec((row_tile, A),
+                             lambda i, te, na: (jnp.minimum(i, last(na)), 0)),
+                pl.BlockSpec((1, Pw, Qw),
+                             lambda i, te, na: (te[jnp.minimum(i, last(na))], 0, 0)),
+            ],
+            out_specs=pl.BlockSpec((row_tile, out), lambda i, te, na: (i, 0)),
+        ),
+        out_shape=jax.ShapeDtypeStruct((R, out), x.dtype),
+        name=name,
+        interpret=_interpret(),
+    )(tile_expert, n_active, x, w)
+
+
+def _gmm_dw_kernel(te_ref, na_ref, x_ref, dy_ref, dw_ref):
+    i = pl.program_id(1)
+    first = jnp.logical_or(i == 0, te_ref[i] != te_ref[jnp.maximum(i - 1, 0)])
+
+    @pl.when(first)
+    def _zero():
+        dw_ref[...] = jnp.zeros_like(dw_ref)
+
+    @pl.when(i < na_ref[0])
+    def _accumulate():
+        dw_ref[0] = dw_ref[0] + jax.lax.dot_general(
+            x_ref[...], dy_ref[...], (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+
+
+def _gmm_dw_call(x, dy, tile_expert, n_active, held, *, row_tile):
+    """(R, P)^T x (R, Q) by expert -> (held, P, Q) float32: an expert's block
+    stays in VMEM across its consecutive row tiles (every expert has at least
+    one, so every block is written)."""
+    R, Pw = x.shape
+    Qw = dy.shape[1]
+    tp = Pw
+    while tp * Qw * 4 > (2 << 20) and tp % 2 == 0 and tp > 128:
+        tp //= 2
+    last = lambda na: jnp.maximum(na[0] - 1, 0)   # noqa: E731
+    return pl.pallas_call(
+        _gmm_dw_kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(Pw // tp, R // row_tile),
+            in_specs=[
+                pl.BlockSpec((row_tile, tp),
+                             lambda p, i, te, na: (jnp.minimum(i, last(na)), p)),
+                pl.BlockSpec((row_tile, Qw),
+                             lambda p, i, te, na: (jnp.minimum(i, last(na)), 0)),
+            ],
+            out_specs=pl.BlockSpec((1, tp, Qw), lambda p, i, te, na: (te[i], p, 0)),
+        ),
+        out_shape=jax.ShapeDtypeStruct((held, Pw, Qw), jnp.float32),
+        name="saturn_gmm_dw",
+        interpret=_interpret(),
+    )(tile_expert, n_active, x, dy)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
+def _gmm_kernels(x, w, tile_expert, n_active, row_tile):
+    return _gmm_call(x, w.astype(x.dtype), tile_expert, n_active,
+                     row_tile=row_tile, transpose_rhs=False, name="saturn_gmm_fwd")
+
+
+def _gmm_kernels_fwd(x, w, tile_expert, n_active, row_tile):
+    return (_gmm_kernels(x, w, tile_expert, n_active, row_tile),
+            (x, w, tile_expert, n_active))
+
+
+def _gmm_kernels_bwd(row_tile, kept, dy):
+    x, w, tile_expert, n_active = kept
+    dx = _gmm_call(dy, w.astype(x.dtype), tile_expert, n_active,
+                   row_tile=row_tile, transpose_rhs=True, name="saturn_gmm_dx")
+    dw = _gmm_dw_call(x, dy, tile_expert, n_active, w.shape[0], row_tile=row_tile)
+    return dx, dw.astype(w.dtype), None, None
+
+
+_gmm_kernels.defvjp(_gmm_kernels_fwd, _gmm_kernels_bwd)
+
+
+def grouped_matmul(x, w, layout: Dict[str, Any], plan: RoutedPlan):
+    """Rows of ``x`` (R, K) times their expert's matrix of ``w`` (held, K, N)
+    under ``layout`` (``routed_layout``): the Pallas kernels
+    (``saturn_gmm_fwd`` / ``_dx`` / ``_dw``) or ``jax.lax.ragged_dot``, whose
+    transposes JAX derives. ``w`` comes in the parameters' dtype and is
+    rounded to ``x``'s here; the kernels' table gradient leaves in float32
+    without passing through that rounding."""
+    if plan.impl == "kernel":
+        return _gmm_kernels(x, w, layout["tile_expert"], layout["n_active"],
+                            plan.row_tile)
+    return jax.lax.ragged_dot(
+        x, w.astype(x.dtype), layout["group_rows"],
+        preferred_element_type=jnp.float32).astype(x.dtype)
+
+
+# ------------------------------------------------------- token <-> row order
+@jax.custom_vjp
+def _to_rows(y, tok, valid, pos):
+    """(T, D) -> (R, D): row r holds its token's row, or zeros."""
+    del pos
+    return jnp.where(valid[:, None], y[tok], jnp.zeros((), y.dtype))
+
+
+@jax.custom_vjp
+def _from_rows(o, tok, valid, pos):
+    """(R, D) -> (T, D) float32: a token's row is the sum of its pairs' rows
+    (``pos`` (T, k) names each pair's row, R where it has none)."""
+    del tok, valid
+    padded = jnp.concatenate([o, jnp.zeros((1, o.shape[1]), o.dtype)])
+    out = jnp.zeros((pos.shape[0], o.shape[1]), jnp.float32)
+    for s in range(pos.shape[1]):
+        out = out + padded[pos[:, s]].astype(jnp.float32)
+    return out
+
+
+def _to_rows_fwd(y, tok, valid, pos):
+    return _to_rows(y, tok, valid, pos), (tok, valid, pos, jnp.zeros((), y.dtype))
+
+
+def _to_rows_bwd(kept, dx):
+    tok, valid, pos, like = kept
+    return _from_rows(dx, tok, valid, pos).astype(like.dtype), None, None, None
+
+
+def _from_rows_fwd(o, tok, valid, pos):
+    return _from_rows(o, tok, valid, pos), (tok, valid, pos, jnp.zeros((), o.dtype))
+
+
+def _from_rows_bwd(kept, dout):
+    tok, valid, pos, like = kept
+    return _to_rows(dout.astype(like.dtype), tok, valid, pos), None, None, None
+
+
+_to_rows.defvjp(_to_rows_fwd, _to_rows_bwd)
+_from_rows.defvjp(_from_rows_fwd, _from_rows_bwd)
+
+
+def routed_layout(local, plan: RoutedPlan) -> Dict[str, Any]:
+    """The row buffer's layout for one step, from ``local`` (T, k) int32: the
+    held experts' own numbers 0 .. held-1 of each (token, slot) pair, ``held``
+    for a pair whose expert is not here. Integer work only."""
+    T, k = local.shape
+    held, tm, R = plan.held, plan.row_tile, plan.rows
+    i32 = jnp.int32
+    key = local.reshape(-1).astype(i32)
+    n = key.shape[0]
+    iota = jnp.arange(n, dtype=i32)
+    mine = key[:, None] == jnp.arange(held, dtype=i32)[None, :]
+    seen = jnp.cumsum(mine.astype(i32), axis=0)               # (n, held)
+    counts = seen[-1]                                         # rows an expert
+    rank_of = jnp.sum(jnp.where(mine, seen, 0), axis=1) - 1   # a pair's, in its expert
+    _, order = jax.lax.sort((key, iota), num_keys=1, is_stable=True)
+    tiles = jnp.maximum(1, -(-counts // tm))
+    zero = jnp.zeros((1,), i32)
+    start = jnp.concatenate([zero, jnp.cumsum(counts)])        # in sorted order
+    tile_end = jnp.cumsum(tiles)
+    pad_start = jnp.concatenate([zero, tile_end * tm])         # in the buffer
+    n_active = tile_end[-1:]                                   # (1,) tiles in use
+    tile_expert = jnp.minimum(jnp.searchsorted(
+        tile_end, jnp.arange(R // tm, dtype=i32), side="right"), held - 1).astype(i32)
+    # a row -> its pair
+    rows = jnp.arange(R, dtype=i32)
+    e_row = tile_expert[rows // tm]
+    rank = rows - pad_start[e_row]
+    valid = (rank < counts[e_row]) & (rows < n_active[0] * tm)
+    pair = order[jnp.clip(start[e_row] + rank, 0, n - 1)]
+    # a pair -> its row (R: none)
+    row_of = pad_start[key] + rank_of
+    pos = jnp.where((key < held) & (row_of < R), row_of, R).reshape(T, k)
+    layout = {"tok": pair // k, "pair": pair, "valid": valid, "pos": pos,
+              "tile_expert": tile_expert, "n_active": n_active.astype(i32),
+              "group_rows": (tiles * tm).astype(i32), "counts": counts,
+              "overflow": n_active[0] * tm > R}
+    # integer tables of a megabyte: a rematerialised layer keeps them
+    # (``LAYOUT_NAME`` in its policy) and its backward sorts nothing again
+    return {name: checkpoint_name(leaf, LAYOUT_NAME) for name, leaf in layout.items()}
+
+
+def _swiglu_rows(x, w_gate, w_up, w_down, layout, plan):
+    h = grouped_matmul(x, w_gate, layout, plan)
+    u = grouped_matmul(x, w_up, layout, plan)
+    a = (jax.nn.silu(h.astype(jnp.float32)) * u.astype(jnp.float32)).astype(x.dtype)
+    return grouped_matmul(a, w_down, layout, plan)
+
+
+def _masked_experts(y, weights, local, w_gate, w_up, w_down):
+    """The exact second path: every held expert over every token, its output
+    weighted by the token's weight for it (0 where it was not chosen). One
+    expert at a time, each rematerialised in the backward: held x the dense
+    work, and no buffer to overflow."""
+    held = w_gate.shape[0]
+    f32 = jnp.float32
+
+    @jax.checkpoint
+    def one(y, wg, wu, wd, m):
+        h = jnp.dot(y, wg.astype(y.dtype), preferred_element_type=f32)
+        u = jnp.dot(y, wu.astype(y.dtype), preferred_element_type=f32)
+        a = (jax.nn.silu(h) * u).astype(y.dtype)
+        return jnp.dot(a, wd.astype(y.dtype), preferred_element_type=f32) * m[:, None]
+
+    def step(acc, xs):
+        e, wg, wu, wd = xs
+        m = jnp.sum(jnp.where(local == e, weights, 0.0), axis=-1)
+        return acc + one(y, wg, wu, wd, m), None
+
+    acc, _ = jax.lax.scan(step, jnp.zeros(y.shape, f32),
+                          (jnp.arange(held, dtype=jnp.int32), w_gate, w_up, w_down))
+    return acc
+
+
+def routed_experts(y, router, w_gate, w_up, w_down, *, plan: RoutedPlan,
+                   first_expert: int = 0, scale: float = 1.0,
+                   dtype: Any = jnp.bfloat16):
+    """The held experts' part of a top-k routed SwiGLU layer.
+
+    ``y`` (T, D); ``router`` (D, experts); ``w_gate`` / ``w_up`` (held, D, F)
+    and ``w_down`` (held, F, D): experts ``first_expert .. + held``.
+
+        s = sigmoid(y router)                  float32, all the experts
+        I = the top_k largest;  w_e = scale * s_e / sum_{e' in I} s_e'
+        out = sum_{e in I, e held} w_e (silu(y Wg_e) * (y Wu_e)) Wd_e
+
+    Every pair whose expert is held is computed: through the row buffer where
+    the step's padded rows fit it, through ``_masked_experts`` where they do
+    not. Returns (out (T, D) in ``dtype``, counters: ``pairs_held``,
+    ``rows_max`` (the fullest held expert), ``second_path`` (0 / 1) as int32
+    scalars, and ``chosen`` (T, top_k), the experts the router chose)."""
+    f32 = jnp.float32
+    T, D = y.shape
+    held = plan.held
+    if (plan.tokens, plan.experts, plan.held) != (T, router.shape[1], w_gate.shape[0]):
+        raise ValueError(f"plan {plan} is not for {T} tokens, "
+                         f"{router.shape[1]} experts, {w_gate.shape[0]} held")
+    _PLANS.append(plan)
+    y = y.astype(dtype)
+    scores = jax.nn.sigmoid(jnp.dot(y.astype(f32), router.astype(f32),
+                                    precision=jax.lax.Precision.HIGHEST))
+    # the choice is kept with the tables it makes (``LAYOUT_NAME``), and the
+    # chosen scores are read at it: a rematerialised layer's backward would
+    # otherwise choose again from scores recomputed to another last bit, and
+    # two scores a rounding apart then change slots (or experts) under tables
+    # built for the forward's order, so one expert's weight gradient lands on
+    # another's router column
+    chosen = checkpoint_name(jax.lax.top_k(scores, plan.top_k)[1], LAYOUT_NAME)
+    top = jnp.take_along_axis(scores, chosen, axis=-1)
+    weights = scale * top / jnp.sum(top, axis=-1, keepdims=True)      # (T, k)
+    local = chosen.astype(jnp.int32) - first_expert
+    local = jnp.where((local >= 0) & (local < held), local, held)
+    layout = routed_layout(local, plan)
+
+    def through_rows(y, weights, w_gate, w_up, w_down):
+        tok, valid, pos = layout["tok"], layout["valid"], layout["pos"]
+        x = _to_rows(y, tok, valid, pos)
+        o = _swiglu_rows(x, w_gate, w_up, w_down, layout, plan)
+        w_row = jnp.where(valid, weights.reshape(-1)[layout["pair"]], 0.0)
+        o = (o.astype(f32) * w_row[:, None]).astype(dtype)
+        return _from_rows(o, tok, valid, pos)
+
+    def through_mask(y, weights, w_gate, w_up, w_down):
+        return _masked_experts(y, weights, local, w_gate, w_up, w_down)
+
+    operands = (y, weights, w_gate, w_up, w_down)
+    if plan.second_path:
+        out = jax.lax.cond(layout["overflow"], through_mask, through_rows, *operands)
+        second = layout["overflow"].astype(jnp.int32)
+    else:
+        out, second = through_rows(*operands), jnp.zeros((), jnp.int32)
+    stats = {"pairs_held": jnp.sum(layout["counts"]),
+             "rows_max": jnp.max(layout["counts"]),
+             "second_path": second, "chosen": chosen}
+    return out.astype(dtype), stats

@@ -72,8 +72,29 @@ class ExpertParallel(SPMDTechnique):
             rules.append(shr.fsdp_rules("data"))
         return shr.compose_rules(*rules)
 
+    def make_step_fns(self, spec, task, config, mesh, ds):
+        routed = spec.hints.get("routed")
+        if routed:
+            # The one place (``execute`` on a hand-made strategy comes here
+            # too). A routed layer (ops/moe.py::routed_experts) computes the
+            # part of its result that the experts held by *this* program
+            # give; dividing the held tables over an ``expert`` axis needs the
+            # exchange of token rows between the shares (an all-to-all each
+            # way around the grouped product), which is not built
+            # (ROADMAP.md, Reach).
+            raise InfeasibleConfig(
+                f"ep: the model's routed layer holds {routed['held']} of "
+                f"{routed['experts']} experts as one share and computes only "
+                f"their part; the exchange of tokens between shares on an "
+                f"expert axis is not built")
+        return super().make_step_fns(spec, task, config, mesh, ds)
+
     def candidate_configs(self, task, n_devices) -> List[Dict[str, Any]]:
         E = self._n_experts(task)
+        if not E and task.get_model().hints.get("routed") and n_devices >= 2:
+            # one grid point, refused where its step is built: the reason
+            # lands on the ``trial.config`` span like pp's
+            return [{"ep": 2, "remat": False, "zero": False}]
         if not E:
             return []  # dense model: EP infeasible, search returns (None, None)
         # No custom train step: the aux load-balance loss is added by the

@@ -102,6 +102,29 @@ def choose_window(n_batches: int, cap: Optional[int] = None) -> int:
     return min(cap, n)
 
 
+def _counter_fields(unit_counters: Sequence[Dict[str, Any]]) -> Dict[str, float]:
+    """The step counters of an interval for its ``task_interval`` event, from
+    each unit's ``{name: scalar or (K,)}``: over the interval's steps the mean
+    of a name ending in ``_held`` / ``_mean`` (per step), the largest of one
+    ending in ``_max``, the sum of any other (``moe_second_path``: steps
+    that took the second path)."""
+    from saturn_tpu.core import distributed as _dist
+
+    out: Dict[str, float] = {}
+    names = sorted({n for c in unit_counters for n in c})
+    for name in names:
+        steps = np.concatenate([
+            np.asarray(_dist.host_array(c[name]), dtype=np.float64).reshape(-1)
+            for c in unit_counters if name in c])
+        if name.endswith(("_held", "_mean")):
+            out[name] = float(steps.mean())
+        elif name.endswith("_max"):
+            out[name] = float(steps.max())
+        else:
+            out[name] = float(steps.sum())
+    return out
+
+
 def _host_fraction(t_host: float, t_device: float) -> float:
     """Fraction of one steady-state batch spent on host-side staging work.
 
@@ -165,6 +188,10 @@ class _Bundle:
     # scan, chunk, grid, the kernel's VMEM sum); empty for a model with no
     # linear-attention layer
     gdn_plans: Tuple[Any, ...] = ()
+    # likewise each routed-expert layer (ops/moe.py's ``RoutedPlan``) and
+    # each window-attention call (ops/flash.py's ``window_plan``)
+    moe_plans: Tuple[Any, ...] = ()
+    window_plans: Tuple[Any, ...] = ()
     _lowered: Any = None
     _compiled: Any = None
     _single_lock: Any = field(default_factory=threading.Lock)
@@ -583,6 +610,16 @@ class SPMDTechnique(BaseTechnique):
             def loss_and_grads(params, batch):
                 return jax.value_and_grad(fused_loss)(params, batch)
 
+            with_stats = getattr(spec, "fused_loss_stats_fn", None)
+            if single and with_stats is not None:
+                # the same loss with the step's counters beside it (a routed
+                # layer's): ``(loss, counters)`` is then the step's second
+                # output, stacked by the window program like a bare loss and
+                # split where the interval reads its losses back
+                def loss_and_grads(params, batch):  # noqa: F811
+                    return jax.value_and_grad(with_stats, has_aux=True)(
+                        params, batch)
+
             return self.step_fns_from_loss_and_grads(
                 spec.init_fn, task, loss_and_grads,
                 update_on_host=update_on_host,
@@ -824,7 +861,9 @@ class SPMDTechnique(BaseTechnique):
         # counts the calls of the model's Python step function, so a second
         # tracing site would show in ``step_traces``.
         from saturn_tpu.ops import ce as _ce
+        from saturn_tpu.ops import flash as _flash
         from saturn_tpu.ops import gdn as _gdn
+        from saturn_tpu.ops import moe as _moe
 
         trace_count = [0]
 
@@ -832,7 +871,9 @@ class SPMDTechnique(BaseTechnique):
             trace_count[0] += 1
             return train_step(state, batch)
 
-        with _ce.traced_plans() as ce_plans, _gdn.traced_plans() as gdn_plans:
+        with _ce.traced_plans() as ce_plans, _gdn.traced_plans() as gdn_plans, \
+                _moe.traced_plans() as moe_plans, \
+                _flash.traced_window_plans() as window_plans:
             closed, out_shapes = jax.make_jaxpr(
                 counted_step, return_shape=True
             )(state_shapes, batch_sds)
@@ -889,6 +930,8 @@ class SPMDTechnique(BaseTechnique):
             trace_count=trace_count,
             ce_plans=tuple(ce_plans),
             gdn_plans=tuple(gdn_plans),
+            moe_plans=tuple(moe_plans),
+            window_plans=tuple(window_plans),
         )
 
     # ------------------------------------------------------------- shardflow
@@ -1100,6 +1143,9 @@ class SPMDTechnique(BaseTechnique):
         kinds = getattr(spec, "stack_kinds", None)
         if kinds:   # a stack of several block kinds: layers of each a period
             out["stack_kinds"] = kinds
+        lead = getattr(spec, "stack_lead", None)
+        if lead:    # layers before the periods, outside the scan
+            out["stack_lead"] = lead
         return out
 
     def _plan_fields(self, task, devices, config) -> Dict[str, Any]:
@@ -1110,7 +1156,12 @@ class SPMDTechnique(BaseTechnique):
         through plain XLA ops (off-TPU, or no block tiles the tokens). And
         ``gdn_plan`` of a model with linear-attention layers: the first
         layer's call of the gated delta rule (``ops/gdn.py::GDNPlan``:
-        kernel or plain scan, chunk, grid, the kernel's VMEM sum). Beside them
+        kernel or plain scan, chunk, grid, the kernel's VMEM sum);
+        ``moe_plan`` of a model with routed-expert layers (``ops/moe.py::
+        RoutedPlan``: kernel or twin, row tile, buffer rows and the worst
+        case, experts held / all, top-k) and ``window_plan`` of one with
+        sliding-window layers (``ops/flash.py::window_plan``: window, block,
+        key blocks visited and skipped a call). Beside them
         ``step_traces``: how often the model's Python step function was
         called for this grid point (its bundle's one trace: 1). Nothing where
         the point's bundle was never built."""
@@ -1123,6 +1174,10 @@ class SPMDTechnique(BaseTechnique):
             out["ce_plan"] = None if plan is None else plan._asdict()
         if bundle.gdn_plans:
             out["gdn_plan"] = bundle.gdn_plans[0]._asdict()
+        if bundle.moe_plans:   # the first routed layer's (all are alike)
+            out["moe_plan"] = bundle.moe_plans[0].as_event()
+        if bundle.window_plans:
+            out["window_plan"] = dict(bundle.window_plans[0])
         return out
 
     def _profile_window(self, config: Dict[str, Any]) -> int:
@@ -1431,6 +1486,10 @@ class SPMDTechnique(BaseTechnique):
         # Every unit's carried loss stays on-device for the sentinel's
         # interval-end fold (tiny buffers: one scalar / (K,) per unit).
         unit_losses: List[Any] = []
+        # a step whose second output is ``(loss, counters)`` (a routed
+        # model's): the counters stay on the device beside the losses and are
+        # read back with them, after the interval's one drain
+        unit_counters: List[Dict[str, Any]] = []
         t_all0 = _timeit.default_timer()
         ts_start = _time.time()  # same clock as the metrics events' ``ts``
         t_steady = t_all0
@@ -1457,6 +1516,9 @@ class SPMDTechnique(BaseTechnique):
                     state, loss = fused_fn(state, dev_batch)  # loss: (K,)
                 else:
                     state, loss = single_fn(state, dev_batch)
+                if isinstance(loss, tuple):
+                    loss, counters = loss
+                    unit_counters.append(counters)
                 unit_losses.append(loss)
                 if u == 0 and len(units) > 1 and not shared:
                     # The first unit still pays one-time warmup (executable
@@ -1614,6 +1676,7 @@ class SPMDTechnique(BaseTechnique):
                     float(x) for u in unit_losses
                     for x in np.asarray(_dist.host_array(u)).reshape(-1)
                 ]
+                perf.update(_counter_fields(unit_counters))
                 with self._flops_lock:
                     cached = key in self._flops_cache
                 # what the package's own tflops / mfu costs the interval: the

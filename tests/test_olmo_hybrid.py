@@ -204,7 +204,7 @@ def test_the_program_told_its_share_computes_that_half():
 
 def test_new_options_are_validated():
     with pytest.raises(ValueError, match="layer_types"):
-        _spec(layer_types=("sliding_attention",))
+        _spec(layer_types=("latent_attention",))      # (a sliding layer is one, since PR 36)
     with pytest.raises(ValueError, match="whole periods"):
         _spec(n_layers=6)
     with pytest.raises(ValueError, match="held_heads"):

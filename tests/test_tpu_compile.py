@@ -23,6 +23,7 @@ from jax.sharding import SingleDeviceSharding
 from saturn_tpu.ops import ce as ce_mod
 from saturn_tpu.ops import flash as flash_mod
 from saturn_tpu.ops import gdn as gdn_mod
+from saturn_tpu.ops import moe as moe_mod
 
 
 @pytest.fixture(scope="module")
@@ -52,6 +53,7 @@ def real_lowering(monkeypatch):
     monkeypatch.setattr(flash_mod, "_use_interpret", lambda: False)
     monkeypatch.setattr(ce_mod, "_use_interpret", lambda: False)
     monkeypatch.setattr(gdn_mod, "_use_interpret", lambda: False)
+    monkeypatch.setattr(moe_mod, "_interpret", lambda: False)
 
 
 def _compile(fn, *shapes, kernels):
@@ -83,6 +85,44 @@ def test_flash_attention_compiles_for_v5e(one_chip, real_lowering, shape, grad):
     if grad:
         kernels += ["saturn_flash_dq", "saturn_flash_dkv"]
     _compile(fn, sds, sds, sds, kernels=kernels)
+
+
+@pytest.mark.parametrize("heads", [48, 64], ids=["6-a-kv-head", "8-a-kv-head"])
+def test_window_and_grouped_flash_compile_for_v5e(one_chip, real_lowering, heads):
+    """The Laguna cell's attention at its own shapes: 48 / 64 q heads over 8
+    k/v heads of 128, seq 8192 x batch 2; the window kernels (window 512,
+    blocks of 256: 3 key blocks a query block) under names of their own."""
+    q = jax.ShapeDtypeStruct((2, heads, 8192, 128), jnp.bfloat16, sharding=one_chip)
+    kv = jax.ShapeDtypeStruct((2, 8, 8192, 128), jnp.bfloat16, sharding=one_chip)
+    window = 512 if heads == 64 else None
+
+    def loss(q, k, v):
+        return jnp.sum(flash_mod.flash_attention(q, k, v, window=window).astype(jnp.float32))
+
+    family = "saturn_swa" if window else "saturn_flash"
+    text = _compile(jax.grad(loss, argnums=(0, 1, 2)), q, kv, kv,
+                    kernels=[f"{family}_fwd", f"{family}_dq", f"{family}_dkv"])
+    assert ("saturn_flash_" in text) == (window is None)
+
+
+def test_routed_layer_kernels_compile_for_v5e(one_chip, real_lowering):
+    """``saturn_gmm_fwd`` / ``_dx`` / ``_dw`` at the Laguna cell's shape:
+    16384 tokens, top-8 of 256 experts, 32 held, d 2048, experts of 512, a
+    row buffer of 2 x the mean pairs in tiles of 128 rows, with the exact
+    second path behind a ``cond``."""
+    sds = lambda *shape, dtype=jnp.float32: jax.ShapeDtypeStruct(   # noqa: E731
+        shape, dtype, sharding=one_chip)
+    plan = moe_mod.routed_plan(16384, 256, 32, 8, buffer=2.0, impl="kernel")
+    assert (plan.rows, plan.second_path) == (36864, True)
+
+    def loss(y, router, w_gate, w_up, w_down):
+        out, _ = moe_mod.routed_experts(y, router, w_gate, w_up, w_down, plan=plan, scale=2.5)
+        return jnp.sum(out.astype(jnp.float32))
+
+    _compile(jax.grad(loss, argnums=(0, 1, 2, 3, 4)),
+             sds(16384, 2048, dtype=jnp.bfloat16), sds(2048, 256), sds(32, 2048, 512),
+             sds(32, 2048, 512), sds(32, 512, 2048),
+             kernels=["saturn_gmm_fwd", "saturn_gmm_dx", "saturn_gmm_dw"])
 
 
 # ------------------------------------------------------ gated delta rule
