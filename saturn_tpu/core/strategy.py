@@ -73,7 +73,7 @@ class Strategy:
     cache_key: Optional[str] = field(default=None)
     # Fraction of a steady-state batch spent on HOST work (staging, pinned
     # host transfers) rather than device compute, in [0, 1]. Measured by the
-    # trial runner (``SPMDTechnique._try_config``); the solver's co-location
+    # trial runner (``SPMDTechnique._measure``); the solver's co-location
     # term uses it to predict which job pairs can fill each other's bubbles
     # when their windows interleave on a shared block. 0.0 (the default, and
     # what pre-existing cache entries report) predicts no overlap win, so a

@@ -31,9 +31,10 @@ class DataParallel(SPMDTechnique):
         return shr.replicated_rules
 
     def candidate_configs(self, task, n_devices) -> List[Dict[str, Any]]:
-        # remat off first (faster when it fits), on as fallback — same
-        # best-guess-first grid ordering idea as ``FSDP.py:72-78``; crossed
-        # with flash attention on TPU so the solver picks from measurement.
+        # remat off and on, crossed with flash attention on TPU: ``search``
+        # times every point that fits and keeps the fastest, so this order
+        # only breaks a tie (remat off wins one); which point is *prepared*
+        # first is ``search``'s to say (the remat points, PR 37).
         return self._with_attention_variants(
             task, [{"remat": False}, {"remat": True}], n_devices
         )

@@ -17,11 +17,14 @@ from __future__ import annotations
 import contextlib
 import logging
 import os
+import queue
 import threading
 import time as _time
 import timeit as _timeit
 from dataclasses import dataclass, field
-from typing import Any, Dict, List, Optional, Sequence, Tuple
+from typing import (
+    Any, Callable, Dict, List, NamedTuple, Optional, Sequence, Tuple,
+)
 
 import jax
 import numpy as np
@@ -300,6 +303,98 @@ class _Bundle:
             return self._fused.setdefault(k, compiled)
 
 
+class _Prepared(NamedTuple):
+    """A grid point whose host work is done (``SPMDTechnique._prepare``):
+    what is left is to put a state on the chip and time the program."""
+
+    bundle: _Bundle
+    program: Any    # the compiled K-step window program, or the 1-step one
+    k: int
+
+
+@dataclass
+class _GridPoint:
+    """One grid point on its way through ``SPMDTechnique.search``."""
+
+    order: int                  # its place in the technique's own grid
+    config: Dict[str, Any]
+    # its ``trial.config``: opened where its preparation starts, closed on
+    # the thread the point ends on
+    span: Any
+    ready: Optional[_Prepared] = None
+    timed: Optional[Tuple[float, float]] = None   # of a ``timed`` point
+    # how it ended (``trial.config``'s ``outcome``); None while it has not
+    outcome: Optional[str] = None
+    refusal: Optional[str] = None   # of a ``refused`` point: fresh / recorded
+    error: Optional[str] = None     # of an ``error`` point, for ``first_error``
+
+
+def _measured_behind(
+    grid: Sequence[Tuple[int, Dict[str, Any]]],
+    prepare: Callable[[int, Dict[str, Any]], _GridPoint],
+    measure: Callable[[_GridPoint], None],
+) -> Tuple[List[_GridPoint], int]:
+    """Every entry of ``grid`` prepared on the caller's thread, in the grid's
+    order, and each point measured behind it, strictly one at a time and in
+    the same order, on a thread of its own: the caller prepares the next
+    points while this one is measured. Returns the points, and how many of
+    them were ready before the measuring thread asked for them.
+
+    The host's half stays on the caller's thread because it is the one that
+    allocates: on the chip's host the same tracing and lowering takes half
+    as long again on a new thread as on the main one, and reading an
+    executable from the compile cache three times as long (PR 37). A grid
+    of one point starts no thread.
+
+    ``prepare`` and ``measure`` keep an ``Exception`` to themselves (it is
+    how that point ended). Anything else one of them raises (a kill) is
+    raised here, on the caller's thread, once the other has finished the
+    point it was on: it starts no further point, and the thread is joined.
+    """
+    if len(grid) < 2:
+        points = [prepare(order, config) for order, config in grid]
+        for point in points:
+            measure(point)
+        return points, 0
+    ready: "queue.SimpleQueue[Optional[_GridPoint]]" = queue.SimpleQueue()
+    gone = threading.Event()     # one side has left: start no further point
+    behind: Dict[str, Any] = {"ahead": 0, "raised": None}
+
+    def measure_in_turn() -> None:
+        try:
+            while True:
+                ahead = not ready.empty()
+                point = ready.get()
+                if point is None or gone.is_set():
+                    return
+                behind["ahead"] += ahead
+                measure(point)
+        except BaseException as e:  # raised again below, on the caller's thread
+            behind["raised"] = e
+            gone.set()
+
+    measurer = threading.Thread(
+        target=measure_in_turn,
+        name=f"meas-{threading.current_thread().name}", daemon=True)
+    measurer.start()
+    points: List[_GridPoint] = []
+    try:
+        for order, config in grid:
+            if gone.is_set():
+                break
+            points.append(prepare(order, config))
+            ready.put(points[-1])
+    except BaseException:
+        gone.set()
+        raise
+    finally:
+        ready.put(None)
+        measurer.join()
+    if behind["raised"] is not None:
+        raise behind["raised"]
+    return points, behind["ahead"]
+
+
 class SPMDTechnique(BaseTechnique):
     """Base for techniques expressible as (mesh shape + sharding rules)."""
 
@@ -486,7 +581,8 @@ class SPMDTechnique(BaseTechnique):
     def candidate_configs(
         self, task: Any, n_devices: int
     ) -> List[Dict[str, Any]]:
-        """Autotune grid, best-guess-first (reference ``FSDP.py:72-78``)."""
+        """Autotune grid (reference ``FSDP.py:72-78``). ``search`` tries every
+        point; the order here breaks a tie in time, nothing else."""
         return [{}]
 
     def param_memory_kind(self, config: Dict[str, Any]) -> Optional[str]:
@@ -1042,92 +1138,141 @@ class SPMDTechnique(BaseTechnique):
     def search(
         self, task: Any, devices: Sequence[Any], tid: int
     ) -> Tuple[Optional[Dict[str, Any]], Optional[float]]:
-        best: Tuple[Optional[Dict[str, Any]], Optional[float]] = (None, None)
-        best_hf = 0.0
-        n_configs = n_memory = n_error = 0
+        """Time every grid point that fits and keep the fastest.
+
+        Two stages, so that the host and the chip work at the same time:
+        this thread, the caller's, walks the grid and prepares each point
+        (``_prepare``: build, compile, memory check — tracing, lowering, a
+        cache hit or a compile or a refusal; nothing of it allocates train
+        state) and hands it over as it ends; a measuring thread behind it
+        measures (``_measure``: init, stage, timing) strictly one point at a
+        time (``_measured_behind``). The memory-frugal points are
+        prepared first (``remat: True`` before the rest, the technique's
+        order otherwise): they are the ones most likely to take timed steps,
+        under which the others' preparation then runs. The winner is the
+        fastest timed point wherever it stood; a tie goes to the technique's
+        own order.
+        """
+        size = len(devices)
+        stack = self._stack_fields(task)
+        above = _metrics.current_span()
+
+        def end(point: _GridPoint, outcome: str, **fields):
+            # one ``trial_config`` event and one ``trial.config`` span per
+            # grid point, on the thread the point ended on: which variant
+            # measured what, which did not fit, which raised — the winner
+            # alone hides the rest — and where its seconds went
+            _metrics.event("trial_config", task=task.name, size=size,
+                           technique=self.name, config=dict(point.config),
+                           **stack,
+                           **self._plan_fields(task, devices, point.config),
+                           **fields)
+            point.outcome = outcome
+            point.span.set(outcome=outcome)
+            point.span.close()
+
+        def attempt(point: _GridPoint, fn):
+            """One stage of a point (its preparation, its measurement) under
+            its ``trial.config`` span. How a stage raises is how the point
+            ended; only what is no ``Exception`` (a kill) goes further."""
+            config = point.config
+            try:
+                with _metrics.under(point.span):
+                    return fn()
+            except InfeasibleConfig as e:
+                log.info("%s trial %s infeasible: %s", self.name, config, e)
+                point.span.set(reason=str(e))
+                end(point, "infeasible", infeasible=str(e))
+            except aot_cache.CompileRefused as e:
+                # The chip's compiler refusing the program for memory (just
+                # now, or on record from an earlier compile) is the memory
+                # check's verdict, not a config that raised.
+                log.info("%s trial %s for task %s refused by the compiler "
+                         "(%s): %s", self.name, config, task.name, e.refusal,
+                         e.first_line)
+                point.refusal = e.refusal
+                point.span.set(refusal=e.refusal)
+                end(point, "refused", memory_rejected=True,
+                    refusal=e.refusal, compiler=e.first_line)
+            except Exception as e:  # a broken config must not kill the sweep
+                # ...but a config that RAISED is not a config that lost: on
+                # the chip a kernel variant that fails to lower would
+                # otherwise lose to its dense twin in silence. Warn, and
+                # count it into the report ``search()`` returns.
+                log.warning("%s trial %s for task %s failed: %r",
+                            self.name, config, task.name, e)
+                point.error = f"{self.name} {config}: {e!r}"
+                end(point, "error", error=repr(e))
+            except BaseException as e:
+                point.span.set(error=type(e).__name__)
+                point.span.close()
+                raise
+            return None
+
+        def prepare(order: int, config: Dict[str, Any]) -> _GridPoint:
+            point = _GridPoint(order, config, _metrics.span(
+                "trial.config", parent=above, task=task.name, size=size,
+                technique=self.name, config=dict(config)).open())
+            point.ready = attempt(
+                point, lambda: self._prepare(task, devices, config))
+            if point.ready is None and point.outcome is None:
+                # _prepare returns None only on the memory check
+                end(point, "memory_rejected", memory_rejected=True)
+            return point
+
+        def measure(point: _GridPoint) -> None:
+            if point.outcome is not None:  # it ended where it was prepared
+                return
+            point.timed = attempt(
+                point, lambda: self._measure(task, point.ready))
+            point.ready = None  # the bundle cache keeps the programs
+            if point.timed is not None:
+                end(point, "timed", per_batch_s=point.timed[0])
+
+        grid = list(enumerate(self.candidate_configs(task, size)))
+        grid.sort(key=lambda oc: oc[1].get("remat") is not True)  # stable
+        points, n_ahead = _measured_behind(grid, prepare, measure)
+
+        best: Optional[_GridPoint] = None
+        n_memory = n_error = 0
         refusals = {"fresh": 0, "recorded": 0}
         first_error: Optional[str] = None
-
-        stack = self._stack_fields(task)
-
-        def note(config, **fields):
-            # one event per grid point: which variant measured what, which
-            # did not fit, which raised — the winner alone hides the rest
-            _metrics.event("trial_config", task=task.name, size=len(devices),
-                           technique=self.name, config=dict(config),
-                           **stack, **self._plan_fields(task, devices, config),
-                           **fields)
-
-        for config in self.candidate_configs(task, len(devices)):
-            n_configs += 1
-            # one ``trial.config`` span per ``trial_config`` event: where the
-            # grid point's seconds went, by how it ended
-            with _metrics.span("trial.config", task=task.name,
-                               size=len(devices), technique=self.name,
-                               config=dict(config)) as sp:
-                try:
-                    timed = self._try_config(task, devices, config)
-                except InfeasibleConfig as e:
-                    log.info("%s trial %s infeasible: %s", self.name, config, e)
-                    note(config, infeasible=str(e))
-                    sp.set(outcome="infeasible", reason=str(e))
-                    continue
-                except aot_cache.CompileRefused as e:
-                    # The chip's compiler refusing the program for memory
-                    # (just now, or on record from an earlier compile) is
-                    # the memory check's verdict, not a config that raised.
-                    log.info("%s trial %s for task %s refused by the "
-                             "compiler (%s): %s", self.name, config,
-                             task.name, e.refusal, e.first_line)
-                    n_memory += 1
-                    refusals[e.refusal] += 1
-                    note(config, memory_rejected=True, refusal=e.refusal,
-                         compiler=e.first_line)
-                    sp.set(outcome="refused", refusal=e.refusal)
-                    continue
-                except Exception as e:  # a broken config must not kill the sweep
-                    # ...but a config that RAISED is not a config that lost:
-                    # on the chip a kernel variant that fails to lower would
-                    # otherwise lose to its dense twin in silence. Warn, and
-                    # count it into the report ``search()`` returns.
-                    log.warning("%s trial %s for task %s failed: %r",
-                                self.name, config, task.name, e)
-                    n_error += 1
-                    if first_error is None:
-                        first_error = f"{self.name} {config}: {e!r}"
-                    note(config, error=repr(e))
-                    sp.set(outcome="error")
-                    continue
-                if timed is None:  # _try_config returns None only on the memory check
-                    n_memory += 1
-                    note(config, memory_rejected=True)
-                    sp.set(outcome="memory_rejected")
-                    continue
-                t, hf = timed
-                note(config, per_batch_s=t)
-                sp.set(outcome="timed")
-            if best[1] is None or t < best[1]:
-                best = (dict(config), t)
-                best_hf = hf
+        for point in points:
+            if point.outcome == "timed":
+                # the fastest; a tie goes to the technique's own order
+                if best is None or ((point.timed[0], point.order)
+                                    < (best.timed[0], best.order)):
+                    best = point
+            elif point.outcome in ("refused", "memory_rejected"):
+                n_memory += 1
+                if point.refusal is not None:
+                    refusals[point.refusal] += 1
+            elif point.outcome == "error":
+                n_error += 1
+                first_error = first_error or point.error
         with self._reports_lock:
-            if best[1] is not None:
-                self._host_fracs[(task.name, len(devices))] = best_hf
+            if best is not None:
+                self._host_fracs[(task.name, size)] = best.timed[1]
             # Memory is the binding constraint only when EVERY candidate was
             # rejected by XLA memory analysis or refused by the compiler for
             # memory — a mesh/divisibility error in any config means smaller
             # sizes might still work, so monotone pruning must not engage.
-            self._search_reports[(task.name, len(devices))] = {
+            self._search_reports[(task.name, size)] = {
                 "memory_infeasible": (
-                    best[1] is None and n_configs > 0 and n_memory == n_configs
+                    best is None and len(grid) > 0 and n_memory == len(grid)
                 ),
-                "configs": n_configs,
+                "configs": len(grid),
                 "memory_rejected": n_memory,
                 "errors": n_error,
                 "first_error": first_error,
                 "refusals_fresh": refusals["fresh"],
                 "refusals_replayed": refusals["recorded"],
+                # points that were ready before this thread asked for them
+                "prepared_ahead": n_ahead,
             }
-        return best
+        if best is None:
+            return None, None
+        return dict(best.config), best.timed[0]
 
     @staticmethod
     def _stack_fields(task: Any) -> Dict[str, int]:
@@ -1189,7 +1334,41 @@ class SPMDTechnique(BaseTechnique):
     def _try_config(
         self, task: Any, devices: Sequence[Any], config: Dict[str, Any]
     ) -> Optional[Tuple[float, float]]:
-        """(seconds/batch, host_fraction) for one config; None = over memory.
+        """(seconds/batch, host_fraction) for one config on this thread;
+        None = over memory. What ``search`` does for a grid point, without
+        the grid (the chip diagnostics call it)."""
+        prepared = self._prepare(task, devices, config)
+        return None if prepared is None else self._measure(task, prepared)
+
+    def _prepare(
+        self, task: Any, devices: Sequence[Any], config: Dict[str, Any]
+    ) -> Optional[_Prepared]:
+        """The host's half of a grid point: build (the one Python trace),
+        compile (lowering, the text hash, a cache hit or a compile or a
+        refusal), the memory check with its audit and, for a point that
+        fits, the init program's compile. None = over memory. Allocates no
+        train state, so it may run while another point is timed.
+
+        The program is the one ``execute()`` dispatches at steady state: the
+        fused K-step window where the config may run one, memory-checked as
+        such (its peak holds the (K, B, T) stack), else the 1-step program.
+        """
+        bundle = self._spanned_build("trial.build", task, devices, config)
+        k = self._profile_window(config)
+        program = self._spanned_compile("trial.compile", bundle, k)
+        if not self._fits_compiled(program, devices,
+                                   task=task, config=config, k=k):
+            return None
+        # The init program too: lowered and compiled here it is a plain call
+        # where the state is put on the chip, and not a trace and a cache
+        # retrieval on the measuring thread while this one holds the GIL
+        # (1.4 s a point where the call is 0.2; read on the chip, PR 37).
+        # ``jit`` keeps what ``lower().compile()`` made; nothing is allocated.
+        bundle.init.lower().compile()
+        return _Prepared(bundle, program, k)
+
+    def _measure(self, task: Any, prepared: _Prepared) -> Tuple[float, float]:
+        """The chip's half of a grid point: (seconds/batch, host_fraction).
 
         The host fraction — staging cost (dataset slice + ``device_put``)
         relative to staging + device compute for one steady-state batch — is
@@ -1200,20 +1379,15 @@ class SPMDTechnique(BaseTechnique):
         execute() time); staging is measured separately, outside the timed
         region.
         """
-        bundle = self._spanned_build("trial.build", task, devices, config)
-        k = self._profile_window(config)
+        bundle, program, k = prepared
+        ds = task.get_dataset()
+        with _metrics.span("trial.init"):
+            state = bundle.init()
         if k > 1:
-            # Profile the fused window program execute() dispatches at
-            # steady state. Memory-check the SAME program (its peak holds
-            # the (K, B, T) stack); pre-staged, per-call-fresh window stacks
-            # keep donation honest and transfer out of the timed region —
-            # at execute() time the prefetcher overlaps staging with
-            # compute, so a trial that timed staging would overestimate.
-            fused = self._spanned_compile("trial.compile", bundle, k)
-            if not self._fits_compiled(fused, devices,
-                                       task=task, config=config, k=k):
-                return None
-            ds = task.get_dataset()
+            # Pre-staged, per-call-fresh window stacks keep donation honest
+            # and transfer out of the timed region — at execute() time the
+            # prefetcher overlaps staging with compute, so a trial that
+            # timed staging would overestimate.
             sharding = bundle.stacked_sharding()
 
             def stage(j: int):
@@ -1222,8 +1396,6 @@ class SPMDTechnique(BaseTechnique):
                 )
                 return jax.device_put(host, sharding)
 
-            with _metrics.span("trial.init"):
-                state = bundle.init()
             # The stacks are staged here, not inside time_fused_window, so
             # that ``trial.timing`` is the device program alone; the second
             # ``trial.stage`` is the probe that prices staging.
@@ -1232,7 +1404,8 @@ class SPMDTechnique(BaseTechnique):
                 jax.block_until_ready(windows)
             with _metrics.span("trial.timing", k=k, n_timed=2):
                 t = time_fused_window(
-                    fused, state, windows.__getitem__, k, n_timed=2, n_warmup=1
+                    program, state, windows.__getitem__, k, n_timed=2,
+                    n_warmup=1
                 )
             with _metrics.span("trial.stage", k=k, n_stacks=1):
                 t0 = _timeit.default_timer()
@@ -1241,21 +1414,13 @@ class SPMDTechnique(BaseTechnique):
                 t_host = (_timeit.default_timer() - t0) / k
                 del probe
             return t, _host_fraction(t_host, t)
-        compiled = self._spanned_compile("trial.compile", bundle, 1)
-        if not self._fits_compiled(compiled, devices,
-                                   task=task, config=config, k=1):
-            return None
-        with _metrics.span("trial.init"):
-            state = bundle.init()
         with _metrics.span("trial.stage", k=1):
             t0 = _timeit.default_timer()
-            batch = jax.device_put(
-                task.get_dataset().batch(0), bundle.batch_sharding
-            )
+            batch = jax.device_put(ds.batch(0), bundle.batch_sharding)
             jax.block_until_ready(batch)
             t_host = _timeit.default_timer() - t0
         with _metrics.span("trial.timing", k=1, n_timed=3):
-            t = time_train_step(compiled, state, batch, n_timed=3, n_warmup=2)
+            t = time_train_step(program, state, batch, n_timed=3, n_warmup=2)
         return t, _host_fraction(t_host, t)
 
     def _spanned_build(self, name: str, task, devices, config,

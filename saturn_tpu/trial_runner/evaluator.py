@@ -265,7 +265,7 @@ def _search_inner(
     # run with a blank topology signature and never hit again.
     topo_sig = pcache.topology_signature(topo) if cache is not None else ""
     # Trials profile whatever dispatch mode execute() will run (fused
-    # K-step windows vs per-step — ``SPMDTechnique._try_config``), so the
+    # K-step windows vs per-step — ``SPMDTechnique._prepare``), so the
     # mode is part of every cache key: a per-step profile recorded before
     # fused dispatch landed (or with a different window cap) must MISS, not
     # warm-start the sweep with numbers execution won't reproduce.

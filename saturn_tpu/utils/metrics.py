@@ -251,6 +251,20 @@ class span:
     def set(self, **fields) -> None:
         self.fields.update(fields)
 
+    def close(self) -> None:
+        """The end of a span taken with :meth:`open`: the span's one event,
+        as ``__exit__`` emits it, from whichever thread the stretch ends on
+        (``thread`` names that one). For a stretch that begins on one thread
+        and ends on another (a grid point's ``trial.config``: prepared on
+        the caller's thread, measured on the search's measuring thread),
+        which no ``with`` block can hold. No annotation for the profiler; a no-op without a
+        sink."""
+        if self.id is not None:
+            event(self.name, ts_start=self.ts_start,
+                  dur_s=time.perf_counter() - self._t0,
+                  thread=threading.current_thread().name,
+                  **self.ids(), **self.fields)
+
     def __enter__(self) -> "span":
         global _ANNOTATION
         if _ANNOTATION is None:
@@ -266,13 +280,10 @@ class span:
     def __exit__(self, exc_type, exc, tb) -> bool:
         try:
             if self.id is not None:
-                dur = time.perf_counter() - self._t0
                 _stack().pop()  # blocks nest: the top of the stack is self
                 if exc_type is not None:
                     self.fields["error"] = exc_type.__name__
-                event(self.name, ts_start=self.ts_start, dur_s=dur,
-                      thread=threading.current_thread().name,
-                      **self.ids(), **self.fields)
+                self.close()
         finally:
             self._ann.__exit__(exc_type, exc, tb)
         return False

@@ -7,13 +7,59 @@ call), ``block_until_ready`` to sync, then time ``n`` steady-state steps.
 
 from __future__ import annotations
 
+import contextlib
+import gc
 import logging
+import sys
+import threading
 import timeit
 from typing import Callable, Tuple
 
 import jax
 
 log = logging.getLogger("saturn_tpu")
+
+#: the interpreter's switch interval while a clock runs (its default is 5 ms)
+_SWITCH_INTERVAL_S = 0.0005
+#: the collector's third threshold while a clock runs: no full collection
+_NO_FULL_COLLECTION = 1 << 30
+_clocks_lock = threading.Lock()
+_clocks = {"running": 0, "gc": (0, 0, 0), "interval": 0.0}
+
+
+@contextlib.contextmanager
+def undisturbed_clock():
+    """Around a timed region whose last reading of the clock needs the GIL
+    back from other threads of this process (``search`` traces and lowers
+    the next grid point on its caller's thread while its measuring thread
+    times this one): no full pass of the cyclic collector, and a short
+    switch interval.
+
+    Read on the chip (PR 37): a full collection over the heap of a tracing
+    thread holds the GIL for 50-290 ms wherever that thread happens to
+    allocate (the young generations' passes take 2-4 ms and go on), and a
+    thread that wants the GIL back from running bytecode waits one switch
+    interval; the first made a timed point of 16 x 127 ms read 4 % long, the
+    second is 0.25 % of it. Both are the interpreter's own, process-wide
+    settings: the first clock to start takes them, the last to stop puts
+    them back (trial threads time side by side on disjoint blocks). What is
+    left is a C call that keeps the GIL (the compile cache's decompression:
+    20 ms)."""
+    with _clocks_lock:
+        if _clocks["running"] == 0:
+            _clocks["gc"] = gc.get_threshold()
+            _clocks["interval"] = sys.getswitchinterval()
+            gc.set_threshold(*_clocks["gc"][:2], _NO_FULL_COLLECTION)
+            sys.setswitchinterval(min(_SWITCH_INTERVAL_S, _clocks["interval"]))
+        _clocks["running"] += 1
+    try:
+        yield
+    finally:
+        with _clocks_lock:
+            _clocks["running"] -= 1
+            if _clocks["running"] == 0:
+                sys.setswitchinterval(_clocks["interval"])
+                gc.set_threshold(*_clocks["gc"])
 
 
 def time_train_step(
@@ -34,11 +80,12 @@ def time_train_step(
     for _ in range(n_warmup):
         state, aux = step(state, batch)
     jax.device_get(aux)
-    t0 = timeit.default_timer()
-    for _ in range(n_timed):
-        state, aux = step(state, batch)
-    jax.device_get(aux)
-    return (timeit.default_timer() - t0) / n_timed
+    with undisturbed_clock():
+        t0 = timeit.default_timer()
+        for _ in range(n_timed):
+            state, aux = step(state, batch)
+        jax.device_get(aux)
+        return (timeit.default_timer() - t0) / n_timed
 
 
 def time_fused_window(
@@ -63,11 +110,12 @@ def time_fused_window(
     for j in range(n_warmup):
         state, aux = fused(state, windows[j])
     jax.device_get(aux)
-    t0 = timeit.default_timer()
-    for j in range(n_warmup, n_warmup + n_timed):
-        state, aux = fused(state, windows[j])
-    jax.device_get(aux)
-    return (timeit.default_timer() - t0) / (n_timed * k)
+    with undisturbed_clock():
+        t0 = timeit.default_timer()
+        for j in range(n_warmup, n_warmup + n_timed):
+            state, aux = fused(state, windows[j])
+        jax.device_get(aux)
+        return (timeit.default_timer() - t0) / (n_timed * k)
 
 
 def hbm_bytes_required(compiled) -> int:

@@ -103,14 +103,14 @@ def test_all_sizes_rehearsal_marks_indivisible_batch_infeasible(
 def test_a_raising_trial_config_fails_the_smoke(rehearsal, monkeypatch):
     from saturn_tpu.parallel.dp import DataParallel
 
-    real = DataParallel._try_config
+    real = DataParallel._prepare
 
     def flaky(self, task, devices, config):
         if config.get("remat"):
             raise RuntimeError("kernel variant failed to lower")
         return real(self, task, devices, config)
 
-    monkeypatch.setattr(DataParallel, "_try_config", flaky)
+    monkeypatch.setattr(DataParallel, "_prepare", flaky)
     monkeypatch.setattr(chip_smoke, "ONE_CHIP_JOBS", TINY_ONE_CHIP[:1])
     with pytest.raises(chip_smoke.SmokeFailure, match="failed to lower"):
         chip_smoke.main([])

@@ -467,10 +467,10 @@ def test_a_refusal_from_running_is_an_error_and_is_not_recorded(
     it stays a config that raised."""
     from saturn_tpu.parallel.dp import DataParallel
 
-    def no_room(self, task, devices, config):
+    def no_room(self, task, prepared):
         raise RuntimeError("RESOURCE_EXHAUSTED: Error allocating device buffer")
 
-    monkeypatch.setattr(DataParallel, "_try_config", no_room)
+    monkeypatch.setattr(DataParallel, "_measure", no_room)
     tech = DataParallel()
     task = _task(str(tmp_path / "ck"), "refused-c")
     ev = str(tmp_path / "ev.jsonl")

@@ -119,6 +119,47 @@ def test_grid_point_traces_its_step_once(name, size, tmp_path, devices8):
         assert calls["train_step"] == 1
 
 
+def test_every_point_of_a_grid_traces_once_on_the_callers_thread(
+        tmp_path, devices8):
+    """The whole grid (PR 37: the caller's thread walks it and prepares while
+    a measuring thread behind it measures): still one Python trace a point,
+    made where the point is built, under whatever context the caller set."""
+    task = _task(tmp_path, "test-tiny", "once-grid")
+    tech = _technique("dp")
+    devices = devices8[:1]
+    grid = tech.candidate_configs(task, 1)
+    assert len(grid) >= 2
+    calls = _count_step_calls(tech)
+
+    path = str(tmp_path / "events.jsonl")
+    with metrics.scoped(path):
+        best, t = tech.search(task, devices, 0)
+    assert best in grid and t > 0
+    assert calls == {"make_step_fns": len(grid), "train_step": len(grid)}
+    points = metrics.read_events(path, kind="trial_config")
+    assert len(points) == len(grid)
+    assert all(p["step_traces"] == 1 and "per_batch_s" in p for p in points)
+    builds = metrics.read_events(path, kind="trial.build")
+    assert [b["traces"] for b in builds] == [1] * len(grid)
+    assert {b["thread"] for b in builds} == {"MainThread"}
+    for reader in ("trial.compile", "trial.memlens"):
+        found = metrics.read_events(path, kind=reader)
+        assert len(found) == len(grid)
+        assert all(sp["trace"] == "shared" for sp in found), reader
+    assert {e["thread"] for e in metrics.read_events(path, kind="trial.timing")
+            } == {"meas-MainThread"}
+    # the init program is compiled where the point is prepared (``jit`` keeps
+    # what ``lower().compile()`` made): putting the state on the chip is a
+    # call, on the measuring thread, and compiles nothing there
+    inits = [e for e in metrics.read_events(path, kind="compile")
+             if "saturn_init" in e["program"]]
+    assert len(inits) == len(grid)
+    assert {e["thread"] for e in inits} == {"MainThread"}
+    assert {e["in_span"]["name"] for e in inits} == {"trial.config"}
+    for config in grid:
+        assert tech._cached_bundle(task, devices, config).step_traces == 1
+
+
 # --------------------------------- (c) trace_step: cached bundle = fresh trace
 @pytest.mark.parametrize("name", ["dp", "fsdp", "tp"])
 def test_trace_step_from_cached_bundle_equals_fresh(name, tmp_path, devices8):

@@ -107,3 +107,23 @@ def test_replayed_programs_match_fresh_traces_bitwise(preset, tmp_path,
         _same_bits(got_loss, want_loss)
     _same_bits(got, want)
     assert bundle.step_traces == 1
+
+
+def test_a_trace_made_beside_the_measuring_thread_is_the_fresh_trace(
+        tmp_path, devices8):
+    """A grid's points after the first are built while ``search``'s measuring
+    thread runs the one before on the chip (PR 37): the program a point's
+    bundle keeps from there is, text for text, what a fresh trace gives."""
+    task = _task(tmp_path, "test-tiny", "bits-prep", batch=2)
+    tech = _technique("dp")
+    devices = devices8[:1]
+    grid = tech.candidate_configs(task, 1)
+    assert len(grid) >= 2
+    best, _ = tech.search(task, devices, 0)
+    assert best in grid
+    for config in grid:
+        bundle = tech._cached_bundle(task, devices, config)
+        assert bundle.step_traces == 1
+        step, _ = _fresh_programs(tech, task, devices, config, bundle)
+        assert _program_text(bundle.lowered) == _program_text(
+            step.lower(bundle.state_shapes, bundle.batch_sds))

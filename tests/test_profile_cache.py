@@ -400,9 +400,12 @@ class TestTrialErrorsReported:
             def candidate_configs(self, task, n_devices):
                 return [{"variant": "kernel"}, {"variant": "dense"}]
 
-            def _try_config(self, task, devices, config):
+            def _prepare(self, task, devices, config):
                 if config["variant"] == "kernel":
                     raise RuntimeError("kernel failed to lower")
+                return config
+
+            def _measure(self, task, prepared):
                 return 0.01, 0.0
 
         library.register("onebad", OneBadConfig)
@@ -439,7 +442,7 @@ class TestTrialErrorsReported:
             def candidate_configs(self, task, n_devices):
                 return [{}]
 
-            def _try_config(self, task, devices, config):
+            def _prepare(self, task, devices, config):
                 raise InfeasibleConfig("batch_size 6 not divisible by data=4")
 
         library.register("neverfits", NeverFits)

@@ -1,0 +1,554 @@
+"""``SPMDTechnique.search`` as two stages: the caller's thread walks the grid
+and prepares each point (build, compile, memory check) while a measuring
+thread behind it measures (init, stage, timing), one point at a time.
+
+The technique under test is ``SPMDTechnique`` itself over stub bundles: a
+stub says how its point ends (timed, refused by the compiler, over the
+memory rule, infeasible, raising) and how long its build and its timed
+program sleep, so every case is a matter of milliseconds on any host. The
+outcomes of case (i) were pinned on the serial walk (one thread, the
+technique's order) before the pipeline replaced it.
+"""
+
+import threading
+import time
+import types
+from collections import Counter
+
+import jax
+import numpy as np
+import pytest
+
+from saturn_tpu.core.technique import InfeasibleConfig
+from saturn_tpu.parallel import spmd_base
+from saturn_tpu.parallel.spmd_base import SPMDTechnique
+from saturn_tpu.resilience.crash import SimulatedKill
+from saturn_tpu.utils import aot_cache, metrics
+
+LIMIT_S = 60.0          # the time limit of a case: none may hang
+HBM = 1 << 30           # what the memory rule runs against (the CPU reports none)
+MEAS = "meas-"          # the measuring thread's name starts with this
+
+
+def within_limit(fn):
+    """``fn()`` on a thread of its own, joined with a time limit; its result,
+    or its exception raised here."""
+    box = {}
+
+    def run():
+        try:
+            box["out"] = fn()
+        except BaseException as e:  # re-raised below, on the test's thread
+            box["exc"] = e
+
+    t = threading.Thread(target=run, name="case", daemon=True)
+    t.start()
+    t.join(LIMIT_S)
+    assert not t.is_alive(), f"search hung for {LIMIT_S:.0f} s"
+    if "exc" in box:
+        raise box["exc"]
+    return box["out"]
+
+
+class Dataset:
+    batch_size = 2
+
+    def batch(self, i):
+        return np.full((2, 4), i, np.int32)
+
+
+class Task:
+    def __init__(self, name="piped"):
+        self.name = name
+
+    def get_model(self, **kw):
+        return types.SimpleNamespace()
+
+    def get_dataset(self):
+        return Dataset()
+
+
+class State:
+    """Train state of a stub: counts how many are alive."""
+
+    def __init__(self, book):
+        self.book = book
+        book.alive += 1
+        book.most_alive = max(book.most_alive, book.alive)
+
+    def __del__(self):
+        self.book.alive -= 1
+
+
+class Program:
+    """The compiled window program of a stub point."""
+
+    def __init__(self, point, book):
+        self.point, self.book = point, book
+
+    def memory_analysis(self):
+        return types.SimpleNamespace(
+            temp_size_in_bytes=self.point.get("need", 1024),
+            argument_size_in_bytes=0, output_size_in_bytes=0,
+            alias_size_in_bytes=0)
+
+    def __call__(self, state, window):
+        self.book.log("step", self.point)
+        if self.point.get("run") == "raise":
+            raise RuntimeError("RESOURCE_EXHAUSTED: no room to run")
+        if self.point.get("run") == "kill":
+            raise SimulatedKill("killed while timing")
+        time.sleep(self.point.get("step_s", 0.0))
+        return state, np.zeros((8,), np.float32)
+
+
+class Init:
+    """A stub's jitted init: compiled where the point is prepared (no state
+    yet), called where it is measured."""
+
+    def __init__(self, bundle):
+        self.point, self.book = bundle.point, bundle.book
+
+    def lower(self):
+        self.book.log("init_compile", self.point)
+        return types.SimpleNamespace(compile=lambda: None)
+
+    def __call__(self):
+        self.book.log("init", self.point)
+        return State(self.book)
+
+
+class Bundle:
+    """What ``_prepare`` and ``_measure`` need of a ``_Bundle``."""
+
+    step_traces = 1
+    _compiled = None
+
+    def __init__(self, point, book):
+        self.point, self.book = point, book
+        self._program = None
+
+    def has_fused(self, k):
+        return self._program is not None
+
+    def fused_compiled(self, k):
+        if self.point.get("compile") == "refuse":
+            raise aot_cache.CompileRefused(
+                "RESOURCE_EXHAUSTED: the program needs 17.1G of 15.7G hbm",
+                "recorded", "jit_saturn_window")
+        if self._program is None:
+            self._program = Program(self.point, self.book)
+        return self._program
+
+    def stacked_sharding(self):
+        return jax.sharding.SingleDeviceSharding(jax.devices()[0])
+
+    @property
+    def init(self):
+        return Init(self)
+
+    @property
+    def traced(self):
+        raise RuntimeError("a stub keeps no trace")  # memlens: best effort
+
+
+class Book:
+    """What the stubs saw, in order, with the thread each call ran on."""
+
+    def __init__(self):
+        self.calls = []
+        self.alive = self.most_alive = 0
+        self._lock = threading.Lock()
+
+    def log(self, what, point):
+        with self._lock:
+            self.calls.append((what, point["id"], time.perf_counter(),
+                               threading.current_thread().name))
+
+    def order(self, what):
+        return [c[1] for c in self.calls if c[0] == what]
+
+
+class Stubbed(SPMDTechnique):
+    """``SPMDTechnique``'s own ``search`` over a grid of stub points."""
+
+    name = "stubbed"
+
+    def __init__(self, points):
+        super().__init__()
+        self.points = points
+        self.book = Book()
+        self._built = {}
+
+    def candidate_configs(self, task, n_devices):
+        return [{k: p[k] for k in ("id", "remat") if k in p}
+                for p in self.points]
+
+    def build(self, task, devices, config, use_cache=True):
+        hit = self._built.get(config["id"])
+        if hit is not None:
+            return hit
+        (point,) = [p for p in self.points if p["id"] == config["id"]]
+        self.book.log("build", point)
+        time.sleep(point.get("build_s", 0.0))
+        how = point.get("build")
+        if how == "infeasible":
+            raise InfeasibleConfig("batch_size 2 not divisible by data=4")
+        if how == "raise":
+            raise ValueError("kernel variant failed to lower")
+        if how == "kill":
+            raise SimulatedKill("killed while building")
+        self._built[config["id"]] = Bundle(point, self.book)
+        return self._built[config["id"]]
+
+
+@pytest.fixture()
+def run(tmp_path, monkeypatch):
+    """search(points) -> (technique, winner, report, events)."""
+    monkeypatch.setenv("SATURN_TPU_HBM_BYTES", str(HBM))
+
+    def go(points, name="piped"):
+        tech = Stubbed(points)
+        path = str(tmp_path / f"{name}.jsonl")
+        with metrics.scoped(path):
+            with metrics.span("search"):
+                best = within_limit(
+                    lambda: tech.search(Task(name), jax.devices()[:1], 0))
+        events = metrics.read_events(path)
+        return tech, best, tech.search_report(name, 1), events
+    return go
+
+
+def no_measuring_thread_left():
+    deadline = time.time() + 5.0
+    while time.time() < deadline:
+        left = [t.name for t in threading.enumerate()
+                if t.name.startswith(MEAS)]
+        if not left:
+            return True
+        time.sleep(0.01)
+    return False
+
+
+def of_kind(events, kind):
+    return [e for e in events if e["kind"] == kind]
+
+
+def interval(e):
+    return e["ts_start"], e["ts_start"] + e["dur_s"]
+
+
+# (i) ------------------------------------------------ every way a point ends
+MIXED = [
+    {"id": "over", "remat": False, "need": HBM},           # over 0.92 x HBM
+    {"id": "refused", "remat": False, "compile": "refuse"},
+    {"id": "slow", "remat": True, "step_s": 0.04},
+    {"id": "fast", "remat": True, "step_s": 0.01},
+    {"id": "odd", "build": "infeasible"},
+    {"id": "broken", "build": "raise"},
+]
+
+
+def test_mixed_grid_ends_as_the_serial_walk_did(run):
+    tech, (config, t), report, events = run(MIXED)
+    assert config == {"id": "fast", "remat": True}
+    assert 0.01 / 8 <= t < 0.04 / 8
+    assert report == {
+        "memory_infeasible": False, "configs": 6, "memory_rejected": 2,
+        "errors": 1,
+        "first_error": "stubbed {'id': 'broken'}: "
+                       "ValueError('kernel variant failed to lower')",
+        "refusals_fresh": 0, "refusals_replayed": 1,
+        "prepared_ahead": report["prepared_ahead"],
+    }
+    assert Counter(e["outcome"] for e in of_kind(events, "trial.config")) == {
+        "timed": 2, "refused": 1, "memory_rejected": 1, "infeasible": 1,
+        "error": 1}
+    notes = {e["config"]["id"]: e for e in of_kind(events, "trial_config")}
+    assert len(of_kind(events, "trial_config")) == len(notes) == 6
+    assert notes["over"]["memory_rejected"] is True and "refusal" not in notes["over"]
+    assert notes["refused"]["memory_rejected"] is True
+    assert notes["refused"]["refusal"] == "recorded"
+    assert notes["refused"]["compiler"].startswith("RESOURCE_EXHAUSTED")
+    assert notes["odd"]["infeasible"] == "batch_size 2 not divisible by data=4"
+    assert notes["broken"]["error"] == "ValueError('kernel variant failed to lower')"
+    assert notes["fast"]["per_batch_s"] == t
+    assert notes["slow"]["per_batch_s"] > t
+    for e in notes.values():
+        assert e["task"] == "piped" and e["size"] == 1
+        assert e["technique"] == "stubbed"
+    by = {e["config"]["id"]: e for e in of_kind(events, "trial.config")}
+    assert by["odd"]["reason"] == notes["odd"]["infeasible"]
+    assert by["refused"]["refusal"] == "recorded"
+    assert tech.host_fraction_report("piped", 1) is not None
+    # the init program is compiled where a point is prepared, if it fits
+    assert tech.book.order("init_compile") == ["slow", "fast"]
+    # ... on the caller's thread, like every build; the states are made and
+    # the steps taken on the measuring thread
+    assert {c[3] for c in tech.book.calls
+            if c[0] in ("build", "init_compile")} == {"case"}
+    assert {c[3] for c in tech.book.calls
+            if c[0] in ("init", "step")} == {"meas-case"}
+    assert no_measuring_thread_left()
+
+
+def test_a_point_that_raises_while_it_runs_is_an_error(run):
+    """``RESOURCE_EXHAUSTED`` out of a program that runs is a config that
+    raised, on the measuring thread, and the grid goes on."""
+    tech, (config, _), report, events = run([
+        {"id": "a", "remat": True, "run": "raise"},
+        {"id": "b", "remat": False, "step_s": 0.01},
+    ])
+    assert config == {"id": "b", "remat": False}
+    assert report["errors"] == 1 and report["memory_rejected"] == 0
+    assert "RESOURCE_EXHAUSTED" in report["first_error"]
+    assert Counter(e["outcome"] for e in of_kind(events, "trial.config")) == {
+        "error": 1, "timed": 1}
+    assert no_measuring_thread_left()
+
+
+# (ii) ------------------------------------------------------------ the overlap
+SLEEPY = [{"id": f"p{i}", "remat": True, "build_s": 0.15, "step_s": 0.2}
+          for i in range(3)]    # a timing is three calls: 0.6 s
+
+
+def test_a_later_build_lies_inside_an_earlier_timing(run):
+    t0 = time.perf_counter()
+    tech, best, report, events = run(SLEEPY)
+    wall = time.perf_counter() - t0
+    timings = {e["parent"]: interval(e) for e in of_kind(events, "trial.timing")}
+    points = {e["id"]: e["config"]["id"] for e in of_kind(events, "trial.config")}
+    builds = {points[e["parent"]]: e for e in of_kind(events, "trial.build")}
+    t_first = [iv for parent, iv in timings.items() if points[parent] == "p0"][0]
+    # p1 is built while p0 is put on the chip and staged; p2 under its timing
+    b_later = interval(builds["p2"])
+    assert t_first[0] <= b_later[0] and b_later[1] <= t_first[1], (
+        t_first, b_later)
+    assert builds["p2"]["thread"] == "case"
+    assert of_kind(events, "trial.timing")[0]["thread"].startswith(MEAS)
+    serial = sum(e["dur_s"] for e in events
+                 if e["kind"] in ("trial.build", "trial.timing"))
+    assert wall < serial - 0.2, (wall, serial)
+    assert report["prepared_ahead"] == 2   # all but the first were waiting
+    assert best[0] is not None and report["configs"] == 3
+
+
+# (iii) ---------------------------------------- one state, one point measured
+def test_init_waits_for_the_timing_before_it(run):
+    tech, _, _, events = run(SLEEPY)
+    assert tech.book.most_alive == 1 and tech.book.alive == 0
+    points = {e["id"]: e["config"]["id"] for e in of_kind(events, "trial.config")}
+    inits = [(points[e["parent"]], interval(e))
+             for e in of_kind(events, "trial.init")]
+    timings = [(points[e["parent"]], interval(e))
+               for e in of_kind(events, "trial.timing")]
+    assert len(inits) == len(timings) == 3
+    for p, (lo, hi) in inits:
+        for q, (t_lo, t_hi) in timings:
+            if p != q:
+                assert hi <= t_lo or lo >= t_hi, (p, q)
+    # one measuring thread, for every point
+    mine = {e["thread"] for e in events
+            if e["kind"] in ("trial.init", "trial.stage", "trial.timing")}
+    assert mine == {"meas-case"}
+    # and the stubs agree: no init between another point's first and last step
+    calls = tech.book.calls
+    for i, (what, p, _, _) in enumerate(calls):
+        if what == "init":
+            before = {c[1] for c in calls[:i] if c[0] == "step"}
+            after = {c[1] for c in calls[i:] if c[0] == "step"}
+            assert not (before & after) - {p}
+
+
+# (iv) ------------------------------------------------- the order, and a tie
+GRID = [
+    {"id": "plain-a", "remat": False}, {"id": "plain-b", "remat": False},
+    {"id": "remat-a", "remat": True}, {"id": "remat-b", "remat": True},
+    {"id": "silent"},                   # says nothing of remat: as remat off
+]
+
+
+def test_remat_points_are_prepared_first(run):
+    tech, _, _, events = run(GRID)
+    want = ["remat-a", "remat-b", "plain-a", "plain-b", "silent"]
+    assert tech.book.order("build") == want
+    assert tech.book.order("init") == want
+    assert [e["config"]["id"] for e in of_kind(events, "trial_config")] == want
+
+
+def test_a_tie_goes_to_the_techniques_order(run, monkeypatch):
+    monkeypatch.setattr(spmd_base, "time_fused_window",
+                        lambda *a, **k: 0.125)
+    _, (config, t), _, _ = run(GRID)
+    assert (config, t) == ({"id": "plain-a", "remat": False}, 0.125)
+
+
+def test_the_fastest_still_wins_wherever_it_stands(run):
+    points = [dict(p, step_s=0.03) for p in GRID]
+    points[1]["step_s"] = 0.0
+    _, (config, _), _, _ = run(points)
+    assert config == {"id": "plain-b", "remat": False}
+
+
+def test_a_grid_of_one_runs_on_the_callers_thread(run):
+    tech, (config, _), report, events = run([{"id": "only", "remat": False}])
+    assert config == {"id": "only", "remat": False}
+    assert {c[3] for c in tech.book.calls} == {"case"}
+    assert {e["thread"] for e in events if e["kind"].startswith("trial.")} == {"case"}
+    assert report["prepared_ahead"] == 0
+
+
+# (v) ------------------------------------------- nothing left behind, no hang
+def killed(points, match):
+    """A search that a ``SimulatedKill`` ends; the technique, for what its
+    stubs saw, once no measuring thread is left."""
+    tech = Stubbed(points)
+    with pytest.raises(SimulatedKill, match=match):
+        within_limit(lambda: tech.search(Task(), jax.devices()[:1], 0))
+    # no wait: the thread was joined before ``search`` let the kill through
+    assert not [t.name for t in threading.enumerate()
+                if t.name.startswith(MEAS)]
+    return tech
+
+
+def test_a_killed_preparation_ends_the_search_and_its_thread():
+    tech = killed([{"id": "a", "remat": True, "step_s": 0.05},
+                   {"id": "b", "remat": True, "build": "kill"},
+                   {"id": "c", "remat": False, "build_s": 0.05}],
+                  "while building")
+    assert tech.book.order("build") == ["a", "b"]   # and no further point
+
+
+def test_a_killed_measurement_ends_the_search_and_its_thread():
+    tech = killed([{"id": "a", "remat": True, "run": "kill"},
+                   {"id": "b", "remat": True, "build_s": 0.3},
+                   {"id": "c", "remat": False, "build_s": 0.3},
+                   {"id": "d", "remat": False, "build_s": 0.3}],
+                  "while timing")
+    # the point in preparation is finished, none is started after it
+    assert tech.book.order("build") == ["a", "b"]
+    assert tech.book.order("init") == ["a"]
+
+
+def test_every_point_raising_leaves_no_thread(run):
+    tech, best, report, _ = run(
+        [{"id": f"x{i}", "remat": bool(i % 2), "build": "raise"}
+         for i in range(4)])
+    assert best == (None, None)
+    assert report["errors"] == report["configs"] == 4
+    assert report["memory_infeasible"] is False
+    assert no_measuring_thread_left()
+
+
+def test_searches_side_by_side_on_one_technique_keep_their_points_apart(
+        tmp_path, monkeypatch):
+    """The evaluator's shape, harder: one technique instance, more searching
+    threads than cores, each with a measuring thread of its own, under a
+    switch interval that interleaves them everywhere. Every search still
+    sees each of its points exactly once and reports its own winner."""
+    import sys
+
+    monkeypatch.setenv("SATURN_TPU_HBM_BYTES", str(HBM))
+    # a host this crowded times nothing to the millisecond: each point's time
+    # is given, the first of the technique's order the fastest
+    points = [dict(p, t=0.01 * (i + 1)) for i, p in enumerate(GRID)]
+    points.append({"id": "refused", "remat": False, "compile": "refuse"})
+    monkeypatch.setattr(spmd_base, "time_fused_window",
+                        lambda fused, *a, **k: fused.point["t"])
+    tech = Stubbed(points)
+    tech.build = types.MethodType(         # no memo shared between the tasks
+        lambda self, task, devices, config, use_cache=True: Bundle(
+            next(p for p in points if p["id"] == config["id"]), self.book),
+        tech)
+    names = [f"job{i}" for i in range(12)]
+    out, path = {}, str(tmp_path / "ev.jsonl")
+
+    def one(name):
+        out[name] = tech.search(Task(name), jax.devices()[:1], 0)
+
+    interval = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    try:
+        with metrics.scoped(path), metrics.span("search"):
+            threads = [threading.Thread(target=one, args=(n,), name=f"trial-{n}")
+                       for n in names]
+            for t in threads:
+                t.start()
+            for t in threads:
+                t.join(LIMIT_S)
+            assert not any(t.is_alive() for t in threads)
+    finally:
+        sys.setswitchinterval(interval)
+    events = metrics.read_events(path)
+    for name in names:
+        assert out[name][0] == {"id": "plain-a", "remat": False}, name
+        report = tech.search_report(name, 1)
+        assert report["configs"] == 6 and report["memory_rejected"] == 1
+        assert report["errors"] == 0 and report["refusals_replayed"] == 1
+        mine = [e for e in of_kind(events, "trial_config") if e["task"] == name]
+        assert sorted(e["config"]["id"] for e in mine) == sorted(
+            p["id"] for p in points)
+    spans_ = of_kind(events, "trial.config")
+    assert len(spans_) == 12 * 6
+    assert Counter(e["outcome"] for e in spans_) == {"timed": 60, "refused": 12}
+    assert no_measuring_thread_left()
+
+
+# ------------------------------------- the clock, while another thread traces
+def clock_settings():
+    import gc
+    import sys
+
+    return gc.get_threshold(), sys.getswitchinterval()
+
+
+def test_a_timed_region_keeps_full_collections_and_long_slices_out():
+    from saturn_tpu.utils import timing
+
+    before = clock_settings()
+    quiet = ((*before[0][:2], timing._NO_FULL_COLLECTION),
+             timing._SWITCH_INTERVAL_S)
+    assert before[0][2] < timing._NO_FULL_COLLECTION
+    assert before[1] > timing._SWITCH_INTERVAL_S
+    seen = []
+
+    def fused(state, window):
+        seen.append(clock_settings())
+        return state, np.zeros((8,), np.float32)
+
+    assert timing.time_fused_window(fused, None, lambda j: j, 8) >= 0
+    # the warm-up call runs as the process was; the two timed ones quietly
+    assert seen == [before, quiet, quiet]
+    assert clock_settings() == before
+    seen.clear()
+    timing.time_train_step(fused, None, 0, n_timed=2, n_warmup=1)
+    assert seen == [before, quiet, quiet]
+    assert clock_settings() == before
+
+
+def test_clocks_side_by_side_put_the_settings_back_once():
+    from saturn_tpu.utils import timing
+
+    before = clock_settings()
+    first_in, second_in, first_out = (threading.Event() for _ in range(3))
+    seen = {}
+
+    def second():
+        first_in.wait(LIMIT_S)
+        with timing.undisturbed_clock():
+            second_in.set()
+            first_out.wait(LIMIT_S)
+            # the first clock has stopped: this one still runs undisturbed
+            seen["inside"] = clock_settings()
+
+    t = threading.Thread(target=second)
+    t.start()
+    with timing.undisturbed_clock():
+        first_in.set()
+        assert second_in.wait(LIMIT_S)
+    first_out.set()
+    t.join(LIMIT_S)
+    assert not t.is_alive()
+    assert seen["inside"] == ((*before[0][:2], timing._NO_FULL_COLLECTION),
+                              timing._SWITCH_INTERVAL_S)
+    assert clock_settings() == before

@@ -380,30 +380,55 @@ def test_leaves_cover_the_orchestrate_wall(tiny_run):
 
 
 HANDOFFS = {
-    "engine launcher thread": ("window", "launch.build", "launch-", "task_interval"),
+    "engine launcher thread": ("window", ("launch.build",), "launch-", "task_interval"),
     # its snapshot's sibling: the two overlap (PR 27)
-    "checkpoint writer thread": ("window", "ckpt.write", "ckpt-", "task_interval"),
-    "trial thread": ("search", "trial", "trial-g1", "search"),
+    "checkpoint writer thread": ("window", ("ckpt.write",), "ckpt-", "task_interval"),
+    "trial thread": ("search", ("trial",), "trial-g1", "search"),
+    # a grid point's chip half, on the thread that measures behind the trial
+    # thread while that one prepares the next points (PR 37); a timed point's
+    # ``trial.config`` is opened by the trial thread and closed by this one
+    "measuring thread": ("search", ("trial.init", "trial.stage",
+                                    "trial.timing"),
+                         "meas-trial-g1", "trial.config"),
 }
 
 
 @pytest.mark.parametrize("who", sorted(HANDOFFS))
 def test_handoff_in_the_program(tiny_run, who):
-    phase, kind, thread_prefix, parent_kind = HANDOFFS[who]
+    phase, kinds, thread_prefix, parent_kind = HANDOFFS[who]
     events = tiny_run[phase]
     ids = {e["id"]: e for e in events if "id" in e}
-    mine = [e for e in spans_of(events) if e["kind"] == kind]
-    assert mine
+    mine = [e for e in spans_of(events) if e["kind"] in kinds]
+    assert {e["kind"] for e in mine} == set(kinds)
     for e in mine:
         assert e["thread"].startswith(thread_prefix), e["thread"]
         assert ids[e["parent"]]["kind"] == parent_kind
-        assert ids[e["parent"]].get("thread", "") != e["thread"] or kind == "launch.build"
+        above = ids[e["parent"]]
+        if who == "measuring thread":
+            # a timed point's ``trial.config`` names the thread it ended on,
+            # this one; it was opened under the trial thread's ``trial``
+            above = ids[above["parent"]]
+            assert above["kind"] == "trial"
+        assert (above.get("thread", "") != e["thread"]
+                or kinds == ("launch.build",))
     if who == "engine launcher thread":
         # task_interval is the launcher thread's, its parent the main thread's
         for ti in (e for e in events if e["kind"] == "task_interval"):
             assert ids[ti["parent"]]["kind"] == "interval"
             assert ids[ti["parent"]]["thread"] == "MainThread"
         assert len({e["thread"] for e in mine}) == 2  # two gangs, two threads
+    if who == "measuring thread":
+        # one measuring thread a trial thread, named after it; the host's
+        # half stays on the trial thread, under the same ``trial.config``
+        assert len({e["thread"] for e in mine}) == 2
+        for e in spans_of(events):
+            if e["kind"] in ("trial.build", "trial.compile",
+                             "trial.memory_check"):
+                assert e["thread"].startswith("trial-g1"), e
+                assert ids[e["parent"]]["kind"] == "trial.config"
+                # closed by the thread the point ended on
+                assert ids[e["parent"]]["thread"] in (
+                    e["thread"], "meas-" + e["thread"])
 
 
 def test_handoff_to_the_solver_pool(tiny_run, sink, devices8):
