@@ -329,6 +329,11 @@ class _GridPoint:
     error: Optional[str] = None     # of an ``error`` point, for ``first_error``
 
 
+#: a wait across the hand-off shorter than this emits no span: the other side
+#: was ready (a chip-bound search would emit one empty wait a point)
+_WAITED_S = 1e-3
+
+
 def _measured_behind(
     grid: Sequence[Tuple[int, Dict[str, Any]]],
     prepare: Callable[[int, Dict[str, Any]], _GridPoint],
@@ -350,6 +355,13 @@ def _measured_behind(
     how that point ended). Anything else one of them raises (a kill) is
     raised here, on the caller's thread, once the other has finished the
     point it was on: it starts no further point, and the thread is joined.
+
+    Who waited for whom is two spans under the caller's open span
+    (``trial``), stamps around the two blocking calls and nothing more:
+    ``trial.wait_prepared`` on the measuring thread (the chip has nothing to
+    measure; ``ahead`` says whether the point was already there) and
+    ``trial.wait_measured`` on the caller's (the host has nothing left to
+    prepare).
     """
     if len(grid) < 2:
         points = [prepare(order, config) for order, config in grid]
@@ -359,12 +371,15 @@ def _measured_behind(
     ready: "queue.SimpleQueue[Optional[_GridPoint]]" = queue.SimpleQueue()
     gone = threading.Event()     # one side has left: start no further point
     behind: Dict[str, Any] = {"ahead": 0, "raised": None}
+    above = _metrics.current_span()  # ``trial``: the two waits' parent
 
     def measure_in_turn() -> None:
         try:
             while True:
                 ahead = not ready.empty()
-                point = ready.get()
+                with _metrics.span("trial.wait_prepared", parent=above,
+                                   min_s=_WAITED_S, ahead=ahead):
+                    point = ready.get()
                 if point is None or gone.is_set():
                     return
                 behind["ahead"] += ahead
@@ -389,7 +404,9 @@ def _measured_behind(
         raise
     finally:
         ready.put(None)
-        measurer.join()
+        with _metrics.span("trial.wait_measured", parent=above,
+                           min_s=_WAITED_S):
+            measurer.join()
     if behind["raised"] is not None:
         raise behind["raised"]
     return points, behind["ahead"]
@@ -1364,7 +1381,8 @@ class SPMDTechnique(BaseTechnique):
         # retrieval on the measuring thread while this one holds the GIL
         # (1.4 s a point where the call is 0.2; read on the chip, PR 37).
         # ``jit`` keeps what ``lower().compile()`` made; nothing is allocated.
-        bundle.init.lower().compile()
+        with _metrics.span("trial.compile", program="init"):
+            bundle.init.lower().compile()
         return _Prepared(bundle, program, k)
 
     def _measure(self, task: Any, prepared: _Prepared) -> Tuple[float, float]:
@@ -1451,7 +1469,8 @@ class SPMDTechnique(BaseTechnique):
             stats = aot_cache.stats()
             return stats["hits"] + stats["warm_hits"]
 
-        with _metrics.span(name, parent=parent, k=int(k)) as sp:
+        with _metrics.span(name, parent=parent, k=int(k),
+                           program="window" if k > 1 else "step") as sp:
             if _metrics.enabled():
                 sp.set(was_warm=bool(bundle.has_fused(k) if k > 1
                                      else bundle._compiled is not None))

@@ -277,7 +277,12 @@ def _search_inner(
         task_sig = None
         if cache is not None:
             try:
-                task_sig = pcache.task_signature(task)
+                # the one stretch of the search's own seconds that is over
+                # 0.3 s in a cell of the benchmark, so the one with a name
+                # (PR 39): the cache pass, the fill and the fusion proposal
+                # are under 0.1 s together there and hold no span
+                with metrics.span("search.fingerprint", task=task.name):
+                    task_sig = pcache.task_signature(task)
             except Exception:
                 logger.info("task %s not fingerprintable — caching off for it",
                             task.name, exc_info=True)

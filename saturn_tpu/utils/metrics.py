@@ -17,10 +17,12 @@ span, emitted at its end, carrying its start); ``docs/architecture.md``
 from __future__ import annotations
 
 import contextlib
+import gc
 import itertools
 import json
 import logging
 import os
+import sys
 import threading
 import time
 from typing import Optional
@@ -169,8 +171,17 @@ _SPAN_IDS = itertools.count(1)  # next() on it is atomic: ids are process-unique
 _OPEN = threading.local()       # .stack: the spans open on this thread
 _ANNOTATION = None              # the profiler's annotation class, on first use
 _COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
-_CACHE_HIT_EVENT = "/jax/compilation_cache/cache_hits"
+#: the host's seconds by kind: the ``jax.monitoring`` duration -> the field of
+#: the span it was spent under (the four the benchmark's own clock totals)
+_HOST_SECONDS = {
+    "/jax/core/compile/jaxpr_trace_duration": "trace_s",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower_s",
+    _COMPILE_EVENT: "compile_s",
+    "/jax/compilation_cache/cache_retrieval_time_sec": "cache_read_s",
+}
 _LISTENING = False
+_ROOTS: list = []       # the root spans open in the process (``with`` blocks)
+_gc_t0: Optional[float] = None   # the collector's pass under way, if stamped
 
 
 def _stack() -> list:
@@ -195,7 +206,24 @@ class span:
     ``search`` or ``orchestrate``: what every span of one call shares) and
     ``thread``. An exception leaves the span emitted with
     ``error=<type name>`` and propagates untouched, ``BaseException``s
-    (``SimulatedKill``) included.
+    (``SimulatedKill``) included; a span that closes in a ``finally`` while
+    a kill (no ``Exception``) passes through its thread carries it too.
+
+    What the host did inside it, stamped by the two listeners (no event of
+    their own; each field left out where nothing was counted): ``trace_s`` /
+    ``lower_s`` / ``compile_s`` / ``cache_read_s`` are JAX's own durations
+    (jaxpr trace, jaxpr -> MLIR, backend compile, persistent-cache
+    retrieval) that ended on a thread while this span was the innermost open
+    there, nested ones counted once (a jit traced inside a trace is inside
+    the outer duration) and a compile that the cache answered under
+    ``cache_read_s`` alone; ``gc_s`` / ``gc_n`` / ``gc_full`` /
+    ``gc_full_max_s`` are the cyclic collector's passes that ran on that
+    thread (seconds, passes, generation-2 passes, the longest of those). A
+    root span (``search``, ``orchestrate``) carries the collector's passes
+    of the whole process over its extent instead.
+
+    ``min_s``: a span shorter than this and without ``error`` emits nothing
+    (a wait that did not wait).
 
     The block is also annotated for the profiler (``saturn.<name>``): under
     any profiler session the same stretch sits on the host plane of the
@@ -212,15 +240,20 @@ class span:
     """
 
     __slots__ = ("name", "fields", "id", "parent", "root", "ts_start",
-                 "_handed", "_t0", "_ann")
+                 "_handed", "_t0", "_ann", "_min_s", "_seconds", "_gc")
 
-    def __init__(self, name: str, parent: Optional["span"] = None, **fields):
+    def __init__(self, name: str, parent: Optional["span"] = None,
+                 min_s: float = 0.0, **fields):
         self.name = name
         self.fields = fields
         self.id: Optional[int] = None
         self.parent: Optional[int] = None
         self.root: Optional[int] = None
         self._handed = parent
+        self._min_s = min_s
+        # field -> [(end, seconds)] of the durations counted here, in order
+        self._seconds: Optional[dict] = None
+        self._gc: Optional[dict] = None  # the collector's passes, as emitted
 
     def open(self) -> "span":
         """Stamp the start and take an id WITHOUT entering the block: for an
@@ -259,11 +292,44 @@ class span:
         the caller's thread, measured on the search's measuring thread),
         which no ``with`` block can hold. No annotation for the profiler; a no-op without a
         sink."""
-        if self.id is not None:
-            event(self.name, ts_start=self.ts_start,
-                  dur_s=time.perf_counter() - self._t0,
-                  thread=threading.current_thread().name,
-                  **self.ids(), **self.fields)
+        if self.id is None:
+            return
+        dur_s = time.perf_counter() - self._t0
+        if dur_s < self._min_s and "error" not in self.fields:
+            return
+        event(self.name, ts_start=self.ts_start, dur_s=dur_s,
+              thread=threading.current_thread().name,
+              **self.ids(), **self._stamped(), **self.fields)
+
+    def _add_seconds(self, field: str, secs: float) -> None:
+        """One of JAX's durations that ended just now under this span. It
+        replaces those of its kind that it contains: they ended after it
+        began (one thread: what ended inside it began inside it)."""
+        if self._seconds is None:
+            self._seconds = {}
+        mine = self._seconds.setdefault(field, [])
+        end = time.time()
+        while mine and mine[-1][0] > end - secs:
+            mine.pop()
+        mine.append((end, secs))
+
+    def _add_gc(self, secs: float, full: bool) -> None:
+        if self._gc is None:
+            self._gc = {"gc_s": 0.0, "gc_n": 0, "gc_full": 0,
+                        "gc_full_max_s": 0.0}
+        self._gc["gc_s"] += secs
+        self._gc["gc_n"] += 1
+        if full:
+            self._gc["gc_full"] += 1
+            self._gc["gc_full_max_s"] = max(self._gc["gc_full_max_s"], secs)
+
+    def _stamped(self) -> dict:
+        out = {field: sum(secs for _, secs in mine)
+               for field, mine in (self._seconds or {}).items()}
+        out.update(self._gc or {})
+        if not out.get("gc_full", 1):
+            del out["gc_full_max_s"]  # no full pass: no longest one
+        return {k: round(v, 6) for k, v in out.items()}
 
     def __enter__(self) -> "span":
         global _ANNOTATION
@@ -275,12 +341,23 @@ class span:
         self._ann.__enter__()
         if self.open().id is not None:
             _stack().append(self)
+            if self.parent is None:
+                _ROOTS.append(self)
         return self
 
     def __exit__(self, exc_type, exc, tb) -> bool:
         try:
             if self.id is not None:
                 _stack().pop()  # blocks nest: the top of the stack is self
+                if self.parent is None:
+                    _ROOTS.remove(self)
+                if exc_type is None:
+                    # a ``finally`` on a kill's way out: the block itself
+                    # raised nothing, the thread is being unwound around it
+                    passing = sys.exc_info()[0]
+                    if passing is not None and not issubclass(passing,
+                                                              Exception):
+                        exc_type = passing
                 if exc_type is not None:
                     self.fields["error"] = exc_type.__name__
                 self.close()
@@ -314,33 +391,61 @@ def under(parent: Optional[span]):
         stack.pop()
 
 
-def _on_cache_hit(name: str, **_) -> None:
-    if name == _CACHE_HIT_EVENT:
-        _OPEN.cache_hit = True  # read by the duration event that follows
-
-
 def _on_duration(name: str, secs: float, fun_name: Optional[str] = None,
                  **_) -> None:
-    if name != _COMPILE_EVENT:
+    """JAX's four compile-path durations, each on the thread it was spent
+    on: added to the innermost span open there (no event of its own), and
+    one ``compile`` event per backend compile."""
+    field = _HOST_SECONDS.get(name)
+    if field is None:
         return
     # JAX clocks the persistent compilation cache's retrievals under the
-    # same event; the hit it reported on this thread just before says so.
-    cached = bool(_OPEN.__dict__.pop("cache_hit", False))
+    # backend compile's event too; the retrieval it reported on this thread
+    # just before says so.
+    cached = field == "compile_s" and bool(
+        _OPEN.__dict__.pop("cache_read", False))
     if not enabled():
         return
-    sp = current_span()  # the listener runs on the compiling thread
-    event("compile", seconds=secs, program=fun_name, cached=cached,
-          in_span=None if sp is None else {"name": sp.name, "id": sp.id},
-          thread=threading.current_thread().name)
+    if field == "cache_read_s":
+        _OPEN.cache_read = True  # read by the compile's duration that follows
+    sp = current_span()  # the listener runs on the thread that did the work
+    if sp is not None and not cached:
+        sp._add_seconds(field, secs)
+    if field == "compile_s":
+        event("compile", seconds=secs, program=fun_name, cached=cached,
+              in_span=None if sp is None else {"name": sp.name, "id": sp.id},
+              thread=threading.current_thread().name)
+
+
+def _on_gc(phase: str, info: dict) -> None:
+    """``gc.callbacks``: each pass of the cyclic collector, on the thread it
+    ran on, counted into the innermost span open there and into every open
+    root span. Passes do not overlap (the collector runs one at a time), so
+    one stamp holds the pass under way."""
+    global _gc_t0
+    if phase == "start":
+        _gc_t0 = time.perf_counter() if enabled() else None
+        return
+    if _gc_t0 is None:
+        return
+    secs, _gc_t0 = time.perf_counter() - _gc_t0, None
+    full = info.get("generation") == 2
+    roots = tuple(_ROOTS)
+    here = current_span()
+    if here is not None and here not in roots:
+        here._add_gc(secs, full)
+    for root in roots:
+        root._add_gc(secs, full)
 
 
 def _listen_for_compiles() -> None:
-    """One ``compile`` event per backend (XLA) compile while a sink is
-    configured: the jitted function's name (``program``), its ``seconds``,
+    """While a sink is configured: one ``compile`` event per backend (XLA)
+    compile -- the jitted function's name (``program``), its ``seconds``,
     whether the persistent compilation cache gave it (``cached``) and the
     span open on the compiling thread (``in_span``: what places a compile
-    inside an interval). Registered once per process, at the first sink; a
-    no-op without one."""
+    inside an interval) -- and the host's seconds by kind and the
+    collector's passes on the spans they fall under (``span``). Registered
+    once per process, at the first sink; no-ops without one."""
     global _LISTENING
     with _CONF_LOCK:
         if _LISTENING:
@@ -348,8 +453,8 @@ def _listen_for_compiles() -> None:
         _LISTENING = True
     import jax.monitoring
 
-    jax.monitoring.register_event_listener(_on_cache_hit)
     jax.monitoring.register_event_duration_secs_listener(_on_duration)
+    gc.callbacks.append(_on_gc)
 
 
 def read_events(path: str, kind: Optional[str] = None) -> list:

@@ -176,6 +176,23 @@ class TestPersistentCache:
         misses = [e for e in read_events(mpath, "profile_cache") if not e.get("hit")]
         assert len(misses) == 8  # first run consulted and missed every point
 
+    @pytest.mark.parametrize("cached", [True, False])
+    def test_a_jobs_fingerprint_is_a_span_of_the_search(self, tmp_path, cached):
+        """The one named stretch of the search's own seconds (PR 39): one
+        ``search.fingerprint`` a job, a child of ``search``, before any
+        trial; none where the sweep runs without a profile cache."""
+        mpath = str(tmp_path / "m.jsonl")
+        run_search([FakeTask("a"), FakeTask("b")], ["counting"],
+                   str(tmp_path / "cache") if cached else None,
+                   metrics_path=mpath)
+        (search,) = read_events(mpath, "search")
+        found = read_events(mpath, "search.fingerprint")
+        assert [e["task"] for e in found] == (["a", "b"] if cached else [])
+        first_trial = min(e["ts_start"] for e in read_events(mpath, "trial"))
+        for e in found:
+            assert e["parent"] == search["id"] == e["root"]
+            assert e["ts"] <= first_trial + 0.005 and e["dur_s"] >= 0
+
     def test_model_change_misses(self, tmp_path):
         cache_dir = str(tmp_path / "cache")
         run_search([FakeTask("a", model_cfg="cfg-v1")], ["counting"], cache_dir)

@@ -19,6 +19,7 @@ import jax
 import numpy as np
 import pytest
 
+from perf.lib import spans as interval_math
 from saturn_tpu.core.technique import InfeasibleConfig
 from saturn_tpu.parallel import spmd_base
 from saturn_tpu.parallel.spmd_base import SPMDTechnique
@@ -30,7 +31,7 @@ HBM = 1 << 30           # what the memory rule runs against (the CPU reports non
 MEAS = "meas-"          # the measuring thread's name starts with this
 
 
-def within_limit(fn):
+def within_limit(fn, limit_s=LIMIT_S):
     """``fn()`` on a thread of its own, joined with a time limit; its result,
     or its exception raised here."""
     box = {}
@@ -43,8 +44,8 @@ def within_limit(fn):
 
     t = threading.Thread(target=run, name="case", daemon=True)
     t.start()
-    t.join(LIMIT_S)
-    assert not t.is_alive(), f"search hung for {LIMIT_S:.0f} s"
+    t.join(limit_s)
+    assert not t.is_alive(), f"search hung for {limit_s:.0f} s"
     if "exc" in box:
         raise box["exc"]
     return box["out"]
@@ -492,6 +493,158 @@ def test_searches_side_by_side_on_one_technique_keep_their_points_apart(
     assert len(spans_) == 12 * 6
     assert Counter(e["outcome"] for e in spans_) == {"timed": 60, "refused": 12}
     assert no_measuring_thread_left()
+
+
+# (vi) ------------------------------------- who waited for whom (PR 39)
+HANDOFF_LIMIT_S = 10.0   # PR 38's wait never ended: these cases' own limit
+HOST = ("trial.build", "trial.compile", "trial.memory_check", "trial.memlens")
+CHIP = ("trial.init", "trial.stage", "trial.timing")
+WAITS = ("trial.wait_prepared", "trial.wait_measured")
+# a timing is three calls of the program
+BOUND = {
+    "host": [{"id": f"h{i}", "remat": True, "build_s": 0.2, "step_s": 0.02 / 3}
+             for i in range(4)],
+    "chip": [{"id": f"c{i}", "remat": True, "build_s": 0.02, "step_s": 0.2 / 3}
+             for i in range(4)],
+}
+
+
+def spanned_search(tmp_path, monkeypatch, points, name="piped"):
+    """The evaluator's shape: ``search`` > ``trial`` open on the thread that
+    calls the technique's ``search``. (technique, what it raised or None,
+    events); never longer than ``HANDOFF_LIMIT_S``."""
+    monkeypatch.setenv("SATURN_TPU_HBM_BYTES", str(HBM))
+    tech = Stubbed(points)
+    path = str(tmp_path / f"{name}.jsonl")
+
+    def go():
+        with metrics.span("search"), metrics.span("trial", task=name):
+            return tech.search(Task(name), jax.devices()[:1], 0)
+
+    raised = None
+    with metrics.scoped(path):
+        try:
+            within_limit(go, HANDOFF_LIMIT_S)
+        except SimulatedKill as e:
+            raised = e
+    return tech, raised, metrics.read_events(path)
+
+
+def seconds(intervals):
+    return interval_math.length(intervals)
+
+
+def overlap(a, b):
+    """Seconds in which a stretch of ``a`` and a stretch of ``b`` are open."""
+    return seconds(a) + seconds(b) - seconds(list(a) + list(b))
+
+
+@pytest.mark.parametrize("bound", sorted(BOUND))
+def test_the_wait_spans_say_who_waited_for_whom(tmp_path, monkeypatch, bound):
+    tech, raised, events = spanned_search(tmp_path, monkeypatch, BOUND[bound])
+    assert raised is None and tech.search_report("piped", 1)["configs"] == 4
+    (search,), (trial,) = of_kind(events, "search"), of_kind(events, "trial")
+    wall = search["dur_s"]
+    host = [interval(e) for e in events if e["kind"] in HOST]
+    chip = [interval(e) for e in events if e["kind"] in CHIP]
+    waits = {k: of_kind(events, k) for k in WAITS}
+    # each wait is a child of ``trial`` on the thread that waited
+    for e in waits["trial.wait_prepared"]:
+        assert e["parent"] == trial["id"] and e["root"] == search["id"]
+        assert e["thread"] == "meas-case" and e["dur_s"] >= 1e-3
+        assert isinstance(e["ahead"], bool) and "error" not in e
+    for e in waits["trial.wait_measured"]:
+        assert e["parent"] == trial["id"] and e["thread"] == "case"
+        assert e["dur_s"] >= 1e-3 and "error" not in e
+    # the four classes, each by its own set arithmetic, add up to the wall
+    both = overlap(host, chip)
+    wait_for_host, wait_for_chip = seconds(host) - both, seconds(chip) - both
+    own = wall - seconds(host + chip)
+    assert wait_for_host + both + wait_for_chip + own == pytest.approx(
+        wall, rel=0.02)
+    assert 0 <= own <= 0.15 * wall, (own, wall)   # stubs keep no books
+    # the cross-check: the measuring thread waits where no chip-side span is
+    # open, the caller where no host-work span is
+    prepared = [interval(e) for e in waits["trial.wait_prepared"]]
+    measured = [interval(e) for e in waits["trial.wait_measured"]]
+    assert overlap(prepared, chip) <= 0.005
+    assert overlap(measured, host) <= 0.005
+    # the measuring thread's life is its chip-side spans and its waits
+    first = min(lo for lo, _ in prepared)
+    idle = (max(hi for _, hi in chip) - first) - seconds(chip)
+    if bound == "host":
+        # it waited for every point (none was ahead), about a build each
+        assert len(prepared) == 4
+        assert not any(e["ahead"] for e in waits["trial.wait_prepared"])
+        assert seconds(prepared) == pytest.approx(idle, abs=0.05)
+        assert seconds(prepared) >= 0.6 and wait_for_host >= 0.6
+        assert wait_for_chip <= 0.1
+        # the caller found the last point measured within a timing
+        assert seconds(measured) <= 0.1
+    else:
+        # only the first point was waited for; the rest were there
+        assert len(prepared) == 1 and seconds(prepared) <= 0.1
+        # the caller's idle time: from its last preparation to the end
+        (joined,) = waits["trial.wait_measured"]
+        last_prepared = max(hi for _, hi in host)
+        assert joined["ts_start"] == pytest.approx(last_prepared, abs=0.05)
+        assert joined["dur_s"] == pytest.approx(
+            trial["ts_start"] + trial["dur_s"] - last_prepared, abs=0.05)
+        assert joined["dur_s"] >= 0.5 and wait_for_chip >= 0.5
+        assert wait_for_host <= 0.15
+    assert no_measuring_thread_left()
+
+
+def test_a_grid_of_one_waits_for_nobody(tmp_path, monkeypatch):
+    _, raised, events = spanned_search(tmp_path, monkeypatch, BOUND["host"][:1])
+    assert raised is None and len(of_kind(events, "trial.config")) == 1
+    assert not [e for e in events if e["kind"] in WAITS]
+
+
+KILLED = {
+    # the caller is killed while the measuring thread waits for its point:
+    # the join runs on the kill's way out and says so
+    "preparation": [{"id": "a", "remat": True, "step_s": 0.05},
+                    {"id": "b", "remat": True, "build_s": 0.3, "build": "kill"},
+                    {"id": "c", "remat": False}],
+    # the measuring thread is killed while the caller prepares: the caller
+    # ends its point, starts no other, and finds the thread gone
+    "measurement": [{"id": "a", "remat": True, "run": "kill"},
+                    {"id": "b", "remat": True, "build_s": 0.3},
+                    {"id": "c", "remat": False}],
+}
+
+
+@pytest.mark.parametrize("half", sorted(KILLED))
+def test_a_kill_in_either_half_closes_the_wait_spans(tmp_path, monkeypatch,
+                                                     half):
+    tech, raised, events = spanned_search(tmp_path, monkeypatch, KILLED[half])
+    assert isinstance(raised, SimulatedKill)
+    assert not [t.name for t in threading.enumerate()
+                if t.name.startswith(MEAS)]        # joined, not left behind
+    assert tech.book.order("build") == ["a", "b"]  # and no further point
+    (trial,) = of_kind(events, "trial")
+    assert trial["error"] == "SimulatedKill"
+    waits = [e for e in events if e["kind"] in WAITS]
+    # every wait that began has ended inside ``trial``, as its child
+    for e in waits:
+        assert e["parent"] == trial["id"]
+        assert e["ts_start"] + e["dur_s"] <= trial["ts_start"] + trial["dur_s"] + 0.005
+    by = {e["config"]["id"]: e for e in of_kind(events, "trial.config")}
+    if half == "preparation":
+        assert by["b"]["error"] == "SimulatedKill"
+        # the measuring thread sat in the hand-off while "b" was built
+        (waited,) = [e for e in of_kind(events, "trial.wait_prepared")
+                     if e["dur_s"] >= 0.1]
+        assert waited["thread"] == "meas-case" and waited["ahead"] is False
+        # the join on the kill's way out carries it, however short
+        (joined,) = of_kind(events, "trial.wait_measured")
+        assert joined["error"] == "SimulatedKill" and joined["thread"] == "case"
+    else:
+        assert by["a"]["error"] == "SimulatedKill"
+        assert "b" not in by   # prepared, never measured: it ended nowhere
+        # no wait was under way when the kill came; none is left open
+        assert all("error" not in e for e in waits)
 
 
 # ------------------------------------- the clock, while another thread traces

@@ -15,6 +15,7 @@ from concurrent.futures import ThreadPoolExecutor
 import jax
 import pytest
 
+from perf.lib import spans as interval_math  # union / clip / subtract / length
 from saturn_tpu.resilience.crash import SimulatedKill
 from saturn_tpu.utils import metrics
 
@@ -139,12 +140,23 @@ def test_no_sink_no_event_no_field(tmp_path, monkeypatch):
     stamped = []
     monkeypatch.setattr(metrics.time, "perf_counter",
                         lambda: stamped.append(1) or 0.0)
+    monkeypatch.setattr(metrics.time, "time",
+                        lambda: stamped.append(1) or 0.0)
     with metrics.span("unit.off", task="t") as sp:
         assert metrics.current_span() is None
         with metrics.under(sp):
             pass
+        # JAX's four durations and a pass of the collector, as the listeners
+        # get them (they stay registered once a sink has been configured)
+        for name in metrics._HOST_SECONDS:
+            metrics._on_duration(name, 0.25, fun_name="unit")
+        metrics._on_gc("start", {"generation": 2})
+        metrics._on_gc("stop", {"generation": 2})
     monkeypatch.undo()
     assert sp.id is None and sp.ids() == {} and not stamped
+    assert sp._seconds is None and sp._gc is None and sp._stamped() == {}
+    assert metrics._gc_t0 is None and not metrics._ROOTS
+    assert not hasattr(metrics._OPEN, "cache_read")
     held = metrics.span("unit.off").open()
     assert held.id is None and held.ids() == {}
     # and nothing reaches a sink configured afterwards
@@ -203,6 +215,217 @@ def test_compile_event_names_program_and_span(sink):
     assert e["in_span"] == {"name": "unit.compiling", "id": sp.id}
     assert e["seconds"] > 0 and e["cached"] is False
     assert e["thread"] == threading.current_thread().name
+
+
+# ---------------------------------- the host's seconds, on the span they fell in
+TRACE, LOWER, COMPILE, CACHE_READ = metrics._HOST_SECONDS
+
+
+def test_a_jit_traced_inside_a_trace_is_counted_once(sink):
+    """``trace_s`` is JAX's own clock, nested once: tracing ``outer`` traces
+    ``inner`` twice (two shapes), each trace of ``inner`` sleeps 50 ms, and
+    JAX reports the three durations, the outer one holding the other two."""
+    import jax.monitoring
+    import jax.numpy as jnp
+
+    reported = []
+
+    def listen(name, secs, **kw):
+        if name == TRACE and "saturn_unit" in kw.get("fun_name", ""):
+            reported.append((kw["fun_name"], secs))
+
+    @jax.jit
+    def saturn_unit_inner(x):
+        time.sleep(0.05)
+        return jnp.tanh(x)
+
+    def saturn_unit_outer(x):
+        return saturn_unit_inner(x).sum() + saturn_unit_inner(x[:2]).sum()
+
+    x = jnp.ones((4, 3))
+    jax.monitoring.register_event_duration_secs_listener(listen)
+    try:
+        with metrics.span("unit.tracing"):
+            jax.jit(saturn_unit_outer).lower(x)
+    finally:
+        jax.monitoring.unregister_event_duration_listener(listen)
+    names = [n for n, _ in reported]
+    assert names == ["saturn_unit_inner"] * 2 + ["saturn_unit_outer"], names
+    outer, naive = reported[-1][1], sum(secs for _, secs in reported)
+    assert 0.1 <= outer and naive >= outer + 0.1
+    (e,) = sink("unit.tracing")
+    # the outer duration, once
+    assert outer - 1e-5 <= e["trace_s"] < outer + 0.05 < naive
+    assert e["trace_s"] <= e["dur_s"] and e["lower_s"] > 0
+    assert "compile_s" not in e and "cache_read_s" not in e
+
+
+def test_durations_one_after_another_add_up(sink):
+    with metrics.span("unit.adds") as sp:
+        for secs in (0.002, 0.003):
+            metrics._on_duration(LOWER, secs, fun_name="unit")
+            time.sleep(0.005)
+    (e,) = sink("unit.adds")
+    assert e["lower_s"] == pytest.approx(0.005) and "trace_s" not in e
+    assert set(sp._seconds) == {"lower_s"}
+
+
+def test_a_cache_read_is_no_compile(sink):
+    """JAX clocks a persistent-cache retrieval under the backend compile's
+    event too: the span counts it under ``cache_read_s`` alone, and the
+    ``compile`` event says ``cached``."""
+    with metrics.span("unit.reads"):
+        metrics._on_duration(CACHE_READ, 0.2)
+        metrics._on_duration(COMPILE, 0.21, fun_name="jit(unit_cached)")
+        time.sleep(0.002)
+        metrics._on_duration(COMPILE, 0.4, fun_name="jit(unit_fresh)")
+    (e,) = sink("unit.reads")
+    assert e["cache_read_s"] == pytest.approx(0.2)
+    assert e["compile_s"] == pytest.approx(0.4)
+    by = {c["program"]: c for c in sink("compile") if "unit_" in c["program"]}
+    assert by["jit(unit_cached)"]["cached"] is True
+    assert by["jit(unit_fresh)"]["cached"] is False
+    assert by["jit(unit_fresh)"]["in_span"]["name"] == "unit.reads"
+
+
+def test_a_duration_with_no_open_span_adds_nowhere(sink):
+    out = {}
+
+    def bare():
+        try:
+            for name in (TRACE, LOWER, CACHE_READ, COMPILE):  # JAX's order
+                metrics._on_duration(name, 0.5, fun_name="jit(unit_bare)")
+            out["span"] = metrics.current_span()
+        except BaseException as e:  # the assertion below names it
+            out["raised"] = e
+
+    with metrics.span("unit.elsewhere"):
+        t = threading.Thread(target=bare, name="bare-thread")
+        t.start()
+        t.join()
+    assert out == {"span": None}
+    (e,) = sink("unit.elsewhere")
+    assert not set(e) & {"trace_s", "lower_s", "compile_s", "cache_read_s"}
+    # the backend compile is still an event of its own, in no span
+    (c,) = [c for c in sink("compile") if c["program"] == "jit(unit_bare)"]
+    assert c["in_span"] is None and c["thread"] == "bare-thread"
+    assert c["cached"] is True  # the retrieval just before it, on that thread
+
+
+class _Cycle:
+    def __init__(self):
+        self.me = self
+
+
+@pytest.mark.parametrize("where", ["collect", "undisturbed_clock"])
+def test_the_collectors_passes_are_counted_on_the_span(sink, where):
+    """A full pass made inside a span is the span's ``gc_full`` (and the
+    root's: the process's passes over its extent); inside
+    ``undisturbed_clock`` no full pass runs, however much is allocated."""
+    import gc
+
+    from saturn_tpu.utils import timing
+
+    with metrics.span("unit.root"):
+        with metrics.span("unit.other"):
+            pass
+        if where == "collect":
+            with metrics.span("unit.gc"):
+                gc.collect()
+        else:
+            with timing.undisturbed_clock(), metrics.span("unit.gc"):
+                kept = [[_Cycle() for _ in range(100)] for _ in range(300)]
+                del kept
+    by = {e["kind"]: e for e in sink()}
+    e, root = by["unit.gc"], by["unit.root"]
+    if where == "collect":
+        assert e["gc_full"] == 1 and e["gc_n"] >= 1
+        assert 0 < e["gc_full_max_s"] <= e["gc_s"] <= e["dur_s"]
+        assert root["gc_full"] >= 1 and root["gc_s"] >= e["gc_s"]
+    else:
+        assert e["gc_n"] >= 3 and e["gc_full"] == 0 and e["gc_s"] > 0
+        assert "gc_full_max_s" not in e
+        assert root["gc_n"] >= e["gc_n"]  # and whatever ran beside it
+    assert not set(by["unit.other"]) & {"gc_s", "gc_n", "gc_full"}
+
+
+def test_passes_on_many_threads_all_reach_the_root(sink):
+    """More allocating threads than cores under one root span, each inside a
+    span of its own, a short switch interval: the root's count is every pass
+    of the process (an independent callback counts them), the threads' spans
+    share them out, and none is lost to a racing update."""
+    import gc
+    import sys
+
+    seen = []
+
+    def count(phase, info):
+        if phase == "stop":
+            seen.append(threading.current_thread().name)
+
+    def churn(above):
+        with metrics.under(above), metrics.span("unit.churn"):
+            for _ in range(40):
+                kept = [_Cycle() for _ in range(2000)]
+                del kept
+
+    interval = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    try:
+        with metrics.span("unit.root") as root:
+            gc.callbacks.append(count)
+            threads = [threading.Thread(target=churn, args=(root,),
+                                        name=f"churn-{i}")
+                       for i in range(2 * (os.cpu_count() or 4))]
+            for t in threads:
+                t.start()
+            for t in threads:
+                t.join(60)
+            gc.callbacks.remove(count)
+            assert not any(t.is_alive() for t in threads)
+    finally:
+        sys.setswitchinterval(interval)
+        if count in gc.callbacks:
+            gc.callbacks.remove(count)
+    (e,) = sink("unit.root")
+    assert e["gc_n"] == len(seen) >= len(threads)
+    on_threads = sum(c.get("gc_n", 0) for c in sink("unit.churn"))
+    assert on_threads == sum(1 for name in seen if name.startswith("churn-"))
+
+
+@pytest.mark.parametrize("ended", ["short", "long", "short_error"])
+def test_a_span_under_its_min_s_emits_nothing(sink, ended):
+    try:
+        with metrics.span("unit.wait", min_s=0.05, ahead=True):
+            if ended == "long":
+                time.sleep(0.06)
+            if ended == "short_error":
+                raise RuntimeError("stop")
+    except RuntimeError:
+        pass
+    found = sink("unit.wait")
+    assert len(found) == (0 if ended == "short" else 1)
+    if ended == "short_error":
+        assert found[0]["error"] == "RuntimeError"
+
+
+@pytest.mark.parametrize("exc, marked", [(SimulatedKill, True),
+                                         (KeyboardInterrupt, True),
+                                         (RuntimeError, False)],
+                         ids=lambda v: getattr(v, "__name__", str(v)))
+def test_a_span_closed_on_a_kills_way_out_carries_it(sink, exc, marked):
+    """A ``finally`` that waits (``_measured_behind``'s join) while a kill
+    unwinds its thread: the span around the wait raised nothing itself and
+    still says what ended it. An ``Exception`` in flight is ordinary
+    business (a handler that does spanned work) and marks nothing."""
+    with pytest.raises(exc):
+        try:
+            raise exc("stop")
+        finally:
+            with metrics.span("unit.join"):
+                pass
+    (e,) = sink("unit.join")
+    assert e.get("error") == (exc.__name__ if marked else None)
 
 
 def test_public_calls_span_their_lazy_import(sink):
@@ -305,6 +528,8 @@ PATH_SPANS = [
     ("search", "trial.memory_check"), ("search", "trial.memlens"),
     ("search", "trial.init"), ("search", "trial.stage"),
     ("search", "trial.timing"), ("search", "prior.memlens"),
+    # the measuring thread waits at least for its first point (PR 39)
+    ("search", "trial.wait_prepared"),
     ("window", "orchestrate"), ("window", "solver.resolve"),
     ("window", "forecast"), ("window", "interval"),
     ("window", "launch.build"), ("window", "launch.init"),
@@ -349,34 +574,58 @@ def test_tree_is_sound(tiny_run, phase):
             assert e["ts"] <= roots[0]["ts"] + 0.005  # joined inside the root
 
 
-def _union(intervals):
-    total, end = 0.0, None
-    for s, e in sorted(intervals):
-        if end is None or s > end:
-            total += e - s
-            end = e
-        elif e > end:
-            total += e - end
-            end = e
-    return total
+def _inside(stretches, lo, hi):
+    return interval_math.length(interval_math.clip(stretches, lo, hi))
 
 
 def test_leaves_cover_the_orchestrate_wall(tiny_run):
-    """The CPU twin of the benchmark's ``idle_unattributed``: what no span
-    below ``orchestrate`` / ``interval`` accounts for is under a tenth of
-    the call. A gang's dispatch loop holds no span (the cost rule), so its
-    steps are ``task_interval``'s own [ts_start, ts]."""
+    """The CPU twin of the benchmark's ``idle_unattributed``, held by
+    structure and not as a share of a wall clock that five other workers
+    load: what no leaf span below ``orchestrate`` accounts for lies in
+    stretches that have a name, each bounded by the spans before and after
+    it: before the first solve, between the solve and the interval (the
+    forecast lies in it), after the interval (the journal's commit and the
+    checkpoint flush lie in it). Those are the call's fixed costs (5-30 ms
+    each alone: bounded in seconds, a share of a 0.5 s window says nothing);
+    inside the interval, where the steps are, under a tenth is unaccounted
+    (under a hundredth alone). A gang's dispatch loop holds no span (the
+    cost rule), so its steps are ``task_interval``'s own [ts_start, ts]."""
     events = tiny_run["window"]
     (root,) = [e for e in events if e["kind"] == "orchestrate"]
+    (interval,) = [e for e in events if e["kind"] == "interval"]
+    solve = min((e for e in events if e["kind"] == "solver.resolve"),
+                key=lambda e: e["ts_start"])
     parents = {e["parent"] for e in events if "id" in e}
     leaves = [(e["ts_start"], e["ts"]) for e in spans_of(events)
               if e["id"] not in parents]
     leaves += [(e["ts_start"], e["ts"]) for e in events
                if e["kind"] == "task_interval"]
-    covered = _union((max(s, root["ts_start"]), min(e, root["ts"]))
-                     for s, e in leaves)
-    assert covered >= 0.9 * (root["ts"] - root["ts_start"]), (
-        covered, root["dur_s"])
+    lo, hi = root["ts_start"], root["ts"]
+    gaps = interval_math.subtract([(lo, hi)], leaves)
+    named = {
+        "before the first solve": (lo, solve["ts_start"]),
+        "between the solve and the interval": (solve["ts"],
+                                               interval["ts_start"]),
+        "after the interval": (interval["ts"], hi),
+    }
+    seconds = {}
+    for name, (s, e) in named.items():
+        assert lo - 0.005 <= s <= e + 0.005 <= hi + 0.01, (name, s - lo, e - lo)
+        seconds[name] = _inside(gaps, s, e)
+        assert seconds[name] <= 1.0, (name, seconds)
+    steps = _inside(gaps, interval["ts_start"], interval["ts"])
+    assert steps <= 0.1 * interval["dur_s"], (steps, interval["dur_s"])
+    # and that is all of it: no unaccounted stretch outside the four
+    total = sum(e - s for s, e in gaps)
+    assert total == pytest.approx(steps + sum(seconds.values()), abs=0.01)
+    # the named spans that lie in the fixed stretches are where they belong
+    by = {k: [e for e in events if e["kind"] == k]
+          for k in ("forecast", "journal.commit", "ckpt.flush")}
+    assert all(solve["ts"] - 0.005 <= e["ts_start"]
+               and e["ts"] <= interval["ts_start"] + 0.005
+               for e in by["forecast"]) and by["forecast"]
+    assert all(e["ts_start"] >= interval["ts"] - 0.005
+               for e in by["journal.commit"] + by["ckpt.flush"])
 
 
 HANDOFFS = {
