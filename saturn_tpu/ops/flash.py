@@ -6,16 +6,49 @@ two matmuls; this kernel keeps score blocks in VMEM with the online-softmax
 recurrence (Flash-Attention-2 style), so HBM traffic drops from O(T²) to
 O(T·D) and both matmuls feed the MXU back-to-back.
 
-VMEM footprint is O(block · D) per program, independent of T: the key/value
-walk is a **grid dimension** (innermost, sequential on TPU), with k/v tiles
-pipelined HBM→VMEM by Pallas block specs and the softmax state (m, l, acc)
-carried in VMEM scratch across the kv steps — so long-context sequences
-never stage a full (T, D) operand on chip.
+Shapes: (B, H, T, D) with T % block == 0. Three ``pallas_call``s an
+attention call: the forward, and the standard two-kernel backward — a dQ
+kernel gridded over query blocks and a dK/dV kernel gridded over key blocks —
+recomputing P = exp(S - lse) from the forward's saved logsumexp. Their names
+are ``saturn_flash_fwd`` / ``_dq`` / ``_dkv`` (``saturn_swa_*`` under a
+window): the benchmark's roofline reader credits every call of the first
+three names with a whole causal attention.
 
-Shapes: (B, H, T, D) with T % block == 0. The backward pass is the standard
-two-kernel split — a dQ kernel gridded over (query block × kv step) and a
-dK/dV kernel gridded over (kv block × query step) — recomputing
-P = exp(S - lse) from the forward's saved logsumexp.
+What a kernel does follows from what the call can see: T, the head dim, the
+window (PR 41; ``flash_plan`` has the blocks and why, PERF.md section 6 the
+chip's readings):
+
+- **The walk over the other sequence is a loop in the kernel.** A grid step
+  holds one block of the side that stays (queries for fwd and dq, keys for
+  dkv) and a *chunk* of the walked side (``_chunk``: all of T at every
+  cell's shapes, a fraction of it in whole blocks beyond 8 MiB of VMEM),
+  fetched once a row block and not once a score block; one
+  ``lax.fori_loop`` walks the chunk's blocks (``_for_blocks``). The state
+  (m, l, acc; dq; dk, dv) lives in VMEM scratch across the loop and the
+  chunks. A window's kernels keep a chunk of one block: what a step fetches
+  is what the window reaches.
+- **Which blocks** (``_reach``): a block wholly above the diagonal (or
+  outside the window) is in no loop; every other runs the one loop body,
+  mask and all. The mask is one iota pair that does not depend on the block
+  and one scalar that does (``_masked``); a second body without it for the
+  blocks wholly under the diagonal read no faster on the chip and cost the
+  host its trace and lowering (PERF.md section 6, PR 41).
+- **The row statistics are vectors along lanes.** m and l (and lse, delta in
+  the dq kernel) live replicated over a 128-lane row, so updating and
+  applying them are plain vector operations; lse and delta cross HBM as
+  lane-dense (BH, 1, T) rows. The dkv kernel computes its scores transposed
+  (keys down the rows): there those rows broadcast over sublanes, and
+  dV = P^T dO and dK = dS^T Q need no transposed operand. Under a head dim
+  of 128 the forward does the same (``_keys_down``): its statistics are
+  then a few vregs and its accumulator (D, block_q), where a (block_q, 64)
+  float32 tile half-fills every vreg it touches.
+- **The softmax scale** multiplies a (block, D) operand where that is exact
+  (a power of two: head dims 64 and 256) and the scores where it is not
+  (128).
+
+The launchers sit behind ``jit``'s tracing cache (``_traced_once``): the
+layer traced again under remat, or the next grid point of a search, binds
+the ``pallas_call`` traced the first time and traces no kernel body again.
 
 Used by the model zoo when ``GPT2Config.attention`` resolves to "flash" —
 which is the DEFAULT on TPU: on one v5e chip at GPT-J widths (head 256,
@@ -39,6 +72,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
+_LANES = 128
 
 
 def _use_interpret() -> bool:
@@ -46,99 +80,241 @@ def _use_interpret() -> bool:
     return jax.default_backend() != "tpu"
 
 
-def _block_mask(iq, jk, block_q, block_k):
-    """(BQ, BK) causal mask for query block iq vs key block jk."""
-    q_pos = iq * block_q + jax.lax.broadcasted_iota(
-        jnp.int32, (block_q, block_k), 0
-    )
-    k_pos = jk * block_k + jax.lax.broadcasted_iota(
-        jnp.int32, (block_q, block_k), 1
-    )
-    return q_pos >= k_pos
-
-
-def _window_mask(iq, jk, block_q, block_k, window):
-    """(BQ, BK) mask of a sliding layer: key j is read by the queries
-    j .. j + window - 1 (the window counts the token itself)."""
-    q_pos = iq * block_q + jax.lax.broadcasted_iota(
-        jnp.int32, (block_q, block_k), 0
-    )
-    k_pos = jk * block_k + jax.lax.broadcasted_iota(
-        jnp.int32, (block_q, block_k), 1
-    )
-    return jnp.logical_and(q_pos >= k_pos, q_pos - k_pos < window)
-
-
-def _window_blocks(window: int, block: int) -> int:
-    """Key blocks a query block reaches back over (itself included), and
-    query blocks a key block is read by, at equal blocks: the kv (q) axis of
-    a window kernel's grid. The other T / block - this many are never
-    visited: not fetched, not masked, not multiplied."""
-    return -(-(window - 1) // block) + 1
-
-
 def _dot(a, b, dims):
     return jax.lax.dot_general(a, b, (dims, ((), ())),
                                preferred_element_type=jnp.float32)
 
 
+def _lanes(x, n: int):
+    """A row statistic kept replicated over a 128-lane row, at ``n`` lanes
+    (a score block's or the accumulator's width): whole vregs side by side,
+    no broadcast out of lane 0."""
+    if n <= _LANES:
+        return x[:, :n]
+    if n % _LANES == 0:
+        return jnp.tile(x, (1, n // _LANES))
+    return jnp.broadcast_to(x[:, :1], (x.shape[0], n))
+
+
+def _column(row):
+    """A (1, n) row of per-query numbers as a lane-replicated (n, 128)
+    column: one transpose a query block, not a relayout a step."""
+    return jnp.broadcast_to(row, (_LANES, row.shape[1])).T
+
+
+def _folds(scale: float) -> bool:
+    """A power of two multiplies a bf16 operand exactly (1/sqrt(64),
+    1/sqrt(256)); any other scale (1/sqrt(128)) stays on the f32 scores."""
+    return math.frexp(scale)[0] == 0.5
+
+
+def _scores(a, b, scale):
+    """``a . b^T * scale`` in float32: the scale on ``a`` (block x D) where
+    that is exact, on the (block x block) product where it is not."""
+    if _folds(scale):
+        return _dot(a * scale, b, ((1,), (1,)))
+    return _dot(a, b, ((1,), (1,))) * scale
+
+
+def _masked(s, q_lo, k_lo, q_axis: int, window):
+    """``s`` with the causal (and the window's) mask of the block whose
+    first query is ``q_lo`` and first key ``k_lo``; queries lie along
+    ``q_axis`` of ``s``."""
+    # q_pos >= k_pos  <=>  (row - column) >= k_lo - q_lo: one iota pair
+    # that does not depend on the block, one scalar that does
+    ahead = (jax.lax.broadcasted_iota(jnp.int32, s.shape, q_axis)
+             - jax.lax.broadcasted_iota(jnp.int32, s.shape, 1 - q_axis))
+    keep = ahead >= k_lo - q_lo
+    if window is not None:
+        keep = jnp.logical_and(keep, ahead < window + k_lo - q_lo)
+    return jnp.where(keep, s, NEG_INF)
+
+
+def _div(a, b: int):
+    """``a // b`` of a count that is not negative: one equation in a kernel
+    (``//`` on a traced integer is floor division's ten)."""
+    return a // b if isinstance(a, int) else jax.lax.div(a, jnp.int32(b))
+
+
+def _most(a, b):
+    return max(a, b) if isinstance(a, int) and isinstance(b, int) \
+        else jnp.maximum(a, b)
+
+
+def _least(a, b):
+    return min(a, b) if isinstance(a, int) and isinstance(b, int) \
+        else jnp.minimum(a, b)
+
+
+def _reach(r, b_row, b_col, n_col, causal, window, rows_are_queries):
+    """``(n_lo, n_hi)``: of the ``n_col`` column blocks beside row block ``r``
+    of the score matrix, the mask leaves something of [n_lo, n_hi) and
+    nothing of the rest. Rows are query blocks and columns key blocks (fwd,
+    dq), or the other way round (dkv). A bound the arguments fix is a Python
+    int, and both are where ``r`` is one."""
+    if not causal:
+        return 0, n_col
+    lo = r * b_row
+    hi = lo + b_row - 1
+    if rows_are_queries:
+        n_lo, n_hi = 0, _div(hi, b_col) + 1
+        if window is not None:      # key > query - window
+            n_lo = _div(_most(lo - window + 1, 0), b_col)
+    else:
+        n_lo, n_hi = _div(lo, b_col), n_col
+        if window is not None:      # query < key + window
+            n_hi = _least(_div(hi + window - 1, b_col) + 1, n_col)
+    return n_lo, n_hi
+
+
+def _when(*conds):
+    """``pl.when`` of the conditions together; a plain call where each is
+    known to hold as the kernel is traced (``True``: an axis of one step), so
+    that no ``cond`` is traced or lowered for it."""
+    live = [c for c in conds if c is not True]
+    if not live:
+        return lambda f: f()
+    return pl.when(functools.reduce(jnp.logical_and, live))
+
+
+def _for_blocks(body, reach, first, per_chunk):
+    """``body(c)`` for each column block ``c`` that ``reach`` (``_reach``)
+    needs of the chunk that starts at block ``first``: one loop; a chunk the
+    mask leaves nothing of runs it no time. ``first`` None: the one chunk
+    holds every block."""
+    lo, hi = reach
+    if first is not None:
+        lo, hi = jnp.maximum(lo, first), jnp.minimum(hi, first + per_chunk)
+
+    def step(c, carry):
+        body(c)
+        return carry
+
+    jax.lax.fori_loop(lo, hi, step, 0)
+
+
+def _chunk_walk(b_row, b_col, chunk, T, causal, window, rows_are_queries):
+    """The grid's innermost axis: (its steps, (row block, step) -> the chunk
+    (of ``chunk // b_col`` column blocks) fetched). A row block's steps start
+    at the first chunk it needs (``_first``); a step past its last names
+    that last chunk again, so nothing is fetched for it, and the kernel's
+    loops find nothing to do there. The steps are the most chunks any row
+    block needs: all of them under the diagonal alone, the few a window
+    reaches under a window."""
+    per_chunk, n_col = chunk // b_col, T // b_col
+
+    def chunks(r):
+        n_lo, n_hi = _reach(r, b_row, b_col, n_col, causal, window,
+                            rows_are_queries)
+        return _div(n_lo, per_chunk), _div(n_hi - 1, per_chunk)
+
+    steps = T // chunk
+    if window is not None:
+        steps = max(last - first + 1
+                    for first, last in map(chunks, range(T // b_row)))
+    if T == chunk:      # every cell's case: an index map with nothing in it
+        return 1, lambda r, step: 0
+
+    def fetched(r, step):
+        first, last = chunks(r)
+        return jnp.minimum(first + step, last)
+
+    return steps, fetched
+
+
+def _first(reach, step, per_chunk, n_col):
+    """The first column block of the chunk a kernel works on at ``step``;
+    None where the one chunk holds every block."""
+    if per_chunk == n_col:
+        return None
+    return (_div(reach[0], per_chunk) + step) * per_chunk
+
+
+def _traced_once(fn):
+    """``fn`` behind ``jit``'s tracing cache, inlined where it is called: a
+    call with shapes and blocks seen before (the layer again under remat,
+    the next grid point of a search) binds the ``pallas_call`` it traced the
+    first time and traces no kernel body again; the caller's jaxpr holds the
+    ``pallas_call`` itself, as if ``fn`` had been called bare."""
+    names = ("block_q", "block_k", "chunk", "scale", "causal", "h", "kv",
+             "window", "interpret")
+    return jax.jit(fn, static_argnames=names, inline=True)
+
+
 # --------------------------------------------------------------------- fwd
 def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr,
-                *, block_q, block_k, scale, causal, window=None):
-    iq, jk = pl.program_id(1), pl.program_id(2)
-    n_kv = pl.num_programs(2)
-    step = jk
+                *, block_q, block_k, seq, steps, scale, causal, window,
+                keys_down):
+    iq, step = pl.program_id(1), pl.program_id(2)
+    per_chunk = k_ref.shape[1] // block_k
 
-    @pl.when(step == 0)
+    @_when(steps == 1 or step == 0)
     def _init():
         m_scr[:] = jnp.full_like(m_scr, NEG_INF)
         l_scr[:] = jnp.zeros_like(l_scr)
         acc_scr[:] = jnp.zeros_like(acc_scr)
 
-    # Blocks fully above the causal diagonal contribute nothing: skip the
-    # matmuls (the k/v fetch is pipelined by the grid either way).
-    needed = True
-    if window is not None:
-        # the grid's kv axis walks the blocks iq - n_kv + 1 .. iq only; one
-        # before the sequence's start is skipped (its fetch is clamped)
-        jk = iq - (n_kv - 1) + step
-        needed = jk >= 0
-    elif causal:
-        needed = jk * block_k <= iq * block_q + block_q - 1
+    reach = _reach(iq, block_q, block_k, seq // block_k, causal, window, True)
+    first = _first(reach, step, per_chunk, seq // block_k)
+    base = 0 if first is None else first
 
-    @pl.when(needed)
-    def _accumulate():
+    def _accumulate(jk):
         # Matmul inputs stay in the storage dtype (bf16): the MXU computes
-        # bf16×bf16→f32 natively via preferred_element_type, while f32×f32
-        # needs multiple passes — upcasting before the dot costs ~2x. Scale
-        # is applied to the f32 scores, softmax state stays f32.
-        q = q_ref[0]                                      # (BQ, D)
-        kb = k_ref[0]                                     # (BK, D)
-        vb = v_ref[0]
-        s = _dot(q, kb, ((1,), (1,))) * scale             # (BQ, BK) f32
-        if window is not None:
-            s = jnp.where(_window_mask(iq, jk, block_q, block_k, window), s,
-                          NEG_INF)
-        elif causal:
-            s = jnp.where(_block_mask(iq, jk, block_q, block_k), s, NEG_INF)
-        m_prev, l_prev = m_scr[:, 0], l_scr[:, 0]
-        m_new = jnp.maximum(m_prev, s.max(axis=-1))
-        p = jnp.exp(s - m_new[:, None])
+        # bf16 x bf16 -> f32 natively via preferred_element_type, while
+        # f32 x f32 needs multiple passes. Scores, softmax state and the
+        # accumulator are f32.
+        at = pl.multiple_of((jk - base) * block_k, block_k)
+        kb = k_ref[0, pl.ds(at, block_k), :]              # (BK, D)
+        if keys_down:
+            # The scores transposed, keys down the rows: m and l are rows
+            # over the queries (a few vregs where a column of BQ takes
+            # BQ / 8), the accumulator is (D, BQ) and v comes as (D, T): at
+            # a head dim under 128 nothing is a half-empty vreg.
+            vt = v_ref[0, :, pl.ds(at, block_k)]          # (D, BK)
+            s = _scores(kb, q_ref[0], scale)              # (BK, BQ) f32
+            if causal:
+                s = _masked(s, iq * block_q, jk * block_k, 1, window)
+            m_prev = m_scr[:]                             # (8, BQ)
+            m_new = jnp.maximum(m_prev, s.max(axis=0, keepdims=True))
+            p = jnp.exp(s - m_new[:1])
+            corr = jnp.exp(m_prev - m_new)
+            m_scr[:] = m_new
+            l_scr[:] = corr * l_scr[:] + p.sum(axis=0, keepdims=True)
+            acc_scr[:] = corr[:1] * acc_scr[:] + _dot(
+                vt, p.astype(vt.dtype), ((1,), (0,)))
+            return
+        # Queries down the rows: m and l are replicated across a 128-lane
+        # row so that their update and their use are plain vector operations.
+        vb = v_ref[0, pl.ds(at, block_k), :]
+        s = _scores(q_ref[0], kb, scale)                  # (BQ, BK) f32
+        if causal:
+            s = _masked(s, iq * block_q, jk * block_k, 0, window)
+        m_prev = m_scr[:]                                 # (BQ, 128)
+        m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
+        p = jnp.exp(s - _lanes(m_new, block_k))
         corr = jnp.exp(m_prev - m_new)
-        m_scr[:, 0] = m_new
-        l_scr[:, 0] = corr * l_prev + p.sum(axis=-1)
-        acc_scr[:] = corr[:, None] * acc_scr[:] + _dot(
+        m_scr[:] = m_new
+        l_scr[:] = corr * l_scr[:] + p.sum(axis=-1, keepdims=True)
+        acc_scr[:] = _lanes(corr, acc_scr.shape[1]) * acc_scr[:] + _dot(
             p.astype(vb.dtype), vb, ((1,), (0,))
         )
 
-    @pl.when(step == n_kv - 1)
+    _for_blocks(_accumulate, reach, first, per_chunk)
+
+    @_when(steps == 1 or step == steps - 1)
     def _finalize():
-        l = l_scr[:, 0]
-        o_ref[0] = (acc_scr[:] / l[:, None]).astype(o_ref.dtype)
-        # lse rides a trailing singleton dim: Mosaic requires the last two
-        # block dims be (mult-of-8, mult-of-128) or equal to the array dims,
-        # so a 2-D (1, block_q) lse block cannot lower; (1, block_q, 1) can.
-        lse_ref[0] = (m_scr[:, 0] + jnp.log(l))[:, None]
+        # lse leaves as a (1, block_q) row of a (BH, 1, T) array: lane-dense
+        # in HBM (a (T, 1) column would be padded to 128 lanes there), read
+        # as it is by the dkv kernel, whose scores are transposed
+        l = l_scr[:]
+        lse = m_scr[:] + jnp.log(l)
+        if keys_down:
+            o_ref[0] = (acc_scr[:] / l[:1]).astype(o_ref.dtype)   # (D, BQ)
+            lse_ref[0] = lse[:1]
+        else:
+            o_ref[0] = (acc_scr[:] / _lanes(l, acc_scr.shape[1])).astype(
+                o_ref.dtype)
+            lse_ref[0] = lse.T[:1]
 
 
 def _kv_of(h: int, kv: int):
@@ -149,216 +325,222 @@ def _kv_of(h: int, kv: int):
     group's row — the kernels never see repeated k/v and the (B, H, T, D)
     activation expansion never materializes. rep == 1 is the identity."""
     rep = h // kv
+    if rep == 1:
+        return lambda bh: bh
 
     def to_kv(bh):
-        return (bh // h) * kv + (bh % h) // rep
+        return _div(bh, h) * kv + _div(jax.lax.rem(bh, jnp.int32(h)), rep)
 
     return to_kv
 
 
-def _kv_walk(window, block_q, block_k, n_kv):
-    """(kv steps of the grid, the key block a (query block, step) reads)."""
-    if window is None:
-        return n_kv, lambda i, j: j
-    if block_q != block_k:
-        raise ValueError("a window kernel takes equal blocks")
-    n_w = min(_window_blocks(window, block_k), n_kv)
-    return n_w, lambda i, j: jnp.maximum(i - (n_w - 1) + j, 0)
+def _keys_down(D: int) -> bool:
+    """The forward's orientation, from the head dim: keys down the rows of
+    the score block where a (block, D) float32 tile would half-fill its
+    vregs (head 64: 27 % of the forward's time on the chip, PERF.md section
+    6, PR 41); queries down the rows from 128 on, where the transposed PV
+    product would stream only D rows through each weight tile."""
+    return D < _LANES
 
 
-def _fwd(q, k, v, *, block_q, block_k, scale, causal, h, kv, window=None):
+@_traced_once
+def _fwd(q, k, v, *, block_q, block_k, chunk, scale, causal, h, kv,
+         window=None, interpret=False):
     BH, T, D = q.shape
     kv_of = _kv_of(h, kv)
-    n_steps, key_block = _kv_walk(window, block_q, block_k, T // block_k)
-    grid = (BH, T // block_q, n_steps)
-    kernel_kw = {} if window is None else {"window": window}
+    keys_down = _keys_down(D)
+    steps, fetched = _chunk_walk(block_q, block_k, chunk, T, causal, window,
+                                 True)
+    walked = pl.BlockSpec((1, chunk, D),
+                          lambda bh, i, j: (kv_of(bh), fetched(i, j), 0))
+    if keys_down:   # v and o cross the kernel's edge as (D, T)
+        v = jnp.swapaxes(v, 1, 2)
+        v_spec = pl.BlockSpec((1, D, chunk),
+                              lambda bh, i, j: (kv_of(bh), 0, fetched(i, j)))
+        o_spec = pl.BlockSpec((1, D, block_q), lambda bh, i, j: (bh, 0, i))
+        o_shape, stat, acc = (BH, D, T), (8, block_q), (D, block_q)
+    else:
+        v_spec = walked
+        o_spec = pl.BlockSpec((1, block_q, D), lambda bh, i, j: (bh, i, 0))
+        o_shape, stat, acc = (BH, T, D), (block_q, _LANES), (block_q, D)
     o, lse = pl.pallas_call(
         functools.partial(
-            _fwd_kernel, block_q=block_q, block_k=block_k, scale=scale,
-            causal=causal, **kernel_kw,
+            _fwd_kernel, block_q=block_q, block_k=block_k, seq=T, steps=steps,
+            scale=scale, causal=causal, window=window, keys_down=keys_down,
         ),
-        grid=grid,
+        grid=(BH, T // block_q, steps),
         in_specs=[
             pl.BlockSpec((1, block_q, D), lambda bh, i, j: (bh, i, 0)),
-            pl.BlockSpec((1, block_k, D),
-                         lambda bh, i, j: (kv_of(bh), key_block(i, j), 0)),
-            pl.BlockSpec((1, block_k, D),
-                         lambda bh, i, j: (kv_of(bh), key_block(i, j), 0)),
+            walked,
+            v_spec,
         ],
         out_specs=[
-            pl.BlockSpec((1, block_q, D), lambda bh, i, j: (bh, i, 0)),
-            pl.BlockSpec((1, block_q, 1), lambda bh, i, j: (bh, i, 0)),
+            o_spec,
+            pl.BlockSpec((1, 1, block_q), lambda bh, i, j: (bh, 0, i)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((BH, T, D), q.dtype),
-            jax.ShapeDtypeStruct((BH, T, 1), jnp.float32),
+            jax.ShapeDtypeStruct(o_shape, q.dtype),
+            jax.ShapeDtypeStruct((BH, 1, T), jnp.float32),
         ],
         scratch_shapes=[
-            pltpu.VMEM((block_q, 1), jnp.float32),   # running max
-            pltpu.VMEM((block_q, 1), jnp.float32),   # running denom
-            pltpu.VMEM((block_q, D), jnp.float32),   # output accumulator
+            pltpu.VMEM(stat, jnp.float32),    # running max
+            pltpu.VMEM(stat, jnp.float32),    # running denom
+            pltpu.VMEM(acc, jnp.float32),     # output accumulator
         ],
         name="saturn_flash_fwd" if window is None else "saturn_swa_fwd",
-        interpret=_use_interpret(),
+        interpret=interpret,
     )(q, k, v)
-    return o, lse
+    return (jnp.swapaxes(o, 1, 2) if keys_down else o), lse
 
 
 # --------------------------------------------------------------------- bwd
 def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
-               dq_scr, *, block_q, block_k, scale, causal, window=None):
-    iq, jk = pl.program_id(1), pl.program_id(2)
-    n_kv = pl.num_programs(2)
-    step = jk
+               dq_scr, lse_scr, delta_scr, *, block_q, block_k, seq, steps,
+               scale, causal, window):
+    iq, step = pl.program_id(1), pl.program_id(2)
+    per_chunk = k_ref.shape[1] // block_k
 
-    @pl.when(step == 0)
+    @_when(steps == 1 or step == 0)
     def _init():
         dq_scr[:] = jnp.zeros_like(dq_scr)
+        # the rows of lse and delta as lane-wide columns, once a query block
+        lse_scr[:] = _column(lse_ref[0])
+        delta_scr[:] = _column(delta_ref[0])
 
-    needed = True
-    if window is not None:
-        jk = iq - (n_kv - 1) + step     # see the fwd kernel
-        needed = jk >= 0
-    elif causal:
-        needed = jk * block_k <= iq * block_q + block_q - 1
+    reach = _reach(iq, block_q, block_k, seq // block_k, causal, window, True)
+    first = _first(reach, step, per_chunk, seq // block_k)
+    base = 0 if first is None else first
 
-    @pl.when(needed)
-    def _accumulate():
+    def _accumulate(jk):
         # bf16 matmul inputs, f32 accumulation — see the fwd kernel note.
-        q = q_ref[0]
-        kb = k_ref[0]
-        vb = v_ref[0]
-        do = do_ref[0]
-        s = _dot(q, kb, ((1,), (1,))) * scale
-        if window is not None:
-            s = jnp.where(_window_mask(iq, jk, block_q, block_k, window), s,
-                          NEG_INF)
-        elif causal:
-            s = jnp.where(_block_mask(iq, jk, block_q, block_k), s, NEG_INF)
-        p = jnp.exp(s - lse_ref[0])          # lse block is (block_q, 1)
-        dp = _dot(do, vb, ((1,), (1,)))
-        ds = p * (dp - delta_ref[0])
+        rows = pl.ds(pl.multiple_of((jk - base) * block_k, block_k),
+                     block_k)
+        kb, vb = k_ref[0, rows, :], v_ref[0, rows, :]
+        s = _scores(q_ref[0], kb, scale)
+        if causal:
+            s = _masked(s, iq * block_q, jk * block_k, 0, window)
+        p = jnp.exp(s - _lanes(lse_scr[:], block_k))
+        dp = _dot(do_ref[0], vb, ((1,), (1,)))
+        ds = p * (dp - _lanes(delta_scr[:], block_k))
         dq_scr[:] = dq_scr[:] + _dot(ds.astype(kb.dtype), kb, ((1,), (0,)))
 
-    @pl.when(step == n_kv - 1)
+    _for_blocks(_accumulate, reach, first, per_chunk)
+
+    @_when(steps == 1 or step == steps - 1)
     def _finalize():
         dq_ref[0] = (dq_scr[:] * scale).astype(dq_ref.dtype)
 
 
 def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                dk_ref, dv_ref, dk_scr, dv_scr, *, block_q, block_k, scale,
-                causal, window=None, n_q_blocks=None):
-    # Grid (bkv, jk, g, iq): g walks the q heads sharing this k/v head
+                dk_ref, dv_ref, dk_scr, dv_scr, *, block_q, block_k, seq,
+                steps, rep, scale, causal, window):
+    # Grid (bkv, jk, g, step): g walks the q heads sharing this k/v head
     # (size 1 without GQA); the (bkv, jk) output block stays resident across
-    # the whole inner (g, iq) sweep, so dk/dv accumulate the group sum the
+    # the whole inner (g, step) sweep, so dk/dv accumulate the group sum the
     # transpose of the activation-side repeat would otherwise need.
-    jk, g, iq = pl.program_id(1), pl.program_id(2), pl.program_id(3)
-    n_g, n_q = pl.num_programs(2), pl.num_programs(3)
-    step = iq
+    jk, g, step = pl.program_id(1), pl.program_id(2), pl.program_id(3)
+    per_chunk = q_ref.shape[1] // block_q
 
-    @pl.when(jnp.logical_and(g == 0, step == 0))
+    @_when(rep == 1 or g == 0, steps == 1 or step == 0)
     def _init():
         dk_scr[:] = jnp.zeros_like(dk_scr)
         dv_scr[:] = jnp.zeros_like(dv_scr)
 
-    needed = True
-    if window is not None:
-        # the grid's q axis walks the blocks jk .. jk + n_q - 1 only; one
-        # past the sequence's end is skipped (its fetch is clamped)
-        iq = jk + step
-        needed = iq < n_q_blocks
-    elif causal:
-        needed = iq * block_q + block_q - 1 >= jk * block_k
+    reach = _reach(jk, block_k, block_q, seq // block_q, causal, window, False)
+    first = _first(reach, step, per_chunk, seq // block_q)
+    base = 0 if first is None else first
 
-    @pl.when(needed)
-    def _accumulate():
-        # bf16 matmul inputs, f32 accumulation — see the fwd kernel note.
-        kb = k_ref[0]
-        vb = v_ref[0]
-        qb = q_ref[0]
-        dob = do_ref[0]
-        s = _dot(qb, kb, ((1,), (1,))) * scale
-        if window is not None:
-            s = jnp.where(_window_mask(iq, jk, block_q, block_k, window), s,
-                          NEG_INF)
-        elif causal:
-            s = jnp.where(_block_mask(iq, jk, block_q, block_k), s, NEG_INF)
-        p = jnp.exp(s - lse_ref[0])                         # (BQ, BK)
-        dv_scr[:] = dv_scr[:] + _dot(p.astype(dob.dtype), dob, ((0,), (0,)))
-        dp = _dot(dob, vb, ((1,), (1,)))
-        ds = (p * (dp - delta_ref[0])).astype(qb.dtype)
+    def _accumulate(iq):
+        # The scores transposed, keys down the rows: lse and delta are
+        # (1, BQ) rows that broadcast over sublanes, and dv = P^T dO and
+        # dk = dS^T Q are plain products (P and dS never transposed).
+        at = pl.multiple_of((iq - base) * block_q, block_q)
+        qb, dob = q_ref[0, pl.ds(at, block_q), :], do_ref[0, pl.ds(at, block_q), :]
+        s = _scores(k_ref[0], qb, scale)                    # (BK, BQ)
+        if causal:
+            s = _masked(s, iq * block_q, jk * block_k, 1, window)
+        p = jnp.exp(s - lse_ref[0, :, pl.ds(at, block_q)])
+        dv_scr[:] = dv_scr[:] + _dot(p.astype(dob.dtype), dob, ((1,), (0,)))
+        dp = _dot(v_ref[0], dob, ((1,), (1,)))
+        ds = (p * (dp - delta_ref[0, :, pl.ds(at, block_q)])).astype(qb.dtype)
         # ds·q is unscaled; the scale factor lands in the finalize below.
-        dk_scr[:] = dk_scr[:] + _dot(ds, qb, ((0,), (0,)))
+        dk_scr[:] = dk_scr[:] + _dot(ds, qb, ((1,), (0,)))
 
-    @pl.when(jnp.logical_and(g == n_g - 1, step == n_q - 1))
+    _for_blocks(_accumulate, reach, first, per_chunk)
+
+    @_when(rep == 1 or g == rep - 1, steps == 1 or step == steps - 1)
     def _finalize():
         dk_ref[0] = (dk_scr[:] * scale).astype(dk_ref.dtype)
         dv_ref[0] = dv_scr[:].astype(dv_ref.dtype)
 
 
-def _bwd(block_q, block_k, scale, causal, h, kv, res, do, window=None):
-    q, k, v, o, lse = res
+@_traced_once
+def _dq(q, k, v, do, lse, delta, *, block_q, block_k, chunk, scale, causal,
+        h, kv, window=None, interpret=False):
     BH, T, D = q.shape
-    BKV = k.shape[0]
-    rep = h // kv
     kv_of = _kv_of(h, kv)
-    n_steps, key_block = _kv_walk(window, block_q, block_k, T // block_k)
-    n_q = T // block_q
-    if window is None:
-        kernel_kw, dkv_kw, q_block = {}, {}, lambda j, i: i
-    else:
-        kernel_kw = {"window": window}
-        dkv_kw = {"window": window, "n_q_blocks": n_q}
-        q_block = lambda j, i: jnp.minimum(j + i, n_q - 1)   # noqa: E731
-    # (BH, T, 1) like lse — see the fwd finalize note on Mosaic block rules.
-    delta = jnp.sum(
-        do.astype(jnp.float32) * o.astype(jnp.float32), axis=-1, keepdims=True
-    )
-
-    dq = pl.pallas_call(
+    steps, fetched = _chunk_walk(block_q, block_k, chunk, T, causal,
+                                         window, True)
+    return pl.pallas_call(
         functools.partial(
-            _dq_kernel, block_q=block_q, block_k=block_k, scale=scale,
-            causal=causal, **kernel_kw,
+            _dq_kernel, block_q=block_q, block_k=block_k, seq=T, steps=steps,
+            scale=scale, causal=causal, window=window,
         ),
-        grid=(BH, T // block_q, n_steps),
+        grid=(BH, T // block_q, steps),
         in_specs=[
             pl.BlockSpec((1, block_q, D), lambda bh, i, j: (bh, i, 0)),
-            pl.BlockSpec((1, block_k, D),
-                         lambda bh, i, j: (kv_of(bh), key_block(i, j), 0)),
-            pl.BlockSpec((1, block_k, D),
-                         lambda bh, i, j: (kv_of(bh), key_block(i, j), 0)),
+            pl.BlockSpec((1, chunk, D),
+                         lambda bh, i, j: (kv_of(bh), fetched(i, j), 0)),
+            pl.BlockSpec((1, chunk, D),
+                         lambda bh, i, j: (kv_of(bh), fetched(i, j), 0)),
             pl.BlockSpec((1, block_q, D), lambda bh, i, j: (bh, i, 0)),
-            pl.BlockSpec((1, block_q, 1), lambda bh, i, j: (bh, i, 0)),
-            pl.BlockSpec((1, block_q, 1), lambda bh, i, j: (bh, i, 0)),
+            pl.BlockSpec((1, 1, block_q), lambda bh, i, j: (bh, 0, i)),
+            pl.BlockSpec((1, 1, block_q), lambda bh, i, j: (bh, 0, i)),
         ],
         out_specs=pl.BlockSpec((1, block_q, D), lambda bh, i, j: (bh, i, 0)),
         out_shape=jax.ShapeDtypeStruct((BH, T, D), q.dtype),
-        scratch_shapes=[pltpu.VMEM((block_q, D), jnp.float32)],
+        scratch_shapes=[
+            pltpu.VMEM((block_q, D), jnp.float32),
+            pltpu.VMEM((block_q, _LANES), jnp.float32),   # lse, lane-wide
+            pltpu.VMEM((block_q, _LANES), jnp.float32),   # delta, lane-wide
+        ],
         name="saturn_flash_dq" if window is None else "saturn_swa_dq",
-        interpret=_use_interpret(),
+        interpret=interpret,
     )(q, k, v, do, lse, delta)
+
+
+@_traced_once
+def _dkv(q, k, v, do, lse, delta, *, block_q, block_k, chunk, scale, causal,
+         h, kv, window=None, interpret=False):
+    T, D = q.shape[1:]
+    BKV = k.shape[0]
+    rep = h // kv
+    steps, fetched = _chunk_walk(block_k, block_q, chunk, T, causal,
+                                         window, False)
 
     def qh(bkv, g):
         # flat (B*KV) k/v row + group member -> flat (B*H) q-head row
-        return (bkv // kv) * h + (bkv % kv) * rep + g
+        if rep == 1:
+            return bkv
+        return _div(bkv, kv) * h + jax.lax.rem(bkv, jnp.int32(kv)) * rep + g
 
-    dk, dv = pl.pallas_call(
+    return pl.pallas_call(
         functools.partial(
-            _dkv_kernel, block_q=block_q, block_k=block_k, scale=scale,
-            causal=causal, **dkv_kw,
+            _dkv_kernel, block_q=block_q, block_k=block_k, seq=T, steps=steps,
+            rep=rep, scale=scale, causal=causal, window=window,
         ),
-        grid=(BKV, T // block_k, rep, n_q if window is None else n_steps),
+        grid=(BKV, T // block_k, rep, steps),
         in_specs=[
-            pl.BlockSpec((1, block_q, D),
-                         lambda bkv, j, g, i: (qh(bkv, g), q_block(j, i), 0)),
+            pl.BlockSpec((1, chunk, D),
+                         lambda bkv, j, g, i: (qh(bkv, g), fetched(j, i), 0)),
             pl.BlockSpec((1, block_k, D), lambda bkv, j, g, i: (bkv, j, 0)),
             pl.BlockSpec((1, block_k, D), lambda bkv, j, g, i: (bkv, j, 0)),
-            pl.BlockSpec((1, block_q, D),
-                         lambda bkv, j, g, i: (qh(bkv, g), q_block(j, i), 0)),
-            pl.BlockSpec((1, block_q, 1),
-                         lambda bkv, j, g, i: (qh(bkv, g), q_block(j, i), 0)),
-            pl.BlockSpec((1, block_q, 1),
-                         lambda bkv, j, g, i: (qh(bkv, g), q_block(j, i), 0)),
+            pl.BlockSpec((1, chunk, D),
+                         lambda bkv, j, g, i: (qh(bkv, g), fetched(j, i), 0)),
+            pl.BlockSpec((1, 1, chunk),
+                         lambda bkv, j, g, i: (qh(bkv, g), 0, fetched(j, i))),
+            pl.BlockSpec((1, 1, chunk),
+                         lambda bkv, j, g, i: (qh(bkv, g), 0, fetched(j, i))),
         ],
         out_specs=[
             pl.BlockSpec((1, block_k, D), lambda bkv, j, g, i: (bkv, j, 0)),
@@ -373,81 +555,85 @@ def _bwd(block_q, block_k, scale, causal, h, kv, res, do, window=None):
             pltpu.VMEM((block_k, D), jnp.float32),
         ],
         name="saturn_flash_dkv" if window is None else "saturn_swa_dkv",
-        interpret=_use_interpret(),
+        interpret=interpret,
     )(q, k, v, do, lse, delta)
+
+
+def _bwd(blocks, scale, causal, h, kv, res, do, window=None):
+    """dq, dk, dv; ``blocks`` = (block_q, block_k, chunk) of the dq kernel
+    and of the dkv kernel."""
+    q, k, v, o, lse = res
+    # a (BH, 1, T) row like lse
+    delta = jnp.sum(
+        do.astype(jnp.float32) * o.astype(jnp.float32), axis=-1
+    )[:, None, :]
+    kw = dict(scale=scale, causal=causal, h=h, kv=kv, window=window,
+              interpret=_use_interpret())
+    (dq_q, dq_k, dq_c), (dkv_q, dkv_k, dkv_c) = blocks
+    dq = _dq(q, k, v, do, lse, delta, block_q=dq_q, block_k=dq_k, chunk=dq_c,
+             **kw)
+    dk, dv = _dkv(q, k, v, do, lse, delta, block_q=dkv_q, block_k=dkv_k,
+                  chunk=dkv_c, **kw)
     return dq, dk, dv
 
 
 # ---------------------------------------------------------------- public
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
-def _flash_bh(q, k, v, block_q, block_k, causal, h, kv):
-    scale = 1.0 / math.sqrt(q.shape[-1])
-    o, _ = _fwd(q, k, v, block_q=block_q, block_k=block_k, scale=scale,
-                causal=causal, h=h, kv=kv)
-    return o
+def _flash_bh(q, k, v, blocks, causal, h, kv, window=None):
+    """Attention on flat heads. ``blocks``: the (block_q, block_k, chunk) of
+    the fwd, dq and dkv kernels. With a ``window`` the same kernels run under
+    names of their own (``saturn_swa_*``: a reader that counts a
+    ``saturn_flash_*`` call as full causal attention must not meet one)."""
+    return _flash_bh_fwd(q, k, v, blocks, causal, h, kv, window)[0]
 
 
-def _flash_bh_fwd(q, k, v, block_q, block_k, causal, h, kv):
+def _flash_bh_fwd(q, k, v, blocks, causal, h, kv, window):
     scale = 1.0 / math.sqrt(q.shape[-1])
-    o, lse = _fwd(q, k, v, block_q=block_q, block_k=block_k, scale=scale,
-                  causal=causal, h=h, kv=kv)
+    bq, bk, chunk = blocks[0]
+    o, lse = _fwd(q, k, v, block_q=bq, block_k=bk, chunk=chunk, scale=scale,
+                  causal=causal, h=h, kv=kv, window=window,
+                  interpret=_use_interpret())
     return o, (q, k, v, o, lse)
 
 
-def _flash_bh_bwd(block_q, block_k, causal, h, kv, res, do):
+def _flash_bh_bwd(blocks, causal, h, kv, window, res, do):
     scale = 1.0 / math.sqrt(res[0].shape[-1])
-    return _bwd(block_q, block_k, scale, causal, h, kv, res, do)
+    return _bwd(blocks[1:], scale, causal, h, kv, res, do, window=window)
 
 
 _flash_bh.defvjp(_flash_bh_fwd, _flash_bh_bwd)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
-def _swa_bh(q, k, v, block, window, h, kv):
-    """Sliding-window attention on flat heads: ``_flash_bh``'s kernels with
-    the window's mask, their grids walking only the blocks the window
-    reaches, under names of their own (``saturn_swa_*``: a reader that counts
-    a ``saturn_flash_*`` call as full causal attention must not meet one)."""
-    return _swa_bh_fwd(q, k, v, block, window, h, kv)[0]
-
-
-def _swa_bh_fwd(q, k, v, block, window, h, kv):
-    scale = 1.0 / math.sqrt(q.shape[-1])
-    o, lse = _fwd(q, k, v, block_q=block, block_k=block, scale=scale,
-                  causal=True, h=h, kv=kv, window=window)
-    return o, (q, k, v, o, lse)
-
-
-def _swa_bh_bwd(block, window, h, kv, res, do):
-    scale = 1.0 / math.sqrt(res[0].shape[-1])
-    return _bwd(block, block, scale, True, h, kv, res, do, window=window)
-
-
-_swa_bh.defvjp(_swa_bh_fwd, _swa_bh_bwd)
-
-
-_WINDOW_PLANS: list = []
+_PLANS: dict = {"window": [], "flash": []}
 
 
 @contextlib.contextmanager
+def _traced_plans(kind: str):
+    before, _PLANS[kind] = _PLANS[kind], []
+    try:
+        yield _PLANS[kind]
+    finally:
+        _PLANS[kind] = before
+
+
 def traced_window_plans():
     """Collects ``window_plan`` of every window call traced inside (as
     ``ops/ce.py``'s ``traced_plans``)."""
-    global _WINDOW_PLANS
-    before, _WINDOW_PLANS = _WINDOW_PLANS, []
-    try:
-        yield _WINDOW_PLANS
-    finally:
-        _WINDOW_PLANS = before
+    return _traced_plans("window")
+
+
+def traced_flash_plans():
+    """Likewise ``flash_plan`` of every causal call traced inside."""
+    return _traced_plans("flash")
 
 
 def window_plan(T: int, window: int, block: Optional[int] = None) -> dict:
-    """The window kernels' grid at sequence ``T``: the (equal) block, the
+    """The window kernels' walk at sequence ``T``: the (equal) block, the
     key blocks a query block visits and how many of a causal walk's it
     skips a call (mean over query blocks)."""
     b = block or _window_block(T)
     n = T // b
-    n_w = min(_window_blocks(window, b), n)
+    n_w = min(-(-(window - 1) // b) + 1, n)
     return {"window": window, "block": b, "blocks_visited": n_w,
             "blocks_skipped_per_call": n * (n + 1) // 2 - sum(
                 min(i + 1, n_w) for i in range(n))}
@@ -463,14 +649,95 @@ def _window_block(T: int) -> int:
     return min(128, T)
 
 
-def _default_block(T: int) -> int:
-    """Largest power-of-two block ≤ 512 dividing T: bigger blocks mean fewer
-    grid programs and larger MXU matmuls; VMEM stays comfortable (the f32
-    score block at 512² is 1 MiB)."""
-    for b in (512, 256, 128):
+def _largest_block(T: int, most: int) -> int:
+    """The largest of ``most``, ``most / 2``, .. 128 that divides T; a T no
+    such block divides is one block (or 128s where those divide it)."""
+    b = most
+    while b >= _LANES:
         if T % b == 0:
             return b
-    return min(128, T)
+        b //= 2
+    return min(_LANES, T)
+
+
+#: the most elements of one walked operand's chunk (chunk x D): with two
+#: operands, each double-buffered, 8 MiB of VMEM in bf16. A guard, not a
+#: plan: every cell's walked side fits whole (the longest, 8192 x 128, just)
+_CHUNK_ELEMENTS = 1024 * 1024
+
+
+def _chunk(T: int, D: int, block: int) -> int:
+    """Columns of the walked sequence a grid step holds in VMEM: all of T
+    where that fits ``_CHUNK_ELEMENTS`` (every cell's does, and the chip has
+    read no other), else the largest whole fraction of T in whole blocks
+    that does (one block at the least): a longer sequence still compiles,
+    at a fetch a chunk. The walk inside a chunk is a loop in the kernel, so
+    a grid step's fixed cost and the operand's fetch are paid once a chunk,
+    not once a block."""
+    for n in range(1, T // block + 1):
+        if T % n == 0 and (T // n) % block == 0 and (T // n) * D <= _CHUNK_ELEMENTS:
+            return T // n
+    return block
+
+
+def _walk(T: int, block_q: int, block_k: int, chunk: int,
+          rows_are_queries: bool) -> dict:
+    """Of the (T / block_q) x (T / block_k) score blocks of a causal head:
+    how many the kernel visits (the rest lie above the diagonal and are in
+    no loop) and how many of those the diagonal crosses: the blocks whose
+    mask changes a score (the one loop body applies it to every visited
+    block: a second, unmasked body read no faster on the chip, PERF.md
+    section 6, PR 41)."""
+    b_row, b_col = (block_q, block_k) if rows_are_queries else (block_k, block_q)
+    visited = masked = 0
+    for r in range(T // b_row):
+        n_lo, n_hi = _reach(r, b_row, b_col, T // b_col, True, None,
+                            rows_are_queries)
+        lo, hi = r * b_row, (r + 1) * b_row - 1
+        visited += n_hi - n_lo
+        # under the diagonal as a whole: the block's last key at or before
+        # its first query
+        masked += sum(
+            not (c * b_col + b_col - 1 <= lo if rows_are_queries
+                 else c * b_col >= hi)
+            for c in range(n_lo, n_hi))
+    return {"block_q": block_q, "block_k": block_k, "chunk": chunk,
+            "visited": visited, "masked": masked}
+
+
+def flash_plan(T: int, D: int, block_q: Optional[int] = None,
+               block_k: Optional[int] = None) -> dict:
+    """The causal kernels' blocks at sequence ``T`` and head dim ``D``, with
+    what each kernel's walk over a head then is (``_walk``). A pure function
+    of its arguments: no device, no compile, nothing tried. ``block_q`` /
+    ``block_k`` put all three kernels on the caller's blocks. The q heads a
+    k/v head (Laguna's 6) select nothing: the dkv kernel walks a group's
+    heads one after the other on the same blocks.
+
+    The rule, from the chip's readings at the cells' shapes (PERF.md section
+    6, PR 41; ``tools/flash_blocks.py``): the walked side (keys for fwd and
+    dq, queries for dkv) in blocks of 512, the side that stays (the grid's
+    row block) 512, and 1024 from T 8192 on at a head dim up to 128. Smaller
+    blocks lose at every shape although they compute fewer scores above the
+    diagonal: a block's step in the kernel's loop is a chain of product,
+    reduction, exp, product that does not overlap the next block's, and its
+    fixed part weighs more the smaller the block; wider ones spill the score
+    block. A row block's fixed cost (state in and out, the dq kernel's two
+    transposes, the fetch of q) is paid T / block times a head, which from
+    T 8192 on is worth the larger block; at head dim 256 the 1024-row
+    operands crowd VMEM and the reading is worse.
+    """
+    stays = _largest_block(T, 1024 if T >= 8192 and D <= _LANES else 512)
+    walked = _largest_block(T, 512)
+    blocks = {"fwd": (block_q or stays, block_k or walked),
+              "dq": (block_q or stays, block_k or walked),
+              "dkv": (block_q or walked, block_k or stays)}
+    out = {"seq": T, "head_dim": D, "keys_down": _keys_down(D)}
+    for name, (bq, bk) in blocks.items():
+        walks_keys = name != "dkv"
+        out[name] = _walk(T, bq, bk, _chunk(T, D, bk if walks_keys else bq),
+                          walks_keys)
+    return out
 
 
 def flash_supported(cfg=None) -> bool:
@@ -478,8 +745,8 @@ def flash_supported(cfg=None) -> bool:
 
     Real lowering needs the TPU backend; interpret mode exists only for
     numerics tests. With a config, also checks the kernel's shape contract
-    (seq divisible by the default block) and that attention is single-program
-    (sequence-parallel configs have their own kernels). Used by the executors'
+    (seq divisible by the plan's smallest block) and that attention is
+    single-program (sequence-parallel configs have their own kernels). Used by the executors'
     autotune grids so the trial runner profiles flash-vs-dense per task and
     the solver selects from measurements (VERDICT r1 items 2-3).
     """
@@ -518,10 +785,11 @@ def flash_attention(
     long context) never exists, and dk/dv come back at (B, KV, T, D) with
     the group sum done in-kernel.
 
-    T must divide by the block sizes (default: the largest of 512/256/128
-    dividing T, else min(128, T) — see ``_default_block``) or this raises —
-    the model config validates the constraint up front
-    (``GPT2Config.__post_init__``); this op stays strict.
+    T must divide by the block sizes (default: ``flash_plan``'s, the largest
+    of 1024 or 512 / 256 / 128 dividing T, else min(128, T)) or this raises
+    — the model config validates the constraint up front
+    (``GPT2Config.__post_init__``); this op stays strict. ``block_q`` /
+    ``block_k`` put all three kernels on the caller's blocks.
     """
     B, H, T, D = q.shape
     KV = k.shape[1]
@@ -539,11 +807,17 @@ def flash_attention(
         b = block_q or _window_block(T)
         if T % b:
             raise ValueError(f"seq len {T} not divisible by the block ({b})")
-        _WINDOW_PLANS.append(window_plan(T, int(window), b))
-        return _swa_bh(qf, kf, vf, b, int(window), H, KV).reshape(B, H, T, D)
-    bq = block_q or _default_block(T)
-    bk = block_k or _default_block(T)
-    if T % bq or T % bk:
-        raise ValueError(f"seq len {T} not divisible by blocks ({bq}, {bk})")
-    o = _flash_bh(qf, kf, vf, bq, bk, causal, H, KV)
+        _PLANS["window"].append(window_plan(T, int(window), b))
+        # a chunk of one block: what a step fetches is what the window reaches
+        blocks = ((b, b, b),) * 3
+        o = _flash_bh(qf, kf, vf, blocks, True, H, KV, int(window))
+        return o.reshape(B, H, T, D)
+    plan = flash_plan(T, D, block_q, block_k)
+    blocks = tuple((plan[n]["block_q"], plan[n]["block_k"], plan[n]["chunk"])
+                   for n in ("fwd", "dq", "dkv"))
+    if any(T % b for triple in blocks for b in triple):
+        raise ValueError(f"seq len {T} not divisible by blocks {blocks}")
+    if causal:
+        _PLANS["flash"].append(plan)
+    o = _flash_bh(qf, kf, vf, blocks, causal, H, KV)
     return o.reshape(B, H, T, D)

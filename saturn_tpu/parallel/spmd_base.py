@@ -192,9 +192,11 @@ class _Bundle:
     # linear-attention layer
     gdn_plans: Tuple[Any, ...] = ()
     # likewise each routed-expert layer (ops/moe.py's ``RoutedPlan``) and
-    # each window-attention call (ops/flash.py's ``window_plan``)
+    # each window-attention call (ops/flash.py's ``window_plan``) and each
+    # causal flash call (its ``flash_plan``)
     moe_plans: Tuple[Any, ...] = ()
     window_plans: Tuple[Any, ...] = ()
+    flash_plans: Tuple[Any, ...] = ()
     _lowered: Any = None
     _compiled: Any = None
     _single_lock: Any = field(default_factory=threading.Lock)
@@ -986,7 +988,8 @@ class SPMDTechnique(BaseTechnique):
 
         with _ce.traced_plans() as ce_plans, _gdn.traced_plans() as gdn_plans, \
                 _moe.traced_plans() as moe_plans, \
-                _flash.traced_window_plans() as window_plans:
+                _flash.traced_window_plans() as window_plans, \
+                _flash.traced_flash_plans() as flash_plans:
             closed, out_shapes = jax.make_jaxpr(
                 counted_step, return_shape=True
             )(state_shapes, batch_sds)
@@ -1045,6 +1048,7 @@ class SPMDTechnique(BaseTechnique):
             gdn_plans=tuple(gdn_plans),
             moe_plans=tuple(moe_plans),
             window_plans=tuple(window_plans),
+            flash_plans=tuple(flash_plans),
         )
 
     # ------------------------------------------------------------- shardflow
@@ -1323,7 +1327,10 @@ class SPMDTechnique(BaseTechnique):
         RoutedPlan``: kernel or twin, row tile, buffer rows and the worst
         case, experts held / all, top-k) and ``window_plan`` of one with
         sliding-window layers (``ops/flash.py::window_plan``: window, block,
-        key blocks visited and skipped a call). Beside them
+        key blocks visited and skipped a call); ``flash_plan`` of one whose
+        causal attention runs the flash kernels (``ops/flash.py::flash_plan``:
+        each kernel's blocks and chunk, the score blocks it visits a head
+        and those of them the diagonal crosses). Beside them
         ``step_traces``: how often the model's Python step function was
         called for this grid point (its bundle's one trace: 1). Nothing where
         the point's bundle was never built."""
@@ -1340,6 +1347,8 @@ class SPMDTechnique(BaseTechnique):
             out["moe_plan"] = bundle.moe_plans[0].as_event()
         if bundle.window_plans:
             out["window_plan"] = dict(bundle.window_plans[0])
+        if bundle.flash_plans:   # the first causal call's (a model has one T, D)
+            out["flash_plan"] = dict(bundle.flash_plans[0])
         return out
 
     def _profile_window(self, config: Dict[str, Any]) -> int:

@@ -230,3 +230,27 @@ def test_plans_arrive_without_the_one_step_program(tmp_path, devices8):
     bundle = tech._cached_bundle(task, devices, config)
     assert bundle.has_fused(8)
     assert bundle._lowered is None and bundle._compiled is None
+
+
+def test_flash_plan_arrives_on_the_trial_config_event(tmp_path, devices8):
+    """A grid point whose attention runs the flash kernels says under which
+    blocks (``ops/flash.py::flash_plan``, collected at the one trace); the
+    dense point beside it says nothing."""
+    from saturn_tpu.ops.flash import flash_plan
+
+    task = _task(tmp_path, "test-tiny", "flash-plan", batch=2)
+    tech = _technique("dp")
+    grid = [{"remat": False, "attention": "flash"},
+            {"remat": False, "attention": "dense"}]
+    tech.candidate_configs = lambda task, n: [dict(c) for c in grid]
+
+    path = str(tmp_path / "events.jsonl")
+    with metrics.scoped(path):
+        tech.search(task, devices8[:1], 0)
+    points = {e["config"]["attention"]: e
+              for e in metrics.read_events(path, kind="trial_config")}
+    head_dim = 16   # test-tiny: d_model 64 over 4 heads
+    assert points["flash"]["flash_plan"] == flash_plan(64, head_dim)
+    assert points["flash"]["flash_plan"]["fwd"]["visited"] == 1
+    assert "flash_plan" not in points["dense"]
+    assert points["flash"]["step_traces"] == 1

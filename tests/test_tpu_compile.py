@@ -75,8 +75,9 @@ def _flash_loss(q, k, v):
 @pytest.mark.parametrize("grad", [False, True], ids=["fwd", "grad"])
 @pytest.mark.parametrize(
     "shape", [(8, 12, 512, 64), (8, 12, 1024, 64), (2, 16, 4096, 128),
-              (1, 15, 8192, 128)],
-    ids=["t512", "t1024", "ouro-t4096-h128", "hybrid-15-heads-t8192-h128"],
+              (1, 15, 8192, 128), (4, 16, 2048, 256)],
+    ids=["t512", "t1024", "ouro-t4096-h128", "hybrid-15-heads-t8192-h128",
+         "gptj-t2048-h256"],
 )
 def test_flash_attention_compiles_for_v5e(one_chip, real_lowering, shape, grad):
     sds = jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one_chip)
