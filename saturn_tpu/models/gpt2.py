@@ -20,6 +20,7 @@ fused MLP) so XLA tiles them onto the systolic array.
 
 from __future__ import annotations
 
+import functools
 import math
 from dataclasses import dataclass, field, replace
 from typing import Any, Dict, Optional, Tuple
@@ -29,6 +30,10 @@ import jax
 import jax.numpy as jnp
 
 from saturn_tpu.core.modelspec import ModelSpec
+
+
+#: layer kinds that are one mixer and no second half (``MixerBlock``)
+MIXER_KINDS = ("mamba2", "attention_only", "latent_moe")
 
 
 @dataclass(frozen=True)
@@ -184,6 +189,44 @@ class GPT2Config:
     expert_ff: int = 512
     shared_ff: int = 0
     routed_scale: float = 1.0
+    # State-space / latent-expert structure knobs (Nemotron-H-class: every
+    # layer one mixer and no second half, x += Mixer(N(x)), the mixer a
+    # Mamba-2 layer, a softmax-attention layer or a routed-expert layer).
+    # Each at its default leaves every earlier preset's program unchanged op
+    # for op.
+    #   layer_types gains the three kinds of such a stack: "mamba2",
+    #     "attention_only" (causal attention over ``n_kv_heads`` grouped k/v
+    #     heads, ``attn_out``, nothing else) and "latent_moe" (the routed
+    #     layer alone).
+    #   norm_eps: the RMSNorms' epsilon where it is not flax's 1e-6.
+    #   ssm_heads / ssm_groups: a Mamba-2 layer's published heads (of
+    #     ``ssm_head_dim`` lanes; 0 = no such layer) and the groups that share
+    #     B and C (``ssm_state`` wide); ``ssm_conv`` the taps of its depthwise
+    #     causal convolution (with a bias), ``ssm_chunk`` the chunk of
+    #     ``ops/ssd.py``. With ``held_heads`` the layer holds the same share
+    #     of its heads as the attention layers of theirs
+    #     (``ssm_heads x held_heads / n_heads``), whole groups only: the
+    #     gated norm is over a group's lanes, so a share on group boundaries
+    #     computes exactly its heads' part of the uncut layer.
+    #   held_heads with ``n_kv_heads``: the held q heads read the k/v heads
+    #     of their groups (8 of 32 q heads over 2 k/v heads: one k/v head).
+    #   latent_dim: the width the routed experts read and write (0 = the
+    #     stream's): ``latent_down`` before them, ``latent_up`` after; the
+    #     router and the shared expert read the stream.
+    #   expert_act: "swiglu" or "relu2" (``relu(x W1)^2 W2``, no gate), of the
+    #     routed experts and the shared one.
+    #   router_bias: a selection bias (``router_bias``, one scalar an expert)
+    #     added to the scores for the choice only.
+    norm_eps: Optional[float] = None
+    ssm_heads: int = 0
+    ssm_head_dim: int = 64
+    ssm_groups: int = 1
+    ssm_state: int = 128
+    ssm_conv: int = 4
+    ssm_chunk: int = 128
+    latent_dim: int = 0
+    expert_act: str = "swiglu"
+    router_bias: bool = False
     name: str = "gpt2-small"
 
     def __post_init__(self) -> None:
@@ -228,20 +271,24 @@ class GPT2Config:
         if not self.pre_norm and not self.sandwich_norm:
             raise ValueError("pre_norm=False needs sandwich_norm=True "
                              "(a block with no norm at all is not offered)")
-        if self.held_heads is not None and (
-            not 1 <= self.held_heads <= self.n_heads or self.n_kv_heads is not None
-        ):
+        if self.held_heads is not None and not 1 <= self.held_heads <= self.n_heads:
             raise ValueError(
-                f"held_heads must be 1..n_heads ({self.n_heads}) with one k/v "
-                f"head per q head, got {self.held_heads}")
+                f"held_heads must be 1..n_heads ({self.n_heads}), got {self.held_heads}")
+        if self.held_heads is not None and self.n_kv_heads is not None:
+            per = self.n_heads // self.n_kv_heads      # q heads a k/v head
+            if self.held_heads % per and per % self.held_heads:
+                raise ValueError(
+                    f"held_heads ({self.held_heads}) over grouped k/v must be whole "
+                    f"groups of {per} q heads, or a whole part of one group")
         if self.layer_types is not None:
             object.__setattr__(self, "layer_types", tuple(self.layer_types))
             kinds = set(self.layer_types)
             if not kinds or kinds - {"linear_attention", "full_attention",
-                                     "sliding_attention"}:
+                                     "sliding_attention", *MIXER_KINDS}:
                 raise ValueError(
                     f"layer_types holds 'linear_attention' / 'full_attention' "
-                    f"/ 'sliding_attention', got {self.layer_types!r}")
+                    f"/ 'sliding_attention' / {' / '.join(map(repr, MIXER_KINDS))}, "
+                    f"got {self.layer_types!r}")
             if (self.n_layers - self.lead_layers) % len(self.layer_types) != 0:
                 raise ValueError(
                     f"n_layers ({self.n_layers}) less the {self.lead_layers} "
@@ -259,6 +306,25 @@ class GPT2Config:
                 raise ValueError(
                     "a linear-attention layer is causal, dense-MLP and "
                     "single-program (its state crosses the whole sequence)")
+            if kinds & set(MIXER_KINDS) and (
+                not self.causal or self.seq_axis is not None or self.moe
+                or self.lead_layers or self.kind_heads is not None
+            ):
+                raise ValueError(
+                    "a stack of mixer-alone layers is causal and single-program "
+                    "(a state-space layer's state crosses the whole sequence), "
+                    "with no leading layer and one q-head count")
+            if "mamba2" in kinds:
+                per = self.ssm_heads // max(self.ssm_groups, 1)
+                if (self.ssm_heads < 1 or self.ssm_heads % self.ssm_groups
+                        or self.ssm_heads * self.heads_held % self.n_heads
+                        or self.ssm_heads_held % per):
+                    raise ValueError(
+                        f"a mamba2 layer needs ssm_heads ({self.ssm_heads}) in whole "
+                        f"groups ({self.ssm_groups}) and a held share on group "
+                        f"boundaries, got {self.heads_held} of {self.n_heads} heads")
+            if "latent_moe" in kinds and not self.routed_experts:
+                raise ValueError("a latent_moe layer needs routed_experts")
 
         if self.kind_heads is not None:
             object.__setattr__(self, "kind_heads", tuple(
@@ -274,12 +340,15 @@ class GPT2Config:
             object.__setattr__(self, "yarn", tuple(self.yarn))
         if self.routed_experts:
             held = self.experts_held
+            if self.expert_act not in ("swiglu", "relu2"):
+                raise ValueError(f"expert_act must be 'swiglu' or 'relu2', "
+                                 f"got {self.expert_act!r}")
             if (self.layer_types is None or self.moe or self.seq_axis is not None
-                    or self.mlp_act != "swiglu"
+                    or (self.expert_act == "swiglu" and self.mlp_act != "swiglu")
                     or not 1 <= self.top_k <= self.routed_experts
                     or held < 1 or self.routed_experts % held):
                 raise ValueError(
-                    "a routed layer is SwiGLU, in a stack of layer_types, "
+                    "a routed layer is SwiGLU or relu2, in a stack of layer_types, "
                     "single-program, with held_experts a whole share of "
                     f"routed_experts: got top_k {self.top_k}, "
                     f"{self.held_experts} of {self.routed_experts} experts")
@@ -303,6 +372,21 @@ class GPT2Config:
     @property
     def heads_held(self) -> int:
         return self.n_heads if self.held_heads is None else self.held_heads
+
+    @property
+    def kv_heads_held(self) -> Optional[int]:
+        """k/v heads the held q heads read (None: one a q head)."""
+        if self.n_kv_heads is None or self.held_heads is None:
+            return self.n_kv_heads
+        return max(1, self.n_kv_heads * self.held_heads // self.n_heads)
+
+    @property
+    def ssm_heads_held(self) -> int:
+        return self.ssm_heads * self.heads_held // self.n_heads
+
+    @property
+    def ssm_groups_held(self) -> int:
+        return max(1, self.ssm_groups * self.heads_held // self.n_heads)
 
     @property
     def n_periods(self) -> int:
@@ -445,6 +529,38 @@ PRESETS: Dict[str, Dict[str, Any]] = {
         window=24, routed_experts=16, held_experts=4, top_k=4, expert_ff=32,
         shared_ff=32, routed_scale=2.5,
     ),
+    # Nemotron-3-Super (nvidia/NVIDIA-Nemotron-3-Super-120B-A12B-BF16,
+    # ``nemotron_h``): 88 layers, each one mixer alone by the letters of
+    # ``hybrid_override_pattern``: M a Mamba-2 layer (128 heads of 64 in 8
+    # groups, state 128, a 4-tap convolution with bias), E a LatentMoE layer
+    # (512 routed relu2 experts of 2688 in a 1024-wide latent, top-22 under a
+    # selection bias, scaled 5, beside a shared relu2 expert of 5376 on the
+    # stream), * attention (32 q heads over 2 k/v heads of 128, no position
+    # signal). RMSNorm (eps 1e-5) before each mixer, no bias but the
+    # convolution's, an untied head. The published order is not periodic (its
+    # first two attention layers follow 7 and 8 others); the preset is its
+    # first whole period, layers 26..36: E M E M E M E M E M *. Multi-token
+    # prediction is not built (ROADMAP.md, Reach).
+    "nemotron3-super": dict(
+        d_model=4096, n_layers=11, n_heads=32, n_kv_heads=2, head_width=128,
+        vocab_size=131072, norm="rmsnorm", norm_eps=1e-5, use_bias=False,
+        tie_head=False, learned_positions=False,
+        layer_types=("latent_moe", "mamba2") * 5 + ("attention_only",),
+        ssm_heads=128, ssm_head_dim=64, ssm_groups=8, ssm_state=128,
+        ssm_conv=4, ssm_chunk=128, routed_experts=512, top_k=22,
+        expert_ff=2688, shared_ff=5376, routed_scale=5.0, latent_dim=1024,
+        expert_act="relu2", router_bias=True,
+    ),
+    "nemotron-test-tiny": dict(
+        d_model=64, n_layers=11, n_heads=8, n_kv_heads=2, head_width=16,
+        vocab_size=256, seq_len=64, norm="rmsnorm", norm_eps=1e-5,
+        use_bias=False, tie_head=False, learned_positions=False,
+        layer_types=("latent_moe", "mamba2") * 5 + ("attention_only",),
+        ssm_heads=16, ssm_head_dim=8, ssm_groups=8, ssm_state=16,
+        ssm_conv=4, ssm_chunk=16, routed_experts=12, held_experts=4, top_k=3,
+        expert_ff=48, shared_ff=96, routed_scale=5.0, latent_dim=32,
+        expert_act="relu2", router_bias=True,
+    ),
     # Switch-style MoE family (extension beyond the reference; SURVEY.md §2.3
     # lists EP as absent there).
     "moe-test-tiny": dict(
@@ -541,11 +657,35 @@ def resolve_attention(cfg: GPT2Config) -> GPT2Config:
     return replace(cfg, attention="flash" if flash_supported(cfg) else "dense")
 
 
+def _tap_init(taps: int):
+    """A depthwise Conv1d's own initialiser: +-1/sqrt(fan_in)."""
+    def init(key, shape, dtype):
+        bound = 1.0 / math.sqrt(taps)
+        return jax.random.uniform(key, shape, dtype, -bound, bound)
+    return init
+
+
+def _decay_init(key, shape, dtype):
+    """Mamba-2's ``A_log``: decay rates uniform in [1, 16)."""
+    return jnp.log(jax.random.uniform(key, shape, dtype, 1.0, 16.0))
+
+
+def _step_init(key, shape, dtype):
+    """Mamba-2's ``dt_bias``: step sizes log-uniform in [1e-3, 1e-1], through
+    the inverse softplus."""
+    step = jnp.exp(jax.random.uniform(key, shape, dtype,
+                                      math.log(1e-3), math.log(1e-1)))
+    return step + jnp.log(-jnp.expm1(-step))
+
+
 def _norm_cls(cfg: GPT2Config):
     """The ONE place the cfg.norm choice maps to a flax module class —
     Block norms, the model's ln_f, and the pipeline head must stay in
     sync."""
-    return nn.RMSNorm if cfg.norm == "rmsnorm" else nn.LayerNorm
+    cls = nn.RMSNorm if cfg.norm == "rmsnorm" else nn.LayerNorm
+    if cfg.norm_eps is not None:
+        return functools.partial(cls, epsilon=cfg.norm_eps)
+    return cls
 
 
 class Block(nn.Module):
@@ -643,7 +783,7 @@ class Block(nn.Module):
             # Grouped-query attention: k/v carry n_kv_heads; one fused
             # projection sized A + 2 * kv_dim (A = the q heads' lanes: D
             # wherever heads x head_dim is the stream's width).
-            kv_heads = cfg.n_kv_heads
+            kv_heads = cfg.kv_heads_held
             kv_dim = kv_heads * cfg.head_dim
             qkv = dense(A + 2 * kv_dim, "qkv")(h)
             q = qkv[..., :A]
@@ -676,7 +816,7 @@ class Block(nn.Module):
             # params stay at kv_heads — the repeat is activation-only. The
             # flash kernel handles grouped k/v natively (ops/flash.py), so
             # the expanded activations never exist there.
-            rep = (n_q if cfg.kind_heads else cfg.n_heads) // kv_heads
+            rep = n_q // kv_heads
             k = jnp.repeat(k, rep, axis=1)
             v = jnp.repeat(v, rep, axis=1)
         if cfg.seq_axis is not None:
@@ -740,12 +880,8 @@ class Block(nn.Module):
         H, dk, dv, taps = cfg.heads_held, cfg.lin_key_dim, cfg.lin_value_dim, cfg.lin_conv
         f32 = jnp.float32
 
-        def tap_init(key, shape, dtype):   # a Conv1d's own: +-1/sqrt(fan_in)
-            bound = 1.0 / math.sqrt(taps)
-            return jax.random.uniform(key, shape, dtype, -bound, bound)
-
         def conv_silu(t, name):
-            w = self.param(name, tap_init, (taps, t.shape[-1]), pdt).astype(f32)
+            w = self.param(name, _tap_init(taps), (taps, t.shape[-1]), pdt).astype(f32)
             padded = jnp.pad(t.astype(f32), ((0, 0), (taps - 1, 0), (0, 0)))
             return nn.silu(sum(w[j] * padded[:, j:j + T] for j in range(taps)))
 
@@ -761,18 +897,9 @@ class Block(nn.Module):
         gate = dense(H * dv, "lin_gate")(h)
         a = dense(H, "lin_a")(h).astype(f32)
         b = dense(H, "lin_b")(h).astype(f32)
-        # Mamba-2's inits: decay rates in [1, 16), step sizes log-uniform in
-        # [1e-3, 1e-1] through the inverse softplus
-        a_log = self.param(
-            "A_log", lambda key, shape, dtype: jnp.log(
-                jax.random.uniform(key, shape, dtype, 1.0, 16.0)), (H,), pdt)
-
-        def dt_init(key, shape, dtype):
-            step = jnp.exp(jax.random.uniform(key, shape, dtype,
-                                              math.log(1e-3), math.log(1e-1)))
-            return step + jnp.log(-jnp.expm1(-step))
-
-        dt_bias = self.param("dt_bias", dt_init, (H,), pdt)
+        # Mamba-2's inits (``_decay_init``, ``_step_init``)
+        a_log = self.param("A_log", _decay_init, (H,), pdt)
+        dt_bias = self.param("dt_bias", _step_init, (H,), pdt)
         beta = (2.0 if cfg.lin_neg_eigval else 1.0) * jax.nn.sigmoid(b)
         g = -jnp.exp(a_log.astype(f32)) * jax.nn.softplus(a + dt_bias.astype(f32))
         o = gated_delta_rule(
@@ -805,26 +932,96 @@ class Block(nn.Module):
         cfg = self.cfg
         B, T, D = inp.shape
         E, held, F = cfg.routed_experts, cfg.experts_held, cfg.expert_ff
+        L = cfg.latent_dim or D             # the width the experts read and write
+        gated = cfg.expert_act == "swiglu"
         pdt = cfg.param_dtype
         init = nn.initializers.normal(0.02)
         router = self.param("router", init, (D, E), pdt)
-        w_gate = self.param("we_gate", init, (held, D, F), pdt)
-        w_up = self.param("we_up", init, (held, D, F), pdt)
-        w_down = self.param("we_down", init, (held, F, D), pdt)
+        bias = self.param("router_bias", nn.initializers.zeros, (E,), pdt) \
+            if cfg.router_bias else None
+        w_gate = self.param("we_gate", init, (held, L, F), pdt) if gated else None
+        w_up = self.param("we_up", init, (held, L, F), pdt)
+        w_down = self.param("we_down", init, (held, F, L), pdt)
         plan = routed_plan(
             B * T, E, held, cfg.top_k,
-            impl="kernel" if self._attention_impl() == "flash" else "xla")
+            impl="kernel" if self._attention_impl() == "flash" else "xla",
+            act=cfg.expert_act, latent=cfg.latent_dim, bias=cfg.router_bias)
+        latent = dense(L, "latent_down")(inp).reshape(B * T, L) \
+            if cfg.latent_dim else None
         y, stats = routed_experts(
             inp.reshape(B * T, D), router, w_gate, w_up, w_down, plan=plan,
-            scale=cfg.routed_scale, dtype=cfg.dtype)
+            scale=cfg.routed_scale, dtype=cfg.dtype, bias=bias, latent=latent)
         for name, value in stats.items():
             self.sow("moe_stats", name, value)
-        y = y.reshape(B, T, D)
+        y = y.reshape(B, T, L)
+        if cfg.latent_dim:
+            y = dense(D, "latent_up")(y)
         if cfg.shared_ff:
-            m = nn.silu(dense(cfg.shared_ff, "shared_gate")(inp)) \
-                * dense(cfg.shared_ff, "shared_in")(inp)
+            m = dense(cfg.shared_ff, "shared_in")(inp)
+            if gated:
+                m = nn.silu(dense(cfg.shared_ff, "shared_gate")(inp)) * m
+            else:
+                m = jnp.square(nn.relu(m.astype(jnp.float32))).astype(cfg.dtype)
             y = y + dense(D, "shared_out")(m)
         return y
+
+    def _ssm_mixer(self, h, dense):
+        """(B, T, D) -> a Mamba-2 layer's output (B, T, D), the held heads'
+        part (H heads of P lanes in G groups of N-wide B and C):
+
+            [z | xBC | dt] = h W_in            (H P | H P + 2 G N | H)
+            xBC = silu(conv(xBC) + b);  x, B, C = split(xBC)
+            Delta = softplus(dt + dt_bias);  A = -exp(A_log)
+            o = ssd(x, Delta, A, B, C, D)      (``ops/ssd.py``)
+            o = N_group(o * silu(z));  out = o W_out
+
+        The convolution is depthwise and causal (tap ``j`` of ``ssm_conv``
+        multiplies the token ``ssm_conv - 1 - j`` back). ``N_group`` is an
+        RMSNorm over each group's lanes separately with one gain a lane.
+        Gates, norm, decay and the recurrence's state are float32; the
+        projections and the recurrence's products take ``cfg.dtype`` operands.
+        The recurrence runs as the Pallas kernel where the attention
+        implementation is "flash", as the plain chunked scan where it is
+        "dense"."""
+        from saturn_tpu.ops.ssd import ssd
+
+        cfg = self.cfg
+        dt, pdt, f32 = cfg.dtype, cfg.param_dtype, jnp.float32
+        B, T, D = h.shape
+        H, G = cfg.ssm_heads_held, cfg.ssm_groups_held
+        P, N, taps = cfg.ssm_head_dim, cfg.ssm_state, cfg.ssm_conv
+        inner, bc = H * P, G * N
+
+        zxbcdt = dense(2 * inner + 2 * bc + H, "in_proj")(h)
+        z = zxbcdt[..., :inner]
+        xbc = zxbcdt[..., inner:2 * inner + 2 * bc]
+        step = zxbcdt[..., 2 * inner + 2 * bc:].astype(f32)
+        w = self.param("conv_w", _tap_init(taps), (taps, inner + 2 * bc), pdt).astype(f32)
+        b = self.param("conv_b", nn.initializers.zeros, (inner + 2 * bc,), pdt)
+        padded = jnp.pad(xbc.astype(f32), ((0, 0), (taps - 1, 0), (0, 0)))
+        xbc = nn.silu(sum(w[j] * padded[:, j:j + T] for j in range(taps))
+                      + b.astype(f32)).astype(dt)
+        a_log = self.param("A_log", _decay_init, (H,), pdt)
+        dt_bias = self.param("dt_bias", _step_init, (H,), pdt)
+        skip = self.param("D", nn.initializers.ones, (H,), pdt)
+        o = ssd(
+            xbc[..., :inner].reshape(B, T, H, P),
+            jax.nn.softplus(step + dt_bias.astype(f32)),
+            -jnp.exp(a_log.astype(f32)),
+            xbc[..., inner:inner + bc].reshape(B, T, G, N),
+            xbc[..., inner + bc:].reshape(B, T, G, N),
+            skip.astype(f32),
+            impl="kernel" if self._attention_impl() == "flash" else "xla",
+            chunk=cfg.ssm_chunk, published=(cfg.ssm_heads, cfg.ssm_groups),
+        )
+        o = o.reshape(B, T, inner) * nn.silu(z.astype(f32))
+        grouped = o.reshape(B, T, G, inner // G)
+        grouped = grouped * jax.lax.rsqrt(
+            jnp.mean(jnp.square(grouped), axis=-1, keepdims=True)
+            + (1e-6 if cfg.norm_eps is None else cfg.norm_eps))
+        gain = self.param("o_norm", nn.initializers.ones, (inner,), pdt)
+        o = grouped.reshape(B, T, inner) * gain.astype(f32)
+        return dense(D, "out_proj")(o.astype(dt))
 
     def _moe_mlp(self, inp):
         """Expert MLP with explicit (E, ...) weight tables — the leading
@@ -854,6 +1051,35 @@ class Block(nn.Module):
         return y
 
 
+class MixerBlock(Block):
+    """A layer that is one mixer and no second half: ``x += Mixer(ln_1(x))``,
+    the mixer by ``kind`` (``MIXER_KINDS``): a Mamba-2 layer
+    (``_ssm_mixer``), causal softmax attention with ``attn_out``
+    (``_softmax_mixer``) or the routed-expert layer (``_routed_mlp``)."""
+
+    @nn.compact
+    def __call__(self, x, _unused):
+        cfg = self.cfg
+
+        def dense(features, name):
+            return nn.Dense(features, dtype=cfg.dtype, param_dtype=cfg.param_dtype,
+                            use_bias=cfg.use_bias, name=name)
+
+        def make_norm(name):
+            return _norm_cls(cfg)(dtype=cfg.dtype, param_dtype=cfg.param_dtype,
+                                  name=name)
+
+        h = make_norm("ln_1")(x)
+        if self.kind == "mamba2":
+            out = self._ssm_mixer(h, dense)
+        elif self.kind == "latent_moe":
+            out = self._routed_mlp(h, dense)
+        else:
+            out = dense(x.shape[-1], "attn_out")(
+                self._softmax_mixer(h, dense, make_norm))
+        return x + out, None
+
+
 def _remat(block_cls, prevent_cse: bool = False, routed: bool = False):
     """``prevent_cse=False`` is for a block that is a scan's whole body: the
     loop boundary already keeps the backward's recomputation apart from the
@@ -880,10 +1106,13 @@ class PeriodBlock(nn.Module):
     @nn.compact
     def __call__(self, x, _unused):
         ff = "routed" if self.cfg.routed_experts else "dense"
-        block_cls = Block
-        if self.cfg.remat:
-            block_cls = _remat(Block, prevent_cse=True, routed=ff == "routed")
         for i, kind in enumerate(self.cfg.layer_types):
+            alone = kind in MIXER_KINDS
+            block_cls = MixerBlock if alone else Block
+            if self.cfg.remat:
+                block_cls = _remat(
+                    block_cls, prevent_cse=True,
+                    routed=kind == "latent_moe" if alone else ff == "routed")
             x, _ = block_cls(self.cfg, kind=kind, ff=ff, name=f"l{i}")(x, None)
         return x, None
 
@@ -1189,7 +1418,8 @@ def build_gpt2(
         # factory accepts seq_axis/seq_axis_size; the sharded attention +
         # boundary-label loss assume causal next-token training. A linear
         # layer's state crosses the whole sequence: not sequence-parallel.
-        "seq_parallel": cfg.causal and "linear_attention" not in (cfg.layer_types or ()),
+        "seq_parallel": cfg.causal and not (
+            {"linear_attention", "mamba2"} & set(cfg.layer_types or ())),
         "pipeline": {
             "embed": pipeline_embed,
             "block": pipeline_block,
@@ -1251,6 +1481,20 @@ def build_laguna(name: str = "laguna-xs2", **overrides) -> ModelSpec:
     which ``held_experts`` are computed here. Same ``ModelSpec`` contract as
     :func:`build_gpt2`: the scanned unit, and ``hints["pipeline"]``'s
     ``block``, is one period; its ``embed`` runs the leading layer."""
+    return build_gpt2(name, **overrides)
+
+
+def build_nemotron_h(name: str = "nemotron3-super", **overrides) -> ModelSpec:
+    """Nemotron-H factory: periods of layers that are each one mixer alone
+    (``MixerBlock``): Mamba-2 state-space layers (``ops/ssd.py`` behind a
+    4-tap convolution, a gated norm a group), routed-expert layers whose
+    relu2 experts read a latent projection of the stream, chosen top-k under
+    a selection bias beside a shared expert
+    (``ops/moe.py::routed_experts``), and causal attention over grouped k/v
+    heads with no position signal. ``held_heads`` / ``held_experts`` make the
+    program one chip's share of every mixer. Same ``ModelSpec`` contract as
+    :func:`build_gpt2`; the scanned unit, and ``hints["pipeline"]``'s
+    ``block``, is one period."""
     return build_gpt2(name, **overrides)
 
 

@@ -118,6 +118,10 @@ class RoutedPlan:
     row_tile: int
     rows: int        # the row buffer
     worst_rows: int  # the buffer no step can overflow
+    act: str = "swiglu"   # an expert's kind: "swiglu" | "relu2" (no gate)
+    latent: int = 0       # the width the experts read and write, where it
+    #                       is not the stream's (0: the stream's)
+    bias: bool = False    # a selection bias is added to the scores, for the choice
 
     @property
     def second_path(self) -> bool:
@@ -159,12 +163,15 @@ ROW_TILE = 128
 
 def routed_plan(tokens: int, experts: int, held: int, top_k: int, *,
                 buffer: Optional[float] = None, row_tile: Optional[int] = None,
-                impl: str = "xla") -> RoutedPlan:
+                impl: str = "xla", act: str = "swiglu", latent: int = 0,
+                bias: bool = False) -> RoutedPlan:
     """``buffer`` (``BUFFER``) x the mean held pairs (tokens x top_k x held /
     experts), plus a tile an expert for the padding, capped at the worst case
     (every token's every choice held)."""
     if impl not in ("kernel", "xla"):
         raise ValueError(f"impl must be 'kernel' or 'xla', got {impl!r}")
+    if act not in ("swiglu", "relu2"):
+        raise ValueError(f"act must be 'swiglu' or 'relu2', got {act!r}")
     buffer = BUFFER if buffer is None else buffer
     if row_tile is None:
         row_tile = ROW_TILE if tokens * top_k >= experts * ROW_TILE else 8
@@ -172,7 +179,8 @@ def routed_plan(tokens: int, experts: int, held: int, top_k: int, *,
     worst = _round_up(tokens * min(top_k, held), row_tile) + pad
     mean = tokens * top_k * held / experts
     rows = min(worst, _round_up(int(math.ceil(buffer * mean)), row_tile) + pad)
-    return RoutedPlan(impl, tokens, experts, held, top_k, row_tile, rows, worst)
+    return RoutedPlan(impl, tokens, experts, held, top_k, row_tile, rows, worst,
+                      act, latent, bias)
 
 
 def _interpret() -> bool:
@@ -245,9 +253,16 @@ def _gmm_dw_call(x, dy, tile_expert, n_active, held, *, row_tile):
     one, so every block is written)."""
     R, Pw = x.shape
     Qw = dy.shape[1]
-    tp = Pw
-    while tp * Qw * 4 > (2 << 20) and tp % 2 == 0 and tp > 128:
-        tp //= 2
+    if Pw % 128 == 0:
+        # the largest whole part of Pw, in lanes of 128, whose float32 block
+        # keeps to 2 MiB (2688 = 21 x 128 against 1024 columns: 384)
+        lanes = Pw // 128
+        tp = 128 * max(m for m in range(1, lanes + 1)
+                       if lanes % m == 0 and (m == 1 or 128 * m * Qw * 4 <= (2 << 20)))
+    else:   # widths under a lane tile (the CPU tests'): halves
+        tp = Pw
+        while tp * Qw * 4 > (2 << 20) and tp % 2 == 0 and tp > 128:
+            tp //= 2
     last = lambda na: jnp.maximum(na[0] - 1, 0)   # noqa: E731
     return pl.pallas_call(
         _gmm_dw_kernel,
@@ -388,11 +403,19 @@ def routed_layout(local, plan: RoutedPlan) -> Dict[str, Any]:
     return {name: checkpoint_name(leaf, LAYOUT_NAME) for name, leaf in layout.items()}
 
 
-def _swiglu_rows(x, w_gate, w_up, w_down, layout, plan):
-    h = grouped_matmul(x, w_gate, layout, plan)
-    u = grouped_matmul(x, w_up, layout, plan)
-    a = (jax.nn.silu(h.astype(jnp.float32)) * u.astype(jnp.float32)).astype(x.dtype)
-    return grouped_matmul(a, w_down, layout, plan)
+def _relu2(u):
+    return jnp.square(jax.nn.relu(u))
+
+
+def _expert_rows(x, w_gate, w_up, w_down, layout, plan):
+    """The row buffer through its experts: a SwiGLU (three grouped products)
+    or, with no gate, ``relu(x W_up)^2 W_down`` (two)."""
+    u = grouped_matmul(x, w_up, layout, plan).astype(jnp.float32)
+    if w_gate is None:
+        a = _relu2(u)
+    else:
+        a = jax.nn.silu(grouped_matmul(x, w_gate, layout, plan).astype(jnp.float32)) * u
+    return grouped_matmul(a.astype(x.dtype), w_down, layout, plan)
 
 
 def _masked_experts(y, weights, local, w_gate, w_up, w_down):
@@ -400,14 +423,17 @@ def _masked_experts(y, weights, local, w_gate, w_up, w_down):
     weighted by the token's weight for it (0 where it was not chosen). One
     expert at a time, each rematerialised in the backward: held x the dense
     work, and no buffer to overflow."""
-    held = w_gate.shape[0]
+    held = w_up.shape[0]
     f32 = jnp.float32
 
     @jax.checkpoint
     def one(y, wg, wu, wd, m):
-        h = jnp.dot(y, wg.astype(y.dtype), preferred_element_type=f32)
         u = jnp.dot(y, wu.astype(y.dtype), preferred_element_type=f32)
-        a = (jax.nn.silu(h) * u).astype(y.dtype)
+        if wg is None:
+            a = _relu2(u).astype(y.dtype)
+        else:
+            h = jnp.dot(y, wg.astype(y.dtype), preferred_element_type=f32)
+            a = (jax.nn.silu(h) * u).astype(y.dtype)
         return jnp.dot(a, wd.astype(y.dtype), preferred_element_type=f32) * m[:, None]
 
     def step(acc, xs):
@@ -422,15 +448,22 @@ def _masked_experts(y, weights, local, w_gate, w_up, w_down):
 
 def routed_experts(y, router, w_gate, w_up, w_down, *, plan: RoutedPlan,
                    first_expert: int = 0, scale: float = 1.0,
-                   dtype: Any = jnp.bfloat16):
-    """The held experts' part of a top-k routed SwiGLU layer.
+                   dtype: Any = jnp.bfloat16, bias=None, latent=None):
+    """The held experts' part of a top-k routed layer.
 
     ``y`` (T, D); ``router`` (D, experts); ``w_gate`` / ``w_up`` (held, D, F)
     and ``w_down`` (held, F, D): experts ``first_expert .. + held``.
 
         s = sigmoid(y router)                  float32, all the experts
-        I = the top_k largest;  w_e = scale * s_e / sum_{e' in I} s_e'
-        out = sum_{e in I, e held} w_e (silu(y Wg_e) * (y Wu_e)) Wd_e
+        I = the top_k largest of s (+ bias);  w_e = scale * s_e / sum_{e' in I} s_e'
+        out = sum_{e in I, e held} w_e (silu(u Wg_e) * (u Wu_e)) Wd_e,   u = y
+
+    ``w_gate`` None (``plan.act`` "relu2"): an expert is ``relu(u Wu_e)^2
+    Wd_e``, two products. ``bias`` (experts,): a selection bias, added to the
+    scores for the choice only (the weights come from ``s``; its gradient is
+    exactly zero). ``latent`` (T, L): the rows ``u`` the experts read where
+    they are not ``y`` (a projection of it; the router still reads ``y``); the
+    tables are then (held, L, F) / (held, F, L) and the result (T, L).
 
     Every pair whose expert is held is computed: through the row buffer where
     the step's padded rows fit it, through ``_masked_experts`` where they do
@@ -440,9 +473,13 @@ def routed_experts(y, router, w_gate, w_up, w_down, *, plan: RoutedPlan,
     f32 = jnp.float32
     T, D = y.shape
     held = plan.held
-    if (plan.tokens, plan.experts, plan.held) != (T, router.shape[1], w_gate.shape[0]):
-        raise ValueError(f"plan {plan} is not for {T} tokens, "
-                         f"{router.shape[1]} experts, {w_gate.shape[0]} held")
+    if (plan.tokens, plan.experts, plan.held, plan.act == "relu2", plan.bias,
+            plan.latent) != (T, router.shape[1], w_up.shape[0], w_gate is None,
+                             bias is not None, 0 if latent is None else latent.shape[1]):
+        raise ValueError(f"plan {plan} is not for {T} tokens, {router.shape[1]} "
+                         f"experts, {w_up.shape[0]} held, gate {w_gate is not None}, "
+                         f"bias {bias is not None}, latent rows "
+                         f"{None if latent is None else latent.shape}")
     _PLANS.append(plan)
     y = y.astype(dtype)
     scores = jax.nn.sigmoid(jnp.dot(y.astype(f32), router.astype(f32),
@@ -453,25 +490,28 @@ def routed_experts(y, router, w_gate, w_up, w_down, *, plan: RoutedPlan,
     # two scores a rounding apart then change slots (or experts) under tables
     # built for the forward's order, so one expert's weight gradient lands on
     # another's router column
-    chosen = checkpoint_name(jax.lax.top_k(scores, plan.top_k)[1], LAYOUT_NAME)
+    choice = scores if bias is None else scores + jax.lax.stop_gradient(
+        bias.astype(f32))
+    chosen = checkpoint_name(jax.lax.top_k(choice, plan.top_k)[1], LAYOUT_NAME)
     top = jnp.take_along_axis(scores, chosen, axis=-1)
     weights = scale * top / jnp.sum(top, axis=-1, keepdims=True)      # (T, k)
     local = chosen.astype(jnp.int32) - first_expert
     local = jnp.where((local >= 0) & (local < held), local, held)
     layout = routed_layout(local, plan)
 
-    def through_rows(y, weights, w_gate, w_up, w_down):
+    def through_rows(u, weights, w_gate, w_up, w_down):
         tok, valid, pos = layout["tok"], layout["valid"], layout["pos"]
-        x = _to_rows(y, tok, valid, pos)
-        o = _swiglu_rows(x, w_gate, w_up, w_down, layout, plan)
+        x = _to_rows(u, tok, valid, pos)
+        o = _expert_rows(x, w_gate, w_up, w_down, layout, plan)
         w_row = jnp.where(valid, weights.reshape(-1)[layout["pair"]], 0.0)
         o = (o.astype(f32) * w_row[:, None]).astype(dtype)
         return _from_rows(o, tok, valid, pos)
 
-    def through_mask(y, weights, w_gate, w_up, w_down):
-        return _masked_experts(y, weights, local, w_gate, w_up, w_down)
+    def through_mask(u, weights, w_gate, w_up, w_down):
+        return _masked_experts(u, weights, local, w_gate, w_up, w_down)
 
-    operands = (y, weights, w_gate, w_up, w_down)
+    operands = (y if latent is None else latent.astype(dtype), weights,
+                w_gate, w_up, w_down)
     if plan.second_path:
         out = jax.lax.cond(layout["overflow"], through_mask, through_rows, *operands)
         second = layout["overflow"].astype(jnp.int32)

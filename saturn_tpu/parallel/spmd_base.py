@@ -195,6 +195,8 @@ class _Bundle:
     # each window-attention call (ops/flash.py's ``window_plan``) and each
     # causal flash call (its ``flash_plan``)
     moe_plans: Tuple[Any, ...] = ()
+    # likewise each state-space layer's scan (ops/ssd.py's ``SSDPlan``)
+    ssd_plans: Tuple[Any, ...] = ()
     window_plans: Tuple[Any, ...] = ()
     flash_plans: Tuple[Any, ...] = ()
     _lowered: Any = None
@@ -979,6 +981,7 @@ class SPMDTechnique(BaseTechnique):
         from saturn_tpu.ops import flash as _flash
         from saturn_tpu.ops import gdn as _gdn
         from saturn_tpu.ops import moe as _moe
+        from saturn_tpu.ops import ssd as _ssd
 
         trace_count = [0]
 
@@ -988,6 +991,7 @@ class SPMDTechnique(BaseTechnique):
 
         with _ce.traced_plans() as ce_plans, _gdn.traced_plans() as gdn_plans, \
                 _moe.traced_plans() as moe_plans, \
+                _ssd.traced_plans() as ssd_plans, \
                 _flash.traced_window_plans() as window_plans, \
                 _flash.traced_flash_plans() as flash_plans:
             closed, out_shapes = jax.make_jaxpr(
@@ -1047,6 +1051,7 @@ class SPMDTechnique(BaseTechnique):
             ce_plans=tuple(ce_plans),
             gdn_plans=tuple(gdn_plans),
             moe_plans=tuple(moe_plans),
+            ssd_plans=tuple(ssd_plans),
             window_plans=tuple(window_plans),
             flash_plans=tuple(flash_plans),
         )
@@ -1113,6 +1118,10 @@ class SPMDTechnique(BaseTechnique):
                     "%s: config needs %.2f GiB > %.2f GiB HBM — infeasible",
                     self.name, need / 2**30, limit / 2**30,
                 )
+                # on record beside the compiler's own refusals: the next
+                # search neither compiles nor reads this program to weigh it
+                # again (``aot_cache.reject``; nothing where the cache is off)
+                sp.set(recorded=aot_cache.reject(compiled, need, limit))
             return ok
 
     def _memlens_calibration(
@@ -1325,7 +1334,11 @@ class SPMDTechnique(BaseTechnique):
         kernel or plain scan, chunk, grid, the kernel's VMEM sum);
         ``moe_plan`` of a model with routed-expert layers (``ops/moe.py::
         RoutedPlan``: kernel or twin, row tile, buffer rows and the worst
-        case, experts held / all, top-k) and ``window_plan`` of one with
+        case, experts held / all, top-k, the expert's kind, the latent width,
+        whether a selection bias is added), ``ssd_plan`` of one with
+        state-space layers (``ops/ssd.py::SSDPlan``: kernel or twin, chunk,
+        heads and groups held / published, the state bytes a layer keeps for
+        the backward) and ``window_plan`` of one with
         sliding-window layers (``ops/flash.py::window_plan``: window, block,
         key blocks visited and skipped a call); ``flash_plan`` of one whose
         causal attention runs the flash kernels (``ops/flash.py::flash_plan``:
@@ -1345,6 +1358,8 @@ class SPMDTechnique(BaseTechnique):
             out["gdn_plan"] = bundle.gdn_plans[0]._asdict()
         if bundle.moe_plans:   # the first routed layer's (all are alike)
             out["moe_plan"] = bundle.moe_plans[0].as_event()
+        if bundle.ssd_plans:   # the first state-space layer's (all are alike)
+            out["ssd_plan"] = bundle.ssd_plans[0]._asdict()
         if bundle.window_plans:
             out["window_plan"] = dict(bundle.window_plans[0])
         if bundle.flash_plans:   # the first causal call's (a model has one T, D)

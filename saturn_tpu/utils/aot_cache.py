@@ -56,6 +56,13 @@ message, and the next compile of the same program raises
   ``RESOURCE_EXHAUSTED`` from *running* a program (an init, a step) depends
   on what else is on the chip and is never recorded; no other exception is.
   An unreadable or malformed record is a miss, never an error.
+- *The program's own memory rule* (PR 42). A program the compiler accepts
+  and ``SPMDTechnique._fits_compiled`` then rejects (over 0.92 x HBM) is a
+  memory verdict of the same kind, and was the one that still cost a compile
+  or a cache read of 50 MB in every search: :func:`reject` records it as a
+  refusal (the message says whose it is) and takes the executable that
+  compile left in JAX's cache out again, so that a cell's cache holds the
+  programs it runs and not those it only weighs.
 """
 
 from __future__ import annotations
@@ -68,7 +75,8 @@ import pickle
 import platform
 import re
 import threading
-from typing import Any, List, NamedTuple, Optional
+import weakref
+from typing import Any, List, NamedTuple, Optional, Tuple
 
 log = logging.getLogger("saturn_tpu")
 
@@ -313,6 +321,20 @@ def _write_refusal(path: str, prog: _Program, message: str) -> None:
         log.info("compile refusal of %s not recorded at %s", prog.name, path)
 
 
+#: compiled executable -> (its program, where its refusal would be recorded,
+#: the files its compile added to JAX's cache): what :func:`reject` needs
+_compiled_as: "weakref.WeakKeyDictionary[Any, Tuple[_Program, str, List[str]]]" = \
+    weakref.WeakKeyDictionary()
+
+
+def _entry_files(root: str, prog: _Program) -> set:
+    """The files of JAX's cache that are entries of a program of this name."""
+    try:
+        return {n for n in os.listdir(root) if n.startswith(f"{prog.name}-")}
+    except OSError:
+        return set()
+
+
 def _compile(lowered: Any, prog: Optional[_Program]) -> Any:
     """``lowered.compile()``, behind the refusal records."""
     path = _refusal_path(prog)
@@ -324,7 +346,17 @@ def _compile(lowered: Any, prog: Optional[_Program]) -> Any:
                      "not compiled again", prog.name, path)
             raise CompileRefused(recorded, "recorded", prog.name)
     try:
-        return lowered.compile()
+        if path is None:
+            return lowered.compile()
+        root = os.path.dirname(os.path.dirname(path))
+        before = _entry_files(root, prog)
+        compiled = lowered.compile()
+        try:
+            _compiled_as[compiled] = (
+                prog, path, sorted(_entry_files(root, prog) - before))
+        except TypeError:   # an executable that takes no weak reference
+            pass
+        return compiled
     except Exception as e:
         message = str(e)
         if _REFUSAL_MARK not in message:
@@ -334,6 +366,32 @@ def _compile(lowered: Any, prog: Optional[_Program]) -> Any:
             _write_refusal(path, prog, message)
         raise CompileRefused(
             message, "fresh", prog.name if prog else None) from e
+
+
+def reject(compiled: Any, need_bytes: int, limit_bytes: int) -> bool:
+    """The program's own memory rule refused ``compiled`` (a program of
+    :func:`load_or_compile` that the compiler accepted): record it as a
+    refusal, so that the next search raises :class:`CompileRefused` where it
+    would have compiled or read 50 MB to weigh it again, and take the
+    executable that compile wrote to JAX's cache out (it will not be run).
+    False where nothing was recorded: the compile cache is off, or the
+    executable is not one of this process's compiles."""
+    try:
+        prog, path, files = _compiled_as.pop(compiled)
+    except (KeyError, TypeError):
+        return False
+    _write_refusal(path, prog, (
+        f"{_REFUSAL_MARK}: the program's memory rule: compiled, "
+        f"{prog.name} needs {need_bytes / 2 ** 30:.3f} GiB, over 0.92 x "
+        f"{limit_bytes / 2 ** 30:.3f} GiB of HBM (the compiler accepted it; "
+        f"recorded by saturn_tpu's memory check)"))
+    root = os.path.dirname(os.path.dirname(path))
+    for name in files:
+        try:
+            os.unlink(os.path.join(root, name))
+        except OSError:
+            pass
+    return True
 
 
 def _path(key: str) -> str:

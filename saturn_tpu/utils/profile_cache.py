@@ -503,5 +503,17 @@ def maybe_enable_persistent_compile_cache() -> Optional[str]:
     if path is not None:
         jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.5)
         jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
+        # A Pallas kernel's payload carries the source locations of its
+        # trace, ten frames of traceback each by default: a backward kernel
+        # is traced four frames under ``_build_uncached``, so the same step
+        # program traced under ``search`` and under an interval's launch (or
+        # a reference check's ``execute``) had two texts and two cache
+        # entries of 47-59 MB (PR 36's second ``jit_saturn_window`` entry,
+        # "not found why"; found in PR 42: ``tests/test_tpu_compile.py``).
+        # Four frames keep the callers out, and the kernels their names in a
+        # device trace (one frame a location, which
+        # ``jax_include_full_tracebacks_in_locations`` offers, renames every
+        # kernel ``tpu_custom_call.N`` there: my chip run, PR 42).
+        jax.config.update("jax_traceback_in_locations_limit", 4)
         log.info("jax persistent compilation cache at %s", path)
     return path

@@ -486,3 +486,64 @@ def test_a_refusal_from_running_is_an_error_and_is_not_recorded(
                for e in events if e["kind"] == "trial_config")
     assert {e["outcome"] for e in events if e["kind"] == "trial.config"} == {"error"}
     assert records(store) == []
+
+
+# ------------------------------------- the program's own memory rule (PR 42)
+class Weighed(Lowered):
+    """A program the compiler accepts, whose compile leaves an entry in
+    JAX's cache directory as the persistent cache does."""
+
+    class Executable:
+        pass
+
+    def __init__(self, root, **kw):
+        super().__init__(raises=None, **kw)
+        self.root = root
+
+    def compile(self):
+        super().compile()
+        for suffix in ("-cache", "-atime"):
+            (self.root / f"jit_saturn_window-0123abcd{suffix}").write_bytes(b"x")
+        return self.Executable()
+
+
+def test_a_program_the_memory_rule_rejects_is_recorded_and_its_entry_removed(store):
+    root = store.parent
+    (root / "jit_saturn_window-other-cache").write_bytes(b"y")   # another program's
+    low = Weighed(root)
+    exe = aot_cache.load_or_compile(low)
+    assert low.compiles == 1 and sorted(os.listdir(root)) == [
+        "jit_saturn_window-0123abcd-atime", "jit_saturn_window-0123abcd-cache",
+        "jit_saturn_window-other-cache"]
+    assert aot_cache.reject(exe, 15 * 2 ** 30, 15.75 * 2 ** 30) is True
+    assert sorted(os.listdir(root)) == ["jit_saturn_window-other-cache", "saturn-refused"]
+    (record,) = records(store)
+    with open(store / record) as f:
+        said = json.load(f)["message"]
+    assert said.startswith("RESOURCE_EXHAUSTED: the program's memory rule")
+    assert "15.000 GiB" in said and "0.92 x 15.750 GiB" in said
+    fresh0, replayed0 = counts()
+    with pytest.raises(CompileRefused) as err:      # the next search: not compiled, not read
+        aot_cache.load_or_compile(Weighed(root))
+    assert err.value.refusal == "recorded" and "memory rule" in err.value.first_line
+    assert counts() == (fresh0, replayed0 + 1)
+    assert aot_cache.reject(exe, 1, 1) is False      # said once
+    assert aot_cache.reject("executable", 1, 1) is False   # not a compile of this process
+
+
+def test_the_memory_check_records_what_it_rejects(store, monkeypatch, tmp_path):
+    from saturn_tpu.parallel import spmd_base
+    from saturn_tpu.parallel.dp import DataParallel
+
+    exe = aot_cache.load_or_compile(Weighed(store.parent))
+    monkeypatch.setattr(spmd_base, "device_hbm_bytes", lambda d: 16 * 2 ** 30)
+    monkeypatch.setattr(spmd_base, "hbm_bytes_required", lambda c: 15 * 2 ** 30)
+    events = str(tmp_path / "ev.jsonl")
+    with metrics.scoped(events):
+        assert DataParallel()._fits_compiled(exe, [object()]) is False
+    (span,) = metrics.read_events(events, kind="trial.memory_check")
+    assert span["recorded"] is True and len(records(store)) == 1
+    monkeypatch.setattr(spmd_base, "hbm_bytes_required", lambda c: 14 * 2 ** 30)
+    assert DataParallel()._fits_compiled(aot_cache.load_or_compile(
+        Weighed(store.parent, body="%0 = mul")), [object()]) is True
+    assert len(records(store)) == 1

@@ -148,6 +148,95 @@ def test_gated_delta_rule_kernel_compiles_for_v5e(one_chip, real_lowering, grad)
         _compile(loss, *args, kernels=["saturn_gdn_fwd_only"])
 
 
+# ------------------------------------------- a kernel program's cache key
+@pytest.mark.parametrize("frames", [10, 4], ids=["ten-frames", "four-frames"])
+def test_a_kernel_programs_text_holds_its_callers_unless_locations_are_short(
+        one_chip, real_lowering, monkeypatch, frames):
+    """A Pallas kernel's payload carries its trace's source locations, ten
+    frames of traceback each by default: the same program lowered under
+    another caller is then another text, so another entry of the compile
+    cache (two ``jit_saturn_window`` entries a cell, PR 36). At four frames,
+    which ``maybe_enable_persistent_compile_cache`` sets wherever the cache
+    is on, the text is the program's alone and the compiled kernel keeps its
+    name (what a device trace and every roofline reader know it by)."""
+    from saturn_tpu.ops import ssd as ssd_mod
+
+    monkeypatch.setattr(ssd_mod, "_use_interpret", lambda: False)
+    sds = lambda *shape, dtype=jnp.bfloat16: jax.ShapeDtypeStruct(
+        shape, dtype, sharding=one_chip)
+    f32 = jnp.float32
+    args = (sds(1, 256, 2, 64), sds(1, 256, 2, dtype=f32), sds(2, dtype=f32),
+            sds(1, 256, 1, 128), sds(1, 256, 1, 128), sds(2, dtype=f32))
+
+    def lowered():
+        # (a new function each time: a jit of the same one answers from its
+        # trace cache; the flash kernels' bodies are traced once a shape, PR 41)
+        return jax.jit(lambda *a: ssd_mod.ssd(*a, impl="kernel")).lower(*args)
+
+    def under_another_caller():
+        return (lambda: lowered())()
+
+    before = jax.config.jax_traceback_in_locations_limit
+    jax.config.update("jax_traceback_in_locations_limit", frames)
+    try:
+        one, other = lowered(), under_another_caller()
+        same = one.as_text() == other.as_text()
+        compiled = one.compile().as_text()
+    finally:
+        jax.config.update("jax_traceback_in_locations_limit", before)
+    assert same == (frames == 4)
+    assert "%saturn_ssd_fwd_only" in compiled
+
+
+# ------------------------------------------------ state-space recurrence
+@pytest.mark.parametrize("grad", [False, True], ids=["fwd", "grad"])
+def test_ssd_kernel_compiles_for_v5e(one_chip, real_lowering, monkeypatch, grad):
+    """``saturn_ssd_fwd`` / ``saturn_ssd_fwd_only`` at the Nemotron cell's own
+    shape: 32 heads of 64 lanes in 2 groups of a 128-wide state, 8192 tokens
+    in chunks of 128, bf16 operands, sixteen float32 states in VMEM, the
+    group's heads walked by a loop inside the kernel."""
+    from saturn_tpu.ops import ssd as ssd_mod
+
+    monkeypatch.setattr(ssd_mod, "_use_interpret", lambda: False)
+    sds = lambda *shape, dtype=jnp.bfloat16: jax.ShapeDtypeStruct(
+        shape, dtype, sharding=one_chip)
+    f32 = jnp.float32
+    args = (sds(1, 8192, 32, 64), sds(1, 8192, 32, dtype=f32), sds(32, dtype=f32),
+            sds(1, 8192, 2, 128), sds(1, 8192, 2, 128), sds(32, dtype=f32))
+
+    def loss(*a):
+        return jnp.sum(ssd_mod.ssd(*a, impl="kernel"))
+
+    if grad:   # the differentiated forward keeps the chunks' states; the backward is XLA's
+        text = _compile(jax.grad(loss, argnums=(0, 1, 2, 3, 4, 5)), *args,
+                        kernels=["saturn_ssd_fwd"])
+        assert "saturn_ssd_fwd_only" not in text
+    else:
+        _compile(loss, *args, kernels=["saturn_ssd_fwd_only"])
+
+
+def test_routed_layer_kernels_compile_for_v5e_at_a_latent_width(one_chip, real_lowering):
+    """The grouped products at the Nemotron cell's shapes: 8 held relu2
+    experts of 1024 x 2688 (2688 = 21 x 128: the table gradient's block keeps
+    to whole lanes of 128), two products an expert, a 6656-row buffer."""
+    plan = moe_mod.routed_plan(8192, 512, 8, 22, impl="kernel", act="relu2",
+                               latent=1024, bias=True)
+    assert (plan.rows, plan.row_tile) == (6656, 128)
+    sds = lambda *shape, dtype=jnp.bfloat16: jax.ShapeDtypeStruct(
+        shape, dtype, sharding=one_chip)
+
+    def loss(y, latent, router, bias, w_up, w_down):
+        out, _ = moe_mod.routed_experts(y, router, None, w_up, w_down, plan=plan,
+                                        scale=5.0, bias=bias, latent=latent)
+        return jnp.sum(out.astype(jnp.float32))
+
+    _compile(jax.grad(loss, argnums=(0, 1, 2, 4, 5)),
+             sds(8192, 4096), sds(8192, 1024), sds(4096, 512, dtype=jnp.float32),
+             sds(512, dtype=jnp.float32), sds(8, 1024, 2688, dtype=jnp.float32),
+             sds(8, 2688, 1024, dtype=jnp.float32),
+             kernels=["saturn_gmm_fwd", "saturn_gmm_dx", "saturn_gmm_dw"])
+
+
 # ---------------------------------------------------------------- fused CE
 CE_SHAPES = {
     "gpt2-small": (4096, 768, 50257),
