@@ -3,6 +3,8 @@
 The reference measured wall-clock for batch 2 of 2 so that CUDA warmup was
 excluded (``FSDP.py:140-149``). Under jit the analog is: compile once (first
 call), ``block_until_ready`` to sync, then time ``n`` steady-state steps.
+A fused window that lasts 2 s or more is, like the reference's batch, timed
+once after its warm-up (``_ONE_WINDOW_FLOOR_S``, ``time_fused_window``).
 """
 
 from __future__ import annotations
@@ -13,7 +15,7 @@ import logging
 import sys
 import threading
 import timeit
-from typing import Callable, Tuple
+from typing import Callable, Optional
 
 import jax
 
@@ -21,6 +23,20 @@ log = logging.getLogger("saturn_tpu")
 
 #: the interpreter's switch interval while a clock runs (its default is 5 ms)
 _SWITCH_INTERVAL_S = 0.0005
+#: a fused window whose warm-up call lasted this long is timed once, not twice.
+#: What is left to disturb a timed region's last clock reading is a C call of
+#: another thread that keeps the GIL, 20 ms (``undisturbed_clock``): 1 % of
+#: 2 s, so a second window only repeats the first (``step_ms`` repeats to four
+#: digits from run to run where a window is 2.4-9.5 s). Taken on the warm-up's
+#: seconds, which hold the program's first call (its load; on several chips
+#: the first collectives) and so read longer than the windows that follow:
+#: from inside one fused call the load cannot be told from the steps.
+_ONE_WINDOW_FLOOR_S = 2.0
+#: windows of a fused program timed after its warm-up under that floor
+_TIMED_WINDOWS = 2
+#: stacks ``time_fused_window`` asks its ``stage`` for at one warm-up call:
+#: ``_measure`` stages as many before the clock starts
+FUSED_WINDOW_STACKS = 1 + _TIMED_WINDOWS
 #: the collector's third threshold while a clock runs: no full collection
 _NO_FULL_COLLECTION = 1 << 30
 _clocks_lock = threading.Lock()
@@ -90,7 +106,7 @@ def time_train_step(
 
 def time_fused_window(
     fused: Callable, state, stage: Callable[[int], object], k: int,
-    n_timed: int = 2, n_warmup: int = 1,
+    n_warmup: int = 1, note: Optional[Callable[..., None]] = None,
 ) -> float:
     """Mean seconds per BATCH for a fused K-step window program.
 
@@ -103,13 +119,33 @@ def time_fused_window(
     device program alone — timing the transfers would hand the MILP
     per-batch numbers execute() never exhibits. Requires ``n_warmup >= 1``
     (the warmup call doubles as the compile + sync fence).
+
+    How many windows are timed is decided by what the warm-up shows, not by
+    the caller: where a warm-up call lasted ``_ONE_WINDOW_FLOOR_S`` (2 s) or
+    more, ONE window is timed, else ``_TIMED_WINDOWS`` (two). The reference
+    timed one batch after one warm-up batch; a window of seconds on the
+    device alone reads the same twice, while one of 0.6-0.9 s can decide a
+    contest of 1 % and keeps its second reading. The warm-up is never the
+    reading (it holds the program's first call), and the count is fixed
+    before the timed region starts: the timed calls go out back to back and
+    are fenced once, since a fence between two of them would put a dispatch
+    gap into the second. ``n_warmup + _TIMED_WINDOWS`` stacks are staged
+    either way (``FUSED_WINDOW_STACKS`` at one warm-up call); where one
+    window is timed the last is not offered. ``note(n_timed=...,
+    warmup_s=...)`` is told the count really timed and the seconds a warm-up
+    call lasted (the ``trial.timing`` span's fields).
     """
     if n_warmup < 1:
         raise ValueError("time_fused_window needs n_warmup >= 1")
-    windows = [stage(j) for j in range(n_warmup + n_timed)]
+    windows = [stage(j) for j in range(n_warmup + _TIMED_WINDOWS)]
+    t0 = timeit.default_timer()
     for j in range(n_warmup):
         state, aux = fused(state, windows[j])
     jax.device_get(aux)
+    warmup_s = (timeit.default_timer() - t0) / n_warmup
+    n_timed = 1 if warmup_s >= _ONE_WINDOW_FLOOR_S else _TIMED_WINDOWS
+    if note is not None:
+        note(n_timed=n_timed, warmup_s=round(warmup_s, 6))
     with undisturbed_clock():
         t0 = timeit.default_timer()
         for j in range(n_warmup, n_warmup + n_timed):

@@ -679,6 +679,99 @@ def test_a_timed_region_keeps_full_collections_and_long_slices_out():
     assert clock_settings() == before
 
 
+# ------------------------------ how many windows a point that fits is timed for
+WARMUP_S, WINDOW_S = 0.08, 0.02      # what the fake program's calls sleep
+FLOORS = {"past-the-floor": (WARMUP_S / 2, 1), "under-the-floor": (60.0, 2)}
+
+
+@pytest.mark.parametrize("case", [*FLOORS, "no-warmup"])
+def test_the_warmup_decides_how_many_windows_are_timed(case, monkeypatch):
+    """A window whose warm-up call lasted the floor or more is timed once,
+    a shorter one twice; either way under the quiet clock, each stack
+    offered once, and the seconds a batch those of the windows timed."""
+    from saturn_tpu.utils import timing
+
+    if case == "no-warmup":
+        with pytest.raises(ValueError, match="n_warmup >= 1"):
+            timing.time_fused_window(lambda s, w: (s, 0), None, lambda j: j,
+                                     8, n_warmup=0)
+        return
+    floor, n_timed = FLOORS[case]
+    monkeypatch.setattr(timing, "_ONE_WINDOW_FLOOR_S", floor)
+    before = clock_settings()
+    quiet = ((*before[0][:2], timing._NO_FULL_COLLECTION),
+             timing._SWITCH_INTERVAL_S)
+    staged, offered, seen, starts, told = [], [], [], [], {}
+
+    def fused(state, window):
+        starts.append(time.perf_counter())
+        offered.append(window)
+        seen.append(clock_settings())
+        time.sleep(WARMUP_S if len(offered) == 1 else WINDOW_S)
+        return state, np.zeros((8,), np.float32)
+
+    t = timing.time_fused_window(
+        fused, None, lambda j: staged.append(j) or j, 8,
+        note=lambda **fields: told.update(fields))
+    end = time.perf_counter()
+    # staged before anything ran, each offered once, the warm-up's first
+    assert staged == [0, 1, 2] and offered == staged[:1 + n_timed]
+    assert seen == [before] + [quiet] * n_timed
+    assert clock_settings() == before
+    # the timed windows' seconds over their batches: the clock starts after
+    # the warm-up, and one window is not divided by two windows' batches
+    assert WINDOW_S / 8 <= t <= (end - starts[1]) / (8 * n_timed)
+    assert told["n_timed"] == n_timed and told["warmup_s"] >= WARMUP_S
+
+
+#: the clock's four readings (round the warm-up, round the timed region),
+#: warm-up calls, and the windows that should then be timed
+CLOCKED = {
+    "exactly-the-floor": ([0.0, 2.0, 5.0, 9.0], 1, 1),
+    "a-hair-under-it": ([0.0, 1.999, 5.0, 9.0], 1, 2),
+    # two warm-up calls of 1.5 s each: a call lasted 1.5 s, not 3
+    "by-the-call-not-the-sum": ([0.0, 3.0, 5.0, 9.0], 2, 2),
+}
+
+
+@pytest.mark.parametrize("case", list(CLOCKED))
+def test_the_floor_is_two_seconds_of_one_warmup_call(case, monkeypatch):
+    """On a scripted clock, against the constant as the package has it."""
+    from saturn_tpu.utils import timing
+
+    readings, n_warmup, n_timed = CLOCKED[case]
+    clock = iter(readings)
+    monkeypatch.setattr(timing, "timeit", types.SimpleNamespace(
+        default_timer=lambda: next(clock)))
+    calls, told = [], {}
+    t = timing.time_fused_window(
+        lambda state, w: (calls.append(w) or state, np.zeros(())), None,
+        lambda j: j, 8, n_warmup=n_warmup,
+        note=lambda **fields: told.update(fields))
+    assert calls == list(range(n_warmup + n_timed))
+    assert t == pytest.approx(4.0 / (8 * n_timed))
+    assert told == {"n_timed": n_timed,
+                    "warmup_s": pytest.approx(readings[1] / n_warmup)}
+    assert next(clock, None) is None    # four readings, no fence in between
+
+
+@pytest.mark.parametrize("case", list(FLOORS))
+def test_the_timing_span_says_how_many_windows_were_timed(case, run,
+                                                          monkeypatch):
+    from saturn_tpu.utils import timing
+
+    floor, n_timed = FLOORS[case]
+    monkeypatch.setattr(timing, "_ONE_WINDOW_FLOOR_S", floor)
+    tech, (config, t), _, events = run(
+        [{"id": "only", "remat": True, "step_s": WARMUP_S}])
+    assert config == {"id": "only", "remat": True}
+    assert tech.book.order("step") == ["only"] * (1 + n_timed)
+    assert WARMUP_S / 8 <= t
+    (timed,) = of_kind(events, "trial.timing")
+    assert (timed["k"], timed["n_timed"]) == (8, n_timed)
+    assert WARMUP_S <= timed["warmup_s"] < timed["dur_s"]
+
+
 def test_clocks_side_by_side_put_the_settings_back_once():
     from saturn_tpu.utils import timing
 

@@ -487,6 +487,14 @@ def tiny_run(tmp_path_factory, devices8):
             tasks, technique_names=["dp"], topology=topo,
             metrics_path=ev["search"], profile_cache=False, parallel_trials=2)
         assert stats["errors"] == 0 and stats["trials_run"] == 2
+        # the window the tests read is two gangs side by side. On a loaded
+        # host the two trial threads can time their jobs slow enough for the
+        # stack of both, priced alone after them, to win the plan (then no
+        # ``launch.*`` span: nine tests fail together); an unpriced stack is
+        # never fused, and the fused launcher has its own phase below
+        for task in tasks:
+            for strategy in task.strategies.values():
+                strategy.fused_per_batch_time = None
         result = saturn_tpu.orchestrate(
             tasks, interval=60.0, topology=topo, metrics_path=ev["window"],
             solver_time_limit=2.0, resume_dir=str(root / "journal"))
