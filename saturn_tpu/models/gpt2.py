@@ -227,6 +227,41 @@ class GPT2Config:
     latent_dim: int = 0
     expert_act: str = "swiglu"
     router_bias: bool = False
+    # Delta-rule / latent-attention structure knobs (Ling-class: periods of
+    # Kimi-delta-attention layers and one latent-attention layer, each before
+    # a dense or routed feed-forward). Each at its default leaves every
+    # earlier preset's program unchanged op for op.
+    #   layer_types gains "kda" (the delta rule with a decay a key channel,
+    #     ``ops/kda.py``, behind three short convolutions: heads of
+    #     ``head_dim`` keys and values, ``lin_conv`` taps, the gate
+    #     ``kda_gate_floor x sigmoid(exp(A_log) (h W_a + dt_bias))``) and "mla"
+    #     (latent attention: a ``kv_latent``-wide normed projection of the
+    #     stream gives every head its ``qk_nope_dim`` content key lanes and
+    #     ``v_head_dim`` value lanes; beside it ``qk_rope_dim`` rotary lanes
+    #     of one key shared by the heads; q of ``qk_nope_dim + qk_rope_dim``
+    #     lanes a head; with ``head_qk_norm`` an RMSNorm over each head's q and
+    #     k lanes, one gain shared by the heads, before the rotation at
+    #     ``rope_theta``). Both take ``attn_gate`` and ``held_heads``.
+    #   lead_kind: the mixer of the leading dense layers ("full_attention"
+    #     or "kda").
+    #   route_groups / route_groups_kept: the routed layer's group limit
+    #     (``ops/moe.py::limited_choice``; 0 = none); routed_buffer: its row
+    #     buffer as a multiple of the mean held pairs (None: ``ops/moe.py::
+    #     BUFFER``).
+    #   swiglu_limit: the largest published clamp of an expert among the
+    #     layers held (a cell's configuration passes it); only 0 (none) is
+    #     built.
+    kv_latent: int = 0
+    qk_nope_dim: int = 128
+    qk_rope_dim: int = 64
+    v_head_dim: int = 128
+    head_qk_norm: bool = False
+    kda_gate_floor: float = -5.0
+    lead_kind: str = "full_attention"
+    route_groups: int = 0
+    route_groups_kept: int = 0
+    routed_buffer: Optional[float] = None
+    swiglu_limit: float = 0.0
     name: str = "gpt2-small"
 
     def __post_init__(self) -> None:
@@ -284,10 +319,12 @@ class GPT2Config:
             object.__setattr__(self, "layer_types", tuple(self.layer_types))
             kinds = set(self.layer_types)
             if not kinds or kinds - {"linear_attention", "full_attention",
-                                     "sliding_attention", *MIXER_KINDS}:
+                                     "sliding_attention", "kda", "mla",
+                                     *MIXER_KINDS}:
                 raise ValueError(
                     f"layer_types holds 'linear_attention' / 'full_attention' "
-                    f"/ 'sliding_attention' / {' / '.join(map(repr, MIXER_KINDS))}, "
+                    f"/ 'sliding_attention' / 'kda' / 'mla' / "
+                    f"{' / '.join(map(repr, MIXER_KINDS))}, "
                     f"got {self.layer_types!r}")
             if (self.n_layers - self.lead_layers) % len(self.layer_types) != 0:
                 raise ValueError(
@@ -306,6 +343,16 @@ class GPT2Config:
                 raise ValueError(
                     "a linear-attention layer is causal, dense-MLP and "
                     "single-program (its state crosses the whole sequence)")
+            if kinds & {"kda", "mla"} and (
+                not self.causal or self.seq_axis is not None or self.moe
+                or self.n_kv_heads is not None or self.kind_heads is not None
+                or self.rotary or kinds & set(MIXER_KINDS)
+                or ("mla" in kinds and (self.kv_latent < 1 or self.qk_rope_dim % 2))
+            ):
+                raise ValueError(
+                    "a kda / mla layer is causal and single-program, one k/v "
+                    "head a q head, with its own rotary (rotary=False) on an "
+                    "even qk_rope_dim beside kv_latent >= 1 lanes of latent")
             if kinds & set(MIXER_KINDS) and (
                 not self.causal or self.seq_axis is not None or self.moe
                 or self.lead_layers or self.kind_heads is not None
@@ -336,6 +383,14 @@ class GPT2Config:
                     f"that divide each count, got {self.kind_heads!r}")
         if self.lead_layers and self.layer_types is None:
             raise ValueError("lead_layers precede a stack of layer_types")
+        if self.lead_kind not in ("full_attention", "kda"):
+            raise ValueError(f"lead_kind must be 'full_attention' or 'kda', "
+                             f"got {self.lead_kind!r}")
+        if self.swiglu_limit:
+            raise ValueError(
+                f"swiglu_limit {self.swiglu_limit}: an expert's clamp is not built "
+                "(the published configuration names the limit and not the "
+                "clamp's form; the layers held have 0: ROADMAP.md, Reach)")
         if self.yarn is not None:
             object.__setattr__(self, "yarn", tuple(self.yarn))
         if self.routed_experts:
@@ -405,7 +460,7 @@ class GPT2Config:
     def stack_lead(self) -> Optional[Dict[str, int]]:
         """Layers before the scanned periods, by kind (full attention with
         the dense MLP); None where the stack has none."""
-        return {"full_attention_dense": self.lead_layers} if self.lead_layers else None
+        return {self.lead_kind + "_dense": self.lead_layers} if self.lead_layers else None
 
     def example_inputs(self, batch_size: int = 1):
         return jnp.zeros((batch_size, self.seq_len), dtype=jnp.int32)
@@ -561,6 +616,45 @@ PRESETS: Dict[str, Dict[str, Any]] = {
         expert_ff=48, shared_ff=96, routed_scale=5.0, latent_dim=32,
         expert_act="relu2", router_bias=True,
     ),
+    # Ling-3.0-flash (inclusionAI/Ling-3.0-flash-VL, the language model):
+    # two leading dense layers (KDA + SwiGLU 6144), then periods of six
+    # layers in the published order from layer 2 on: KDA, KDA, KDA, MLA, KDA,
+    # KDA (the MLA layers are the published 5, 11, ..: every sixth), each
+    # before a shared expert beside 512 routed ones (top-8 among the experts
+    # of the 4 best of 8 groups, sigmoid scores under a selection bias,
+    # normalised and scaled 2.5), each a SwiGLU of 768. KDA: 32 heads of 128
+    # keys and values, three 4-tap convolutions, a gate a key channel bounded
+    # at -5, a gate a head on the output. MLA: 32 heads, q and k of 128 + 64
+    # rotary lanes (base 6e6), v of 128, from a 512-wide latent, an RMSNorm on
+    # each head's q and k. RMSNorm before each branch, no bias, an untied
+    # head, no position table. A stack is 2 + 6 p layers: 38 here, the
+    # published 42 less the four layers that follow the last whole period (a
+    # trailing part of a period is not built: ROADMAP.md, Reach). Neither
+    # the vision tower nor multi-token prediction is built.
+    "ling3-flash": dict(
+        d_model=2560, n_layers=38, n_heads=32, head_width=128, d_ff=6144,
+        vocab_size=157184, norm="rmsnorm", mlp_act="swiglu", use_bias=False,
+        tie_head=False, learned_positions=False, attn_gate=True,
+        lead_layers=2, lead_kind="kda",
+        layer_types=("kda",) * 3 + ("mla",) + ("kda",) * 2,
+        kv_latent=512, qk_nope_dim=128, qk_rope_dim=64, v_head_dim=128,
+        head_qk_norm=True, rope_theta=6e6, lin_conv=4, kda_gate_floor=-5.0,
+        routed_experts=512, top_k=8, expert_ff=768, shared_ff=768,
+        routed_scale=2.5, router_bias=True, route_groups=8,
+        route_groups_kept=4,
+    ),
+    "ling-test-tiny": dict(
+        d_model=64, n_layers=7, n_heads=4, head_width=16, d_ff=128,
+        vocab_size=256, seq_len=128, norm="rmsnorm", mlp_act="swiglu",
+        use_bias=False, tie_head=False, learned_positions=False,
+        attn_gate=True, lead_layers=1, lead_kind="kda",
+        layer_types=("kda",) * 3 + ("mla",) + ("kda",) * 2,
+        kv_latent=32, qk_nope_dim=16, qk_rope_dim=8, v_head_dim=16,
+        head_qk_norm=True, rope_theta=6e6, lin_conv=4, kda_gate_floor=-5.0,
+        routed_experts=16, held_experts=4, top_k=4,
+        expert_ff=32, shared_ff=32, routed_scale=2.5, router_bias=True,
+        route_groups=4, route_groups_kept=2,
+    ),
     # Switch-style MoE family (extension beyond the reference; SURVEY.md §2.3
     # lists EP as absent there).
     "moe-test-tiny": dict(
@@ -678,6 +772,18 @@ def _step_init(key, shape, dtype):
     return step + jnp.log(-jnp.expm1(-step))
 
 
+def _gate_bias_init(key, shape, dtype):
+    """A KDA layer's ``dt_bias``: the gate's logit about -3.9 (a decay of
+    exp(-0.1) a token), spread so that a channel in a hundred forgets
+    faster than exp(-2)."""
+    return -3.9 + 1.1 * jax.random.normal(key, shape, dtype)
+
+
+def _unit(t):
+    """``t`` over its l2 norm along the last axis (a head's lanes)."""
+    return t * jax.lax.rsqrt(jnp.sum(t * t, axis=-1, keepdims=True) + 1e-6)
+
+
 def _norm_cls(cfg: GPT2Config):
     """The ONE place the cfg.norm choice maps to a flax module class —
     Block norms, the model's ln_f, and the pipeline head must stay in
@@ -727,6 +833,10 @@ class Block(nn.Module):
         h = make_norm("ln_1")(x) if cfg.pre_norm else x
         if self.kind == "linear_attention":
             attn = self._linear_mixer(h, dense)
+        elif self.kind == "kda":
+            attn = self._kda_mixer(h, dense)
+        elif self.kind == "mla":
+            attn = self._mla_mixer(h, dense, make_norm)
         else:
             attn = self._softmax_mixer(h, dense, make_norm)
         attn = dense(D, "attn_out")(attn)
@@ -877,23 +987,12 @@ class Block(nn.Module):
         cfg = self.cfg
         dt, pdt = cfg.dtype, cfg.param_dtype
         B, T, _ = h.shape
-        H, dk, dv, taps = cfg.heads_held, cfg.lin_key_dim, cfg.lin_value_dim, cfg.lin_conv
+        H, dk, dv = cfg.heads_held, cfg.lin_key_dim, cfg.lin_value_dim
         f32 = jnp.float32
 
-        def conv_silu(t, name):
-            w = self.param(name, _tap_init(taps), (taps, t.shape[-1]), pdt).astype(f32)
-            padded = jnp.pad(t.astype(f32), ((0, 0), (taps - 1, 0), (0, 0)))
-            return nn.silu(sum(w[j] * padded[:, j:j + T] for j in range(taps)))
-
-        def heads(t, width):
-            return t.reshape(B, T, H, width).transpose(0, 2, 1, 3)
-
-        def unit(t):
-            return t * jax.lax.rsqrt(jnp.sum(t * t, axis=-1, keepdims=True) + 1e-6)
-
-        q = heads(conv_silu(dense(H * dk, "lin_q")(h), "conv_q"), dk)
-        k = heads(conv_silu(dense(H * dk, "lin_k")(h), "conv_k"), dk)
-        v = heads(conv_silu(dense(H * dv, "lin_v")(h), "conv_v"), dv)
+        q = self._conv_heads(h, dense, "q", dk)
+        k = self._conv_heads(h, dense, "k", dk)
+        v = self._conv_heads(h, dense, "v", dv)
         gate = dense(H * dv, "lin_gate")(h)
         a = dense(H, "lin_a")(h).astype(f32)
         b = dense(H, "lin_b")(h).astype(f32)
@@ -903,7 +1002,7 @@ class Block(nn.Module):
         beta = (2.0 if cfg.lin_neg_eigval else 1.0) * jax.nn.sigmoid(b)
         g = -jnp.exp(a_log.astype(f32)) * jax.nn.softplus(a + dt_bias.astype(f32))
         o = gated_delta_rule(
-            (unit(q) / math.sqrt(dk)).astype(dt), unit(k).astype(dt), v.astype(dt),
+            (_unit(q) / math.sqrt(dk)).astype(dt), _unit(k).astype(dt), v.astype(dt),
             g.transpose(0, 2, 1), beta.transpose(0, 2, 1),
             impl="kernel" if self._attention_impl() == "flash" else "xla",
             chunk=cfg.lin_chunk,
@@ -911,6 +1010,131 @@ class Block(nn.Module):
         o = nn.RMSNorm(dtype=f32, param_dtype=pdt, name="o_norm")(o)   # float32 as it comes
         o = o.transpose(0, 2, 1, 3).reshape(B, T, H * dv)
         return (o * nn.silu(gate.astype(f32))).astype(dt)
+
+    def _conv_heads(self, h, dense, which, width):
+        """``silu(conv(h W))`` by heads, (B, H, T, width) float32: the
+        projection ``lin_<which>`` to the held heads' lanes, then the depthwise
+        causal convolution ``conv_<which>`` of ``lin_conv`` taps a lane."""
+        cfg = self.cfg
+        B, T, _ = h.shape
+        H, taps, f32 = cfg.heads_held, cfg.lin_conv, jnp.float32
+        t = dense(H * width, "lin_" + which)(h)
+        w = self.param("conv_" + which, _tap_init(taps), (taps, t.shape[-1]),
+                       cfg.param_dtype).astype(f32)
+        padded = jnp.pad(t.astype(f32), ((0, 0), (taps - 1, 0), (0, 0)))
+        out = nn.silu(sum(w[j] * padded[:, j:j + T] for j in range(taps)))
+        return out.reshape(B, T, H, width).transpose(0, 2, 1, 3)
+
+    def _head_gate(self, attn, h, dense, n_heads):
+        """``attn`` (B, H, T, lanes) times a sigmoid gate a head from the
+        block's normed input (``attn_gate``, d_model -> heads), float32."""
+        gate = jax.nn.sigmoid(dense(n_heads, "attn_gate")(h).astype(jnp.float32))
+        return (attn.astype(jnp.float32)
+                * gate.transpose(0, 2, 1)[..., None]).astype(self.cfg.dtype)
+
+    def _kda_mixer(self, h, dense):
+        """(B, T, D) -> the held heads' Kimi-delta-attention output (B, T,
+        held x head_dim), before ``attn_out``:
+
+            q~, k~, v~ = silu(conv(h Wq)), silu(conv(h Wk)), silu(conv(h Wv))
+            q = q~ / |q~| / sqrt(dk);  k = k~ / |k~|        (per head)
+            beta = sigmoid(h Wb)                            (a scalar a head)
+            g = floor sigmoid(exp(A_log) (h Wa + dt_bias))  (log decay a channel)
+            o = kda(q, k, v, g, beta)                       (``ops/kda.py``)
+            out = RMSNorm_head(o) * sigmoid(h Wgate)        (a gate a head)
+
+        ``A_log`` is a scalar a head, ``dt_bias`` one a channel; ``floor`` =
+        ``kda_gate_floor`` (-5) bounds the gate, which is what the chunked
+        form's sub-blocks lean on. The convolution is ``_linear_mixer``'s.
+        Gates, norms and the rule's state are float32. The rule is the plain
+        chunked scan at every grid point (no kernel of it yet: ROADMAP.md,
+        M5)."""
+        from saturn_tpu.ops.kda import kda
+
+        cfg = self.cfg
+        dt, pdt, f32 = cfg.dtype, cfg.param_dtype, jnp.float32
+        B, T, _ = h.shape
+        H, dk = cfg.heads_held, cfg.head_dim
+        q, k, v = (self._conv_heads(h, dense, which, dk) for which in "qkv")
+        a = dense(H * dk, "lin_a")(h).astype(f32)
+        b = dense(H, "lin_b")(h).astype(f32)
+        a_log = self.param("A_log", nn.initializers.zeros, (H,), pdt)
+        dt_bias = self.param("dt_bias", _gate_bias_init, (H * dk,), pdt)
+        rate = jnp.repeat(jnp.exp(a_log.astype(f32)), dk)                 # (H dk,)
+        g = cfg.kda_gate_floor * jax.nn.sigmoid(rate * (a + dt_bias.astype(f32)))
+        o = kda((_unit(q) / math.sqrt(dk)).astype(dt), _unit(k).astype(dt), v.astype(dt),
+                g.reshape(B, T, H, dk).transpose(0, 2, 1, 3),
+                jax.nn.sigmoid(b).transpose(0, 2, 1))
+        # one gain of head_dim lanes, shared by the heads; float32 as it comes
+        o = nn.RMSNorm(dtype=f32, param_dtype=pdt, name="o_norm",
+                       **({} if cfg.norm_eps is None else {"epsilon": cfg.norm_eps}))(o)
+        if cfg.attn_gate:
+            o = self._head_gate(o, h, dense, H)
+        return o.astype(dt).transpose(0, 2, 1, 3).reshape(B, T, H * dk)
+
+    def _mla_mixer(self, h, dense, make_norm):
+        """(B, T, D) -> the held heads' latent-attention output (B, T, held x
+        v_head_dim), before ``attn_out``:
+
+            [qc_n | qr_n] = h Wq                  (a head: nope | rope lanes)
+            [c | kr] = h Wkva;  c = N(c)          (kv_latent | rope lanes)
+            [kc_n | v_n] = c Wkvb                 (a head: nope | v_head_dim)
+            q_n = [qc_n | qr_n];  k_n = [kc_n | kr]   (kr shared by the heads)
+            q_n, k_n = N_h(q_n), N_h(k_n)         (``head_qk_norm``: over a
+                                                   head's lanes, one gain)
+            rotary on the rope lanes of q_n and k_n at ``rope_theta``
+            o_n = softmax(q_n . k_n / sqrt(nope + rope), causal) v_n
+
+        and a sigmoid gate a head (``attn_gate``). The rotary lanes are the
+        last ``qk_rope_dim`` of a head, in split-half order. Flash where the
+        attention implementation is "flash" (``saturn_mla_*``: the scores'
+        lanes and the values' differ), the plain einsums where it is "dense".
+        The shared rotary key is broadcast over the heads here: the heads'
+        norm makes each head's copy its own."""
+        cfg = self.cfg
+        dt, pdt, f32 = cfg.dtype, cfg.param_dtype, jnp.float32
+        B, T, _ = h.shape
+        H, L = cfg.heads_held, cfg.kv_latent
+        dn, dr, dv = cfg.qk_nope_dim, cfg.qk_rope_dim, cfg.v_head_dim
+        q = dense(H * (dn + dr), "mla_q")(h).reshape(B, T, H, dn + dr)
+        kva = dense(L + dr, "mla_kv_a")(h)
+        c = make_norm("kv_norm")(kva[..., :L])
+        kvb = dense(H * (dn + dv), "mla_kv_b")(c).reshape(B, T, H, dn + dv)
+        k = jnp.concatenate(
+            [kvb[..., :dn], jnp.broadcast_to(kva[:, :, None, L:], (B, T, H, dr))], axis=-1)
+        v = kvb[..., dn:]
+        if cfg.head_qk_norm:
+            eps = 1e-6 if cfg.norm_eps is None else cfg.norm_eps
+
+            def head_norm(t, name):
+                gain = self.param(name, nn.initializers.ones, (dn + dr,), pdt)
+                t = t.astype(f32)
+                t = t * jax.lax.rsqrt(jnp.mean(t * t, axis=-1, keepdims=True) + eps)
+                return (t * gain.astype(f32)).astype(dt)
+
+            q, k = head_norm(q, "q_norm"), head_norm(k, "k_norm")
+        sin, cos = rotary_sin_cos(jnp.arange(T), dr, cfg.rope_theta)
+
+        def turned(t):      # (B, T, H, dn + dr) -> (B, H, T, dn + dr)
+            t = t.transpose(0, 2, 1, 3)
+            return jnp.concatenate(
+                [t[..., :dn], apply_rotary(t[..., dn:], sin, cos, dr)], axis=-1)
+
+        q, k, v = turned(q), turned(k), v.transpose(0, 2, 1, 3)
+        if self._attention_impl() == "flash":
+            from saturn_tpu.ops.flash import flash_attention
+
+            attn = flash_attention(q, k, v, causal=True)
+        else:
+            scores = jnp.einsum("bhqd,bhkd->bhqk", q, k).astype(f32)
+            scores = scores / math.sqrt(dn + dr)
+            mask = jnp.tril(jnp.ones((T, T), dtype=bool))
+            scores = jnp.where(mask[None, None], scores, jnp.float32(-1e30))
+            attn = jnp.einsum("bhqk,bhkd->bhqd",
+                              jax.nn.softmax(scores, axis=-1).astype(dt), v)
+        if cfg.attn_gate:
+            attn = self._head_gate(attn, h, dense, H)
+        return attn.transpose(0, 2, 1, 3).reshape(B, T, H * dv)
 
     def _attention_impl(self) -> str:
         """'auto' resolution for configs built without ``build_gpt2`` — one
@@ -945,7 +1169,9 @@ class Block(nn.Module):
         plan = routed_plan(
             B * T, E, held, cfg.top_k,
             impl="kernel" if self._attention_impl() == "flash" else "xla",
-            act=cfg.expert_act, latent=cfg.latent_dim, bias=cfg.router_bias)
+            act=cfg.expert_act, latent=cfg.latent_dim, bias=cfg.router_bias,
+            buffer=cfg.routed_buffer, groups=cfg.route_groups,
+            groups_kept=cfg.route_groups_kept)
         latent = dense(L, "latent_down")(inp).reshape(B * T, L) \
             if cfg.latent_dim else None
         y, stats = routed_experts(
@@ -1119,7 +1345,7 @@ class PeriodBlock(nn.Module):
 
 class LeadBlocks(nn.Module):
     """The ``cfg.lead_layers`` layers before the scanned periods, each a
-    full-attention :class:`Block` with the dense MLP under the name
+    full-attention (``cfg.lead_kind``) :class:`Block` with the dense MLP under the name
     ``l<i>``; rematerialised one by one under remat, like a period's."""
 
     cfg: GPT2Config
@@ -1128,7 +1354,7 @@ class LeadBlocks(nn.Module):
     def __call__(self, x):
         block_cls = _remat(Block, prevent_cse=True) if self.cfg.remat else Block
         for i in range(self.cfg.lead_layers):
-            x, _ = block_cls(self.cfg, kind="full_attention", name=f"l{i}")(x, None)
+            x, _ = block_cls(self.cfg, kind=self.cfg.lead_kind, name=f"l{i}")(x, None)
         return x
 
 
@@ -1419,7 +1645,7 @@ def build_gpt2(
         # boundary-label loss assume causal next-token training. A linear
         # layer's state crosses the whole sequence: not sequence-parallel.
         "seq_parallel": cfg.causal and not (
-            {"linear_attention", "mamba2"} & set(cfg.layer_types or ())),
+            {"linear_attention", "mamba2", "kda"} & set(cfg.layer_types or ())),
         "pipeline": {
             "embed": pipeline_embed,
             "block": pipeline_block,
@@ -1495,6 +1721,22 @@ def build_nemotron_h(name: str = "nemotron3-super", **overrides) -> ModelSpec:
     program one chip's share of every mixer. Same ``ModelSpec`` contract as
     :func:`build_gpt2`; the scanned unit, and ``hints["pipeline"]``'s
     ``block``, is one period."""
+    return build_gpt2(name, **overrides)
+
+
+def build_ling(name: str = "ling3-flash", **overrides) -> ModelSpec:
+    """Ling factory: leading dense layers outside the scan (param key
+    ``lead``: KDA + SwiGLU), then periods of five Kimi-delta-attention layers
+    (``ops/kda.py`` behind three 4-tap convolutions, a decay a key channel)
+    and one latent-attention layer (MLA: ``ops/flash.py`` at 192 score lanes
+    and 128 value lanes), every mixer under a gate a head, each before a
+    shared expert beside top-k routed experts chosen under a group limit and
+    a selection bias (``ops/moe.py::routed_experts``). ``held_heads`` /
+    ``held_experts`` make the program one chip's share of every mixer and
+    routed layer. A non-zero ``swiglu_limit`` (the published last layers'
+    clamp) is refused. Same ``ModelSpec`` contract as :func:`build_gpt2`: the
+    scanned unit, and ``hints["pipeline"]``'s ``block``, is one period; its
+    ``embed`` runs the leading layers."""
     return build_gpt2(name, **overrides)
 
 

@@ -230,6 +230,17 @@ def _first(reach, step, per_chunk, n_col):
     return (_div(reach[0], per_chunk) + step) * per_chunk
 
 
+def _name(part: str, window, D: int, Dv: int) -> str:
+    """A kernel's name in the device trace: ``saturn_flash_<part>``, under a
+    window ``saturn_swa_<part>``, and where the values are narrower or wider
+    than the scores' lanes (latent attention: 192 / 128) ``saturn_mla_<part>``:
+    a reader that credits a ``saturn_flash_*`` call with one head width must
+    not meet one."""
+    if D != Dv:
+        return f"saturn_mla_{part}"
+    return f"saturn_flash_{part}" if window is None else f"saturn_swa_{part}"
+
+
 def _traced_once(fn):
     """``fn`` behind ``jit``'s tracing cache, inlined where it is called: a
     call with shapes and blocks seen before (the layer again under remat,
@@ -347,6 +358,7 @@ def _keys_down(D: int) -> bool:
 def _fwd(q, k, v, *, block_q, block_k, chunk, scale, causal, h, kv,
          window=None, interpret=False):
     BH, T, D = q.shape
+    Dv = v.shape[-1]        # the values' lanes (D but under latent attention)
     kv_of = _kv_of(h, kv)
     keys_down = _keys_down(D)
     steps, fetched = _chunk_walk(block_q, block_k, chunk, T, causal, window,
@@ -355,14 +367,15 @@ def _fwd(q, k, v, *, block_q, block_k, chunk, scale, causal, h, kv,
                           lambda bh, i, j: (kv_of(bh), fetched(i, j), 0))
     if keys_down:   # v and o cross the kernel's edge as (D, T)
         v = jnp.swapaxes(v, 1, 2)
-        v_spec = pl.BlockSpec((1, D, chunk),
+        v_spec = pl.BlockSpec((1, Dv, chunk),
                               lambda bh, i, j: (kv_of(bh), 0, fetched(i, j)))
-        o_spec = pl.BlockSpec((1, D, block_q), lambda bh, i, j: (bh, 0, i))
-        o_shape, stat, acc = (BH, D, T), (8, block_q), (D, block_q)
+        o_spec = pl.BlockSpec((1, Dv, block_q), lambda bh, i, j: (bh, 0, i))
+        o_shape, stat, acc = (BH, Dv, T), (8, block_q), (Dv, block_q)
     else:
-        v_spec = walked
-        o_spec = pl.BlockSpec((1, block_q, D), lambda bh, i, j: (bh, i, 0))
-        o_shape, stat, acc = (BH, T, D), (block_q, _LANES), (block_q, D)
+        v_spec = walked if Dv == D else pl.BlockSpec(
+            (1, chunk, Dv), lambda bh, i, j: (kv_of(bh), fetched(i, j), 0))
+        o_spec = pl.BlockSpec((1, block_q, Dv), lambda bh, i, j: (bh, i, 0))
+        o_shape, stat, acc = (BH, T, Dv), (block_q, _LANES), (block_q, Dv)
     o, lse = pl.pallas_call(
         functools.partial(
             _fwd_kernel, block_q=block_q, block_k=block_k, seq=T, steps=steps,
@@ -387,7 +400,7 @@ def _fwd(q, k, v, *, block_q, block_k, chunk, scale, causal, h, kv,
             pltpu.VMEM(stat, jnp.float32),    # running denom
             pltpu.VMEM(acc, jnp.float32),     # output accumulator
         ],
-        name="saturn_flash_fwd" if window is None else "saturn_swa_fwd",
+        name=_name("fwd", window, D, Dv),
         interpret=interpret,
     )(q, k, v)
     return (jnp.swapaxes(o, 1, 2) if keys_down else o), lse
@@ -478,6 +491,7 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 def _dq(q, k, v, do, lse, delta, *, block_q, block_k, chunk, scale, causal,
         h, kv, window=None, interpret=False):
     BH, T, D = q.shape
+    Dv = v.shape[-1]
     kv_of = _kv_of(h, kv)
     steps, fetched = _chunk_walk(block_q, block_k, chunk, T, causal,
                                          window, True)
@@ -491,9 +505,9 @@ def _dq(q, k, v, do, lse, delta, *, block_q, block_k, chunk, scale, causal,
             pl.BlockSpec((1, block_q, D), lambda bh, i, j: (bh, i, 0)),
             pl.BlockSpec((1, chunk, D),
                          lambda bh, i, j: (kv_of(bh), fetched(i, j), 0)),
-            pl.BlockSpec((1, chunk, D),
+            pl.BlockSpec((1, chunk, Dv),
                          lambda bh, i, j: (kv_of(bh), fetched(i, j), 0)),
-            pl.BlockSpec((1, block_q, D), lambda bh, i, j: (bh, i, 0)),
+            pl.BlockSpec((1, block_q, Dv), lambda bh, i, j: (bh, i, 0)),
             pl.BlockSpec((1, 1, block_q), lambda bh, i, j: (bh, 0, i)),
             pl.BlockSpec((1, 1, block_q), lambda bh, i, j: (bh, 0, i)),
         ],
@@ -504,7 +518,7 @@ def _dq(q, k, v, do, lse, delta, *, block_q, block_k, chunk, scale, causal,
             pltpu.VMEM((block_q, _LANES), jnp.float32),   # lse, lane-wide
             pltpu.VMEM((block_q, _LANES), jnp.float32),   # delta, lane-wide
         ],
-        name="saturn_flash_dq" if window is None else "saturn_swa_dq",
+        name=_name("dq", window, D, Dv),
         interpret=interpret,
     )(q, k, v, do, lse, delta)
 
@@ -513,6 +527,7 @@ def _dq(q, k, v, do, lse, delta, *, block_q, block_k, chunk, scale, causal,
 def _dkv(q, k, v, do, lse, delta, *, block_q, block_k, chunk, scale, causal,
          h, kv, window=None, interpret=False):
     T, D = q.shape[1:]
+    Dv = v.shape[-1]
     BKV = k.shape[0]
     rep = h // kv
     steps, fetched = _chunk_walk(block_k, block_q, chunk, T, causal,
@@ -534,8 +549,8 @@ def _dkv(q, k, v, do, lse, delta, *, block_q, block_k, chunk, scale, causal,
             pl.BlockSpec((1, chunk, D),
                          lambda bkv, j, g, i: (qh(bkv, g), fetched(j, i), 0)),
             pl.BlockSpec((1, block_k, D), lambda bkv, j, g, i: (bkv, j, 0)),
-            pl.BlockSpec((1, block_k, D), lambda bkv, j, g, i: (bkv, j, 0)),
-            pl.BlockSpec((1, chunk, D),
+            pl.BlockSpec((1, block_k, Dv), lambda bkv, j, g, i: (bkv, j, 0)),
+            pl.BlockSpec((1, chunk, Dv),
                          lambda bkv, j, g, i: (qh(bkv, g), fetched(j, i), 0)),
             pl.BlockSpec((1, 1, chunk),
                          lambda bkv, j, g, i: (qh(bkv, g), 0, fetched(j, i))),
@@ -544,17 +559,17 @@ def _dkv(q, k, v, do, lse, delta, *, block_q, block_k, chunk, scale, causal,
         ],
         out_specs=[
             pl.BlockSpec((1, block_k, D), lambda bkv, j, g, i: (bkv, j, 0)),
-            pl.BlockSpec((1, block_k, D), lambda bkv, j, g, i: (bkv, j, 0)),
+            pl.BlockSpec((1, block_k, Dv), lambda bkv, j, g, i: (bkv, j, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((BKV, T, D), k.dtype),
-            jax.ShapeDtypeStruct((BKV, T, D), v.dtype),
+            jax.ShapeDtypeStruct((BKV, T, Dv), v.dtype),
         ],
         scratch_shapes=[
             pltpu.VMEM((block_k, D), jnp.float32),
-            pltpu.VMEM((block_k, D), jnp.float32),
+            pltpu.VMEM((block_k, Dv), jnp.float32),
         ],
-        name="saturn_flash_dkv" if window is None else "saturn_swa_dkv",
+        name=_name("dkv", window, D, Dv),
         interpret=interpret,
     )(q, k, v, do, lse, delta)
 
@@ -706,7 +721,7 @@ def _walk(T: int, block_q: int, block_k: int, chunk: int,
 
 
 def flash_plan(T: int, D: int, block_q: Optional[int] = None,
-               block_k: Optional[int] = None) -> dict:
+               block_k: Optional[int] = None, d_v: Optional[int] = None) -> dict:
     """The causal kernels' blocks at sequence ``T`` and head dim ``D``, with
     what each kernel's walk over a head then is (``_walk``). A pure function
     of its arguments: no device, no compile, nothing tried. ``block_q`` /
@@ -726,6 +741,11 @@ def flash_plan(T: int, D: int, block_q: Optional[int] = None,
     transposes, the fetch of q) is paid T / block times a head, which from
     T 8192 on is worth the larger block; at head dim 256 the 1024-row
     operands crowd VMEM and the reading is worse.
+
+    ``d_v``: the values' lanes where they are not the scores' ``D`` (latent
+    attention: q and k of 192 lanes, v of 128). The blocks and the chunk go
+    by ``D``, the wider operand of every product (no chip has read another
+    rule at two widths), and the plan says both (``d_qk``, ``d_v``).
     """
     stays = _largest_block(T, 1024 if T >= 8192 and D <= _LANES else 512)
     walked = _largest_block(T, 512)
@@ -733,6 +753,8 @@ def flash_plan(T: int, D: int, block_q: Optional[int] = None,
               "dq": (block_q or stays, block_k or walked),
               "dkv": (block_q or walked, block_k or stays)}
     out = {"seq": T, "head_dim": D, "keys_down": _keys_down(D)}
+    if d_v is not None and d_v != D:
+        out.update(d_qk=D, d_v=d_v)
     for name, (bq, bk) in blocks.items():
         walks_keys = name != "dkv"
         out[name] = _walk(T, bq, bk, _chunk(T, D, bk if walks_keys else bq),
@@ -775,6 +797,11 @@ def flash_attention(
 ) -> jax.Array:
     """Fused causal attention over (B, H, T, D); differentiable.
 
+    ``v`` (and the result) may have other lanes than ``q`` and ``k``
+    (latent attention: (B, H, T, 192) scores over (B, H, T, 128) values, at
+    scale 1 / sqrt(192)): the same three kernels under the names
+    ``saturn_mla_*``, each operand's block at its own width.
+
     ``window`` (causal only): query i reads keys i - window + 1 .. i, through
     the ``saturn_swa_*`` kernels, whose grids visit the blocks the window
     reaches and no other (``window_plan``); one block size, ``block_q``.
@@ -793,14 +820,19 @@ def flash_attention(
     """
     B, H, T, D = q.shape
     KV = k.shape[1]
+    Dv = v.shape[-1]
     if v.shape[1] != KV or KV < 1 or H % KV != 0:
         raise ValueError(
             f"k/v heads ({k.shape[1]}, {v.shape[1]}) must match and divide "
             f"q heads ({H})"
         )
+    if k.shape[-1] != D or (Dv != D and window is not None):
+        raise ValueError(
+            f"q and k share their lanes ({D}, {k.shape[-1]}); v's may differ "
+            f"({Dv}) without a window")
     qf = q.reshape(B * H, T, D)
     kf = k.reshape(B * KV, T, D)
-    vf = v.reshape(B * KV, T, D)
+    vf = v.reshape(B * KV, T, Dv)
     if window is not None:
         if not causal or window < 1 or (block_k or block_q) != block_q:
             raise ValueError("a window is causal, >= 1, with one block size")
@@ -812,7 +844,7 @@ def flash_attention(
         blocks = ((b, b, b),) * 3
         o = _flash_bh(qf, kf, vf, blocks, True, H, KV, int(window))
         return o.reshape(B, H, T, D)
-    plan = flash_plan(T, D, block_q, block_k)
+    plan = flash_plan(T, D, block_q, block_k, d_v=Dv)
     blocks = tuple((plan[n]["block_q"], plan[n]["block_k"], plan[n]["chunk"])
                    for n in ("fwd", "dq", "dkv"))
     if any(T % b for triple in blocks for b in triple):
@@ -820,4 +852,4 @@ def flash_attention(
     if causal:
         _PLANS["flash"].append(plan)
     o = _flash_bh(qf, kf, vf, blocks, causal, H, KV)
-    return o.reshape(B, H, T, D)
+    return o.reshape(B, H, T, Dv)

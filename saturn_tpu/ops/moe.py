@@ -122,6 +122,9 @@ class RoutedPlan:
     latent: int = 0       # the width the experts read and write, where it
     #                       is not the stream's (0: the stream's)
     bias: bool = False    # a selection bias is added to the scores, for the choice
+    groups: int = 0       # the experts lie in this many groups of consecutive
+    #                       ones (0: no group limit) and a token chooses among
+    groups_kept: int = 0  # the experts of its ``groups_kept`` best groups
 
     @property
     def second_path(self) -> bool:
@@ -164,14 +167,23 @@ ROW_TILE = 128
 def routed_plan(tokens: int, experts: int, held: int, top_k: int, *,
                 buffer: Optional[float] = None, row_tile: Optional[int] = None,
                 impl: str = "xla", act: str = "swiglu", latent: int = 0,
-                bias: bool = False) -> RoutedPlan:
+                bias: bool = False, groups: int = 0,
+                groups_kept: int = 0) -> RoutedPlan:
     """``buffer`` (``BUFFER``) x the mean held pairs (tokens x top_k x held /
     experts), plus a tile an expert for the padding, capped at the worst case
-    (every token's every choice held)."""
+    (every token's every choice held). ``groups`` / ``groups_kept``: the
+    group limit of the choice (``limited_choice``)."""
     if impl not in ("kernel", "xla"):
         raise ValueError(f"impl must be 'kernel' or 'xla', got {impl!r}")
     if act not in ("swiglu", "relu2"):
         raise ValueError(f"act must be 'swiglu' or 'relu2', got {act!r}")
+    if groups and (experts % groups or not 1 <= groups_kept <= groups
+                   or top_k > groups_kept * (experts // groups)
+                   or experts // groups < 2):
+        raise ValueError(
+            f"a group limit needs experts ({experts}) in whole groups ({groups}) "
+            f"of two or more, 1 <= groups_kept ({groups_kept}) <= groups, and "
+            f"top_k ({top_k}) experts among the kept groups'")
     buffer = BUFFER if buffer is None else buffer
     if row_tile is None:
         row_tile = ROW_TILE if tokens * top_k >= experts * ROW_TILE else 8
@@ -180,7 +192,21 @@ def routed_plan(tokens: int, experts: int, held: int, top_k: int, *,
     mean = tokens * top_k * held / experts
     rows = min(worst, _round_up(int(math.ceil(buffer * mean)), row_tile) + pad)
     return RoutedPlan(impl, tokens, experts, held, top_k, row_tile, rows, worst,
-                      act, latent, bias)
+                      act, latent, bias, groups, groups_kept if groups else 0)
+
+
+def limited_choice(choice, groups: int, groups_kept: int):
+    """``choice`` (T, experts), the scores a token chooses by, with the
+    experts outside the token's ``groups_kept`` best groups at -inf: the
+    experts are ``groups`` groups of consecutive ones, a group's score is the
+    sum of its two largest entries (group-limited routing with a selection
+    bias, DeepSeek-V3's)."""
+    t, e = choice.shape
+    grouped = choice.reshape(t, groups, e // groups)
+    best = jnp.sum(jax.lax.top_k(grouped, 2)[0], axis=-1)               # (T, groups)
+    kept = jax.lax.top_k(best, groups_kept)[1]                          # (T, kept)
+    keep = jnp.any(kept[:, :, None] == jnp.arange(groups)[None, None, :], axis=1)
+    return jnp.where(keep[:, :, None], grouped, -jnp.inf).reshape(t, e)
 
 
 def _interpret() -> bool:
@@ -455,7 +481,9 @@ def routed_experts(y, router, w_gate, w_up, w_down, *, plan: RoutedPlan,
     and ``w_down`` (held, F, D): experts ``first_expert .. + held``.
 
         s = sigmoid(y router)                  float32, all the experts
-        I = the top_k largest of s (+ bias);  w_e = scale * s_e / sum_{e' in I} s_e'
+        I = the top_k largest of s (+ bias), among the experts of the token's
+            best groups under a group limit (``plan.groups``: ``limited_choice``)
+        w_e = scale * s_e / sum_{e' in I} s_e'
         out = sum_{e in I, e held} w_e (silu(u Wg_e) * (u Wu_e)) Wd_e,   u = y
 
     ``w_gate`` None (``plan.act`` "relu2"): an expert is ``relu(u Wu_e)^2
@@ -492,6 +520,8 @@ def routed_experts(y, router, w_gate, w_up, w_down, *, plan: RoutedPlan,
     # another's router column
     choice = scores if bias is None else scores + jax.lax.stop_gradient(
         bias.astype(f32))
+    if plan.groups:
+        choice = limited_choice(choice, plan.groups, plan.groups_kept)
     chosen = checkpoint_name(jax.lax.top_k(choice, plan.top_k)[1], LAYOUT_NAME)
     top = jnp.take_along_axis(scores, chosen, axis=-1)
     weights = scale * top / jnp.sum(top, axis=-1, keepdims=True)      # (T, k)

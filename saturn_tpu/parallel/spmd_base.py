@@ -198,6 +198,8 @@ class _Bundle:
     moe_plans: Tuple[Any, ...] = ()
     # likewise each state-space layer's scan (ops/ssd.py's ``SSDPlan``)
     ssd_plans: Tuple[Any, ...] = ()
+    # likewise each Kimi-delta-attention call (ops/kda.py's ``KDAPlan``)
+    kda_plans: Tuple[Any, ...] = ()
     window_plans: Tuple[Any, ...] = ()
     flash_plans: Tuple[Any, ...] = ()
     _lowered: Any = None
@@ -982,6 +984,7 @@ class SPMDTechnique(BaseTechnique):
         from saturn_tpu.ops import flash as _flash
         from saturn_tpu.ops import gdn as _gdn
         from saturn_tpu.ops import moe as _moe
+        from saturn_tpu.ops import kda as _kda
         from saturn_tpu.ops import ssd as _ssd
 
         trace_count = [0]
@@ -993,6 +996,7 @@ class SPMDTechnique(BaseTechnique):
         with _ce.traced_plans() as ce_plans, _gdn.traced_plans() as gdn_plans, \
                 _moe.traced_plans() as moe_plans, \
                 _ssd.traced_plans() as ssd_plans, \
+                _kda.traced_plans() as kda_plans, \
                 _flash.traced_window_plans() as window_plans, \
                 _flash.traced_flash_plans() as flash_plans:
             closed, out_shapes = jax.make_jaxpr(
@@ -1053,6 +1057,7 @@ class SPMDTechnique(BaseTechnique):
             gdn_plans=tuple(gdn_plans),
             moe_plans=tuple(moe_plans),
             ssd_plans=tuple(ssd_plans),
+            kda_plans=tuple(kda_plans),
             window_plans=tuple(window_plans),
             flash_plans=tuple(flash_plans),
         )
@@ -1180,9 +1185,14 @@ class SPMDTechnique(BaseTechnique):
         time (``_measured_behind``). The memory-frugal points are
         prepared first (``remat: True`` before the rest, the technique's
         order otherwise): they are the ones most likely to take timed steps,
-        under which the others' preparation then runs. The winner is the
-        fastest timed point wherever it stood; a tie goes to the technique's
-        own order.
+        under which the others' preparation then runs. A ``remat: False``
+        point whose ``remat: True`` twin (every other key alike) was over
+        memory is over memory too, and ends so without being built:
+        rematerialisation only ever lowers a program's peak, and the point
+        would be traced, lowered and compiled to be refused (a period of six
+        blocks: 20 s + 5 s + 53 s on the chip's host, PR 45). The winner is
+        the fastest timed point wherever it stood; a tie goes to the
+        technique's own order.
         """
         size = len(devices)
         stack = self._stack_fields(task)
@@ -1240,15 +1250,25 @@ class SPMDTechnique(BaseTechnique):
                 raise
             return None
 
+        over_memory: List[Dict[str, Any]] = []  # the configs that ended so
+
         def prepare(order: int, config: Dict[str, Any]) -> _GridPoint:
             point = _GridPoint(order, config, _metrics.span(
                 "trial.config", parent=above, task=task.name, size=size,
                 technique=self.name, config=dict(config)).open())
+            if config.get("remat") is False and \
+                    dict(config, remat=True) in over_memory:
+                point.span.set(implied_by="remat")
+                end(point, "memory_rejected", memory_rejected=True,
+                    implied_by="remat")
+                return point
             point.ready = attempt(
                 point, lambda: self._prepare(task, devices, config))
             if point.ready is None and point.outcome is None:
                 # _prepare returns None only on the memory check
                 end(point, "memory_rejected", memory_rejected=True)
+            if point.outcome in ("refused", "memory_rejected"):
+                over_memory.append(dict(config))
             return point
 
         def measure(point: _GridPoint) -> None:
@@ -1339,12 +1359,15 @@ class SPMDTechnique(BaseTechnique):
         whether a selection bias is added), ``ssd_plan`` of one with
         state-space layers (``ops/ssd.py::SSDPlan``: kernel or twin, chunk,
         heads and groups held / published, the state bytes a layer keeps for
-        the backward) and ``window_plan`` of one with
+        the backward), ``kda_plan`` of one with Kimi-delta-attention layers
+        (``ops/kda.py::KDAPlan``: chunk, sub-block, heads, d_k, d_v, the kept
+        states' bytes) and ``window_plan`` of one with
         sliding-window layers (``ops/flash.py::window_plan``: window, block,
         key blocks visited and skipped a call); ``flash_plan`` of one whose
         causal attention runs the flash kernels (``ops/flash.py::flash_plan``:
         each kernel's blocks and chunk, the score blocks it visits a head
-        and those of them the diagonal crosses). Beside them
+        and those of them the diagonal crosses; ``d_qk`` / ``d_v`` where the
+        scores' lanes and the values' differ: latent attention). Beside them
         ``step_traces``: how often the model's Python step function was
         called for this grid point (its bundle's one trace: 1). Nothing where
         the point's bundle was never built."""
@@ -1361,6 +1384,8 @@ class SPMDTechnique(BaseTechnique):
             out["moe_plan"] = bundle.moe_plans[0].as_event()
         if bundle.ssd_plans:   # the first state-space layer's (all are alike)
             out["ssd_plan"] = bundle.ssd_plans[0]._asdict()
+        if bundle.kda_plans:   # the first delta-rule layer's (all are alike)
+            out["kda_plan"] = bundle.kda_plans[0]._asdict()
         if bundle.window_plans:
             out["window_plan"] = dict(bundle.window_plans[0])
         if bundle.flash_plans:   # the first causal call's (a model has one T, D)

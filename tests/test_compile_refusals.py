@@ -406,25 +406,39 @@ def _search(tmp_path, devices, tag, name="refused-a"):
         library._REGISTRY.update(registry)
 
 
+def _by_remat(points):
+    """(the points the compiler saw: ``remat: True``; those that ended over
+    memory because their ``remat: True`` twin had: never built)."""
+    seen = [e for e in points if e["config"].get("remat") is True]
+    implied = [e for e in points if e["config"].get("remat") is not True]
+    assert seen and implied and all(e.get("implied_by") == "remat" for e in implied)
+    return seen, implied
+
+
 def test_search_takes_refusals_as_the_memory_verdict(
         store, tmp_path, devices8, refusing_compiler):
     stats, events, task = _search(tmp_path, devices8, "first")
     points = [e for e in events if e["kind"] == "trial_config"]
-    n = len(points)
-    assert n >= 2  # dp's grid: remat x attention
+    assert len(points) >= 2  # dp's grid: remat x attention
+    seen, implied = _by_remat(points)
+    n = len(seen)
     assert stats["errors"] == 0 and stats["first_error"] is None
     assert stats["refusals_fresh"] == n and stats["refusals_replayed"] == 0
     assert len(refusing_compiler) == n and len(records(store)) >= 1
-    for e in points:
+    for e in seen:
         assert e["memory_rejected"] is True and e["refusal"] == "fresh"
         assert e["compiler"] == HBM.splitlines()[0]
         assert "error" not in e and "per_batch_s" not in e
+    for e in implied:   # over memory with rematerialisation: over it without
+        assert e["memory_rejected"] is True and "refusal" not in e
+        assert "error" not in e and "per_batch_s" not in e and "step_traces" not in e
     spans = [e for e in events if e["kind"] == "trial.config"]
-    assert [e["outcome"] for e in spans] == ["refused"] * n
-    assert [e["refusal"] for e in spans] == ["fresh"] * n
+    assert [e["outcome"] for e in spans] == ["refused"] * n + ["memory_rejected"] * len(implied)
+    assert [e.get("refusal") for e in spans] == ["fresh"] * n + [None] * len(implied)
     compiles = [e for e in events if e["kind"] == "trial.compile"]
     assert [e["refusal"] for e in compiles] == ["fresh"] * n
     assert all(e["error"] == "CompileRefused" for e in compiles)
+    assert len([e for e in events if e["kind"] == "trial.build"]) == n
     (trial,) = [e for e in events if e["kind"] == "trial"]
     assert trial["feasible"] is False and trial["memory_infeasible"] is True
     assert not task.feasible_strategies()
@@ -434,19 +448,22 @@ def test_search_takes_refusals_as_the_memory_verdict(
     assert len(refusing_compiler) == n
     assert stats["errors"] == 0
     assert stats["refusals_fresh"] == 0 and stats["refusals_replayed"] == n
-    points = [e for e in events if e["kind"] == "trial_config"]
-    assert [e["refusal"] for e in points] == ["recorded"] * n
+    seen, implied = _by_remat([e for e in events if e["kind"] == "trial_config"])
+    assert [e["refusal"] for e in seen] == ["recorded"] * n
     assert all(e["memory_rejected"] is True and e["compiler"] == HBM.splitlines()[0]
-               for e in points)
+               for e in seen)
+    assert all(e["memory_rejected"] is True for e in implied)
     assert [e["refusal"] for e in events if e["kind"] == "trial.compile"] == ["recorded"] * n
-    assert [e["outcome"] for e in events if e["kind"] == "trial.config"] == ["refused"] * n
+    assert [e["outcome"] for e in events if e["kind"] == "trial.config"] == \
+        ["refused"] * n + ["memory_rejected"] * len(implied)
     assert not [e for e in events if e["kind"] == "compile"
                 and "saturn_window" in e["program"]]
 
 
 def test_search_report_says_memory_infeasible(store, devices8, refusing_compiler, tmp_path):
-    """``SPMDTechnique.search`` itself: every config refused => the report
-    the evaluator's monotone pruning reads says memory, with no error."""
+    """``SPMDTechnique.search`` itself: every config refused, or over memory
+    because its ``remat: True`` twin was refused => the report the
+    evaluator's monotone pruning reads says memory, with no error."""
     from saturn_tpu.parallel.dp import DataParallel
 
     tech = DataParallel()
@@ -457,7 +474,9 @@ def test_search_report_says_memory_infeasible(store, devices8, refusing_compiler
     assert n >= 2 and report["memory_infeasible"] is True
     assert report["memory_rejected"] == n and report["errors"] == 0
     assert report["first_error"] is None
-    assert report["refusals_fresh"] + report["refusals_replayed"] == n
+    # the compiler saw the ``remat: True`` half of the grid
+    assert report["refusals_fresh"] + report["refusals_replayed"] == n // 2
+    assert len(refusing_compiler) == n // 2
 
 
 def test_a_refusal_from_running_is_an_error_and_is_not_recorded(
@@ -547,3 +566,38 @@ def test_the_memory_check_records_what_it_rejects(store, monkeypatch, tmp_path):
     assert DataParallel()._fits_compiled(aot_cache.load_or_compile(
         Weighed(store.parent, body="%0 = mul")), [object()]) is True
     assert len(records(store)) == 1
+
+
+# --------------------------- a stack the techniques cannot rebuild (PR 45)
+@pytest.mark.parametrize("name, reason", [
+    ("pp", "several block kinds"), ("ep", "exchange of tokens"),
+    ("ring", None), ("ulysses", None)])
+def test_a_ling_task_is_refused_with_a_reason_on_the_trial_config_span(
+        name, reason, tmp_path, devices8):
+    """``pp`` stages a stack of one kind and ``ep`` would need the exchange of
+    token rows between shares: every grid point ends as an infeasible
+    ``trial.config`` span that says so. A KDA layer's state crosses the whole
+    sequence, so the model says it is not sequence-parallel and ``ring`` /
+    ``ulysses`` offer no grid point at all."""
+    from saturn_tpu.parallel import BUILTIN_TECHNIQUES
+    from tests import test_ling_techniques as ling_tests
+
+    tech, devices = BUILTIN_TECHNIQUES[name](), list(devices8[:4])
+    task = ling_tests._task(tmp_path, f"ling-refused-{name}", batch=4)
+    configs = tech.candidate_configs(task, len(devices))
+    if reason is None:
+        assert not configs and task.get_model().hints["seq_parallel"] is False
+        events = str(tmp_path / "ev.jsonl")
+        with metrics.scoped(events):
+            assert tech.search(task, devices, 0) == (None, None)
+        assert not metrics.read_events(events, kind="trial.config")
+        return
+    ling_tests.refused(tech, task, devices, configs, tmp_path, reason)
+
+
+def test_a_non_zero_swiglu_limit_refuses_at_build():
+    from saturn_tpu.models.gpt2 import build_ling
+
+    with pytest.raises(ValueError, match="clamp is not built"):
+        build_ling("ling-test-tiny", swiglu_limit=7.0)
+    assert build_ling("ling-test-tiny", swiglu_limit=0.0).config.swiglu_limit == 0.0

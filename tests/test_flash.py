@@ -5,6 +5,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from saturn_tpu.ops import flash as flash_mod
 from saturn_tpu.ops.flash import flash_attention
 
 
@@ -415,3 +416,40 @@ def test_three_small_kernels_an_attention_call(kind, D, T, remat):
     for name, n in calls:
         assert n <= MOST_OF_PARENT * PARENT_BODY_EQNS[name], (
             name, n, PARENT_BODY_EQNS[name])
+
+
+# ------------------------- equal widths trace to what they did (PR 45)
+#: sha256 (16 hex) of the jaxpr text of ``grad(flash_attention)`` with the
+#: TPU lowering's ``pallas_call``s traced (not lowered), taken on the commit
+#: before the kernels took two head widths (9beeaa8; memory addresses blanked):
+#: kernel bodies, grids, index maps, scratch shapes and names, character for
+#: character. To take them again: ``_flash_text`` below, on that tree.
+_TEXT_BEFORE_TWO_WIDTHS = {
+    "head-64": ((64,), {}, "4eb13136ef864380"),
+    "head-128": ((128,), {}, "674df037d5bc3c82"),
+    "head-256": ((256,), {}, "26cf3659d158eec7"),
+    "head-128-t8192-grouped": ((128,), {"t": 8192, "h": 2, "kv": 1}, "9487d455add689bc"),
+    "head-128-window-512": ((128,), {"window": 512, "t": 2048}, "41d5382a5e4113fb"),
+}
+
+
+def _flash_text(d, t=1024, h=4, kv=4, window=None):
+    import re
+
+    q = jax.ShapeDtypeStruct((1, h, t, d), jnp.bfloat16)
+    k = jax.ShapeDtypeStruct((1, kv, t, d), jnp.bfloat16)
+    fn = lambda q, k, v: jnp.sum(
+        flash_mod.flash_attention(q, k, v, window=window).astype(jnp.float32))
+    return re.sub(r"0x[0-9a-f]+", "0x",
+                  str(jax.make_jaxpr(jax.grad(fn, argnums=(0, 1, 2)))(q, k, k)))
+
+
+@pytest.mark.parametrize("case", list(_TEXT_BEFORE_TWO_WIDTHS))
+def test_equal_widths_trace_to_the_text_they_did_before_two_widths(case, monkeypatch):
+    import hashlib
+
+    monkeypatch.setattr(flash_mod, "_use_interpret", lambda: False)
+    args, kw, want = _TEXT_BEFORE_TWO_WIDTHS[case]
+    text = _flash_text(*args, **kw)
+    assert "saturn_mla_" not in text
+    assert hashlib.sha256(text.encode()).hexdigest()[:16] == want

@@ -101,6 +101,7 @@ def test_dp_through_search_and_orchestrate_reproduces_the_reference(
     assert plan == {"impl": "xla", "tokens": 2 * SEQ, "experts": 16, "held": 4, "top_k": 4,
                     "row_tile": 8, "rows": 512 + 32, "worst_rows": 512 + 32,
                     "act": "swiglu", "latent": 0, "bias": False,   # (PR 42's fields)
+                    "groups": 0, "groups_kept": 0,                 # (PR 45's)
                     "second_path": False}
     assert "window_plan" not in configs[0]            # the masked einsum has no blocks
 

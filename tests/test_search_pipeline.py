@@ -377,6 +377,78 @@ def test_remat_points_are_prepared_first(run):
     assert [e["config"]["id"] for e in of_kind(events, "trial_config")] == want
 
 
+class Twinned(Stubbed):
+    """A grid whose points differ in ``remat`` alone: a stub is found by its
+    ``attention`` and its ``remat`` (``Stubbed`` finds it by an ``id`` no
+    two points share, so none of its points is another's twin)."""
+
+    def candidate_configs(self, task, n_devices):
+        return [{k: p[k] for k in ("attention", "remat")} for p in self.points]
+
+    def build(self, task, devices, config, use_cache=True):
+        (point,) = [p for p in self.points
+                    if (p["attention"], p["remat"]) == (config["attention"], config["remat"])]
+        key = point["id"]
+        if key not in self._built:
+            self.book.log("build", point)
+            self._built[key] = Bundle(point, self.book)
+        return self._built[key]
+
+
+#: how the ``remat: True`` point of a pair ends -> how its twin must
+TWINS = {
+    "refused": ({"compile": "refuse"}, "implied"),
+    "over": ({"need": HBM}, "implied"),
+    "fits": ({"step_s": 0.01}, "built"),
+}
+
+
+@pytest.mark.parametrize("case", sorted(TWINS))
+def test_a_point_is_over_memory_where_its_remat_twin_was(case, tmp_path, monkeypatch):
+    """Rematerialisation only lowers a program's peak: where the point with
+    it was refused by the compiler or by the memory rule, the one without it
+    ends ``memory_rejected`` and is never built; where it fitted, the one
+    without it is prepared as before. Another ``attention`` is no twin."""
+    monkeypatch.setenv("SATURN_TPU_HBM_BYTES", str(HBM))
+    frugal, want = TWINS[case]
+    tech = Twinned([
+        {"id": "plain-dense", "attention": "dense", "remat": False, "step_s": 0.02},
+        {"id": "plain-flash", "attention": "flash", "remat": False, "step_s": 0.02},
+        {"id": "remat-dense", "attention": "dense", "remat": True, **frugal},
+        {"id": "remat-flash", "attention": "flash", "remat": True, "step_s": 0.03},
+    ])
+    path = str(tmp_path / "twins.jsonl")
+    with metrics.scoped(path):
+        with metrics.span("search"):
+            best = within_limit(lambda: tech.search(Task("twins"), jax.devices()[:1], 0))
+    events = metrics.read_events(path)
+    report = tech.search_report("twins", 1)
+    notes = {(e["config"]["attention"], e["config"]["remat"]): e
+             for e in of_kind(events, "trial_config")}
+    spans_ = {(e["config"]["attention"], e["config"]["remat"]): e
+              for e in of_kind(events, "trial.config")}
+    assert len(notes) == len(spans_) == 4 and report["configs"] == 4
+    built = tech.book.order("build")
+    if want == "implied":
+        assert "plain-dense" not in built and built[:2] == ["remat-dense", "remat-flash"]
+        assert notes["dense", False]["memory_rejected"] is True
+        assert notes["dense", False]["implied_by"] == "remat"
+        assert spans_["dense", False]["outcome"] == "memory_rejected"
+        assert spans_["dense", False]["implied_by"] == "remat"
+        assert "step_traces" not in notes["dense", False]
+        assert "implied_by" not in notes["dense", True]
+        assert report["memory_rejected"] == 2
+        assert best[0] == {"attention": "flash", "remat": False}
+    else:
+        assert sorted(built) == ["plain-dense", "plain-flash", "remat-dense", "remat-flash"]
+        assert report["memory_rejected"] == 0
+        assert all("implied_by" not in e for e in notes.values())
+        assert best[0]["remat"] is True and best[0]["attention"] == "dense"
+    # the flash pair has its own twin, which fitted
+    assert "plain-flash" in built and "implied_by" not in notes["flash", False]
+    assert no_measuring_thread_left()
+
+
 def test_a_tie_goes_to_the_techniques_order(run, monkeypatch):
     monkeypatch.setattr(spmd_base, "time_fused_window",
                         lambda *a, **k: 0.125)

@@ -148,6 +148,47 @@ def test_gated_delta_rule_kernel_compiles_for_v5e(one_chip, real_lowering, grad)
         _compile(loss, *args, kernels=["saturn_gdn_fwd_only"])
 
 
+# ------------------------------- Kimi delta attention, latent attention
+@pytest.mark.parametrize("grad", [False, True], ids=["fwd", "grad"])
+def test_kda_scan_compiles_for_v5e(one_chip, real_lowering, grad):
+    """The delta rule's chunked scan (plain XLA ops: no kernel of it yet) at
+    the Ling cell's own shape: 32 heads of 128 keys and values, 8192 tokens
+    in chunks of 64 with 16-token sub-blocks, bf16 operands, a gate a key
+    channel in float32; the differentiated call keeps the chunks' states."""
+    from saturn_tpu.ops import kda as kda_mod
+
+    sds = lambda *shape, dtype=jnp.bfloat16: jax.ShapeDtypeStruct(
+        shape, dtype, sharding=one_chip)
+    f32 = jnp.float32
+    args = (sds(1, 32, 8192, 128), sds(1, 32, 8192, 128), sds(1, 32, 8192, 128),
+            sds(1, 32, 8192, 128, dtype=f32), sds(1, 32, 8192, dtype=f32))
+    loss = lambda *a: jnp.sum(kda_mod.kda(*a))
+    with kda_mod.traced_plans() as plans:
+        text = _compile(jax.grad(loss, argnums=(0, 1, 2, 3, 4)) if grad else loss,
+                        *args, kernels=[])
+    assert "tpu_custom_call" not in text
+    assert (plans[0].sub, plans[0].chunks) == (16, 128)
+    assert plans[0].state_bytes_kept == 128 * 32 * 128 * 128 * 4
+
+
+@pytest.mark.parametrize("grad", [False, True], ids=["fwd", "grad"])
+def test_latent_attention_flash_compiles_for_v5e(one_chip, real_lowering, grad):
+    """``saturn_mla_*`` at the Ling cell's own shape: 32 heads, q and k of
+    192 lanes (128 content + 64 rotary), v of 128, 8192 tokens: the walked
+    side goes in two chunks of 4096 (8192 x 192 is over the chunk guard)."""
+    sds = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one_chip)
+    args = (sds(1, 32, 8192, 192), sds(1, 32, 8192, 192), sds(1, 32, 8192, 128))
+    plan = flash_mod.flash_plan(8192, 192, d_v=128)
+    assert (plan["d_qk"], plan["d_v"], plan["fwd"]["chunk"], plan["fwd"]["block_q"]) == (
+        192, 128, 4096, 512)
+    if grad:
+        text = _compile(jax.grad(_flash_loss, argnums=(0, 1, 2)), *args,
+                        kernels=["saturn_mla_fwd", "saturn_mla_dq", "saturn_mla_dkv"])
+    else:
+        text = _compile(_flash_loss, *args, kernels=["saturn_mla_fwd"])
+    assert "saturn_flash_" not in text
+
+
 # ------------------------------------------- a kernel program's cache key
 @pytest.mark.parametrize("frames", [10, 4], ids=["ten-frames", "four-frames"])
 def test_a_kernel_programs_text_holds_its_callers_unless_locations_are_short(
@@ -257,6 +298,10 @@ CE_SHAPES = {
     # = 24.5 blocks of 512): the hybrid cell's head, stash mode (8192 x 12544
     # bf16 scores are 0.2 GB); dx asks for its VMEM as at d 4096
     "olmo-hybrid-8k": (8192, 3840, 12544),
+    # d 2560 = 20 x 128 lanes and the held eighth of a vocabulary (19712 rows
+    # = 154 x 128 = 38.5 blocks of 512): the Ling cell's head, a width no
+    # other cell runs (PR 45)
+    "ling-8k": (8192, 2560, 19712),
 }
 
 
@@ -304,7 +349,7 @@ def test_fused_ce_grad_compiles_for_v5e(one_chip, real_lowering, name, stash):
     assert _vmem_limit_of(text, "saturn_ce_dx") == plan.dx_vmem_limit
     assert _vmem_limit_of(text, "saturn_ce_fwd") is None
     assert _vmem_limit_of(text, "saturn_ce_dw") is None
-    assert (plan.dx_vmem_limit is not None) == (d in (3840, 4096)), plan
+    assert (plan.dx_vmem_limit is not None) == (d in (2560, 3840, 4096)), plan
     compiled = lowered.compile().as_text()
     for kernel in ("saturn_ce_fwd", "saturn_ce_dx", "saturn_ce_dw"):
         assert any(kernel in line for line in compiled.splitlines()

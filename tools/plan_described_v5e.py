@@ -51,7 +51,7 @@ def main() -> int:
     from saturn_tpu.parallel.dp import DataParallel
     from saturn_tpu.utils.timing import hbm_bytes_required
 
-    for mod in (ce, flash, gdn, ssd):       # lower the kernels, do not interpret
+    for mod in (ce, flash, gdn, ssd):  # lower the kernels, do not interpret
         mod._use_interpret = lambda: False
     moe._interpret = lambda: False
 
@@ -105,7 +105,8 @@ def main() -> int:
         kernels = sorted({name for name in (
             "saturn_flash_fwd", "saturn_flash_dq", "saturn_flash_dkv", "saturn_ssd_fwd",
             "saturn_gmm_fwd", "saturn_gmm_dx", "saturn_gmm_dw", "saturn_gdn_fwd",
-            "saturn_swa_fwd", "saturn_ce_fwd") if name in text})
+            "saturn_swa_fwd", "saturn_ce_fwd", "saturn_mla_fwd",
+            "saturn_mla_dq", "saturn_mla_dkv") if name in text})
         print(json.dumps({
             "workload": args.workload, "overrides": cfg["run"].get("overrides"),
             "seq": job.seq, "batch": job.batch, "k": args.k, "config": point,
