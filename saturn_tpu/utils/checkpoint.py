@@ -11,7 +11,9 @@ Format (round 19, ROADMAP item 6): **sharded manifest**. The logical
 checkpoint path holds a checksummed JSON manifest (tree structure, leaf
 dtypes/shapes, per-leaf shard index→file map, PartitionSpec fingerprint);
 the array bytes live beside it in per-rank ``.npz`` shard files named
-``<path>.g<GEN>.r<RANK>.npz``. Each process writes only its
+``<path>.g<GEN>.r<RANK>.npz`` (a rank whose members are 256 MB or more
+spreads them over up to four, ``...r<RANK>.l<LANE>.npz``: the manifest names
+each member's file, so every reader follows it). Each process writes only its
 locally-addressable shards — the device→host copy is a pure local transfer,
 with **no allgather and no replication funnel** (the SAT-X002 anti-pattern
 the previous single-writer format needed two sanction markers for). The
@@ -24,10 +26,17 @@ are garbage-collected only after it lands).
 
 A save is a **pipeline** (PR 27): the plan (manifest body and the ordered
 list of members this process fetches) comes from metadata alone; then the
-device→host copies run back to back while the writer thread is already
-putting the shards that have landed into the shard file. The caller is held
-until its last shard is on the host, the commit order is what it was, and
-``save`` is ``save_async`` joined.
+device→host copies run back to back while the shards that have landed are
+already being put into the shard file. The caller is held until its last
+shard is on the host, the commit order is what it was, and ``save`` is
+``save_async`` joined. The disk half runs on **lanes** (PR 46): the plan
+gives every member to a lane, largest first and each to the lane with the
+fewest bytes, and fetches in that order; a lane is a daemon thread and a
+shard file of its own; the save's one writer thread (``ckpt-<base>``, the
+only one the join points know) starts them, returns only when each has
+ended, and then renames their files and the manifest. How many lanes comes
+from the plan's bytes and members alone (``_LANE_MIN_BYTES``, ``_LANES``):
+one, and the file name and layout of every earlier save, under 256 MB.
 
 Saving by *path* rather than pickling tree structure is what makes
 interval-boundary **technique switching** work (the reference's central
@@ -70,8 +79,27 @@ log = logging.getLogger("saturn_tpu")
 MANIFEST_FORMAT = "saturn-ckpt-manifest"
 MANIFEST_VERSION = 1
 
-#: Shard files committed beside a manifest: ``<path>.g<GEN>.r<RANK>.npz``.
-_SHARD_RE = re.compile(r"\.g([0-9a-f]+)\.r(\d+)\.npz$")
+#: Shard files committed beside a manifest: ``<path>.g<GEN>.r<RANK>.npz``,
+#: or ``<path>.g<GEN>.r<RANK>.l<LANE>.npz`` for a rank whose save took
+#: several lanes. Two groups either way: generation and rank.
+_SHARD_RE = re.compile(r"\.g([0-9a-f]+)\.r(\d+)(?:\.l\d+)?\.npz$")
+
+#: A rank's members go into one shard file (today's name, one lane) while
+#: they are fewer bytes than this, and into ``_LANES`` files (fewer where
+#: the rank has fewer members), each written on a thread of its own, from
+#: this size on. Both are constants of the format's writer, read from the
+#: plan's metadata alone: the manifest's writer names the other ranks' files
+#: without talking to them, so nothing of the host (its cores) may enter.
+#: Measured on the benchmark's host (PR 46, ``tools/ckpt_lanes.py``, 7.3 GB
+#: from one chip, through ``write_array``): one lane 5.2-6.1 s a save, two
+#: 3.6-4.6, three 3.5-3.7, four 3.6-3.7, six 3.6-3.7: from three on the
+#: copies to the host and the writes share what that host gives both, and
+#: more lanes buy nothing.
+_LANE_MIN_BYTES = 256 << 20
+_LANES = 4
+#: A lane hands its members to its file through one buffer of this size
+#: (``_write_member``; 1 MiB pieces kept no pace, 8 and 16 MiB read slower).
+_PIECE_BYTES = 4 << 20
 
 
 class CheckpointCorruptError(RuntimeError):
@@ -308,18 +336,45 @@ def _pspec_fingerprint(tree: Any) -> str:
 class _Plan(NamedTuple):
     """The first stage of a save, from metadata alone (no device is touched
     and no array is copied): the global shard plan — the manifest body —
-    and, in the order the shard file will hold them, the ``(member,
-    source)`` pairs this process must fetch. A source is a single-device
-    ``jax.Array`` (a shard this process owns; fetched by a pure local
-    device→host copy, never a gather) or a host ``ndarray`` (written by the
-    tree's writer rank)."""
+    and, in the order they are fetched (the largest first, so that what is
+    left to write when the last one lands is small), the ``(member,
+    source)`` pairs this process must fetch, each with the lane that writes
+    it. A source is a single-device ``jax.Array`` (a shard this process
+    owns; fetched by a pure local device→host copy, never a gather) or a
+    host ``ndarray`` (written by the tree's writer rank)."""
 
     manifest: Dict[str, Any]
     fetch: List[Tuple[str, Any]]
+    lane_of: List[int]  #: parallel to ``fetch``: index into ``files``
+    files: List[str]  #: this rank's shard files (base names), one a lane
     rank: int
     gen: str
     writes_manifest: bool
     nbytes: int  #: bytes this process copies to the host and writes
+
+
+def _assign_lanes(stem: str, shards: List[Tuple[int, Dict[str, Any]]]
+                  ) -> Tuple[List[str], List[int], List[int]]:
+    """Spread one rank's members over its lanes and name each one's file in
+    its manifest entry. ``shards`` is ``(bytes, manifest shard entry)`` in
+    tree order; the result is the rank's file names, one a lane, then the
+    order to fetch in (indices into ``shards``, the largest first) and each
+    fetched member's lane. Largest first, each to the lane with the fewest
+    bytes so far, so that the lanes end together. Every process computes
+    the same answer for every rank: nothing but the plan enters."""
+    total = sum(n for n, _ in shards)
+    lanes = 1 if total < _LANE_MIN_BYTES else min(_LANES, len(shards))
+    files = ([f"{stem}.npz"] if lanes == 1
+             else [f"{stem}.l{k}.npz" for k in range(lanes)])
+    order = sorted(range(len(shards)), key=lambda i: -shards[i][0])  # stable
+    held = [0] * lanes
+    lane_of = []
+    for i in order:
+        k = held.index(min(held))
+        held[k] += shards[i][0]
+        shards[i][1]["file"] = files[k]
+        lane_of.append(k)
+    return files, order, lane_of
 
 
 def _plan(path: str, tree: Any) -> _Plan:
@@ -328,8 +383,10 @@ def _plan(path: str, tree: Any) -> _Plan:
     wrank = _writer_rank(tree)
     base = os.path.basename(path)
     leaves: Dict[str, Any] = {}
-    fetch: List[Tuple[str, Any]] = []
-    nbytes = 0
+    # every rank's members, as (bytes, manifest shard entry), in tree order;
+    # the sources of this rank's, in the same order
+    owned: Dict[int, List[Tuple[int, Dict[str, Any]]]] = {}
+    sources: List[Any] = []
     for tpath, leaf in jax.tree_util.tree_flatten_with_path(tree)[0]:
         key = _path_str(tpath)
         if key in leaves:
@@ -360,34 +417,42 @@ def _plan(path: str, tree: Any) -> _Plan:
                                    getattr(d, "id", 0)),
                 )
                 orank = getattr(owner, "process_index", 0)
-                member = f"{key}#s{i}"
                 shards.append({
                     "index": [[a, b] for a, b in extent],
-                    "file": f"{base}.g{gen}.r{orank}.npz",
-                    "key": member,
+                    "file": None,  # its rank's lane: _assign_lanes
+                    "key": f"{key}#s{i}",
                 })
+                owned.setdefault(orank, []).append((
+                    stored_dtype.itemsize * math.prod(
+                        b - a for a, b in extent), shards[-1]))
                 if orank == rank:
-                    fetch.append((member, by_dev_id[getattr(owner, "id", 0)].data))
-                    nbytes += stored_dtype.itemsize * math.prod(
-                        b - a for a, b in extent)
+                    sources.append(by_dev_id[getattr(owner, "id", 0)].data)
         else:
             # Host (plain numpy / python scalar) leaf: one full-extent
             # shard, written by the tree's writer rank.
-            member = f"{key}#s0"
             shards.append({
                 "index": [[0, d] for d in shape],
-                "file": f"{base}.g{gen}.r{wrank}.npz",
-                "key": member,
+                "file": None,
+                "key": f"{key}#s0",
             })
+            owned.setdefault(wrank, []).append(
+                (leaf.size * stored_dtype.itemsize, shards[-1]))
             if rank == wrank:
-                fetch.append((member, leaf))
-                nbytes += leaf.size * stored_dtype.itemsize
+                sources.append(leaf)
         leaves[key] = {
             "shape": list(shape),
             "dtype": str(leaf.dtype),
             "stored_dtype": str(stored_dtype),
             "shards": shards,
         }
+    files: List[str] = []
+    fetch: List[Tuple[str, Any]] = []
+    lane_of: List[int] = []
+    for r, mine in owned.items():
+        r_files, order, r_lane_of = _assign_lanes(f"{base}.g{gen}.r{r}", mine)
+        if r == rank:
+            files, lane_of = r_files, r_lane_of
+            fetch = [(mine[i][1]["key"], sources[i]) for i in order]
     manifest = {
         "format": MANIFEST_FORMAT,
         "version": MANIFEST_VERSION,
@@ -395,7 +460,9 @@ def _plan(path: str, tree: Any) -> _Plan:
         "pspec_fingerprint": _pspec_fingerprint(tree),
         "leaves": leaves,
     }
-    return _Plan(manifest, fetch, rank, gen, rank == wrank, nbytes)
+    nbytes = sum(n for n, _ in owned.get(rank, ()))
+    return _Plan(manifest, fetch, lane_of, files, rank, gen, rank == wrank,
+                 nbytes)
 
 
 def _gc_stale_generations(path: str, keep_gen: str) -> None:
@@ -412,112 +479,232 @@ def _gc_stale_generations(path: str, keep_gen: str) -> None:
 
 
 # ------------------------------------------------------------- the pipeline
-# A save is three stages. (1) ``_plan``: the manifest and the list of
-# members to fetch, from metadata. (2) The caller's thread copies the shards
-# to the host one after another, in plan order, and hands each to the writer
-# as it lands. (3) The writer thread, started before the first shard is
-# waited for, puts each member into the shard file as it arrives, and after
-# the last one commits exactly as before: shard rename, then (manifest
-# writer only) manifest rename, publication, GC. End-of-interval
+# A save is three stages. (1) ``_plan``: the manifest, the list of members to
+# fetch (the largest first) and each one's lane, from metadata. (2) The
+# caller's thread copies the shards to the host one after another, in plan
+# order, and hands each to its lane as it lands. (3) The lanes, a thread and
+# a shard file each, started before the first shard is waited for, put each
+# member into their file as it arrives; when every lane has ended, the
+# save's one writer thread commits exactly as before: shard renames, then
+# (manifest writer only) manifest rename, publication, GC. End-of-interval
 # checkpoints are GB-scale (full train state incl. optimizer): a save that
 # is joined right away — the last interval of any job, every interval of a
-# multi-host one — costs the longer of copy and write, not their sum.
+# multi-host one — costs the longer of copy and write, not their sum, and
+# with lanes enough (``_LANES``) the write is the shorter of the two.
 #
 # The caller's thread is held exactly until its last local shard is on the
 # host (the engine may donate the buffers into the next interval's first
-# step), and never by the writer: the hand-off queue is unbounded. One
-# writer thread per path; restore() and a second save to the same path
-# wait for the in-flight write first. A failed write is recorded per path
-# and re-raised at the next join point (exists/restore/save_async/flush) —
-# a checkpoint that never hit disk must not be silently reported as saved.
+# step), and never by a lane: the hand-off queues are unbounded. One
+# writer thread per path (``ckpt-<base>``, the one in ``_PENDING``);
+# restore() and a second save to the same path wait for the in-flight write
+# first. A failed write is recorded per path and re-raised at the next join
+# point (exists/restore/save_async/flush) — a checkpoint that never hit
+# disk must not be silently reported as saved.
+#
+# Who ends whom. The writer thread starts the lanes (daemon threads named
+# ``ckpt-<base>.l<k>``) and returns only when every one has: joining it is
+# joining the whole save, and no thread, pool or process outlives a save.
+# A lane blocks in one place, its inbox, and a mark is certain to come
+# there: ``_LANDED_ALL`` or ``_FETCH_FAILED`` from the caller's thread on
+# every way out of its loop, ``_STOPPED`` from whichever thread failed first
+# (another lane, the writer thread). The first error is the save's error;
+# the others stop at their next member, every temp file and every
+# file of the new generation is removed, and nothing is committed.
 _PENDING: Dict[str, threading.Thread] = {}
 _FAILED: Dict[str, BaseException] = {}
 _PENDING_LOCK = threading.Lock()
 
-_LANDED_ALL = object()  # hand-off marks: every member is in the queue /
-_FETCH_FAILED = object()  # a fetch raised, commit nothing
+_LANDED_ALL = object()  # hand-off marks: every member is in the queues /
+_FETCH_FAILED = object()  # a fetch raised, commit nothing /
+_STOPPED = object()  # another thread of the save failed, commit nothing
 
 
 class _SaveAborted(Exception):
-    """Raised on the writer thread when the caller's thread gave up."""
+    """Raised on a lane or the writer thread when the save was given up
+    elsewhere: the thread that gave it up raises what stopped it."""
+
+
+class _Lane:
+    """One shard file of a save in flight and the queue that feeds it:
+    ``inbox`` carries ``(member, ndarray)`` in plan order and then a mark."""
+
+    __slots__ = ("file", "inbox", "starved_s", "tmp", "nbytes", "n_members")
+    # ``nbytes`` / ``n_members``: what the lane has put into its file so far
+
+    def __init__(self, file: str):
+        self.file = file
+        self.inbox: "queue.SimpleQueue" = queue.SimpleQueue()
+        self.starved_s = 0.0
+        self.tmp: Optional[str] = None  # the staged file, until its rename
+        self.nbytes = 0
+        self.n_members = 0
 
 
 class _Stream:
-    """One save in flight: what the caller's thread and the writer thread
-    share. ``inbox`` carries ``(member, ndarray)`` in plan order and then
-    one of the two marks; ``snapshot_end`` (``perf_counter``) is stamped
-    before the mark is put; ``error`` is what the writer died of."""
+    """One save in flight: what the caller's thread, the writer thread and
+    the lanes share. ``snapshot_end`` (``perf_counter``) is stamped before
+    the marks are put; ``stop`` is set once nothing will be committed;
+    ``error`` is what the save died of (the first failure, kept)."""
 
-    __slots__ = ("inbox", "thread", "error", "starved_s", "snapshot_end")
+    __slots__ = ("lanes", "thread", "error", "stop", "snapshot_end", "_lock")
 
-    def __init__(self):
-        self.inbox: "queue.SimpleQueue" = queue.SimpleQueue()
+    def __init__(self, files: List[str]):
+        self.lanes = [_Lane(f) for f in files]
         self.thread: Optional[threading.Thread] = None
         self.error: Optional[BaseException] = None
-        self.starved_s = 0.0
+        self.stop = threading.Event()
         self.snapshot_end: Optional[float] = None
+        self._lock = threading.Lock()
 
-    def landed(self):
-        """Writer side: the members as they arrive, until the mark."""
+    def landed(self, lane: _Lane):
+        """Lane side: its members as they arrive, until the mark."""
         while True:
             t0 = time.perf_counter()
-            item = self.inbox.get()
-            self.starved_s += time.perf_counter() - t0
+            item = lane.inbox.get()
+            lane.starved_s += time.perf_counter() - t0
+            if self.stop.is_set() or item is _FETCH_FAILED:
+                raise _SaveAborted()
             if item is _LANDED_ALL:
                 return
-            if item is _FETCH_FAILED:
-                raise _SaveAborted()
             yield item
 
+    def _mark(self, mark: object) -> None:
+        for lane in self.lanes:
+            lane.inbox.put(mark)
+
     def end_snapshot(self, mark: object) -> None:
+        """Caller's side, on every way out of its loop."""
         self.snapshot_end = time.perf_counter()
-        self.inbox.put(mark)
+        if mark is not _LANDED_ALL:
+            self.stop.set()
+        self._mark(mark)
+
+    def fail(self, err: BaseException) -> None:
+        """A lane or the writer thread died of ``err``: keep the first
+        error, stop the save, wake every lane that waits for a member."""
+        with self._lock:
+            if self.error is None:
+                self.error = err
+        self.stop.set()
+        self._mark(_STOPPED)
 
 
-def _commit_streamed(path: str, plan: _Plan, stream: _Stream) -> None:
-    """The disk half of a save, on the writer thread: stage this rank's
-    shard file member by member as the shards land, rename it, then
-    (manifest writer only) stage + rename the manifest — the atomic commit
-    point — and notify publication. Crash-barrier crossings bracket both
-    renames; a kill at either leaves the previous generation untouched."""
+def _write_member(zf: zipfile.ZipFile, member: str, arr: np.ndarray,
+                  piece: np.ndarray) -> None:
+    """One member of a shard file, as ``np.savez`` writes it (the same
+    ``.npy`` header, the same bytes, the zip's CRC-32 over them), handed to
+    the file through ``piece``, the lane's one reused buffer. Both other
+    ways were measured on the benchmark's host (PR 46, ``tools/
+    ckpt_lanes.py``, 7.3 GB on four lanes): ``write_array`` allocates a
+    16 MiB copy of every piece it writes, and the save is 3.6-3.7 s where
+    it is 3.1-3.2 through a reused one (fresh pages are what copies and
+    writes contend for there); a ``write`` straight from the array's own
+    buffer, the memory the device→host copy has just filled, holds the
+    next copy back (8-10 s)."""
+    with zf.open(member + ".npy", "w", force_zip64=True) as fid:
+        if not arr.flags.c_contiguous or arr.dtype.kind not in "biufc":
+            np.lib.format.write_array(fid, arr, allow_pickle=False)
+            return
+        np.lib.format.write_array_header_1_0(
+            fid, np.lib.format.header_data_from_array_1_0(arr))
+        raw = arr.reshape(-1).view(np.uint8)
+        for at in range(0, raw.size, piece.size):
+            n = min(piece.size, raw.size - at)
+            np.copyto(piece[:n], raw[at:at + n])
+            fid.write(piece[:n])
+
+
+def _run_lane(d: str, plan: _Plan, stream: _Stream, lane: _Lane,
+              above: Optional[metrics.span]) -> None:
+    """A lane's thread: stage its shard file member by member as its shards
+    land, and cross ``mid-shard-write`` once the bytes are staged. The
+    rename is the writer thread's. Never raises: what it died of stops the
+    save (``stream.fail``)."""
+    try:
+        with metrics.span("ckpt.lane", parent=above,
+                          file=lane.file) as wrote:
+            try:
+                fd, lane.tmp = tempfile.mkstemp(dir=d, suffix=".tmp")
+                # what np.savez does per member, one member at a time: the
+                # file is the same .npz
+                piece = np.empty(_PIECE_BYTES, np.uint8)
+                with os.fdopen(fd, "wb") as f, \
+                        zipfile.ZipFile(f, "w", allowZip64=True) as zf:
+                    for member, arr in stream.landed(lane):
+                        _write_member(zf, member, arr, piece)
+                        lane.nbytes += arr.nbytes
+                        lane.n_members += 1
+                _barrier("mid-shard-write", path=os.path.join(d, lane.file),
+                         tmp=lane.tmp, gen=plan.gen)
+            finally:
+                wrote.set(bytes=lane.nbytes, n_members=lane.n_members,
+                          starved_s=lane.starved_s)
+    except _SaveAborted:
+        pass
+    except BaseException as e:  # SimulatedKill included
+        stream.fail(e)
+
+
+def _commit_streamed(path: str, plan: _Plan, stream: _Stream,
+                     above: Optional[metrics.span]) -> None:
+    """The disk half of a save, on the writer thread: run this rank's lanes
+    to their end, rename every lane's shard file, then (manifest writer
+    only) stage + rename the manifest — the atomic commit point — and
+    notify publication. Crash-barrier crossings precede both kinds of
+    rename; a kill at either leaves the previous generation untouched.
+    Returns, or raises, only after every lane it started has ended."""
     d = os.path.dirname(os.path.abspath(path))
-    os.makedirs(d, exist_ok=True)
-    if plan.fetch:
-        fname = os.path.join(d, f"{os.path.basename(path)}"
-                                f".g{plan.gen}.r{plan.rank}.npz")
+    base = os.path.basename(path)
+    lanes = [threading.Thread(target=_run_lane, name=f"ckpt-{base}.l{k}",
+                              args=(d, plan, stream, lane, above),
+                              daemon=True)
+             for k, lane in enumerate(stream.lanes)]
+    started: List[threading.Thread] = []
+    renamed: List[str] = []
+    committed = False
+    try:
+        try:
+            os.makedirs(d, exist_ok=True)
+            for t in lanes:
+                t.start()
+                started.append(t)
+        except BaseException as e:
+            stream.fail(e)
+        for t in started:
+            t.join()
+        if stream.error is not None:
+            raise stream.error
+        if stream.stop.is_set():
+            raise _SaveAborted()
+        for lane in stream.lanes:
+            fname = os.path.join(d, lane.file)
+            os.replace(lane.tmp, fname)
+            renamed.append(fname)
+        if not plan.writes_manifest:
+            committed = True
+            return
+        body = dict(plan.manifest)
+        body["checksum"] = _manifest_checksum(body)
         fd, tmp = tempfile.mkstemp(dir=d, suffix=".tmp")
         try:
-            # what np.savez does per member, one member at a time: the
-            # file is the same .npz
-            with os.fdopen(fd, "wb") as f, \
-                    zipfile.ZipFile(f, "w", allowZip64=True) as zf:
-                for member, arr in stream.landed():
-                    with zf.open(member + ".npy", "w",
-                                 force_zip64=True) as fid:
-                        np.lib.format.write_array(fid, arr,
-                                                  allow_pickle=False)
-            _barrier("mid-shard-write", path=fname, tmp=tmp, gen=plan.gen)
-            os.replace(tmp, fname)
+            with os.fdopen(fd, "w", encoding="utf-8") as f:
+                json.dump(body, f, separators=(",", ":"))
+            _barrier("pre-manifest-rename", path=path, tmp=tmp, gen=plan.gen)
+            os.replace(tmp, path)  # atomic: no torn checkpoints on crash
+            committed = True
+            _notify_published(path)
         finally:
             if os.path.exists(tmp):
                 os.unlink(tmp)
-    else:
-        for _ in stream.landed():  # no local shard: only the mark comes
-            pass
-    if not plan.writes_manifest:
-        return
-    body = dict(plan.manifest)
-    body["checksum"] = _manifest_checksum(body)
-    fd, tmp = tempfile.mkstemp(dir=d, suffix=".tmp")
-    try:
-        with os.fdopen(fd, "w", encoding="utf-8") as f:
-            json.dump(body, f, separators=(",", ":"))
-        _barrier("pre-manifest-rename", path=path, tmp=tmp, gen=plan.gen)
-        os.replace(tmp, path)  # atomic: no torn checkpoints on crash
-        _notify_published(path)
     finally:
-        if os.path.exists(tmp):
-            os.unlink(tmp)
+        doomed = [lane.tmp for lane in stream.lanes if lane.tmp]
+        if not committed:
+            doomed += renamed  # of a generation no manifest will name
+        for f in doomed:
+            try:
+                os.unlink(f)
+            except OSError:
+                pass  # renamed away, or never made
     _gc_stale_generations(path, plan.gen)
 
 
@@ -558,15 +745,16 @@ def _fetch(source: Any) -> np.ndarray:
 
 def _start_save(path: str, tree: Any, park_failure: bool) -> _Stream:
     """Run the pipeline up to the moment the last local shard is on the
-    host and handed to the writer; the writer thread is still running on
-    return. A fetch that raises stops the writer (nothing is committed, the
-    temp file is removed) and propagates from here. What the *writer* dies
-    of is left in ``stream.error`` and, with ``park_failure``, parked for
-    the path's next join point."""
+    host and handed to its lane; the writer thread and its lanes are still
+    running on return. A fetch that raises stops them (nothing is
+    committed, the temp files are removed) and propagates from here. What
+    the *writer* or a lane dies of is left in ``stream.error`` and, with
+    ``park_failure``, parked for the path's next join point."""
     base = os.path.basename(path)
     key = os.path.abspath(path)
     # ``ckpt.write`` is its snapshot's sibling, not its child: the two
     # overlap, and a span's self time is its duration minus its children.
+    # The lanes' ``ckpt.lane`` are its siblings for the same reason.
     above = metrics.current_span()
     # Both stretches are on the caller's thread — the gang's: training is
     # stalled for as long as they last (``ckpt_stall`` reads them).
@@ -574,29 +762,32 @@ def _start_save(path: str, tree: Any, park_failure: bool) -> _Stream:
         _wait_pending(path)  # at most one in-flight write per path
     with metrics.span("ckpt.snapshot", path=base) as snapped:
         plan = _plan(path, tree)
-        stream = _Stream()
+        stream = _Stream(plan.files)
 
         def write():
             try:
                 with metrics.span("ckpt.write", parent=above, path=base,
                                   bytes=plan.nbytes,
-                                  n_shards=len(plan.fetch)) as wrote:
+                                  n_shards=len(plan.fetch),
+                                  lanes=len(stream.lanes)) as wrote:
                     t0 = time.perf_counter()
                     try:
-                        _commit_streamed(path, plan, stream)
+                        _commit_streamed(path, plan, stream, above)
                     finally:
                         t1 = time.perf_counter()
                         end = stream.snapshot_end  # None: still snapshotting
                         under = t1 if end is None else min(t1, end)
                         wrote.set(overlap_s=max(under - t0, 0.0),
-                                  starved_s=stream.starved_s)
+                                  starved_s=max(
+                                      (l.starved_s for l in stream.lanes),
+                                      default=0.0))
             except _SaveAborted:
                 pass  # the caller's thread raises what stopped it
             except BaseException as e:  # re-raised at a join point
-                stream.error = e
+                stream.fail(e)
                 if park_failure:
                     log.exception("async checkpoint write to %s failed", path)
-                    _record_async_failure(key, path, e)
+                    _record_async_failure(key, path, stream.error)
             finally:
                 with _PENDING_LOCK:
                     if _PENDING.get(key) is threading.current_thread():
@@ -604,19 +795,23 @@ def _start_save(path: str, tree: Any, park_failure: bool) -> _Stream:
 
         stream.thread = threading.Thread(target=write, name=f"ckpt-{base}",
                                          daemon=True)
-        with _PENDING_LOCK:
-            _PENDING[key] = stream.thread
-        stream.thread.start()
         n_streamed = 0
         try:
-            for member, source in plan.fetch:
-                if not stream.thread.is_alive():
-                    break  # the writer failed: nothing left to feed
-                stream.inbox.put((member, _fetch(source)))
+            with _PENDING_LOCK:
+                _PENDING[key] = stream.thread
+            stream.thread.start()
+            for (member, source), k in zip(plan.fetch, plan.lane_of):
+                if stream.stop.is_set():
+                    break  # the write failed: nothing left to feed
+                stream.lanes[k].inbox.put((member, _fetch(source)))
                 n_streamed += 1
         except BaseException:
             stream.end_snapshot(_FETCH_FAILED)
-            stream.thread.join()
+            if stream.thread.ident is not None:  # it was started
+                stream.thread.join()
+            with _PENDING_LOCK:
+                if _PENDING.get(key) is stream.thread:
+                    del _PENDING[key]
             raise
         stream.end_snapshot(_LANDED_ALL)
         snapped.set(bytes=plan.nbytes, n_streamed=n_streamed)

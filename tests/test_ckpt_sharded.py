@@ -10,6 +10,7 @@ and what the consumers observe.
 
 import json
 import os
+import re
 import threading
 import zipfile
 
@@ -57,8 +58,49 @@ def _no_leaked_crash_barrier():
     ckpt.set_crash_barrier(None)
 
 
+@pytest.fixture(params=["one_lane", "several_lanes"])
+def lanes(request, monkeypatch):
+    """PR 46: a save under ``_LANE_MIN_BYTES`` takes one lane and today's
+    file name; the tests lower that private constant (no knob reads it) so
+    that their small trees take ``_LANES`` lanes, a shard file each."""
+    if request.param == "several_lanes":
+        monkeypatch.setattr(ckpt, "_LANE_MIN_BYTES", 1)
+    return request.param
+
+
+def n_lanes(lanes, members):
+    return 1 if lanes == "one_lane" else min(ckpt._LANES, members)
+
+
+def ckpt_threads():
+    return sorted(t.name for t in threading.enumerate()
+                  if t.name.startswith("ckpt-"))
+
+
+def within(seconds, fn):
+    """Run ``fn`` with a time limit of its own: a save that hangs fails
+    here in seconds and holds no worker until the suite's clock. Returns
+    what ``fn`` returned or raises what it raised."""
+    box = []
+
+    def run():
+        try:
+            box.append((True, fn()))
+        except BaseException as e:
+            box.append((False, e))
+
+    t = threading.Thread(target=run, daemon=True, name="limited")
+    t.start()
+    t.join(seconds)
+    assert not t.is_alive(), f"still running after {seconds} s: it hangs"
+    ok, what = box[0]
+    if not ok:
+        raise what
+    return what
+
+
 class TestManifestFormat:
-    def test_manifest_and_shard_layout(self, tmp_path, devices8):
+    def test_manifest_and_shard_layout(self, tmp_path, devices8, lanes):
         state = make_state(mesh_of(4))
         path = str(tmp_path / "t.npz")
         ckpt.save(path, state)
@@ -81,13 +123,36 @@ class TestManifestFormat:
         shard_files = [
             n for n in os.listdir(tmp_path) if ckpt._SHARD_RE.search(n)
         ]
-        assert shard_files, "no shard files written"
+        assert len(shard_files) == n_lanes(lanes, 9)  # 4 + 4 + 1 members
+        named = {sh["file"] for leaf in man["leaves"].values()
+                 for sh in leaf["shards"]}
+        assert named == set(shard_files)
+        keys = set()
         for n in shard_files:
             assert n.startswith("t.npz.g")
+            # one lane: exactly the name every earlier save had
+            assert bool(re.fullmatch(r"t\.npz\.g[0-9a-f]+\.r0\.npz", n)) == (
+                lanes == "one_lane")
+            assert re.fullmatch(
+                r"t\.npz\.g[0-9a-f]+\.r0(\.l[0-3])?\.npz", n)
             assert zipfile.is_zipfile(tmp_path / n)
+            with zipfile.ZipFile(tmp_path / n) as zf:
+                assert zf.testzip() is None
+            with np.load(tmp_path / n) as z:  # plain numpy reads a lane
+                assert z.files and all(z[k] is not None for k in z.files)
+                keys.update(z.files)
+        assert len(keys) == 9  # every member in exactly one lane's file
         assert ckpt.verify(path)
+        summ = ckpt.summarize(path)
+        assert summ["ok"] and summ["shard_files"] == len(shard_files)
+        assert summ["shards"] == 9
+        inv = ckpt.summarize_dir(str(tmp_path))
+        assert inv["orphan_shards"] == [] and len(inv["checkpoints"]) == 1
+        ckpt.delete(path)
+        assert os.listdir(tmp_path) == []  # every lane's file went with it
 
-    def test_cross_technique_chain_bit_identical(self, tmp_path, devices8):
+    def test_cross_technique_chain_bit_identical(self, tmp_path, devices8,
+                                                 lanes):
         """dp -> fsdp-style resharded save -> tp-style columns: the bytes
         survive two migrations (per-leaf tobytes, the ISSUE acceptance)."""
         path = str(tmp_path / "t.npz")
@@ -119,7 +184,8 @@ class TestManifestFormat:
             b = got["params"][key.split("/")[1]] if "/" in key else got[key]
             assert a.tobytes() == b.tobytes(), key
 
-    def test_resave_garbage_collects_old_generation(self, tmp_path, devices8):
+    def test_resave_garbage_collects_old_generation(self, tmp_path, devices8,
+                                                    lanes):
         state = make_state(mesh_of(4))
         path = str(tmp_path / "t.npz")
         ckpt.save(path, state)
@@ -127,6 +193,7 @@ class TestManifestFormat:
         ckpt.save(path, state)
         gen2 = {n for n in os.listdir(tmp_path) if ckpt._SHARD_RE.search(n)}
         assert gen1.isdisjoint(gen2), "stale generation not collected"
+        assert len(gen1) == len(gen2) == n_lanes(lanes, 9)
         assert ckpt.verify(path)
 
     def test_tampered_manifest_quarantined(self, tmp_path, devices8):
@@ -143,17 +210,18 @@ class TestManifestFormat:
             ckpt.load_arrays(path)
         assert os.path.exists(path + ".corrupt")
 
-    def test_missing_shard_file_quarantined(self, tmp_path, devices8):
+    def test_missing_shard_file_quarantined(self, tmp_path, devices8, lanes):
         state = make_state(mesh_of(4))
         path = str(tmp_path / "t.npz")
         ckpt.save(path, state)
-        victim = next(
+        victim = sorted(
             n for n in os.listdir(tmp_path) if ckpt._SHARD_RE.search(n)
-        )
+        )[-1]  # the only file, or the last lane's
         os.unlink(tmp_path / victim)
         assert not ckpt.verify(path)
         with pytest.raises(ckpt.CheckpointCorruptError):
             ckpt.load_arrays(path)
+        assert os.path.exists(path + ".corrupt")
 
 
 class TestCompatReader:
@@ -277,8 +345,9 @@ class Recorder:
         self.members = []  # the plan's, in its order
         self.first_written = threading.Event()
         self.seen_in_tmp = None
+        self.first_nbytes = None  # of the first member a lane wrote whole
         self.n_fetch = 0
-        real_fetch, real_write = ckpt._fetch, np.lib.format.write_array
+        real_fetch, real_write = ckpt._fetch, ckpt._write_member
         real_plan = ckpt._plan
 
         def plan(path, tree):
@@ -293,21 +362,24 @@ class Recorder:
                 raise OSError(f"no shard {k} for you")
             if hold_last and k == len(self.members) - 1:
                 assert self.first_written.wait(60), "writer never wrote"
-                (tmp,) = litter(directory)
-                self.seen_in_tmp = os.path.getsize(
-                    os.path.join(directory, tmp))
+                tmps = litter(directory)  # one a lane
+                assert tmps
+                self.seen_in_tmp = max(os.path.getsize(
+                    os.path.join(directory, tmp)) for tmp in tmps)
             self.events.append(("fetched", self.members[k]))
             return real_fetch(source)
 
-        def write_array(fid, arr, **kw):
-            real_write(fid, arr, **kw)
-            fid.flush()
-            self.events.append(("written", arr.shape))
+        def write_member(zf, member, arr, piece):
+            real_write(zf, member, arr, piece)
+            zf.fp.flush()
+            self.events.append(("written", member))
+            if not self.first_written.is_set():
+                self.first_nbytes = arr.nbytes
             self.first_written.set()
 
         monkeypatch.setattr(ckpt, "_plan", plan)
         monkeypatch.setattr(ckpt, "_fetch", fetch)
-        monkeypatch.setattr(np.lib.format, "write_array", write_array)
+        monkeypatch.setattr(ckpt, "_write_member", write_member)
 
 
 @pytest.fixture
@@ -325,7 +397,7 @@ class TestStreamedSave:
     @pytest.mark.parametrize("layout", LAYOUTS)
     @pytest.mark.parametrize("mode", MODES)
     def test_every_reader_reads_it_and_savez_writes_the_same_file(
-            self, tmp_path, mode, layout):
+            self, tmp_path, mode, layout, lanes):
         tree = stream_tree(layout)
         want = {
             "params/a": A,
@@ -357,6 +429,8 @@ class TestStreamedSave:
             assert summ["leaves"] == 4 and summ["shards"] == n_members(layout)
 
         read_all()
+        assert ckpt_threads() == []  # the flush joined every lane
+        assert len(shard_files(tmp_path)) == n_lanes(lanes, n_members(layout))
         with open(path) as f:
             assert json.load(f)["version"] == 1
         # the same members through plain np.savez, under the same manifest:
@@ -372,28 +446,65 @@ class TestStreamedSave:
             assert zip_directory(full) == streamed
         read_all()
 
+    def test_members_of_every_kind_are_the_bytes_savez_writes(
+            self, tmp_path, monkeypatch, lanes):
+        """A lane hands a member to its file through a reused piece (PR 46);
+        what is not plain C-ordered numbers goes through ``write_array``
+        as before. Either way the file is the one ``np.savez`` writes."""
+        monkeypatch.setattr(ckpt, "_PIECE_BYTES", 1 << 10)  # many pieces
+        tree = {
+            "big": np.arange(5000, dtype=np.float64).reshape(50, 100),
+            "fortran": np.asfortranarray(np.arange(12.0).reshape(3, 4)),
+            "strided": np.arange(40, dtype=np.int16)[::3],
+            "flags": np.array([True, False, True]),
+            "z": np.array([1 + 2j, 3 - 4j], dtype=np.complex64),
+            "words": np.array(["ab", "c"]),
+            "none": np.zeros((0, 7), np.float32),
+            "point": np.asarray(2.5, np.float32),
+        }
+        path = str(tmp_path / "t.npz")
+        ckpt.save(path, tree)
+        assert ckpt.verify(path)
+        got = ckpt.load_arrays(path)
+        for k, v in tree.items():
+            assert got[k].dtype == v.dtype and got[k].shape == v.shape, k
+            assert got[k].tobytes() == np.ascontiguousarray(v).reshape(
+                v.shape).tobytes(), k
+        for name in shard_files(tmp_path):
+            full = str(tmp_path / name)
+            streamed = zip_directory(full)
+            with np.load(full) as z:
+                members = {k: z[k] for k in z.files}
+            with open(full, "wb") as f:
+                np.savez(f, **members)
+            assert zip_directory(full) == streamed
+
     @pytest.mark.parametrize("layout", LAYOUTS)
     @pytest.mark.parametrize("mode", MODES)
     def test_first_member_is_on_disk_before_the_last_is_fetched(
-            self, tmp_path, monkeypatch, mode, layout):
+            self, tmp_path, monkeypatch, mode, layout, lanes):
         rec = Recorder(monkeypatch, str(tmp_path), hold_last=True)
         path = str(tmp_path / "t.npz")
         run_save(mode, path, stream_tree(layout))
         members = rec.members
         assert len(members) == n_members(layout)
         fetched = [e for e in rec.events if e[0] == "fetched"]
-        assert [m for _, m in fetched] == members  # plan order
+        assert [m for _, m in fetched] == members  # plan order:
+        assert members[0].startswith("params/a#")  # the largest first,
+        assert members[-1] == "step#s0"  # the smallest last
         first_write = next(i for i, e in enumerate(rec.events)
                            if e[0] == "written")
         assert first_write < rec.events.index(("fetched", members[-1]))
         # ... and its bytes were in the shard file's temp file by then
         first = A.nbytes // (4 if layout == "sharded" else 1)
-        assert rec.seen_in_tmp is not None and rec.seen_in_tmp >= first
+        if lanes == "several_lanes":  # whichever lane ended a member first
+            first = rec.first_nbytes
+        assert rec.seen_in_tmp is not None and rec.seen_in_tmp >= first > 0
         assert not litter(tmp_path) and ckpt.verify(path)
 
     @pytest.mark.parametrize("layout", LAYOUTS)
     def test_save_async_returns_with_every_shard_on_the_host(
-            self, tmp_path, monkeypatch, layout):
+            self, tmp_path, monkeypatch, layout, lanes):
         rec = Recorder(monkeypatch, str(tmp_path))
         tree = stream_tree(layout)
         path = str(tmp_path / "t.npz")
@@ -416,57 +527,57 @@ class TestStreamedSave:
 
     @pytest.mark.parametrize("layout", LAYOUTS)
     @pytest.mark.parametrize("mode", MODES)
+    @pytest.mark.parametrize("fail_at", [0, 2, 3])  # 3: the last, if 4
     def test_fetch_that_raises_commits_nothing(
-            self, tmp_path, monkeypatch, mode, layout):
+            self, tmp_path, monkeypatch, mode, layout, lanes, fail_at):
         path = str(tmp_path / "t.npz")
         ckpt.save(path, stream_tree(layout))
         before = sorted(os.listdir(tmp_path))
         prev = ckpt.load_arrays(path)["params/a"].tobytes()
-        Recorder(monkeypatch, str(tmp_path), fail_at=2)
+        Recorder(monkeypatch, str(tmp_path), fail_at=fail_at)
         bad = stream_tree(layout)
-        with pytest.raises(OSError, match="no shard 2"):  # on this thread
-            getattr(ckpt, mode)(path, bad)
-        assert not ckpt._PENDING  # the writer was stopped and joined
+        with pytest.raises(OSError, match=f"no shard {fail_at}"):
+            # on this thread, and soon
+            within(60, lambda: getattr(ckpt, mode)(path, bad))
+        assert not ckpt._PENDING  # the writer was stopped and joined,
+        assert ckpt_threads() == []  # and every lane with it
         ckpt.flush()  # ... and parked nothing
         assert sorted(os.listdir(tmp_path)) == before  # no tmp, no new gen
         assert ckpt.verify(path)
         assert ckpt.load_arrays(path)["params/a"].tobytes() == prev
 
     @pytest.mark.parametrize("join", ["flush", "next_save_async", "save"])
-    def test_writer_that_dies_surfaces_and_hangs_nobody(self, tmp_path, join):
+    def test_writer_that_dies_surfaces_and_hangs_nobody(self, tmp_path, join,
+                                                        lanes):
         (tmp_path / "nodir").write_bytes(b"")  # the "directory" is a file
+
         target = str(tmp_path / "nodir" / "t.npz")
-        raised = []
 
-        def caller():
-            try:
-                if join == "save":
-                    ckpt.save(target, stream_tree("sharded"))
-                else:
-                    ckpt.save_async(target, stream_tree("sharded"))
-                    if join == "flush":
-                        ckpt.flush()
-                    else:
-                        ckpt.save_async(target, stream_tree("sharded"))
-            except BaseException as e:
-                raised.append(e)
+        def go():
+            if join == "save":
+                ckpt.save(target, stream_tree("sharded"))
+                return
+            ckpt.save_async(target, stream_tree("sharded"))
+            if join == "flush":
+                ckpt.flush()
+            else:
+                ckpt.save_async(target, stream_tree("sharded"))
 
-        t = threading.Thread(target=caller, daemon=True)
-        t.start()
-        t.join(60)
-        assert not t.is_alive(), "the caller's thread hangs on a dead writer"
-        (err,) = raised
+        with pytest.raises((OSError, RuntimeError)) as ei:
+            within(60, go)  # the caller's thread hangs on no dead writer
+        err = ei.value
         if join == "save":  # the writer's own error, on the caller's thread
             assert isinstance(err, OSError)
         else:
             assert isinstance(err, RuntimeError)
             assert "async checkpoint write" in str(err)
             assert isinstance(err.__cause__, OSError)
+        assert ckpt_threads() == []  # it died before it started a lane
         ckpt.flush()  # consumed: the next join point is clean
 
     @pytest.mark.parametrize("mode", MODES)
     def test_write_span_is_its_snapshots_sibling_and_overlaps_it(
-            self, tmp_path, monkeypatch, mode):
+            self, tmp_path, monkeypatch, mode, lanes):
         Recorder(monkeypatch, str(tmp_path), hold_last=True)
         ev = str(tmp_path / "ev.jsonl")
         with metrics.scoped(ev):
@@ -488,8 +599,186 @@ class TestStreamedSave:
         assert snap["bytes"] == write["bytes"] == A.nbytes + 8 * 4 + 16 * 4 + 4
         assert 0 < write["overlap_s"] <= write["dur_s"] + 1e-6
         assert 0 <= write["starved_s"] <= write["dur_s"] + 1e-6
+        # one ckpt.write a save whatever its lanes (the benchmark divides
+        # the bytes by the seconds of the spans of that name); a lane's
+        # span has a name of its own and is the write's sibling
+        assert write["lanes"] == len(by["ckpt.lane"]) == n_lanes(lanes, 13)
+        assert len({e["thread"] for e in by["ckpt.lane"]}) == write["lanes"]
+        for e in by["ckpt.lane"]:
+            assert e["parent"] == outer["id"] and e["root"] == outer["id"]
+            assert e["thread"].startswith(write["thread"] + ".l")
+            assert 0 <= e["starved_s"] <= write["starved_s"] + 1e-9
+            assert write["ts_start"] <= e["ts_start"] + 0.005
+            assert e["ts"] <= write["ts"] + 0.005
+        assert sum(e["bytes"] for e in by["ckpt.lane"]) == write["bytes"]
+        assert sum(e["n_members"] for e in by["ckpt.lane"]) == 13
         # the join of a synchronous save is a ckpt.flush of its own
         assert len(by["ckpt.flush"]) == (2 if mode == "save" else 1)
+
+
+@pytest.mark.usefixtures("devices8", "no_pending")
+class TestLaneEndings:
+    """PR 46: a save over the size threshold writes its members on several
+    lanes, a daemon thread and a shard file each, started by the save's one
+    writer thread (``ckpt-<base>``, the one in ``_PENDING``) and ended
+    before that one returns, on every path. Each case has a time limit of
+    its own (``within``) and ends with no ``ckpt-`` thread alive."""
+
+    @pytest.fixture(autouse=True)
+    def _several(self, monkeypatch):
+        monkeypatch.setattr(ckpt, "_LANE_MIN_BYTES", 1)
+        assert ckpt_threads() == []
+        yield
+        for t in threading.enumerate():
+            if t.name.startswith("ckpt-"):
+                t.join(30)
+        assert ckpt_threads() == []
+
+    @pytest.mark.parametrize("layout", LAYOUTS)
+    @pytest.mark.parametrize("mode", MODES)
+    def test_a_lane_that_dies_stops_the_others_and_commits_nothing(
+            self, tmp_path, monkeypatch, mode, layout):
+        """Lane 1's write raises while the other lanes are inside a member:
+        the error is that lane's, the others stop, no temp file and no file
+        of the new generation is left, the previous generation verifies."""
+        path = str(tmp_path / "t.npz")
+        ckpt.save(path, stream_tree(layout))
+        before = sorted(os.listdir(tmp_path))
+        prev = ckpt.load_arrays(path)["params/a"].tobytes()
+        real, real_fail = ckpt._write_member, ckpt._Stream.fail
+        inside = threading.Semaphore(0)
+        failed = threading.Event()
+        after = []
+
+        def fail(stream, err):
+            real_fail(stream, err)
+            failed.set()
+
+        def write_member(zf, member, arr, piece):
+            if threading.current_thread().name.endswith(".l1"):
+                for _ in range(ckpt._LANES - 1):  # the others are mid-member
+                    assert inside.acquire(timeout=30)
+                raise OSError("disk full on lane 1")
+            if failed.is_set():
+                after.append(member)  # a member begun after the stop
+            inside.release()
+            assert failed.wait(30), "the failed lane told nobody"
+            real(zf, member, arr, piece)
+
+        monkeypatch.setattr(ckpt._Stream, "fail", fail)
+        monkeypatch.setattr(ckpt, "_write_member", write_member)
+
+        def go():
+            getattr(ckpt, mode)(path, stream_tree(layout))
+            ckpt.flush()
+
+        with pytest.raises((OSError, RuntimeError)) as ei:
+            within(60, go)
+        err = ei.value
+        if mode == "save_async":  # parked, raised at the join point
+            assert "async checkpoint write" in str(err)
+            err = err.__cause__
+        assert isinstance(err, OSError) and "lane 1" in str(err)
+        assert after == []  # a lane ends the member it is in, and no more
+        assert ckpt_threads() == [] and not ckpt._PENDING
+        assert sorted(os.listdir(tmp_path)) == before  # no tmp, no new gen
+        assert ckpt.verify(path)
+        assert ckpt.load_arrays(path)["params/a"].tobytes() == prev
+        ckpt.flush()  # consumed: the next join point is clean
+
+    @pytest.mark.parametrize("mode", MODES)
+    @pytest.mark.parametrize("n", [0, 1, 2, 3])
+    def test_fewer_members_than_lanes(self, tmp_path, mode, n):
+        """A lane for every member and no lane without one; a tree with no
+        member at all starts no lane and still commits its manifest."""
+        tree = {f"m{i}": np.full((3, 5), i, np.float32) for i in range(n)}
+        path = str(tmp_path / "t.npz")
+        within(60, lambda: run_save(mode, path, tree))
+        assert ckpt_threads() == [] and not litter(tmp_path)
+        assert len(shard_files(tmp_path)) == n
+        assert ckpt.verify(path)
+        got = ckpt.load_arrays(path)
+        assert set(got) == set(tree)
+        for k in tree:
+            assert got[k].tobytes() == tree[k].tobytes()
+
+    @pytest.mark.parametrize("layout", LAYOUTS)
+    @pytest.mark.parametrize("mode", MODES)
+    @pytest.mark.parametrize("point",
+                             ["mid-shard-write", "pre-manifest-rename"])
+    def test_kill_at_a_barrier_ends_every_lane(self, tmp_path, mode, layout,
+                                               point):
+        from saturn_tpu.resilience.crash import CrashInjector, SimulatedKill
+
+        path = str(tmp_path / "t.npz")
+        ckpt.save(path, stream_tree(layout))
+        before = sorted(os.listdir(tmp_path))
+        prev = ckpt.load_arrays(path)["params/a"].tobytes()
+        seen = []
+        inj = CrashInjector(point)
+
+        def barrier(at, ctx):
+            seen.append((at, threading.current_thread().name,
+                         os.path.exists(ctx["tmp"])))
+            inj.barrier(at, ctx)
+
+        ckpt.set_crash_barrier(barrier)
+
+        def go():
+            getattr(ckpt, mode)(path, stream_tree(layout))
+            ckpt.flush()
+
+        with pytest.raises((SimulatedKill, RuntimeError)) as ei:
+            within(60, go)
+        ckpt.set_crash_barrier(None)
+        err = ei.value if mode == "save" else ei.value.__cause__
+        assert isinstance(err, SimulatedKill)
+        # every lane crosses mid-shard-write with its own staged file, on
+        # its own thread; the writer thread crosses pre-manifest-rename
+        mid = [s for s in seen if s[0] == "mid-shard-write"]
+        assert all(name.startswith("ckpt-t.npz.l") and staged
+                   for _, name, staged in mid)
+        if point == "pre-manifest-rename":
+            assert len({name for _, name, _ in mid}) == ckpt._LANES
+            assert seen[-1] == (point, "ckpt-t.npz", True)
+        assert ckpt_threads() == [] and not ckpt._PENDING
+        assert sorted(os.listdir(tmp_path)) == before
+        assert ckpt.verify(path)
+        assert ckpt.load_arrays(path)["params/a"].tobytes() == prev
+
+    def test_lanes_come_from_the_plan_alone(self, monkeypatch):
+        """Bytes and members decide, the largest member first, each to the
+        lane with the fewest bytes: every process of a multi-process save
+        names the same files for every rank."""
+        mb = 1 << 20
+        monkeypatch.setattr(ckpt, "_LANE_MIN_BYTES", 256 * mb)
+        sizes = [10 * mb, 300 * mb, 20 * mb, 300 * mb, 150 * mb, 150 * mb,
+                 4, 90 * mb]
+        shards = [(n, {"key": f"k{i}", "file": None})
+                  for i, n in enumerate(sizes)]
+        files, order, lane_of = ckpt._assign_lanes("t.npz.g1f.r2", shards)
+        assert files == [f"t.npz.g1f.r2.l{k}.npz" for k in range(ckpt._LANES)]
+        assert [sizes[i] for i in order] == sorted(sizes, reverse=True)
+        assert order[:2] == [1, 3]  # equal sizes keep the tree's order
+        held = [0] * len(files)
+        for i, k in zip(order, lane_of):
+            assert held[k] == min(held)  # the emptiest lane at its turn
+            held[k] += sizes[i]
+            assert shards[i][1]["file"] == files[k]
+        assert max(held) - min(held) <= max(sizes)  # they end together
+        assert all(ckpt._SHARD_RE.search(f).groups() == ("1f", "2")
+                   for f in files)
+        # under the threshold: one lane under the name it always had
+        small = [(n, {"key": f"k{i}", "file": None})
+                 for i, n in enumerate([100 * mb, 100 * mb, 55 * mb])]
+        files, order, lane_of = ckpt._assign_lanes("t.npz.g1f.r2", small)
+        assert files == ["t.npz.g1f.r2.npz"] and lane_of == [0, 0, 0]
+        assert ckpt._SHARD_RE.search(files[0]).groups() == ("1f", "2")
+        # over it with two members: two lanes, never an empty one
+        two = [(200 * mb, {"key": "a", "file": None}),
+               (200 * mb, {"key": "b", "file": None})]
+        files, _, lane_of = ckpt._assign_lanes("t.npz.g1f.r0", two)
+        assert len(files) == 2 and sorted(lane_of) == [0, 1]
 
 
 @pytest.mark.crash

@@ -544,6 +544,7 @@ PATH_SPANS = [
     ("window", "launch.compile"), ("window", "readback"),
     ("window", "step_flops"), ("window", "ckpt.wait_pending"),
     ("window", "ckpt.snapshot"), ("window", "ckpt.write"),
+    ("window", "ckpt.lane"),
     ("window", "ckpt.flush"), ("window", "journal.commit"),
     ("resume", "launch.restore"), ("fused", "fused_interval"),
 ]
@@ -573,12 +574,12 @@ def test_tree_is_sound(tiny_run, phase):
         p = ids[e["parent"]]
         if "dur_s" not in e or "dur_s" not in p:
             continue  # task_interval is stamped by hand, with its own meaning
-        if e["kind"] == "ckpt.write":
-            continue  # outlives its snapshot by design; its root holds it
+        if e["kind"] in ("ckpt.write", "ckpt.lane"):
+            continue  # outlive their snapshot by design; the root holds them
         assert p["ts_start"] - 0.005 <= e["ts_start"], (e["kind"], p["kind"])
         assert e["ts"] <= p["ts"] + 0.005, (e["kind"], p["kind"])
     for e in spans_of(events):
-        if e["kind"] == "ckpt.write":
+        if e["kind"] in ("ckpt.write", "ckpt.lane"):
             assert e["ts"] <= roots[0]["ts"] + 0.005  # joined inside the root
 
 
@@ -640,6 +641,9 @@ HANDOFFS = {
     "engine launcher thread": ("window", ("launch.build",), "launch-", "task_interval"),
     # its snapshot's sibling: the two overlap (PR 27)
     "checkpoint writer thread": ("window", ("ckpt.write",), "ckpt-", "task_interval"),
+    # a lane of the save, started by the writer thread; its write's sibling
+    # (PR 46; a tiny state takes one lane: the case below takes four)
+    "checkpoint lane thread": ("window", ("ckpt.lane",), "ckpt-", "task_interval"),
     "trial thread": ("search", ("trial",), "trial-g1", "search"),
     # a grid point's chip half, on the thread that measures behind the trial
     # thread while that one prepares the next points (PR 37); a timed point's
@@ -686,6 +690,62 @@ def test_handoff_in_the_program(tiny_run, who):
                 # closed by the thread the point ended on
                 assert ids[e["parent"]]["thread"] in (
                     e["thread"], "meas-" + e["thread"])
+
+
+@pytest.mark.parametrize("mode", ["save", "save_async"])
+def test_one_write_span_a_save_and_one_lane_span_a_lane(
+        sink, tmp_path, monkeypatch, mode):
+    """PR 46: the benchmark's ``ckpt_write_gb_per_s`` divides the bytes by
+    the seconds of the spans named ``ckpt.write``, so there is one a save
+    however many lanes wrote it, with ``lanes`` on it; each lane has a
+    ``ckpt.lane`` on a thread of its own, and ``ckpt_stall``'s three spans
+    (the caller's two, the join) are what they were."""
+    import numpy as np
+
+    from saturn_tpu.utils import checkpoint as ckpt
+
+    monkeypatch.setattr(ckpt, "_LANE_MIN_BYTES", 1)
+    tree = {f"m{i}": np.full((64, 64 + i), i, np.float32) for i in range(6)}
+    tree["step"] = np.asarray(3, np.int32)
+    nbytes = sum(v.nbytes for v in tree.values())
+    with metrics.span("task_interval_like") as outer:
+        getattr(ckpt, mode)(str(tmp_path / "t.npz"), tree)
+    ckpt.flush()
+    assert not [t for t in threading.enumerate() if t.name.startswith("ckpt-")]
+    (write,) = sink("ckpt.write")
+    assert {"bytes", "lanes", "n_shards", "overlap_s", "starved_s",
+            "path"} <= set(write)
+    assert write["bytes"] == nbytes and write["n_shards"] == 7
+    assert write["lanes"] == ckpt._LANES == 4 and write["path"] == "t.npz"
+    assert write["thread"] == "ckpt-t.npz" and write["parent"] == outer.id
+    assert 0 <= write["overlap_s"] <= write["dur_s"] + 1e-6
+    lanes = sink("ckpt.lane")
+    assert len(lanes) == write["lanes"]
+    assert (sorted(e["thread"] for e in lanes)
+            == [f"ckpt-t.npz.l{k}" for k in range(4)])
+    assert sum(e["bytes"] for e in lanes) == write["bytes"]
+    assert sum(e["n_members"] for e in lanes) == write["n_shards"]
+    assert write["starved_s"] == pytest.approx(
+        max(e["starved_s"] for e in lanes))
+    files = sorted(e["file"] for e in lanes)
+    assert files == sorted(n for n in os.listdir(tmp_path)
+                           if ckpt._SHARD_RE.search(n))
+    for e in lanes:
+        assert {"bytes", "n_members", "starved_s", "file"} <= set(e)
+        assert e["parent"] == outer.id and e["root"] == outer.id
+        assert write["ts_start"] - 0.005 <= e["ts_start"]
+        assert e["ts"] <= write["ts"] + 0.005 and "error" not in e
+    # ckpt_stall's spans: the caller's thread, then the join(s)
+    (wait,), (snap,) = sink("ckpt.wait_pending"), sink("ckpt.snapshot")
+    me = threading.current_thread().name
+    assert wait["thread"] == snap["thread"] == me
+    assert wait["parent"] == snap["parent"] == outer.id
+    assert snap["bytes"] == nbytes and snap["n_streamed"] == 7
+    assert wait["path"] == snap["path"] == "t.npz"
+    flushes = sink("ckpt.flush")
+    assert [e["n_pending"] for e in flushes] == (
+        [1, 0] if mode == "save" else [1])
+    assert all(e["thread"] == me for e in flushes)
 
 
 def test_handoff_to_the_solver_pool(tiny_run, sink, devices8):
