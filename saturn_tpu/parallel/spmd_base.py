@@ -38,6 +38,7 @@ from saturn_tpu.parallel import sharding as shr
 from saturn_tpu.utils import aot_cache
 from saturn_tpu.utils import checkpoint as ckpt
 from saturn_tpu.utils import metrics as _metrics
+from saturn_tpu.utils import point_records
 from saturn_tpu.utils.timing import (
     FUSED_WINDOW_STACKS,
     device_hbm_bytes,
@@ -74,6 +75,14 @@ def _env_hbm_bytes() -> int:
         return max(int(float(os.environ.get("SATURN_TPU_HBM_BYTES", "0"))), 0)
     except ValueError:
         return 0
+
+
+def _hbm_limit(device: Any) -> int:
+    """The HBM limit the memory rule reads: the device's own, else (a platform
+    that reports none: CPU tests) the capacity memlens reads from the
+    environment, so that CPU sweeps can model a chip; 0 = none known."""
+    limit = device_hbm_bytes(device)
+    return limit if limit > 0 else _env_hbm_bytes()
 
 
 def max_window() -> int:
@@ -333,6 +342,8 @@ class _GridPoint:
     # how it ended (``trial.config``'s ``outcome``); None while it has not
     outcome: Optional[str] = None
     refusal: Optional[str] = None   # of a ``refused`` point: fresh / recorded
+    compiler: Optional[str] = None  # ...and the compiler's first line
+    unbuilt: bool = False   # it ended on its point record: nothing was built
     error: Optional[str] = None     # of an ``error`` point, for ``first_error``
 
 
@@ -499,7 +510,8 @@ class SPMDTechnique(BaseTechnique):
         """Pop the report of the most recent ``search`` of (task, size): how
         many configs there were, how many XLA's memory analysis rejected or
         the compiler refused for memory (of those, ``refusals_fresh`` by a
-        compile and ``refusals_replayed`` from ``aot_cache``'s records), how
+        compile and ``refusals_replayed`` from a record, ``refusals_unbuilt``
+        of these from the point's own record with nothing built), how
         many raised (``errors``, with ``first_error``), and whether memory
         alone made the point infeasible. None when no search ran."""
         with self._reports_lock:
@@ -1105,11 +1117,7 @@ class SPMDTechnique(BaseTechnique):
         SAT-M005 drift audit accrues for free on every sweep.
         """
         with _metrics.span("trial.memory_check", k=int(k)) as sp:
-            limit = device_hbm_bytes(devices[0])
-            if limit <= 0:
-                # platform doesn't report limits (CPU tests); honor the same
-                # env capacity memlens reads, so CPU sweeps can model a chip
-                limit = _env_hbm_bytes()
+            limit = _hbm_limit(devices[0])
             need = hbm_bytes_required(compiled)
             sp.set(need_bytes=int(need), limit_bytes=int(limit))
             if task is not None and config is not None:
@@ -1190,9 +1198,11 @@ class SPMDTechnique(BaseTechnique):
         memory is over memory too, and ends so without being built:
         rematerialisation only ever lowers a program's peak, and the point
         would be traced, lowered and compiled to be refused (a period of six
-        blocks: 20 s + 5 s + 53 s on the chip's host, PR 45). The winner is
-        the fastest timed point wherever it stood; a tie goes to the
-        technique's own order.
+        blocks: 20 s + 5 s + 53 s on the chip's host, PR 45). So does a point
+        whose own verdict is on record by what it is made from
+        (``utils/point_records``, PR 47), unless nothing gets timed: then such
+        points run again in full. The winner is the fastest timed point
+        wherever it stood; a tie goes to the technique's own order.
         """
         size = len(devices)
         stack = self._stack_fields(task)
@@ -1231,7 +1241,7 @@ class SPMDTechnique(BaseTechnique):
                 log.info("%s trial %s for task %s refused by the compiler "
                          "(%s): %s", self.name, config, task.name, e.refusal,
                          e.first_line)
-                point.refusal = e.refusal
+                point.refusal, point.compiler = e.refusal, e.first_line
                 point.span.set(refusal=e.refusal)
                 end(point, "refused", memory_rejected=True,
                     refusal=e.refusal, compiler=e.first_line)
@@ -1252,7 +1262,8 @@ class SPMDTechnique(BaseTechnique):
 
         over_memory: List[Dict[str, Any]] = []  # the configs that ended so
 
-        def prepare(order: int, config: Dict[str, Any]) -> _GridPoint:
+        def prepare(order: int, config: Dict[str, Any],
+                    recorded: bool = True) -> _GridPoint:
             point = _GridPoint(order, config, _metrics.span(
                 "trial.config", parent=above, task=task.name, size=size,
                 technique=self.name, config=dict(config)).open())
@@ -1262,6 +1273,19 @@ class SPMDTechnique(BaseTechnique):
                 end(point, "memory_rejected", memory_rejected=True,
                     implied_by="remat")
                 return point
+            # Its memory verdict may be on record by what the point is made
+            # from (``utils/point_records``, PR 47): it then ends here too,
+            # with nothing built, traced or lowered to find the text's record.
+            record = point_records.of(
+                self, task, devices, config, self._profile_window(config),
+                parent=point.span, read=recorded)
+            if record.verdict is not None:
+                point.refusal, point.unbuilt = "recorded", True
+                point.span.set(refusal="recorded", unbuilt=True)
+                end(point, memory_rejected=True, refusal="recorded",
+                    unbuilt=True, **record.verdict)
+                over_memory.append(dict(config))
+                return point
             point.ready = attempt(
                 point, lambda: self._prepare(task, devices, config))
             if point.ready is None and point.outcome is None:
@@ -1269,6 +1293,7 @@ class SPMDTechnique(BaseTechnique):
                 end(point, "memory_rejected", memory_rejected=True)
             if point.outcome in ("refused", "memory_rejected"):
                 over_memory.append(dict(config))
+            record.note(point.outcome, point.compiler)  # a verdict, or none
             return point
 
         def measure(point: _GridPoint) -> None:
@@ -1283,6 +1308,19 @@ class SPMDTechnique(BaseTechnique):
         grid = list(enumerate(self.candidate_configs(task, size)))
         grid.sort(key=lambda oc: oc[1].get("remat") is not True)  # stable
         points, n_ahead = _measured_behind(grid, prepare, measure)
+        if any(p.unbuilt for p in points) and \
+                not any(p.outcome == "timed" for p in points):
+            # A stale record may cost a point, never a job: nothing was timed,
+            # so the points that ended on their records (and those implied)
+            # run again in full before memory is reported.
+            again = {p.order for p in points
+                     if p.unbuilt or "implied_by" in p.span.fields}
+            kept = [p for p in points if p.order not in again]
+            over_memory[:] = [dict(p.config) for p in kept
+                              if p.outcome in ("refused", "memory_rejected")]
+            points = kept + _measured_behind(
+                [oc for oc in grid if oc[0] in again],
+                lambda o, c: prepare(o, c, False), measure)[0]
 
         best: Optional[_GridPoint] = None
         n_memory = n_error = 0
@@ -1318,6 +1356,8 @@ class SPMDTechnique(BaseTechnique):
                 "first_error": first_error,
                 "refusals_fresh": refusals["fresh"],
                 "refusals_replayed": refusals["recorded"],
+                # of the replayed: on the point's own record, nothing built
+                "refusals_unbuilt": sum(p.unbuilt for p in points),
                 # points that were ready before this thread asked for them
                 "prepared_ahead": n_ahead,
             }

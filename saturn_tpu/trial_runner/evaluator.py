@@ -114,7 +114,7 @@ def search(
 
     Returns sweep stats ``{"trials_run", "cache_hits", "pruned",
     "interpolated", "dispatch", "fused_groups", "errors", "first_error",
-    "refusals_fresh", "refusals_replayed"}`` —
+    "refusals_fresh", "refusals_replayed", "refusals_unbuilt"}`` —
     the online admission controller uses ``trials_run`` to distinguish warm
     (zero-trial) from cold arrivals. ``errors`` counts the candidate configs
     (and whole trials, past their retry budget) that *raised* instead of
@@ -125,7 +125,10 @@ def search(
     compiler refuses for memory is not among them: it failed the memory
     check. ``refusals_fresh`` counts those the compiler refused in this
     sweep, ``refusals_replayed`` those a record of an earlier refusal
-    answered without a compile (``utils/aot_cache``, "Refusal records").
+    answered without a compile (``utils/aot_cache``, "Refusal records"), and
+    ``refusals_unbuilt`` those of the replayed whose verdict was found by what
+    the point is made from, before anything was built
+    (``utils/point_records``).
     """
     if log:
         logging.basicConfig(level=logging.INFO)
@@ -336,7 +339,8 @@ def _search_inner(
     errors = {"n": 0, "first": None}
     # Configs the chip's compiler refused for memory: by a compile, or from
     # the record of an earlier one (``utils/aot_cache``, "Refusal records").
-    refusals = {"refusals_fresh": 0, "refusals_replayed": 0}
+    refusals = {"refusals_fresh": 0, "refusals_replayed": 0,
+                "refusals_unbuilt": 0}
 
     def note_errors(n: int, first: Optional[str]) -> None:
         with update_lock:

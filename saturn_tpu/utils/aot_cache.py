@@ -63,6 +63,14 @@ message, and the next compile of the same program raises
   refusal (the message says whose it is) and takes the executable that
   compile left in JAX's cache out again, so that a cell's cache holds the
   programs it runs and not those it only weighs.
+- *Point records* (PR 47). The key above exists only once a grid point has
+  been built, traced and lowered, which is most of what a refused point
+  still costs a search. ``utils/point_records`` keeps a second record beside
+  these (``saturn-refused/point-<key>.json``), keyed by what the point is
+  made from and guarded by a manifest of source files, that remembers the
+  verdict reached here; ``SPMDTechnique.search`` asks it before it builds.
+  This record stays the authority: the other is written only after the full
+  path ended in a verdict, and a miss there leads back here.
 """
 
 from __future__ import annotations
@@ -94,7 +102,9 @@ SCHEMA_VERSION = 1
 _stats_lock = threading.Lock()
 _stats = {"hits": 0, "misses": 0, "stores": 0, "errors": 0,
           "prewarms": 0, "warm_hits": 0,
-          "refusals_fresh": 0, "refusals_replayed": 0}
+          "refusals_fresh": 0, "refusals_replayed": 0,
+          # grid points ended on their point record (``utils/point_records``)
+          "refusals_unbuilt": 0}
 
 # In-process warm pool fed by the compile-ahead service
 # (``tenancy.compile_ahead``): executables compiled in the background

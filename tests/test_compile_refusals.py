@@ -443,19 +443,27 @@ def test_search_takes_refusals_as_the_memory_verdict(
     assert trial["feasible"] is False and trial["memory_infeasible"] is True
     assert not task.feasible_strategies()
 
-    # a later sweep of the same programs: the records answer, no compile
+    # a later sweep of the same programs: the records answer, no compile.
+    # Each point ends on its point record first, with nothing built (PR 47);
+    # nothing is timed, so rule 4 runs them again in full, and there the
+    # text-keyed records answer as before.
     stats, events, _ = _search(tmp_path, devices8, "second")
     assert len(refusing_compiler) == n
     assert stats["errors"] == 0
     assert stats["refusals_fresh"] == 0 and stats["refusals_replayed"] == n
-    seen, implied = _by_remat([e for e in events if e["kind"] == "trial_config"])
+    assert stats["refusals_unbuilt"] == 0   # the report is of the full walk
+    notes = [e for e in events if e["kind"] == "trial_config"]
+    unbuilt, full = notes[:len(notes) // 2], notes[len(notes) // 2:]
+    assert [e.get("unbuilt") for e in unbuilt if e["config"]["remat"]] == [True] * n
+    assert all("unbuilt" not in e for e in full)
+    seen, implied = _by_remat(full)
     assert [e["refusal"] for e in seen] == ["recorded"] * n
     assert all(e["memory_rejected"] is True and e["compiler"] == HBM.splitlines()[0]
                for e in seen)
     assert all(e["memory_rejected"] is True for e in implied)
     assert [e["refusal"] for e in events if e["kind"] == "trial.compile"] == ["recorded"] * n
     assert [e["outcome"] for e in events if e["kind"] == "trial.config"] == \
-        ["refused"] * n + ["memory_rejected"] * len(implied)
+        (["refused"] * n + ["memory_rejected"] * len(implied)) * 2
     assert not [e for e in events if e["kind"] == "compile"
                 and "saturn_window" in e["program"]]
 
@@ -601,3 +609,454 @@ def test_a_non_zero_swiglu_limit_refuses_at_build():
     with pytest.raises(ValueError, match="clamp is not built"):
         build_ling("ling-test-tiny", swiglu_limit=7.0)
     assert build_ling("ling-test-tiny", swiglu_limit=0.0).config.swiglu_limit == 0.0
+
+
+# ----------------- point records: the verdict by what a point is made from (PR 47)
+from saturn_tpu.utils import point_records  # noqa: E402
+
+
+def point_records_of(store):
+    return [n for n in records(store) if n.startswith("point-")]
+
+
+def _made(save_dir, name="made-a", seq=SEQ, batch=BATCH, seed=3, optimizer="adamw",
+          **model):
+    """``_task`` with whatever a case changes about what the point is made from."""
+    from saturn_tpu import HParams, Task
+    from saturn_tpu.data.lm_dataset import make_lm_dataset
+    from saturn_tpu.models.gpt2 import build_gpt2
+    from saturn_tpu.models.loss import pretraining_loss
+
+    return Task(
+        get_model=lambda **kw: build_gpt2("test-tiny", seq_len=seq, **model, **kw),
+        get_dataloader=lambda: make_lm_dataset(
+            context_length=seq, batch_size=batch, vocab_size=VOCAB,
+            n_tokens=seq * batch * 8, seed=seed),
+        loss_fn=pretraining_loss,
+        hparams=HParams(lr=1e-3, batch_count=16, optimizer=optimizer),
+        chip_range=[1], name=name, save_dir=save_dir,
+    )
+
+
+def _dp():
+    from saturn_tpu.parallel.dp import DataParallel
+
+    return DataParallel()
+
+
+def _record(task, devices, config=None, k=8, tech=None):
+    return point_records.of(tech or _dp(), task, list(devices),
+                            config or {"remat": False}, k)
+
+
+class Choosy:
+    """Mixed into dp: a grid of two, whose ``remat: False`` point ends in full
+    as ``verdict`` says, after a real build; what was built is counted."""
+
+    verdict = "refused"
+
+    def __init__(self):
+        super().__init__()
+        self.built = []
+
+    def candidate_configs(self, task, n_devices):
+        return [{"remat": False}, {"remat": True}]
+
+    def build(self, task, devices, config, use_cache=True):
+        self.built.append(dict(config))
+        return super().build(task, devices, config, use_cache)
+
+    def _prepare(self, task, devices, config):
+        if config["remat"] is True or self.verdict == "fits":
+            return super()._prepare(task, devices, config)
+        self._spanned_build("trial.build", task, devices, config)
+        if self.verdict == "refused":
+            raise CompileRefused(HBM, "fresh", "jit_saturn_window")
+        if self.verdict == "memory_rejected":
+            return None
+        if self.verdict == "infeasible":
+            from saturn_tpu.core.technique import InfeasibleConfig
+
+            raise InfeasibleConfig("batch_size 4 not divisible by data=3")
+        raise ValueError("kernel variant failed to lower")
+
+
+from saturn_tpu.parallel.dp import DataParallel  # noqa: E402
+
+
+class ChoosyDP(Choosy, DataParallel):
+    """At module level: a technique class made inside a function has no
+    name to be found by again, and its points no identity."""
+
+
+def _choosy(verdict):
+    tech = ChoosyDP()
+    tech.verdict = verdict
+    return tech
+
+
+def _choosy_search(tmp_path, devices, verdict, tag, task=None):
+    tech = _choosy(verdict)
+    task = task or _task(str(tmp_path / "ck"), "choosy")
+    ev = str(tmp_path / f"{tag}.jsonl")
+    with metrics.scoped(ev):
+        best = tech.search(task, list(devices[:1]), 0)
+    notes = {e["config"]["remat"]: e for e in metrics.read_events(ev, kind="trial_config")}
+    return tech, best, tech.search_report(task.name, 1), notes, metrics.read_events(ev)
+
+
+@pytest.mark.parametrize("verdict", ["refused", "memory_rejected"])
+def test_a_point_over_memory_in_full_is_not_built_by_the_next_search(
+        store, tmp_path, devices8, verdict):
+    tech, best, report, notes, _ = _choosy_search(tmp_path, devices8, verdict, "first")
+    assert best[0] == {"remat": True} and report["memory_rejected"] == 1
+    assert report["refusals_unbuilt"] == 0 and "unbuilt" not in notes[False]
+    assert {"remat": False} in tech.built and notes[False]["step_traces"] == 1
+    (name,) = point_records_of(store)
+    with open(store / name) as f:
+        rec = json.load(f)
+    assert rec["outcome"] == verdict and rec["schema"] == point_records.SCHEMA_VERSION
+    assert rec["compiler"] == (HBM.splitlines()[0] if verdict == "refused" else None)
+    assert "saturn_tpu.parallel.spmd_base" in rec["manifest"]
+    assert __name__ in rec["manifest"]          # the caller's own module
+    assert not any(n.split(".")[0] in ("jax", "numpy", "optax", "json")
+                   for n in rec["manifest"])
+    assert "choosy" not in json.dumps({k: v for k, v in rec.items() if k != "manifest"})
+
+    unbuilt0 = aot_cache.stats()["refusals_unbuilt"]
+    tech, best, report, notes, events = _choosy_search(tmp_path, devices8, verdict, "second")
+    assert best[0] == {"remat": True}
+    assert {"remat": False} not in tech.built   # ``build`` not called for it
+    e = notes[False]
+    assert e["unbuilt"] is True and e["refusal"] == "recorded"
+    assert e["memory_rejected"] is True and "step_traces" not in e
+    assert e.get("compiler") == rec["compiler"]
+    (span,) = [s for s in events if s["kind"] == "trial.config"
+               and s["config"] == {"remat": False}]
+    assert span["outcome"] == verdict and span["unbuilt"] is True
+    assert span["refusal"] == "recorded"
+    inside = [s["kind"] for s in events if s.get("parent") == span["id"]]
+    assert inside == ["trial.identity"]         # no trial.build, no trial.compile
+    (ident,) = [s for s in events if s["kind"] == "trial.identity"
+                and s["parent"] == span["id"]]
+    assert ident["identity"] is True and ident["hit"] is True and ident["dur_s"] < 5.0
+    assert report["memory_rejected"] == 1 and report["memory_infeasible"] is False
+    assert report["refusals_unbuilt"] == 1 and report["refusals_replayed"] == 1
+    assert aot_cache.stats()["refusals_unbuilt"] == unbuilt0 + 1
+    assert "unbuilt" not in notes[True] and notes[True]["step_traces"] == 1
+
+
+@pytest.mark.parametrize("verdict", ["infeasible", "error", "fits"])
+def test_another_end_leaves_no_point_record_and_takes_a_standing_one_away(
+        store, tmp_path, devices8, verdict):
+    _choosy_search(tmp_path, devices8, "refused", "first")
+    assert len(point_records_of(store)) == 1
+    # the record is stale (its cause has gone): make it miss, as an edited
+    # file would, so that the point takes the full path and ends otherwise
+    point_records._hashes.clear()
+    (name,) = point_records_of(store)
+    with open(store / name) as f:
+        rec = json.load(f)
+    rec["manifest"][__name__][1] = "0" * 64
+    with open(store / name, "w") as f:
+        json.dump(rec, f)
+    tech, _, report, notes, _ = _choosy_search(tmp_path, devices8, verdict, "second")
+    assert {"remat": False} in tech.built and "unbuilt" not in notes[False]
+    assert report["refusals_unbuilt"] == 0
+    assert point_records_of(store) == []
+
+
+def test_a_refusal_from_running_leaves_no_point_record(store, tmp_path, devices8, monkeypatch):
+    from saturn_tpu.parallel.dp import DataParallel
+
+    def no_room(self, task, prepared):
+        raise RuntimeError("RESOURCE_EXHAUSTED: Error allocating device buffer")
+
+    monkeypatch.setattr(DataParallel, "_measure", no_room)
+    _, best, report, notes, _ = _choosy_search(tmp_path, devices8, "fits", "ran")
+    assert best == (None, None) and report["errors"] == 2
+    assert point_records_of(store) == []
+
+
+def test_an_implied_end_leaves_no_point_record(store, tmp_path, devices8, refusing_compiler):
+    _, events, _ = _search(tmp_path, devices8, "implied")
+    seen, implied = _by_remat([e for e in events if e["kind"] == "trial_config"])
+    assert len(point_records_of(store)) == len(seen)   # none for an implied point
+
+
+#: what changes about a point -> the record written before the change misses
+def _miss_grid_config(ctx):
+    return dict(config={"remat": False, "attention": "dense"})
+
+
+def _miss_window(ctx):
+    return dict(k=1)
+
+
+def _miss_batch_shape(ctx):
+    return dict(task=_made(ctx.ck, batch=BATCH * 2))
+
+
+def _miss_parameter_shape(ctx):
+    # the same config, another tree: as the benchmark does, ``init_fn`` replaced
+    import dataclasses
+
+    import jax.numpy as jnp
+
+    task = _made(ctx.ck)
+    inner = task._get_model
+    task._get_model = lambda **kw: dataclasses.replace(
+        inner(**kw), init_fn=lambda rng: {"w": jnp.zeros((3, 5))})
+    return dict(task=task)
+
+
+def _miss_spec_config(ctx):
+    return dict(task=_made(ctx.ck, rope_theta=5e5))
+
+
+def _miss_hbm_limit(ctx):
+    ctx.monkeypatch.setenv("SATURN_TPU_HBM_BYTES", str(8 * 2 ** 30))
+    return {}
+
+
+def _miss_device_count(ctx):
+    return dict(devices=ctx.devices[:2])
+
+
+def _miss_saturn_variable(ctx):
+    ctx.monkeypatch.setenv("SATURN_TPU_CE_BLOCK", "256")
+    return {}
+
+
+def _miss_xla_flags(ctx):
+    ctx.monkeypatch.setenv(
+        "XLA_FLAGS", os.environ.get("XLA_FLAGS", "") + " --xla_tpu_scoped_vmem_limit_kib=32768")
+    return {}
+
+
+def _miss_platform_version(ctx):
+    real = aot_cache._compiler_identity()
+    ctx.monkeypatch.setattr(aot_cache, "_compiler_identity",
+                            lambda: [real[0] + " libtpu-next"] + real[1:])
+    return {}
+
+
+def _miss_lr(ctx):
+    task = _made(ctx.ck)
+    task.hparams.lr = 3e-4
+    return dict(task=task)
+
+
+def _miss_technique(ctx):
+    from saturn_tpu.parallel.fsdp import FSDP
+
+    return dict(tech=FSDP())
+
+
+def _miss_file_edited(ctx):
+    with open(ctx.helper, "a") as f:
+        f.write("\nTILE = 256\n")
+    point_records._hashes.clear()       # as a new process would find it
+    return {}
+
+
+def _miss_file_gone(ctx):
+    os.unlink(ctx.helper)
+    point_records._hashes.clear()
+    return {}
+
+
+MISSES = {f.__name__[len("_miss_"):]: f for f in (
+    _miss_grid_config, _miss_window, _miss_batch_shape, _miss_parameter_shape,
+    _miss_spec_config, _miss_hbm_limit, _miss_device_count, _miss_saturn_variable,
+    _miss_xla_flags, _miss_platform_version, _miss_lr, _miss_technique,
+    _miss_file_edited, _miss_file_gone)}
+
+
+@pytest.mark.parametrize("case", sorted(MISSES))
+def test_a_point_made_of_anything_else_misses(store, tmp_path, devices8, monkeypatch, case):
+    import importlib.util
+    import types
+
+    helper = tmp_path / "callers_helper.py"
+    helper.write_text("TILE = 128\n")
+    spec = importlib.util.spec_from_file_location("callers_helper_pr47", helper)
+    module = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(module)
+    monkeypatch.setitem(sys.modules, "callers_helper_pr47", module)
+    monkeypatch.setenv("SATURN_TPU_HBM_BYTES", str(16 * 2 ** 30))
+    ctx = types.SimpleNamespace(ck=str(tmp_path / "ck"), monkeypatch=monkeypatch,
+                                devices=list(devices8), helper=str(helper))
+    first = dict(task=_made(ctx.ck), devices=ctx.devices[:1])
+    written = _record(**first)
+    assert written.path is not None and written.verdict is None
+    written.note("refused", "RESOURCE_EXHAUSTED: over")
+    point_records._hashes.clear()
+    again = _record(**dict(first, task=_made(ctx.ck)))
+    assert again.path == written.path and again.verdict == {
+        "outcome": "refused", "compiler": "RESOURCE_EXHAUSTED: over"}
+    changed = _record(**dict(first, **MISSES[case](ctx)))
+    assert changed.verdict is None
+    if case.startswith("file_"):
+        assert changed.path == written.path     # the key stands, the manifest fails
+        changed.note("memory_rejected")         # the full path rewrites the record
+        if case == "file_edited":
+            assert _record(**first).verdict["outcome"] == "memory_rejected"
+    else:
+        assert changed.path not in (None, written.path)
+
+
+def test_the_datas_seed_and_the_tasks_name_are_not_in_the_key(store, tmp_path, devices8):
+    ck = str(tmp_path / "ck")
+    one = _record(_made(ck, name="job-a", seed=3), devices8[:1])
+    other = _record(_made(str(tmp_path / "elsewhere"), name="job-b", seed=2 ** 31 + 11),
+                    devices8[4:5])
+    assert one.path is not None and one.path == other.path
+
+
+def _closure_optimizer(scale):
+    import optax
+
+    def make(lr):
+        return optax.sgd(lr * scale)
+    return make
+
+
+def _module_level_optimizer(lr):
+    import optax
+
+    return optax.sgd(lr)
+
+
+def test_what_cannot_be_written_down_is_no_identity(store, tmp_path, devices8):
+    ck = str(tmp_path / "ck")
+    named = _record(_made(ck, optimizer=_module_level_optimizer), devices8[:1])
+    assert named.path is not None and named.path != _record(_made(ck), devices8[:1]).path
+    task = _made(ck, optimizer=_closure_optimizer(0.5))
+    ev = str(tmp_path / "ev.jsonl")
+    with metrics.scoped(ev):
+        closure = _record(task, devices8[:1])
+    assert closure.path is None and closure.verdict is None
+    (span,) = metrics.read_events(ev, kind="trial.identity")
+    assert span["identity"] is False and "no module-level name" in span["why"]
+    closure.note("refused", "x")
+    assert point_records_of(store) == []
+    # ... and the search takes today's path: built in every search, no record
+    for tag in ("first", "second"):
+        tech, best, report, notes, _ = _choosy_search(
+            tmp_path, devices8, "refused", tag, task=task)
+        assert {"remat": False} in tech.built and "unbuilt" not in notes[False]
+        assert report["refusals_unbuilt"] == 0 and best[0] == {"remat": True}
+    assert point_records_of(store) == []
+
+
+def test_a_name_whose_source_nothing_vouches_for_is_no_identity(
+        store, tmp_path, devices8, monkeypatch):
+    """A function of a module without a source file (a notebook's or a
+    ``python -c``'s ``__main__``) has a module-level name and can change
+    without a trace: neither a version string nor the manifest covers it."""
+    import types
+
+    notebook = types.ModuleType("notebook_main_pr47")
+    exec("import optax\ndef make(lr):\n    return optax.sgd(lr)\n", notebook.__dict__)
+    monkeypatch.setitem(sys.modules, "notebook_main_pr47", notebook)
+    assert notebook.make.__module__ == "notebook_main_pr47"
+    point_records._vouched.cache_clear()
+    ev = str(tmp_path / "ev.jsonl")
+    with metrics.scoped(ev):
+        rec = _record(_made(str(tmp_path / "ck"), optimizer=notebook.make), devices8[:1])
+    assert rec.path is None
+    (span,) = metrics.read_events(ev, kind="trial.identity")
+    assert "nothing vouches" in span["why"]
+    point_records._vouched.cache_clear()
+
+
+@pytest.mark.parametrize("value", [
+    jax.numpy.zeros((2,)), threading.Lock(), lambda: threading.Lock()],
+    ids=["array", "lock", "closure-free-lambda"])
+def test_a_spec_that_holds_what_has_no_canonical_form(store, tmp_path, devices8, value):
+    task = _made(str(tmp_path / "ck"))
+    inner = task._get_model
+
+    def get_model(**kw):
+        spec = inner(**kw)
+        spec.hints["extra"] = value
+        return spec
+    task._get_model = get_model
+    rec = _record(task, devices8[:1])
+    # a lambda without free variables is written down by its name: an identity
+    assert (rec.path is None) == (not callable(value))
+
+
+def test_without_a_compile_cache_a_point_has_no_record(tmp_path, devices8, monkeypatch):
+    monkeypatch.setattr(profile_cache, "maybe_enable_persistent_compile_cache",
+                        lambda: None)
+    monkeypatch.chdir(tmp_path)
+    rec = _record(_made(str(tmp_path / "ck")), devices8[:1])
+    assert rec.path is None and rec.verdict is None
+    rec.note("refused", "x")
+    assert os.listdir(tmp_path) == ["ck"]
+
+
+@pytest.mark.parametrize("content", [
+    b"", b"{\"schema\": 1, \"outcome\": \"refus", b"[1, 2]", b"{\"schema\": 1}",
+    b"{\"schema\": 1, \"outcome\": \"timed\", \"manifest\": {}}",
+    b"{\"schema\": 1, \"outcome\": \"refused\", \"manifest\": {}}",
+    b"{\"schema\": 1, \"outcome\": \"refused\", \"manifest\": {\"m\": 7}}",
+    b"{\"schema\": 0, \"outcome\": \"refused\", \"manifest\": {\"m\": [\"/x\", \"0\"]}}",
+    b"\xff\xfe\x00garbage",
+], ids=["empty", "truncated", "list", "bare", "no-verdict", "no-manifest",
+        "bad-manifest", "old-schema", "bytes"])
+def test_a_malformed_point_record_is_a_miss(store, tmp_path, devices8, content):
+    task = _made(str(tmp_path / "ck"))
+    rec = _record(task, devices8[:1])
+    rec.note("refused", "RESOURCE_EXHAUSTED: over")
+    assert _record(task, devices8[:1]).verdict is not None
+    with open(rec.path, "wb") as f:
+        f.write(content)
+    assert _record(task, devices8[:1]).verdict is None
+    rec.note("refused", "RESOURCE_EXHAUSTED: over")      # whole again
+    assert _record(task, devices8[:1]).verdict["outcome"] == "refused"
+
+
+def test_threads_recording_one_point_leave_one_valid_record(store, tmp_path, devices8):
+    n = 8
+    task = _made(str(tmp_path / "ck"))
+    recs = [_record(task, devices8[:1]) for _ in range(n)]
+    assert len({r.path for r in recs}) == 1
+    barrier = threading.Barrier(n)
+    seen, lock = [], threading.Lock()
+
+    def record(rec):
+        barrier.wait(timeout=30)
+        rec.note("refused", "RESOURCE_EXHAUSTED: over")
+        hit = _record(task, devices8[:1]).verdict
+        with lock:
+            seen.append(hit)
+
+    old = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    try:
+        threads = [threading.Thread(target=record, args=(r,)) for r in recs]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(timeout=60)
+    finally:
+        sys.setswitchinterval(old)
+    assert not any(t.is_alive() for t in threads) and len(seen) == n
+    # whoever read met a whole record: nobody read half a one
+    assert all(v == {"outcome": "refused", "compiler": "RESOURCE_EXHAUSTED: over"}
+               for v in seen)
+    (name,) = records(store)                    # no temp file left either
+    assert name.startswith("point-") and name.endswith(".json")
+
+
+def test_the_two_kinds_of_record_share_a_directory_and_no_name(store, tmp_path, devices8):
+    with pytest.raises(CompileRefused):
+        aot_cache.load_or_compile(Lowered())
+    _record(_made(str(tmp_path / "ck")), devices8[:1]).note("refused", "x")
+    text, point = sorted(records(store), key=lambda n: n.startswith("point-"))
+    assert not text.startswith("point-") and point.startswith("point-")
+    # the text-keyed reader never takes a point record for one of its own
+    assert aot_cache._read_refusal(str(store / point)) is None

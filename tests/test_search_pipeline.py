@@ -24,7 +24,7 @@ from saturn_tpu.core.technique import InfeasibleConfig
 from saturn_tpu.parallel import spmd_base
 from saturn_tpu.parallel.spmd_base import SPMDTechnique
 from saturn_tpu.resilience.crash import SimulatedKill
-from saturn_tpu.utils import aot_cache, metrics
+from saturn_tpu.utils import aot_cache, metrics, point_records, profile_cache
 
 LIMIT_S = 60.0          # the time limit of a case: none may hang
 HBM = 1 << 30           # what the memory rule runs against (the CPU reports none)
@@ -259,7 +259,7 @@ def test_mixed_grid_ends_as_the_serial_walk_did(run):
         "errors": 1,
         "first_error": "stubbed {'id': 'broken'}: "
                        "ValueError('kernel variant failed to lower')",
-        "refusals_fresh": 0, "refusals_replayed": 1,
+        "refusals_fresh": 0, "refusals_replayed": 1, "refusals_unbuilt": 0,
         "prepared_ahead": report["prepared_ahead"],
     }
     assert Counter(e["outcome"] for e in of_kind(events, "trial.config")) == {
@@ -446,6 +446,162 @@ def test_a_point_is_over_memory_where_its_remat_twin_was(case, tmp_path, monkeyp
         assert best[0]["remat"] is True and best[0]["attention"] == "dense"
     # the flash pair has its own twin, which fitted
     assert "plain-flash" in built and "implied_by" not in notes["flash", False]
+    assert no_measuring_thread_left()
+
+
+# ------------------ a verdict on record by what the point is made from (PR 47)
+@pytest.fixture()
+def recorded(tmp_path, monkeypatch):
+    """Point records on, at a temp directory, for stubs: a stub task has no
+    ``ModelSpec`` to be written down, so a point's identity here is its
+    config's (the real key is ``tests/test_compile_refusals.py``'s). Returns
+    the record files' names."""
+    import hashlib
+    import json
+    import os
+
+    root = tmp_path / "xla-cache"
+    root.mkdir()
+    monkeypatch.setattr(profile_cache, "maybe_enable_persistent_compile_cache",
+                        lambda: str(root))
+    monkeypatch.setattr(
+        point_records, "_key", lambda technique, task, devices, config, k:
+        hashlib.sha256(json.dumps(config, sort_keys=True).encode()).hexdigest())
+
+    def names():
+        folder = root / "saturn-refused"
+        return sorted(os.listdir(folder)) if folder.exists() else []
+    return names
+
+
+def test_points_on_record_end_unbuilt_and_the_rest_as_before(run, recorded):
+    tech, (config, _), report, events = run(MIXED, name="first")
+    assert config == {"id": "fast", "remat": True}
+    assert report["refusals_unbuilt"] == 0 and len(recorded()) == 2  # over, refused
+    assert sorted(tech.book.order("build")) == sorted(p["id"] for p in MIXED)
+    assert all(e["identity"] and not e["hit"] for e in of_kind(events, "trial.identity"))
+
+    tech, (config, t), report, events = run(MIXED, name="second")
+    assert config == {"id": "fast", "remat": True}
+    assert sorted(tech.book.order("build")) == ["broken", "fast", "odd", "slow"]
+    assert report == {
+        "memory_infeasible": False, "configs": 6, "memory_rejected": 2,
+        "errors": 1,
+        "first_error": "stubbed {'id': 'broken'}: "
+                       "ValueError('kernel variant failed to lower')",
+        "refusals_fresh": 0, "refusals_replayed": 2, "refusals_unbuilt": 2,
+        "prepared_ahead": report["prepared_ahead"],
+    }
+    notes = {e["config"]["id"]: e for e in of_kind(events, "trial_config")}
+    assert len(of_kind(events, "trial_config")) == 6
+    for name, outcome in (("over", "memory_rejected"), ("refused", "refused")):
+        e = notes[name]
+        assert e["unbuilt"] is True and e["refusal"] == "recorded"
+        assert e["memory_rejected"] is True and "step_traces" not in e
+        (span,) = [s for s in of_kind(events, "trial.config")
+                   if s["config"]["id"] == name]
+        assert span["outcome"] == outcome and span["unbuilt"] is True
+        assert [s["kind"] for s in events if s.get("parent") == span["id"]] == \
+            ["trial.identity"]
+    assert notes["refused"]["compiler"].startswith("RESOURCE_EXHAUSTED")
+    assert "compiler" not in notes["over"]
+    assert all("unbuilt" not in notes[n] for n in ("slow", "fast", "odd", "broken"))
+    assert len(recorded()) == 2       # infeasible, error and timed leave none
+    assert no_measuring_thread_left()
+
+
+def test_a_point_that_runs_out_of_room_leaves_no_record(run, recorded):
+    _, best, report, _ = run([{"id": "a", "remat": True, "run": "raise"},
+                              {"id": "b", "remat": True, "step_s": 0.01}])
+    assert best[0]["id"] == "b" and report["errors"] == 1
+    assert recorded() == []
+
+
+def test_a_point_that_fits_takes_its_record_away(run, recorded, monkeypatch):
+    run([{"id": "a", "need": HBM}, {"id": "b", "step_s": 0.01}], name="first")
+    assert len(recorded()) == 1
+    # the record outlives its cause only as long as nothing asks: a changed
+    # source file makes it miss, the point takes the full path and now fits
+    monkeypatch.setattr(point_records, "_manifest_holds", lambda manifest: False)
+    tech, best, report, events = run(
+        [{"id": "a", "step_s": 0.001}, {"id": "b", "step_s": 0.01}], name="second")
+    assert best[0]["id"] == "a" and report["refusals_unbuilt"] == 0
+    assert recorded() == []
+
+
+def test_a_stale_record_may_cost_a_point_never_a_job(run, recorded):
+    """Rule 4: every point of the grid on record and none timed. All of them
+    run again in full before memory is reported, and a job that fits now is
+    found feasible (the records' cause is gone and nothing told them)."""
+    over = [{"id": "a", "remat": True, "need": HBM},
+            {"id": "b", "remat": True, "compile": "refuse"}]
+    tech, best, report, _ = run(over, name="first")
+    assert best == (None, None) and report["memory_infeasible"] is True
+    assert len(recorded()) == 2 and tech.book.order("build") == ["a", "b"]
+
+    # still over: ended unbuilt, then run again in full, then reported
+    tech, best, report, events = run(over, name="second")
+    assert best == (None, None) and report["memory_infeasible"] is True
+    assert report["memory_rejected"] == 2 and report["configs"] == 2
+    assert report["refusals_unbuilt"] == 0 and tech.book.order("build") == ["a", "b"]
+    notes = of_kind(events, "trial_config")
+    assert [(e["config"]["id"], e.get("unbuilt", False)) for e in notes] == [
+        ("a", True), ("b", True), ("a", False), ("b", False)]
+    assert len(recorded()) == 2
+
+    # the same points (the stubs' identity is their config) fit now
+    fits = [{"id": "a", "remat": True, "step_s": 0.02},
+            {"id": "b", "remat": True, "step_s": 0.01}]
+    tech, best, report, events = run(fits, name="third")
+    assert best[0] == {"id": "b", "remat": True}
+    assert report["memory_infeasible"] is False and report["memory_rejected"] == 0
+    assert report["refusals_unbuilt"] == 0 and report["configs"] == 2
+    assert tech.book.order("build") == ["a", "b"] and recorded() == []
+    assert tech.host_fraction_report("third", 1) is not None
+    assert no_measuring_thread_left()
+
+
+def test_one_timed_point_and_the_records_are_believed(run, recorded):
+    """Rule 4 is for a search that found nothing: with a timed point the
+    unbuilt ones stay unbuilt."""
+    grid = [{"id": "a", "remat": True, "need": HBM},
+            {"id": "b", "remat": True, "step_s": 0.01}]
+    run(grid, name="first")
+    tech, best, report, events = run(grid, name="second")
+    assert best[0]["id"] == "b" and tech.book.order("build") == ["b"]
+    assert report["refusals_unbuilt"] == 1 and report["memory_rejected"] == 1
+    assert len(of_kind(events, "trial_config")) == 2
+
+
+@pytest.mark.parametrize("case", ["refused", "over"])
+def test_implied_by_remat_follows_from_a_replayed_twin(case, tmp_path, monkeypatch,
+                                                       recorded):
+    monkeypatch.setenv("SATURN_TPU_HBM_BYTES", str(HBM))
+    frugal, _ = TWINS[case]
+    points = [
+        {"id": "plain-dense", "attention": "dense", "remat": False, "step_s": 0.02},
+        {"id": "plain-flash", "attention": "flash", "remat": False, "step_s": 0.02},
+        {"id": "remat-dense", "attention": "dense", "remat": True, **frugal},
+        {"id": "remat-flash", "attention": "flash", "remat": True, "step_s": 0.03},
+    ]
+    for tag in ("first", "second"):
+        tech = Twinned(points)
+        path = str(tmp_path / f"{tag}.jsonl")
+        with metrics.scoped(path):
+            with metrics.span("search"):
+                best = within_limit(
+                    lambda: tech.search(Task("twins"), jax.devices()[:1], 0))
+        events = metrics.read_events(path)
+        notes = {(e["config"]["attention"], e["config"]["remat"]): e
+                 for e in of_kind(events, "trial_config")}
+        assert best[0] == {"attention": "flash", "remat": False}
+        assert notes["dense", False]["implied_by"] == "remat"
+        assert "unbuilt" not in notes["dense", False]
+        assert len(recorded()) == 1       # the implied end has no record of its own
+    # in the second search the twin itself was never built, and its twin followed
+    assert notes["dense", True]["unbuilt"] is True
+    assert sorted(tech.book.order("build")) == ["plain-flash", "remat-flash"]
+    assert tech.search_report("twins", 1)["refusals_unbuilt"] == 1
     assert no_measuring_thread_left()
 
 

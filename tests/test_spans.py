@@ -532,6 +532,7 @@ def tiny_run(tmp_path_factory, devices8):
 #: path (no search runs it) and has its own case below.
 PATH_SPANS = [
     ("search", "search"), ("search", "trial"), ("search", "trial.config"),
+    ("search", "trial.identity"),   # PR 47: emitted where no record is on, too
     ("search", "trial.build"), ("search", "trial.compile"),
     ("search", "trial.memory_check"), ("search", "trial.memlens"),
     ("search", "trial.init"), ("search", "trial.stage"),

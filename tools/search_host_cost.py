@@ -8,7 +8,9 @@ that two trees can be read beside each other in one ``chiprun`` call:
 
 Chip or fail, as ``perf/run.py`` is. A tree's first run compiles its
 programs into the XLA cache (run it once before the reading). Prints the
-harness's ``search: wall`` line, then for each grid point the spans of its
+harness's ``search: wall`` line, then for each grid point how it ended
+(``unbuilt`` where its point record ended it before anything was built, PR
+47) and what its ``trial.identity`` cost, then the spans of its
 preparation (``trial.build`` / ``trial.compile`` / ``trial.memory_check``)
 with JAX's own seconds on them (``trace_s`` / ``lower_s`` / ``cache_read_s``
 / ``compile_s``, nested traces counted once) and the collector's, then the
@@ -69,6 +71,18 @@ def main() -> int:
               f"{run.search['wall_s']:.2f}s (package "
               f"{os.path.relpath(os.path.dirname(saturn_tpu.__file__))})",
               flush=True)
+        for e in sorted(spans, key=lambda e: e["ts_start"]):
+            if e["kind"] != "trial.config":
+                continue
+            identity = [c for c in spans if c["kind"] == "trial.identity"
+                        and c.get("parent") == e["id"]]
+            how = " ".join(f"{k}={e[k]}" for k in ("refusal", "implied_by") if k in e)
+            cost = "none" if not identity else (
+                f"{sum(c['dur_s'] for c in identity):.3f}s"
+                + "".join(f" (no identity: {c['why']})" for c in identity if "why" in c))
+            print(f"host: point {e.get('config')} ended {e.get('outcome')}"
+                  f"{' unbuilt' if e.get('unbuilt') else ''} {how} in "
+                  f"{e['dur_s']:.2f}s; trial.identity {cost}", flush=True)
         for e in sorted(spans, key=lambda e: e["ts_start"]):
             point = point_of(e)
             if point is None or e is point or not e["kind"].startswith("trial."):
