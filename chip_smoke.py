@@ -55,8 +55,8 @@ FOUR_CHIP_BATCHES = 96
 # launch barrier the cores halt on an on-device assertion ("schecklt: Invalid
 # logical z: enhanced-barrier-parent-phase-1"), and with the barrier switched
 # off two programs on disjoint blocks deadlock. PERF.md, PR 24, has the
-# diagnosis (tools/chip_diag_concurrent.py reproduces it). The four-chip block
-# is exercised by phase (a); the gangs here run side by side on single chips.
+# diagnosis. The four-chip block is exercised by phase (a); the gangs here
+# run side by side on single chips.
 FOUR_CHIP_SIZES = (1,)
 AGREE_SHAPE, AGREE_STEPS = (512, 8), 8  # (seq, batch) and steps of phase (a)
 TECHNIQUES = ("dp", "fsdp")

@@ -55,7 +55,7 @@ ENV_CAPACITY = "SATURN_TPU_HBM_BYTES"
 #: a point is *infeasible* only when predicted peak > OOM_MARGIN x
 #: capacity: static over-prediction within the margin never prunes a
 #: point the compiler might still fit
-OOM_MARGIN = float(os.environ.get("SATURN_TPU_MEMLENS_PRUNE_MARGIN", "1.15"))
+OOM_MARGIN = 1.15
 
 #: the same allocator headroom spmd_base._fits_compiled enforces;
 #: predictions between it and capacity get the SAT-M004 warning

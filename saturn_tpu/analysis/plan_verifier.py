@@ -24,7 +24,6 @@ call from every plan-adoption site.
 
 from __future__ import annotations
 
-import os
 from typing import Any, Callable, Dict, Iterable, List, Optional, Sequence, Tuple
 
 from saturn_tpu.analysis.diagnostics import (
@@ -72,8 +71,7 @@ def coschedule_find(names: Iterable[str], plan: Any) -> Callable[[str], str]:
 #: Above this many gang members the O(N²)-pairs + transitive-closure exact
 #: check hands off to the per-device sweep (same guarantees for every
 #: solver-produced plan; see :func:`_launch_diagnostics_sweep`).
-SWEEP_THRESHOLD = int(os.environ.get("SATURN_TPU_VERIFY_SWEEP_THRESHOLD",
-                                     "256"))
+SWEEP_THRESHOLD = 256
 
 
 def launch_diagnostics(names: Sequence[str], plan: Any, *,

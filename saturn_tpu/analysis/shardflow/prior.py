@@ -22,9 +22,10 @@ eventually-measured runtime by more than ``AUDIT_TOLERANCE``, which is
 how a drifting cost model gets caught instead of silently steering
 admission.
 
-The hardware constants are env-overridable deployment knobs, not
-measurements — the prior's job is *relative ordering* across techniques
-and sizes, and SAT-X005 polices its absolute error.
+The hardware constants are not measurements — the prior's job is
+*relative ordering* across techniques and sizes, and SAT-X005 polices its
+absolute error. The MFU target and the overlap factors are deployment knobs
+(``SATURN_TPU_PRIOR_MFU``, ``SATURN_TPU_PRIOR_OVERLAP_<OP>``).
 """
 
 from __future__ import annotations
@@ -42,9 +43,11 @@ log = logging.getLogger("saturn_tpu")
 #: |static - profiled| / profiled above which SAT-X005 fires.
 AUDIT_TOLERANCE = 0.35
 
-_ENV_PEAK = "SATURN_TPU_PRIOR_PEAK_FLOPS"
-_ENV_ICI = "SATURN_TPU_PRIOR_ICI_BYTES_S"
-_ENV_DCN = "SATURN_TPU_PRIOR_DCN_BYTES_S"
+#: The prior's roofline constants (see the module docstring).
+PEAK_FLOPS = 100e12     # bf16-class chip
+ICI_BYTES_S = 4.5e10    # per-link ICI
+DCN_BYTES_S = 2.5e9     # per-host DCN
+
 _ENV_MFU = "SATURN_TPU_PRIOR_MFU"
 _ENV_OVERLAP_PREFIX = "SATURN_TPU_PRIOR_OVERLAP_"
 
@@ -97,9 +100,9 @@ def overlap_factor_signature() -> str:
 def hardware_model() -> Dict[str, float]:
     """Roofline constants for the prior (per chip / per link)."""
     return {
-        "peak_flops": _envf(_ENV_PEAK, 100e12),   # bf16-class chip
-        "ici_bytes_s": _envf(_ENV_ICI, 4.5e10),   # per-link ICI
-        "dcn_bytes_s": _envf(_ENV_DCN, 2.5e9),    # per-host DCN
+        "peak_flops": PEAK_FLOPS,
+        "ici_bytes_s": ICI_BYTES_S,
+        "dcn_bytes_s": DCN_BYTES_S,
         "mfu": _envf(_ENV_MFU, 0.45),             # the repo's MFU target
     }
 

@@ -36,7 +36,6 @@ with stable ``SAT-H*`` event codes (see ``docs/architecture.md`` runbook).
 from __future__ import annotations
 
 import logging
-import os
 from dataclasses import dataclass
 from typing import Any, Dict, FrozenSet, Iterable, List, Optional, Tuple
 
@@ -82,7 +81,7 @@ CAUSE_HUNG = "hung_dispatch"
 
 @dataclass(frozen=True)
 class GuardianConfig:
-    """Recovery-policy knobs.
+    """Recovery policy.
 
     ``watchdog_floor_s`` is generous by default because the FIRST interval
     of a task pays XLA compilation inside its window — the deadline is
@@ -99,21 +98,6 @@ class GuardianConfig:
     watchdog: bool = True
     watchdog_factor: float = 8.0   # k in  k x profiled window time
     watchdog_floor_s: float = 60.0
-
-    @classmethod
-    def from_env(cls) -> "GuardianConfig":
-        def _f(name: str, default: float) -> float:
-            return float(os.environ.get(name, "") or default)
-
-        return cls(
-            retry_budget=int(_f("SATURN_TPU_HEALTH_RETRIES", cls.retry_budget)),
-            hung_budget=int(_f("SATURN_TPU_HUNG_RETRIES", cls.hung_budget)),
-            backoff_cap=int(_f("SATURN_TPU_HEALTH_BACKOFF_CAP", cls.backoff_cap)),
-            watchdog=os.environ.get("SATURN_TPU_WATCHDOG", "1").strip().lower()
-            not in ("0", "off", "false", "no"),
-            watchdog_factor=_f("SATURN_TPU_WATCHDOG_FACTOR", cls.watchdog_factor),
-            watchdog_floor_s=_f("SATURN_TPU_WATCHDOG_FLOOR_S", cls.watchdog_floor_s),
-        )
 
 
 @dataclass(frozen=True)
@@ -141,7 +125,7 @@ class TrainingGuardian:
     never participate in a lock-order cycle."""
 
     def __init__(self, config: Optional[GuardianConfig] = None, journal=None):
-        self.config = config if config is not None else GuardianConfig.from_env()
+        self.config = config if config is not None else GuardianConfig()
         self.journal = journal
         self._mu = tsan.lock("guardian.lock")
         # (task, cause) -> consecutive faults; cleared by note_success.

@@ -29,7 +29,6 @@ state, so the retry folds from exactly the pre-fault statistics.
 from __future__ import annotations
 
 import functools
-import os
 from dataclasses import dataclass
 from typing import Any, Callable, Dict, Optional, Tuple
 
@@ -86,7 +85,7 @@ class NumericFaultError(RuntimeError):
 
 @dataclass(frozen=True)
 class SentinelConfig:
-    """Sentinel policy knobs (resolved once per interval).
+    """Sentinel policy (resolved once per interval).
 
     ``spike_factor <= 0`` disables the EWMA spike score; non-finiteness is
     always screened while ``enabled``.
@@ -97,38 +96,19 @@ class SentinelConfig:
     ewma_alpha: float = 0.3
     warmup_steps: int = 8
 
-    @classmethod
-    def from_env(cls) -> "SentinelConfig":
-        """``SATURN_TPU_SENTINEL`` (0/off disables),
-        ``SATURN_TPU_SENTINEL_SPIKE`` (factor, 0 = off),
-        ``SATURN_TPU_SENTINEL_ALPHA``, ``SATURN_TPU_SENTINEL_WARMUP``."""
-        raw = os.environ.get("SATURN_TPU_SENTINEL", "1").strip().lower()
-        enabled = raw not in ("0", "off", "false", "no")
-        return cls(
-            enabled=enabled,
-            spike_factor=float(
-                os.environ.get("SATURN_TPU_SENTINEL_SPIKE", "0") or 0.0
-            ),
-            ewma_alpha=float(
-                os.environ.get("SATURN_TPU_SENTINEL_ALPHA", "0.3") or 0.3
-            ),
-            warmup_steps=int(
-                os.environ.get("SATURN_TPU_SENTINEL_WARMUP", "8") or 8
-            ),
-        )
-
 
 _override: Optional[SentinelConfig] = None
 
 
 def set_config(cfg: Optional[SentinelConfig]) -> None:
-    """Process-wide override (tests / campaigns); ``None`` restores env."""
+    """Process-wide override (tests / campaigns); ``None`` restores the
+    default policy."""
     global _override
     _override = cfg
 
 
 def get_config() -> SentinelConfig:
-    return _override if _override is not None else SentinelConfig.from_env()
+    return _override if _override is not None else SentinelConfig()
 
 
 def carry_init() -> np.ndarray:

@@ -44,16 +44,16 @@ the identical objective through plain XLA ops
 
 from __future__ import annotations
 
-import contextlib
 import functools
-import threading
-from typing import Any, List, NamedTuple, Optional
+from typing import Any, NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from saturn_tpu.ops import plans
 
 NEG_INF = -1e30
 
@@ -605,30 +605,6 @@ def ce_plan(n_tokens: int, d_model: int, n_vocab: int, *,
     )
 
 
-# Who traces a program asks here what its fused calls ran as: the plan of
-# every call traced by this thread inside the block, None for a call that
-# fell back to plain XLA ops (``parallel/spmd_base.py`` puts the first on the
-# grid point's ``trial_config`` event).
-_traced = threading.local()
-
-
-@contextlib.contextmanager
-def traced_plans():
-    outer = getattr(_traced, "plans", None)
-    plans: List[Optional[CEPlan]] = []
-    _traced.plans = plans
-    try:
-        yield plans
-    finally:
-        _traced.plans = outer
-
-
-def _note_plan(plan: Optional[CEPlan]) -> None:
-    plans = getattr(_traced, "plans", None)
-    if plans is not None:
-        plans.append(plan)
-
-
 def fused_linear_cross_entropy(
     x: jax.Array,
     w: jax.Array,
@@ -699,7 +675,7 @@ def fused_linear_cross_entropy(
         _use_interpret() or plan.bv % 128 != 0 or plan.bv_dw % 128 != 0
     ):
         plan = None
-    _note_plan(plan)
+    plans.record("ce", plan)   # None: the call falls back to plain XLA ops
     if plan is None:
         return dense_fallback()
     x2 = x.reshape(N, D)

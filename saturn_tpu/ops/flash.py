@@ -46,7 +46,7 @@ chip's readings):
   (a power of two: head dims 64 and 256) and the scores where it is not
   (128).
 
-The launchers sit behind ``jit``'s tracing cache (``_traced_once``): the
+The launchers sit behind ``jit``'s tracing cache (``plans._traced_once``): the
 layer traced again under remat, or the next grid point of a search, binds
 the ``pallas_call`` traced the first time and traces no kernel body again.
 
@@ -61,7 +61,6 @@ Numerics are validated against the dense reference in interpret mode on CPU
 
 from __future__ import annotations
 
-import contextlib
 import functools
 import math
 from typing import Optional
@@ -70,6 +69,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from saturn_tpu.ops import plans
 
 NEG_INF = -1e30
 _LANES = 128
@@ -241,15 +242,9 @@ def _name(part: str, window, D: int, Dv: int) -> str:
     return f"saturn_flash_{part}" if window is None else f"saturn_swa_{part}"
 
 
-def _traced_once(fn):
-    """``fn`` behind ``jit``'s tracing cache, inlined where it is called: a
-    call with shapes and blocks seen before (the layer again under remat,
-    the next grid point of a search) binds the ``pallas_call`` it traced the
-    first time and traces no kernel body again; the caller's jaxpr holds the
-    ``pallas_call`` itself, as if ``fn`` had been called bare."""
-    names = ("block_q", "block_k", "chunk", "scale", "causal", "h", "kv",
-             "window", "interpret")
-    return jax.jit(fn, static_argnames=names, inline=True)
+#: the launchers' static arguments
+_STATIC = ("block_q", "block_k", "chunk", "scale", "causal", "h", "kv",
+           "window", "interpret")
 
 
 # --------------------------------------------------------------------- fwd
@@ -354,7 +349,7 @@ def _keys_down(D: int) -> bool:
     return D < _LANES
 
 
-@_traced_once
+@plans._traced_once(*_STATIC)
 def _fwd(q, k, v, *, block_q, block_k, chunk, scale, causal, h, kv,
          window=None, interpret=False):
     BH, T, D = q.shape
@@ -487,7 +482,7 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         dv_ref[0] = dv_scr[:].astype(dv_ref.dtype)
 
 
-@_traced_once
+@plans._traced_once(*_STATIC)
 def _dq(q, k, v, do, lse, delta, *, block_q, block_k, chunk, scale, causal,
         h, kv, window=None, interpret=False):
     BH, T, D = q.shape
@@ -523,7 +518,7 @@ def _dq(q, k, v, do, lse, delta, *, block_q, block_k, chunk, scale, causal,
     )(q, k, v, do, lse, delta)
 
 
-@_traced_once
+@plans._traced_once(*_STATIC)
 def _dkv(q, k, v, do, lse, delta, *, block_q, block_k, chunk, scale, causal,
          h, kv, window=None, interpret=False):
     T, D = q.shape[1:]
@@ -617,29 +612,6 @@ def _flash_bh_bwd(blocks, causal, h, kv, window, res, do):
 
 
 _flash_bh.defvjp(_flash_bh_fwd, _flash_bh_bwd)
-
-
-_PLANS: dict = {"window": [], "flash": []}
-
-
-@contextlib.contextmanager
-def _traced_plans(kind: str):
-    before, _PLANS[kind] = _PLANS[kind], []
-    try:
-        yield _PLANS[kind]
-    finally:
-        _PLANS[kind] = before
-
-
-def traced_window_plans():
-    """Collects ``window_plan`` of every window call traced inside (as
-    ``ops/ce.py``'s ``traced_plans``)."""
-    return _traced_plans("window")
-
-
-def traced_flash_plans():
-    """Likewise ``flash_plan`` of every causal call traced inside."""
-    return _traced_plans("flash")
 
 
 def window_plan(T: int, window: int, block: Optional[int] = None) -> dict:
@@ -839,7 +811,7 @@ def flash_attention(
         b = block_q or _window_block(T)
         if T % b:
             raise ValueError(f"seq len {T} not divisible by the block ({b})")
-        _PLANS["window"].append(window_plan(T, int(window), b))
+        plans.record("window", window_plan(T, int(window), b))
         # a chunk of one block: what a step fetches is what the window reaches
         blocks = ((b, b, b),) * 3
         o = _flash_bh(qf, kf, vf, blocks, True, H, KV, int(window))
@@ -850,6 +822,6 @@ def flash_attention(
     if any(T % b for triple in blocks for b in triple):
         raise ValueError(f"seq len {T} not divisible by blocks {blocks}")
     if causal:
-        _PLANS["flash"].append(plan)
+        plans.record("flash", plan)
     o = _flash_bh(qf, kf, vf, blocks, causal, H, KV)
     return o.reshape(B, H, T, Dv)

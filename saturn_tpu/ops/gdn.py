@@ -57,14 +57,15 @@ the rule run token by token.
 
 from __future__ import annotations
 
-import contextlib
 import functools
-from typing import List, NamedTuple, Optional
+from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from saturn_tpu.ops import plans
 
 CHUNK = 64
 _HIGHEST = jax.lax.Precision.HIGHEST
@@ -287,20 +288,6 @@ class GDNPlan(NamedTuple):
     vmem_bytes: Optional[int]   # the kernel's VMEM sum; None for "xla"
 
 
-_PLANS: Optional[List[GDNPlan]] = None
-
-
-@contextlib.contextmanager
-def traced_plans():
-    """Collects the plan of every call traced inside (as ``ops/ce.py``'s)."""
-    global _PLANS
-    before, _PLANS = _PLANS, []
-    try:
-        yield _PLANS
-    finally:
-        _PLANS = before
-
-
 def gated_delta_rule(q, k, v, g, beta, *, impl: str = "xla", chunk: int = CHUNK):
     """``q`` / ``k`` (B, H, T, dk), ``v`` (B, H, T, dv), ``g`` (log decay,
     <= 0) / ``beta`` (B, H, T) float32 -> ``o`` (B, H, T, dv) **float32**;
@@ -318,10 +305,9 @@ def gated_delta_rule(q, k, v, g, beta, *, impl: str = "xla", chunk: int = CHUNK)
     pad = -t % chunk
     flat = lambda x: jnp.pad(x.reshape(b * h, *x.shape[2:]),
                              ((0, 0), (0, pad)) + ((0, 0),) * (x.ndim - 3))
-    if _PLANS is not None:
-        _PLANS.append(GDNPlan(
-            impl, chunk, b * h, (t + pad) // chunk, dk, dv,
-            fwd_vmem_bytes(chunk, dk, dv, q.dtype.itemsize) if impl == "kernel" else None))
+    plans.record("gdn", GDNPlan(
+        impl, chunk, b * h, (t + pad) // chunk, dk, dv,
+        fwd_vmem_bytes(chunk, dk, dv, q.dtype.itemsize) if impl == "kernel" else None))
     o = _gdn(flat(q), flat(k), flat(v), flat(g.astype(jnp.float32)),
              flat(beta.astype(jnp.float32)), chunk, impl == "kernel")
     return o[:, :t].reshape(b, h, t, dv)

@@ -64,13 +64,12 @@ token by token, with the gates at their bound and at 0.
 
 from __future__ import annotations
 
-import contextlib
-import functools
-from typing import List, NamedTuple, Optional
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
 
+from saturn_tpu.ops import plans
 from saturn_tpu.ops.gdn import (_HIGHEST, _by_chunks, _from_chunks, _mm, _mm32,
                                 _unit_lower_inverse)
 
@@ -157,14 +156,11 @@ def _chunk(s, q, k, v, g, beta):
     return o, s_next
 
 
-#: behind ``jit``'s tracing cache, inlined where it is called (as
-#: ``ops/flash.py``'s launchers are): the same layer again (five of a period's
-#: six, each again under remat and in the ``custom_vjp``'s rules) binds what
-#: was traced the first time. A chunk's vjp is among the step's longest traces.
-_traced_once = functools.partial(jax.jit, inline=True)
-
-
-@_traced_once
+# The scans sit behind ``jit``'s tracing cache (as ``ops/flash.py``'s launchers
+# do): the same layer again (five of a period's six, each again under remat
+# and in the ``custom_vjp``'s rules) binds what was traced the first time. A
+# chunk's vjp is among the step's longest traces.
+@plans._traced_once()
 def _fwd_scan(q, k, v, g, beta):
     """-> (o (N, T, dv) float32, the state each chunk started from
     (T / C, N, dk, dv) float32)."""
@@ -178,7 +174,7 @@ def _fwd_scan(q, k, v, g, beta):
     return _from_chunks(o), starts
 
 
-@_traced_once
+@plans._traced_once()
 def _bwd_scan(q, k, v, g, beta, starts, do):
     def body(ds, xs):
         s, do_c, *inputs = xs
@@ -218,20 +214,6 @@ class KDAPlan(NamedTuple):
     state_bytes_kept: int       # the chunks' starting states kept for the backward
 
 
-_PLANS: Optional[List[KDAPlan]] = None
-
-
-@contextlib.contextmanager
-def traced_plans():
-    """Collects the plan of every call traced inside (as ``ops/ce.py``'s)."""
-    global _PLANS
-    before, _PLANS = _PLANS, []
-    try:
-        yield _PLANS
-    finally:
-        _PLANS = before
-
-
 def kda(q, k, v, g, beta):
     """``q`` / ``k`` (B, H, T, dk), ``v`` (B, H, T, dv), ``g`` (B, H, T, dk)
     (log decay a channel, <= 0, and no lower than -88 / ``SUB`` a token) /
@@ -245,10 +227,9 @@ def kda(q, k, v, g, beta):
     pad = -t % CHUNK
     flat = lambda x: jnp.pad(x.reshape(b * h, *x.shape[2:]),
                              ((0, 0), (0, pad)) + ((0, 0),) * (x.ndim - 3))
-    if _PLANS is not None:
-        chunks = (t + pad) // CHUNK
-        _PLANS.append(KDAPlan("xla", CHUNK, SUB, b * h, chunks, dk, dv,
-                              chunks * b * h * dk * dv * 4))
+    chunks = (t + pad) // CHUNK
+    plans.record("kda", KDAPlan("xla", CHUNK, SUB, b * h, chunks, dk, dv,
+                                chunks * b * h * dk * dv * 4))
     o = _kda(flat(q), flat(k), flat(v), flat(g.astype(jnp.float32)),
              flat(beta.astype(jnp.float32)))
     return o[:, :t].reshape(b, h, t, dv)

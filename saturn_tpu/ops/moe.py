@@ -15,7 +15,6 @@ all-to-alls over ICI.
 
 from __future__ import annotations
 
-import contextlib
 import functools
 import math
 from dataclasses import asdict, dataclass
@@ -26,6 +25,8 @@ import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from saturn_tpu.ops import plans
 
 
 def expert_capacity(n_tokens: int, n_experts: int, capacity_factor: float) -> int:
@@ -132,21 +133,6 @@ class RoutedPlan:
 
     def as_event(self) -> Dict[str, Any]:
         return dict(asdict(self), second_path=self.second_path)
-
-
-_PLANS: list = []
-
-
-@contextlib.contextmanager
-def traced_plans():
-    """Collects the plan of every routed layer traced inside (as
-    ``ops/ce.py``'s)."""
-    global _PLANS
-    before, _PLANS = _PLANS, []
-    try:
-        yield _PLANS
-    finally:
-        _PLANS = before
 
 
 def _round_up(n: int, to: int) -> int:
@@ -508,7 +494,7 @@ def routed_experts(y, router, w_gate, w_up, w_down, *, plan: RoutedPlan,
                          f"experts, {w_up.shape[0]} held, gate {w_gate is not None}, "
                          f"bias {bias is not None}, latent rows "
                          f"{None if latent is None else latent.shape}")
-    _PLANS.append(plan)
+    plans.record("moe", plan)
     y = y.astype(dtype)
     scores = jax.nn.sigmoid(jnp.dot(y.astype(f32), router.astype(f32),
                                     precision=jax.lax.Precision.HIGHEST))

@@ -48,15 +48,15 @@ the recurrence run token by token.
 
 from __future__ import annotations
 
-import contextlib
 import functools
-from typing import List, NamedTuple, Optional
+from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from saturn_tpu.ops import plans
 from saturn_tpu.ops.gdn import _by_chunks, _dot, _from_chunks, _mm, _use_interpret
 
 CHUNK = 128
@@ -243,20 +243,6 @@ class SSDPlan(NamedTuple):
     vmem_bytes: Optional[int]   # the kernel's VMEM sum; None for "xla"
 
 
-_PLANS: Optional[List[SSDPlan]] = None
-
-
-@contextlib.contextmanager
-def traced_plans():
-    """Collects the plan of every call traced inside (as ``ops/ce.py``'s)."""
-    global _PLANS
-    before, _PLANS = _PLANS, []
-    try:
-        yield _PLANS
-    finally:
-        _PLANS = before
-
-
 def ssd(x, dt, a, b, c, d, *, impl: str = "xla", chunk: int = CHUNK,
         published: Optional[tuple] = None):
     """``x`` (B, T, H, P); ``dt`` (B, T, H) float32, > 0; ``a`` (H,) float32,
@@ -288,13 +274,12 @@ def ssd(x, dt, a, b, c, d, *, impl: str = "xla", chunk: int = CHUNK,
         y = jnp.moveaxis(y, 1, 2).reshape(bsz * groups, t, n)
         return jnp.pad(y, ((0, 0), (0, pad), (0, 0)))
 
-    if _PLANS is not None:
-        chunks = (t + pad) // chunk
-        heads_all, groups_all = published or (h, groups)
-        _PLANS.append(SSDPlan(
-            impl, chunk, bsz * groups, chunks, h, groups, heads_all, groups_all, p, n,
-            chunks * bsz * h * p * n * 4,
-            fwd_vmem_bytes(chunk, per, p, n, x.dtype.itemsize) if impl == "kernel" else None))
+    chunks = (t + pad) // chunk
+    heads_all, groups_all = published or (h, groups)
+    plans.record("ssd", SSDPlan(
+        impl, chunk, bsz * groups, chunks, h, groups, heads_all, groups_all, p, n,
+        chunks * bsz * h * p * n * 4,
+        fwd_vmem_bytes(chunk, per, p, n, x.dtype.itemsize) if impl == "kernel" else None))
     o = _ssd(heads_first(xdt), groups_first(b), groups_first(c), heads_first(g),
              chunk, impl == "kernel")
     o = jnp.moveaxis(o[:, :, :t].reshape(bsz, h, t, p), 1, 2)

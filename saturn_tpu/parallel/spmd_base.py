@@ -34,6 +34,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from saturn_tpu.core.mesh import make_submesh
 from saturn_tpu.core.technique import BaseTechnique, InfeasibleConfig
+from saturn_tpu.ops import plans as _plans
 from saturn_tpu.parallel import sharding as shr
 from saturn_tpu.utils import aot_cache
 from saturn_tpu.utils import checkpoint as ckpt
@@ -41,8 +42,8 @@ from saturn_tpu.utils import metrics as _metrics
 from saturn_tpu.utils import point_records
 from saturn_tpu.utils.timing import (
     FUSED_WINDOW_STACKS,
-    device_hbm_bytes,
     hbm_bytes_required,
+    hbm_limit,
     time_fused_window,
     time_train_step,
 )
@@ -65,24 +66,6 @@ def _stage_to_device(tree):
 DEFAULT_MAX_WINDOW = 8
 
 _ENV_MAX_WINDOW = "SATURN_TPU_MAX_WINDOW"
-
-
-def _env_hbm_bytes() -> int:
-    """SATURN_TPU_HBM_BYTES (memlens's capacity override) as an int, 0
-    when unset/garbage — platforms that report no memory stats fall back
-    to it so compile-time rejection works on CPU sweeps too."""
-    try:
-        return max(int(float(os.environ.get("SATURN_TPU_HBM_BYTES", "0"))), 0)
-    except ValueError:
-        return 0
-
-
-def _hbm_limit(device: Any) -> int:
-    """The HBM limit the memory rule reads: the device's own, else (a platform
-    that reports none: CPU tests) the capacity memlens reads from the
-    environment, so that CPU sweeps can model a chip; 0 = none known."""
-    limit = device_hbm_bytes(device)
-    return limit if limit > 0 else _env_hbm_bytes()
 
 
 def max_window() -> int:
@@ -193,24 +176,11 @@ class _Bundle:
     # bundle (a one-element list the tracing site counts into): 1
     trace_count: List[int]
     retrace_key: Any = None   # stable (task, config, block) dispatch identity
-    # what each fused head+loss call of the step was traced as (ops/ce.py's
-    # plan; None for a call that fell back to plain XLA ops); empty where the
-    # step has no such call
-    ce_plans: Tuple[Any, ...] = ()
-    # likewise each gated-delta-rule call (ops/gdn.py's plan: kernel or plain
-    # scan, chunk, grid, the kernel's VMEM sum); empty for a model with no
-    # linear-attention layer
-    gdn_plans: Tuple[Any, ...] = ()
-    # likewise each routed-expert layer (ops/moe.py's ``RoutedPlan``) and
-    # each window-attention call (ops/flash.py's ``window_plan``) and each
-    # causal flash call (its ``flash_plan``)
-    moe_plans: Tuple[Any, ...] = ()
-    # likewise each state-space layer's scan (ops/ssd.py's ``SSDPlan``)
-    ssd_plans: Tuple[Any, ...] = ()
-    # likewise each Kimi-delta-attention call (ops/kda.py's ``KDAPlan``)
-    kda_plans: Tuple[Any, ...] = ()
-    window_plans: Tuple[Any, ...] = ()
-    flash_plans: Tuple[Any, ...] = ()
+    # what the step's ops were traced as (``ops/plans.py``): an op family's
+    # name -> the plan of each of its calls in the order traced (None for a
+    # call that fell back to plain XLA ops); no key for a family the step
+    # does not call
+    plans: Dict[str, Tuple[Any, ...]] = field(default_factory=dict)
     _lowered: Any = None
     _compiled: Any = None
     _single_lock: Any = field(default_factory=threading.Lock)
@@ -992,25 +962,13 @@ class SPMDTechnique(BaseTechnique):
         # every analysis (``trace_step``) is made from it; ``trace_count``
         # counts the calls of the model's Python step function, so a second
         # tracing site would show in ``step_traces``.
-        from saturn_tpu.ops import ce as _ce
-        from saturn_tpu.ops import flash as _flash
-        from saturn_tpu.ops import gdn as _gdn
-        from saturn_tpu.ops import moe as _moe
-        from saturn_tpu.ops import kda as _kda
-        from saturn_tpu.ops import ssd as _ssd
-
         trace_count = [0]
 
         def counted_step(state, batch):
             trace_count[0] += 1
             return train_step(state, batch)
 
-        with _ce.traced_plans() as ce_plans, _gdn.traced_plans() as gdn_plans, \
-                _moe.traced_plans() as moe_plans, \
-                _ssd.traced_plans() as ssd_plans, \
-                _kda.traced_plans() as kda_plans, \
-                _flash.traced_window_plans() as window_plans, \
-                _flash.traced_flash_plans() as flash_plans:
+        with _plans.traced() as traced_as:
             closed, out_shapes = jax.make_jaxpr(
                 counted_step, return_shape=True
             )(state_shapes, batch_sds)
@@ -1065,13 +1023,7 @@ class SPMDTechnique(BaseTechnique):
             replay=replay,
             batch_sds=batch_sds,
             trace_count=trace_count,
-            ce_plans=tuple(ce_plans),
-            gdn_plans=tuple(gdn_plans),
-            moe_plans=tuple(moe_plans),
-            ssd_plans=tuple(ssd_plans),
-            kda_plans=tuple(kda_plans),
-            window_plans=tuple(window_plans),
-            flash_plans=tuple(flash_plans),
+            plans={name: tuple(got) for name, got in traced_as.items()},
         )
 
     # ------------------------------------------------------------- shardflow
@@ -1117,7 +1069,7 @@ class SPMDTechnique(BaseTechnique):
         SAT-M005 drift audit accrues for free on every sweep.
         """
         with _metrics.span("trial.memory_check", k=int(k)) as sp:
-            limit = _hbm_limit(devices[0])
+            limit = hbm_limit(devices[0])
             need = hbm_bytes_required(compiled)
             sp.set(need_bytes=int(need), limit_bytes=int(limit))
             if task is not None and config is not None:
@@ -1385,51 +1337,20 @@ class SPMDTechnique(BaseTechnique):
         return out
 
     def _plan_fields(self, task, devices, config) -> Dict[str, Any]:
-        """``ce_plan`` of a grid point whose loss is the fused one, for its
-        ``trial_config`` event: the blocks, the backward's mode, the backward
-        kernels' VMEM sums and what dx asked the compiler for, as the step
-        was traced (``ops/ce.py::ce_plan``); None where the op computed
-        through plain XLA ops (off-TPU, or no block tiles the tokens). And
-        ``gdn_plan`` of a model with linear-attention layers: the first
-        layer's call of the gated delta rule (``ops/gdn.py::GDNPlan``:
-        kernel or plain scan, chunk, grid, the kernel's VMEM sum);
-        ``moe_plan`` of a model with routed-expert layers (``ops/moe.py::
-        RoutedPlan``: kernel or twin, row tile, buffer rows and the worst
-        case, experts held / all, top-k, the expert's kind, the latent width,
-        whether a selection bias is added), ``ssd_plan`` of one with
-        state-space layers (``ops/ssd.py::SSDPlan``: kernel or twin, chunk,
-        heads and groups held / published, the state bytes a layer keeps for
-        the backward), ``kda_plan`` of one with Kimi-delta-attention layers
-        (``ops/kda.py::KDAPlan``: chunk, sub-block, heads, d_k, d_v, the kept
-        states' bytes) and ``window_plan`` of one with
-        sliding-window layers (``ops/flash.py::window_plan``: window, block,
-        key blocks visited and skipped a call); ``flash_plan`` of one whose
-        causal attention runs the flash kernels (``ops/flash.py::flash_plan``:
-        each kernel's blocks and chunk, the score blocks it visits a head
-        and those of them the diagonal crosses; ``d_qk`` / ``d_v`` where the
-        scores' lanes and the values' differ: latent attention). Beside them
-        ``step_traces``: how often the model's Python step function was
-        called for this grid point (its bundle's one trace: 1). Nothing where
-        the point's bundle was never built."""
+        """What the grid point's ops were traced as, for its ``trial_config``
+        event: ``<family>_plan`` for each op family that recorded a call
+        (``ops/plans.py``), the first call's plan in its event form (a
+        model's calls of one family are alike; the family's op file says what
+        its plan holds), None where that call fell back to plain XLA ops.
+        Beside them ``step_traces``: how often the model's Python step
+        function was called for this grid point (its bundle's one trace: 1).
+        Nothing where the point's bundle was never built."""
         bundle = self._cached_bundle(task, devices, config)
         if bundle is None:
             return {}
         out: Dict[str, Any] = {"step_traces": bundle.step_traces}
-        if bundle.ce_plans:
-            plan = bundle.ce_plans[0]
-            out["ce_plan"] = None if plan is None else plan._asdict()
-        if bundle.gdn_plans:
-            out["gdn_plan"] = bundle.gdn_plans[0]._asdict()
-        if bundle.moe_plans:   # the first routed layer's (all are alike)
-            out["moe_plan"] = bundle.moe_plans[0].as_event()
-        if bundle.ssd_plans:   # the first state-space layer's (all are alike)
-            out["ssd_plan"] = bundle.ssd_plans[0]._asdict()
-        if bundle.kda_plans:   # the first delta-rule layer's (all are alike)
-            out["kda_plan"] = bundle.kda_plans[0]._asdict()
-        if bundle.window_plans:
-            out["window_plan"] = dict(bundle.window_plans[0])
-        if bundle.flash_plans:   # the first causal call's (a model has one T, D)
-            out["flash_plan"] = dict(bundle.flash_plans[0])
+        for name, got in bundle.plans.items():
+            out[f"{name}_plan"] = _plans.as_event(got[0])
         return out
 
     def _profile_window(self, config: Dict[str, Any]) -> int:
@@ -1437,15 +1358,6 @@ class SPMDTechnique(BaseTechnique):
         windows of the max size, so that is what the MILP's per-batch times
         must measure — not the per-step program fused dispatch retired."""
         return max_window() if self._fused_ok(config) else 1
-
-    def _try_config(
-        self, task: Any, devices: Sequence[Any], config: Dict[str, Any]
-    ) -> Optional[Tuple[float, float]]:
-        """(seconds/batch, host_fraction) for one config on this thread;
-        None = over memory. What ``search`` does for a grid point, without
-        the grid (the chip diagnostics call it)."""
-        prepared = self._prepare(task, devices, config)
-        return None if prepared is None else self._measure(task, prepared)
 
     def _prepare(
         self, task: Any, devices: Sequence[Any], config: Dict[str, Any]

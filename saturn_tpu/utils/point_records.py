@@ -66,6 +66,7 @@ import numpy as np
 
 from saturn_tpu.utils import aot_cache
 from saturn_tpu.utils import metrics as _metrics
+from saturn_tpu.utils.timing import hbm_limit
 
 log = logging.getLogger("saturn_tpu")
 
@@ -281,8 +282,6 @@ def _parameter_shapes(task: Any) -> list:
 def _key(technique: Any, task: Any, devices: Sequence[Any],
          config: Dict[str, Any], k: int) -> str:
     """The point's identity as a file name; raises where it has none."""
-    from saturn_tpu.parallel.spmd_base import _hbm_limit   # utils below parallel
-
     spec = task.get_model(**technique._model_overrides(config))
     if not dataclasses.is_dataclass(spec):
         raise _NoIdentity("the model is no ModelSpec")
@@ -299,7 +298,7 @@ def _key(technique: Any, task: Any, devices: Sequence[Any],
         [_named(type(technique)), getattr(technique, "name", None)],
         canon.of(dict(config)), int(k),
         [len(devices), sorted({str(getattr(d, "device_kind", "?"))
-                               for d in devices}), _hbm_limit(devices[0])],
+                               for d in devices}), hbm_limit(devices[0])],
         aot_cache._runtime_identity(), aot_cache._compiler_identity(),
         list(_versions()), _settings(), _where(),
         model, _parameter_shapes(task),

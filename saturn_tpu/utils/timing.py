@@ -12,6 +12,7 @@ from __future__ import annotations
 import contextlib
 import gc
 import logging
+import os
 import sys
 import threading
 import timeit
@@ -195,3 +196,21 @@ def device_hbm_bytes(device) -> int:
     except Exception:
         pass
     return 0
+
+
+def env_hbm_bytes() -> int:
+    """SATURN_TPU_HBM_BYTES (memlens's capacity override) as an int, 0
+    when unset/garbage — platforms that report no memory stats fall back
+    to it so compile-time rejection works on CPU sweeps too."""
+    try:
+        return max(int(float(os.environ.get("SATURN_TPU_HBM_BYTES", "0"))), 0)
+    except ValueError:
+        return 0
+
+
+def hbm_limit(device) -> int:
+    """The HBM limit the memory rule reads: the device's own, else (a platform
+    that reports none: CPU tests) the capacity memlens reads from the
+    environment, so that CPU sweeps can model a chip; 0 = none known."""
+    limit = device_hbm_bytes(device)
+    return limit if limit > 0 else env_hbm_bytes()

@@ -547,18 +547,18 @@ def test_ce_plan_is_none_where_no_block_tiles_the_tokens():
 
 @pytest.mark.parametrize("interpret", [True, None], ids=["kernel", "dense-fallback"])
 def test_traced_plans_say_what_a_call_ran_as(interpret):
-    """``traced_plans`` collects the plan the op followed, None for a call
+    """``plans.traced`` collects the plan the op followed, None for a call
     that computed through plain XLA ops (off-TPU without interpret mode)."""
-    from saturn_tpu.ops import ce
+    from saturn_tpu.ops import ce, plans
 
     x, w, labels = _case(n=128, d=64, v=256)
-    with ce.traced_plans() as outer:
-        with ce.traced_plans() as plans:
+    with plans.traced() as outer:
+        with plans.traced() as got:
             jax.make_jaxpr(lambda x_: fused_linear_cross_entropy(
                 x_, w, labels, interpret=interpret))(x)
-        assert outer == []          # the inner block kept its own
+        assert outer == {}          # the inner block kept its own
     want = ce.ce_plan(128, 64, 256) if interpret else None
-    assert plans == [want]
+    assert got == {"ce": [want]}
     fused_linear_cross_entropy(x, w, labels, interpret=interpret)  # no block open
 
 

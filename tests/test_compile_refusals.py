@@ -563,7 +563,7 @@ def test_the_memory_check_records_what_it_rejects(store, monkeypatch, tmp_path):
     from saturn_tpu.parallel.dp import DataParallel
 
     exe = aot_cache.load_or_compile(Weighed(store.parent))
-    monkeypatch.setattr(spmd_base, "device_hbm_bytes", lambda d: 16 * 2 ** 30)
+    monkeypatch.setattr(spmd_base, "hbm_limit", lambda d: 16 * 2 ** 30)
     monkeypatch.setattr(spmd_base, "hbm_bytes_required", lambda c: 15 * 2 ** 30)
     events = str(tmp_path / "ev.jsonl")
     with metrics.scoped(events):

@@ -15,6 +15,7 @@ import numpy as np
 import pytest
 
 from saturn_tpu.ops import gdn
+from saturn_tpu.ops import plans as op_plans
 
 IMPLS = ("xla", "kernel")
 
@@ -100,9 +101,10 @@ def test_which_kernel_runs_where_and_the_plan_of_a_call():
     assert "saturn_gdn_fwd_only" in alone            # outside a gradient: no states kept
     under_grad = str(jax.make_jaxpr(jax.grad(fn))(*x)).replace("saturn_gdn_fwd_only", "")
     assert "saturn_gdn_fwd" in under_grad            # the differentiated forward keeps them
-    with gdn.traced_plans() as plans:
+    with op_plans.traced() as got:
         jax.eval_shape(lambda *a: gdn.gated_delta_rule(*a, impl="kernel", chunk=16), *x)
         jax.eval_shape(lambda *a: gdn.gated_delta_rule(*a, impl="xla"), *x)
+    plans = got["gdn"]
     assert plans[0] == gdn.GDNPlan("kernel", 16, 6, 4, 24, 40,
                                    gdn.fwd_vmem_bytes(16, 24, 40, 4))
     assert plans[1] == gdn.GDNPlan("xla", 64, 6, 1, 24, 40, None)

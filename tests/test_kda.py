@@ -13,6 +13,7 @@ import numpy as np
 import pytest
 
 from saturn_tpu.ops import kda
+from saturn_tpu.ops import plans as op_plans
 
 GATES = {"spread": None, "at-the-bound": -4.99, "at-zero": 0.0}
 
@@ -141,6 +142,6 @@ def test_bf16_operands_and_a_float32_state():
 
 def test_the_plan_of_a_call():
     x = _inputs(7, 100)
-    with kda.traced_plans() as plans:
+    with op_plans.traced() as got:
         jax.eval_shape(kda.kda, *x)
-    assert plans == [kda.KDAPlan("xla", 64, 16, 6, 2, 24, 40, 2 * 6 * 24 * 40 * 4)]
+    assert got["kda"] == [kda.KDAPlan("xla", 64, 16, 6, 2, 24, 40, 2 * 6 * 24 * 40 * 4)]

@@ -17,6 +17,7 @@ import pytest
 from perf.reference import ling
 from saturn_tpu.models.gpt2 import GPT2Config, build_ling, config_for
 from saturn_tpu.ops import flash, kda, moe
+from saturn_tpu.ops import plans as op_plans
 
 SEED, SEQ = 3, 128
 KINDS = {"kda": 5, "mla": 1}
@@ -77,9 +78,9 @@ def test_logits_loss_and_gradients_are_the_references(reference_side, attention)
 def test_the_kernel_grid_point_traces_the_kernels_and_says_its_plans():
     spec = build_ling("ling-test-tiny", attention="flash", remat=True)
     shapes = jax.eval_shape(spec.init_fn, jax.random.PRNGKey(0))
-    with kda.traced_plans() as kda_plans, moe.traced_plans() as moe_plans, \
-            flash.traced_flash_plans() as flash_plans:
+    with op_plans.traced() as got:
         text = str(jax.make_jaxpr(jax.grad(spec.fused_loss_fn))(shapes, _tokens()))
+    kda_plans, moe_plans, flash_plans = got["kda"], got["moe"], got["flash"]
     for kernel in ("saturn_mla_fwd", "saturn_mla_dq", "saturn_mla_dkv",
                    "saturn_gmm_fwd", "saturn_gmm_dw"):
         assert kernel in text, kernel

@@ -261,14 +261,14 @@ def test_prune_margin_env_default():
 
 
 def test_env_hbm_bytes_backstop(monkeypatch):
-    from saturn_tpu.parallel import spmd_base
+    from saturn_tpu.utils import timing
 
     monkeypatch.delenv(ml_passes.ENV_CAPACITY, raising=False)
-    assert spmd_base._env_hbm_bytes() == 0
+    assert timing.env_hbm_bytes() == 0
     monkeypatch.setenv(ml_passes.ENV_CAPACITY, "123456")
-    assert spmd_base._env_hbm_bytes() == 123456
+    assert timing.env_hbm_bytes() == 123456
     monkeypatch.setenv(ml_passes.ENV_CAPACITY, "junk")
-    assert spmd_base._env_hbm_bytes() == 0
+    assert timing.env_hbm_bytes() == 0
 
 
 # ------------------------------------------------- pipeline stash residency

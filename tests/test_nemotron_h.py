@@ -77,12 +77,13 @@ def test_logits_loss_and_gradients_are_the_references(reference_side, attention)
 
 
 def test_the_kernel_grid_point_traces_the_kernels_and_says_its_plans():
-    from saturn_tpu.ops import ssd
+    from saturn_tpu.ops import plans as op_plans
 
     spec = build_nemotron_h("nemotron-test-tiny", attention="flash", remat=True, **HELD)
     shapes = jax.eval_shape(spec.init_fn, jax.random.PRNGKey(0))
-    with ssd.traced_plans() as ssd_plans, moe.traced_plans() as moe_plans:
+    with op_plans.traced() as got:
         text = str(jax.make_jaxpr(jax.grad(spec.fused_loss_fn))(shapes, _tokens()))
+    ssd_plans, moe_plans = got["ssd"], got["moe"]
     for kernel in ("saturn_ssd_fwd", "saturn_gmm_fwd", "saturn_gmm_dw", "saturn_flash_fwd"):
         assert kernel in text, kernel
     plan = ssd_plans[0]

@@ -12,6 +12,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from saturn_tpu.ops import plans as op_plans
 from saturn_tpu.ops import ssd
 
 IMPLS = ("xla", "kernel")
@@ -105,10 +106,11 @@ def test_which_kernel_runs_where_and_the_plan_of_a_call():
     assert "saturn_ssd_fwd_only" in alone            # outside a gradient: no states kept
     under_grad = str(jax.make_jaxpr(jax.grad(fn))(*x)).replace("saturn_ssd_fwd_only", "")
     assert "saturn_ssd_fwd" in under_grad            # the differentiated forward keeps them
-    with ssd.traced_plans() as plans:
+    with op_plans.traced() as got:
         jax.eval_shape(lambda *a: ssd.ssd(*a, impl="kernel", chunk=16,
                                           published=(16, 8)), *x)
         jax.eval_shape(lambda *a: ssd.ssd(*a, impl="xla"), *x)
+    plans = got["ssd"]
     kept = 4 * 2 * 4 * 8 * 16 * 4          # chunks x batch x heads x P x N x 4 B
     assert plans[0] == ssd.SSDPlan("kernel", 16, 4, 4, 4, 2, 16, 8, 8, 16, kept,
                                    ssd.fwd_vmem_bytes(16, 2, 8, 16, 4))

@@ -156,6 +156,7 @@ def test_kda_scan_compiles_for_v5e(one_chip, real_lowering, grad):
     in chunks of 64 with 16-token sub-blocks, bf16 operands, a gate a key
     channel in float32; the differentiated call keeps the chunks' states."""
     from saturn_tpu.ops import kda as kda_mod
+    from saturn_tpu.ops import plans as op_plans
 
     sds = lambda *shape, dtype=jnp.bfloat16: jax.ShapeDtypeStruct(
         shape, dtype, sharding=one_chip)
@@ -163,9 +164,10 @@ def test_kda_scan_compiles_for_v5e(one_chip, real_lowering, grad):
     args = (sds(1, 32, 8192, 128), sds(1, 32, 8192, 128), sds(1, 32, 8192, 128),
             sds(1, 32, 8192, 128, dtype=f32), sds(1, 32, 8192, dtype=f32))
     loss = lambda *a: jnp.sum(kda_mod.kda(*a))
-    with kda_mod.traced_plans() as plans:
+    with op_plans.traced() as got:
         text = _compile(jax.grad(loss, argnums=(0, 1, 2, 3, 4)) if grad else loss,
                         *args, kernels=[])
+    plans = got["kda"]
     assert "tpu_custom_call" not in text
     assert (plans[0].sub, plans[0].chunks) == (16, 128)
     assert plans[0].state_bytes_kept == 128 * 32 * 128 * 128 * 4
@@ -392,7 +394,7 @@ def test_dp_step_with_sharded_fused_ce_compiles_for_v5e_2x2(
     assert sum("saturn_ce_" in l for l in calls) == 3
     # what the grid point's ``trial_config`` event carries as ``ce_plan``: the
     # one fused call, traced on a shard of 8 x 512 / 4 tokens
-    (plan,) = bundle.ce_plans
+    (plan,) = bundle.plans["ce"]
     assert plan == ce_mod.ce_plan(1024, 768, 50257)
     assert plan.mode == "stash" and plan.dx_vmem_limit is None
 
