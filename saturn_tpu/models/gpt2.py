@@ -262,6 +262,25 @@ class GPT2Config:
     route_groups_kept: int = 0
     routed_buffer: Optional[float] = None
     swiglu_limit: float = 0.0
+    # Route-before-mixer structure knobs (SmallThinker-class: every layer
+    # routed, no dense layer and no shared expert, a rotary-less full layer
+    # to three rotated sliding ones). Each at its default leaves every
+    # earlier preset's program unchanged op for op.
+    #   route_from: the rows a routed layer's router reads. "ff_input": the
+    #     experts' own, the normed stream after the mixer. "block_input": the
+    #     block's input, ahead of ``ln_1`` and un-normed; the route
+    #     (``ops/moe.py::route``) is made before the mixer and handed across
+    #     it to the experts (``experts_under``), which read ``ln_2`` of the
+    #     stream after the mixer.
+    #   router_score: "sigmoid" (normalised over the chosen) or "softmax"
+    #     (over the chosen logits).
+    #   expert_act gains "reglu": ``relu(u W_g) * (u W_u)``.
+    #   rotary_kinds: the softmax layer kinds that rotate q and k where not
+    #     every one does (None: all of them, with ``rotary``); a kind left
+    #     out has no position signal but its causal mask.
+    route_from: str = "ff_input"
+    router_score: str = "sigmoid"
+    rotary_kinds: Optional[Tuple[str, ...]] = None
     name: str = "gpt2-small"
 
     def __post_init__(self) -> None:
@@ -393,11 +412,33 @@ class GPT2Config:
                 "clamp's form; the layers held have 0: ROADMAP.md, Reach)")
         if self.yarn is not None:
             object.__setattr__(self, "yarn", tuple(self.yarn))
+        if self.rotary_kinds is not None:
+            object.__setattr__(self, "rotary_kinds", tuple(self.rotary_kinds))
+            if not self.rotary or set(self.rotary_kinds) - {
+                    "full_attention", "sliding_attention"}:
+                raise ValueError(
+                    "rotary_kinds names the softmax kinds ('full_attention', "
+                    "'sliding_attention') that rotate under rotary=True, got "
+                    f"{self.rotary_kinds!r}")
         if self.routed_experts:
             held = self.experts_held
-            if self.expert_act not in ("swiglu", "relu2"):
-                raise ValueError(f"expert_act must be 'swiglu' or 'relu2', "
-                                 f"got {self.expert_act!r}")
+            if self.expert_act not in ("swiglu", "reglu", "relu2"):
+                raise ValueError(f"expert_act must be 'swiglu', 'reglu' or "
+                                 f"'relu2', got {self.expert_act!r}")
+            if self.expert_act == "reglu" and self.shared_ff:
+                raise ValueError("a ReGLU shared expert is not built (the one "
+                                 "ReGLU stack has no shared expert)")
+            if self.router_score not in ("sigmoid", "softmax"):
+                raise ValueError(f"router_score must be 'sigmoid' or 'softmax', "
+                                 f"got {self.router_score!r}")
+            if self.route_from not in ("ff_input", "block_input") or (
+                    self.route_from == "block_input" and (
+                        self.latent_dim or self.parallel_residual
+                        or set(self.layer_types or ()) & set(MIXER_KINDS))):
+                raise ValueError(
+                    "route_from is 'ff_input' or, in a sequential block of a "
+                    "mixer and a routed feed-forward with no latent, "
+                    f"'block_input': got {self.route_from!r}")
             if (self.layer_types is None or self.moe or self.seq_axis is not None
                     or (self.expert_act == "swiglu" and self.mlp_act != "swiglu")
                     or not 1 <= self.top_k <= self.routed_experts
@@ -655,6 +696,35 @@ PRESETS: Dict[str, Dict[str, Any]] = {
         expert_ff=32, shared_ff=32, routed_scale=2.5, router_bias=True,
         route_groups=4, route_groups_kept=2,
     ),
+    # SmallThinker (PowerInfer/SmallThinker-21BA3B-Instruct): 52 layers, every
+    # one the same but for its attention's kind: layer l with l % 4 == 0 full
+    # attention with q and k *not* rotated (NoPE), the other three of a period
+    # a 4096-key sliding window rotated over all 128 lanes at base 1.5e6; 28 q
+    # heads over 4 k/v heads of 128 (q 3584 wide). Every feed-forward is 64
+    # routed ReGLU experts of 768 (top-6, a softmax over the chosen logits),
+    # no shared expert and no dense layer; **the router reads the block's
+    # input, before the first norm and the attention**
+    # (``route_from="block_input"``). RMSNorm (eps 1e-6) before each branch,
+    # no bias, an untied head. 52 layers are 13 whole periods in the
+    # published order: full, sliding, sliding, sliding.
+    "smallthinker-21b": dict(
+        d_model=2560, n_layers=52, n_heads=28, n_kv_heads=4, head_width=128,
+        vocab_size=151936, rotary=True, rope_theta=1.5e6,
+        window_rope_theta=1.5e6, rotary_kinds=("sliding_attention",),
+        norm="rmsnorm", use_bias=False, tie_head=False,
+        layer_types=("full_attention",) + ("sliding_attention",) * 3,
+        window=4096, routed_experts=64, top_k=6, expert_ff=768,
+        expert_act="reglu", router_score="softmax", route_from="block_input",
+    ),
+    "smallthinker-test-tiny": dict(
+        d_model=64, n_layers=4, n_heads=14, n_kv_heads=2, head_width=16,
+        vocab_size=256, seq_len=64, rotary=True, rope_theta=1.5e6,
+        window_rope_theta=1.5e6, rotary_kinds=("sliding_attention",),
+        norm="rmsnorm", use_bias=False, tie_head=False,
+        layer_types=("full_attention",) + ("sliding_attention",) * 3,
+        window=32, routed_experts=16, held_experts=4, top_k=4, expert_ff=32,
+        expert_act="reglu", router_score="softmax", route_from="block_input",
+    ),
     # Switch-style MoE family (extension beyond the reference; SURVEY.md §2.3
     # lists EP as absent there).
     "moe-test-tiny": dict(
@@ -830,6 +900,10 @@ class Block(nn.Module):
             return nn.Dense(features, dtype=dt, param_dtype=pdt,
                             use_bias=cfg.use_bias, name=name)
 
+        # a router that reads the block's input makes its route here, ahead of
+        # the first norm; the experts take it up after the mixer
+        made = self._route(x) if (
+            self.ff == "routed" and cfg.route_from == "block_input") else None
         h = make_norm("ln_1")(x) if cfg.pre_norm else x
         if self.kind == "linear_attention":
             attn = self._linear_mixer(h, dense)
@@ -848,7 +922,7 @@ class Block(nn.Module):
             if cfg.moe:
                 return self._moe_mlp(inp)
             if self.ff == "routed":
-                return self._routed_mlp(inp, dense)
+                return self._routed_mlp(inp, dense, made)
             if cfg.mlp_act == "swiglu":
                 # Separate gate/up projections (NOT one fused 2F Dense): the
                 # TP column rule shards each kernel's output dim, so
@@ -909,7 +983,15 @@ class Block(nn.Module):
 
         q = heads(q, n_q)
         k, v = heads(k, kv_heads), heads(v, kv_heads)
-        if cfg.rotary:
+        if cfg.rotary_kinds is not None:
+            from saturn_tpu.ops import plans
+
+            kinds = [kind for kind in dict.fromkeys(cfg.layer_types or (self.kind,))
+                     if kind in ("full_attention", "sliding_attention")]
+            plans.record("rotary", {
+                "rotated": [kind for kind in kinds if kind in cfg.rotary_kinds],
+                "unrotated": [kind for kind in kinds if kind not in cfg.rotary_kinds]})
+        if cfg.rotary and (cfg.rotary_kinds is None or self.kind in cfg.rotary_kinds):
             if cfg.seq_axis is not None:
                 # Global positions for a sequence-sharded chunk.
                 offset = jax.lax.axis_index(cfg.seq_axis) * T
@@ -1141,42 +1223,81 @@ class Block(nn.Module):
         rule, shared with the factory path (:func:`resolve_attention`)."""
         return resolve_attention(self.cfg).attention
 
-    def _routed_mlp(self, inp, dense):
-        """A shared expert beside the held share of ``routed_experts`` routed
-        ones (``ops/moe.py::routed_experts``): every expert a SwiGLU of
-        ``expert_ff`` (``shared_ff``). The router scores all the experts in
+    def _routed_plan(self, tokens: int):
+        from saturn_tpu.ops.moe import routed_plan
+
+        cfg = self.cfg
+        return routed_plan(
+            tokens, cfg.routed_experts, cfg.experts_held, cfg.top_k,
+            impl="kernel" if self._attention_impl() == "flash" else "xla",
+            act=cfg.expert_act, latent=cfg.latent_dim, bias=cfg.router_bias,
+            buffer=cfg.routed_buffer, groups=cfg.route_groups,
+            groups_kept=cfg.route_groups_kept, score=cfg.router_score,
+            route_from=cfg.route_from)
+
+    def _router(self, D: int):
+        """(the router's matrix, its selection bias or None)."""
+        cfg = self.cfg
+        router = self.param("router", nn.initializers.normal(0.02),
+                            (D, cfg.routed_experts), cfg.param_dtype)
+        bias = self.param("router_bias", nn.initializers.zeros,
+                          (cfg.routed_experts,), cfg.param_dtype) \
+            if cfg.router_bias else None
+        return router, bias
+
+    def _route(self, x):
+        """The route (``ops/moe.py::route``) of the block's input ``x``
+        (B, T, D), un-normed: the first half of the routed layer, made ahead
+        of the mixer (``route_from="block_input"``)."""
+        from saturn_tpu.ops import plans
+        from saturn_tpu.ops.moe import route
+
+        cfg = self.cfg
+        B, T, D = x.shape
+        plan = self._routed_plan(B * T)
+        plans.record("moe", plan)
+        router, bias = self._router(D)
+        return route(x.reshape(B * T, D).astype(cfg.dtype), router, plan=plan,
+                     scale=cfg.routed_scale, bias=bias)
+
+    def _routed_mlp(self, inp, dense, made=None):
+        """A shared expert (``shared_ff``; 0: none) beside the held share of
+        ``routed_experts`` routed ones (``ops/moe.py::routed_experts``):
+        every expert a SwiGLU, ReGLU or relu2 of ``expert_ff`` (``shared_ff``).
+        The router scores all the experts in
         float32 and keeps its ``top_k`` a token; the tables hold
         ``held_experts`` experts (a leading expert axis: dim 1 under the
         layer scan) in ``param_dtype`` and are rounded to ``dtype`` inside
         the op. The kernels run where the attention implementation is
         "flash", the plain twin where it is "dense". The layer's counters go
-        to the ``moe_stats`` collection."""
-        from saturn_tpu.ops.moe import routed_experts, routed_plan
+        to the ``moe_stats`` collection. ``made``: the route where the block
+        made it ahead of its mixer (``_route``); the experts here run under
+        it (``ops/moe.py::experts_under``)."""
+        from saturn_tpu.ops.moe import experts_under, routed_experts
 
         cfg = self.cfg
         B, T, D = inp.shape
-        E, held, F = cfg.routed_experts, cfg.experts_held, cfg.expert_ff
+        held, F = cfg.experts_held, cfg.expert_ff
         L = cfg.latent_dim or D             # the width the experts read and write
-        gated = cfg.expert_act == "swiglu"
+        gated = cfg.expert_act != "relu2"
         pdt = cfg.param_dtype
         init = nn.initializers.normal(0.02)
-        router = self.param("router", init, (D, E), pdt)
-        bias = self.param("router_bias", nn.initializers.zeros, (E,), pdt) \
-            if cfg.router_bias else None
+        if made is None:
+            router, bias = self._router(D)
         w_gate = self.param("we_gate", init, (held, L, F), pdt) if gated else None
         w_up = self.param("we_up", init, (held, L, F), pdt)
         w_down = self.param("we_down", init, (held, F, L), pdt)
-        plan = routed_plan(
-            B * T, E, held, cfg.top_k,
-            impl="kernel" if self._attention_impl() == "flash" else "xla",
-            act=cfg.expert_act, latent=cfg.latent_dim, bias=cfg.router_bias,
-            buffer=cfg.routed_buffer, groups=cfg.route_groups,
-            groups_kept=cfg.route_groups_kept)
+        plan = self._routed_plan(B * T)
         latent = dense(L, "latent_down")(inp).reshape(B * T, L) \
             if cfg.latent_dim else None
-        y, stats = routed_experts(
-            inp.reshape(B * T, D), router, w_gate, w_up, w_down, plan=plan,
-            scale=cfg.routed_scale, dtype=cfg.dtype, bias=bias, latent=latent)
+        if made is None:
+            y, stats = routed_experts(
+                inp.reshape(B * T, D), router, w_gate, w_up, w_down, plan=plan,
+                scale=cfg.routed_scale, dtype=cfg.dtype, bias=bias, latent=latent)
+        else:
+            y, stats = experts_under(
+                made, inp.reshape(B * T, D).astype(cfg.dtype), w_gate, w_up,
+                w_down, plan=plan, dtype=cfg.dtype)
         for name, value in stats.items():
             self.sow("moe_stats", name, value)
         y = y.reshape(B, T, L)
@@ -1644,8 +1765,11 @@ def build_gpt2(
         # factory accepts seq_axis/seq_axis_size; the sharded attention +
         # boundary-label loss assume causal next-token training. A linear
         # layer's state crosses the whole sequence: not sequence-parallel.
-        "seq_parallel": cfg.causal and not (
-            {"linear_attention", "mamba2", "kda"} & set(cfg.layer_types or ())),
+        # Nor is a sliding layer's mask or a routed layer (the configuration
+        # refuses them a sequence axis).
+        "seq_parallel": cfg.causal and not cfg.routed_experts and not (
+            {"linear_attention", "mamba2", "kda", "sliding_attention"}
+            & set(cfg.layer_types or ())),
         "pipeline": {
             "embed": pipeline_embed,
             "block": pipeline_block,
@@ -1737,6 +1861,19 @@ def build_ling(name: str = "ling3-flash", **overrides) -> ModelSpec:
     clamp) is refused. Same ``ModelSpec`` contract as :func:`build_gpt2`: the
     scanned unit, and ``hints["pipeline"]``'s ``block``, is one period; its
     ``embed`` runs the leading layers."""
+    return build_gpt2(name, **overrides)
+
+
+def build_smallthinker(name: str = "smallthinker-21b", **overrides) -> ModelSpec:
+    """SmallThinker factory: periods of one rotary-less full-attention layer
+    and three rotated sliding-window layers over grouped k/v heads, every
+    feed-forward the held share of top-k routed ReGLU experts under a softmax
+    over the chosen logits, with no shared expert and no dense layer; a
+    layer's router reads the block's un-normed input, so its route
+    (``ops/moe.py::route``) is made ahead of the mixer and the experts run
+    under it after (``experts_under``). Same ``ModelSpec`` contract as
+    :func:`build_gpt2`: the scanned unit, and ``hints["pipeline"]``'s
+    ``block``, is one period (a route never leaves its block)."""
     return build_gpt2(name, **overrides)
 
 

@@ -119,13 +119,19 @@ class RoutedPlan:
     row_tile: int
     rows: int        # the row buffer
     worst_rows: int  # the buffer no step can overflow
-    act: str = "swiglu"   # an expert's kind: "swiglu" | "relu2" (no gate)
+    act: str = "swiglu"   # an expert's kind: "swiglu" | "reglu" (relu for the
+    #                       gate's silu) | "relu2" (no gate)
     latent: int = 0       # the width the experts read and write, where it
     #                       is not the stream's (0: the stream's)
     bias: bool = False    # a selection bias is added to the scores, for the choice
     groups: int = 0       # the experts lie in this many groups of consecutive
     #                       ones (0: no group limit) and a token chooses among
     groups_kept: int = 0  # the experts of its ``groups_kept`` best groups
+    score: str = "sigmoid"        # "sigmoid": w_e = scale s_e / sum of the chosen
+    #                               s; "softmax": a softmax over the chosen logits
+    route_from: str = "ff_input"  # the rows the router reads: the experts' own
+    #                               ("ff_input") or the block's input, ahead of
+    #                               its mixer and un-normed ("block_input")
 
     @property
     def second_path(self) -> bool:
@@ -145,6 +151,8 @@ def _round_up(n: int, to: int) -> int:
 BUFFER = 2.0
 #: the name ``routed_layout``'s tables carry for a checkpoint policy
 LAYOUT_NAME = "saturn_moe_layout"
+#: an expert's kinds: a gated pair under silu or relu, or relu(x W_up)^2 alone
+ACTS = ("swiglu", "reglu", "relu2")
 #: rows of one tile of the grouped product (every expert's rows are padded to
 #: whole tiles): the MXU's 128, or 8 where an expert's mean rows are fewer
 ROW_TILE = 128
@@ -154,15 +162,21 @@ def routed_plan(tokens: int, experts: int, held: int, top_k: int, *,
                 buffer: Optional[float] = None, row_tile: Optional[int] = None,
                 impl: str = "xla", act: str = "swiglu", latent: int = 0,
                 bias: bool = False, groups: int = 0,
-                groups_kept: int = 0) -> RoutedPlan:
+                groups_kept: int = 0, score: str = "sigmoid",
+                route_from: str = "ff_input") -> RoutedPlan:
     """``buffer`` (``BUFFER``) x the mean held pairs (tokens x top_k x held /
     experts), plus a tile an expert for the padding, capped at the worst case
     (every token's every choice held). ``groups`` / ``groups_kept``: the
     group limit of the choice (``limited_choice``)."""
     if impl not in ("kernel", "xla"):
         raise ValueError(f"impl must be 'kernel' or 'xla', got {impl!r}")
-    if act not in ("swiglu", "relu2"):
-        raise ValueError(f"act must be 'swiglu' or 'relu2', got {act!r}")
+    if act not in ACTS:
+        raise ValueError(f"act must be one of {ACTS}, got {act!r}")
+    if score not in ("sigmoid", "softmax"):
+        raise ValueError(f"score must be 'sigmoid' or 'softmax', got {score!r}")
+    if route_from not in ("ff_input", "block_input"):
+        raise ValueError(
+            f"route_from must be 'ff_input' or 'block_input', got {route_from!r}")
     if groups and (experts % groups or not 1 <= groups_kept <= groups
                    or top_k > groups_kept * (experts // groups)
                    or experts // groups < 2):
@@ -178,7 +192,8 @@ def routed_plan(tokens: int, experts: int, held: int, top_k: int, *,
     mean = tokens * top_k * held / experts
     rows = min(worst, _round_up(int(math.ceil(buffer * mean)), row_tile) + pad)
     return RoutedPlan(impl, tokens, experts, held, top_k, row_tile, rows, worst,
-                      act, latent, bias, groups, groups_kept if groups else 0)
+                      act, latent, bias, groups, groups_kept if groups else 0,
+                      score, route_from)
 
 
 def limited_choice(choice, groups: int, groups_kept: int):
@@ -419,24 +434,32 @@ def _relu2(u):
     return jnp.square(jax.nn.relu(u))
 
 
+def _gate_act(act: str):
+    """What the gate's product goes through before it multiplies ``up``."""
+    return jax.nn.relu if act == "reglu" else jax.nn.silu
+
+
 def _expert_rows(x, w_gate, w_up, w_down, layout, plan):
-    """The row buffer through its experts: a SwiGLU (three grouped products)
-    or, with no gate, ``relu(x W_up)^2 W_down`` (two)."""
+    """The row buffer through its experts: a gated pair (three grouped
+    products; ``plan.act`` says silu or relu) or, with no gate,
+    ``relu(x W_up)^2 W_down`` (two)."""
     u = grouped_matmul(x, w_up, layout, plan).astype(jnp.float32)
     if w_gate is None:
         a = _relu2(u)
     else:
-        a = jax.nn.silu(grouped_matmul(x, w_gate, layout, plan).astype(jnp.float32)) * u
+        a = _gate_act(plan.act)(
+            grouped_matmul(x, w_gate, layout, plan).astype(jnp.float32)) * u
     return grouped_matmul(a.astype(x.dtype), w_down, layout, plan)
 
 
-def _masked_experts(y, weights, local, w_gate, w_up, w_down):
+def _masked_experts(y, weights, local, w_gate, w_up, w_down, act):
     """The exact second path: every held expert over every token, its output
     weighted by the token's weight for it (0 where it was not chosen). One
     expert at a time, each rematerialised in the backward: held x the dense
     work, and no buffer to overflow."""
     held = w_up.shape[0]
     f32 = jnp.float32
+    gate_act = _gate_act(act)
 
     @jax.checkpoint
     def one(y, wg, wu, wd, m):
@@ -445,7 +468,7 @@ def _masked_experts(y, weights, local, w_gate, w_up, w_down):
             a = _relu2(u).astype(y.dtype)
         else:
             h = jnp.dot(y, wg.astype(y.dtype), preferred_element_type=f32)
-            a = (jax.nn.silu(h) * u).astype(y.dtype)
+            a = (gate_act(h) * u).astype(y.dtype)
         return jnp.dot(a, wd.astype(y.dtype), preferred_element_type=f32) * m[:, None]
 
     def step(acc, xs):
@@ -458,46 +481,40 @@ def _masked_experts(y, weights, local, w_gate, w_up, w_down):
     return acc
 
 
-def routed_experts(y, router, w_gate, w_up, w_down, *, plan: RoutedPlan,
-                   first_expert: int = 0, scale: float = 1.0,
-                   dtype: Any = jnp.bfloat16, bias=None, latent=None):
-    """The held experts' part of a top-k routed layer.
+# A routed layer is two halves with one seam. The **route** reads the router's
+# rows and makes everything that is integer or a weight: scores -> the choice
+# (kept under ``LAYOUT_NAME``) -> the weights -> the row buffer's tables. The
+# **experts under a route** take the rows the experts read through the held
+# tables. ``routed_experts`` is the two in a row on one row set; a block whose
+# router reads its input ahead of the mixer (``models/gpt2.py``,
+# ``route_from="block_input"``) makes the route first and hands it across.
+def route(y, router, *, plan: RoutedPlan, first_expert: int = 0,
+          scale: float = 1.0, bias=None) -> Dict[str, Any]:
+    """The route of rows ``y`` (T, D) under ``router`` (D, experts):
 
-    ``y`` (T, D); ``router`` (D, experts); ``w_gate`` / ``w_up`` (held, D, F)
-    and ``w_down`` (held, F, D): experts ``first_expert .. + held``.
-
-        s = sigmoid(y router)                  float32, all the experts
+        z = y router                            float32, all the experts
         I = the top_k largest of s (+ bias), among the experts of the token's
             best groups under a group limit (``plan.groups``: ``limited_choice``)
-        w_e = scale * s_e / sum_{e' in I} s_e'
-        out = sum_{e in I, e held} w_e (silu(u Wg_e) * (u Wu_e)) Wd_e,   u = y
+        "sigmoid": s = sigmoid(z);  w_e = scale * s_e / sum_{e' in I} s_e'
+        "softmax": s = softmax(z);  w_e = scale * exp(z_e) / sum_{e' in I} exp(z_e')
+                   (the softmax over all the experts, its top_k renormalised,
+                   is the softmax over the chosen logits)
 
-    ``w_gate`` None (``plan.act`` "relu2"): an expert is ``relu(u Wu_e)^2
-    Wd_e``, two products. ``bias`` (experts,): a selection bias, added to the
-    scores for the choice only (the weights come from ``s``; its gradient is
-    exactly zero). ``latent`` (T, L): the rows ``u`` the experts read where
-    they are not ``y`` (a projection of it; the router still reads ``y``); the
-    tables are then (held, L, F) / (held, F, L) and the result (T, L).
-
-    Every pair whose expert is held is computed: through the row buffer where
-    the step's padded rows fit it, through ``_masked_experts`` where they do
-    not. Returns (out (T, D) in ``dtype``, counters: ``pairs_held``,
-    ``rows_max`` (the fullest held expert), ``second_path`` (0 / 1) as int32
-    scalars, and ``chosen`` (T, top_k), the experts the router chose)."""
+    ``bias`` (experts,): a selection bias, added to the scores for the choice
+    only (its gradient is exactly zero). Returns ``weights`` (T, k) float32,
+    ``local`` (T, k) (a pair's held expert, ``held`` where it is not here),
+    ``layout`` (``routed_layout``) and ``chosen`` (T, k), the experts the
+    router chose."""
     f32 = jnp.float32
-    T, D = y.shape
     held = plan.held
-    if (plan.tokens, plan.experts, plan.held, plan.act == "relu2", plan.bias,
-            plan.latent) != (T, router.shape[1], w_up.shape[0], w_gate is None,
-                             bias is not None, 0 if latent is None else latent.shape[1]):
-        raise ValueError(f"plan {plan} is not for {T} tokens, {router.shape[1]} "
-                         f"experts, {w_up.shape[0]} held, gate {w_gate is not None}, "
-                         f"bias {bias is not None}, latent rows "
-                         f"{None if latent is None else latent.shape}")
-    plans.record("moe", plan)
-    y = y.astype(dtype)
-    scores = jax.nn.sigmoid(jnp.dot(y.astype(f32), router.astype(f32),
-                                    precision=jax.lax.Precision.HIGHEST))
+    if (plan.tokens, plan.experts, plan.bias) != (
+            y.shape[0], router.shape[1], bias is not None):
+        raise ValueError(f"plan {plan} is not for {y.shape[0]} tokens, "
+                         f"{router.shape[1]} experts, bias {bias is not None}")
+    logits = jnp.dot(y.astype(f32), router.astype(f32),
+                     precision=jax.lax.Precision.HIGHEST)
+    soft = plan.score == "softmax"
+    scores = jax.nn.softmax(logits, axis=-1) if soft else jax.nn.sigmoid(logits)
     # the choice is kept with the tables it makes (``LAYOUT_NAME``), and the
     # chosen scores are read at it: a rematerialised layer's backward would
     # otherwise choose again from scores recomputed to another last bit, and
@@ -509,11 +526,35 @@ def routed_experts(y, router, w_gate, w_up, w_down, *, plan: RoutedPlan,
     if plan.groups:
         choice = limited_choice(choice, plan.groups, plan.groups_kept)
     chosen = checkpoint_name(jax.lax.top_k(choice, plan.top_k)[1], LAYOUT_NAME)
-    top = jnp.take_along_axis(scores, chosen, axis=-1)
-    weights = scale * top / jnp.sum(top, axis=-1, keepdims=True)      # (T, k)
+    if soft:    # over the chosen logits themselves: no quotient of small shares
+        weights = scale * jax.nn.softmax(
+            jnp.take_along_axis(logits, chosen, axis=-1), axis=-1)
+    else:
+        top = jnp.take_along_axis(scores, chosen, axis=-1)
+        weights = scale * top / jnp.sum(top, axis=-1, keepdims=True)   # (T, k)
     local = chosen.astype(jnp.int32) - first_expert
     local = jnp.where((local >= 0) & (local < held), local, held)
-    layout = routed_layout(local, plan)
+    return {"weights": weights, "local": local, "chosen": chosen,
+            "layout": routed_layout(local, plan)}
+
+
+def experts_under(made: Dict[str, Any], u, w_gate, w_up, w_down, *,
+                  plan: RoutedPlan, dtype: Any = jnp.bfloat16):
+    """The held experts over rows ``u`` (T, L) under the route ``made``
+    (:func:`route`), each pair times its weight:
+
+        out = sum_{e in I, e held} w_e (act(u Wg_e) * (u Wu_e)) Wd_e
+
+    Every pair whose expert is held is computed: through the row buffer where
+    the step's padded rows fit it, through ``_masked_experts`` where they do
+    not. Returns (out (T, L) in ``dtype``, counters: ``pairs_held``,
+    ``rows_max`` (the fullest held expert), ``second_path`` (0 / 1) as int32
+    scalars, and ``chosen`` (T, top_k))."""
+    f32 = jnp.float32
+    layout, local = made["layout"], made["local"]
+    if (plan.held, plan.act == "relu2") != (w_up.shape[0], w_gate is None):
+        raise ValueError(f"plan {plan} is not for {w_up.shape[0]} held experts, "
+                         f"gate {w_gate is not None}")
 
     def through_rows(u, weights, w_gate, w_up, w_down):
         tok, valid, pos = layout["tok"], layout["valid"], layout["pos"]
@@ -524,10 +565,9 @@ def routed_experts(y, router, w_gate, w_up, w_down, *, plan: RoutedPlan,
         return _from_rows(o, tok, valid, pos)
 
     def through_mask(u, weights, w_gate, w_up, w_down):
-        return _masked_experts(u, weights, local, w_gate, w_up, w_down)
+        return _masked_experts(u, weights, local, w_gate, w_up, w_down, plan.act)
 
-    operands = (y if latent is None else latent.astype(dtype), weights,
-                w_gate, w_up, w_down)
+    operands = (u, made["weights"], w_gate, w_up, w_down)
     if plan.second_path:
         out = jax.lax.cond(layout["overflow"], through_mask, through_rows, *operands)
         second = layout["overflow"].astype(jnp.int32)
@@ -535,5 +575,32 @@ def routed_experts(y, router, w_gate, w_up, w_down, *, plan: RoutedPlan,
         out, second = through_rows(*operands), jnp.zeros((), jnp.int32)
     stats = {"pairs_held": jnp.sum(layout["counts"]),
              "rows_max": jnp.max(layout["counts"]),
-             "second_path": second, "chosen": chosen}
+             "second_path": second, "chosen": made["chosen"]}
     return out.astype(dtype), stats
+
+
+def routed_experts(y, router, w_gate, w_up, w_down, *, plan: RoutedPlan,
+                   first_expert: int = 0, scale: float = 1.0,
+                   dtype: Any = jnp.bfloat16, bias=None, latent=None):
+    """The held experts' part of a top-k routed layer whose router reads the
+    rows its experts read: :func:`route` of ``y``, then :func:`experts_under`
+    it on ``y``.
+
+    ``y`` (T, D); ``router`` (D, experts); ``w_gate`` / ``w_up`` (held, D, F)
+    and ``w_down`` (held, F, D): experts ``first_expert .. + held``.
+    ``w_gate`` None (``plan.act`` "relu2"): an expert is ``relu(u Wu_e)^2
+    Wd_e``, two products. ``latent`` (T, L): the rows ``u`` the experts read
+    where they are not ``y`` (a projection of it; the router still reads
+    ``y``); the tables are then (held, L, F) / (held, F, L) and the result
+    (T, L). Returns what :func:`experts_under` does."""
+    if (plan.latent, plan.route_from) != (
+            0 if latent is None else latent.shape[1], "ff_input"):
+        raise ValueError(f"plan {plan} is not for latent rows "
+                         f"{None if latent is None else latent.shape} under a "
+                         "router that reads the experts' own rows")
+    plans.record("moe", plan)
+    y = y.astype(dtype)
+    made = route(y, router, plan=plan, first_expert=first_expert, scale=scale,
+                 bias=bias)
+    return experts_under(made, y if latent is None else latent.astype(dtype),
+                         w_gate, w_up, w_down, plan=plan, dtype=dtype)

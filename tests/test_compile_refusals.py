@@ -603,6 +603,32 @@ def test_a_ling_task_is_refused_with_a_reason_on_the_trial_config_span(
     ling_tests.refused(tech, task, devices, configs, tmp_path, reason)
 
 
+@pytest.mark.parametrize("name, reason", [
+    ("pp", "several block kinds"), ("ep", "exchange of tokens"),
+    ("ring", None), ("ulysses", None)])
+def test_a_smallthinker_task_is_refused_with_a_reason_on_the_trial_config_span(
+        name, reason, tmp_path, devices8):
+    """(PR 49) ``pp`` stages a stack of one kind and ``ep`` would need the
+    exchange of token rows between shares: every grid point ends as an
+    infeasible ``trial.config`` span that says so, none fails inside a trace.
+    A sliding layer's mask and a routed layer are single-program, so the
+    model says it is not sequence-parallel and ``ring`` / ``ulysses`` offer no
+    grid point at all. (A technique whose block walk had to carry a route
+    across a mixer would refuse here too: none has to, the walk's unit is the
+    period and a route never leaves its block; fsdp / tp overlap and
+    offload's stream run it against the reference in
+    ``tests/test_smallthinker_techniques.py``.)"""
+    from saturn_tpu.parallel import BUILTIN_TECHNIQUES
+    from tests import test_smallthinker_techniques as st_tests
+
+    tech, devices = BUILTIN_TECHNIQUES[name](), list(devices8[:4])
+    task = st_tests._task(tmp_path, f"smallthinker-refused-{name}", batch=4)
+    configs = tech.candidate_configs(task, len(devices))
+    if reason is None:
+        return st_tests.offers_nothing(tech, task, devices, configs, tmp_path)
+    st_tests.refused(tech, task, devices, configs, tmp_path, reason)
+
+
 def test_a_non_zero_swiglu_limit_refuses_at_build():
     from saturn_tpu.models.gpt2 import build_ling
 

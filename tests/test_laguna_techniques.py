@@ -102,6 +102,7 @@ def test_dp_through_search_and_orchestrate_reproduces_the_reference(
                     "row_tile": 8, "rows": 512 + 32, "worst_rows": 512 + 32,
                     "act": "swiglu", "latent": 0, "bias": False,   # (PR 42's fields)
                     "groups": 0, "groups_kept": 0,                 # (PR 45's)
+                    "score": "sigmoid", "route_from": "ff_input",  # (PR 49's)
                     "second_path": False}
     assert "window_plan" not in configs[0]            # the masked einsum has no blocks
 
@@ -178,9 +179,11 @@ def test_every_technique_runs_the_stack_or_refuses_with_a_reason(
         return _refused(tech, task, devices, configs, tmp_path, "several block kinds")
     if name in ("ring", "ulysses"):
         # a sliding layer's mask and a routed layer are single-program: the
-        # configuration refuses a sequence axis, where the model is built
+        # model says it is not sequence-parallel and no grid point is offered
+        # (the configuration refuses a sequence axis where a model is built)
+        assert not configs and task.get_model().hints["seq_parallel"] is False
         with pytest.raises(ValueError, match="single-program"):
-            tech.build(task, devices, configs[0], use_cache=False)
+            build_laguna("laguna-test-tiny", seq_axis="seq", seq_axis_size=2)
         return
     for config in _picks(configs):
         with jax.default_matmul_precision("highest"):

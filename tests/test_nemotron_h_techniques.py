@@ -97,7 +97,8 @@ def test_dp_through_search_and_orchestrate_reproduces_the_reference(
     assert plan == {"impl": "xla", "tokens": 2 * SEQ, "experts": 12, "held": 4, "top_k": 3,
                     "row_tile": 8, "rows": 384 + 32, "worst_rows": 384 + 32,
                     "act": "relu2", "latent": 32, "bias": True, "groups": 0,
-                    "groups_kept": 0, "second_path": False}
+                    "groups_kept": 0, "score": "sigmoid", "route_from": "ff_input",
+                    "second_path": False}
     ssd_plan = configs[0]["ssd_plan"]
     assert (ssd_plan["impl"], ssd_plan["chunk"], ssd_plan["heads"], ssd_plan["groups"],
             ssd_plan["heads_published"], ssd_plan["groups_published"]) == ("xla", 16, 4, 2, 16, 8)

@@ -327,9 +327,16 @@ class TestOverlapPricing:
                                                    devices8):
         """Cold-start strategies carry the static decomposition the
         calibrator needs once a measurement supersedes them."""
+        from saturn_tpu import library
         from saturn_tpu.analysis.shardflow import prior
         from saturn_tpu.core.mesh import SliceTopology
 
+        # (the prior resolves "fsdp" through the library: do not count on an
+        # earlier file of this worker having left it registered; with PR 49's
+        # two new files the driver's command schedules this one behind a file
+        # that hands the registry back as it found it, empty: 1466 passed and
+        # this one failed, exit 1, in a whole run without this line)
+        library.register_default_library()
         topo = SliceTopology(devices8)
         added = prior.synthesize_strategies(
             tiny_task, topo, technique_names=["fsdp"])

@@ -27,6 +27,14 @@ _TEXT_BEFORE_LING = {
     ("gptj-test-tiny", False): "7f583ada3586f5d7",
     ("gptj-test-tiny", True): "5ed40e5058a5f3dd",
 }
+#: the Ling stack's own tiny preset, taken on the commit before
+#: ``ops/moe.py::routed_experts`` became two halves (PR 49; 2029792): the third
+#: caller of the one-call form, whose text must not move either
+_TEXT_BEFORE_THE_ROUTE_WAS_SPLIT = {
+    ("ling-test-tiny", False): "f8253b6a1828715b",
+    ("ling-test-tiny", True): "93a93dbfaf52f2f5",
+}
+_PINNED = {**_TEXT_BEFORE_LING, **_TEXT_BEFORE_THE_ROUTE_WAS_SPLIT}
 
 
 def _step_text(preset, remat):
@@ -41,12 +49,12 @@ def _step_text(preset, remat):
     return re.sub(r"0x[0-9a-f]+", "0x", str(jax.make_jaxpr(jax.grad(loss))(params, tokens)))
 
 
-@pytest.mark.parametrize("preset,remat", list(_TEXT_BEFORE_LING))
+@pytest.mark.parametrize("preset,remat", list(_PINNED))
 def test_a_step_program_traces_to_the_text_it_had_before_the_ling_stack(
         preset, remat, monkeypatch):
     for mod in (ce, flash, gdn, ssd):
         monkeypatch.setattr(mod, "_use_interpret", lambda: False)
     monkeypatch.setattr(moe, "_interpret", lambda: False)
     text = _step_text(preset, remat)
-    assert "saturn_mla_" not in text
-    assert hashlib.sha256(text.encode()).hexdigest()[:16] == _TEXT_BEFORE_LING[preset, remat]
+    assert ("saturn_mla_" in text) == (preset == "ling-test-tiny")
+    assert hashlib.sha256(text.encode()).hexdigest()[:16] == _PINNED[preset, remat]

@@ -106,6 +106,48 @@ def test_window_and_grouped_flash_compile_for_v5e(one_chip, real_lowering, heads
     assert ("saturn_flash_" in text) == (window is None)
 
 
+@pytest.mark.parametrize("window", [4096, None], ids=["window-of-half", "full"])
+def test_flash_at_seven_q_heads_a_kv_head_compiles_for_v5e(one_chip, real_lowering, window):
+    """The SmallThinker cell's attention at its own shapes: 28 q heads over 4
+    k/v heads of 128 (7 a group: the dkv kernel walks a group's members), seq
+    8192 x batch 4; the window kernels at a window of half the sequence (the
+    ``saturn_swa_*`` grids walk eight times the key blocks Laguna's 512
+    reach), the full layer's under ``saturn_flash_*``."""
+    q = jax.ShapeDtypeStruct((4, 28, 8192, 128), jnp.bfloat16, sharding=one_chip)
+    kv = jax.ShapeDtypeStruct((4, 4, 8192, 128), jnp.bfloat16, sharding=one_chip)
+
+    def loss(q, k, v):
+        return jnp.sum(flash_mod.flash_attention(q, k, v, window=window).astype(jnp.float32))
+
+    family = "saturn_swa" if window else "saturn_flash"
+    text = _compile(jax.grad(loss, argnums=(0, 1, 2)), q, kv, kv,
+                    kernels=[f"{family}_fwd", f"{family}_dq", f"{family}_dkv"])
+    assert ("saturn_flash_" in text) == (window is None)
+
+
+def test_the_two_halves_of_a_reglu_routed_layer_compile_for_v5e(one_chip, real_lowering):
+    """``saturn_gmm_*`` at the SmallThinker cell's shape, through the route
+    and the experts under it: 32768 tokens, softmax top-6 of 64 experts, 16
+    held ReGLU tables of 2560 x 768 (Ling's table shape at 24 times its
+    rows), a row buffer of 1.5 x the mean pairs behind the exact second
+    path, the router reading other rows than the experts."""
+    sds = lambda *shape, dtype=jnp.float32: jax.ShapeDtypeStruct(   # noqa: E731
+        shape, dtype, sharding=one_chip)
+    plan = moe_mod.routed_plan(32768, 64, 16, 6, buffer=1.5, impl="kernel", act="reglu",
+                               score="softmax", route_from="block_input")
+    assert (plan.rows, plan.row_tile, plan.second_path) == (73728 + 2048, 128, True)
+
+    def loss(x, u, router, w_gate, w_up, w_down):
+        made = moe_mod.route(x, router, plan=plan)
+        out, _ = moe_mod.experts_under(made, u, w_gate, w_up, w_down, plan=plan)
+        return jnp.sum(out.astype(jnp.float32))
+
+    _compile(jax.grad(loss, argnums=(0, 1, 2, 3, 4, 5)),
+             sds(32768, 2560, dtype=jnp.bfloat16), sds(32768, 2560, dtype=jnp.bfloat16),
+             sds(2560, 64), sds(16, 2560, 768), sds(16, 2560, 768), sds(16, 768, 2560),
+             kernels=["saturn_gmm_fwd", "saturn_gmm_dx", "saturn_gmm_dw"])
+
+
 def test_routed_layer_kernels_compile_for_v5e(one_chip, real_lowering):
     """``saturn_gmm_fwd`` / ``_dx`` / ``_dw`` at the Laguna cell's shape:
     16384 tokens, top-8 of 256 experts, 32 held, d 2048, experts of 512, a
@@ -304,6 +346,10 @@ CE_SHAPES = {
     # = 154 x 128 = 38.5 blocks of 512): the Ling cell's head, a width no
     # other cell runs (PR 45)
     "ling-8k": (8192, 2560, 19712),
+    # the SmallThinker cell's head: d 2560 again, 19072 rows = 149 x 128 (no
+    # multiple of any larger block: padded under the mask to the blocks'
+    # common multiple) at seq 8192 x batch 4 (PR 49)
+    "smallthinker-8k-b4": (32768, 2560, 19072),
 }
 
 
