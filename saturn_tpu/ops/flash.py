@@ -15,8 +15,8 @@ window): the benchmark's roofline reader credits every call of the first
 three names with a whole causal attention.
 
 What a kernel does follows from what the call can see: T, the head dim, the
-window (PR 41; ``flash_plan`` has the blocks and why, PERF.md section 6 the
-chip's readings):
+window (PR 41; ``flash_plan`` and ``window_plan`` have the blocks and why,
+PERF.md section 6 the chip's readings):
 
 - **The walk over the other sequence is a loop in the kernel.** A grid step
   holds one block of the side that stays (queries for fwd and dq, keys for
@@ -25,8 +25,12 @@ chip's readings):
   fetched once a row block and not once a score block; one
   ``lax.fori_loop`` walks the chunk's blocks (``_for_blocks``). The state
   (m, l, acc; dq; dk, dv) lives in VMEM scratch across the loop and the
-  chunks. A window's kernels keep a chunk of one block: what a step fetches
-  is what the window reaches.
+  chunks. Under a window (``window_plan``, PR 50) fwd and dq run this same
+  walk over the span of the chunk the window reaches; dkv, whose walked
+  operands are another head's at every grid step, does so from a window of
+  four walked blocks on (SmallThinker's 4096 keys) and under that (Laguna's
+  512) keeps a chunk of one block, the reached blocks a grid axis: what a
+  step fetches is then what the window reaches.
 - **Which blocks** (``_reach``): a block wholly above the diagonal (or
   outside the window) is in no loop; every other runs the one loop body,
   mask and all. The mask is one iota pair that does not depend on the block
@@ -614,28 +618,6 @@ def _flash_bh_bwd(blocks, causal, h, kv, window, res, do):
 _flash_bh.defvjp(_flash_bh_fwd, _flash_bh_bwd)
 
 
-def window_plan(T: int, window: int, block: Optional[int] = None) -> dict:
-    """The window kernels' walk at sequence ``T``: the (equal) block, the
-    key blocks a query block visits and how many of a causal walk's it
-    skips a call (mean over query blocks)."""
-    b = block or _window_block(T)
-    n = T // b
-    n_w = min(-(-(window - 1) // b) + 1, n)
-    return {"window": window, "block": b, "blocks_visited": n_w,
-            "blocks_skipped_per_call": n * (n + 1) // 2 - sum(
-                min(i + 1, n_w) for i in range(n))}
-
-
-def _window_block(T: int) -> int:
-    """256 where it divides T: at a window of 512 a query block then visits
-    3 key blocks (768 keys for the 512 it needs); 512 would visit 2 (1024),
-    128 five (640) in products a quarter the size."""
-    for b in (256, 128):
-        if T % b == 0:
-            return b
-    return min(128, T)
-
-
 def _largest_block(T: int, most: int) -> int:
     """The largest of ``most``, ``most / 2``, .. 128 that divides T; a T no
     such block divides is one block (or 128s where those divide it)."""
@@ -668,25 +650,27 @@ def _chunk(T: int, D: int, block: int) -> int:
 
 
 def _walk(T: int, block_q: int, block_k: int, chunk: int,
-          rows_are_queries: bool) -> dict:
+          rows_are_queries: bool, window: Optional[int] = None) -> dict:
     """Of the (T / block_q) x (T / block_k) score blocks of a causal head:
-    how many the kernel visits (the rest lie above the diagonal and are in
-    no loop) and how many of those the diagonal crosses: the blocks whose
-    mask changes a score (the one loop body applies it to every visited
-    block: a second, unmasked body read no faster on the chip, PERF.md
-    section 6, PR 41)."""
+    how many the kernel visits (the rest lie above the diagonal, or beyond
+    the ``window``, and are in no loop) and how many of those the diagonal
+    (or the window's far edge) crosses: the blocks whose mask changes a
+    score (the one loop body applies it to every visited block: a second,
+    unmasked body read no faster on the chip, PERF.md section 6, PR 41)."""
     b_row, b_col = (block_q, block_k) if rows_are_queries else (block_k, block_q)
+    reach = window if window is not None else T
     visited = masked = 0
     for r in range(T // b_row):
-        n_lo, n_hi = _reach(r, b_row, b_col, T // b_col, True, None,
+        n_lo, n_hi = _reach(r, b_row, b_col, T // b_col, True, window,
                             rows_are_queries)
         lo, hi = r * b_row, (r + 1) * b_row - 1
         visited += n_hi - n_lo
-        # under the diagonal as a whole: the block's last key at or before
-        # its first query
+        # kept as a whole: the block's last key at or before its first
+        # query, and its first key within reach of its last query
         masked += sum(
-            not (c * b_col + b_col - 1 <= lo if rows_are_queries
-                 else c * b_col >= hi)
+            not (c * b_col + b_col - 1 <= lo and c * b_col > hi - reach
+                 if rows_are_queries
+                 else c * b_col >= hi and c * b_col + b_col - 1 < lo + reach)
             for c in range(n_lo, n_hi))
     return {"block_q": block_q, "block_k": block_k, "chunk": chunk,
             "visited": visited, "masked": masked}
@@ -734,6 +718,81 @@ def flash_plan(T: int, D: int, block_q: Optional[int] = None,
     return out
 
 
+#: the dkv kernel walks a window's queries by the loop inside a chunk from
+#: this many walked blocks a window on; under it by the grid (``window_plan``)
+_MANY_BLOCKS = 4
+
+
+def _window_block(T: int, window: int) -> int:
+    """The window kernels' one block: the largest of 512, 256, 128 dividing
+    T that is no longer than the window (128 at the least). A block of 512
+    at a window of 512 computes 1024 keys a query where 256s compute 768 and
+    still reads a third faster on the chip (PERF.md section 6, PR 50): a
+    block's step is a chain that does not overlap the next block's, and its
+    fixed part weighs more the smaller the block (``flash_plan``)."""
+    most = 512
+    while most > _LANES and most > window:
+        most //= 2
+    return _largest_block(T, most)
+
+
+def window_plan(T: int, D: int, window: int, block_q: Optional[int] = None,
+                block_k: Optional[int] = None) -> dict:
+    """The window kernels' blocks at sequence ``T``, head dim ``D`` and a
+    window of ``window`` keys, with what each kernel's walk over a head then
+    is: to the ``saturn_swa_*`` kernels what ``flash_plan`` is to the causal
+    ones, and as pure. ``block_q`` / ``block_k`` (one of them: both) put all
+    three kernels on the caller's blocks.
+
+    One block on both sides (``_window_block``: 512 from a window of 512
+    on), and the walk over the reached blocks by what a chunk of the walked
+    side costs to fetch (the chip's readings: PERF.md section 6, PR 50;
+    ``tools/flash_blocks.py``):
+
+    - **fwd and dq walk the keys**, and k / v are shared by every row block
+      and every q head of a group: a chunk of all of T (``_chunk``) is
+      fetched once a k/v head, so the walk is always the loop inside the
+      kernel over ``_reach``'s span of it (2 blocks of 512 at Laguna's
+      window of 512, at most 9 at SmallThinker's 4096) and the grid has one
+      step a row block.
+    - **dkv walks the queries**, and q / dO are another head's at every
+      step of the grid: a chunk is fetched anew each time. Where the window
+      spans ``_MANY_BLOCKS`` walked blocks or more (SmallThinker's 8) the
+      loop's work outweighs the fetch of all of T and the walk is the loop;
+      under that (Laguna's 1) the chunk is one block and the reached blocks
+      are a grid axis, so that a step fetches what the window reaches and no
+      more.
+
+    Each kernel's entry says its blocks and chunk, ``_walk``'s counts under
+    the window (``visited`` / ``masked`` score blocks a head), ``steps`` (the
+    grid's innermost axis: chunks a row block walks), ``blocks_a_step`` (the
+    most blocks the loop walks in one of them) and ``computed_over_needed``:
+    the scores the visited blocks hold over the scores the window needs
+    (``sum_i min(i + 1, window)``).
+    """
+    b = _window_block(T, window)
+    bq, bk = block_q or block_k or b, block_k or block_q or b
+    w = min(window, T)
+    needed = w * (w + 1) // 2 + (T - w) * w
+    out = {"window": window, "seq": T, "head_dim": D}
+    for name in ("fwd", "dq", "dkv"):
+        walks_keys = name != "dkv"
+        b_row, b_col = (bq, bk) if walks_keys else (bk, bq)
+        loop = walks_keys or window >= _MANY_BLOCKS * b_col
+        chunk = _chunk(T, D, b_col) if loop else b_col
+        walk = _walk(T, bq, bk, chunk, walks_keys, window)
+        walk["steps"] = _chunk_walk(b_row, b_col, chunk, T, True, window,
+                                    walks_keys)[0]
+        walk["blocks_a_step"] = min(chunk // b_col, max(
+            hi - lo for lo, hi in (
+                _reach(r, b_row, b_col, T // b_col, True, window, walks_keys)
+                for r in range(T // b_row))))
+        walk["computed_over_needed"] = round(
+            walk["visited"] * bq * bk / needed, 3)
+        out[name] = walk
+    return out
+
+
 def flash_supported(cfg=None) -> bool:
     """Can the Pallas kernel lower (not interpret) for this model config?
 
@@ -775,8 +834,10 @@ def flash_attention(
     ``saturn_mla_*``, each operand's block at its own width.
 
     ``window`` (causal only): query i reads keys i - window + 1 .. i, through
-    the ``saturn_swa_*`` kernels, whose grids visit the blocks the window
-    reaches and no other (``window_plan``); one block size, ``block_q``.
+    the ``saturn_swa_*`` kernels, which visit the blocks the window reaches
+    and no other, on ``window_plan``'s blocks and chunks: the causal
+    kernels' in-kernel loop over the reached span, but for dkv at a window
+    of few blocks, whose reached blocks stay a grid axis.
 
     Grouped-query attention is native: ``k``/``v`` may carry fewer heads
     (B, KV, T, D) with KV dividing H — the kernels index each q head's
@@ -788,7 +849,8 @@ def flash_attention(
     of 1024 or 512 / 256 / 128 dividing T, else min(128, T)) or this raises
     — the model config validates the constraint up front
     (``GPT2Config.__post_init__``); this op stays strict. ``block_q`` /
-    ``block_k`` put all three kernels on the caller's blocks.
+    ``block_k`` put all three kernels on the caller's blocks (under a
+    window one of them alone names both).
     """
     B, H, T, D = q.shape
     KV = k.shape[1]
@@ -806,22 +868,17 @@ def flash_attention(
     kf = k.reshape(B * KV, T, D)
     vf = v.reshape(B * KV, T, Dv)
     if window is not None:
-        if not causal or window < 1 or (block_k or block_q) != block_q:
-            raise ValueError("a window is causal, >= 1, with one block size")
-        b = block_q or _window_block(T)
-        if T % b:
-            raise ValueError(f"seq len {T} not divisible by the block ({b})")
-        plans.record("window", window_plan(T, int(window), b))
-        # a chunk of one block: what a step fetches is what the window reaches
-        blocks = ((b, b, b),) * 3
-        o = _flash_bh(qf, kf, vf, blocks, True, H, KV, int(window))
-        return o.reshape(B, H, T, D)
-    plan = flash_plan(T, D, block_q, block_k, d_v=Dv)
+        if not causal or window < 1:
+            raise ValueError("a window is causal and >= 1")
+        window = int(window)
+        family, plan = "window", window_plan(T, D, window, block_q, block_k)
+    else:
+        family, plan = "flash", flash_plan(T, D, block_q, block_k, d_v=Dv)
     blocks = tuple((plan[n]["block_q"], plan[n]["block_k"], plan[n]["chunk"])
                    for n in ("fwd", "dq", "dkv"))
     if any(T % b for triple in blocks for b in triple):
         raise ValueError(f"seq len {T} not divisible by blocks {blocks}")
     if causal:
-        plans.record("flash", plan)
-    o = _flash_bh(qf, kf, vf, blocks, causal, H, KV)
+        plans.record(family, plan)
+    o = _flash_bh(qf, kf, vf, blocks, causal, H, KV, window)
     return o.reshape(B, H, T, Dv)

@@ -253,11 +253,16 @@ def _masked_dense(q, k, v, window):
                       jax.nn.softmax(jnp.where(keep, s, -jnp.inf), -1), v)
 
 
-#: id: (T, D, block_q, block_k, chunks of the walked side, window). The walk
-#: over the other sequence is a loop inside a chunk and a grid axis over the
-#: chunks: at the cells' shapes one chunk holds all of T, so the chunked walk
-#: (a longer T's) is held here, at chunks of T / 2 and T / 4. D 16 and 64 run
-#: the forward keys-down, 128 queries-down.
+#: id: (T, D, block_q, block_k, chunks of the walked side, window[, q heads,
+#: k/v heads]). The walk over the other sequence is a loop inside a chunk and
+#: a grid axis over the chunks: at the cells' shapes one chunk holds all of T,
+#: so the chunked walk (a longer T's) is held here, at chunks of T / 2 and
+#: T / 4. D 16 and 64 run the forward keys-down, 128 queries-down.
+#: ``many-blocks-*`` (PR 50): a window of eight walked blocks beside a row
+#: block of two, in a chunk of all of T: the plan ``window_plan`` gives
+#: SmallThinker's 4096 keys at 8192, there on equal blocks of 512; here
+#: also on unequal ones, fwd and dq's row block the wider (wide-q) and dkv's
+#: (wide-k); at T 384 five of the six row blocks reach back past key 0.
 CHUNKED = {
     "one-chunk-d64": (256, 64, 32, 32, 1, None),
     "two-chunks-d64": (256, 64, 32, 32, 2, None),
@@ -269,6 +274,12 @@ CHUNKED = {
     "window-of-two-blocks-d128": (256, 128, 32, 32, 8, 64),
     "window-across-chunks": (256, 16, 32, 32, 2, 100),
     "window-of-nearly-all": (256, 16, 32, 32, 8, 255),
+    "many-blocks-one-chunk-equal-rep7": (512, 16, 32, 32, 1, 256, 7, 1),
+    "many-blocks-one-chunk-rep7": (512, 16, 64, 32, 1, 256, 7, 1),
+    "many-blocks-one-chunk-wide-k-rep7": (512, 16, 32, 64, 1, 256, 7, 1),
+    "many-blocks-one-chunk-d128-rep7-two-kv": (512, 128, 64, 32, 1, 256, 14, 2),
+    "many-blocks-at-the-sequence-start-rep7": (384, 16, 64, 32, 1, 256, 7, 1),
+    "many-blocks-past-a-block-edge-two-chunks": (512, 16, 32, 64, 2, 250, 7, 1),
 }
 
 
@@ -279,8 +290,8 @@ def test_chunked_walk_matches_dense(case):
     are) against dense float32 attention under the same mask."""
     from saturn_tpu.ops import flash
 
-    T, D, bq, bk, n, window = CHUNKED[case]
-    B, H, KV = 1, 2, 1
+    T, D, bq, bk, n, window, H, KV = (*CHUNKED[case], 2, 1)[:8]
+    B = 1
     q, k, v = _grouped(B, H, KV, T, D)
     w = jnp.asarray(np.random.default_rng(2).standard_normal(q.shape), jnp.float32)
     blocks = ((bq, bk, max(bk, T // n)),) * 2 + ((bq, bk, max(bq, T // n)),)
@@ -313,6 +324,11 @@ def test_a_windows_grid_walks_only_the_chunks_it_reaches():
         steps, _ = flash._chunk_walk(256, 256, 256, 8192, True, None, rows_are_queries)
         assert steps == 32
     assert flash._chunk_walk(512, 512, 4096, 4096, True, None, True)[0] == 1
+    # a window of many blocks in a chunk of all of T (PR 50): one step, where
+    # the same window walked by the grid in 256s took 17
+    assert flash._chunk_walk(512, 512, 8192, 8192, True, 4096, True)[0] == 1
+    assert flash._chunk_walk(512, 512, 8192, 8192, True, 4096, False)[0] == 1
+    assert flash._chunk_walk(256, 256, 256, 8192, True, 4096, True)[0] == 17
 
 
 #: (T, head dim, rep) of the cells' causal calls
@@ -346,6 +362,85 @@ def test_flash_plan_is_a_pure_function_of_the_shapes(cell, monkeypatch):
         assert 0 < walk["masked"] <= walk["visited"] <= (T // bq) * (T // bk)
         # the kernel computes visited * bq * bk scores of the T^2 / 2 needed
         assert walk["visited"] * bq * bk >= T * (T + 1) // 2
+
+
+#: the cells' window calls: (T, head dim, window) -> visited score blocks a
+#: head, and (chunk, innermost grid steps, most blocks the loop walks in one
+#: step) of fwd and dq, which walk the keys, and of dkv, which walks the
+#: queries; every kernel on blocks of 512 x 512
+WINDOW_SHAPES = {
+    "laguna-512": ((8192, 128, 512), 31, (8192, 1, 2), (512, 2, 1)),
+    "smallthinker-4096": ((8192, 128, 4096), 108, (8192, 1, 9), (8192, 1, 9)),
+}
+
+
+@pytest.mark.parametrize("cell", list(WINDOW_SHAPES))
+def test_window_plan_is_a_pure_function_of_the_shapes(cell, monkeypatch):
+    """``window_plan(T, D, window)``: same answer twice, no device asked,
+    nothing compiled or run; it says what each kernel runs on, every block
+    and chunk divides T, and ``window / block`` decides dkv's walk: at
+    Laguna's one block of 512 its reached blocks are a grid axis, at
+    SmallThinker's eight all three kernels walk by the loop inside one chunk
+    of all of T."""
+    from saturn_tpu.ops import flash
+
+    def no(*a, **k):
+        raise AssertionError("window_plan touched the device or a compiler")
+
+    for name in ("devices", "default_backend", "jit", "make_jaxpr"):
+        monkeypatch.setattr(jax, name, no)
+    monkeypatch.setattr(flash.pl, "pallas_call", no)
+    (T, D, window), visited, walks_keys, walks_queries = WINDOW_SHAPES[cell]
+    plan = flash.window_plan(T, D, window)
+    assert plan == flash.window_plan(T, D, window)
+    assert (plan["window"], plan["seq"], plan["head_dim"]) == (window, T, D)
+    needed = window * (window + 1) // 2 + (T - window) * window
+    for kernel in ("fwd", "dq", "dkv"):
+        walk = plan[kernel]
+        bq, bk, chunk = walk["block_q"], walk["block_k"], walk["chunk"]
+        assert (bq, bk) == (512, 512)
+        assert T % bq == 0 and T % bk == 0 and T % chunk == 0
+        assert chunk % (bq if kernel == "dkv" else bk) == 0
+        assert (chunk, walk["steps"], walk["blocks_a_step"]) == (
+            walks_queries if kernel == "dkv" else walks_keys)
+        assert 0 < walk["masked"] <= walk["visited"] == visited
+        assert walk["computed_over_needed"] == round(visited * bq * bk / needed, 3)
+        assert 1.0 <= walk["computed_over_needed"] <= 2.0
+
+
+@pytest.mark.parametrize("window, block, dkv_loops", [
+    (24, 128, False), (128, 128, False), (255, 128, False), (256, 256, False),
+    (511, 256, False), (512, 512, False), (1024, 512, False), (2047, 512, False),
+    (2048, 512, True), (8192, 512, True)])
+def test_where_the_window_rule_changes_plan(window, block, dkv_loops):
+    """One block no longer than the window (512 at the most, 128 at the
+    least); fwd and dq always walk a chunk of all of T by the loop, dkv from
+    ``_MANY_BLOCKS`` = 4 walked blocks a window on (4 x 512 at T 8192)."""
+    from saturn_tpu.ops import flash
+
+    plan = flash.window_plan(8192, 128, window)
+    for kernel in ("fwd", "dq", "dkv"):
+        assert (plan[kernel]["block_q"], plan[kernel]["block_k"]) == (block, block)
+    assert plan["fwd"]["chunk"] == plan["dq"]["chunk"] == 8192
+    assert plan["dkv"]["chunk"] == (8192 if dkv_loops else block)
+
+
+def test_flash_attention_takes_unequal_blocks_under_a_window():
+    """``block_q`` / ``block_k`` put the three window kernels on the caller's
+    blocks (the one-block-size refusal went with PR 50), the walk by the same
+    rule: a window of eight walked blocks in one chunk of all of T."""
+    q, k, v = _grouped(1, 7, 1, 512, 16)
+    plan = flash_mod.window_plan(512, 16, 256, 64, 32)
+    assert plan["dq"] == {
+        "block_q": 64, "block_k": 32, "chunk": 512, "visited": 60, "masked": 24,
+        "steps": 1, "blocks_a_step": 10, "computed_over_needed": 1.248}
+    assert (plan["dkv"]["chunk"], plan["dkv"]["blocks_a_step"]) == (512, 5)
+    out = flash_attention(q, k, v, window=256, block_q=64, block_k=32)
+    np.testing.assert_allclose(np.asarray(out),
+                               np.asarray(_masked_dense(q, k, v, 256)),
+                               rtol=1e-4, atol=1e-5)
+    with pytest.raises(ValueError, match="not divisible"):
+        flash_attention(q, k, v, window=256, block_q=96)
 
 
 # -------------------------------------- the host's side of a step program
@@ -388,18 +483,18 @@ PARENT_BODY_EQNS = {"saturn_flash_fwd": 69, "saturn_flash_dq": 50,
                     "saturn_swa_dq": 51, "saturn_swa_dkv": 65}
 
 
-@pytest.mark.parametrize("kind, D, T, remat", [
-    ("flash", 64, 1024, False), ("flash", 128, 4096, False),
-    ("flash", 256, 2048, False), ("flash", 64, 1024, True),
-    ("swa", 128, 1024, False),
-], ids=["d64-t1024", "d128-t4096", "d256-t2048", "d64-remat", "window"])
-def test_three_small_kernels_an_attention_call(kind, D, T, remat):
+@pytest.mark.parametrize("kind, D, T, remat, window", [
+    ("flash", 64, 1024, False, None), ("flash", 128, 4096, False, None),
+    ("flash", 256, 2048, False, None), ("flash", 64, 1024, True, None),
+    ("swa", 128, 1024, False, 512), ("swa", 128, 8192, False, 4096),
+    ("swa", 128, 8192, True, 4096),
+], ids=["d64-t1024", "d128-t4096", "d256-t2048", "d64-remat", "window",
+        "window-of-many-blocks", "window-of-many-blocks-remat"])
+def test_three_small_kernels_an_attention_call(kind, D, T, remat, window):
     """``grad`` of one attention call is exactly the three ``pallas_call``s
     under the names the benchmark's roofline reader credits (a fourth, the
     forward again, where the layer is rematerialised), and no kernel body has
     grown past 1.6x the parent's equation count. Traced only: nothing runs."""
-    window = 512 if kind == "swa" else None
-
     def attend(q, k, v):
         return flash_attention(q, k, v, window=window)
 
@@ -429,7 +524,15 @@ _TEXT_BEFORE_TWO_WIDTHS = {
     "head-128": ((128,), {}, "674df037d5bc3c82"),
     "head-256": ((256,), {}, "26cf3659d158eec7"),
     "head-128-t8192-grouped": ((128,), {"t": 8192, "h": 2, "kv": 1}, "9487d455add689bc"),
-    "head-128-window-512": ((128,), {"window": 512, "t": 2048}, "41d5382a5e4113fb"),
+    # the two window texts were taken on PR 50's tree, which moved them on
+    # purpose: ``window_plan`` put Laguna's 512 keys on blocks of 512 (fwd and
+    # dq walking by the loop inside a chunk of all of T, dkv's two reached
+    # blocks a grid axis) where 256s walked by the grid read a third slower on
+    # the chip, and SmallThinker's 4096 keys at 8192 (7 q heads a k/v head)
+    # on the loop in all three kernels (PERF.md section 6, PR 50)
+    "head-128-window-512": ((128,), {"window": 512, "t": 2048}, "901028d73b5e8e16"),
+    "head-128-window-4096-t8192-grouped": (
+        (128,), {"window": 4096, "t": 8192, "h": 7, "kv": 1}, "59a806b1caa523ba"),
 }
 
 

@@ -268,10 +268,17 @@ def test_window_kernels_have_names_of_their_own_and_skip_blocks():
     assert "saturn_flash_" not in text
     plain = str(jax.make_jaxpr(jax.grad(lambda q: flash_attention(q, q, q).sum()))(q))
     assert "saturn_flash_dq" in plain and "saturn_swa_" not in plain
-    # 8192 positions, a window of 512, blocks of 256: 3 of a row's up to 32 blocks
-    plan = window_plan(8192, 512)
-    assert plan == {"window": 512, "block": 256, "blocks_visited": 3,
-                    "blocks_skipped_per_call": 32 * 33 // 2 - (1 + 2 + 30 * 3)}
+    # 8192 positions, a window of 512, blocks of 512: a row block reaches 2 of
+    # a row's up to 16 blocks, 1 + 15 x 2 of a causal walk's 16 x 17 / 2; fwd
+    # and dq walk them by the loop inside one chunk of all of T, dkv as a grid
+    # axis over chunks of one block (PR 50)
+    plan = window_plan(8192, 128, 512)
+    assert (plan["window"], plan["seq"], plan["head_dim"]) == (512, 8192, 128)
+    walk = {"block_q": 512, "block_k": 512, "visited": 1 + 15 * 2, "masked": 31,
+            "computed_over_needed": 2.0}
+    assert plan["fwd"] == plan["dq"] == {**walk, "chunk": 8192, "steps": 1,
+                                         "blocks_a_step": 2}
+    assert plan["dkv"] == {**walk, "chunk": 512, "steps": 2, "blocks_a_step": 1}
     with pytest.raises(ValueError, match="window"):
         flash_attention(q, q, q, window=8, causal=False)
 

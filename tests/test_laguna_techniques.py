@@ -116,8 +116,11 @@ def test_the_flash_grid_point_says_its_window_plan(tmp_path, devices8):
     tech.build(task, devices, config)
     fields = tech._plan_fields(task, devices, config)
     assert fields["moe_plan"]["impl"] == "kernel" and fields["step_traces"] == 1
-    assert fields["window_plan"] == {"window": 24, "block": 64, "blocks_visited": 1,
-                                     "blocks_skipped_per_call": 0}
+    plan = fields["window_plan"]
+    assert (plan["window"], plan["seq"], plan["head_dim"]) == (24, SEQ, 16)
+    assert plan["fwd"] == plan["dq"] == plan["dkv"] == {    # one block is all of T
+        "block_q": 64, "block_k": 64, "chunk": 64, "visited": 1, "masked": 1,
+        "steps": 1, "blocks_a_step": 1, "computed_over_needed": 3.251}
 
 
 # --------------------------------------------------- every technique

@@ -119,7 +119,14 @@ def test_the_flash_grid_point_says_its_plans(tmp_path, devices8):
     assert (fields["moe_plan"]["route_from"], fields["moe_plan"]["score"],
             fields["moe_plan"]["act"]) == ("block_input", "softmax", "reglu")
     assert fields["rotary_plan"] == {"rotated": [SLIDING], "unrotated": [FULL]}
-    assert fields["window_plan"]["window"] == 32 and "flash_plan" in fields
+    plan = fields["window_plan"]
+    assert plan["window"] == 32 and "flash_plan" in fields
+    # at the tiny preset the window is half of one block: the few-blocks plan,
+    # one block walked by the grid (the cell's 4096 of 8192 takes the causal
+    # kernels': tests/test_flash.py::test_window_plan_is_a_pure_function_of_the_shapes)
+    assert all((plan[k]["block_q"], plan[k]["block_k"], plan[k]["chunk"],
+                plan[k]["steps"], plan[k]["blocks_a_step"]) == (64, 64, 64, 1, 1)
+               for k in ("fwd", "dq", "dkv"))
 
 
 # --------------------------------------------------- every technique

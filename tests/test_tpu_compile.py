@@ -92,7 +92,8 @@ def test_flash_attention_compiles_for_v5e(one_chip, real_lowering, shape, grad):
 def test_window_and_grouped_flash_compile_for_v5e(one_chip, real_lowering, heads):
     """The Laguna cell's attention at its own shapes: 48 / 64 q heads over 8
     k/v heads of 128, seq 8192 x batch 2; the window kernels (window 512,
-    blocks of 256: 3 key blocks a query block) under names of their own."""
+    blocks of 512: 2 key blocks a query block, fwd and dq walking them inside
+    a chunk of all of T) under names of their own."""
     q = jax.ShapeDtypeStruct((2, heads, 8192, 128), jnp.bfloat16, sharding=one_chip)
     kv = jax.ShapeDtypeStruct((2, 8, 8192, 128), jnp.bfloat16, sharding=one_chip)
     window = 512 if heads == 64 else None
@@ -110,9 +111,9 @@ def test_window_and_grouped_flash_compile_for_v5e(one_chip, real_lowering, heads
 def test_flash_at_seven_q_heads_a_kv_head_compiles_for_v5e(one_chip, real_lowering, window):
     """The SmallThinker cell's attention at its own shapes: 28 q heads over 4
     k/v heads of 128 (7 a group: the dkv kernel walks a group's members), seq
-    8192 x batch 4; the window kernels at a window of half the sequence (the
-    ``saturn_swa_*`` grids walk eight times the key blocks Laguna's 512
-    reach), the full layer's under ``saturn_flash_*``."""
+    8192 x batch 4; the window kernels at a window of half the sequence (all
+    three walk its at most 9 blocks of 512 by the loop inside a chunk of all
+    of T), the full layer's under ``saturn_flash_*``."""
     q = jax.ShapeDtypeStruct((4, 28, 8192, 128), jnp.bfloat16, sharding=one_chip)
     kv = jax.ShapeDtypeStruct((4, 4, 8192, 128), jnp.bfloat16, sharding=one_chip)
 
