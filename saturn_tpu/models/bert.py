@@ -105,7 +105,7 @@ def build_bert(name: str = "bert-base", **overrides) -> ModelSpec:
         # input against the original tokens, unmasked positions ignored via
         # label -1 — the same mean-over-masked objective as mlm_loss.
         def _fused(params, tokens, reduction):
-            from saturn_tpu.ops.ce import fused_linear_cross_entropy
+            from saturn_tpu.ops.ce import fused_linear_cross_entropy, stash_of
 
             x = spec.hidden_fn(params, mask_tokens(tokens))
             labels = jnp.where(
@@ -113,7 +113,8 @@ def build_bert(name: str = "bert-base", **overrides) -> ModelSpec:
                 tokens.astype(jnp.int32), -1,
             )
             return fused_linear_cross_entropy(
-                x, params["wte"], labels, reduction=reduction
+                x, params["wte"], labels, reduction=reduction,
+                stash=stash_of(cfg.ce_mode),
             )
 
         def fused_loss_fn(params, tokens):

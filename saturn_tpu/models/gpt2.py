@@ -80,6 +80,12 @@ class GPT2Config:
     # for memory first at long seq). Ignored when seq_axis is set
     # (sequence-parallel attention has its own kernels).
     attention: str = "auto"
+    # The fused head's backward (ops/ce.py), where a caller states it:
+    # "stash" keeps the forward's bf16 logits for it, "recompute" derives
+    # each score block again. None leaves it to the op (the stash is kept
+    # under ``ce.STASH_BYTES_MAX``). The trial runner states "stash" for a
+    # larger one where the compiled program has room for it.
+    ce_mode: Optional[str] = None
     # False = bidirectional (encoder / BERT-class) attention. Sequence-
     # parallel attention paths assume causal, so seq techniques are only
     # feasible for causal configs.
@@ -293,6 +299,9 @@ class GPT2Config:
                 f"attention must be 'auto', 'dense' or 'flash', "
                 f"got {self.attention!r}"
             )
+        from saturn_tpu.ops.ce import stash_of
+
+        stash_of(self.ce_mode)   # a ValueError for a mode the op has not
         if self.rotary:
             rd = self.rotary_dim if self.rotary_dim is not None else self.head_dim
             if rd % 2 != 0 or rd > self.head_dim:
@@ -1707,14 +1716,15 @@ def build_gpt2(
             return _fused_of(hidden_fn(params, tokens), params, tokens, reduction)
 
         def _fused_of(x, params, tokens, reduction):
-            from saturn_tpu.ops.ce import fused_linear_cross_entropy
+            from saturn_tpu.ops.ce import fused_linear_cross_entropy, stash_of
 
             labels = jnp.pad(
                 tokens[:, 1:].astype(jnp.int32), ((0, 0), (0, 1)),
                 constant_values=-1,
             )
             return fused_linear_cross_entropy(
-                x, params[head_key], labels, reduction=reduction
+                x, params[head_key], labels, reduction=reduction,
+                stash=stash_of(cfg.ce_mode),
             )
 
         def fused_loss_fn(params, tokens):

@@ -28,6 +28,26 @@ logits or the softmax gradient:
   score block from x·W^T in-kernel, which costs one extra matmul pass per
   backward kernel and needs ZERO O(N·V) memory.
 
+Who chooses the mode: a caller that states it (``stash=True`` / ``False``;
+a model's ``ce_mode``, :func:`stash_of`), else :func:`ce_plan` by the stash's
+size alone. Under ``STASH_BYTES_MAX`` (0.5 GiB) the stash is kept and nobody
+is asked. Over it an unasked call recomputes, because a constant cannot know
+whether the program has room: the stash lives from the head's forward to its
+backward, when no layer's recomputed activations are alive, so where the
+program's peak lies elsewhere it costs nothing. Compiled for a v5e, the 0.77
+GiB stash of 8192 tokens x d 4096 x 50400 adds 0.39 GiB to a remat program's
+11.34, the 0.75 GiB at d 2048 x 49152 nothing to 14.11 of the 14.49 GiB the
+memory rule allows, and either sits on top of a peak that is over the rule
+already without remat. The compile knows, so the trial runner asks it
+(``parallel/spmd_base.py``, the head's rungs: a grid point whose head
+:func:`stash_over_the_constant` names is prepared with ``ce_mode="stash"``
+first and as the grid has it, recomputing, only where the compiler or the
+memory rule refuses that program; PR 51). The stash saves a
+matmul pass of two in ``saturn_ce_dx`` and in ``saturn_ce_dw``: 35.2 -> 17.8
+and 41.5 -> 23.7 ms a step at the first shape, the forward 19.6 -> 20.3 for
+writing the stash (``gptj-6b-1chip.steady``'s traced pair, PR 51; alone:
+the readings at :func:`_dx_compute_bound_block`).
+
 Masked tokens use label -1 (the standard ignore index): they never match a
 vocab column, and the wrapper zeros their loss and (via the mean's cotangent)
 their gradient. The vocab axis is padded to a block multiple inside the op —
@@ -426,7 +446,17 @@ def _dx_compute_bound_block(stash: bool) -> int:
       8192 x 1024 x 50257 recompute                 9.36   9.06   8.91
       2048 x 4096 x 50400 stash              9.18   4.91   4.56
     The smallest block within 2 % of the best of its row is the rule's in
-    every row (256, 256, 512 with what fits the default limit, 512)."""
+    every row (256, 256, 512 with what fits the default limit, 512).
+    The shape the trial runner asks the compile about (PR 51), same tool:
+      8192 x 4096 x 50400 stash             36.41  19.32  17.92
+    (512 again). Beside it, ms: the forward 23.62 (recompute 22.98: the
+    stash's 0.77 GiB written), the whole call forward + dx + dW in one
+    program 67.60 (recompute 99.35), dW at its own blocks (bn 256, bv 128)
+    44.18 recompute and 40.22 stash *alone*, its residuals handed over as
+    arguments, but 67.60 - 23.62 - 17.92 = 26.1 inside the whole call and
+    23.7 a step in the traced cell (41.5 recompute): alone, the stash-mode
+    dW reads 14 ms long for a reason nobody has found (PERF.md section 7);
+    the whole call and the cell are the readings to go by."""
     passes = 1 if stash else 2
     bn = 128
     while passes * bn < 2 * _RIDGE_FLOP_PER_BYTE and bn < 512:
@@ -535,11 +565,34 @@ def dense_linear_cross_entropy(x, w, labels, *, ignore_index=-1):
     return jnp.where(valid, per_tok, 0.0).sum() / count
 
 
-# Auto stash threshold: keep the bf16 logits stash (saves one recompute
-# matmul pass in each backward kernel) while it stays a modest slice of
-# HBM; above this, recompute mode drops ALL O(N·V) memory — the difference
-# between b8x2048 GPT-2 fitting on a v5e chip or not.
+# The size under which nobody is asked: a bf16 logits stash this small (it
+# saves one recompute matmul pass in each backward kernel) is kept by every
+# call that states no mode. Over it such a call recomputes and holds no
+# O(N·V) memory at all — the difference between b8x2048 GPT-2 fitting on a
+# v5e chip or not — and the mode is the caller's to state where it knows
+# better: the trial runner states ``stash`` where the compiled program has
+# room for it (``parallel/spmd_base.py``, the head's rungs). The constant is
+# no verdict on what fits; it only says from where on the question is worth a
+# compile.
 STASH_BYTES_MAX = 512 * 1024 * 1024
+
+#: a model's ``ce_mode`` -> the ``stash`` argument of the fused call
+_STASH_OF = {None: None, "stash": True, "recompute": False}
+
+
+def stash_of(mode: Optional[str]) -> Optional[bool]:
+    """``stash`` of :func:`fused_linear_cross_entropy` for a mode by name:
+    ``"stash"``, ``"recompute"``, or None for the automatic choice."""
+    try:
+        return _STASH_OF[mode]
+    except KeyError:
+        raise ValueError(f"unknown fused-head mode (ce_mode) {mode!r}; "
+                         f"options: 'stash', 'recompute' or None") from None
+
+
+def _stash_bytes(n_tokens: int, n_vocab: int, blocks) -> int:
+    """The bf16 logits stash of a stash-mode call at these blocks."""
+    return n_tokens * _padded_vocab(n_vocab, blocks) * 2
 
 
 class CEPlan(NamedTuple):
@@ -592,7 +645,7 @@ def ce_plan(n_tokens: int, d_model: int, n_vocab: int, *,
                             block_n, block_v)
 
     if stash is None:
-        stash = (n_tokens * _padded_vocab(n_vocab, blocks_at(True)) * 2
+        stash = (_stash_bytes(n_tokens, n_vocab, blocks_at(True))
                  <= STASH_BYTES_MAX)
     stash = bool(stash)
     blocks = blocks_at(stash)
@@ -603,6 +656,43 @@ def ce_plan(n_tokens: int, d_model: int, n_vocab: int, *,
         _dw_vmem(bn_dw, bv_dw, d_model, stash),
         _dx_vmem_limit(bn_dx, bv, d_model, stash),
     )
+
+
+def call_plan(n_tokens: int, d_model: int, n_vocab: int, *,
+              stash: Optional[bool] = None, block_n: Optional[int] = None,
+              block_v: Optional[int] = None,
+              interpret: Optional[bool] = None) -> Optional[CEPlan]:
+    """What a :func:`fused_linear_cross_entropy` call with these shapes and
+    arguments runs as on this backend: :func:`ce_plan`, or None where the
+    call computes through plain XLA ops (no block tiles the tokens, no TPU
+    and no interpret mode, a vocab block the chip cannot tile)."""
+    plan = ce_plan(n_tokens, d_model, n_vocab, stash=stash, block_n=block_n,
+                   block_v=block_v)
+    # Real TPU lowering needs lane-aligned vocab blocks (Mosaic tiles the
+    # last dim in 128-lane units); _padded_vocab's LCM padding already makes
+    # every grid tile Vp exactly, so misalignment — possible only with an
+    # explicit non-128-multiple block_v — is the one way left to reach the
+    # kernel with a shape the chip can't lower. Route it to dense. Interpret
+    # mode (CPU numerics tests) has no such constraint.
+    if plan is not None and not interpret and (
+        _use_interpret() or plan.bv % 128 != 0 or plan.bv_dw % 128 != 0
+    ):
+        return None
+    return plan
+
+
+def stash_over_the_constant(n_tokens: int, d_model: int,
+                            n_vocab: int) -> Optional[int]:
+    """The bytes of the bf16 logits stash a call with these shapes would
+    keep in stash mode, where a call that states no mode recomputes *only*
+    because that is more than ``STASH_BYTES_MAX``: the head whose mode is
+    worth asking the compile about. None where the automatic choice keeps
+    the stash already, or where the call runs no kernel."""
+    plan = call_plan(n_tokens, d_model, n_vocab)
+    if plan is None or plan.mode == "stash":
+        return None
+    kept = ce_plan(n_tokens, d_model, n_vocab, stash=True)
+    return _stash_bytes(n_tokens, n_vocab, kept.blocks)
 
 
 def fused_linear_cross_entropy(
@@ -635,7 +725,9 @@ def fused_linear_cross_entropy(
     False recomputes score blocks from x·W^T in each backward kernel (one
     extra matmul pass per kernel, ZERO O(N·V) memory — long-context mode).
     None (default) stashes only while the stash stays under
-    ``STASH_BYTES_MAX``.
+    ``STASH_BYTES_MAX``, the size under which nobody is asked; over it the
+    mode is for a caller to state who knows what the program has room for
+    (the trial runner, through a model's ``ce_mode``).
 
     Falls back to :func:`dense_linear_cross_entropy` math when the kernel
     cannot lower for these shapes on this backend.
@@ -664,17 +756,8 @@ def fused_linear_cross_entropy(
         per_tok = _dense_per_token(x.reshape(N, D), w, lab1)
         return reduce(per_tok, lab1 != ignore_index)
 
-    plan = ce_plan(N, D, V, stash=stash, block_n=block_n, block_v=block_v)
-    # Real TPU lowering needs lane-aligned vocab blocks (Mosaic tiles the
-    # last dim in 128-lane units); _padded_vocab's LCM padding already makes
-    # every grid tile Vp exactly, so misalignment — possible only with an
-    # explicit non-128-multiple block_v — is the one way left to reach the
-    # kernel with a shape the chip can't lower. Route it to dense. Interpret
-    # mode (CPU numerics tests) has no such constraint.
-    if plan is not None and not interp and (
-        _use_interpret() or plan.bv % 128 != 0 or plan.bv_dw % 128 != 0
-    ):
-        plan = None
+    plan = call_plan(N, D, V, stash=stash, block_n=block_n, block_v=block_v,
+                     interpret=interp)
     plans.record("ce", plan)   # None: the call falls back to plain XLA ops
     if plan is None:
         return dense_fallback()

@@ -303,6 +303,8 @@ class _GridPoint:
     """One grid point on its way through ``SPMDTechnique.search``."""
 
     order: int                  # its place in the technique's own grid
+    # the grid's, with ``ce_mode: "stash"`` added where the point is ready on
+    # its head's stash rung: the config of the program that is timed
     config: Dict[str, Any]
     # its ``trial.config``: opened where its preparation starts, closed on
     # the thread the point ends on
@@ -315,6 +317,10 @@ class _GridPoint:
     compiler: Optional[str] = None  # ...and the compiler's first line
     unbuilt: bool = False   # it ended on its point record: nothing was built
     error: Optional[str] = None     # of an ``error`` point, for ``first_error``
+    # what the fused head's rungs did (``SPMDTechnique._head_rungs``), as its
+    # ``trial_config`` event and its span carry it (``ce_ladder``); None for
+    # a point whose head nobody is asked about
+    ladder: Optional[Dict[str, Any]] = None
 
 
 #: a wait across the hand-off shorter than this emits no span: the other side
@@ -671,16 +677,11 @@ class SPMDTechnique(BaseTechnique):
         #   natively.
         fused = getattr(spec, "fused_loss_fn", None)
         parts = getattr(spec, "fused_loss_parts_fn", None)
-        tag = getattr(loss_fn, "supports_fused_head", None)
         single = mesh is None or getattr(mesh, "size", 1) <= 1
         if (
-            fused is not None
-            and self.fused_loss_ok
-            and (single or (self.fused_loss_shardable and parts is not None))
+            self._fused_head_offered(spec, task, single)
             and forward is spec.apply_fn
             and forward_with_aux is None
-            and tag is not None
-            and tag == getattr(spec, "fused_loss_objective", None)
         ):
             if single:
                 fused_loss = fused
@@ -738,6 +739,23 @@ class SPMDTechnique(BaseTechnique):
 
         return self.step_fns_from_loss_and_grads(
             spec.init_fn, task, loss_and_grads, update_on_host=update_on_host
+        )
+
+    def _fused_head_offered(self, spec: Any, task: Any, single: bool) -> bool:
+        """Whether model, loss and block offer this technique the fused
+        head+loss (the conditions above that need no forward to ask): the
+        model has one for the objective the task's loss names, the technique
+        takes it, and the block is one device or one the technique can run
+        it on shard by shard."""
+        tag = getattr(task.loss_fn, "supports_fused_head", None)
+        return bool(
+            getattr(spec, "fused_loss_fn", None) is not None
+            and self.fused_loss_ok
+            and (single or (
+                self.fused_loss_shardable
+                and getattr(spec, "fused_loss_parts_fn", None) is not None))
+            and tag is not None
+            and tag == getattr(spec, "fused_loss_objective", None)
         )
 
     @staticmethod
@@ -828,6 +846,8 @@ class SPMDTechnique(BaseTechnique):
             out["remat"] = config["remat"]
         if config.get("attention"):
             out["attention"] = config["attention"]
+        if config.get("ce_mode"):
+            out["ce_mode"] = config["ce_mode"]
         return out
 
     def _with_attention_variants(
@@ -1056,7 +1076,7 @@ class SPMDTechnique(BaseTechnique):
     def _fits_compiled(
         self, compiled: Any, devices: Sequence[Any], *,
         task: Any = None, config: Optional[Dict[str, Any]] = None,
-        k: int = 1,
+        k: int = 1, said: Optional[Dict[str, Any]] = None,
     ) -> bool:
         """Memory check against a specific compiled program — the fused
         K-step trial analyzes the window program it will actually time (its
@@ -1066,12 +1086,16 @@ class SPMDTechnique(BaseTechnique):
         When the caller knows the (task, config) this program came from,
         every check also emits a ``memlens_calibration`` metrics event —
         static predicted bytes next to the compiled figure — so the
-        SAT-M005 drift audit accrues for free on every sweep.
+        SAT-M005 drift audit accrues for free on every sweep. ``said``
+        receives the two figures the span carries (``need_bytes``,
+        ``limit_bytes``).
         """
         with _metrics.span("trial.memory_check", k=int(k)) as sp:
             limit = hbm_limit(devices[0])
             need = hbm_bytes_required(compiled)
             sp.set(need_bytes=int(need), limit_bytes=int(limit))
+            if said is not None:
+                said.update(need_bytes=int(need), limit_bytes=int(limit))
             if task is not None and config is not None:
                 with _metrics.span("trial.memlens", k=int(k), trace=(
                         self._trace_source(task, devices, config))):
@@ -1165,6 +1189,9 @@ class SPMDTechnique(BaseTechnique):
             # grid point, on the thread the point ended on: which variant
             # measured what, which did not fit, which raised — the winner
             # alone hides the rest — and where its seconds went
+            if point.ladder is not None:
+                fields["ce_ladder"] = point.ladder
+                point.span.set(ce_ladder=point.ladder)
             _metrics.event("trial_config", task=task.name, size=size,
                            technique=self.name, config=dict(point.config),
                            **stack,
@@ -1213,6 +1240,64 @@ class SPMDTechnique(BaseTechnique):
             return None
 
         over_memory: List[Dict[str, Any]] = []  # the configs that ended so
+        stash_over: List[Dict[str, Any]] = []   # ...whose stash rung did
+        room: List[Optional[int]] = []   # what the rule leaves after the state
+
+        def stash_rung(point: _GridPoint, recorded: bool) -> bool:
+            """The first rung of a point whose fused head would stash more
+            than the op keeps unasked (``_head_rungs``): the point prepared
+            with the head stating ``stash``, where that is worth a build.
+            True: the point is ready on that rung (its config now says so) or
+            ended on it (it raised, it is infeasible); False: the rung is not
+            kept (the static bound, the twin's rung, a record, the compiler,
+            the memory rule: ``point.ladder`` says which) and the point goes
+            on as the grid has it, where an unasked head recomputes. A rung
+            is no grid point: it has no event of its own, and a rung refused
+            counts no refusal."""
+            config, said = point.config, point.ladder
+            rung = dict(config, ce_mode="stash")
+            if said["room_bytes"] is not None and \
+                    said["stash_bytes"] > said["room_bytes"]:
+                said["skipped"] = "static"   # no compile can make it fit
+                return False
+            if config.get("remat") is False and \
+                    dict(config, remat=True) in stash_over:
+                said["skipped"] = "remat"    # the same bytes over the twin's
+                return False
+            record = point_records.of(
+                self, task, devices, rung, self._profile_window(rung),
+                parent=point.span, read=recorded)
+            if record.verdict is not None:
+                said["skipped"] = "recorded"
+                stash_over.append(dict(config))
+                return False
+            said["tried"].append("stash")
+            why: Dict[str, Any] = {}
+
+            def prepared():
+                try:
+                    return self._prepare(task, devices, rung, rung=why)
+                except aot_cache.CompileRefused as e:
+                    why.update(outcome="refused", refusal=e.refusal,
+                               compiler=e.first_line)
+                    return None
+
+            point.ready = attempt(point, prepared)
+            if point.ready is None and point.outcome is None:
+                log.info("%s trial %s for task %s: the head's stash rung "
+                         "(%.2f GiB) is not kept: %s", self.name, config,
+                         task.name, said["stash_bytes"] / 2**30, why)
+                said["refused"] = why
+                if why["outcome"] in point_records.VERDICTS:
+                    stash_over.append(dict(config))
+                record.note(why["outcome"], why.get("compiler"))
+                return False
+            record.note(point.outcome)   # it fits, or no memory verdict
+            if point.ready is not None:
+                said["kept"] = "stash"
+                point.config = rung
+                point.span.set(config=dict(rung))
+            return True
 
         def prepare(order: int, config: Dict[str, Any],
                     recorded: bool = True) -> _GridPoint:
@@ -1225,6 +1310,14 @@ class SPMDTechnique(BaseTechnique):
                 end(point, "memory_rejected", memory_rejected=True,
                     implied_by="remat")
                 return point
+            head = self._head_rungs(task, devices, config)
+            if head is not None:
+                if not room:   # the task's, not the point's: once a search
+                    room.append(self._room_after_state(task, devices))
+                point.ladder = dict(head, room_bytes=room[0], tried=[],
+                                    kept=None)
+                if stash_rung(point, recorded):
+                    return point
             # Its memory verdict may be on record by what the point is made
             # from (``utils/point_records``, PR 47): it then ends here too,
             # with nothing built, traced or lowered to find the text's record.
@@ -1238,6 +1331,8 @@ class SPMDTechnique(BaseTechnique):
                     unbuilt=True, **record.verdict)
                 over_memory.append(dict(config))
                 return point
+            if point.ladder is not None:
+                point.ladder["tried"].append("recompute")
             point.ready = attempt(
                 point, lambda: self._prepare(task, devices, config))
             if point.ready is None and point.outcome is None:
@@ -1245,6 +1340,8 @@ class SPMDTechnique(BaseTechnique):
                 end(point, "memory_rejected", memory_rejected=True)
             if point.outcome in ("refused", "memory_rejected"):
                 over_memory.append(dict(config))
+            elif point.ready is not None and point.ladder is not None:
+                point.ladder["kept"] = "recompute"
             record.note(point.outcome, point.compiler)  # a verdict, or none
             return point
 
@@ -1270,6 +1367,8 @@ class SPMDTechnique(BaseTechnique):
             kept = [p for p in points if p.order not in again]
             over_memory[:] = [dict(p.config) for p in kept
                               if p.outcome in ("refused", "memory_rejected")]
+            stash_over[:] = [c for c in stash_over
+                             if c in [p.config for p in kept]]
             points = kept + _measured_behind(
                 [oc for oc in grid if oc[0] in again],
                 lambda o, c: prepare(o, c, False), measure)[0]
@@ -1353,6 +1452,68 @@ class SPMDTechnique(BaseTechnique):
             out[f"{name}_plan"] = _plans.as_event(got[0])
         return out
 
+    def _head_rungs(self, task: Any, devices: Sequence[Any],
+                    config: Dict[str, Any]) -> Optional[Dict[str, Any]]:
+        """Whether this grid point's fused head (``ops/ce.py``) is one whose
+        backward mode the compile is asked about: ``{"stash_bytes": n}``, the
+        bf16 logits the backward would read in place of computing its scores
+        a second time. None where nobody is asked: the config or the model's
+        own kwargs state a mode, the model takes none (no ``ce_mode`` in its
+        config), model, loss and block offer this technique no fused head,
+        or the op keeps the stash by itself (under ``ce.STASH_BYTES_MAX``)
+        or runs no kernel here.
+
+        Shapes in, a figure out, nothing traced: the head sees every token
+        of the batch (of the shard's, where the loss runs under dp's
+        ``shard_map``). Whether the technique's step really calls that head
+        is read from the rung's own trace (``_prepare``, ``inert``). Never
+        raises: what is wrong with the point is the build's to say, where it
+        is counted."""
+        from saturn_tpu.ops import ce
+
+        if config.get("ce_mode"):
+            return None
+        try:
+            spec = task.get_model(**self._model_overrides(config))
+            cfg = spec.config
+            if getattr(cfg, "ce_mode", "no such field") is not None:
+                return None
+            single = len(devices) <= 1
+            if not self._fused_head_offered(spec, task, single) or \
+                    self.param_memory_kind(config) == "pinned_host":
+                return None
+            tokens = int(np.prod(task.get_dataset().example_batch().shape))
+            stash = ce.stash_over_the_constant(
+                tokens // len(devices), int(cfg.d_model), int(cfg.vocab_size))
+            return None if stash is None else {"stash_bytes": int(stash)}
+        except Exception as e:
+            log.debug("%s %s: the head's rungs are not known: %r",
+                      self.name, config, e)
+            return None
+
+    def _room_after_state(self, task: Any,
+                          devices: Sequence[Any]) -> Optional[int]:
+        """What the 0.92 x HBM rule leaves a program after the train state
+        (parameters and optimizer state, traced abstractly: nothing is
+        allocated), known before anything is built: a stash larger than this
+        is not worth a compile. The whole state a device, as where the fused
+        head runs (one device, or dp's replicas). None where no limit is
+        known or the state cannot be traced (no bound: the compile says)."""
+        limit = hbm_limit(devices[0])
+        if limit <= 0:
+            return None
+        try:
+            params = task.get_model().abstract_init()
+            state = (params, jax.eval_shape(
+                task.hparams.make_optimizer().init, params))
+        except Exception as e:
+            log.debug("%s: the train state of %s was not traced: %r",
+                      self.name, task.name, e)
+            return None
+        return int(0.92 * limit) - sum(
+            int(np.prod(leaf.shape)) * leaf.dtype.itemsize
+            for leaf in jax.tree_util.tree_leaves(state))
+
     def _profile_window(self, config: Dict[str, Any]) -> int:
         """K the trial should profile: steady-state execute() runs full
         windows of the max size, so that is what the MILP's per-batch times
@@ -1360,7 +1521,8 @@ class SPMDTechnique(BaseTechnique):
         return max_window() if self._fused_ok(config) else 1
 
     def _prepare(
-        self, task: Any, devices: Sequence[Any], config: Dict[str, Any]
+        self, task: Any, devices: Sequence[Any], config: Dict[str, Any],
+        rung: Optional[Dict[str, Any]] = None,
     ) -> Optional[_Prepared]:
         """The host's half of a grid point: build (the one Python trace),
         compile (lowering, the text hash, a cache hit or a compile or a
@@ -1371,12 +1533,27 @@ class SPMDTechnique(BaseTechnique):
         The program is the one ``execute()`` dispatches at steady state: the
         fused K-step window where the config may run one, memory-checked as
         such (its peak holds the (K, B, T) stack), else the 1-step program.
+
+        ``rung``: handed over where ``config`` is the stash rung of the
+        point's head (``_head_rungs``) and not the point's last word; it
+        receives why a rung that returns None was not kept: ``outcome``
+        ``memory_rejected`` with the check's ``need_bytes`` and
+        ``limit_bytes``, or ``inert`` where the step traced to no stashing
+        head after all (this technique's step for this config does not call
+        the model's fused loss: nothing is compiled for it).
         """
         bundle = self._spanned_build("trial.build", task, devices, config)
+        if rung is not None and not any(
+                getattr(plan, "mode", None) == "stash"
+                for plan in bundle.plans.get("ce", ())):
+            rung.update(outcome="inert")
+            return None
         k = self._profile_window(config)
         program = self._spanned_compile("trial.compile", bundle, k)
-        if not self._fits_compiled(program, devices,
-                                   task=task, config=config, k=k):
+        if not self._fits_compiled(program, devices, task=task, config=config,
+                                   k=k, said=rung):
+            if rung is not None:
+                rung.update(outcome="memory_rejected")
             return None
         # The init program too: lowered and compiled here it is a plain call
         # where the state is put on the chip, and not a trace and a cache

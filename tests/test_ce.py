@@ -591,3 +591,299 @@ def test_dx_token_block_changes_no_bit(stash):
         rtol=3e-2, atol=2e-4)
     np.testing.assert_allclose(np.asarray(large[2]), np.asarray(ref_gw),
                                rtol=3e-2, atol=3e-4)
+
+
+# ------------- the backward's mode, stated by a caller and asked of the compile
+# (PR 51). Under ``STASH_BYTES_MAX`` nobody is asked and the automatic choice
+# is what it was (``AUTO_BLOCKS`` and ``CELL_PLANS`` above); over it a model's
+# ``ce_mode`` states the mode, and the trial runner states ``stash`` for a
+# grid point whose compiled program has room (``SPMDTechnique._head_rungs``;
+# the rungs themselves are ``tests/test_search_pipeline.py``'s).
+#: (tokens, d_model, vocab) -> the bf16 logits a stash-mode call would keep,
+#: None where an unasked call keeps them by itself (the first two are over
+#: the constant: GPT-J's and Ouro's cells; the third is gpt2-medium's)
+STASHES = {
+    (8192, 4096, 50400): 8192 * 50688 * 2,
+    (8192, 2048, 49152): 8192 * 49152 * 2,
+    (4096, 1024, 50257): None,
+    (2048, 4096, 50400): None,
+}
+
+
+@pytest.mark.parametrize("shape", list(STASHES))
+def test_the_constant_says_whose_mode_is_worth_asking_about(shape, monkeypatch):
+    from saturn_tpu.ops import ce
+
+    assert ce.stash_over_the_constant(*shape) is None   # off the TPU: no kernel
+    monkeypatch.setattr(ce, "_use_interpret", lambda: False)
+    assert ce.stash_over_the_constant(*shape) == STASHES[shape]
+    auto, kept = ce.ce_plan(*shape), ce.ce_plan(*shape, stash=True)
+    assert (auto.mode == "recompute") == (STASHES[shape] is not None)
+    assert ce.call_plan(*shape) == auto
+    # stating the mode the op would choose changes nothing of the plan
+    assert ce.ce_plan(*shape, stash=auto.mode == "stash") == auto
+    assert kept.mode == "stash"
+    assert (ce._stash_bytes(shape[0], shape[2], kept.blocks)
+            > ce.STASH_BYTES_MAX) == (STASHES[shape] is not None)
+
+
+def test_no_token_block_means_no_stash_to_ask_about(monkeypatch):
+    from saturn_tpu.ops import ce
+
+    monkeypatch.setattr(ce, "_use_interpret", lambda: False)
+    monkeypatch.setattr(ce, "STASH_BYTES_MAX", 0)
+    assert ce.stash_over_the_constant(100, 64, 256) is None
+    assert ce.stash_over_the_constant(128, 64, 256) == 128 * 256 * 2
+
+
+@pytest.mark.parametrize("mode", [None, "stash", "recompute"])
+@pytest.mark.parametrize("family", ["gpt2", "bert"])
+def test_a_models_ce_mode_reaches_the_plan(family, mode, monkeypatch):
+    """Both callers of the fused head hand ``ce_mode`` on; a model that states
+    nothing traces to the plan the op chooses (here ``stash``: 128 tokens x
+    256 columns), one that states ``recompute`` to the other."""
+    from saturn_tpu.models import bert, gpt2
+    from saturn_tpu.ops import ce, plans
+
+    monkeypatch.setattr(ce, "_use_interpret", lambda: False)
+    spec = (bert.build_bert("bert-test-tiny", ce_mode=mode) if family == "bert"
+            else gpt2.build_gpt2("test-tiny", ce_mode=mode))
+    assert spec.config.ce_mode == mode
+    params = jax.eval_shape(spec.init_fn, jax.random.PRNGKey(0))
+    tokens = jax.ShapeDtypeStruct((2, 64), jnp.int32)
+    with plans.traced() as got:
+        jax.make_jaxpr(jax.grad(spec.fused_loss_fn))(params, tokens)
+    (plan,) = got["ce"]
+    d, v = spec.config.d_model, spec.config.vocab_size
+    assert plan == ce.ce_plan(128, d, v, stash=ce.stash_of(mode))
+    assert plan.mode == (mode or "stash")
+
+
+def test_an_unknown_ce_mode_is_refused_where_the_model_is_built():
+    from saturn_tpu.models import gpt2
+    from saturn_tpu.ops import ce
+
+    with pytest.raises(ValueError, match="ce_mode"):
+        gpt2.build_gpt2("test-tiny", ce_mode="keep")
+    with pytest.raises(ValueError, match="ce_mode"):
+        ce.stash_of("keep")
+    assert [ce.stash_of(m) for m in (None, "stash", "recompute")] == [
+        None, True, False]
+
+
+def _lm_task(tmp_path, name="asked", preset="test-tiny", batch=2, **task_kw):
+    from saturn_tpu import HParams, Task
+    from saturn_tpu.data.lm_dataset import make_lm_dataset
+    from saturn_tpu.models import gpt2
+    from saturn_tpu.models.loss import pretraining_loss
+
+    kw = dict(
+        get_model=lambda **kw: gpt2.build_gpt2(preset, **kw),
+        get_dataloader=lambda: make_lm_dataset(
+            context_length=64, batch_size=batch, vocab_size=256,
+            n_tokens=64 * batch * 16),
+        loss_fn=pretraining_loss,
+        hparams=HParams(lr=1e-3, batch_count=16),
+        save_dir=str(tmp_path / f"ckpts-{name}"), name=name)
+    kw.update(task_kw)
+    return Task(**kw)
+
+
+HBM = 1 << 30
+
+
+@pytest.fixture()
+def asked(monkeypatch):
+    """A chip of 1 GiB, kernels that "lower", and a constant so small that
+    the tiny model's 64 KiB of logits are over it."""
+    from saturn_tpu.ops import ce
+
+    monkeypatch.setenv("SATURN_TPU_HBM_BYTES", str(HBM))
+    monkeypatch.setattr(ce, "_use_interpret", lambda: False)
+    monkeypatch.setattr(ce, "STASH_BYTES_MAX", 1024)
+
+
+def _state_bytes(task):
+    params = task.get_model().abstract_init()
+    state = (params, jax.eval_shape(task.hparams.make_optimizer().init, params))
+    return sum(int(np.prod(x.shape)) * x.dtype.itemsize
+               for x in jax.tree_util.tree_leaves(state))
+
+
+def test_head_rungs_of_a_head_over_the_constant(tmp_path, asked, monkeypatch,
+                                                devices8):
+    from saturn_tpu.parallel.dp import DataParallel
+
+    task = _lm_task(tmp_path, batch=4)
+    tech = DataParallel()
+    for config in ({"remat": False}, {"remat": True, "attention": "dense"}):
+        assert tech._head_rungs(task, devices8[:1], config) == {
+            "stash_bytes": 4 * 64 * 256 * 2}
+    # under dp's shard_map the head sees the shard's tokens; the state is whole
+    assert tech._head_rungs(task, devices8[:2], {"remat": False}) == {
+        "stash_bytes": 2 * 64 * 256 * 2}
+    for n in (1, 2):
+        assert tech._room_after_state(task, devices8[:n]) \
+            == int(0.92 * HBM) - _state_bytes(task)
+    # no limit known (the CPU reports none), or a state that cannot be traced:
+    # no static bound, the compile says
+    monkeypatch.delenv("SATURN_TPU_HBM_BYTES")
+    assert tech._room_after_state(task, devices8[:1]) is None
+    monkeypatch.setenv("SATURN_TPU_HBM_BYTES", str(HBM))
+    broken = _lm_task(tmp_path, name="broken", get_model=_cannot_be_built)
+    assert tech._room_after_state(broken, devices8[:1]) is None
+
+
+def _tiny(preset="test-tiny", **fixed):
+    from saturn_tpu.models import gpt2
+
+    return lambda **kw: gpt2.build_gpt2(preset, **{**fixed, **kw})
+
+
+def _without_the_field(**kw):
+    import types
+
+    spec = _tiny()(**kw)
+    spec.config = types.SimpleNamespace(d_model=64, vocab_size=256)
+    return spec
+
+
+def _cannot_be_built(**kw):
+    raise RuntimeError("no such checkpoint")
+
+
+def _untagged_loss(logits, batch):
+    from saturn_tpu.models.loss import pretraining_loss
+
+    return pretraining_loss(logits, batch)
+
+
+#: why nobody is asked -> (technique, chips, grid config, what the task is
+#: made with beside ``_lm_task``'s own); the last two are the op's own reasons
+NOBODY_ASKED = {
+    "the-config-states-it": ("dp", 1, {"remat": False, "ce_mode": "recompute"}, {}),
+    "the-users-kwargs-state-it": ("dp", 1, {"remat": False}, {
+        "get_model": _tiny(ce_mode="recompute")}),
+    "a-model-without-a-fused-head": ("dp", 1, {"remat": False}, {
+        "get_model": _tiny("moe-test-tiny")}),
+    "a-model-that-takes-no-ce-mode": ("dp", 1, {"remat": False}, {
+        "get_model": _without_the_field}),
+    "a-model-that-cannot-be-built": ("dp", 1, {"remat": False}, {
+        "get_model": _cannot_be_built}),
+    "a-loss-the-head-does-not-compute": ("dp", 1, {"remat": False}, {
+        "loss_fn": _untagged_loss}),
+    "a-technique-that-shards-the-vocabulary": ("tp", 1, {"remat": False}, {}),
+    "params-sharded-over-chips": ("fsdp", 2, {"remat": False}, {}),
+    "state-in-host-memory": ("fsdp", 1, {"remat": False, "offload": True}, {}),
+    "under-the-constant": ("dp", 1, {"remat": False}, {}),
+    "no-kernel-on-this-backend": ("dp", 1, {"remat": False}, {}),
+}
+
+
+@pytest.mark.parametrize("case", list(NOBODY_ASKED))
+def test_head_rungs_is_none_where_nobody_is_asked(case, tmp_path, monkeypatch,
+                                                  devices8):
+    from saturn_tpu.ops import ce
+    from saturn_tpu.parallel import BUILTIN_TECHNIQUES
+
+    monkeypatch.setenv("SATURN_TPU_HBM_BYTES", str(HBM))
+    monkeypatch.setattr(ce, "STASH_BYTES_MAX",
+                        ce.STASH_BYTES_MAX if case == "under-the-constant" else 1024)
+    monkeypatch.setattr(ce, "_use_interpret",
+                        lambda: case == "no-kernel-on-this-backend")
+    name, n, config, task_kw = NOBODY_ASKED[case]
+    task = _lm_task(tmp_path, **task_kw)
+    tech = BUILTIN_TECHNIQUES[name]()
+    assert tech._head_rungs(task, devices8[:n], config) is None
+    if case in ("under-the-constant", "no-kernel-on-this-backend"):
+        # ... and the same point is asked about once the op's reason is gone
+        monkeypatch.setattr(ce, "STASH_BYTES_MAX", 1024)
+        monkeypatch.setattr(ce, "_use_interpret", lambda: False)
+        assert tech._head_rungs(task, devices8[:n], config) is not None
+
+
+@pytest.fixture()
+def kernels_in_interpret_mode(asked, monkeypatch):
+    """``asked``, and every fused call runs its kernels in interpret mode, so
+    that a whole search compiles and runs here."""
+    import functools
+
+    from saturn_tpu.ops import ce
+
+    monkeypatch.setattr(ce, "fused_linear_cross_entropy", functools.partial(
+        ce.fused_linear_cross_entropy, interpret=True))
+
+
+def _jaxpr_text(bundle):
+    import re
+
+    return re.sub(r"0x[0-9a-f]+", "0x", str(bundle.traced["jaxpr"]))
+
+
+@pytest.mark.parametrize("rung", ["fits", "over"])
+def test_a_search_asks_the_compile_and_its_config_says_what_it_was_told(
+        rung, tmp_path, kernels_in_interpret_mode, monkeypatch, devices8):
+    """dp's own search over one grid point of a real (tiny) model. Where the
+    stash rung fits: one build, the returned config carries ``ce_mode``, the
+    ``trial_config`` event's ``ce_plan`` reads ``stash``, and a technique
+    that knows nothing but that config (``execute()`` in a later process)
+    builds the program the trial timed, text for text. Where the memory rule
+    rejects it: a second build, the point timed as the grid has it."""
+    from saturn_tpu.parallel.dp import DataParallel
+    from saturn_tpu.utils import metrics
+
+    class OnePoint(DataParallel):
+        built = None
+
+        def candidate_configs(self, task, n_devices):
+            return [{"remat": False}]
+
+        def _build_uncached(self, task, devices, config):
+            self.built.append(dict(config))   # a Python trace of the step
+            return super()._build_uncached(task, devices, config)
+
+        def _fits_compiled(self, compiled, devices, *, config=None, said=None,
+                           **kw):
+            if rung == "over" and config.get("ce_mode") == "stash":
+                said.update(need_bytes=HBM, limit_bytes=HBM)
+                return False
+            return super()._fits_compiled(compiled, devices, config=config,
+                                          said=said, **kw)
+
+    monkeypatch.setenv("SATURN_TPU_MAX_WINDOW", "2")
+    task = _lm_task(tmp_path, name=f"asked-{rung}")
+    tech = OnePoint()
+    tech.built = []
+    path = str(tmp_path / "events.jsonl")
+    with metrics.scoped(path):
+        config, per_batch = tech.search(task, devices8[:1], 0)
+    (note,) = metrics.read_events(path, kind="trial_config")
+    (span,) = metrics.read_events(path, kind="trial.config")
+    stash = {"remat": False, "ce_mode": "stash"}
+    if rung == "fits":
+        assert config == stash and tech.built == [stash]
+        assert note["ce_plan"]["mode"] == "stash"
+        assert note["ce_ladder"]["tried"] == ["stash"]
+        assert note["ce_ladder"]["kept"] == "stash"
+    else:
+        assert config == {"remat": False}
+        assert tech.built == [stash, {"remat": False}]
+        assert note["ce_plan"]["mode"] == "recompute"
+        assert note["ce_ladder"]["tried"] == ["stash", "recompute"]
+        assert note["ce_ladder"]["kept"] == "recompute"
+        assert note["ce_ladder"]["refused"] == {
+            "outcome": "memory_rejected", "need_bytes": HBM, "limit_bytes": HBM}
+    assert note["config"] == span["config"] == config
+    assert note["ce_ladder"] == span["ce_ladder"]
+    assert note["ce_ladder"]["stash_bytes"] == 2 * 64 * 256 * 2
+    assert note["ce_ladder"]["room_bytes"] == int(0.92 * HBM) - _state_bytes(task)
+    assert span["outcome"] == "timed" and note["per_batch_s"] == per_batch > 0
+    assert note["step_traces"] == 1 and "memory_rejected" not in note
+    # the config alone gives the program again
+    timed = tech._cached_bundle(task, devices8[:1], config)
+    again = DataParallel().build(task, devices8[:1], dict(config))
+    assert again is not timed and _jaxpr_text(again) == _jaxpr_text(timed)
+    assert again.plans["ce"][0].mode == note["ce_plan"]["mode"]
+    other = DataParallel().build(
+        task, devices8[:1], stash if rung == "over" else {"remat": False})
+    assert _jaxpr_text(other) != _jaxpr_text(timed)

@@ -132,7 +132,17 @@ class Bundle:
     def has_fused(self, k):
         return self._program is not None
 
+    @property
+    def plans(self):
+        """What the step's ops were traced as: a fused head in the mode the
+        stub says (``recompute`` if it says nothing), none where ``inert``."""
+        if self.point.get("inert"):
+            return {}
+        return {"ce": (types.SimpleNamespace(
+            mode=self.point.get("mode", "recompute")),)}
+
     def fused_compiled(self, k):
+        self.book.log("compile", self.point)
         if self.point.get("compile") == "refuse":
             raise aot_cache.CompileRefused(
                 "RESOURCE_EXHAUSTED: the program needs 17.1G of 15.7G hbm",
@@ -186,10 +196,13 @@ class Stubbed(SPMDTechnique):
                 for p in self.points]
 
     def build(self, task, devices, config, use_cache=True):
-        hit = self._built.get(config["id"])
+        (point,) = [p for p in self.points if p["id"] == config["id"]]
+        return self._bundle_of(point)
+
+    def _bundle_of(self, point):
+        hit = self._built.get(point["id"])
         if hit is not None:
             return hit
-        (point,) = [p for p in self.points if p["id"] == config["id"]]
         self.book.log("build", point)
         time.sleep(point.get("build_s", 0.0))
         how = point.get("build")
@@ -199,8 +212,8 @@ class Stubbed(SPMDTechnique):
             raise ValueError("kernel variant failed to lower")
         if how == "kill":
             raise SimulatedKill("killed while building")
-        self._built[config["id"]] = Bundle(point, self.book)
-        return self._built[config["id"]]
+        self._built[point["id"]] = Bundle(point, self.book)
+        return self._built[point["id"]]
 
 
 @pytest.fixture()
@@ -208,8 +221,8 @@ def run(tmp_path, monkeypatch):
     """search(points) -> (technique, winner, report, events)."""
     monkeypatch.setenv("SATURN_TPU_HBM_BYTES", str(HBM))
 
-    def go(points, name="piped"):
-        tech = Stubbed(points)
+    def go(points, name="piped", technique=Stubbed):
+        tech = technique(points)
         path = str(tmp_path / f"{name}.jsonl")
         with metrics.scoped(path):
             with metrics.span("search"):
@@ -1026,3 +1039,249 @@ def test_clocks_side_by_side_put_the_settings_back_once():
     assert seen["inside"] == ((*before[0][:2], timing._NO_FULL_COLLECTION),
                               timing._SWITCH_INTERVAL_S)
     assert clock_settings() == before
+
+
+# ------------- the fused head's rungs: stash first, recompute if refused (PR 51)
+MB = 1 << 20
+
+
+class Laddered(Stubbed):
+    """A grid of points whose fused head would stash ``head`` bytes (a point
+    that says none has a head nobody is asked about). A point is found by its
+    ``attention`` and ``remat``, as ``Twinned``'s; built with ``ce_mode:
+    "stash"`` it is another program, which ends as the point's ``stash``
+    entry says and is booked as ``<id>+stash``."""
+
+    def candidate_configs(self, task, n_devices):
+        return [{k: p[k] for k in ("attention", "remat")} for p in self.points]
+
+    def _point(self, config):
+        (point,) = [p for p in self.points if (p["attention"], p["remat"])
+                    == (config["attention"], config["remat"])]
+        return point
+
+    def _head_rungs(self, task, devices, config):
+        point = self._point(config)
+        if config.get("ce_mode") or "head" not in point:
+            return None
+        return {"stash_bytes": point["head"]}
+
+    def _room_after_state(self, task, devices):
+        self.book.log("room", self.points[0])   # the task's: once a search
+        return self.points[0].get("room", HBM // 2)
+
+    def build(self, task, devices, config, use_cache=True):
+        point = self._point(config)
+        if config.get("ce_mode") == "stash":
+            point = {"mode": "stash", **point, **point["stash"],
+                     "id": point["id"] + "+stash"}
+        return self._bundle_of(point)
+
+
+def laddered(point_over, **more):
+    return {"id": "a", "attention": "flash", "remat": True, "head": 300 * MB,
+            "step_s": 0.03, **point_over, **more}
+
+
+def only(events, kind):
+    (e,) = of_kind(events, kind)
+    return e
+
+
+def test_a_stash_rung_that_fits_is_the_points_one_program(run):
+    tech, (config, t), report, events = run(
+        [laddered({"stash": {"step_s": 0.01}})], technique=Laddered)
+    # one build, one compile, one timed program: the stashing one
+    assert tech.book.order("build") == tech.book.order("compile") == ["a+stash"]
+    assert set(tech.book.order("step")) == {"a+stash"}
+    # ... and the config the search returns says so, like a pinned attention
+    assert config == {"attention": "flash", "remat": True, "ce_mode": "stash"}
+    assert 0.01 / 8 <= t < 0.03 / 8
+    note, span = only(events, "trial_config"), only(events, "trial.config")
+    assert note["config"] == span["config"] == config
+    assert note["ce_ladder"] == span["ce_ladder"] == {
+        "stash_bytes": 300 * MB, "room_bytes": HBM // 2,
+        "tried": ["stash"], "kept": "stash"}
+    assert span["outcome"] == "timed" and note["per_batch_s"] == t
+    assert report["configs"] == 1 and report["memory_rejected"] == 0
+    assert report["errors"] == 0
+    assert no_measuring_thread_left()
+
+
+#: how the stash rung is lost -> what ``ce_ladder.refused`` says of it
+LOST = {
+    "over": ({"need": HBM}, {"outcome": "memory_rejected", "need_bytes": HBM,
+                             "limit_bytes": HBM}),
+    "refused": ({"compile": "refuse"}, {
+        "outcome": "refused", "refusal": "recorded",
+        "compiler": "RESOURCE_EXHAUSTED: the program needs 17.1G of 15.7G hbm"}),
+    "inert": ({"inert": True}, {"outcome": "inert"}),
+}
+
+
+@pytest.mark.parametrize("case", sorted(LOST))
+def test_a_stash_rung_that_is_refused_is_no_refused_point(case, run):
+    """The rung over the rule, refused by the compiler, or traced to no
+    stashing head: the point is built again as the grid has it and *that*
+    program is timed. One grid point, one ``trial_config`` event that counts
+    no refusal (the benchmark's ``search_refused_per_job`` reads
+    ``memory_rejected`` / ``error`` keys), one timed program."""
+    stash, said = LOST[case]
+    tech, (config, _), report, events = run(
+        [laddered({"stash": stash})], technique=Laddered)
+    assert tech.book.order("build") == ["a+stash", "a"]
+    # a rung that traced to no stashing head is not compiled
+    assert tech.book.order("compile") == (
+        ["a"] if case == "inert" else ["a+stash", "a"])
+    assert set(tech.book.order("step")) == {"a"}
+    assert tech.book.order("init_compile") == ["a"]
+    assert config == {"attention": "flash", "remat": True}
+    note, span = only(events, "trial_config"), only(events, "trial.config")
+    assert note["config"] == span["config"] == config
+    assert note["ce_ladder"] == span["ce_ladder"] == {
+        "stash_bytes": 300 * MB, "room_bytes": HBM // 2,
+        "tried": ["stash", "recompute"], "kept": "recompute", "refused": said}
+    assert span["outcome"] == "timed" and "per_batch_s" in note
+    assert not {"memory_rejected", "error", "refusal"} & set(note)
+    assert report["configs"] == 1 and report["memory_rejected"] == 0
+    assert report["refusals_fresh"] == report["refusals_replayed"] == 0
+    assert report["errors"] == 0
+    # both rungs' host work lies under the point's one span
+    assert Counter(e["kind"] for e in events if e.get("parent") == span["id"]
+                   and e["kind"] in ("trial.build", "trial.memory_check")) == {
+        "trial.build": 2, "trial.memory_check": 1 if case != "over" else 2}
+    assert no_measuring_thread_left()
+
+
+def test_a_refused_rung_on_record_ends_unbuilt(run, recorded):
+    """The rung's verdict is a point record like any other (its identity
+    holds the rung's config): the next search goes straight to the rung that
+    fits. A rung that fits leaves none."""
+    grid = [laddered({"stash": {"need": HBM}}),
+            laddered({"stash": {"step_s": 0.02}}, id="b", attention="dense")]
+    tech, best, report, _ = run(grid, name="first", technique=Laddered)
+    assert tech.book.order("build") == ["a+stash", "a", "b+stash"]
+    assert len(recorded()) == 1 and report["refusals_unbuilt"] == 0
+
+    tech, (config, _), report, events = run(grid, name="second",
+                                            technique=Laddered)
+    assert tech.book.order("build") == ["a", "b+stash"]
+    notes = {e["config"]["attention"]: e for e in of_kind(events, "trial_config")}
+    assert notes["flash"]["ce_ladder"] == {
+        "stash_bytes": 300 * MB, "room_bytes": HBM // 2, "skipped": "recorded",
+        "tried": ["recompute"], "kept": "recompute"}
+    assert "unbuilt" not in notes["flash"] and "per_batch_s" in notes["flash"]
+    assert notes["dense"]["ce_ladder"]["kept"] == "stash"
+    assert config == {"attention": "dense", "remat": True, "ce_mode": "stash"}
+    # the point was built (its other rung): nothing of it counts as unbuilt
+    assert report["refusals_unbuilt"] == 0 and report["memory_rejected"] == 0
+    identities = of_kind(events, "trial.identity")
+    assert [e["hit"] for e in identities] == [True, False, False]
+    assert len(recorded()) == 1
+    assert no_measuring_thread_left()
+
+
+def test_a_stale_rung_record_costs_a_rung_never_a_job(run, recorded):
+    """Both rungs of the only point on record and nothing timed: the point
+    runs again in full, the stash rung included."""
+    over = [laddered({"need": HBM, "stash": {"need": HBM}})]
+    run(over, name="first", technique=Laddered)
+    assert len(recorded()) == 2
+    tech, best, report, events = run(over, name="second", technique=Laddered)
+    assert best == (None, None) and report["memory_infeasible"] is True
+    assert tech.book.order("build") == ["a+stash", "a"]
+    first, again = of_kind(events, "trial_config")
+    assert first["unbuilt"] is True and first["ce_ladder"]["skipped"] == "recorded"
+    assert first["ce_ladder"]["tried"] == [] and first["ce_ladder"]["kept"] is None
+    assert "unbuilt" not in again and again["ce_ladder"]["tried"] == [
+        "stash", "recompute"]
+    assert again["memory_rejected"] is True and again["ce_ladder"]["kept"] is None
+
+    fits = [laddered({"stash": {"step_s": 0.01}})]
+    tech, (config, _), report, _ = run(fits, name="third", technique=Laddered)
+    # the rung that fits takes its record away; the other rung's stays until
+    # somebody asks about that rung again (it is not this point's program)
+    assert config["ce_mode"] == "stash" and len(recorded()) == 1
+    assert tech.book.order("build") == ["a+stash"]
+
+
+#: how the ``remat: True`` twin ends on its last rung -> how the point without
+#: remat ends: its stash rung is skipped either way (the stash adds the same
+#: bytes under either), it is implied over memory only by the twin's last word
+TWIN_LADDERS = {
+    "twin-recomputes": ({"step_s": 0.03}, "built"),
+    "twin-over": ({"need": HBM}, "implied"),
+}
+
+
+@pytest.mark.parametrize("case", sorted(TWIN_LADDERS))
+def test_remat_off_skips_the_rung_its_twin_lost(case, run):
+    last, want = TWIN_LADDERS[case]
+    tech, (config, _), report, events = run([
+        laddered({"stash": {"step_s": 0.0}}, id="plain", remat=False,
+                 step_s=0.02),
+        laddered({"stash": {"need": HBM}, **last}, id="frugal"),
+        laddered({"stash": {"step_s": 0.05}}, id="other", attention="dense",
+                 step_s=0.05),
+    ], technique=Laddered)
+    notes = {(e["config"]["attention"], e["config"]["remat"]): e
+             for e in of_kind(events, "trial_config")}
+    built = tech.book.order("build")
+    assert "plain+stash" not in built and built[:2] == ["frugal+stash", "frugal"]
+    assert notes["flash", True]["ce_ladder"]["refused"]["outcome"] == "memory_rejected"
+    if want == "built":
+        assert "plain" in built
+        assert notes["flash", False]["ce_ladder"] == {
+            "stash_bytes": 300 * MB, "room_bytes": HBM // 2, "skipped": "remat",
+            "tried": ["recompute"], "kept": "recompute"}
+        assert config == {"attention": "flash", "remat": False}
+        assert report["memory_rejected"] == 0
+    else:
+        assert "plain" not in built
+        assert notes["flash", False]["implied_by"] == "remat"
+        assert "ce_ladder" not in notes["flash", False]
+        assert config == {"attention": "dense", "remat": True, "ce_mode": "stash"}
+        assert report["memory_rejected"] == 2
+    # another ``attention`` is no twin: its rung was tried, and kept
+    assert notes["dense", True]["ce_ladder"]["tried"] == ["stash"]
+    assert len(tech.book.order("room")) == 1   # the state is the task's
+    assert report["configs"] == 3 and len(notes) == 3
+    assert no_measuring_thread_left()
+
+
+#: a point whose head gets no stash rung -> its ``ce_ladder`` (None: no key)
+NO_RUNG = {
+    "over-the-static-bound": (
+        {"head": 300 * MB, "room": 299 * MB, "stash": {"step_s": 0.0}},
+        {"stash_bytes": 300 * MB, "room_bytes": 299 * MB, "skipped": "static",
+         "tried": ["recompute"], "kept": "recompute"}),
+    "nobody-is-asked": ({"stash": {"step_s": 0.0}}, None),
+}
+
+
+@pytest.mark.parametrize("case", sorted(NO_RUNG))
+def test_no_rung_where_the_stash_cannot_fit_or_nobody_is_asked(case, run):
+    """A stash larger than what the rule leaves after the train state is not
+    worth a compile (a 128k-token job does not pay for a doomed one), and a
+    head under the constant, or no fused head, has ``_head_rungs`` None."""
+    over, want = NO_RUNG[case]
+    point = {"id": "a", "attention": "flash", "remat": True, "step_s": 0.01,
+             **over}
+    tech, (config, _), report, events = run([point], technique=Laddered)
+    assert tech.book.order("build") == tech.book.order("compile") == ["a"]
+    assert config == {"attention": "flash", "remat": True}
+    note = only(events, "trial_config")
+    assert note.get("ce_ladder") == want
+    assert only(events, "trial.config").get("ce_ladder") == want
+
+
+def test_a_stash_rung_that_raises_is_how_the_point_ended(run):
+    """Only memory takes a point to its next rung: a stashing kernel that
+    fails to lower must not lose to its recomputing twin in silence."""
+    tech, best, report, events = run(
+        [laddered({"stash": {"build": "raise"}})], technique=Laddered)
+    assert best == (None, None) and tech.book.order("build") == ["a+stash"]
+    assert report["errors"] == 1 and "failed to lower" in report["first_error"]
+    note = only(events, "trial_config")
+    assert note["ce_ladder"]["tried"] == ["stash"] and note["ce_ladder"]["kept"] is None
+    assert note["config"] == {"attention": "flash", "remat": True}

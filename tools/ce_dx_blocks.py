@@ -9,8 +9,10 @@ residuals; then ``saturn_ce_dx`` alone is timed at every block of the row
 asking the compiler for VMEM exactly as ``fused_linear_cross_entropy`` would
 (``_dx_vmem_limit``). ``dx`` at every block is compared bit for bit with the
 first block's. ``saturn_ce_dw`` and ``saturn_ce_fwd`` at the plan's own blocks
-are timed beside it. One JSON line a row on stdout and in
-``chiprun_out/ce_dx_blocks.jsonl``. No CPU branch: without a TPU it exits 1.
+are timed beside it, and the whole call (forward and both backward kernels in
+one program, as a step runs them: ``grad_ms``). One JSON line a row on stdout
+and in ``chiprun_out/ce_dx_blocks.jsonl``. No CPU branch: without a TPU it
+exits 1.
 """
 import json
 import os
@@ -31,6 +33,9 @@ ROWS = [
     ((8192, 2048, 49152, False), (256, 512)),             # ouro-2.6b-1chip
     ((2048, 4096, 50400, True), (128, 256, 512)),         # d 4096 under the stash threshold
     ((8192, 1024, 50257, False), (256, 512, 1024)),
+    # gptj-6b-1chip.steady again, in the mode the trial runner asks the
+    # compile about since PR 51 (a 0.77 GiB stash)
+    ((8192, 4096, 50400, True), (128, 256, 512)),
 ]
 PEAK_FLOPS, HBM_BYTES_PER_S = 197e12, 819e9
 
@@ -75,6 +80,12 @@ def main():
                 blocks, v, False, stash, limit, res_, g_)[pick])
 
         dw_ms = timed_ms(bwd(plan.blocks, plan.dx_vmem_limit, 1), res, g)
+        # the whole call as a step runs it: forward and both backward kernels
+        # in one program, the gradients of the mean loss
+        whole = jax.jit(jax.grad(lambda x_, w_: jnp.sum(ce._fused_ce(
+            x_, w_, lab, plan.blocks, v, False, stash, plan.dx_vmem_limit) * g),
+            argnums=(0, 1)))
+        grad_ms = timed_ms(whole, x, w)
         first = None
         for bn_dx in dx_blocks:
             blocks = plan.blocks[:4] + (bn_dx,)
@@ -98,6 +109,7 @@ def main():
                    "plan_bn_dx": plan.bn_dx, "vmem_limit": limit,
                    "dx_vmem": ce._dx_vmem(bn_dx, plan.bv, d, stash), **row,
                    "dw_ms": round(dw_ms, 3), "fwd_ms": round(fwd_ms, 3),
+                   "grad_ms": round(grad_ms, 3),
                    "device": dev.device_kind}
             line = json.dumps(row)
             print(line, flush=True)

@@ -10,7 +10,8 @@ Chip or fail, as ``perf/run.py`` is. A tree's first run compiles its
 programs into the XLA cache (run it once before the reading). Prints the
 harness's ``search: wall`` line, then for each grid point how it ended
 (``unbuilt`` where its point record ended it before anything was built, PR
-47) and what its ``trial.identity`` cost, then the spans of its
+47), what its ``trial.identity`` cost and what its fused head's rungs did
+(``ce_ladder``, PR 51: a refused rung's planned bytes), then the spans of its
 preparation (``trial.build`` / ``trial.compile`` / ``trial.memory_check``)
 with JAX's own seconds on them (``trace_s`` / ``lower_s`` / ``cache_read_s``
 / ``compile_s``, nested traces counted once) and the collector's, then the
@@ -83,6 +84,8 @@ def main() -> int:
             print(f"host: point {e.get('config')} ended {e.get('outcome')}"
                   f"{' unbuilt' if e.get('unbuilt') else ''} {how} in "
                   f"{e['dur_s']:.2f}s; trial.identity {cost}", flush=True)
+            if "ce_ladder" in e:   # the fused head's rungs (PR 51)
+                print(f"host:   ce_ladder {e['ce_ladder']}", flush=True)
         for e in sorted(spans, key=lambda e: e["ts_start"]):
             point = point_of(e)
             if point is None or e is point or not e["kind"].startswith("trial."):
