@@ -93,7 +93,8 @@ class ModelSpec:
     @property
     def stack_lead(self) -> Optional[Dict[str, int]]:
         """Layers before the scanned periods, by kind, e.g.
-        ``{"full_attention_dense": 1}``: ``hints["stack_lead"]``; they are in
+        ``{"full_attention_dense": 1}`` or ``{"conv_dense": 1}`` (the mixer's
+        kind before the dense MLP): ``hints["stack_lead"]``; they are in
         ``stack_layers``, not in a period, and run inside
         ``hints["pipeline"]["embed"]``. None where the stack has none."""
         lead = self.hints.get("stack_lead")

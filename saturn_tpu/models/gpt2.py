@@ -287,6 +287,22 @@ class GPT2Config:
     route_from: str = "ff_input"
     router_score: str = "sigmoid"
     rotary_kinds: Optional[Tuple[str, ...]] = None
+    # Short-convolution structure knobs (LFM2-class: three doubly gated
+    # short-convolution layers to one grouped-query attention layer, leading
+    # dense layers whose mixer is the convolution, routed SwiGLU experts
+    # after). Each at its default leaves every earlier preset's program
+    # unchanged op for op.
+    #   layer_types gains "conv": ``[B | C | u] = y W_in; out = (C * conv(B *
+    #     u)) W_out``, the convolution depthwise and causal over
+    #     ``conv_taps`` tokens, no activation, no heads
+    #     (``Block._short_conv_mixer``); ``lead_kind`` takes it too.
+    #   head_qk_norm on a softmax layer: an RMSNorm over each head's q and k
+    #     lanes (one gain of ``head_dim`` shared by the heads), under grouped
+    #     k/v too, before the rotation.
+    #   route_eps: added to the sum a token's chosen sigmoid scores are
+    #     normalised by (``ops/moe.py::RoutedPlan.eps``).
+    conv_taps: int = 3
+    route_eps: float = 0.0
     name: str = "gpt2-small"
 
     def __post_init__(self) -> None:
@@ -347,11 +363,11 @@ class GPT2Config:
             object.__setattr__(self, "layer_types", tuple(self.layer_types))
             kinds = set(self.layer_types)
             if not kinds or kinds - {"linear_attention", "full_attention",
-                                     "sliding_attention", "kda", "mla",
+                                     "sliding_attention", "kda", "mla", "conv",
                                      *MIXER_KINDS}:
                 raise ValueError(
                     f"layer_types holds 'linear_attention' / 'full_attention' "
-                    f"/ 'sliding_attention' / 'kda' / 'mla' / "
+                    f"/ 'sliding_attention' / 'kda' / 'mla' / 'conv' / "
                     f"{' / '.join(map(repr, MIXER_KINDS))}, "
                     f"got {self.layer_types!r}")
             if (self.n_layers - self.lead_layers) % len(self.layer_types) != 0:
@@ -381,6 +397,14 @@ class GPT2Config:
                     "a kda / mla layer is causal and single-program, one k/v "
                     "head a q head, with its own rotary (rotary=False) on an "
                     "even qk_rope_dim beside kv_latent >= 1 lanes of latent")
+            if "conv" in kinds | {self.lead_kind} and (
+                not self.causal or self.seq_axis is not None or self.moe
+                or self.held_heads is not None or self.conv_taps < 1
+            ):
+                raise ValueError(
+                    "a short-convolution layer is causal and single-program (it "
+                    "reads conv_taps - 1 >= 0 tokens back across a shard's edge) "
+                    "and holds all its channels (a share of them is not built)")
             if kinds & set(MIXER_KINDS) and (
                 not self.causal or self.seq_axis is not None or self.moe
                 or self.lead_layers or self.kind_heads is not None
@@ -411,9 +435,12 @@ class GPT2Config:
                     f"that divide each count, got {self.kind_heads!r}")
         if self.lead_layers and self.layer_types is None:
             raise ValueError("lead_layers precede a stack of layer_types")
-        if self.lead_kind not in ("full_attention", "kda"):
-            raise ValueError(f"lead_kind must be 'full_attention' or 'kda', "
-                             f"got {self.lead_kind!r}")
+        if self.lead_kind not in ("full_attention", "kda", "conv"):
+            raise ValueError(f"lead_kind must be 'full_attention', 'kda' or "
+                             f"'conv', got {self.lead_kind!r}")
+        if self.head_qk_norm and self.qk_norm:
+            raise ValueError("qk_norm (over all held heads' lanes together) and "
+                             "head_qk_norm (over each head's) are one or the other")
         if self.swiglu_limit:
             raise ValueError(
                 f"swiglu_limit {self.swiglu_limit}: an expert's clamp is not built "
@@ -508,8 +535,8 @@ class GPT2Config:
 
     @property
     def stack_lead(self) -> Optional[Dict[str, int]]:
-        """Layers before the scanned periods, by kind (full attention with
-        the dense MLP); None where the stack has none."""
+        """Layers before the scanned periods, by kind (``lead_kind``'s mixer
+        with the dense MLP); None where the stack has none."""
         return {self.lead_kind + "_dense": self.lead_layers} if self.lead_layers else None
 
     def example_inputs(self, batch_size: int = 1):
@@ -734,6 +761,39 @@ PRESETS: Dict[str, Dict[str, Any]] = {
         window=32, routed_experts=16, held_experts=4, top_k=4, expert_ff=32,
         expert_act="reglu", router_score="softmax", route_from="block_input",
     ),
+    # LFM2-8B-A1B (LiquidAI/LFM2-8B-A1B, ``lfm2_moe``): 24 layers, 18 doubly
+    # gated short convolutions (3 taps, no bias, no activation) and 6
+    # grouped-query attention layers (32 q heads over 8 k/v heads of 64, an
+    # RMSNorm a head on q and k before a rotation of all 64 lanes at base
+    # 1e6). Layers 0 and 1 are dense (a conv mixer before a SwiGLU of 7168),
+    # every later feed-forward 32 routed SwiGLU experts of 1792: top-4 of
+    # sigmoid scores under a selection bias, the weights normalised over the
+    # chosen (+ 1e-6), scaled 1, no shared expert. RMSNorm (eps 1e-5) before
+    # each branch, no bias, the head tied to the embedding. The published
+    # order after the two leading layers is four periods of full, conv, conv,
+    # conv (layers 2..17) and then full, conv, conv, full, conv, conv (18..23):
+    # a tail of two periods of *three*. A stack here is 2 + 4 p layers: 18, the
+    # published 24 less that tail (periods of another length after the last
+    # whole one are not built: ROADMAP.md, Reach; ``build_lfm2`` refuses a
+    # depth that reaches them).
+    "lfm2-8b-a1b": dict(
+        d_model=2048, n_layers=18, n_heads=32, n_kv_heads=8, d_ff=7168,
+        vocab_size=65536, rotary=True, rope_theta=1e6, norm="rmsnorm",
+        norm_eps=1e-5, mlp_act="swiglu", use_bias=False, head_qk_norm=True,
+        lead_layers=2, lead_kind="conv",
+        layer_types=("full_attention",) + ("conv",) * 3, conv_taps=3,
+        routed_experts=32, top_k=4, expert_ff=1792, routed_scale=1.0,
+        router_bias=True, route_eps=1e-6,
+    ),
+    "lfm2-test-tiny": dict(
+        d_model=64, n_layers=5, n_heads=8, n_kv_heads=2, d_ff=128,
+        vocab_size=256, seq_len=64, rotary=True, rope_theta=1e6,
+        norm="rmsnorm", norm_eps=1e-5, mlp_act="swiglu", use_bias=False,
+        head_qk_norm=True, lead_layers=1, lead_kind="conv",
+        layer_types=("full_attention",) + ("conv",) * 3, conv_taps=3,
+        routed_experts=16, held_experts=4, top_k=4, expert_ff=32,
+        routed_scale=1.0, router_bias=True, route_eps=1e-6,
+    ),
     # Switch-style MoE family (extension beyond the reference; SURVEY.md §2.3
     # lists EP as absent there).
     "moe-test-tiny": dict(
@@ -884,8 +944,10 @@ class Block(nn.Module):
     parallel form (one ln, attn and mlp added together). ``rotary=True``
     rotates the first ``rotary_dim`` q/k dims by position.
 
-    ``kind`` chooses the mixer: softmax attention, or the gated delta rule of
-    ``ops/gdn.py`` behind a short convolution (``_linear_mixer``). With
+    ``kind`` chooses the mixer: softmax attention, the gated delta rule of
+    ``ops/gdn.py`` behind a short convolution (``_linear_mixer``), Kimi delta
+    attention, latent attention, or the doubly gated short convolution alone
+    (``_short_conv_mixer``). With
     ``held_heads`` the mixer computes the held heads' part of its output:
     the projections' columns and ``attn_out``'s rows of those heads, and
     nothing that stands in for the heads held elsewhere."""
@@ -920,6 +982,8 @@ class Block(nn.Module):
             attn = self._kda_mixer(h, dense)
         elif self.kind == "mla":
             attn = self._mla_mixer(h, dense, make_norm)
+        elif self.kind == "conv":
+            attn = self._short_conv_mixer(h, dense)
         else:
             attn = self._softmax_mixer(h, dense, make_norm)
         attn = dense(D, "attn_out")(attn)
@@ -992,6 +1056,11 @@ class Block(nn.Module):
 
         q = heads(q, n_q)
         k, v = heads(k, kv_heads), heads(v, kv_heads)
+        if cfg.head_qk_norm:
+            # over a head's lanes, one gain shared by the heads (the q heads'
+            # and the fewer k/v heads' alike), before the rotation
+            q = self._head_norm(q, "q_norm", cfg.head_dim)
+            k = self._head_norm(k, "k_norm", cfg.head_dim)
         if cfg.rotary_kinds is not None:
             from saturn_tpu.ops import plans
 
@@ -1116,6 +1185,51 @@ class Block(nn.Module):
         out = nn.silu(sum(w[j] * padded[:, j:j + T] for j in range(taps)))
         return out.reshape(B, T, H, width).transpose(0, 2, 1, 3)
 
+    def _head_norm(self, t, name, lanes):
+        """An RMSNorm over the last axis of ``t`` (a head's ``lanes``) under
+        one gain ``name`` of ``lanes`` entries shared by the heads, float32."""
+        cfg, f32 = self.cfg, jnp.float32
+        eps = 1e-6 if cfg.norm_eps is None else cfg.norm_eps
+        gain = self.param(name, nn.initializers.ones, (lanes,), cfg.param_dtype)
+        t = t.astype(f32)
+        t = t * jax.lax.rsqrt(jnp.mean(t * t, axis=-1, keepdims=True) + eps)
+        return (t * gain.astype(f32)).astype(cfg.dtype)
+
+    def _short_conv_mixer(self, h, dense):
+        """(B, T, D) -> the doubly gated short convolution's output (B, T, D),
+        before ``attn_out`` (its ``W_out``):
+
+            B = h W_b;  C = h W_c;  u = h W_x       (``W_in`` = [W_b | W_c | W_x])
+            s = B * u
+            c_t = sum_j w_j * s_{t - (taps - 1) + j}    depthwise, causal
+            out = C * c
+
+        ``s`` is zero before the sequence; the last tap multiplies the token
+        itself; no activation, no heads, no bias. The three blocks of the
+        input projection are three kernels (``conv_b`` / ``conv_c`` /
+        ``conv_x``): the tensor-parallel column rule shards each one's
+        channels, so a channel's B, C and u lie on one shard and the gates and
+        the depthwise convolution are local (a fused contiguous split would
+        put all of B on the first shards: the comment on ``mlp_gate``). Gates
+        and taps' products float32, the projections in ``cfg.dtype``. Plain
+        XLA ops: no kernel of it."""
+        from saturn_tpu.ops import plans
+
+        cfg = self.cfg
+        f32 = jnp.float32
+        _, T, D = h.shape
+        taps = cfg.conv_taps
+        plans.record("conv", {
+            "impl": "xla", "taps": taps, "channels": D,
+            "layers_a_period": (cfg.layer_types or ()).count("conv"),
+            "layers_in_the_lead": cfg.lead_layers if cfg.lead_kind == "conv" else 0})
+        gate_in, gate_out, u = (dense(D, "conv_" + which)(h).astype(f32)
+                                for which in "bcx")
+        w = self.param("conv_w", _tap_init(taps), (taps, D), cfg.param_dtype).astype(f32)
+        padded = jnp.pad(gate_in * u, ((0, 0), (taps - 1, 0), (0, 0)))
+        conv = sum(w[j] * padded[:, j:j + T] for j in range(taps))
+        return (gate_out * conv).astype(cfg.dtype)
+
     def _head_gate(self, attn, h, dense, n_heads):
         """``attn`` (B, H, T, lanes) times a sigmoid gate a head from the
         block's normed input (``attn_gate``, d_model -> heads), float32."""
@@ -1195,15 +1309,8 @@ class Block(nn.Module):
             [kvb[..., :dn], jnp.broadcast_to(kva[:, :, None, L:], (B, T, H, dr))], axis=-1)
         v = kvb[..., dn:]
         if cfg.head_qk_norm:
-            eps = 1e-6 if cfg.norm_eps is None else cfg.norm_eps
-
-            def head_norm(t, name):
-                gain = self.param(name, nn.initializers.ones, (dn + dr,), pdt)
-                t = t.astype(f32)
-                t = t * jax.lax.rsqrt(jnp.mean(t * t, axis=-1, keepdims=True) + eps)
-                return (t * gain.astype(f32)).astype(dt)
-
-            q, k = head_norm(q, "q_norm"), head_norm(k, "k_norm")
+            q = self._head_norm(q, "q_norm", dn + dr)
+            k = self._head_norm(k, "k_norm", dn + dr)
         sin, cos = rotary_sin_cos(jnp.arange(T), dr, cfg.rope_theta)
 
         def turned(t):      # (B, T, H, dn + dr) -> (B, H, T, dn + dr)
@@ -1242,7 +1349,7 @@ class Block(nn.Module):
             act=cfg.expert_act, latent=cfg.latent_dim, bias=cfg.router_bias,
             buffer=cfg.routed_buffer, groups=cfg.route_groups,
             groups_kept=cfg.route_groups_kept, score=cfg.router_score,
-            route_from=cfg.route_from)
+            route_from=cfg.route_from, eps=cfg.route_eps)
 
     def _router(self, D: int):
         """(the router's matrix, its selection bias or None)."""
@@ -1475,7 +1582,7 @@ class PeriodBlock(nn.Module):
 
 class LeadBlocks(nn.Module):
     """The ``cfg.lead_layers`` layers before the scanned periods, each a
-    full-attention (``cfg.lead_kind``) :class:`Block` with the dense MLP under the name
+    :class:`Block` of ``cfg.lead_kind``'s mixer with the dense MLP under the name
     ``l<i>``; rematerialised one by one under remat, like a period's."""
 
     cfg: GPT2Config
@@ -1774,11 +1881,12 @@ def build_gpt2(
         "embed_param_keys": ("wte", "wpe") if has_wpe else ("wte",),
         # factory accepts seq_axis/seq_axis_size; the sharded attention +
         # boundary-label loss assume causal next-token training. A linear
-        # layer's state crosses the whole sequence: not sequence-parallel.
+        # layer's state crosses the whole sequence, a convolution reads
+        # tokens back across a shard's edge: not sequence-parallel.
         # Nor is a sliding layer's mask or a routed layer (the configuration
         # refuses them a sequence axis).
         "seq_parallel": cfg.causal and not cfg.routed_experts and not (
-            {"linear_attention", "mamba2", "kda", "sliding_attention"}
+            {"linear_attention", "mamba2", "kda", "sliding_attention", "conv"}
             & set(cfg.layer_types or ())),
         "pipeline": {
             "embed": pipeline_embed,
@@ -1884,6 +1992,34 @@ def build_smallthinker(name: str = "smallthinker-21b", **overrides) -> ModelSpec
     under it after (``experts_under``). Same ``ModelSpec`` contract as
     :func:`build_gpt2`: the scanned unit, and ``hints["pipeline"]``'s
     ``block``, is one period (a route never leaves its block)."""
+    return build_gpt2(name, **overrides)
+
+
+#: the published layers a ``lfm2-8b-a1b`` stack can hold: the two leading
+#: dense layers and the four whole periods of four that follow them
+LFM2_WHOLE_PERIODS = 18
+
+
+def build_lfm2(name: str = "lfm2-8b-a1b", **overrides) -> ModelSpec:
+    """LFM2 factory: leading dense layers outside the scan (param key
+    ``lead``: a doubly gated short convolution before a SwiGLU), then periods
+    of one grouped-query attention layer (an RMSNorm a head on q and k before
+    the rotation) and three short-convolution layers
+    (``Block._short_conv_mixer``: plain XLA ops), each before the held share
+    of top-k routed SwiGLU experts chosen by sigmoid scores under a selection
+    bias (``ops/moe.py::routed_experts``), no shared expert, the head tied to
+    the embedding. A depth past the published model's last whole period of
+    four is refused: its last six layers are two periods of three. Same
+    ``ModelSpec`` contract as :func:`build_gpt2`: the scanned unit, and
+    ``hints["pipeline"]``'s ``block``, is one period; its ``embed`` runs the
+    leading layers."""
+    depth = overrides.get("n_layers", PRESETS[name]["n_layers"]) if name in PRESETS else 0
+    if name == "lfm2-8b-a1b" and depth > LFM2_WHOLE_PERIODS:
+        raise ValueError(
+            f"n_layers {depth}: the published stack's layers 18..23 are full, "
+            "conv, conv, full, conv, conv, two periods of three after four of "
+            f"four; a stack here is whole periods of one length, {LFM2_WHOLE_PERIODS} "
+            "layers at the most")
     return build_gpt2(name, **overrides)
 
 

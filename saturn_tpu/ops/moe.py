@@ -18,7 +18,7 @@ from __future__ import annotations
 import functools
 import math
 from dataclasses import asdict, dataclass
-from typing import Any, Dict, Optional, Tuple
+from typing import Any, Dict, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -132,6 +132,8 @@ class RoutedPlan:
     route_from: str = "ff_input"  # the rows the router reads: the experts' own
     #                               ("ff_input") or the block's input, ahead of
     #                               its mixer and un-normed ("block_input")
+    eps: float = 0.0      # added to the sum the chosen sigmoid scores are
+    #                       normalised by (0: the bare sum)
 
     @property
     def second_path(self) -> bool:
@@ -163,7 +165,7 @@ def routed_plan(tokens: int, experts: int, held: int, top_k: int, *,
                 impl: str = "xla", act: str = "swiglu", latent: int = 0,
                 bias: bool = False, groups: int = 0,
                 groups_kept: int = 0, score: str = "sigmoid",
-                route_from: str = "ff_input") -> RoutedPlan:
+                route_from: str = "ff_input", eps: float = 0.0) -> RoutedPlan:
     """``buffer`` (``BUFFER``) x the mean held pairs (tokens x top_k x held /
     experts), plus a tile an expert for the padding, capped at the worst case
     (every token's every choice held). ``groups`` / ``groups_kept``: the
@@ -177,6 +179,9 @@ def routed_plan(tokens: int, experts: int, held: int, top_k: int, *,
     if route_from not in ("ff_input", "block_input"):
         raise ValueError(
             f"route_from must be 'ff_input' or 'block_input', got {route_from!r}")
+    if eps and (eps < 0 or score != "sigmoid"):
+        raise ValueError(f"eps ({eps}) is a positive term of the sigmoid "
+                         "scores' normalising sum")
     if groups and (experts % groups or not 1 <= groups_kept <= groups
                    or top_k > groups_kept * (experts // groups)
                    or experts // groups < 2):
@@ -193,7 +198,7 @@ def routed_plan(tokens: int, experts: int, held: int, top_k: int, *,
     rows = min(worst, _round_up(int(math.ceil(buffer * mean)), row_tile) + pad)
     return RoutedPlan(impl, tokens, experts, held, top_k, row_tile, rows, worst,
                       act, latent, bias, groups, groups_kept if groups else 0,
-                      score, route_from)
+                      score, route_from, eps)
 
 
 def limited_choice(choice, groups: int, groups_kept: int):
@@ -231,13 +236,60 @@ def _gmm_kernel(te_ref, na_ref, x_ref, w_ref, o_ref, *, transpose_rhs):
         o_ref[...] = jnp.zeros_like(o_ref)
 
 
+class GmmPlan(NamedTuple):
+    """How ``saturn_gmm_fwd`` / ``_dx`` hold an expert's matrix (``gmm_plan``
+    on the ``trial_config`` event)."""
+
+    table_bytes: int            # one expert's matrix, the kernel's one block of it
+    vmem: int                   # what a grid step holds, by the shapes
+    vmem_limit: Optional[int]   # what the call asks the compiler for (None:
+    #                             the default scoped limit)
+
+
+#: the largest matrix a call holds double-buffered inside the compiler's
+#: default scoped VMEM (16 MiB a kernel on a v5e, ``ops/ce.py``): 3/8 of it,
+#: which leaves a quarter for the row tiles and the float32 product. The
+#: tables the cells ran before it are under it (2048 x 512: 2 MiB; 2560 x
+#: 768: 3.75; 1024 x 2688: 5.25) and keep the call they had; 2048 x 1792 is
+#: 7 MiB, 16.75 by the sum below, and asks: unasked the compiler refuses
+#: ``saturn_gmm_fwd`` inside the step program, on the chip and for a described
+#: v5e alike ("Scoped allocation with size 16.32M and limit 16.00M exceeded
+#: scoped vmem limit by 332.0K"; PERF.md, PR 52, call F), though the routed
+#: layer alone compiles unasked. A request is the sum and a quarter, in whole
+#: MiB (21-22).
+_GMM_TABLE_MAX = 6 << 20
+_GMM_REQUEST_MARGIN = 1.25
+
+
+def gmm_plan(row_tile: int, lanes_in: int, lanes_out: int, table: int,
+             itemsize: int) -> GmmPlan:
+    """``table`` entries of an expert's matrix, a row tile of ``lanes_in``
+    lanes in and ``lanes_out`` out: every streamed block twice (double
+    buffering) and the float32 product once. Over ``_GMM_TABLE_MAX`` the call
+    asks for that sum with a margin, in whole MiB; the matrix stays one block
+    (fetched once an expert: consecutive row tiles of an expert name the same
+    block), so the least HBM traffic is kept."""
+    table_bytes = table * itemsize
+    vmem = (2 * (table_bytes + row_tile * (lanes_in + lanes_out) * itemsize)
+            + row_tile * lanes_out * 4)
+    limit = None
+    if table_bytes > _GMM_TABLE_MAX:
+        limit = -(-int(vmem * _GMM_REQUEST_MARGIN) // (1 << 20)) << 20
+    return GmmPlan(table_bytes, vmem, limit)
+
+
 def _gmm_call(x, w, tile_expert, n_active, *, row_tile, transpose_rhs, name):
     """(R, A) x (held, P, Q) -> (R, Q), or (R, P) against the transposes: a
-    row tile times its expert's whole matrix (2 MiB at 2048 x 512 in bf16)."""
+    row tile times its expert's whole matrix (2 MiB at 2048 x 512 in bf16),
+    under the scoped VMEM ``gmm_plan`` says it needs."""
     R, A = x.shape
     _, Pw, Qw = w.shape
     out = Pw if transpose_rhs else Qw
     last = lambda na: jnp.maximum(na[0] - 1, 0)   # noqa: E731
+    plan = gmm_plan(row_tile, A, out, Pw * Qw, x.dtype.itemsize)
+    plans.record("gmm", plan)
+    asked = {} if plan.vmem_limit is None else {
+        "compiler_params": pltpu.CompilerParams(vmem_limit_bytes=plan.vmem_limit)}
     return pl.pallas_call(
         functools.partial(_gmm_kernel, transpose_rhs=transpose_rhs),
         grid_spec=pltpu.PrefetchScalarGridSpec(
@@ -256,6 +308,7 @@ def _gmm_call(x, w, tile_expert, n_active, *, row_tile, transpose_rhs, name):
         out_shape=jax.ShapeDtypeStruct((R, out), x.dtype),
         name=name,
         interpret=_interpret(),
+        **asked,
     )(tile_expert, n_active, x, w)
 
 
@@ -495,7 +548,7 @@ def route(y, router, *, plan: RoutedPlan, first_expert: int = 0,
         z = y router                            float32, all the experts
         I = the top_k largest of s (+ bias), among the experts of the token's
             best groups under a group limit (``plan.groups``: ``limited_choice``)
-        "sigmoid": s = sigmoid(z);  w_e = scale * s_e / sum_{e' in I} s_e'
+        "sigmoid": s = sigmoid(z);  w_e = scale * s_e / (sum_{e' in I} s_e' + eps)
         "softmax": s = softmax(z);  w_e = scale * exp(z_e) / sum_{e' in I} exp(z_e')
                    (the softmax over all the experts, its top_k renormalised,
                    is the softmax over the chosen logits)
@@ -531,7 +584,11 @@ def route(y, router, *, plan: RoutedPlan, first_expert: int = 0,
             jnp.take_along_axis(logits, chosen, axis=-1), axis=-1)
     else:
         top = jnp.take_along_axis(scores, chosen, axis=-1)
-        weights = scale * top / jnp.sum(top, axis=-1, keepdims=True)   # (T, k)
+        scaled = scale * top
+        total = jnp.sum(top, axis=-1, keepdims=True)
+        if plan.eps:
+            total = total + plan.eps
+        weights = scaled / total                                       # (T, k)
     local = chosen.astype(jnp.int32) - first_expert
     local = jnp.where((local >= 0) & (local < held), local, held)
     return {"weights": weights, "local": local, "chosen": chosen,

@@ -63,7 +63,10 @@ def tensor_parallel_rules(axis: str = "model"):
     (``Strategy.py:34``).
     """
 
-    col = re.compile(r"(qkv|mlp_in|mlp_gate)/kernel$")
+    # (``conv_b|conv_c|conv_x``: the three blocks of a short-convolution
+    # mixer's input projection, a kernel each, so that a channel's two gates
+    # and its convolved value land on one shard: ``Block._short_conv_mixer``)
+    col = re.compile(r"(qkv|mlp_in|mlp_gate|conv_b|conv_c|conv_x)/kernel$")
     row = re.compile(r"(attn_out|mlp_out)/kernel$")
     colb = re.compile(r"(qkv|mlp_in|mlp_gate)/bias$")
     # Paths are full state paths ('params/wte', 'opt_state/0/mu/wte', ...),

@@ -103,7 +103,7 @@ def test_dp_through_search_and_orchestrate_reproduces_the_reference(
                     "act": "swiglu", "latent": 0, "bias": False,   # (PR 42's fields)
                     "groups": 0, "groups_kept": 0,                 # (PR 45's)
                     "score": "sigmoid", "route_from": "ff_input",  # (PR 49's)
-                    "second_path": False}
+                    "eps": 0.0, "second_path": False}                 # (PR 52's)
     assert "window_plan" not in configs[0]            # the masked einsum has no blocks
 
 

@@ -1,9 +1,13 @@
 """(Second file of two, so that ``--dist loadfile`` spreads the compiles: the
-model and its ops are ``tests/test_ling.py``.) The Ling stack (``build_ling``)
-at ``ling-test-tiny`` on the CPU, in float32, through ``search`` ->
+model and its ops are ``tests/test_lfm2.py``.) The LFM2 stack (``build_lfm2``)
+at ``lfm2-test-tiny`` on the CPU, in float32, through ``search`` ->
 ``orchestrate`` under dp and through every technique's own step, against the
-plain reference ``perf/reference/ling.py`` from the same seeded weights.
-Tolerances as ``tests/test_laguna_techniques.py``."""
+plain reference ``perf/reference/lfm2.py`` from the same seeded weights. A
+technique that rebuilds the model from ``hints["pipeline"]`` (fsdp / tp
+overlap, offload's stream) walks the leading layer inside ``embed`` and whole
+periods after it; tp shards a convolution mixer's three input kernels by
+channel (``parallel/sharding.py``'s column rule). Tolerances as
+``tests/test_laguna_techniques.py``."""
 
 import dataclasses
 
@@ -13,17 +17,17 @@ import numpy as np
 import pytest
 
 from perf.lib import refcheck
-from perf.reference import ling
+from perf.reference import lfm2 as st
 from saturn_tpu.core.technique import InfeasibleConfig
-from saturn_tpu.models.gpt2 import build_ling
+from saturn_tpu.models.gpt2 import build_lfm2
 from saturn_tpu.utils import metrics
-from tests.test_ling import ARCH, KINDS, LEAD, SEED, SEQ
+from tests.test_lfm2 import ARCH, KINDS, LEAD, SEED, SEQ
 
 LR = 1e-3
 
 
 def _weights():
-    return ling.program_params(ARCH, ling.seed_key(SEED))
+    return st.program_params(ARCH, st.seed_key(SEED))
 
 
 def _task(save_dir, name, batch=2, steps=8, **model_kw):
@@ -32,8 +36,11 @@ def _task(save_dir, name, batch=2, steps=8, **model_kw):
     from saturn_tpu.models.loss import pretraining_loss
 
     def get_model(**kw):
-        spec = build_ling("ling-test-tiny", dtype=jnp.float32,
-                          **{"seq_len": SEQ, **model_kw, **kw})
+        # (a buffer no step can overflow: at 128 tokens a step the held pairs
+        # of a step swing by a third of their mean)
+        spec = build_lfm2(
+            "lfm2-test-tiny", dtype=jnp.float32,
+            **{"seq_len": SEQ, "routed_buffer": 100.0, **model_kw, **kw})
         return dataclasses.replace(spec, init_fn=lambda rng: _weights())
 
     return Task(
@@ -62,9 +69,7 @@ def test_dp_through_search_and_orchestrate_reproduces_the_reference(
     from saturn_tpu.core.mesh import SliceTopology
     from saturn_tpu.utils import checkpoint
 
-    # (a buffer no step can overflow, through the model's own knob: at 128
-    # tokens a step the held pairs swing by a third of their mean)
-    task = _task(tmp_path / "ck", "ling-dp", routed_buffer=100.0)
+    task = _task(tmp_path / "ck", "lfm2-dp")
     topo = SliceTopology(list(devices8[:1]))
     ev = {k: str(tmp_path / f"{k}.jsonl") for k in ("search", "window")}
     with jax.default_matmul_precision("highest"):
@@ -73,49 +78,57 @@ def test_dp_through_search_and_orchestrate_reproduces_the_reference(
         assert stats["errors"] == 0 and 1 in task.feasible_strategies()
         result = saturn_tpu.orchestrate([task], interval=600.0, topology=topo,
                                         metrics_path=ev["window"], solver_time_limit=2.0)
-    assert result["completed"] == ["ling-dp"] and not result["failed"]
+    assert result["completed"] == ["lfm2-dp"] and not result["failed"]
     batches = [task.batch_at(i) for i in range(8)]
-    ref_losses, ref_state = ling.train(ARCH, SEED, batches, LR, keep_state=True)
+    ref_losses, ref_state = st.train(ARCH, SEED, batches, LR, keep_state=True)
     (interval,) = metrics.read_events(ev["window"], kind="task_interval")
     np.testing.assert_allclose(interval["losses"], ref_losses, rtol=2e-5)
     state = refcheck.checkpoint_state(checkpoint.load_arrays(task.ckpt_path))
-    errors = refcheck.state_errors(ref_state, state)
+    leaves = {}
+    errors = refcheck.state_errors(ref_state, state, leaves=leaves)
     assert errors["grad_rel_rms"] < 1e-3 and errors["update_rel_rms"] < 3e-3, errors
+    assert all(leaves[f"blocks/l{i}/router"]["grad_rel_rms"] < 1e-3 for i in range(4))
+    assert all(leaves[leaf]["grad_rel_rms"] < 1e-3 for leaf in (
+        "lead/l0/conv_w", "blocks/l1/conv_w", "blocks/l0/q_norm", "blocks/l0/k_norm", "wte"))
     # what the events say of the stack, and the routed layers' counters
-    assert (interval["stack_layers"], interval["stack_kinds"], interval["stack_lead"]) == (
-        7, KINDS, LEAD)
+    assert (interval["stack_layers"], interval["stack_kinds"], interval.get("stack_lead")) == (
+        5, KINDS, LEAD)
     assert "mfu" not in interval and "tflops" not in interval     # no wrong figure
     assert 0 < interval["moe_pairs_held"] <= 4 * 2 * SEQ and interval["moe_second_path"] == 0
     assert interval["moe_rows_max"] >= interval["moe_rows_mean"] == \
         pytest.approx(interval["moe_pairs_held"] / 4)
     configs = metrics.read_events(ev["search"], kind="trial_config")
-    assert configs and all((e["stack_layers"], e["stack_kinds"], e["stack_lead"]) == (
-        7, KINDS, LEAD) for e in configs)
+    assert configs and all((e["stack_layers"], e["stack_kinds"], e.get("stack_lead")) == (
+        5, KINDS, LEAD) for e in configs)
     plan = configs[0]["moe_plan"]       # off the TPU the grid holds the plain twins only
     assert plan == {"impl": "xla", "tokens": 2 * SEQ, "experts": 16, "held": 4, "top_k": 4,
-                    "row_tile": 8, "rows": 4 * 2 * SEQ + 32, "worst_rows": 4 * 2 * SEQ + 32,
-                    "act": "swiglu", "latent": 0, "bias": True, "groups": 4,
-                    "groups_kept": 2, "score": "sigmoid", "route_from": "ff_input",
-                    "eps": 0.0, "second_path": False}
-    kda_plan = configs[0]["kda_plan"]
-    assert kda_plan == {"impl": "xla", "chunk": 64, "sub": 16, "n": 2 * 4, "chunks": 2,
-                        "dk": 16, "dv": 16, "state_bytes_kept": 2 * 8 * 16 * 16 * 4}
-    assert "gdn_plan" not in configs[0] and "ssd_plan" not in configs[0]
+                    "row_tile": 8, "rows": 512 + 32, "worst_rows": 512 + 32,
+                    "act": "swiglu", "latent": 0, "bias": True, "groups": 0,
+                    "groups_kept": 0, "score": "sigmoid", "route_from": "ff_input",
+                    "eps": 1e-6, "second_path": False}
+    assert configs[0]["conv_plan"] == {"impl": "xla", "taps": 3, "channels": 64,
+                                       "layers_a_period": 3, "layers_in_the_lead": 1}
+    assert "gmm_plan" not in configs[0]          # the plain twin has no blocks to hold
 
 
-def test_the_kernel_grid_point_says_its_plans(tmp_path, devices8):
+def test_the_flash_grid_point_says_its_plans(tmp_path, devices8):
     from saturn_tpu.parallel.dp import DataParallel
 
     tech, devices = DataParallel(), list(devices8[:1])
-    task = _task(tmp_path, "ling-plans")
+    task = _task(tmp_path, "lfm2-plans")
     config = {"remat": True, "attention": "flash"}
     tech.build(task, devices, config)
     fields = tech._plan_fields(task, devices, config)
     assert fields["moe_plan"]["impl"] == "kernel" and fields["step_traces"] == 1
-    assert (fields["moe_plan"]["groups"], fields["moe_plan"]["groups_kept"]) == (4, 2)
-    assert fields["kda_plan"]["impl"] == "xla"      # no kernel of the rule yet
-    assert (fields["flash_plan"]["d_qk"], fields["flash_plan"]["d_v"]) == (24, 16)
-    assert "gdn_plan" not in fields and "window_plan" not in fields
+    assert (fields["moe_plan"]["bias"], fields["moe_plan"]["eps"],
+            fields["moe_plan"]["act"]) == (True, 1e-6, "swiglu")
+    assert fields["conv_plan"]["impl"] == "xla" and "flash_plan" in fields
+    # what ``saturn_gmm_fwd`` holds an expert's matrix as: 64 x 32 float32 is
+    # far under the limit, so the call asks the compiler for nothing
+    assert fields["gmm_plan"] == {"table_bytes": 64 * 32 * 4,
+                                  "vmem": 2 * (64 * 32 * 4 + 8 * 96 * 4) + 8 * 32 * 4,
+                                  "vmem_limit": None}
+    assert "window_plan" not in fields
 
 
 # --------------------------------------------------- every technique
@@ -129,15 +142,15 @@ def _technique_names():
 def two_reference_steps():
     task = _task("/nonexistent", "ref", batch=4)
     batches = [task.batch_at(i) for i in range(2)]
-    losses, state = ling.train(ARCH, SEED, batches, LR, keep_state=True)
+    losses, state = st.train(ARCH, SEED, batches, LR, keep_state=True)
     return batches, losses, state
 
 
 def _picks(configs):
     """The first grid point, and the first of each kind that rebuilds the
     model from ``hints["pipeline"]`` (``overlap``: the ZeRO-3 program of fsdp
-    and tp; ``stream``: offload's layer loop): their unit is the period of
-    six blocks, after the leading layer inside ``embed``."""
+    and tp; ``stream``: offload's layer loop): their unit is the period, so
+    a block's route crosses its mixer inside the unit they walk."""
     out = [configs[0]]
     for key in ("overlap", "stream"):
         hit = next((c for c in configs if c.get(key)), None)
@@ -162,25 +175,35 @@ def refused(tech, task, devices, configs, tmp_path, reason):
         tech.build(task, devices, configs[0], use_cache=False)
 
 
+def offers_nothing(tech, task, devices, configs, tmp_path):
+    """No grid point, so no span: the model says it is not sequence-parallel
+    (a convolution reads two tokens back across a shard's edge, and a routed
+    layer is single-program), and the configuration refuses a sequence axis
+    where a model is built."""
+    assert not configs and task.get_model().hints["seq_parallel"] is False
+    events = str(tmp_path / "ev.jsonl")
+    with metrics.scoped(events):
+        assert tech.search(task, devices, 0) == (None, None)
+    assert not metrics.read_events(events, kind="trial.config")
+    with pytest.raises(ValueError, match="single-program"):
+        build_lfm2("lfm2-test-tiny", seq_axis="seq", seq_axis_size=2)
+
+
 @pytest.mark.parametrize("name", _technique_names())
 def test_every_technique_runs_the_stack_or_refuses_with_a_reason(
         name, tmp_path, devices8, two_reference_steps):
     from saturn_tpu.parallel import BUILTIN_TECHNIQUES
 
     tech, devices = BUILTIN_TECHNIQUES[name](), list(devices8[:4])
-    task = _task(tmp_path, f"ling-{name}", batch=4, routed_buffer=100.0)
+    task = _task(tmp_path, f"lfm2-{name}", batch=4)
     batches, ref_losses, ref_state = two_reference_steps
     configs = tech.candidate_configs(task, len(devices))
-    if name == "ep":    # the held share is one program's: no exchange of token rows yet
+    if name == "ep":    # the held share is one program's: no exchange of tokens yet
         return refused(tech, task, devices, configs, tmp_path, "exchange of tokens")
     if name == "pp":
         return refused(tech, task, devices, configs, tmp_path, "several block kinds")
     if name in ("ring", "ulysses"):
-        # a KDA layer's state crosses the whole sequence: the model says it is
-        # not sequence-parallel, and the techniques offer no grid point
-        assert not configs and task.get_model().hints["seq_parallel"] is False
-        assert tech.search(task, devices, 0) == (None, None)
-        return
+        return offers_nothing(tech, task, devices, configs, tmp_path)
     for config in _picks(configs):
         with jax.default_matmul_precision("highest"):
             bundle = tech.build(task, devices, config, use_cache=False)
@@ -190,28 +213,7 @@ def test_every_technique_runs_the_stack_or_refuses_with_a_reason(
                     state, jax.device_put(np.asarray(tokens), bundle.batch_sharding))
                 losses.append(float(loss[0] if isinstance(loss, tuple) else loss))
         np.testing.assert_allclose(losses, ref_losses, rtol=2e-5, err_msg=str(config))
-        got = ling.flat(jax.tree_util.tree_map(np.asarray, jax.device_get(state["params"])))
+        got = st.flat(jax.tree_util.tree_map(np.asarray, jax.device_get(state["params"])))
         off = sum(float(np.sum(np.square(got[k] - v))) for k, v in ref_state["params"].items())
         moved = sum(v ** 2 for v in ref_state["moved"].values())
         assert (off / moved) ** 0.5 < 3e-3, (config, (off / moved) ** 0.5)
-
-
-def test_step_flops_are_left_out_and_the_analyses_walk_the_chunk_scan(tmp_path, devices8):
-    """Shardflow cannot see into the rule's ``custom_vjp``, so the package
-    reports no ``tflops`` / ``mfu`` rather than a figure short by a mixer;
-    shardflow and memlens still walk the step's one trace: the sub-blocks'
-    batched products in the chunk scan in the period in the layer scan, and
-    the routed layers' ``cond``."""
-    from saturn_tpu.analysis.memlens import liveness
-    from saturn_tpu.analysis.shardflow.interp import interpret
-    from saturn_tpu.parallel.dp import DataParallel
-
-    tech, devices = DataParallel(), list(devices8[:1])
-    config = {"remat": True, "attention": "dense"}
-    task = _task(tmp_path, "ling-flops")
-    assert tech._step_flops(task, devices, config) is None
-    traced = tech.trace_step(task, devices, config)
-    assert liveness.analyze(traced, window=1).peak_bytes > 0
-    assert liveness.analyze(traced, window=8).peak_bytes >= \
-        liveness.analyze(traced, window=1).peak_bytes
-    assert interpret(traced).flops > 0

@@ -98,7 +98,7 @@ def test_dp_through_search_and_orchestrate_reproduces_the_reference(
                     "row_tile": 8, "rows": 384 + 32, "worst_rows": 384 + 32,
                     "act": "relu2", "latent": 32, "bias": True, "groups": 0,
                     "groups_kept": 0, "score": "sigmoid", "route_from": "ff_input",
-                    "second_path": False}
+                    "eps": 0.0, "second_path": False}
     ssd_plan = configs[0]["ssd_plan"]
     assert (ssd_plan["impl"], ssd_plan["chunk"], ssd_plan["heads"], ssd_plan["groups"],
             ssd_plan["heads_published"], ssd_plan["groups_published"]) == ("xla", 16, 4, 2, 16, 8)

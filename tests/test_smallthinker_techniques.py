@@ -102,7 +102,7 @@ def test_dp_through_search_and_orchestrate_reproduces_the_reference(
                     "row_tile": 8, "rows": 512 + 32, "worst_rows": 512 + 32,
                     "act": "reglu", "latent": 0, "bias": False, "groups": 0,
                     "groups_kept": 0, "score": "softmax", "route_from": "block_input",
-                    "second_path": False}
+                    "eps": 0.0, "second_path": False}
     assert configs[0]["rotary_plan"] == {"rotated": [SLIDING], "unrotated": [FULL]}
     assert "window_plan" not in configs[0]            # the masked einsum has no blocks
 

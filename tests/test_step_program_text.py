@@ -34,7 +34,14 @@ _TEXT_BEFORE_THE_ROUTE_WAS_SPLIT = {
     ("ling-test-tiny", False): "f8253b6a1828715b",
     ("ling-test-tiny", True): "93a93dbfaf52f2f5",
 }
-_PINNED = {**_TEXT_BEFORE_LING, **_TEXT_BEFORE_THE_ROUTE_WAS_SPLIT}
+#: SmallThinker's tiny preset, taken on the commit before the grouped product
+#: got its plan and ``_softmax_mixer`` its norm a head (PR 52; a0578f3)
+_TEXT_BEFORE_THE_GMM_PLAN = {
+    ("smallthinker-test-tiny", False): "b42945fff53f7507",
+    ("smallthinker-test-tiny", True): "c43749e06216ee3a",
+}
+_PINNED = {**_TEXT_BEFORE_LING, **_TEXT_BEFORE_THE_ROUTE_WAS_SPLIT,
+           **_TEXT_BEFORE_THE_GMM_PLAN}
 
 
 def _step_text(preset, remat):

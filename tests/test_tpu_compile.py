@@ -323,6 +323,57 @@ def test_routed_layer_kernels_compile_for_v5e_at_a_latent_width(one_chip, real_l
              kernels=["saturn_gmm_fwd", "saturn_gmm_dx", "saturn_gmm_dw"])
 
 
+def test_routed_layer_kernels_compile_for_v5e_at_a_table_over_the_default_vmem(
+        one_chip, real_lowering):
+    """The grouped products at the LFM2 cell's shapes: 32768 tokens, sigmoid
+    top-4 of 32 experts under a selection bias, 8 held SwiGLU tables of 2048 x
+    1792 (7 MiB in bf16: double-buffered 14 of the 16 MiB a kernel is given
+    unasked), a row buffer of 1.5 x the mean pairs. ``saturn_gmm_fwd`` /
+    ``_dx`` keep one block an expert and ask the compiler for the VMEM
+    ``gmm_plan`` sums (21-22 MiB); ``saturn_gmm_dw`` asks for nothing."""
+    sds = lambda *shape, dtype=jnp.float32: jax.ShapeDtypeStruct(   # noqa: E731
+        shape, dtype, sharding=one_chip)
+    plan = moe_mod.routed_plan(32768, 32, 8, 4, buffer=1.5, impl="kernel", bias=True,
+                               eps=1e-6)
+    assert (plan.rows, plan.row_tile, plan.second_path) == (49152 + 1024, 128, True)
+
+    def loss(y, router, bias, w_gate, w_up, w_down):
+        out, _ = moe_mod.routed_experts(y, router, w_gate, w_up, w_down, plan=plan,
+                                        bias=bias)
+        return jnp.sum(out.astype(jnp.float32))
+
+    args = (sds(32768, 2048, dtype=jnp.bfloat16), sds(2048, 32), sds(32),
+            sds(8, 2048, 1792), sds(8, 2048, 1792), sds(8, 1792, 2048))
+    grad = jax.grad(loss, argnums=(0, 1, 3, 4, 5))
+    lowered = jax.jit(grad).lower(*args).as_text()
+    asked = {m for line in lowered.splitlines() if "tpu_custom_call" in line
+             for kernel in ("saturn_gmm_fwd", "saturn_gmm_dx", "saturn_gmm_dw")
+             if f'kernel_name = "{kernel}"' in line
+             for m in [(kernel, (re.search(r"scoped_memory_configs.*?size\\22:\s*(\d+)",
+                                           line) or [None, None])[1])]}
+    # (21 MiB where the row tile leaves 1792 lanes wide, 22 where it leaves 2048)
+    both = {str(21 << 20), str(22 << 20)}
+    assert asked == {(kernel, size) for kernel in ("saturn_gmm_fwd", "saturn_gmm_dx")
+                     for size in both} | {("saturn_gmm_dw", None)}
+    _compile(grad, *args, kernels=["saturn_gmm_fwd", "saturn_gmm_dx", "saturn_gmm_dw"])
+
+
+def test_flash_at_four_q_heads_a_kv_head_of_64_lanes_compiles_for_v5e(
+        one_chip, real_lowering):
+    """``saturn_flash_*`` at the LFM2 cell's shape, which no cell had run:
+    head 64 (gpt2-medium's, the kernels' weakest) under grouped k/v, 32 q
+    heads over 8 k/v heads, 8192 positions, batch 4."""
+    sds = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.bfloat16,   # noqa: E731
+                                              sharding=one_chip)
+
+    def loss(q, k, v):
+        return jnp.sum(flash_mod.flash_attention(q, k, v, causal=True).astype(jnp.float32))
+
+    _compile(jax.grad(loss, argnums=(0, 1, 2)),
+             sds(4, 32, 8192, 64), sds(4, 8, 8192, 64), sds(4, 8, 8192, 64),
+             kernels=["saturn_flash_fwd", "saturn_flash_dq", "saturn_flash_dkv"])
+
+
 # ---------------------------------------------------------------- fused CE
 CE_SHAPES = {
     "gpt2-small": (4096, 768, 50257),
@@ -351,6 +402,9 @@ CE_SHAPES = {
     # multiple of any larger block: padded under the mask to the blocks'
     # common multiple) at seq 8192 x batch 4 (PR 49)
     "smallthinker-8k-b4": (32768, 2560, 19072),
+    # the LFM2 cell's head: d 2048 (Ouro's and Laguna's width) over the held
+    # quarter of a tied vocabulary, 16384 rows, at seq 8192 x batch 4 (PR 52)
+    "lfm2-8k-b4": (32768, 2048, 16384),
 }
 
 
